@@ -65,40 +65,51 @@ from paddle_tpu.flags import get_flag, set_flags
 from paddle_tpu.data_feeder import DataFeeder
 
 
-def enable_compile_cache(cache_dir):
-    """Point jax's persistent on-disk compilation cache at ``cache_dir``
-    (ROADMAP item 5: cold-start as a product metric).  Every XLA/Mosaic
-    compile is keyed on (graph, flags, shapes) and reused across
-    processes and restarts, so a serving replica fleet warms its bucket
-    set from disk instead of paying a per-replica compile storm.
-    Called automatically at import when ``PADDLE_TPU_COMPILE_CACHE_DIR``
-    is set; returns True when the cache was enabled."""
+def compile_cache_dir():
+    """The one place the persistent compilation cache's directory is
+    decided.  ``JAX_COMPILATION_CACHE_DIR`` when the environment sets
+    it (jax reads that variable itself, so no code here or anywhere
+    else sets a directory then); otherwise ``<checkout>/.jax_cache`` —
+    a fixed path, because the path is part of every entry's key and a
+    directory that moves never hits.  None when jax's cache is
+    switched off (``jax_enable_compilation_cache`` False, as
+    tests/conftest.py does)."""
     import os as _os
 
     import jax as _jax
 
-    try:
-        _os.makedirs(cache_dir, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        # serving buckets are tiny, fast compiles — cache everything,
-        # not just the >1s entries jax defaults to keeping
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                           0.0)
-        _jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                           0)
-        return True
-    except Exception:  # noqa: BLE001 — a cache is an optimization, never a crash
-        return False
+    if not _jax.config.jax_enable_compilation_cache:
+        return None
+    return _os.environ.get("JAX_COMPILATION_CACHE_DIR") or _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache")
 
 
-def _init_compile_cache():
+def enable_compile_cache():
+    """Turn on jax's persistent on-disk compilation cache at
+    ``compile_cache_dir()``: every XLA/Mosaic compile is keyed on
+    (graph, flags, shapes) and reused across processes and restarts, so
+    a second run of a program — or a replica fleet warming its bucket
+    set — replays compiles from disk.  Called by the entry points that
+    compile for the chip (chip_smoke.py, bench.py legs,
+    tools/serving_load.py, the servers' ``start()``), not at import.
+    A default directory that cannot be made raises.  Returns the
+    directory, or None when the cache is switched off."""
     import os as _os
 
-    cache_dir = _os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR")
-    if cache_dir:
-        enable_compile_cache(cache_dir)
+    import jax as _jax
 
+    cache_dir = compile_cache_dir()
+    if cache_dir is None:
+        return None
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _os.makedirs(cache_dir, exist_ok=True)
+        _jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # serving buckets and eager startup ops are tiny, fast compiles —
+    # cache everything, not just the >1s entries jax defaults to keeping
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return cache_dir
 
-_init_compile_cache()
 
 __version__ = "0.1.0"

@@ -856,7 +856,7 @@ class CompiledProgram:
                           for k, v in state.items()}
             # compile event (ISSUE 9): jit-cache miss = a new (shapes,
             # program) entry — the cold-start cost the serving bucket
-            # cache and PADDLE_TPU_COMPILE_CACHE_DIR exist to bound
+            # cache and the persistent compile cache exist to bound
             _M_COMPILES.inc()
             _obs_flight.record(
                 "executor", "compile",
@@ -877,15 +877,22 @@ class CompiledProgram:
                                     feed_shardings=feed_shardings)
             self._cache[key] = fn
         if self._mesh is not None and not multiproc:
-            # conform COMMITTED state arrays to the declared
-            # in_shardings: jit auto-places uncommitted arrays but
-            # refuses a committed mismatch — e.g. a checkpoint
-            # restored right after the startup program lands whole on
-            # device 0 (the relaunched-trainer resume path), or the
-            # sharding rules changed between runs.  Expected
+            # conform state arrays to the declared in_shardings BEFORE
+            # the call, committed or not.  Two reasons.  jit refuses a
+            # committed mismatch — e.g. a checkpoint restored right
+            # after the startup program lands whole on device 0 (the
+            # relaunched-trainer resume path), or the sharding rules
+            # changed between runs.  And an array's aval carries its
+            # mesh (jax 0.9): what the startup program made has none,
+            # what a step returns has this one, so a first step fed
+            # as-is is traced and compiled once for the startup state
+            # and AGAIN on step 2 for its own outputs (found on the
+            # four-chip v5e: a second 33 s compile).  Expected
             # shardings are cached per jit key; steady-state arrays
             # (outputs of the previous step) already match and skip
             # the device_put.
+            from jax.sharding import NamedSharding
+
             skey = ("__state_sh__",) + key
             expect = self._cache.get(skey)
             if expect is None:
@@ -894,9 +901,10 @@ class CompiledProgram:
                 self._cache[skey] = expect
             for k, sh in expect.items():
                 v = state[k]
-                if isinstance(v, jax.Array) and \
-                        getattr(v, "committed", False) and \
-                        not sh.is_equivalent_to(v.sharding, v.ndim):
+                if not (isinstance(v, jax.Array) and
+                        isinstance(v.sharding, NamedSharding) and
+                        v.sharding.mesh == sh.mesh and
+                        sh.is_equivalent_to(v.sharding, v.ndim)):
                     state[k] = jax.device_put(v, sh)
         import time as _time
 

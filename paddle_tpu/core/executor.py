@@ -55,7 +55,11 @@ class RuntimeCtx:
 
 
 class Executor:
-    """reference: python/paddle/fluid/executor.py:294"""
+    """reference: python/paddle/fluid/executor.py:294
+
+    ``place`` is kept for API parity and decides nothing: XLA's default
+    device (jax.devices()[0]) owns placement on both the interpreted and
+    the compiled path, whatever Place is passed (core/types.py)."""
 
     def __init__(self, place: Place = None):
         self.place = place if place is not None else CPUPlace()

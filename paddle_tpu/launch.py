@@ -6,10 +6,13 @@ PADDLE_TRAINER_ID / PADDLE_TRAINERS_NUM / PADDLE_TRAINER_ENDPOINTS /
 PADDLE_CURRENT_ENDPOINT injected; trainers bootstrap NCCL from these).
 
 TPU-first difference: within one host, SPMD needs ONE process driving all
-local chips (multi-process per host would fight over the TPU runtime), so
---nproc_per_node defaults to 1 and the launcher's main job is multi-HOST
-fan-out: every spawned process gets the same env contract and
-fleet.init() wires jax.distributed from it.
+local chips, so --nproc_per_node defaults to 1 and the launcher's main job
+is multi-HOST fan-out: every spawned process gets the same env contract and
+fleet.init() wires jax.distributed from it.  With more than one process per
+host the first to touch JAX holds every local chip and the others fail or
+hang at start-up: only use it where each process is pinned to its own chips
+(TPU_VISIBLE_CHIPS and the matching TPU_PROCESS_* variables, set by the
+caller) or runs on the CPU.  This launcher itself never imports JAX.
 
 Usage:  python -m paddle_tpu.launch --nnodes 1 --node_rank 0 \
             --started_port 6170 train.py [args...]

@@ -3,9 +3,9 @@
 Reference parity (SURVEY.md §2.1/§2.8): framework/blocking_queue.h +
 channel.h, framework/data_feed.cc (MultiSlotDataFeed), recordio/,
 framework/io/shell.cc.  Loaded via ctypes from libpaddle_tpu_native.so,
-built on first import with the in-tree Makefile (g++); if the toolchain is
-unavailable a pure-Python fallback with the same classes keeps every
-feature working (slower parse path only).
+built on first import with the in-tree Makefile (g++); if the build or the
+load fails, a warning says why and a pure-Python fallback with the same
+classes keeps every feature working (slower parse path only).
 
 `NATIVE` tells callers which implementation is live.
 """
@@ -17,6 +17,7 @@ import os
 import queue as _pyqueue
 import struct
 import subprocess
+import warnings
 import zlib
 
 import numpy as np
@@ -33,11 +34,20 @@ def _build_and_load():
         try:
             subprocess.run(["make", "-s"], cwd=_DIR, check=True,
                            capture_output=True, timeout=120)
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
+            err = getattr(e, "stderr", b"") or b""
+            warnings.warn(
+                "paddle_tpu.native: `make` in %s failed (%s%s); using "
+                "the pure-Python fallback" % (
+                    _DIR, e, (": " + err.decode(errors="replace")[-400:])
+                    if err else ""), RuntimeWarning)
             return None
     try:
         lib = ctypes.CDLL(_SO)
-    except OSError:
+    except OSError as e:
+        warnings.warn(
+            "paddle_tpu.native: could not load %s (%s); using the "
+            "pure-Python fallback" % (_SO, e), RuntimeWarning)
         return None
     lib.pt_free.argtypes = [ctypes.c_void_p]
     lib.pt_queue_create.restype = ctypes.c_void_p

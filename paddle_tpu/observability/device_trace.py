@@ -12,7 +12,7 @@ step/compile wraps its work in ``annotate(kernel)``:
   - tracing flag OFF: the site is ONE module-global None check (the
     PR-9 disabled-cost contract) — callers guard with
     ``if tracing._tracer is not None`` exactly like span sites;
-  - at RUNTIME (``jax.core.trace_state_clean()``): a
+  - at RUNTIME (``jax.core.trace_ctx.is_top_level()``): a
     ``jax.profiler.TraceAnnotation`` whose name carries the kernel and
     the ACTIVE trace id under the grammar ``pt#<kernel>#<trace_id>``
     (``pt#<kernel>#-`` when no trace is active; an UNSAMPLED trace
@@ -143,7 +143,7 @@ def annotate(kernel):
         return _NULL
     import jax
 
-    if not jax.core.trace_state_clean():
+    if not jax.core.trace_ctx.is_top_level():
         # tracing INTO a jit: the kernel identity rides the HLO
         # metadata (stable across requests); never bake a trace id
         # into a cached compile

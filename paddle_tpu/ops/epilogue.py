@@ -69,11 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
 from paddle_tpu.observability import tracing as _obs_trace
-
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support
-# both (same shim as ops/pallas_conv.py / ops/pallas_kernels.py)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+from paddle_tpu.ops.pallas_kernels import _count_impl
 
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _FC_BLOCK_M = 256
@@ -419,8 +415,10 @@ def _fc_ep_pallas(x2, w2, bias, residual, act, approximate,
             x2.dtype.itemsize, w2.dtype.itemsize,
             jnp.dtype(out_dtype).itemsize)
         if est > _VMEM_BUDGET_BYTES:
+            _count_impl("fc_epilogue", "xla")
             return _fc_reference(x2, w2, bias, residual, act,
                                  approximate)
+    _count_impl("fc_epilogue", "interpret" if interpret else "pallas")
 
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn))
     in_specs = [
@@ -437,7 +435,7 @@ def _fc_ep_pallas(x2, w2, bias, residual, act, approximate,
         operands.append(residual)
     params = {}
     if not interpret:
-        params["compiler_params"] = _CompilerParams(
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
     kernel = functools.partial(
         _fc_ep_kernel, act=act, approximate=approximate,
@@ -458,6 +456,7 @@ def _fc_ep(x2, w2, bias, residual, act, approximate, impl):
     if impl in ("pallas", "interpret"):
         return _fc_ep_pallas(x2, w2, bias, residual, act, approximate,
                              interpret=impl == "interpret")
+    _count_impl("fc_epilogue", "xla")
     return _fc_reference(x2, w2, bias, residual, act, approximate)
 
 

@@ -116,7 +116,7 @@ def fused_adam(ins, attrs):
     optimizer tail is a single elementwise pass over one contiguous
     buffer instead of ~N small kernels XLA schedules independently —
     the Adam-tail A/B lever for the transformer batch-slide diagnosis
-    (PROFILE_r4 §5.3 deferral; VERDICT r5 next-round #6).  The update
+    (ROADMAP 1.3).  The update
     math matches the per-param `adam` op (lr_t computed in f32, cast
     per dtype group); beta pows are shared — every param sees the same
     step count."""

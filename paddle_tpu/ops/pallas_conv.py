@@ -49,13 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
 from paddle_tpu.observability import tracing as _obs_trace
-
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support
-# both so the kernel lowers under the CI jax as well as the chip
-# host's (the seed's TPU cross-lowering tests failed on exactly this
-# drift)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+from paddle_tpu.ops.pallas_kernels import _count_impl
 
 # VMEM budget for the compiled kernel: one image block + filter tile +
 # accumulator + residual tile, doubled for Pallas' input double
@@ -206,8 +200,10 @@ def _conv_ep_pallas(x, w, bias, residual, strides, padding, act,
                              w_hwio.dtype.itemsize,
                              jnp.dtype(out_dtype).itemsize)
         if est > _VMEM_BUDGET_BYTES:
+            _count_impl("conv2d_epilogue", "xla")
             return _reference(x, w, bias, residual, strides, padding,
                               act)
+    _count_impl("conv2d_epilogue", "interpret" if interpret else "pallas")
 
     grid = (n, pl.cdiv(cout, bco))
     in_specs = [
@@ -224,7 +220,7 @@ def _conv_ep_pallas(x, w, bias, residual, strides, padding, act,
         operands.append(residual)
     params = {}
     if not interpret:
-        params["compiler_params"] = _CompilerParams(
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
     kernel = functools.partial(
         _conv_ep_kernel, kh=kh, kw=kw, sh=sh, sw=sw, oh=oh, ow=ow,
@@ -251,6 +247,7 @@ def _conv_ep(x, w, bias, residual, strides, padding, act, impl):
     if impl in ("pallas", "interpret"):
         return _conv_ep_pallas(x, w, bias, residual, strides, padding,
                                act, interpret=impl == "interpret")
+    _count_impl("conv2d_epilogue", "xla")
     return _reference(x, w, bias, residual, strides, padding, act)
 
 
@@ -392,6 +389,7 @@ def _conv_stats_pallas(x, w, bias, strides, padding, interpret=False):
         est += 4 * bco * 4 * 2
         if est > _VMEM_BUDGET_BYTES:
             return _conv_stats_xla(x, w, bias, strides, padding)
+    _count_impl("conv2d_bn_stats", "interpret" if interpret else "pallas")
 
     grid = (n, pl.cdiv(cout, bco))
     in_specs = [
@@ -404,7 +402,7 @@ def _conv_stats_pallas(x, w, bias, strides, padding, interpret=False):
         operands.append(bias.reshape(1, cout))
     params = {}
     if not interpret:
-        params["compiler_params"] = _CompilerParams(
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
     kernel = functools.partial(
         _conv_stats_kernel, kh=kh, kw=kw, sh=sh, sw=sw, oh=oh, ow=ow,
@@ -436,6 +434,7 @@ def _conv_stats_xla(x, w, bias, strides, padding):
     """XLA fallback with the kernel's stat semantics: plain conv, then
     per-image partial sums of the (cast) output — multi-output fused by
     XLA into one read pass over y."""
+    _count_impl("conv2d_bn_stats", "xla")
     y = _conv_core(x, w, strides, padding)
     if bias is not None:
         y = y + bias.astype(y.dtype)
@@ -530,7 +529,7 @@ def _bn_apply_pallas(y, mean, rstd, scale, shift, residual, act,
         operands.append(residual)
     params = {}
     if not interpret:
-        params["compiler_params"] = _CompilerParams(
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"))
     kernel = functools.partial(_bn_apply_kernel, act=act,
                                has_res=residual is not None)
@@ -645,6 +644,7 @@ def _conv_bn_core(x, w, bias, scale, shift, residual, strides, padding,
                                shift.astype(jnp.float32), residual, act,
                                interpret=interp)
         return out, mean, var, y
+    _count_impl("conv2d_bn_stats", "xla")
     return _conv_bn_unfused(x, w, bias, scale, shift, residual, strides,
                             padding, act, eps)
 

@@ -117,19 +117,3 @@ def reset():
     global _current_mesh
     _current_mesh = None
     _rings.clear()
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-    """Version-compat wrapper: jax.shard_map (>=0.8, check_vma) vs the old
-    jax.experimental.shard_map (check_rep).  Replication checking is off —
-    our kernels use explicit collectives (ppermute/psum/all_to_all)."""
-    try:
-        from jax import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_rep)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_rep)

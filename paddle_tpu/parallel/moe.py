@@ -77,13 +77,12 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, mesh=None, axis="ep",
 
     if mesh is not None and axis in mesh.axis_names \
             and mesh.shape[axis] > 1 and e % mesh.shape[axis] == 0:
-        from paddle_tpu.parallel.env import shard_map
         from jax.sharding import PartitionSpec as P
 
         es = P(axis)
-        ye = shard_map(experts, mesh=mesh,
-                       in_specs=(es, es, es, es, es), out_specs=es,
-                       check_rep=False)(xe, w1, b1, w2, b2)
+        ye = jax.shard_map(experts, mesh=mesh,
+                           in_specs=(es, es, es, es, es), out_specs=es,
+                           check_vma=False)(xe, w1, b1, w2, b2)
     else:
         ye = experts(xe, w1, b1, w2, b2)
 

@@ -50,7 +50,6 @@ def pipeline_apply(stage_fn, stage_params, x, num_microbatches,
             out = stage_fn(p_i, out)
         return out
 
-    from paddle_tpu.parallel.env import shard_map
     from jax.sharding import PartitionSpec as P
 
     S = mesh.shape[axis]
@@ -85,9 +84,9 @@ def pipeline_apply(stage_fn, stage_params, x, num_microbatches,
                          jnp.zeros_like(valid))
         return lax.psum(mine, axis)
 
-    out = shard_map(local, mesh=mesh,
-                    in_specs=(params_spec, P()),
-                    out_specs=P(), check_rep=False)(stage_params, xmb)
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(params_spec, P()),
+                        out_specs=P(), check_vma=False)(stage_params, xmb)
     return out.reshape((b,) + out.shape[2:])
 
 

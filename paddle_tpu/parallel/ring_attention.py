@@ -93,7 +93,6 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False,
 
         impl = "flash" if _on_tpu() else "xla"
 
-    from paddle_tpu.parallel.env import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -174,8 +173,8 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False,
         out = (o / jnp.maximum(l[..., None], 1e-30)).astype(q_blk.dtype)
         return jnp.swapaxes(out, 1, 2)          # back to [B, blk, H, D]
 
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _plain_attention(q, k, v, causal, scale):

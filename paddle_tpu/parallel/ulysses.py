@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 
 
@@ -48,7 +49,6 @@ def ulysses_attention(q, k, v, mesh=None, axis="sp", causal=False,
         impl = "flash" if _on_tpu() else "xla"
 
     from jax import lax
-    from paddle_tpu.parallel.env import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -83,5 +83,5 @@ def ulysses_attention(q, k, v, mesh=None, axis="sp", causal=False,
         qh, kh, vh = seq2head(ql), seq2head(kl), seq2head(vl)
         return head2seq(attend(qh, kh, vh))
 
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

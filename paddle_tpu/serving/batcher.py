@@ -126,7 +126,7 @@ class ShapeBucketBatcher:
                        # (signature, bucket) was never formed before
                        # is COLD (the replica pays a compile unless a
                        # persistent compilation cache pre-warmed it —
-                       # PADDLE_TPU_COMPILE_CACHE_DIR); the rest are
+                       # paddle_tpu.compile_cache_dir()); the rest are
                        # WARM.  tools/serving_load.py banks both next
                        # to time_to_first_batch_s (ROADMAP item 5).
                        "bucket_cold": 0, "bucket_warm": 0}

@@ -93,6 +93,7 @@ a sequence.
 
 from __future__ import annotations
 
+import functools
 import queue as queue_mod
 import threading
 import time
@@ -186,38 +187,46 @@ class TinyDecodeLM:
             return jnp.asarray(
                 (rng.randn(*shape) * 0.3).astype(np.float32), dtype)
 
-        self.embed = w(self.vocab, d_model)
-        self.wq = w(d_model, hd)
-        self.wk = w(d_model, hd)
-        self.wv = w(d_model, hd)
-        self.wo = w(hd, self.vocab)
+        self.params = {"embed": w(self.vocab, d_model),
+                       "wq": w(d_model, hd), "wk": w(d_model, hd),
+                       "wv": w(d_model, hd), "wo": w(hd, self.vocab)}
 
-        def _qkv(tokens):
-            e = self.embed[tokens]
+        def _qkv(p, tokens):
+            e = p["embed"][tokens]
             shp = (tokens.shape[0], self.num_heads, self.head_dim)
-            return ((e @ self.wq).reshape(shp),
-                    (e @ self.wk).reshape(shp),
-                    (e @ self.wv).reshape(shp))
+            return ((e @ p["wq"]).reshape(shp),
+                    (e @ p["wk"]).reshape(shp),
+                    (e @ p["wv"]).reshape(shp))
 
-        def _logits(attn_out):
+        def _logits(p, attn_out):
             flat = attn_out.reshape(attn_out.shape[0], hd)
-            return flat.astype(self.wo.dtype) @ self.wo
+            return flat.astype(p["wo"].dtype) @ p["wo"]
 
-        # the pure functions are public so a caller building its own
-        # jitted decode step (bench.py _build_llm_decode, the lowering
-        # gate) can inline them under one jit
-        self.qkv_fn = _qkv
-        self.logits_fn = _logits
+        # The weights ride as an ARGUMENT of the adapter's own jits.
+        # Closed over, jit bakes them into the executable as constants:
+        # at vocab 32000 x d_model 1024 that is 245 MB of program per
+        # token-count shape, slow to compile, held on the device beside
+        # the arrays themselves, and more than a persistent compile
+        # cache entry may hold, so every start compiled it again (found
+        # on the v5e by chip_smoke.py).
+        self.qkv_of = _qkv
+        self.logits_of = _logits
         self._qkv_jit = jax.jit(_qkv)
         self._logits_jit = jax.jit(_logits)
+        # the closed-over forms stay public for a caller that inlines
+        # them under its own jit (bench.py _build_llm_decode, the
+        # lowering gate); that jit then carries the weights
+        self.qkv_fn = functools.partial(_qkv, self.params)
+        self.logits_fn = functools.partial(_logits, self.params)
 
     def qkv(self, tokens):
         import jax.numpy as jnp
 
-        return self._qkv_jit(jnp.asarray(np.asarray(tokens, np.int32)))
+        return self._qkv_jit(self.params,
+                             jnp.asarray(np.asarray(tokens, np.int32)))
 
     def logits(self, attn_out):
-        return self._logits_jit(attn_out)
+        return self._logits_jit(self.params, attn_out)
 
 
 class DecodeConfig:
@@ -492,6 +501,9 @@ class DecodeServer:
     def start(self):
         if not self._started:
             self._started = True
+            from paddle_tpu import enable_compile_cache
+
+            enable_compile_cache()
             if self.config.trace_sample is not None:
                 _trace.set_sample_rate(self.config.trace_sample)
             if self.config.metrics_port is not None:

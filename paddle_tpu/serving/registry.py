@@ -13,8 +13,8 @@ to assert a rollback restored the exact old program.
 Prewarm-compile (the rollout contract): ``ModelVersion.prewarm``
 builds a predictor and pushes a zeros batch of every serving bucket
 through it, so the whole bucket set is compiled BEFORE the version
-takes traffic — with PADDLE_TPU_COMPILE_CACHE_DIR set (PR 8) the
-compiles land in / replay from the persistent compile cache, shared
+takes traffic — with the persistent compile cache on
+(paddle_tpu.compile_cache_dir()) the compiles land in / replay from it, shared
 across replicas and process restarts.  A version whose model cannot
 load or compile surfaces the typed ``PrewarmFailedError`` and takes
 zero traffic (the old version keeps serving — no partial fleet).
@@ -161,8 +161,8 @@ class ModelVersion:
     def prewarm(self, buckets=(1, 2, 4, 8), predictor=None):
         """Compile every serving bucket BEFORE the version takes
         traffic: a zeros batch per bucket through the predictor (the
-        server-prewarm shape — with PADDLE_TPU_COMPILE_CACHE_DIR the
-        compiles persist across replicas/restarts).  Returns the
+        server-prewarm shape — with the persistent compile cache on
+        the compiles persist across replicas/restarts).  Returns the
         warmed predictor; raises the typed PrewarmFailedError on any
         load/compile failure."""
         import numpy as np

@@ -83,11 +83,11 @@ class ServingConfig:
         # cold-start follow-through (ROADMAP item 5): compile every
         # (replica, bucket) entry at start() so the first real request
         # never pays a bucket compile.  With the persistent
-        # compilation cache (PADDLE_TPU_COMPILE_CACHE_DIR) the prewarm
-        # replays compiles from disk — seconds instead of the
+        # compilation cache on (paddle_tpu.compile_cache_dir()) the
+        # prewarm replays compiles from disk — seconds instead of the
         # first-compile minutes — which is why the default is
-        # "prewarm iff the cache dir is set": without it, prewarm
-        # still helps p99 but moves the full compile cost to startup.
+        # "prewarm iff the cache is on": without it, prewarm still
+        # helps p99 but moves the full compile cost to startup.
         # PADDLE_TPU_SERVING_PREWARM=0/1 overrides.
         if prewarm is None:
             import os
@@ -96,8 +96,9 @@ class ServingConfig:
             if env is not None:
                 prewarm = env.lower() in ("1", "true", "yes", "on")
             else:
-                prewarm = bool(
-                    os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR"))
+                from paddle_tpu import compile_cache_dir
+
+                prewarm = compile_cache_dir() is not None
         self.prewarm = bool(prewarm)
         # observability (ISSUE 9): mount /metrics + /varz on this
         # server.  None -> PADDLE_TPU_METRICS_PORT -> off; 0 binds an
@@ -183,6 +184,9 @@ class InferenceServer:
         if self._started:
             return self
         self._started = True
+        from paddle_tpu import enable_compile_cache
+
+        enable_compile_cache()
         if self.config.trace_sample is not None:
             _trace.set_sample_rate(self.config.trace_sample)
         if self.config.metrics_port is not None:
@@ -210,7 +214,7 @@ class InferenceServer:
     def prewarm_buckets(self):
         """Run a zeros batch of every bucket size through every
         replica's predictor, so the full serving bucket set is
-        compiled (or replayed from PADDLE_TPU_COMPILE_CACHE_DIR)
+        compiled (or replayed from paddle_tpu.compile_cache_dir())
         BEFORE the first request arrives — the replica-start half of
         the cold-start story (docs/SERVING.md; tools/serving_load.py
         banks the resulting warm-vs-cold time_to_first_batch_s pair).

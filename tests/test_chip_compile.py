@@ -1,0 +1,95 @@
+"""The main path's Pallas kernels at real widths, compiled for a
+DESCRIBED v5e — rehearsal (iii) of the on-chip-measurement guide, the
+cheap part (about two seconds a case), kept as tests so they guard
+every later PR at no chip time.  The whole-step compiles (20-40 s each)
+live in tools/tpu_lowering_check.py; the stride-2 and stem convs
+(~50 s each) are left out.
+
+A compile asks the chip's whole compiler (Mosaic lowering rules, VMEM
+limits, HBM fit) and runs nothing: it says nothing about results or
+times.  Skipped where the topology cannot be described (no libtpu, or
+another process holds its lock).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+BF16 = jnp.bfloat16
+
+
+def _sds(shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _flash(shape, grad):
+    from paddle_tpu.ops.pallas_kernels import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, impl="pallas")
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    return (bwd if grad else fwd), (_sds(shape),) * 3, 3 if grad else 1
+
+
+def _decode(head_dim, head_pack, batch=64, heads=8, page_size=128,
+            max_pages=4):
+    from paddle_tpu.ops.pallas_kernels import flash_decode
+
+    def fn(q, kp, vp, tables, lens):
+        return flash_decode(q, kp, vp, tables, lens, impl="pallas",
+                            head_pack=head_pack)
+
+    pool = _sds((batch * max_pages + 1, heads, page_size, head_dim))
+    return fn, (_sds((batch, heads, head_dim)), pool, pool,
+                _sds((batch, max_pages), jnp.int32),
+                _sds((batch,), jnp.int32)), 1
+
+
+def _conv(bn, n=128, hw=56, cin=64, cout=64):
+    from paddle_tpu.ops.pallas_conv import conv2d_bn_act, conv2d_epilogue
+
+    x, w = _sds((n, hw, hw, cin)), _sds((cout, cin, 3, 3))
+    res, vec = _sds((n, hw, hw, cout)), _sds((cout,), jnp.float32)
+    if bn:
+        return (lambda x, w, g, b, res: conv2d_bn_act(
+            x, w, g, b, None, res, paddings=(1, 1), act="relu",
+            impl="pallas")), (x, w, vec, vec, res), 2
+    return (lambda x, w, b, res: conv2d_epilogue(
+        x, w, b, res, paddings=(1, 1), act="relu",
+        impl="pallas")), (x, w, vec, res), 1
+
+
+def _fc(m=16384, k=512, n=2048):
+    from paddle_tpu.ops.epilogue import fc_epilogue
+
+    return (lambda x, w, b: fc_epilogue(x, w, b, act="relu",
+                                        impl="pallas")), (
+        _sds((m, k)), _sds((k, n)), _sds((n,), jnp.float32)), 1
+
+
+CASES = {
+    "flash_fwd_32x8x512x64": lambda: _flash((32, 8, 512, 64), False),
+    "flash_bwd_32x8x512x64": lambda: _flash((32, 8, 512, 64), True),
+    "flash_fwd_1x8x32768x128": lambda: _flash((1, 8, 32768, 128), False),
+    "flash_bwd_1x8x32768x128": lambda: _flash((1, 8, 32768, 128), True),
+    "flash_decode_d128_b64": lambda: _decode(128, False),
+    "flash_decode_d64_headpacked_b64": lambda: _decode(64, True),
+    "conv2d_epilogue_3x3_56x56x64_mb128": lambda: _conv(False),
+    "conv2d_bn_act_3x3_56x56x64_mb128": lambda: _conv(True),
+    "fc_epilogue_16384x512x2048": lambda: _fc(),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_described_v5e(chip_gate, case):
+    fn, avals, n_kernels = CASES[case]()
+    exe = chip_gate.compile_for_chip(fn, avals)
+    # the Pallas kernel is IN the module: a geometry or VMEM gate that
+    # rerouted to the XLA form would compile too, and prove nothing
+    assert exe.as_text().count(
+        'custom_call_target="tpu_custom_call"') == n_kernels
+    assert exe.memory_analysis().temp_size_in_bytes >= 0

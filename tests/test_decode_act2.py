@@ -568,7 +568,7 @@ def test_chunked_join_32k_slo():
 # bench legs + workload signatures
 # ---------------------------------------------------------------------------
 
-def test_bench_spec_leg_contract_and_self_draft():
+def test_bench_spec_leg_contract_and_self_draft(cpu_bench_peaks):
     import bench
 
     res = bench.bench_llm_decode_spec(
@@ -590,7 +590,7 @@ def test_bench_spec_leg_contract_and_self_draft():
     assert res_self["emitted_per_iter"] == 3.0   # k+1 every iter
 
 
-def test_bench_chunked_join_and_prefix_share_contract():
+def test_bench_chunked_join_and_prefix_share_contract(cpu_bench_peaks):
     import bench
 
     res = bench.bench_llm_decode_chunked_join(

@@ -41,7 +41,7 @@ def test_resnet_tiny_cifar_trains():
     assert losses[-1] < losses[0], losses
 
 
-def test_resnet_cifar10_trains_and_benches():
+def test_resnet_cifar10_trains_and_benches(cpu_bench_peaks):
     """resnet_cifar10 (reference tests/book/test_image_classification
     .py:28, the ResNet32 row of float16_benchmark.md:72-74): trains,
     and the bench leg's bf16+NHWC inference build runs on CPU."""

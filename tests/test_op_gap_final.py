@@ -146,7 +146,6 @@ def test_allreduce_broadcast_solo_and_mesh():
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.parallel import env as penv
-    from paddle_tpu.parallel.env import shard_map
 
     from jax.sharding import Mesh
 
@@ -159,8 +158,9 @@ def test_allreduce_broadcast_solo_and_mesh():
             )["Out"][None]
 
         vals = np.arange(8, dtype=np.float32).reshape(8, 1)
-        out = shard_map(red, mesh=mesh, in_specs=(P("dp"),),
-                        out_specs=P("dp"))(vals)
+        out = jax.shard_map(red, mesh=mesh, in_specs=(P("dp"),),
+                            out_specs=P("dp"),
+                            check_vma=False)(vals)
         np.testing.assert_allclose(np.asarray(out).ravel(),
                                    np.full(8, vals.sum()), rtol=1e-6)
 
@@ -169,8 +169,9 @@ def test_allreduce_broadcast_solo_and_mesh():
                 {"X": x[0]}, {"root": 3, "sync_mode": False}
             )["Out"][None]
 
-        out = shard_map(bc, mesh=mesh, in_specs=(P("dp"),),
-                        out_specs=P("dp"))(vals)
+        out = jax.shard_map(bc, mesh=mesh, in_specs=(P("dp"),),
+                            out_specs=P("dp"),
+                            check_vma=False)(vals)
         np.testing.assert_allclose(np.asarray(out).ravel(),
                                    np.full(8, 3.0), rtol=1e-6)
     finally:

@@ -436,7 +436,7 @@ def test_decode_submit_validation():
 # bench leg + load generator plumbing
 # ---------------------------------------------------------------------------
 
-def test_bench_llm_decode_row_contract():
+def test_bench_llm_decode_row_contract(cpu_bench_peaks):
     import bench
 
     res = bench.bench_llm_decode(streams=2, prefill_len=8,

@@ -486,7 +486,6 @@ class TestDGCSparseAllreduce:
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.parallel import dgc_allreduce
-        from paddle_tpu.parallel.env import shard_map
 
         mesh = self._mesh()
         rng = np.random.RandomState(0)
@@ -498,9 +497,10 @@ class TestDGCSparseAllreduce:
                                         momentum=0.9, axis="dp")
             return avg[None], u2[None], v2[None]
 
-        f = shard_map(step, mesh=mesh,
-                      in_specs=(P("dp"), P("dp"), P("dp")),
-                      out_specs=(P("dp"), P("dp"), P("dp")))
+        f = jax.shard_map(step, mesh=mesh,
+                          in_specs=(P("dp"), P("dp"), P("dp")),
+                          out_specs=(P("dp"), P("dp"), P("dp")),
+                          check_vma=False)
         avg, u2, v2 = f(grads, zeros, zeros)
         # sparsity 0 -> every entry sent -> exact dense mean on every rank
         expect = grads.mean(axis=0)
@@ -515,7 +515,6 @@ class TestDGCSparseAllreduce:
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.parallel import dgc_allreduce, dgc_compress_ratio
-        from paddle_tpu.parallel.env import shard_map
 
         mesh = self._mesh()
         rng = np.random.RandomState(1)
@@ -532,9 +531,10 @@ class TestDGCSparseAllreduce:
         from paddle_tpu.parallel import dgc_top_k_count
 
         k = dgc_top_k_count(100, sparsity)
-        f = shard_map(step, mesh=mesh,
-                      in_specs=(P("dp"), P("dp"), P("dp")),
-                      out_specs=(P("dp"), P("dp"), P("dp")))
+        f = jax.shard_map(step, mesh=mesh,
+                          in_specs=(P("dp"), P("dp"), P("dp")),
+                          out_specs=(P("dp"), P("dp"), P("dp")),
+                          check_vma=False)
         avg, u2, v2 = f(grads, zeros, zeros)
         avg, u2, v2 = (np.asarray(avg), np.asarray(u2), np.asarray(v2))
         # each worker sent exactly k entries: v2 keeps the rest

@@ -1,22 +1,19 @@
-"""Full-model TPU cross-lowering gate (tools/tpu_lowering_check.py).
+"""Full-model TPU compile gate (tools/tpu_lowering_check.py).
 
-The kernel-level legality tests in test_pallas_kernels.py check
-flash_attention in isolation; this checks the COMPLETE bench programs
-(IR build -> transpiles -> autodiff -> optimizer -> jit) cross-lowered
-for platform=tpu, i.e. exactly what bench.py will ask the chip to run.
-A fast subset runs here; tools/ci.sh runs the full sweep.
+The kernel-level tests (test_pallas_kernels.py, test_chip_compile.py)
+check the kernels in isolation; this checks the COMPLETE bench programs
+(IR build -> transpiles -> autodiff -> optimizer -> jit) compiled by
+the chip's own compiler for a described v5e, i.e. exactly what bench.py
+will ask the chip to run.  A subset runs here; tools/ci.sh runs the
+full sweep.
 """
-
-import pathlib
-import sys
 
 import pytest
 
-_ROOT = str(pathlib.Path(__file__).resolve().parents[1])
-
 
 @pytest.mark.parametrize("workload", [
-    "transformer_train",       # the one that crashed on first chip run
+    "transformer_train",       # the one that crashed on first chip run;
+    #                            also chip_smoke.py's train step
     "deepfm_train",
     "resnet50_infer_int8",     # int8 dot_general path
     # ISSUE 5: s8-in convs + fused requantize epilogues — the
@@ -37,12 +34,9 @@ _ROOT = str(pathlib.Path(__file__).resolve().parents[1])
     "serving_tp_sharded",
     "llm_decode_disagg",
 ])
-def test_bench_workload_lowers_for_tpu(workload):
-    if _ROOT not in sys.path:
-        sys.path.insert(0, _ROOT)
-    from tools.tpu_lowering_check import _workloads, check_workload
-
-    ok, detail, _ = check_workload(workload, _workloads()[workload])
+def test_bench_workload_lowers_for_tpu(chip_gate, workload):
+    ok, detail, _ = chip_gate.check_workload(
+        workload, chip_gate._workloads()[workload])
     assert ok, detail
 
 
@@ -53,8 +47,10 @@ def test_bench_workload_lowers_for_tpu(workload):
 def test_sequence_parallel_flash_lowers_for_tpu(which, causal):
     """The sp paths run the Pallas kernel on PER-CHUNK shapes inside
     shard_map — different block shapes than the single-chip bench, so
-    they get their own Mosaic legality check (AbstractMesh lets us
-    lower for an 8-device TPU mesh from the CPU)."""
+    they get their own Mosaic legality check.  The described v5e:2x2
+    has four devices and sp is eight here, so this one stays a
+    jax.export cross-lowering over an AbstractMesh: Mosaic's lowering
+    rules run, the TPU compiler does not."""
     import jax
     import jax.numpy as jnp
     from jax import export
@@ -64,11 +60,7 @@ def test_sequence_parallel_flash_lowers_for_tpu(which, causal):
     from paddle_tpu.parallel.ulysses import ulysses_attention
 
     fn = ring_attention if which == "ring" else ulysses_attention
-    try:
-        mesh = AbstractMesh((8,), ("sp",))
-    except TypeError:
-        # jax <= 0.4.x spells it AbstractMesh(((name, size), ...))
-        mesh = AbstractMesh((("sp", 8),))
+    mesh = AbstractMesh((8,), ("sp",))
     q = jnp.zeros((2, 4096, 8, 64), jnp.bfloat16)
 
     def step(q, k, v):

@@ -368,6 +368,11 @@ def _free_port():
 
 
 def driver(args):
+    """One process per simulated host, each FORCED onto the CPU's
+    virtual devices (PADDLE_TPU_PLATFORM=cpu below): a chip belongs to
+    one process, so N "hosts" on one machine would otherwise fight over
+    its chips.  This parent never touches JAX.  On a real cluster run
+    the worker on every host instead (module docstring)."""
     eps = [f"127.0.0.1:{_free_port()}" for _ in range(args.nnodes)]
     procs = []
     for rank in range(args.nnodes):

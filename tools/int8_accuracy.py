@@ -22,9 +22,7 @@ quantization-consistency bound, measured at the prediction level the
 reference tables use.  Random-init logits have SMALLER margins than a
 trained net's, so the bound here is conservative.
 
-The row is written to docs/int8_accuracy_rn32cifar.json;
-tools/bank_onchip.py carries it into the bench artifact next to the
-int8 latency row.  Asserts delta(int8, bf16) <= 0.5 pp (the reference
+The row is written to docs/int8_accuracy_rn32cifar.json.  Asserts delta(int8, bf16) <= 0.5 pp (the reference
 tables' bar) unless --no-assert.
 
 Usage: python tools/int8_accuracy.py [--n 256] [--batch 64]

@@ -1,9 +1,9 @@
 """Perf-regression sentinel: machine-gate fresh bench/serving rows
 against the banked baselines (ISSUE 12).
 
-The repo banks performance rows (docs/bench_rows_latest.json,
-BENCH_*.json, and the CPU-harness serving baselines) but until now
-nothing DIFFED a fresh run against them — a regression only surfaced
+The repo keeps performance rows (bench rows files and the CPU-harness
+serving baselines) but until now nothing DIFFED a fresh run against
+them — a regression only surfaced
 when a human read two JSON files.  This tool compares a fresh
 one-JSON-line row set against a baseline, keyed by workload identity
 (bench rows: bench.py's ``_workload_sig``; serving rows: the
@@ -23,11 +23,10 @@ Modes:
                      new one with --update-baseline).  The ci.sh step
                      gates the CPU-harness rows: inter-token p50 and
                      time_to_first_batch warm/cold.
-    --mode bench     fresh = bench.py stdout line (or its rows_file);
-                     baseline = docs/bench_rows_latest.json /
-                     BENCH_*.json.  Rows pair by _workload_sig and
-                     only same-device rows compare (a degraded CPU
-                     row never gates an on-chip number).
+    --mode bench     fresh = bench.py's rows file
+                     (chiprun_out/bench_rows.json); baseline = another
+                     one, named with --baseline.  Rows pair by
+                     _workload_sig and only same-device rows compare.
 
 stdout contract: EXACTLY ONE JSON line —
 
@@ -204,9 +203,9 @@ def main(argv=None):
     ap.add_argument("--fresh", required=True,
                     help="comma-separated files of one-JSON-line rows")
     ap.add_argument("--baseline", default=None,
-                    help="baseline file (default: docs/"
-                         "perf_baseline_cpu.json for serving, docs/"
-                         "bench_rows_latest.json for bench)")
+                    help="baseline file (default for serving: docs/"
+                         "perf_baseline_cpu.json; bench mode has no "
+                         "default)")
     ap.add_argument("--band", type=float, default=DEFAULT_BAND,
                     help="default noise band (ratio, default 4.0)")
     ap.add_argument("--update-baseline", default=None,
@@ -227,9 +226,11 @@ def main(argv=None):
         gated = None if args.all_metrics else SERVING_GATED_METRICS
     else:
         fresh = bench_rows(fresh_recs)
-        default_baseline = os.path.join(REPO, "docs",
-                                        "bench_rows_latest.json")
+        default_baseline = None
         gated = None
+        if not (args.baseline or args.update_baseline):
+            ap.error("--mode bench needs --baseline: no bench rows "
+                     "file is committed")
 
     if args.update_baseline:
         doc = {"mode": args.mode, "band": args.band, "rows": fresh}
@@ -255,7 +256,7 @@ def main(argv=None):
             args.band = float(doc["band"]) \
                 if args.band == DEFAULT_BAND else args.band
     else:
-        # a raw bench rows file (docs/bench_rows_latest.json shape)
+        # a raw bench rows file (bench.py's chiprun_out/ shape)
         baseline = bench_rows([doc]) if args.mode == "bench" \
             else serving_rows([doc])
 

@@ -36,9 +36,11 @@ Modes:
 Cold-start metrics (ROADMAP item 5): every mode's JSON line carries
 ``time_to_first_batch_s`` (server start -> first completed request,
 measured on a cold probe BEFORE any warmup) and the batcher's
-bucket-cache ``bucket_cold``/``bucket_warm`` hit counts — run with
-PADDLE_TPU_COMPILE_CACHE_DIR set to see the persistent compilation
-cache turn the cold number warm across process restarts.  The
+bucket-cache ``bucket_cold``/``bucket_warm`` hit counts — run twice
+over one persistent compilation cache
+(``paddle_tpu.compile_cache_dir()``, which the servers' ``start()``
+turns on) to see it turn the cold number warm across process
+restarts.  The
 fixed/overload modes additionally bank the warm-vs-cold PAIR:
 ``time_to_first_batch_cold_s`` (no prewarm) next to
 ``time_to_first_batch_warm_s`` (a second server with
@@ -145,8 +147,8 @@ def probe_first_batch(srv, deadline_s=60.0):
     """Cold-start metric (ROADMAP item 5): wall seconds from now (the
     server is up, NOTHING compiled yet) to the first completed
     request — dominated by the first bucket compile unless the
-    persistent compilation cache (PADDLE_TPU_COMPILE_CACHE_DIR) served
-    it from disk."""
+    persistent compilation cache (paddle_tpu.compile_cache_dir())
+    served it from disk."""
     import numpy as np
 
     t0 = time.monotonic()
@@ -640,9 +642,9 @@ def main(argv=None):
             # cold-start metric FIRST (nothing compiled yet,
             # prewarm=False so the env can't warm it behind our
             # back), then the usual full warmup so the measured run
-            # never pays a compile — with PADDLE_TPU_COMPILE_CACHE_DIR
-            # set, this number is the warm-disk replay of the bucket
-            # compile
+            # never pays a compile — on a second run over the same
+            # persistent cache, this number is the warm-disk replay
+            # of the bucket compile
             ttfb = probe_first_batch(srv)
             warm_server(srv)
             cap_qps = None
@@ -666,8 +668,8 @@ def main(argv=None):
             srv.stop()
         # the WARM half of the cold-start pair (ROADMAP item 5): a
         # SECOND server over the same model with prewarm=True — every
-        # (replica, bucket) entry compiled (or replayed from
-        # PADDLE_TPU_COMPILE_CACHE_DIR) at replica start — then the
+        # (replica, bucket) entry compiled (or replayed from the
+        # persistent compile cache) at replica start — then the
         # same first-request probe.  warm << cold is the banked
         # evidence that replica start absorbs the bucket compiles.
         srv2 = make_server(mdir, replicas=args.replicas,

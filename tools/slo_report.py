@@ -9,9 +9,8 @@ Input: one or more serving_load one-JSON-line outputs —
     python tools/slo_report.py --run --mode overload2x --seconds 4
 
 ``--run`` invokes tools/serving_load.py as a subprocess (args after
---run pass through) and reports on its line — the chip-chaser task
-shape (`serving_qps_slo` in tools/chip_chaser.py; keyed by
-tools/bank_onchip.py).
+--run pass through) and reports on its line.  This process never
+touches JAX, so the child may hold the chip.
 
 ``--fleet <path>`` (ISSUE 12) additionally ingests a collector fleet
 snapshot (observability/collector.py ``snapshot()`` / ``dump()``
