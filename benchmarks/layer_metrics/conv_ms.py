@@ -1,0 +1,12 @@
+"""Layer: kernels (XLA convolution and matmul fusions).  Device time per
+step on the first device of convolution instructions and of fusions
+whose computation holds one, ms (to the TPU compiler a matmul is a
+convolution too).  Source: the device trace, with the compiled step's
+HLO text to say which fusions hold a convolution.
+"""
+
+
+def read(m):
+    if m["trace"] is None:
+        return None
+    return m["tr"].per_step_ms(m["trace"], "category_ns", "convolution")
