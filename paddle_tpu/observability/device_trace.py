@@ -21,12 +21,13 @@ step/compile wraps its work in ``annotate(kernel)``:
     chrome export truncates event names at the last colon and would
     eat the id;
   - while TRACING INTO a jit (kernel called from a larger compiled
-    graph): a ``jax.named_scope("pt_<kernel>")`` instead — the scope
-    rides the HLO metadata into the compiled program once, so device
-    op names stay attributable per-kernel while the per-request id
-    comes from the surrounding runtime ``executor.step`` annotation
-    (a trace id frozen at trace time would be a lie: the compile is
-    cached across requests).
+    graph): nothing.  Every ``pl.pallas_call`` in ``ops/`` carries a
+    fixed ``name="pt_<kernel>"`` that rides the HLO metadata into the
+    compiled program whatever the flag says, so the device op is
+    named always and the compiled module does not depend on
+    ``tracing``; the per-request id comes from the surrounding
+    runtime ``executor.step`` annotation (a trace id frozen at trace
+    time would be a lie: the compile is cached across requests).
 
 **DeviceTraceSession** — wraps ``jax.profiler.start_trace`` /
 ``stop_trace``, parses the emitted trace-event JSON
@@ -144,10 +145,10 @@ def annotate(kernel):
     import jax
 
     if not jax.core.trace_ctx.is_top_level():
-        # tracing INTO a jit: the kernel identity rides the HLO
-        # metadata (stable across requests); never bake a trace id
-        # into a cached compile
-        return jax.named_scope("pt_" + _scope_safe(kernel))
+        # tracing INTO a jit: the pallas_call's own name= carries the
+        # kernel identity into the HLO, flag or no flag; never bake a
+        # trace id (or the flag) into a cached compile
+        return _NULL
     ctx = _tracing.current()
     tid = ctx[0] if ctx is not None else None
     if tid is not None and not t._verdict(tid):
@@ -164,11 +165,6 @@ def session_annotation(kernel, trace_id=None):
 
     return jax.profiler.TraceAnnotation(annotation_name(kernel,
                                                         trace_id))
-
-
-def _scope_safe(name):
-    return "".join(c if c.isalnum() or c == "_" else "_"
-                   for c in name)
 
 
 def _union_us(intervals):

@@ -69,7 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
 from paddle_tpu.observability import tracing as _obs_trace
-from paddle_tpu.ops.pallas_kernels import _count_impl
+from paddle_tpu.ops.pallas_kernels import _count_impl, named_pallas_call
 
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _FC_BLOCK_M = 256
@@ -440,8 +440,9 @@ def _fc_ep_pallas(x2, w2, bias, residual, act, approximate,
     kernel = functools.partial(
         _fc_ep_kernel, act=act, approximate=approximate,
         has_bias=bias is not None, has_res=residual is not None)
-    return pl.pallas_call(
+    return named_pallas_call(
         kernel,
+        name="pt_fc_ep",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni: (mi, ni)),

@@ -49,7 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
 from paddle_tpu.observability import tracing as _obs_trace
-from paddle_tpu.ops.pallas_kernels import _count_impl
+from paddle_tpu.ops.pallas_kernels import _count_impl, named_pallas_call
 
 # VMEM budget for the compiled kernel: one image block + filter tile +
 # accumulator + residual tile, doubled for Pallas' input double
@@ -226,8 +226,9 @@ def _conv_ep_pallas(x, w, bias, residual, strides, padding, act,
         _conv_ep_kernel, kh=kh, kw=kw, sh=sh, sw=sw, oh=oh, ow=ow,
         act=act, has_bias=bias is not None,
         has_res=residual is not None)
-    return pl.pallas_call(
+    return named_pallas_call(
         kernel,
+        name="pt_conv_ep",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, oh, ow, bco),
@@ -410,8 +411,9 @@ def _conv_stats_pallas(x, w, bias, strides, padding, interpret=False):
     # stat arrays ride as [N, 8, Cout] (sublane-replicated x8 — see the
     # kernel comment); the finalization reads row 0
     stat_spec = pl.BlockSpec((1, 8, bco), lambda ni, co: (ni, 0, co))
-    y, s1, s2 = pl.pallas_call(
+    y, s1, s2 = named_pallas_call(
         kernel,
+        name="pt_conv_stats",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -533,8 +535,9 @@ def _bn_apply_pallas(y, mean, rstd, scale, shift, residual, act,
             dimension_semantics=("parallel", "parallel", "parallel"))
     kernel = functools.partial(_bn_apply_kernel, act=act,
                                has_res=residual is not None)
-    return pl.pallas_call(
+    return named_pallas_call(
         kernel,
+        name="pt_bn_apply",
         grid=grid,
         in_specs=in_specs,
         out_specs=row_spec,
@@ -596,7 +599,8 @@ def conv2d_epilogue(x, w, bias=None, residual=None, *, strides=(1, 1),
     padding = _norm_padding(paddings)
     if _obs_trace._tracer is not None:
         # device-time attribution (ISSUE 10): annotation at runtime,
-        # named_scope inside a jit trace — one module-global check off
+        # nothing inside a jit trace (the pallas_call's name= names
+        # the kernel there) — one module-global check off
         with _obs_device.annotate("conv2d_epilogue"):
             return _conv_ep(x, w, bias, residual, strides, padding,
                             act or "", impl)

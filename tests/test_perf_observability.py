@@ -308,8 +308,9 @@ def test_device_trace_session_joins_host_span(tracer, tmp_path):
 def test_kernel_entry_annotations_unsampled_and_off_paths(tracer):
     """Kernel entries run unchanged with tracing off, and an UNSAMPLED
     trace emits no runtime annotation (head sampling reaches the
-    device plane); inside a jit trace the annotate site returns a
-    named_scope, never a TraceAnnotation with a frozen id."""
+    device plane); inside a jit trace the annotate site returns the
+    null context (the pallas_call's own name= names the kernel), never
+    a TraceAnnotation with a frozen id."""
     import jax
     import jax.numpy as jnp
 
