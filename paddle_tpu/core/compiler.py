@@ -34,6 +34,7 @@ from paddle_tpu.observability import collector as _obs_collector
 from paddle_tpu.observability import device_trace as _obs_device
 from paddle_tpu.observability import flight_recorder as _obs_flight
 from paddle_tpu.observability import metrics as _obs_metrics
+from paddle_tpu.observability import step_record as _obs_steps
 from paddle_tpu.observability import tracing as _obs_trace
 
 # executor observability (ISSUE 9): per-step wall time + compile
@@ -795,6 +796,18 @@ class CompiledProgram:
         return out_feeds, out_state
 
     def _run(self, executor, feed, fetch_list, scope, return_numpy):
+        # the step record (observability/step_record.py): one `run`
+        # record a call, always on, appended also when the step raises
+        rec = _obs_steps.Record("run", program=id(self),
+                                first_call=False, fetched=return_numpy)
+        rec.stamp("enter", phase="executor.prepare")
+        try:
+            return self._run_phases(rec, feed, fetch_list, scope,
+                                    return_numpy)
+        finally:
+            rec.done()
+
+    def _run_phases(self, rec, feed, fetch_list, scope, return_numpy):
         import jax
         import jax.numpy as jnp
 
@@ -824,6 +837,7 @@ class CompiledProgram:
                 jax.process_count() > 1 else jnp.asarray(arr)
         fetch_names = [f if isinstance(f, str) else f.name
                        for f in fetch_list]
+        rec.stamp("feeds")
         # persistable state from scope
         state = {}
         for n in self._persistable_names:
@@ -833,6 +847,7 @@ class CompiledProgram:
                     f"CompiledProgram: persistable '{n}' is uninitialized —"
                     " run the startup program first")
             state[n] = var.get()
+        rec.stamp("state")
         multiproc = self._mesh is not None and jax.process_count() > 1
         feed_shardings = None
         if multiproc:
@@ -849,7 +864,9 @@ class CompiledProgram:
             _mesh_fingerprint(self._mesh),
         )
         fn = self._cache.get(key)
+        rec.stamp("key")
         if fn is None:
+            rec.fields["first_call"] = True
             feed_specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                           for k, v in feeds.items()}
             state_specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
@@ -876,6 +893,9 @@ class CompiledProgram:
                                     fetch_names, state_specs,
                                     feed_shardings=feed_shardings)
             self._cache[key] = fn
+            rec.stamp("built")
+        else:
+            rec.fields["built"] = rec.fields["key"]
         if self._mesh is not None and not multiproc:
             # conform state arrays to the declared in_shardings BEFORE
             # the call, committed or not.  Two reasons.  jit refuses a
@@ -906,34 +926,40 @@ class CompiledProgram:
                         v.sharding.mesh == sh.mesh and
                         sh.is_equivalent_to(v.sharding, v.ndim)):
                     state[k] = jax.device_put(v, sh)
-        import time as _time
-
-        t0 = _time.perf_counter()
+        rec.stamp("conformed", phase="executor.dispatch")
         if _obs_trace._tracer is not None:
             with _obs_trace._tracer.span("executor.step"), \
                     _obs_device.annotate("executor.step"):
                 new_state, fetches = fn(state, feeds)
         else:
             new_state, fetches = fn(state, feeds)
-        _M_STEP_SECONDS.observe(_time.perf_counter() - t0)
+        rec.stamp("dispatched", phase="executor.commit")
+        # host enqueue time, not a step time: the device has not
+        # finished when fn returns
+        _M_STEP_SECONDS.observe(
+            (rec.fields["dispatched"] - rec.fields["conformed"]) * 1e-9)
         # trainer fleet push (ISSUE 12): a step boundary is the
         # trainer's natural push moment — rate-limited inside, runs on
         # the pusher thread, one None/memo check when off
         _obs_collector.maybe_step_push()
         for k, v in new_state.items():
             scope.var(k).set(v)
-        if return_numpy:
-            out = []
-            for v in fetches:
-                if isinstance(v, jax.Array) and \
-                        not v.is_fully_addressable and \
-                        not v.is_fully_replicated:
-                    # sharded output spanning other processes: gather
-                    # the global value (reference: fetch implies a
-                    # device->host gather in multi-trainer mode)
-                    from jax.experimental import multihost_utils
+        if not return_numpy:
+            rec.stamp("committed", phase=None)
+            rec.fields["returned"] = rec.fields["committed"]
+            return list(fetches)
+        rec.stamp("committed", phase="executor.fetch")
+        out = []
+        for v in fetches:
+            if isinstance(v, jax.Array) and \
+                    not v.is_fully_addressable and \
+                    not v.is_fully_replicated:
+                # sharded output spanning other processes: gather
+                # the global value (reference: fetch implies a
+                # device->host gather in multi-trainer mode)
+                from jax.experimental import multihost_utils
 
-                    v = multihost_utils.process_allgather(v, tiled=True)
-                out.append(np.asarray(v))
-            return out
-        return list(fetches)
+                v = multihost_utils.process_allgather(v, tiled=True)
+            out.append(np.asarray(v))
+        rec.stamp("returned", phase=None)
+        return out

@@ -32,6 +32,11 @@ stack needs all three (docs/OBSERVABILITY.md).
                       one-store trace assembly, staleness marking,
                       and the fleet SLO roll-up
 
+  step_record.py      the always-on step record: a bounded ring of
+                      per-call stamps from CompiledProgram._run and
+                      DeviceFeeder (where exe.run's host time goes),
+                      the same phases as profiler annotations
+
 ``paddle_tpu/profiler.py`` (the Fluid-shaped start_profiler/
 stop_profiler/RecordEvent surface) is a thin shim over tracing.py.
 """
@@ -41,6 +46,7 @@ from paddle_tpu.observability import device_trace
 from paddle_tpu.observability import flight_recorder
 from paddle_tpu.observability import metrics
 from paddle_tpu.observability import slo
+from paddle_tpu.observability import step_record
 from paddle_tpu.observability import tracing
 from paddle_tpu.observability.collector import (CollectorPusher,
                                                 CollectorServer)
@@ -66,5 +72,5 @@ __all__ = [
     "SLOMonitor", "Span", "Tracer", "collector", "device_trace",
     "flight_recorder", "maybe_tracer", "metrics",
     "metrics_port_from_env", "parse_prometheus_text", "registry",
-    "slo", "start_tracing", "stop_tracing", "tracing",
+    "slo", "start_tracing", "step_record", "stop_tracing", "tracing",
 ]

@@ -9,9 +9,8 @@ Two halves:
 ``conv2d_bn_act``, paged-KV ``append``) and every ``CompiledProgram``
 step/compile wraps its work in ``annotate(kernel)``:
 
-  - tracing flag OFF: the site is ONE module-global None check (the
-    PR-9 disabled-cost contract) — callers guard with
-    ``if tracing._tracer is not None`` exactly like span sites;
+  - tracing flag OFF: the site is ONE module-global None check and
+    the null context (the PR-9 disabled-cost contract);
   - at RUNTIME (``jax.core.trace_ctx.is_top_level()``): a
     ``jax.profiler.TraceAnnotation`` whose name carries the kernel and
     the ACTIVE trace id under the grammar ``pt#<kernel>#<trace_id>``
@@ -129,16 +128,18 @@ _NULL = _NullCtx()
 
 
 def annotate(kernel):
-    """The kernel-entry annotation site.  Callers keep the PR-9
-    one-conditional shape::
+    """The kernel-entry annotation site::
 
-        if tracing._tracer is not None:
-            with device_trace.annotate("flash_attention"):
-                return _flash(...)
-        return _flash(...)
+        with device_trace.annotate("flash_attention"):
+            return _flash(...)
 
-    (calling it with tracing off also just returns a null context —
-    the guard is about the disabled COST, not correctness)."""
+    With tracing off this is one module-global check and the null
+    context.  The Pallas kernel entries call it unguarded, on ONE call
+    line: source locations ride the Mosaic payload, and a second call
+    line under ``if tracing._tracer is not None`` would make the
+    compiled module and its cache key depend on the flag.  Sites that
+    trace nothing into a jit (executor.step, paged_kv_append) may keep
+    the guard."""
     t = _tracing._tracer
     if t is None:
         return _NULL

@@ -68,7 +68,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
-from paddle_tpu.observability import tracing as _obs_trace
 from paddle_tpu.ops.pallas_kernels import _count_impl, named_pallas_call
 
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
@@ -515,12 +514,11 @@ def fc_epilogue(x, w, bias=None, residual=None, *, act=None,
     bit."""
     if impl is None:
         impl = "pallas" if _on_tpu() else "xla"
-    if _obs_trace._tracer is not None:
-        with _obs_device.annotate("fc_epilogue"):
-            return _fc_ep(x, w, bias, residual, act or "",
-                          bool(approximate), impl)
-    return _fc_ep(x, w, bias, residual, act or "", bool(approximate),
-                  impl)
+    # one call line, flag on or off (see ops/pallas_kernels.py
+    # flash_attention): the compiled module does not depend on the flag
+    with _obs_device.annotate("fc_epilogue"):
+        return _fc_ep(x, w, bias, residual, act or "",
+                      bool(approximate), impl)
 
 
 def _on_tpu():

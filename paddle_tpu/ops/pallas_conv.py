@@ -48,7 +48,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
-from paddle_tpu.observability import tracing as _obs_trace
 from paddle_tpu.ops.pallas_kernels import _count_impl, named_pallas_call
 
 # VMEM budget for the compiled kernel: one image block + filter tile +
@@ -597,15 +596,12 @@ def conv2d_epilogue(x, w, bias=None, residual=None, *, strides=(1, 1),
         impl = "pallas" if _on_tpu() else "xla"
     strides = tuple(int(s) for s in strides)
     padding = _norm_padding(paddings)
-    if _obs_trace._tracer is not None:
-        # device-time attribution (ISSUE 10): annotation at runtime,
-        # nothing inside a jit trace (the pallas_call's name= names
-        # the kernel there) — one module-global check off
-        with _obs_device.annotate("conv2d_epilogue"):
-            return _conv_ep(x, w, bias, residual, strides, padding,
-                            act or "", impl)
-    return _conv_ep(x, w, bias, residual, strides, padding,
-                    act or "", impl)
+    # device-time attribution (ISSUE 10): a runtime annotation under
+    # the `tracing` flag, else the null context; one call line either
+    # way (see ops/pallas_kernels.py flash_attention)
+    with _obs_device.annotate("conv2d_epilogue"):
+        return _conv_ep(x, w, bias, residual, strides, padding,
+                        act or "", impl)
 
 
 def _conv_bn_unfused(x, w, bias, scale, shift, residual, strides,
@@ -740,13 +736,9 @@ def conv2d_bn_act(x, w, scale, shift, bias=None, residual=None, *,
         impl = "pallas" if _on_tpu() else "xla"
     strides = tuple(int(s) for s in strides)
     padding = _norm_padding(paddings)
-    if _obs_trace._tracer is not None:
-        with _obs_device.annotate("conv2d_bn_act"):
-            return _conv_bn_act(x, w, bias, scale, shift, residual,
-                                strides, padding, act or "",
-                                float(epsilon), impl)
-    return _conv_bn_act(x, w, bias, scale, shift, residual, strides,
-                        padding, act or "", float(epsilon), impl)
+    with _obs_device.annotate("conv2d_bn_act"):
+        return _conv_bn_act(x, w, bias, scale, shift, residual, strides,
+                            padding, act or "", float(epsilon), impl)
 
 
 def _on_tpu():

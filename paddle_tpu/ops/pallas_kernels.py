@@ -63,7 +63,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
 from paddle_tpu.observability import metrics as _obs_metrics
-from paddle_tpu.observability import tracing as _obs_trace
 
 # Which implementation each kernel entry ended on, counted where the
 # choice is final: after the platform default AND after the geometry /
@@ -796,16 +795,14 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     block_k = block_k or _default_block(k.shape[-2])
     packed_stats, head_pack = _resolve_variants(packed_stats, head_pack)
     _count_impl("flash_attention", impl)
-    if _obs_trace._tracer is not None:
-        # device-time attribution (ISSUE 10): annotate the entry with
-        # the active trace id at runtime (nothing inside a jit trace:
-        # the pallas_call's name= names the kernel there) — one
-        # module-global check when tracing is off
-        with _obs_device.annotate("flash_attention"):
-            return _flash(q, k, v, causal, float(scale), block_q,
-                          block_k, impl, packed_stats, head_pack)
-    return _flash(q, k, v, causal, float(scale), block_q, block_k, impl,
-                  packed_stats, head_pack)
+    # device-time attribution (ISSUE 10): at runtime with the `tracing`
+    # flag on, an annotation carrying the active trace id; otherwise
+    # the null context.  ONE call line either way: source locations
+    # ride the Mosaic payload, so two call lines would make the
+    # compiled module (and its cache key) depend on the flag
+    with _obs_device.annotate("flash_attention"):
+        return _flash(q, k, v, causal, float(scale), block_q, block_k,
+                      impl, packed_stats, head_pack)
 
 
 def _on_tpu():
@@ -1290,15 +1287,10 @@ def flash_decode(q, k_pages, v_pages, block_tables, seq_lens, *,
             q, k_pages, hpb, vmem_budget_bytes, q_len):
         impl = "xla"   # documented fallback: gather + reference replay
     _count_impl("flash_decode", impl)
-    if _obs_trace._tracer is not None:
-        with _obs_device.annotate("flash_decode"):
-            return _flash_decode_entry(q, k_pages, v_pages,
-                                       block_tables, seq_lens, scale,
-                                       impl, hpb, int8kv, kv_scales,
-                                       q_len)
-    return _flash_decode_entry(q, k_pages, v_pages, block_tables,
-                               seq_lens, scale, impl, hpb, int8kv,
-                               kv_scales, q_len)
+    with _obs_device.annotate("flash_decode"):   # see flash_attention
+        return _flash_decode_entry(q, k_pages, v_pages, block_tables,
+                                   seq_lens, scale, impl, hpb, int8kv,
+                                   kv_scales, q_len)
 
 
 def _flash_decode_entry(q, k_pages, v_pages, block_tables, seq_lens,

@@ -19,6 +19,8 @@ import random as _random
 from queue import Queue
 from threading import Thread
 
+from paddle_tpu.observability import step_record as _obs_steps
+
 __all__ = [
     "batch", "shuffle", "buffered", "cache", "chain", "compose", "firstn",
     "map_readers", "xmap_readers", "PyReader", "DataLoader",
@@ -220,13 +222,27 @@ class DeviceFeeder:
 
             try:
                 while True:
+                    t_get = _obs_steps.now()
                     item = self._host_q.get()
                     if item is DeviceFeeder._END or self._stopped:
                         break
-                    if self._to_device:
-                        item = {k: jax.device_put(v)
-                                for k, v in item.items()}
-                    self._dev_q.put(item)
+                    # one `put` record a batch (observability/
+                    # step_record.py): when this thread issued the
+                    # copies, against when the consumer was in exe.run
+                    rec = _obs_steps.Record("put", bytes=sum(
+                        getattr(v, "nbytes", 0) for v in item.values()))
+                    rec.stamp("start", phase="feeder.put")
+                    try:
+                        if self._to_device:
+                            item = {k: jax.device_put(v)
+                                    for k, v in item.items()}
+                        rec.stamp("end", phase=None)
+                        self._dev_q.put(item)
+                        f = rec.fields
+                        f["host_wait"] = f["start"] - t_get
+                        f["dev_wait"] = _obs_steps.now() - f["end"]
+                    finally:
+                        rec.done()
             except BaseException as e:
                 self._err.append(e)
             finally:
@@ -241,7 +257,11 @@ class DeviceFeeder:
         return self
 
     def __next__(self):
+        rec = _obs_steps.Record("next")
+        rec.stamp("start")
         item = self._dev_q.get()
+        rec.stamp("end")
+        rec.done()
         if item is DeviceFeeder._END:
             # stay drained: re-park the sentinel so another next() raises
             # again instead of blocking on the empty queue forever
