@@ -2,6 +2,8 @@
 stable device-side kernel names (ISSUE 24).  No test here asserts a
 duration: stamps are checked for order only."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -263,7 +265,7 @@ def test_kernel_name_is_innermost_in_forward_and_backward():
     f, args = _flash_grad()
     text = jax.jit(f).lower(*args).as_text(debug_info=True)
     for name in ("pt_flash_fwd", "pt_flash_bwd_dq", "pt_flash_bwd_dkv"):
-        assert "(pt)/%s/pallas_call\"" % name in text, name
+        assert re.search(r'\(pt\)+/%s/pallas_call"' % name, text), name
         assert "(%s)" % name not in text
 
 
