@@ -11,14 +11,14 @@ drops its oldest entry when full.
 Timestamps are ``time.perf_counter_ns()`` — the clock
 ``time.perf_counter()`` reads, so they join a caller's own per-step
 times.  Three kinds of record (``"kind"``), every one with ``thread``
-(``threading.get_ident()``):
+(``threading.get_ident()``), ``seq`` (process-wide order of creation)
+and ``done`` (when it was closed and appended):
 
 ``run``  one per ``CompiledProgram._run`` call, appended when the call
     ends (also when it raises: the record then holds the stamps it
-    reached).  ``seq`` (process-wide order of entry), ``program``
-    (``id`` of the CompiledProgram), ``first_call`` (the call missed
-    the program's jit cache), ``fetched`` (``return_numpy``) and the
-    stamps, in order:
+    reached).  ``program`` (``id`` of the CompiledProgram),
+    ``first_call`` (the call missed the program's jit cache),
+    ``fetched`` (``return_numpy``) and the stamps, in order:
 
       enter       _run entered
       feeds       feeds coerced to arrays of the declared dtype
@@ -35,6 +35,9 @@ times.  Three kinds of record (``"kind"``), every one with ``thread``
       committed   collector push and scope write-back done
       returned    fetches are numpy, i.e. the device finished; equals
                   ``committed`` when ``fetched`` is false
+      done        the record was closed: ``_run``'s inner frame is gone,
+                  and with it the previous step's state arrays (their
+                  buffers were donated to the step)
 
 ``put``  one per batch in ``DeviceFeeder``'s transfer thread:
     ``host_wait`` (ns blocked waiting for the producer), ``start`` /
@@ -105,6 +108,7 @@ class Record:
         if self._open is not None:
             self._open.__exit__(None, None, None)
             self._open = None
+        self.fields["done"] = now()
         _ring.append(self.fields)
 
 

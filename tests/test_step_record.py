@@ -18,7 +18,7 @@ from paddle_tpu.observability import (device_trace, metrics, step_record,
 from paddle_tpu.reader import DeviceFeeder
 
 RUN_STAMPS = ["enter", "feeds", "state", "key", "built", "conformed",
-              "dispatched", "committed", "returned"]
+              "dispatched", "committed", "returned", "done"]
 
 
 @pytest.fixture(autouse=True)
@@ -71,6 +71,7 @@ def test_raising_step_still_leaves_its_record():
     (rec,) = step_record.records("run")
     assert rec["enter"] <= rec["feeds"]
     assert "state" not in rec and "returned" not in rec
+    assert rec["feeds"] <= rec["done"]
     assert rec["first_call"] is False and rec["fetched"] is True
 
 
