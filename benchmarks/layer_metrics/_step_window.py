@@ -4,8 +4,8 @@ first_call_s).  A reader runs in the process that ran the cell, so it
 reads the ring itself (paddle_tpu/observability/step_record.py:
 `run` records of CompiledProgram._run, `put` records of DeviceFeeder's
 transfer thread, all in time.perf_counter_ns()).  A program from
-before the record has no such module: everything here then gives None,
-and the metric is left out of the line.
+before the record has no such module: everything here is then empty,
+the reader gives None and the metric is left out of the line.
 
 The measured window's steps, without the harness's help: of the step
 program's `run` records (the program with the most records) that did
@@ -18,51 +18,51 @@ before).  Load with runpy.run_path, as the readers do.
 import collections
 
 
-def _step_runs():
+def _records(kind):
     try:
         from paddle_tpu.observability import step_record
     except ImportError:
-        return None, None
-    runs = step_record.records("run")
+        return []
+    return step_record.records(kind)
+
+
+def _step_runs():
+    runs = _records("run")
     if not runs:
-        return None, None
+        return []
     program = collections.Counter(
         r["program"] for r in runs).most_common(1)[0][0]
-    return step_record, [r for r in runs if r["program"] == program]
+    return [r for r in runs if r["program"] == program]
 
 
 def window(m):
-    """The window's `run` records, oldest first, or None."""
-    _, runs = _step_runs()
-    if not runs or not m.get("attempted"):
-        return None
-    steps = [r for r in runs if not r["first_call"] and r["fetched"]]
-    return steps[-m["attempted"]:] or None
+    """The window's `run` records, oldest first."""
+    steps = [r for r in _step_runs()
+             if not r["first_call"] and r["fetched"]]
+    return steps[-m["attempted"]:] if m.get("attempted") else []
 
 
 def first_call():
     """The step program's record of the call that built its step."""
-    _, runs = _step_runs()
-    return next((r for r in runs or [] if r["first_call"]), None)
+    return next((r for r in _step_runs() if r["first_call"]), None)
 
 
 def puts(m):
-    """(window's run records, the `put` records that began inside the
-    window), or (None, None)."""
+    """(the window's run records, the `put` records that began inside
+    the window)."""
     steps = window(m)
     if not steps:
-        return None, None
-    step_record, _ = _step_runs()
+        return [], []
     t0 = steps[0]["enter"]
     t1 = max(r.get("returned", r["enter"]) for r in steps)
-    return steps, [p for p in step_record.records("put")
+    return steps, [p for p in _records("put")
                    if "end" in p and t0 <= p["start"] <= t1]
 
 
-def median_ms(steps, later, earlier):
+def median_ms(records, later, earlier):
     """Median over the records that hold both stamps of
     later - earlier, ms; None when none does."""
-    d = sorted(r[later] - r[earlier] for r in steps or []
+    d = sorted(r[later] - r[earlier] for r in records
                if later in r and earlier in r)
     if not d:
         return None

@@ -15,7 +15,7 @@ _sw = runpy.run_path(os.path.join(os.path.dirname(__file__),
 
 def read(m):
     steps, puts = _sw["puts"](m)
-    steps = [r for r in steps or [] if "dispatched" in r]
+    steps = [r for r in steps if "dispatched" in r]
     if not steps:
         return None
     overlap = sum(max(0, min(p["end"], r["dispatched"])

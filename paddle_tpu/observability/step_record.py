@@ -46,7 +46,7 @@ and ``done`` (when it was closed and appended):
     ``dev_wait`` (ns blocked because the consumer is behind),
     ``bytes``.
 
-``next``  one per ``DeviceFeeder.__next__``: ``start`` / ``end`` around
+``next``  one per ``DeviceFeeder.__next__``: ``start`` / ``done`` around
     the wait for a device-resident batch.
 
 The same phases are on the profiler's clock: ``Record.stamp(...,

@@ -260,7 +260,6 @@ class DeviceFeeder:
         rec = _obs_steps.Record("next")
         rec.stamp("start")
         item = self._dev_q.get()
-        rec.stamp("end")
         rec.done()
         if item is DeviceFeeder._END:
             # stay drained: re-park the sentinel so another next() raises

@@ -112,7 +112,7 @@ def test_feeder_leaves_one_put_record_a_batch():
         assert p["thread"] not in consumer     # the transfer thread
     nexts = step_record.records("next")
     assert len(nexts) == n + 1                 # the last one met END
-    assert all(r["start"] <= r["end"] for r in nexts)
+    assert all(r["start"] <= r["done"] for r in nexts)
 
 
 def test_ring_is_bounded_and_reads_are_copies():
