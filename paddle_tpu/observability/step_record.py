@@ -65,10 +65,10 @@ import time
 
 from paddle_tpu.observability import device_trace as _device
 
-__all__ = ["MAXLEN", "Record", "records", "clear", "now"]
+__all__ = ["MAXLEN", "Record", "records", "clear"]
 
 MAXLEN = 4096
-now = time.perf_counter_ns
+_now = time.perf_counter_ns
 
 _ring = collections.deque(maxlen=MAXLEN)
 _seq = itertools.count()
@@ -94,12 +94,12 @@ class Record:
 
     def stamp(self, name, phase=_KEEP):
         if phase is _KEEP:
-            self.fields[name] = now()
+            self.fields[name] = _now()
             return
         if self._open is not None:
             self._open.__exit__(None, None, None)
             self._open = None
-        self.fields[name] = now()
+        self.fields[name] = _now()
         if phase is not None:
             self._open = _device.session_annotation(phase)
             self._open.__enter__()
@@ -108,7 +108,7 @@ class Record:
         if self._open is not None:
             self._open.__exit__(None, None, None)
             self._open = None
-        self.fields["done"] = now()
+        self.fields["done"] = _now()
         _ring.append(self.fields)
 
 

@@ -68,7 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
-from paddle_tpu.ops.pallas_kernels import _count_impl, named_pallas_call
+from paddle_tpu.ops.pallas_kernels import _count_impl, _named_pallas_call
 
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _FC_BLOCK_M = 256
@@ -439,7 +439,7 @@ def _fc_ep_pallas(x2, w2, bias, residual, act, approximate,
     kernel = functools.partial(
         _fc_ep_kernel, act=act, approximate=approximate,
         has_bias=bias is not None, has_res=residual is not None)
-    return named_pallas_call(
+    return _named_pallas_call(
         kernel,
         name="pt_fc_ep",
         grid=grid,

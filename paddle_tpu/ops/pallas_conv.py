@@ -48,7 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
-from paddle_tpu.ops.pallas_kernels import _count_impl, named_pallas_call
+from paddle_tpu.ops.pallas_kernels import _count_impl, _named_pallas_call
 
 # VMEM budget for the compiled kernel: one image block + filter tile +
 # accumulator + residual tile, doubled for Pallas' input double
@@ -225,7 +225,7 @@ def _conv_ep_pallas(x, w, bias, residual, strides, padding, act,
         _conv_ep_kernel, kh=kh, kw=kw, sh=sh, sw=sw, oh=oh, ow=ow,
         act=act, has_bias=bias is not None,
         has_res=residual is not None)
-    return named_pallas_call(
+    return _named_pallas_call(
         kernel,
         name="pt_conv_ep",
         grid=grid,
@@ -410,7 +410,7 @@ def _conv_stats_pallas(x, w, bias, strides, padding, interpret=False):
     # stat arrays ride as [N, 8, Cout] (sublane-replicated x8 — see the
     # kernel comment); the finalization reads row 0
     stat_spec = pl.BlockSpec((1, 8, bco), lambda ni, co: (ni, 0, co))
-    y, s1, s2 = named_pallas_call(
+    y, s1, s2 = _named_pallas_call(
         kernel,
         name="pt_conv_stats",
         grid=grid,
@@ -534,7 +534,7 @@ def _bn_apply_pallas(y, mean, rstd, scale, shift, residual, act,
             dimension_semantics=("parallel", "parallel", "parallel"))
     kernel = functools.partial(_bn_apply_kernel, act=act,
                                has_res=residual is not None)
-    return named_pallas_call(
+    return _named_pallas_call(
         kernel,
         name="pt_bn_apply",
         grid=grid,

@@ -80,7 +80,7 @@ def _count_impl(kernel, impl):
     _M_KERNEL_IMPL.inc(kernel=kernel, impl=impl)
 
 
-def named_pallas_call(kernel, name, **kw):
+def _named_pallas_call(kernel, name, **kw):
     """`pl.pallas_call` under a device-side name that stays put.
 
     The TPU compiler names a Mosaic custom call after the innermost
@@ -357,7 +357,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     else:
         lse_shape = (b * h, tq_p, _MIN_LANES)
         lse_block = (hpb, bq, _MIN_LANES)
-    out, lse = named_pallas_call(
+    out, lse = _named_pallas_call(
         kernel,
         name="pt_flash_fwd",
         grid=grid,
@@ -609,7 +609,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     qspec = pl.BlockSpec((hpb, bq, d), lambda bh, i, j: (bh, i, 0))
     lspec = pl.BlockSpec(lblk, lambda bh, i, j: (bh, i, 0))
     kspec = pl.BlockSpec((hpb, bk, d), lambda bh, i, j: (bh, j, 0))
-    dq = named_pallas_call(
+    dq = _named_pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         name="pt_flash_bwd_dq",
         grid=(b * h // hpb, tq_p // bq, tk_p // bk),
@@ -626,7 +626,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     qspec2 = pl.BlockSpec((hpb, bq, d), lambda bh, j, i: (bh, i, 0))
     lspec2 = pl.BlockSpec(lblk, lambda bh, j, i: (bh, i, 0))
     kspec2 = pl.BlockSpec((hpb, bk, d), lambda bh, j, i: (bh, j, 0))
-    dk, dv = named_pallas_call(
+    dk, dv = _named_pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
         name="pt_flash_bwd_dkv",
         grid=(b * h // hpb, tk_p // bk, tq_p // bq),
@@ -1035,7 +1035,7 @@ def _flash_decode_pallas(q, k_pages, v_pages, block_tables, seq_lens,
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-    out = named_pallas_call(
+    out = _named_pallas_call(
         kernel,
         name="pt_flash_decode",
         grid_spec=grid_spec,

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import itertools
 import random as _random
+import time
 from queue import Queue
 from threading import Thread
 
@@ -222,7 +223,7 @@ class DeviceFeeder:
 
             try:
                 while True:
-                    t_get = _obs_steps.now()
+                    t_get = time.perf_counter_ns()
                     item = self._host_q.get()
                     if item is DeviceFeeder._END or self._stopped:
                         break
@@ -240,7 +241,7 @@ class DeviceFeeder:
                         self._dev_q.put(item)
                         f = rec.fields
                         f["host_wait"] = f["start"] - t_get
-                        f["dev_wait"] = _obs_steps.now() - f["end"]
+                        f["dev_wait"] = time.perf_counter_ns() - f["end"]
                     finally:
                         rec.done()
             except BaseException as e:
