@@ -68,7 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
-from paddle_tpu.ops.pallas_kernels import _count_impl, _named_pallas_call
+from paddle_tpu.ops.pallas_kernels import _count_impl, _kernel_scope
 
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _FC_BLOCK_M = 256
@@ -439,7 +439,7 @@ def _fc_ep_pallas(x2, w2, bias, residual, act, approximate,
     kernel = functools.partial(
         _fc_ep_kernel, act=act, approximate=approximate,
         has_bias=bias is not None, has_res=residual is not None)
-    return _named_pallas_call(
+    return pl.pallas_call(
         kernel,
         name="pt_fc_ep",
         grid=grid,
@@ -516,7 +516,7 @@ def fc_epilogue(x, w, bias=None, residual=None, *, act=None,
         impl = "pallas" if _on_tpu() else "xla"
     # one call line, flag on or off (see ops/pallas_kernels.py
     # flash_attention): the compiled module does not depend on the flag
-    with _obs_device.annotate("fc_epilogue"):
+    with _obs_device.annotate("fc_epilogue"), _kernel_scope():
         return _fc_ep(x, w, bias, residual, act or "",
                       bool(approximate), impl)
 

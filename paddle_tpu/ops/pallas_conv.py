@@ -48,7 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability import device_trace as _obs_device
-from paddle_tpu.ops.pallas_kernels import _count_impl, _named_pallas_call
+from paddle_tpu.ops.pallas_kernels import _count_impl, _kernel_scope
 
 # VMEM budget for the compiled kernel: one image block + filter tile +
 # accumulator + residual tile, doubled for Pallas' input double
@@ -225,7 +225,7 @@ def _conv_ep_pallas(x, w, bias, residual, strides, padding, act,
         _conv_ep_kernel, kh=kh, kw=kw, sh=sh, sw=sw, oh=oh, ow=ow,
         act=act, has_bias=bias is not None,
         has_res=residual is not None)
-    return _named_pallas_call(
+    return pl.pallas_call(
         kernel,
         name="pt_conv_ep",
         grid=grid,
@@ -410,7 +410,7 @@ def _conv_stats_pallas(x, w, bias, strides, padding, interpret=False):
     # stat arrays ride as [N, 8, Cout] (sublane-replicated x8 — see the
     # kernel comment); the finalization reads row 0
     stat_spec = pl.BlockSpec((1, 8, bco), lambda ni, co: (ni, 0, co))
-    y, s1, s2 = _named_pallas_call(
+    y, s1, s2 = pl.pallas_call(
         kernel,
         name="pt_conv_stats",
         grid=grid,
@@ -469,8 +469,9 @@ def conv2d_bn_stats(x, w, bias=None, *, strides=(1, 1), paddings=(0, 0),
     strides = tuple(int(s) for s in strides)
     padding = _norm_padding(paddings)
     if impl in ("pallas", "interpret"):
-        y, s1, s2 = _conv_stats_pallas(x, w, bias, strides, padding,
-                                       interpret=impl == "interpret")
+        with _kernel_scope():
+            y, s1, s2 = _conv_stats_pallas(x, w, bias, strides, padding,
+                                           interpret=impl == "interpret")
     else:
         y, s1, s2 = _conv_stats_xla(x, w, bias, strides, padding)
     m = float(y.shape[0] * y.shape[1] * y.shape[2])
@@ -534,7 +535,7 @@ def _bn_apply_pallas(y, mean, rstd, scale, shift, residual, act,
             dimension_semantics=("parallel", "parallel", "parallel"))
     kernel = functools.partial(_bn_apply_kernel, act=act,
                                has_res=residual is not None)
-    return _named_pallas_call(
+    return pl.pallas_call(
         kernel,
         name="pt_bn_apply",
         grid=grid,
@@ -599,7 +600,7 @@ def conv2d_epilogue(x, w, bias=None, residual=None, *, strides=(1, 1),
     # device-time attribution (ISSUE 10): a runtime annotation under
     # the `tracing` flag, else the null context; one call line either
     # way (see ops/pallas_kernels.py flash_attention)
-    with _obs_device.annotate("conv2d_epilogue"):
+    with _obs_device.annotate("conv2d_epilogue"), _kernel_scope():
         return _conv_ep(x, w, bias, residual, strides, padding,
                         act or "", impl)
 
@@ -736,7 +737,7 @@ def conv2d_bn_act(x, w, scale, shift, bias=None, residual=None, *,
         impl = "pallas" if _on_tpu() else "xla"
     strides = tuple(int(s) for s in strides)
     padding = _norm_padding(paddings)
-    with _obs_device.annotate("conv2d_bn_act"):
+    with _obs_device.annotate("conv2d_bn_act"), _kernel_scope():
         return _conv_bn_act(x, w, bias, scale, shift, residual, strides,
                             padding, act or "", float(epsilon), impl)
 

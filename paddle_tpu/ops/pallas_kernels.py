@@ -80,26 +80,26 @@ def _count_impl(kernel, impl):
     _M_KERNEL_IMPL.inc(kernel=kernel, impl=impl)
 
 
-def _named_pallas_call(kernel, name, **kw):
-    """`pl.pallas_call` under a device-side name that stays put.
+def _kernel_scope():
+    """The name-stack element every Pallas kernel entry opens around
+    its work, so that the device-side kernel names stay put.
 
+    Each `pl.pallas_call` in ops/ carries a fixed `name="pt_<kernel>"`.
     The TPU compiler names a Mosaic custom call after the innermost
-    element of the jax name stack, and the profiler's device line
-    shows that name.  `pallas_call(name=)` makes `name` that element,
-    flag or no flag.  Under `jax.vjp` (core/registry.py builds every
-    `<op>_grad` so) jax wraps the first scope entered inside a
-    transform in the transform's name, `jvp(pt_flash_fwd)`, and the
-    instruction would read `jvp_pt_flash_fwd_`: the outer `pt` scope
-    is there to take that wrapping (`jvp(pt)/pt_flash_fwd`), so the
-    instruction is `pt_flash_fwd` in the forward op, in the backward
-    op and under `shard_map` alike.  No name ends in a digit or a dot
-    (benchmarks/trace_reduce.py strips those)."""
-    call = pl.pallas_call(kernel, name=name, **kw)
-
-    def run(*operands):
-        with jax.named_scope("pt"):
-            return call(*operands)
-    return run
+    element of the jax name stack, which `name=` makes the kernel's,
+    and the profiler's device line shows it.  But under `jax.vjp`
+    (core/registry.py builds every `<op>_grad` so) jax wraps the FIRST
+    scope entered inside a transform in the transform's name, and the
+    instruction would read `jvp_pt_flash_fwd_` or
+    `transpose_jvp_pt_flash_bwd_dq__`.  This scope takes that wrapping
+    (`transpose(jvp(pt))/pt_flash_bwd_dq/pallas_call`), so the
+    instruction is the kernel's name in the forward op, in the backward
+    op and under `shard_map` alike.  It is opened at the entry, outside
+    the custom_vjp call, not around each pallas_call: a wrapper there
+    cost 0.8 s of a 5.5 s first trace of the sharded Transformer step
+    (PERF.md, PR 24).  No kernel name ends in a digit or a dot
+    (benchmarks/trace_reduce.py strips those to group calls)."""
+    return jax.named_scope("pt")
 
 
 _NEG_INF = -1e30
@@ -357,7 +357,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     else:
         lse_shape = (b * h, tq_p, _MIN_LANES)
         lse_block = (hpb, bq, _MIN_LANES)
-    out, lse = _named_pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         name="pt_flash_fwd",
         grid=grid,
@@ -609,7 +609,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     qspec = pl.BlockSpec((hpb, bq, d), lambda bh, i, j: (bh, i, 0))
     lspec = pl.BlockSpec(lblk, lambda bh, i, j: (bh, i, 0))
     kspec = pl.BlockSpec((hpb, bk, d), lambda bh, i, j: (bh, j, 0))
-    dq = _named_pallas_call(
+    dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         name="pt_flash_bwd_dq",
         grid=(b * h // hpb, tq_p // bq, tk_p // bk),
@@ -626,7 +626,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     qspec2 = pl.BlockSpec((hpb, bq, d), lambda bh, j, i: (bh, i, 0))
     lspec2 = pl.BlockSpec(lblk, lambda bh, j, i: (bh, i, 0))
     kspec2 = pl.BlockSpec((hpb, bk, d), lambda bh, j, i: (bh, j, 0))
-    dk, dv = _named_pallas_call(
+    dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
         name="pt_flash_bwd_dkv",
         grid=(b * h // hpb, tk_p // bk, tq_p // bq),
@@ -756,8 +756,9 @@ def flash_attention_lse(q, k, v, *, causal=False, scale=None,
     block_k = block_k or _default_block(k.shape[-2])
     packed_stats, head_pack = _resolve_variants(packed_stats, head_pack)
     _count_impl("flash_attention", impl)
-    return _flash_lse(q, k, v, causal, float(scale), block_q, block_k,
-                      impl == "interpret", packed_stats, head_pack)
+    with _kernel_scope():
+        return _flash_lse(q, k, v, causal, float(scale), block_q, block_k,
+                          impl == "interpret", packed_stats, head_pack)
 
 
 def _default_block(t):
@@ -800,7 +801,7 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     # the null context.  ONE call line either way: source locations
     # ride the Mosaic payload, so two call lines would make the
     # compiled module (and its cache key) depend on the flag
-    with _obs_device.annotate("flash_attention"):
+    with _obs_device.annotate("flash_attention"), _kernel_scope():
         return _flash(q, k, v, causal, float(scale), block_q, block_k,
                       impl, packed_stats, head_pack)
 
@@ -1035,7 +1036,7 @@ def _flash_decode_pallas(q, k_pages, v_pages, block_tables, seq_lens,
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-    out = _named_pallas_call(
+    out = pl.pallas_call(
         kernel,
         name="pt_flash_decode",
         grid_spec=grid_spec,
@@ -1287,7 +1288,8 @@ def flash_decode(q, k_pages, v_pages, block_tables, seq_lens, *,
             q, k_pages, hpb, vmem_budget_bytes, q_len):
         impl = "xla"   # documented fallback: gather + reference replay
     _count_impl("flash_decode", impl)
-    with _obs_device.annotate("flash_decode"):   # see flash_attention
+    # see flash_attention
+    with _obs_device.annotate("flash_decode"), _kernel_scope():
         return _flash_decode_entry(q, k_pages, v_pages, block_tables,
                                    seq_lens, scale, impl, hpb, int8kv,
                                    kv_scales, q_len)
