@@ -71,6 +71,7 @@ def _reset_program_state():
     from paddle_tpu.core import scope as scope_mod
     from paddle_tpu.core.program import Program
     from paddle_tpu.layers import nn as nn_layers
+    from paddle_tpu.parallel import env as penv
 
     old = (framework.switch_main_program(Program()),
            framework.switch_startup_program(Program()),
@@ -78,6 +79,11 @@ def _reset_program_state():
            scope_mod._global_scope)
     scope_mod._global_scope = scope_mod.Scope()
     nn_layers._dropout_counter_var.clear()
+    # the process-wide mesh too: a CompiledProgram over a mesh sets it
+    # (parallel/env.py), and one left by an earlier test of the same
+    # worker (test_tpu_lowering_gate.py leaves a mesh of DESCRIBED TPU
+    # devices) sent later tests' arrays to devices that do not exist
+    penv.reset()
     return old
 
 
