@@ -327,6 +327,15 @@ def _pad_axis(x, axis, mult):
     return jnp.pad(x, widths)
 
 
+# jitted so that a step traces each kernel once per signature, not once
+# per layer: jax keeps no cache of kernel-body traces (the partial it is
+# handed is new every call), and of a six-layer Transformer step's first
+# trace most was the same three kernel bodies traced 24 times.  XLA
+# inlines the calls: the compiled step is the same module (same opcode
+# counts and code size, compiled for a described v5e; PERF.md, PR 24).
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret", "packed_stats",
+    "head_pack"))
 def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                       interpret=False, packed_stats=False,
                       head_pack=False):
@@ -543,6 +552,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_ref[h, ...] = dv_acc[h].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=(    # see _flash_fwd_pallas
+    "causal", "scale", "block_q", "block_k", "interpret", "packed_stats",
+    "head_pack"))
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
                       block_k, interpret=False, dlse=None,
                       packed_stats=False, head_pack=False):
