@@ -22,10 +22,10 @@ the GSPMD rounds found dynamically:
     plan falls back to the unsharded kernel at trace time with no
     signal; here it is a typed diagnostic at annotate time;
   * the untagged-grad-op escape — a tagged flash_attention whose
-    flash_attention_grad sibling lost its tags re-traces the kernel
-    inside shard_map's partitioner ("Mosaic kernels cannot be
-    automatically partitioned", caught once at the export gate, at
-    zero chip cost only by luck).
+    flash_attention_grad sibling lost its tags runs its kernels
+    inside the SPMD partitioner, outside shard_map ("Mosaic kernels
+    cannot be automatically partitioned", caught once at the export
+    gate, at zero chip cost only by luck).
 
 docs/ANALYSIS.md has the rule table.
 """
@@ -301,10 +301,11 @@ def check_sharding(program, plan, raise_=True, label=""):
                 diags.append(Diagnostic(
                     "sharding-untagged-grad",
                     "flash_attention ops are gspmd-tagged but this "
-                    "grad op is not: the vjp re-traces the forward "
-                    "under the GRAD op's attrs, so the kernel lands "
-                    "inside the SPMD partitioner untagged ('Mosaic "
-                    "kernels cannot be automatically partitioned')",
+                    "grad op is not: it runs the backward kernels "
+                    "(and, without saved Out/LSE, the forward again) "
+                    "under the GRAD op's attrs, so they land inside "
+                    "the SPMD partitioner untagged ('Mosaic kernels "
+                    "cannot be automatically partitioned')",
                     block_idx=0, op_idx=i, op_type=op.type))
 
     if raise_ and any(d.severity == _ERROR for d in diags):

@@ -8,8 +8,11 @@ TPU-first difference: the reference needs a hand-written C++ GradOpMaker per
 op; here the '<type>_grad' op is synthesized from the forward compute via
 jax.vjp (core/registry.py _generic_grad_def), and ops may override with an
 IR-level grad_maker when the vjp shape is wrong (e.g. sparse embedding
-grads).  The resulting backward ops are ordinary IR ops: they serialize,
-transpile, and compile like any other — same capability as the reference.
+grads), or with a registered '<type>_grad' op that reads the forward op's
+saved outputs instead of running its forward again (flash_attention_grad
+reads Out and LSE).  The resulting backward ops are ordinary IR ops: they
+serialize, transpile, and compile like any other — same capability as the
+reference.
 """
 
 from __future__ import annotations
@@ -185,6 +188,15 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
         grad_inputs = dict(grad_out_slots)
         for slot, names in op.inputs.items():
             grad_inputs[slot] = list(names)
+        # a forward OUTPUT the grad op declares among its inputs is a
+        # saved residual (the reference's DefaultGradOpDescMaker hands
+        # the grad op inputs, outputs and output grads alike).  The
+        # generic vjp grad declares none, so for every other op nothing
+        # more is bound and nothing more stays live
+        grad_def = get_op_def(op.type + "_grad")
+        for slot, names in op.outputs.items():
+            if slot in grad_def.inputs and slot not in grad_inputs:
+                grad_inputs[slot] = list(names)
         grad_outputs = {}
         for slot, names in op.inputs.items():
             if not any(_needs_grad(block, n, no_grad_set)

@@ -29,9 +29,12 @@ _WHITE_KEEP_FP32 = {
 # white-list ops with multiple outputs where only SOME are emitted in
 # the low dtype (conv2d_bn_train: Output follows the bf16 inputs; the
 # stat outputs MeanOut/VarianceOut/SavedMean/SavedVariance stay fp32,
-# like batch_norm's non-Y outputs under the follow-X rule)
+# like batch_norm's non-Y outputs under the follow-X rule;
+# flash_attention: Out follows the bf16 q/k/v, the saved row statistic
+# LSE is float32 whatever the inputs are)
 _WHITE_LOWP_OUT = {
     "conv2d_bn_train": frozenset({"Output"}),
+    "flash_attention": frozenset({"Out"}),
 }
 
 

@@ -189,8 +189,15 @@ def _generic_grad_def(fwd_type: str) -> OpDef:
     from the forward one instead of requiring a hand-written grad kernel.
 
     Note: the vjp re-traces the forward op.  Under the compiled (whole
-    program) executor XLA CSEs the duplicated forward; in interpreter mode it
-    is a per-op recompute, the debug path where that cost is acceptable.
+    program) executor XLA CSEs a duplicated forward made of XLA ops; in
+    interpreter mode it is a per-op recompute, the debug path where that
+    cost is acceptable.  XLA does NOT CSE a duplicated Mosaic custom call:
+    the six-layer Transformer step held 12 pt_flash_fwd calls (PERF.md,
+    PR 24).  So an op whose forward is a Pallas kernel needs a registered
+    `<fwd>_grad` op that reads the forward's saved outputs (a name in
+    _REGISTRY wins over this; append_backward binds the forward outputs
+    such an op declares among its inputs): flash_attention_grad in
+    ops/pallas_kernels.py.
     """
     fwd = get_op_def(fwd_type)
     if not fwd.differentiable:

@@ -930,12 +930,20 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     block_q/block_k override the kernel tile sizes (default picked by
     sequence length: 1024 for T >= 1024, else 512 — pinned by the
     2026-08-01 v5e sweep, tools/flash_block_sweep.py).
+
+    Returns Out.  The op also writes LSE, the per-row log-sum-exp
+    (float32 [B, H, Tq], no gradient): the residual flash_attention_grad
+    reads, with Out, instead of running the forward kernel again.
     """
-    return _single_out(
-        "flash_attention", q,
-        {"causal": causal, "scale": float(scale or 0.0),
-         "block_q": int(block_q or 0), "block_k": int(block_k or 0)},
-        ins_extra={"K": k, "V": v}, in_slot="Q")
+    helper = LayerHelper("flash_attention")
+    out = helper.create_variable_for_type_inference(q.dtype)
+    lse = helper.create_variable_for_type_inference("float32", True)
+    helper.append_op(
+        type="flash_attention", inputs={"Q": q, "K": k, "V": v},
+        outputs={"Out": out, "LSE": lse},
+        attrs={"causal": causal, "scale": float(scale or 0.0),
+               "block_q": int(block_q or 0), "block_k": int(block_k or 0)})
+    return out
 
 
 def linear_chain_crf(input, label, param_attr=None, length=None,

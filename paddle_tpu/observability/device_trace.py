@@ -131,7 +131,7 @@ def annotate(kernel):
     """The kernel-entry annotation site::
 
         with device_trace.annotate("flash_attention"):
-            return _flash(...)
+            return _flash_lse(...)
 
     With tracing off this is one module-global check and the null
     context.  The Pallas kernel entries call it unguarded, on ONE call

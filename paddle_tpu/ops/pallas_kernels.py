@@ -16,12 +16,13 @@ dimension innermost so the (acc, m, l) scratch carries across KV steps;
 hpb is the heads-per-block packing factor (1, or 2 under the
 `flash_head_pack` flag — see below).
 
-The public `flash_attention` is differentiable via custom_vjp: forward
+The public `flash_attention` is differentiable via ONE custom_vjp
+(`_flash_lse`, shared with `flash_attention_lse` and the IR op): forward
 runs the Pallas kernel on TPU (plain XLA path elsewhere) and saves
 (q, k, v, o, lse); backward runs dedicated Pallas kernels (two-pass
 FlashAttention bwd: a dq sweep and a dk/dv sweep that recompute P
 blockwise from lse) — the [Tq, Tk] matrices stay in VMEM in both
-directions.  The XLA impl keeps the plain einsum replay.
+directions.  The XLA impl is plain attention, differentiated by jax.
 
 Memory-layout variants (docs/FLASH_ATTENTION.md; both default OFF until
 the chip chaser validates them — zero behavior change under the
@@ -111,8 +112,9 @@ _F32_SUBLANES = 8  # f32 min sublane tile — gates the packed-stats block
 # reference (XLA) implementation — also the backward path
 # ---------------------------------------------------------------------------
 
-def _plain_attention(q, k, v, causal, scale):
-    """q/k/v: [B, H, T, D]."""
+def _plain_attention(q, k, v, causal, scale, with_lse=False):
+    """q/k/v: [B, H, T, D].  with_lse: also the log-sum-exp of each
+    row of the scaled, masked scores, float32 [B, H, Tq]."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     p = None
@@ -126,8 +128,11 @@ def _plain_attention(q, k, v, causal, scale):
         p = jax.nn.softmax(s, axis=-1) * mask
     else:
         p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)) \
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)) \
         .astype(q.dtype)
+    if with_lse:
+        return out, jax.nn.logsumexp(s, axis=-1)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -558,7 +563,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
                       block_k, interpret=False, dlse=None,
                       packed_stats=False, head_pack=False):
-    """q/k/v: [B, H, T, D]; lse: [B*H, Tq_padded]; g = dO.
+    """q/k/v: [B, H, T, D]; lse: [B*H, Tq] or q-block padded, as the
+    forward kernel returns it; g = dO.
 
     dlse ([B*H, Tq] or None): cotangent of the lse output when the
     caller consumes it (ring attention's cross-chunk merge).  Since
@@ -580,6 +586,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     vp = _pad_axis(v.reshape(b * h, tk, d), 1, bk)
     gp = _pad_axis(g.reshape(b * h, tq, d), 1, bq)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
+    # rows past tq are masked in the kernels: what they hold is not read
+    lse = _pad_axis(lse, 1, bq)
     packed = packed_stats and _packed_geom_ok(bq)
     hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)) else 1
     # delta = rowsum(dO * O): cheap elementwise+reduce, done in XLA;
@@ -659,59 +667,14 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
 
 
 # ---------------------------------------------------------------------------
-# public differentiable entry
+# differentiable entries: ONE custom_vjp over the forward/backward kernels
 # ---------------------------------------------------------------------------
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, causal, scale, block_q, block_k, impl,
-           packed_stats, head_pack):
-    if impl == "pallas":
-        return _flash_fwd_pallas(q, k, v, causal, scale, block_q,
-                                 block_k, packed_stats=packed_stats,
-                                 head_pack=head_pack)[0]
-    if impl == "interpret":
-        return _flash_fwd_pallas(q, k, v, causal, scale, block_q,
-                                 block_k, interpret=True,
-                                 packed_stats=packed_stats,
-                                 head_pack=head_pack)[0]
-    return _plain_attention(q, k, v, causal, scale)
-
-
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, impl,
-                    packed_stats, head_pack):
-    if impl in ("pallas", "interpret"):
-        out, lse = _flash_fwd_pallas(q, k, v, causal, scale, block_q,
-                                     block_k,
-                                     interpret=impl == "interpret",
-                                     packed_stats=packed_stats,
-                                     head_pack=head_pack)
-        return out, (q, k, v, out, lse)
-    out = _plain_attention(q, k, v, causal, scale)
-    return out, (q, k, v, None, None)
-
-
-def _flash_bwd_rule(causal, scale, block_q, block_k, impl,
-                    packed_stats, head_pack, res, g):
-    q, k, v, o, lse = res
-    if impl in ("pallas", "interpret"):
-        return _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale,
-                                 block_q, block_k,
-                                 interpret=impl == "interpret",
-                                 packed_stats=packed_stats,
-                                 head_pack=head_pack)
-    _, vjp = jax.vjp(
-        lambda a, b, c: _plain_attention(a, b, c, causal, scale), q, k, v)
-    return vjp(g)
-
-
-_flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
-
-
-# -- (out, lse) variant: the mergeable summary ring attention needs ----
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                packed_stats, head_pack):
+    """(out, lse): lse is the mergeable summary ring attention needs and
+    the residual the IR grad op reads."""
     return _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                              interpret=interpret,
                              packed_stats=packed_stats,
@@ -758,19 +721,15 @@ def flash_attention_lse(q, k, v, *, causal=False, scale=None,
     packed_stats/head_pack: None -> the `flash_packed_stats` /
     `flash_head_pack` flags; explicit bools override.  The returned lse
     is layout-independent ([B*H, Tq_padded]) in every mode."""
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
     if impl not in ("pallas", "interpret"):
         raise ValueError(
             "flash_attention_lse impl must be 'pallas' or 'interpret', "
             "got %r" % (impl,))
-    block_q = block_q or _default_block(q.shape[-2])
-    block_k = block_k or _default_block(k.shape[-2])
-    packed_stats, head_pack = _resolve_variants(packed_stats, head_pack)
+    impl, kw = _call_args(q, k, causal, scale, block_q, block_k, impl,
+                          packed_stats, head_pack)
     _count_impl("flash_attention", impl)
     with _kernel_scope():
-        return _flash_lse(q, k, v, causal, float(scale), block_q, block_k,
-                          impl == "interpret", packed_stats, head_pack)
+        return _flash_lse(q, k, v, **kw)
 
 
 def _default_block(t):
@@ -800,13 +759,41 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     explicit bools override — outputs are identical in every mode, only
     the kernel's HBM layout and grid packing change.
     """
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    if impl is None:
-        impl = "pallas" if _on_tpu() else "xla"
-    block_q = block_q or _default_block(q.shape[-2])
-    block_k = block_k or _default_block(k.shape[-2])
+    return _flash_attention_fwd(
+        q, k, v, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, impl=impl, packed_stats=packed_stats,
+        head_pack=head_pack)[0]
+
+
+def _call_args(q, k, causal=False, scale=None, block_q=None, block_k=None,
+               impl=None, packed_stats=None, head_pack=None):
+    """What a flash entry's unset (None, or an op attr's 0) arguments
+    mean, resolved in ONE place (the saved-residual backward reads the
+    forward's lse and must tile it the same way): scale 1/sqrt(d), impl
+    `_auto_impl()`, blocks by sequence length, layout variants from
+    their flags.  Returns (impl, the static arguments `_flash_lse` and
+    `_flash_bwd_pallas` share)."""
+    impl = impl or _auto_impl()
     packed_stats, head_pack = _resolve_variants(packed_stats, head_pack)
+    return impl, dict(
+        causal=bool(causal),
+        scale=float(scale or 1.0 / math.sqrt(q.shape[-1])),
+        block_q=block_q or _default_block(q.shape[-2]),
+        block_k=block_k or _default_block(k.shape[-2]),
+        interpret=impl == "interpret",
+        packed_stats=packed_stats, head_pack=head_pack)
+
+
+def _flash_attention_fwd(q, k, v, **call):
+    """flash_attention plus the residual its backward needs: (out, lse),
+    lse the log-sum-exp of each row of the scaled, masked scores,
+    float32 [B, H, Tq] (-1e30 on a fully masked row).  The same quantity
+    on every impl: the kernel's own row statistic on pallas/interpret,
+    `logsumexp` of the scores plain attention forms on xla.
+    Differentiable in q, k, v.  The `flash_attention` IR op is this;
+    `_flash_attention_bwd` is its grad op when (out, lse) were kept.
+    **call: `_call_args`' keywords."""
+    impl, kw = _call_args(q, k, **call)
     _count_impl("flash_attention", impl)
     # device-time attribution (ISSUE 10): at runtime with the `tracing`
     # flag on, an annotation carrying the active trace id; otherwise
@@ -814,8 +801,46 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     # ride the Mosaic payload, so two call lines would make the
     # compiled module (and its cache key) depend on the flag
     with _obs_device.annotate("flash_attention"), _kernel_scope():
-        return _flash(q, k, v, causal, float(scale), block_q, block_k,
-                      impl, packed_stats, head_pack)
+        if impl == "xla":
+            return _plain_attention(q, k, v, kw["causal"], kw["scale"],
+                                    with_lse=True)
+        out, lse = _flash_lse(q, k, v, **kw)
+    b, h, tq, _ = q.shape
+    # the kernel's [B*H, Tq_padded]: a slice (none at tq % block_q == 0)
+    # and a free reshape.  The kernel writes the statistic lane-
+    # replicated ([B*H, Tq, 128]) and `_flash_fwd_pallas` strips the
+    # lanes; tied to out, that strip has to run here in the forward.
+    # Left free, XLA's scheduler put it off until the backward read
+    # lse and kept 128x the vector alive meanwhile: +0.4 GB a chip in
+    # the dp2 x tp2 step (compiled for a described v5e; PERF.md, PR 25)
+    return lax.optimization_barrier(
+        (out, lse[:, :tq].reshape(b, h, tq)))
+
+
+def _flash_attention_bwd(q, k, v, out, lse, g, **call):
+    """(dq, dk, dv) from what `_flash_attention_fwd` returned and the
+    cotangent g of out: the two backward kernels and nothing else.  The
+    forward kernel does not run again.  Kernel impls only: plain
+    attention keeps no residual worth saving, jax differentiates it."""
+    _, kw = _call_args(q, k, **call)
+    b, h, tq, _ = q.shape
+    # lse has been ready since the forward, and the kernels read it
+    # lane-replicated ([B*H, Tq, 128]): left free, XLA's scheduler makes
+    # that broadcast right after the forward kernel and keeps 128x the
+    # vector alive until here, +0.8 GB in the six-layer step at seq
+    # 8192 (compiled for a described v5e; PERF.md, PR 25).  Tied to g,
+    # it cannot be made before the backward reaches this layer.
+    lse, g = lax.optimization_barrier((lse, g))
+    # see _flash_attention_fwd: one call line, flag or no flag
+    with _obs_device.annotate("flash_attention_grad"), _kernel_scope():
+        return _flash_bwd_pallas(q, k, v, out, lse.reshape(b * h, tq), g,
+                                 **kw)
+
+
+def _auto_impl():
+    """What impl=None means: the Pallas kernel on a TPU, XLA elsewhere
+    (interpret mode is only ever asked for by name)."""
+    return "pallas" if _on_tpu() else "xla"
 
 
 def _on_tpu():
@@ -1332,14 +1357,16 @@ def _flash_decode_entry(q, k_pages, v_pages, block_tables, seq_lens,
 from paddle_tpu.core.registry import register_op  # noqa: E402
 
 
-def _gspmd_flash_shard_map(attrs, q, k, v, call):
+def _gspmd_flash_shard_map(attrs, call, operands, out_ranks):
     """GSPMD front-end hook (parallel/gspmd.py tag_attention_ops):
     when the typed `gspmd` flag is on and the op carries
     gspmd_batch_axis / gspmd_head_axis attrs, run the kernel under
     shard_map on the current mesh — Mosaic kernels can't ride XLA's
     automatic partitioner, and attention is independent per
-    (batch, head) row so the dp x tp split is exact.  Flag off or an
-    untagged op returns None and the caller runs the plain
+    (batch, head) row so the dp x tp split is exact.  Every operand and
+    output leads with [B, H]: a rank-r one rides P(batch_axis,
+    head_axis, None, ...) (q/k/v/out/grads rank 4, lse rank 3).  Flag
+    off or an untagged op returns None and the caller runs the plain
     single-program path.  A TAGGED op that fails a gate (no mesh, axis
     missing or size 1, dim not divisible) also runs plain, and says so
     in paddle_tpu_kernel_impl_total{kernel="flash_attention_gspmd"}:
@@ -1357,7 +1384,7 @@ def _gspmd_flash_shard_map(attrs, q, k, v, call):
     mesh = penv.get_mesh()
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape)) \
         if mesh is not None else {}
-    bsz, hsz = q.shape[0], q.shape[1]
+    bsz, hsz = operands[0].shape[:2]
     if ba and (sizes.get(ba, 1) <= 1 or bsz % sizes.get(ba, 1) != 0):
         ba = None
     if ha and (sizes.get(ha, 1) <= 1 or hsz % sizes.get(ha, 1) != 0):
@@ -1368,31 +1395,77 @@ def _gspmd_flash_shard_map(attrs, q, k, v, call):
     from jax.sharding import PartitionSpec as P
 
     _count_impl("flash_attention_gspmd", "shard_map")
-    spec = P(ba, ha, None, None)
-    f = jax.shard_map(call, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec, check_vma=False)
-    return f(q, k, v)
+
+    def spec(rank):
+        return P(ba, ha, *[None] * (rank - 2))
+
+    f = jax.shard_map(call, mesh=mesh,
+                      in_specs=tuple(spec(x.ndim) for x in operands),
+                      out_specs=tuple(spec(r) for r in out_ranks),
+                      check_vma=False)
+    return f(*operands)
 
 
-@register_op("flash_attention", inputs=("Q", "K", "V"), outputs=("Out",),
-             attrs={"causal": False, "scale": 0.0, "block_q": 0,
-                    "block_k": 0, "gspmd_batch_axis": "",
-                    "gspmd_head_axis": ""})
+_FLASH_OP_ATTRS = {"causal": False, "scale": 0.0, "block_q": 0,
+                   "block_k": 0, "gspmd_batch_axis": "",
+                   "gspmd_head_axis": ""}
+
+
+def _flash_op_call(attrs):
+    # an attr left at its 0 default means unset, as None does (_call_args)
+    return {k: attrs.get(k) for k in
+            ("causal", "scale", "block_q", "block_k")}
+
+
+@register_op("flash_attention", inputs=("Q", "K", "V"),
+             outputs=("Out", "LSE"), attrs=_FLASH_OP_ATTRS)
 def _flash_attention_op(ins, attrs):
-    scale = attrs.get("scale") or None
+    """Out and the residual the grad op reads instead of running the
+    forward kernel again: LSE, the per-row log-sum-exp, float32
+    [B, H, Tq] on every impl (_flash_attention_fwd).  An op desc that
+    binds no LSE (a program from before the slot) runs the same."""
+    call = functools.partial(_flash_attention_fwd, **_flash_op_call(attrs))
+    operands = (ins["Q"], ins["K"], ins["V"])
+    res = _gspmd_flash_shard_map(attrs, call, operands, (4, 3))
+    out, lse = call(*operands) if res is None else res
+    return {"Out": out, "LSE": lse}
 
-    def call(q, k, v):
-        return flash_attention(q, k, v,
-                               causal=bool(attrs.get("causal")),
-                               scale=scale,
-                               block_q=attrs.get("block_q") or None,
-                               block_k=attrs.get("block_k") or None)
 
-    out = _gspmd_flash_shard_map(attrs, ins["Q"], ins["K"], ins["V"],
-                                 call)
-    if out is None:
-        out = call(ins["Q"], ins["K"], ins["V"])
-    return {"Out": out}
+@register_op("flash_attention_grad",
+             inputs=("Q", "K", "V", "Out", "LSE", "Out@GRAD"),
+             outputs=("Q@GRAD", "K@GRAD", "V@GRAD"),
+             optional=("Out", "LSE"), attrs=_FLASH_OP_ATTRS,
+             differentiable=False)
+def _flash_attention_grad_op(ins, attrs):
+    """Hand-written: XLA does not CSE a duplicated Mosaic custom call,
+    so the generic `jax.vjp` grad op ran the forward kernel a second
+    time in every layer (12 pt_flash_fwd in a six-layer step; PERF.md,
+    PR 24).  The choice follows from what the op can see:
+
+      * Out and LSE bound (append_backward binds them) and the impl
+        resolves to a kernel: the two backward kernels on the saved
+        residuals, under the same shard_map gate as the forward;
+      * either slot unbound (a program serialized before the slots, a
+        hand-built op) or the XLA impl: `jax.vjp` over the forward
+        op's compute, as the generic grad op did.
+
+    paddle_tpu_kernel_impl_total{kernel="flash_attention_grad"} says
+    which: impl="saved" | "recompute"."""
+    q, k, v, g = (ins[s] for s in ("Q", "K", "V", "Out@GRAD"))
+    if "Out" not in ins or "LSE" not in ins or _auto_impl() == "xla":
+        _count_impl("flash_attention_grad", "recompute")
+        _, vjp = jax.vjp(
+            lambda q, k, v: _flash_attention_op(
+                {"Q": q, "K": k, "V": v}, attrs)["Out"], q, k, v)
+        dq, dk, dv = vjp(g)
+    else:
+        _count_impl("flash_attention_grad", "saved")
+        call = functools.partial(_flash_attention_bwd,
+                                 **_flash_op_call(attrs))
+        operands = (q, k, v, ins["Out"], ins["LSE"], g)
+        res = _gspmd_flash_shard_map(attrs, call, operands, (4, 4, 4))
+        dq, dk, dv = call(*operands) if res is None else res
+    return {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
 
 
 @register_op("flash_decode",

@@ -492,11 +492,12 @@ def tag_attention_ops(program, plan, batch_axis=None, head_axis=None):
     n = 0
     for block in program.blocks:
         for op in block.ops:
-            # the _grad op re-traces the forward compute under jax.vjp
-            # with its OWN attrs (registry._generic_grad_def), so the
-            # backward kernels ride the same shard_map iff the grad op
-            # is tagged too (append_backward copied the attrs before
-            # this pass ran)
+            # the _grad op runs the backward kernels on the forward's
+            # saved Out/LSE (ops/pallas_kernels.py
+            # _flash_attention_grad_op; jax.vjp over the forward when
+            # they are not bound) under its OWN attrs, so they ride the
+            # same shard_map iff the grad op is tagged too
+            # (append_backward copied the attrs before this pass ran)
             if op.type not in ("flash_attention",
                                "flash_attention_grad"):
                 continue

@@ -256,13 +256,13 @@ def test_flash_attention_ir_op_block_override(monkeypatch):
     from paddle_tpu.ops import pallas_kernels
 
     seen = {}
-    real = pallas_kernels.flash_attention
+    real = pallas_kernels._flash_attention_fwd   # the op's kernel entry
 
     def spy(q, k, v, **kw):
         seen.update(kw)
         return real(q, k, v, **kw)
 
-    monkeypatch.setattr(pallas_kernels, "flash_attention", spy)
+    monkeypatch.setattr(pallas_kernels, "_flash_attention_fwd", spy)
 
     rng = np.random.RandomState(1)
     qkv = rng.randn(3, 1, 2, 40, 8).astype(np.float32)
