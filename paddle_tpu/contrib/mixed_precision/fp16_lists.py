@@ -33,6 +33,15 @@ white_list = {
     # fused mul+bias+residual+act (ops/epilogue.py): bf16 operands,
     # f32 accumulation on the MXU — same story as the mul it replaces
     "fc_epilogue",
+    # the experts' grouped matmuls (ops/pallas_gmm.py): bf16 rows and
+    # weights, f32 accumulation; the gates stay float32
+    # (fp16_utils._WHITE_KEEP_FP32)
+    "moe_experts",
+    # the two halves of a hyper-connection (ops/llm_ops.py): they read
+    # and write the n residual streams, the step's largest activations,
+    # in bf16; every coefficient (norm, projections, Sinkhorn) is
+    # computed and kept in float32 inside them (fp16_utils)
+    "mhc_pre", "mhc_post",
 }
 
 # numerically sensitive: keep fp32
@@ -41,6 +50,9 @@ black_list = {
     "softmax", "softmax_with_cross_entropy", "sigmoid_cross_entropy_with_logits",
     "cross_entropy", "cross_entropy2",
     "reduce_sum", "reduce_mean",
+    # router scores and the top-k selection over them: a rounding here
+    # sends a token to another expert
+    "moe_route",
 }
 
 # dtype-agnostic: run in whatever dtype arrives
@@ -54,13 +66,15 @@ gray_list = {
     "slice", "flatten2", "stack", "unstack", "expand", "scale", "cast",
     "elementwise_op", "squeeze2", "unsqueeze2", "pad", "pad2d", "gather",
     "swapaxes", "flip", "assign", "space_to_depth",
+    # float32 inside, written in the dtype that arrives
+    "rotary_embedding", "swiglu",
 }
 
 # normalization ops whose output dtype follows X (statistics stay fp32
 # inside the op compute — see ops/nn.py batch_norm/layer_norm)
 follow_x_list = {
     "batch_norm", "sync_batch_norm", "layer_norm", "group_norm",
-    "instance_norm", "data_norm",
+    "instance_norm", "data_norm", "rms_norm",
 }
 
 

@@ -24,6 +24,12 @@ _FLOATS = {"float32", "float64"}
 _WHITE_KEEP_FP32 = {
     "conv2d_bn_train": frozenset(
         {"Scale", "BNBias", "Mean", "Variance"}),
+    # the router's gates weigh the experts' outputs in float32
+    "moe_experts": frozenset({"TopkWeight"}),
+    # a hyper-connection's norm scale, projections, gains and biases,
+    # and the coefficients mhc_pre hands mhc_post: float32 end to end
+    "mhc_pre": frozenset({"NormScale", "Phi", "Alpha", "Bias"}),
+    "mhc_post": frozenset({"HPost", "HRes"}),
 }
 
 # white-list ops with multiple outputs where only SOME are emitted in
@@ -35,6 +41,7 @@ _WHITE_KEEP_FP32 = {
 _WHITE_LOWP_OUT = {
     "conv2d_bn_train": frozenset({"Output"}),
     "flash_attention": frozenset({"Out"}),
+    "mhc_pre": frozenset({"U"}),
 }
 
 
@@ -57,21 +64,26 @@ def rewrite_program(program, amp_lists, dest_dtype="bfloat16"):
 
     lowp = set()      # var names whose runtime value is dest_dtype
     new_ops = []
+    # var -> {dtype: the cast of it}, for as long as no op writes var
+    # again: ONE cast op however many ops read it.  A cast per reader
+    # under the one name `<var>.cast_<dtype>` made the readers' grad
+    # ops add into one gradient var that append_backward then handed
+    # to EVERY one of those casts' grad ops: a float32 activation read
+    # by q, k and v projections got 3 dv + 2 dk + dq (PERF.md, PR 27)
+    casts = {}
 
-    def insert_cast(name, dst, cache):
-        key = (name, dst)
-        if key in cache:
-            return cache[key]
-        cast_name = f"{name}.cast_{dst}"
-        shape = block.var(name).shape if block.has_var(name) else None
-        block.create_var(name=cast_name, dtype=dst, shape=shape)
-        new_ops.append(OpDesc("cast", {"X": [name]}, {"Out": [cast_name]},
-                              {"out_dtype": dst}))
-        cache[key] = cast_name
-        return cast_name
+    def insert_cast(name, dst):
+        made = casts.setdefault(name, {})
+        if dst not in made:
+            made[dst] = f"{name}.cast_{dst}"
+            shape = block.var(name).shape if block.has_var(name) else None
+            block.create_var(name=made[dst], dtype=dst, shape=shape)
+            new_ops.append(OpDesc("cast", {"X": [name]},
+                                  {"Out": [made[dst]]},
+                                  {"out_dtype": dst}))
+        return made[dst]
 
     for op in block.ops:
-        cache = {}
         if op.type in amp_lists.white_list:
             keep = _WHITE_KEEP_FP32.get(op.type, frozenset())
             for slot, names in list(op.inputs.items()):
@@ -80,7 +92,7 @@ def rewrite_program(program, amp_lists, dest_dtype="bfloat16"):
                 out = []
                 for n in names:
                     if eligible(n) and n not in lowp:
-                        n = insert_cast(n, dest_dtype, cache)
+                        n = insert_cast(n, dest_dtype)
                         lowp.add(n)
                     out.append(n)
                 op.inputs[slot] = out
@@ -103,7 +115,7 @@ def rewrite_program(program, amp_lists, dest_dtype="bfloat16"):
                 out = []
                 for n in names:
                     if n in lowp:
-                        n = insert_cast(n, "float32", cache)
+                        n = insert_cast(n, "float32")
                     out.append(n)
                 op.inputs[slot] = out
             out_lowp = False
@@ -111,6 +123,8 @@ def rewrite_program(program, amp_lists, dest_dtype="bfloat16"):
             out_lowp = str(op.attrs.get("out_dtype")) in (
                 dest_dtype, str(dest_dtype))
         new_ops.append(op)
+        for n in op.output_names():
+            casts.pop(n, None)
         for slot, names in op.outputs.items():
             slot_lowp = out_lowp and (
                 op.type not in _FOLLOW_X or slot == "Y")

@@ -39,8 +39,15 @@ class Uniform(Initializer):
 
 
 class Normal(Initializer):
-    def __init__(self, loc=0.0, scale=1.0, seed=0):
+    """fast: for models of 10^8 parameters and up.  The startup op
+    draws float32 samples from a Generator seeded by one draw of the
+    seeded stream (ops/basic.py gaussian_random), and is appended
+    without append-time shape inference, which would run the op, that
+    is draw the whole tensor on the host, once more at build time."""
+
+    def __init__(self, loc=0.0, scale=1.0, seed=0, fast=False):
         self.loc, self.scale, self.seed = loc, scale, seed
+        self.fast = fast
 
     def __call__(self, var, block):
         block.append_op(
@@ -48,7 +55,8 @@ class Normal(Initializer):
             outputs={"Out": var},
             attrs={"shape": list(var.shape), "dtype": var.dtype,
                    "mean": float(self.loc), "std": float(self.scale),
-                   "seed": self.seed},
+                   "seed": self.seed, "fast": self.fast},
+            infer_shape=not self.fast,
         )
 
 

@@ -1,0 +1,182 @@
+"""Layer functions of the 2024-26 decoder block (ops/llm_ops.py):
+rms_norm, rotary_embedding, swiglu, moe_route, moe_experts and the two
+halves of a manifold-constrained hyper-connection, mhc_pre / mhc_post.
+docs/XING4_BLOCK.md has the equations; models/xing4.py builds a model
+from them."""
+
+from __future__ import annotations
+
+import numpy as np
+
+from paddle_tpu.layers.helper import LayerHelper
+
+__all__ = ["rms_norm", "rotary_embedding", "swiglu", "moe_route",
+           "moe_experts", "mhc_pre", "mhc_post"]
+
+
+def _named(attr, name, part):
+    """A ParamAttr named `<name>_<part>.w` when the layer is named and
+    the caller gave none: deterministic names let a reference or a
+    second program find the weights in the scope."""
+    import copy
+
+    from paddle_tpu.param_attr import ParamAttr
+
+    attr = copy.copy(ParamAttr._to_attr(attr))   # the caller's is shared
+    if attr.name is None and name is not None:
+        attr.name = "%s_%s.w" % (name, part) if part else name + ".w"
+    return attr
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """RMSNorm over the last axis with a learnable scale (initially 1);
+    the statistic is float32, the output has the input's dtype."""
+    from paddle_tpu.initializer import Constant
+
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(
+        _named(param_attr, name, ""), [int(input.shape[-1])], "float32",
+        default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="rms_norm", inputs={"X": input, "Scale": scale},
+                     outputs={"Y": out}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(x, rotary_dim=None, theta=10000.0, factor=1.0,
+                     original_max_position=4096, beta_fast=32.0,
+                     beta_slow=1.0, mscale=1.0, name=None):
+    """Rotary position embedding of x [B, T, H, D] over positions
+    0..T-1: the last `rotary_dim` entries of D (default all) rotate as
+    interleaved pairs, with YaRN-scaled frequencies when factor != 1
+    (ops/llm_ops.py yarn_inv_freq)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="rotary_embedding", inputs={"X": x}, outputs={"Out": out},
+        attrs={"rotary_dim": int(rotary_dim or 0), "theta": float(theta),
+               "factor": float(factor),
+               "original_max_position": int(original_max_position),
+               "beta_fast": float(beta_fast),
+               "beta_slow": float(beta_slow), "mscale": float(mscale)})
+    return out
+
+
+def swiglu(gate, up, name=None):
+    """silu(gate) * up."""
+    helper = LayerHelper("swiglu", name=name)
+    out = helper.create_variable_for_type_inference(gate.dtype)
+    helper.append_op(type="swiglu", inputs={"Gate": gate, "Up": up},
+                     outputs={"Out": out})
+    return out
+
+
+def moe_route(input, n_experts, k, routed_scaling_factor=1.0,
+              norm_topk_prob=True, param_attr=None, bias_attr=None,
+              name=None):
+    """Sigmoid top-k router over ALL n_experts (DeepSeek-V3 2.1.2,
+    `noaux_tc` without a group limit): returns (topk_idx int32,
+    topk_weight float32), both [.., k].  The selection bias
+    (`<name>_bias.w`, zeros) is persistable and gets no gradient: it
+    selects and does not weigh."""
+    from paddle_tpu.initializer import Constant
+
+    helper = LayerHelper("moe_route", name=name)
+    c = int(input.shape[-1])
+    w = helper.create_parameter(_named(param_attr, name, ""),
+                                [c, n_experts], "float32")
+    battr = _named(bias_attr, name, "bias")
+    battr.trainable = False
+    bias = helper.create_parameter(battr, [n_experts], "float32",
+                                   default_initializer=Constant(0.0))
+    idx = helper.create_variable_for_type_inference("int32", True)
+    weight = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="moe_route", inputs={"X": input, "W": w, "Bias": bias},
+        outputs={"TopkIdx": idx, "TopkWeight": weight},
+        attrs={"k": int(k),
+               "routed_scaling_factor": float(routed_scaling_factor),
+               "norm_topk_prob": bool(norm_topk_prob)})
+    return idx, weight
+
+
+def moe_experts(input, topk_idx, topk_weight, held, width,
+                param_attr=None, block_m=None, impl=None, name=None):
+    """The part of a sparse SwiGLU feed-forward that the experts in
+    `held` (a list of expert ids: what this chip holds of the layer)
+    contribute: sum over a token's selected held experts of its gate
+    times the expert's SwiGLU, width `width`.  Weights are stacked
+    `[len(held), C, width]` (`<name>_gate.w`, `<name>_up.w`) and
+    `[len(held), width, C]` (`<name>_down.w`).  No token is dropped; a
+    selected expert that is not held adds nothing."""
+    helper = LayerHelper("moe_experts", name=name)
+    c, g = int(input.shape[-1]), len(held)
+
+    def stack(part, shape):
+        return helper.create_parameter(_named(param_attr, name, part),
+                                       shape, "float32")
+
+    wg, wu = stack("gate", [g, c, width]), stack("up", [g, c, width])
+    wd = stack("down", [g, width, c])
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="moe_experts",
+        inputs={"X": input, "TopkIdx": topk_idx, "TopkWeight": topk_weight,
+                "WGate": wg, "WUp": wu, "WDown": wd},
+        outputs={"Out": out},
+        attrs={"held": [int(e) for e in held], "block_m": int(block_m or 0),
+               "impl": impl or ""})
+    return out
+
+
+def mhc_pre(x, sinkhorn_iters=20, eps=1e-6, clamp_min=-30.0,
+            clamp_max=30.0, param_attr=None, name=None):
+    """Read half of a manifold-constrained hyper-connection.  x
+    [B, n, T, C]: n residual streams, stream-major.  Returns (u
+    [B, T, C], the sublayer's input; h_post [B, n, T]; h_res
+    [B, n, n, T]) for `mhc_post`.  Parameters: `<name>_norm.w` [nC]
+    ones, `<name>_phi.w` [nC, 2n + n^2], `<name>_alpha.w` [3] = 0.01,
+    `<name>_bias.w` [2n + n^2] = zeros then 8 I, so that h_res starts
+    at the identity to 1e-3."""
+    from paddle_tpu.initializer import Constant, NumpyArrayInitializer
+
+    helper = LayerHelper("mhc_pre", name=name)
+    n, c = int(x.shape[1]), int(x.shape[3])
+    width = 2 * n + n * n
+    norm = helper.create_parameter(
+        _named(None, name, "norm"), [n * c], "float32",
+        default_initializer=Constant(1.0))
+    phi = helper.create_parameter(_named(param_attr, name, "phi"),
+                                  [n * c, width], "float32")
+    alpha = helper.create_parameter(
+        _named(None, name, "alpha"), [3], "float32",
+        default_initializer=Constant(0.01))
+    bias0 = np.concatenate([np.zeros(2 * n), 8.0 * np.eye(n).reshape(-1)]
+                           ).astype(np.float32)
+    bias = helper.create_parameter(
+        _named(None, name, "bias"), [width], "float32",
+        default_initializer=NumpyArrayInitializer(bias0))
+    u = helper.create_variable_for_type_inference(x.dtype)
+    h_post = helper.create_variable_for_type_inference("float32")
+    h_res = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="mhc_pre",
+        inputs={"X": x, "NormScale": norm, "Phi": phi, "Alpha": alpha,
+                "Bias": bias},
+        outputs={"U": u, "HPost": h_post, "HRes": h_res},
+        attrs={"sinkhorn_iters": int(sinkhorn_iters), "eps": float(eps),
+               "clamp_min": float(clamp_min),
+               "clamp_max": float(clamp_max)})
+    return u, h_post, h_res
+
+
+def mhc_post(x, y, h_post, h_res, name=None):
+    """Write half: h_res x + outer(h_post, y), the streams after the
+    sublayer whose output is y [B, T, C]."""
+    helper = LayerHelper("mhc_post", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="mhc_post",
+        inputs={"X": x, "Y": y, "HPost": h_post, "HRes": h_res},
+        outputs={"Out": out})
+    return out

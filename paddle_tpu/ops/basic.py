@@ -69,11 +69,22 @@ def fill_constant(ins, attrs):
 
 @register_op("gaussian_random", inputs=(), outputs=("Out",),
              attrs={"shape": REQUIRED, "mean": 0.0, "std": 1.0, "seed": 0,
-                    "dtype": "float32"},
+                    "dtype": "float32", "fast": False},
              differentiable=False)
 def gaussian_random(ins, attrs):
+    """fast: float32 samples from a numpy Generator whose seed is ONE
+    draw from the stream `seed` names, instead of float64 samples from
+    that stream itself: 4-5x quicker, which counts at 10^8 parameters
+    and up.  Another sequence of numbers, so off unless asked for."""
     rng = _np_rng(attrs["seed"])
-    x = rng.normal(attrs["mean"], attrs["std"], size=tuple(attrs["shape"]))
+    shape = tuple(attrs["shape"])
+    if attrs["fast"]:
+        gen = np.random.default_rng(int(rng.randint(0, 2 ** 31 - 1)))
+        x = gen.standard_normal(shape, dtype=np.float32)
+        x *= np.float32(attrs["std"])
+        x += np.float32(attrs["mean"])
+    else:
+        x = rng.normal(attrs["mean"], attrs["std"], size=shape)
     return {"Out": jnp.asarray(x.astype(attrs["dtype"]))}
 
 

@@ -1,0 +1,409 @@
+"""Ops of the 2024-26 decoder block: RMSNorm, rotary embedding with YaRN
+frequencies, SwiGLU, the sigmoid top-k router with a selection bias, the
+grouped expert feed-forward over the experts this chip holds, and the
+two halves of a manifold-constrained hyper-connection (n residual
+streams mixed by a Sinkhorn-normalised matrix).
+
+Equations: docs/XING4_BLOCK.md.  Attention, routing and experts follow
+the DeepSeek-V3 report (arXiv:2412.19437, 2.1.1-2.1.2), the residual
+path mHC (arXiv:2512.24880) over Hyper-Connections (arXiv:2409.19606).
+models/xing4.py builds the block from these through layers/llm.py.
+
+Precision under AMP (contrib/mixed_precision): statistics, router
+scores, mixing coefficients and the Sinkhorn iterations are computed in
+float32 whatever the activations' dtype; only what is written back to
+the residual streams or handed to a matmul follows the input's dtype.
+
+Every compute runs under a jax.named_scope (pt_mla, pt_moe_route,
+pt_moe_experts, pt_mhc, pt_rms_norm, pt_swiglu) so that a reader of the
+compiled step's HLO metadata can tell the XLA fusions apart.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from paddle_tpu.core.registry import REQUIRED, register_op
+
+_F32 = jnp.float32
+_HIGHEST = lax.Precision.HIGHEST
+
+
+# ---------------------------------------------------------------------------
+# RMSNorm, SwiGLU
+# ---------------------------------------------------------------------------
+
+def _rms(xf, eps):
+    return xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                          + eps)
+
+
+@register_op("rms_norm", inputs=("X", "Scale"), outputs=("Y",),
+             attrs={"epsilon": 1e-6})
+def rms_norm(ins, attrs):
+    """Y = X / sqrt(mean(X^2, last axis) + epsilon) * Scale, the
+    statistic in float32, Y in X's dtype."""
+    x = ins["X"]
+    with jax.named_scope("pt_rms_norm"):
+        y = _rms(x.astype(_F32), attrs["epsilon"]) \
+            * ins["Scale"].astype(_F32)
+        return {"Y": y.astype(x.dtype)}
+
+
+@register_op("swiglu", inputs=("Gate", "Up"), outputs=("Out",))
+def swiglu(ins, attrs):
+    """Out = silu(Gate) * Up, in float32, written in Gate's dtype."""
+    g = ins["Gate"]
+    with jax.named_scope("pt_swiglu"):
+        out = jax.nn.silu(g.astype(_F32)) * ins["Up"].astype(_F32)
+        return {"Out": out.astype(g.dtype)}
+
+
+# ---------------------------------------------------------------------------
+# rotary embedding, YaRN frequencies
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def yarn_inv_freq(dim, theta, factor, original_max, beta_fast, beta_slow):
+    """Inverse frequencies [dim/2] of a rotary embedding scaled by YaRN
+    (Peng et al. 2023, as the deepseek_v3 modelling code computes them):
+    dimensions that turn more than beta_fast times over the original
+    context keep theta^(-2i/dim), those that turn fewer than beta_slow
+    times are divided by `factor`, and a linear ramp joins the two
+    correction dimensions.  factor 1 is the plain embedding."""
+    pos = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if factor == 1:
+        return (1.0 / pos).astype(np.float32)
+
+    def correction_dim(turns):
+        return dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                      # 1: extrapolate (unscaled)
+    inv = (1.0 / (factor * pos)) * (1.0 - keep) + (1.0 / pos) * keep
+    return inv.astype(np.float32)
+
+
+@register_op("rotary_embedding", inputs=("X",), outputs=("Out",),
+             attrs={"rotary_dim": 0, "theta": 10000.0, "factor": 1.0,
+                    "original_max_position": 4096, "beta_fast": 32.0,
+                    "beta_slow": 1.0, "mscale": 1.0})
+def rotary_embedding(ins, attrs):
+    """X [B, T, H, D]: rotates the LAST rotary_dim entries of D (0: all
+    of D) as interleaved pairs (x[2i], x[2i+1]) by the angle
+    position * inv_freq[i], positions 0..T-1 along axis 1; the leading
+    D - rotary_dim entries pass through.  The frequencies are a
+    constant built from the attributes (yarn_inv_freq); cos and sin are
+    multiplied by `mscale`."""
+    x = ins["X"]
+    d = x.shape[-1]
+    rd = attrs["rotary_dim"] or d
+    if rd % 2 or (d - rd) % 2:
+        raise ValueError("rotary_embedding: rotary_dim %d of %d must "
+                         "leave whole pairs on both sides" % (rd, d))
+    inv = yarn_inv_freq(rd, float(attrs["theta"]), float(attrs["factor"]),
+                        int(attrs["original_max_position"]),
+                        float(attrs["beta_fast"]),
+                        float(attrs["beta_slow"]))
+    with jax.named_scope("pt_mla"):
+        t = x.shape[1]
+        ang = jnp.arange(t, dtype=_F32)[:, None] * jnp.asarray(inv)[None]
+        # the pairs that pass through turn by the angle 0.  One product
+        # over all of D: a slice and a concatenate would each cost a
+        # copy of X forward and a padded copy of its gradient backward
+        keep = (d - rd) // 2
+        cos = jnp.pad(jnp.cos(ang) * attrs["mscale"], ((0, 0), (keep, 0)),
+                      constant_values=1.0)[None, :, None, :, None]
+        sin = jnp.pad(jnp.sin(ang) * attrs["mscale"], ((0, 0), (keep, 0))
+                      )[None, :, None, :, None]
+        pairs = x.astype(_F32).reshape(x.shape[:-1] + (d // 2, 2))
+        # (a, b) -> (a cos - b sin, a sin + b cos)
+        turned = jnp.flip(pairs, -1) * jnp.asarray([-1.0, 1.0], _F32)
+        out = pairs * cos + turned * sin
+        return {"Out": out.reshape(x.shape).astype(x.dtype)}
+
+
+# ---------------------------------------------------------------------------
+# router
+# ---------------------------------------------------------------------------
+
+@register_op("moe_route", inputs=("X", "W", "Bias"),
+             outputs=("TopkIdx", "TopkWeight"),
+             attrs={"k": REQUIRED, "routed_scaling_factor": 1.0,
+                    "norm_topk_prob": True})
+def moe_route(ins, attrs):
+    """Sigmoid scores over ALL experts, float32: s = sigmoid(X W).  The
+    k experts with the largest s + Bias are selected (the bias selects,
+    it does not weigh), their gates are routed_scaling_factor * s_e /
+    sum of the selected s (norm_topk_prob) or routed_scaling_factor *
+    s_e.  X [.., C], W [C, E], Bias [E] -> TopkIdx int32, TopkWeight
+    float32, both [.., k].  No group limit (n_group = topk_group = 1),
+    no capacity: nothing is dropped here."""
+    x, w = ins["X"], ins["W"]
+    with jax.named_scope("pt_moe_route"):
+        s = jax.nn.sigmoid(jnp.matmul(x.astype(_F32), w.astype(_F32),
+                                      precision=_HIGHEST))
+        _, idx = lax.top_k(s + ins["Bias"].astype(_F32), attrs["k"])
+        sel = jnp.take_along_axis(s, idx, axis=-1)
+        if attrs["norm_topk_prob"]:
+            sel = sel / jnp.sum(sel, axis=-1, keepdims=True)
+        return {"TopkIdx": idx.astype(jnp.int32),
+                "TopkWeight": sel * attrs["routed_scaling_factor"]}
+
+
+# ---------------------------------------------------------------------------
+# the experts this chip holds
+# ---------------------------------------------------------------------------
+
+def _group_layout(idx, held, tm):
+    """Where each token-expert pair goes when the pairs routed to held
+    experts are sorted by expert and every group is padded to whole
+    tiles of tm rows (at least one tile a group).
+
+    idx [N, k] int32 expert ids, held: tuple of the expert ids held.
+    Returns a dict: dest [N, k] the padded row of each pair (undefined
+    where not `mine`), mine [N, k] bool, row_pair [M] the pair (n * k +
+    j) that feeds each padded row (undefined where not row_live),
+    row_live [M] bool, tile_group [M / tm] int32, n_active [1] int32,
+    with M = (ceil(N k / tm) + G) * tm rows: the worst case, every pair
+    routed here."""
+    n, k = idx.shape
+    g = len(held)
+    p = n * k
+    n_tiles = -(-p // tm) + g
+    local = jnp.full(idx.shape, g, jnp.int32)
+    for j, e in enumerate(held):
+        local = jnp.where(idx == e, j, local)
+    key = local.reshape(p)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    groups = jnp.arange(g, dtype=jnp.int32)
+    sizes = jnp.sum((key[:, None] == groups[None]).astype(jnp.int32), 0)
+    tiles = jnp.maximum(-(-sizes // tm), 1)
+    start = jnp.cumsum(sizes) - sizes              # first sorted position
+    tile_end = jnp.cumsum(tiles)
+    pstart = (tile_end - tiles) * tm               # first padded row
+    n_active = tile_end[-1:]
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles, dtype=jnp.int32),
+                         side="right"), g - 1).astype(jnp.int32)
+    # rows -> pairs
+    row = jnp.arange(n_tiles * tm, dtype=jnp.int32)
+    row_group = tile_group[row // tm]
+    off = row - pstart[row_group]
+    row_live = (off < sizes[row_group]) & (row // tm < n_active[0])
+    row_pair = order[jnp.clip(start[row_group] + off, 0, p - 1)]
+    # pairs -> rows: the rank of a pair inside its group is its sorted
+    # position less the group's first
+    rank = jnp.argsort(order).astype(jnp.int32)    # inverse permutation
+    safe = jnp.minimum(key, g - 1)
+    dest = (pstart[safe] + rank - start[safe]).reshape(n, k)
+    return {"dest": dest, "mine": local < g, "row_pair": row_pair,
+            "row_live": row_live, "tile_group": tile_group,
+            "n_active": n_active.astype(jnp.int32)}
+
+
+def _gather_rows(x, index, live):
+    return jnp.where(live[:, None], jnp.take(x, index, axis=0), 0)
+
+
+def _silu_and_grad(h):
+    sig = jax.nn.sigmoid(h)
+    return h * sig, sig * (1 + h * (1 - sig))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _routed_experts(x, gate, wg, wu, wd, lay, k, tm, impl):
+    return _routed_fwd(x, gate, wg, wu, wd, lay, k, tm, impl)[0]
+
+
+def _routed_fwd(x, gate, wg, wu, wd, lay, k, tm, impl):
+    """x [N, C], gate [N, k] float32 -> sum over a token's held experts
+    of gate * SwiGLU_e(x), [N, C] in x's dtype.  Gathers only: a row
+    gather lays the tokens out by expert, three grouped matmuls run
+    over the tiles that hold rows, and each token gathers its k rows
+    back."""
+    from paddle_tpu.ops.pallas_gmm import gmm
+
+    tg, na = lay["tile_group"], lay["n_active"]
+    xs = _gather_rows(x, lay["row_pair"] // k, lay["row_live"])
+    hg = gmm(xs, wg, tg, na, tm, impl)
+    hu = gmm(xs, wu, tg, na, tm, impl)
+    act = (_silu_and_grad(hg.astype(_F32))[0] * hu.astype(_F32)) \
+        .astype(x.dtype)
+    ys = gmm(act, wd, tg, na, tm, impl)
+    picked = jnp.where(lay["mine"][..., None],
+                       jnp.take(ys, jnp.where(lay["mine"], lay["dest"], 0),
+                                axis=0).astype(_F32), 0.0)   # [N, k, C]
+    out = jnp.sum(picked * gate[..., None], axis=1).astype(x.dtype)
+    return out, (x, gate, wg, wu, wd, lay, xs, hg, hu, ys)
+
+
+def _routed_bwd(k, tm, impl, res, g_out):
+    from paddle_tpu.ops.pallas_gmm import gmm, tgmm
+
+    x, gate, wg, wu, wd, lay, xs, hg, hu, ys = res
+    tg, na = lay["tile_group"], lay["n_active"]
+    n_groups = wg.shape[0]
+    mine, dest = lay["mine"], jnp.where(lay["mine"], lay["dest"], 0)
+    gf = g_out.astype(_F32)
+    # d gate: each pair's expert output against the token's cotangent
+    picked = jnp.where(mine[..., None],
+                       jnp.take(ys, dest, axis=0).astype(_F32), 0.0)
+    d_gate = jnp.sum(picked * gf[:, None, :], axis=-1)
+    # d ys, by rows: the row's gate times its token's cotangent
+    row_gate = jnp.where(lay["row_live"],
+                         jnp.take(gate.reshape(-1), lay["row_pair"]), 0.0)
+    g_ys = (_gather_rows(gf, lay["row_pair"] // k, lay["row_live"])
+            * row_gate[:, None]).astype(x.dtype)
+    silu, dsilu = _silu_and_grad(hg.astype(_F32))
+    act = (silu * hu.astype(_F32)).astype(x.dtype)
+    d_wd = tgmm(act, g_ys, tg, na, tm, n_groups, impl)
+    g_act = gmm(g_ys, wd, tg, na, tm, impl, transpose_rhs=True) \
+        .astype(_F32)
+    g_hu = (g_act * silu).astype(x.dtype)
+    g_hg = (g_act * hu.astype(_F32) * dsilu).astype(x.dtype)
+    d_wg = tgmm(xs, g_hg, tg, na, tm, n_groups, impl)
+    d_wu = tgmm(xs, g_hu, tg, na, tm, n_groups, impl)
+    g_xs = gmm(g_hg, wg, tg, na, tm, impl, transpose_rhs=True) \
+        .astype(_F32) + gmm(g_hu, wu, tg, na, tm, impl,
+                            transpose_rhs=True).astype(_F32)
+    d_x = jnp.sum(jnp.where(mine[..., None],
+                            jnp.take(g_xs, dest, axis=0), 0.0),
+                  axis=1).astype(x.dtype)
+    return d_x, d_gate.astype(gate.dtype), d_wg, d_wu, d_wd, None
+
+
+_routed_experts.defvjp(_routed_fwd, _routed_bwd)
+
+
+@register_op("moe_experts",
+             inputs=("X", "TopkIdx", "TopkWeight", "WGate", "WUp", "WDown"),
+             outputs=("Out",),
+             attrs={"held": REQUIRED, "block_m": 0, "impl": ""})
+def moe_experts(ins, attrs):
+    """The routed experts' part of a sparse feed-forward that THIS chip
+    computes: Out = sum over the token's selected experts that are in
+    `held` of TopkWeight * W_down[e](silu(x W_gate[e]) * (x W_up[e])).
+
+    held: the expert ids whose weights the stacks WGate/WUp [G, C, W]
+    and WDown [G, W, C] hold, in stack order; the router ran over all
+    experts.  A selected expert that is not held adds nothing, and no
+    token is dropped: the token-expert pairs routed to held experts are
+    sorted by expert and go through three grouped matmuls
+    (ops/pallas_gmm.py) with a static worst-case number of rows and
+    run-time group sizes.  impl: "" (pallas on a TPU, xla elsewhere),
+    "pallas", "interpret", "xla"; block_m: rows a tile (0: 256)."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    x = ins["X"]
+    c = x.shape[-1]
+    idx = ins["TopkIdx"].reshape(-1, ins["TopkIdx"].shape[-1])
+    impl = attrs["impl"] or pk._auto_impl()
+    tm = int(attrs["block_m"] or 256)
+    pk._count_impl("moe_gmm", impl)
+    with jax.named_scope("pt_moe_experts"):
+        lay = _group_layout(idx, tuple(int(e) for e in attrs["held"]), tm)
+        gate = ins["TopkWeight"].reshape(idx.shape).astype(_F32)
+        dt = x.dtype
+        # see pallas_kernels._flash_attention_fwd: one call line
+        with pk._obs_device.annotate("moe_experts"), pk._kernel_scope():
+            out = _routed_experts(
+                x.reshape(-1, c), gate, ins["WGate"].astype(dt),
+                ins["WUp"].astype(dt), ins["WDown"].astype(dt), lay,
+                idx.shape[-1], tm, impl)
+        return {"Out": out.reshape(x.shape)}
+
+
+# ---------------------------------------------------------------------------
+# manifold-constrained hyper-connections: n residual streams
+# ---------------------------------------------------------------------------
+
+def sinkhorn(a, iters, eps, row_axis=-1, col_axis=-2):
+    """exp(a) with rows then columns divided by their sums (+ eps),
+    `iters` times; a row's entries lie along row_axis."""
+    m = jnp.exp(a)
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=row_axis, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=col_axis, keepdims=True) + eps)
+    return m
+
+
+@register_op("mhc_pre",
+             inputs=("X", "NormScale", "Phi", "Alpha", "Bias"),
+             outputs=("U", "HPost", "HRes"),
+             attrs={"sinkhorn_iters": 20, "eps": 1e-6,
+                    "clamp_min": -30.0, "clamp_max": 30.0})
+def mhc_pre(ins, attrs):
+    """The read half of a hyper-connection round a sublayer.  X
+    [B, n, T, C], the n residual streams, stream-major: the last two
+    axes are whole (sublane, lane) tiles, and a mix along n is
+    elementwise over n slabs.  With x~ = RMSNorm_eps(vec(X)) *
+    NormScale (vec over a token's n C entries, stream-major, [nC]) and
+    p = x~ Phi, Phi [nC, 2n + n^2] = [phi_pre | phi_post | phi_res],
+    Alpha [3], Bias [2n + n^2] in the same order:
+
+        H_pre  = sigmoid(Alpha[0] p[:n] + Bias[:n])
+        H_post = 2 sigmoid(Alpha[1] p[n:2n] + Bias[n:2n])
+        H_res  = sinkhorn(clip(Alpha[2] mat(p[2n:]) + mat(Bias[2n:])))
+        U      = H_pre X            the sublayer's input, [B, T, C]
+
+    all in float32; U is written in X's dtype; HPost [B, n, T] and HRes
+    [B, n, n, T] (HRes[b, i, j, t] weighs stream j in new stream i)
+    stay float32 for mhc_post, tokens on the lane axis: the 20
+    Sinkhorn rounds then work on [n, n, T] slabs instead of T padded
+    [n, n] tiles."""
+    x = ins["X"]
+    b, n, t, c = x.shape
+    with jax.named_scope("pt_mhc"):
+        xf = x.astype(_F32)
+        inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=(1, 3))
+                        + attrs["eps"])                        # [B, T]
+        # x~ Phi = rsqrt(..) * (X (NormScale * Phi)): X is read once
+        w = (ins["NormScale"].astype(_F32)[:, None]
+             * ins["Phi"].astype(_F32)).reshape(n, c, -1)
+        p = jnp.einsum("bntc,ncw->bwt", xf, w, precision=_HIGHEST) \
+            * inv[:, None, :]                                  # [B, W, T]
+        alpha, bias = ins["Alpha"].astype(_F32), ins["Bias"].astype(_F32)
+        h_pre = jax.nn.sigmoid(alpha[0] * p[:, :n] + bias[:n, None])
+        h_post = 2 * jax.nn.sigmoid(alpha[1] * p[:, n:2 * n]
+                                    + bias[n:2 * n, None])
+        raw = alpha[2] * p[:, 2 * n:].reshape(b, n, n, t) \
+            + bias[2 * n:].reshape(n, n)[..., None]
+        h_res = sinkhorn(jnp.clip(raw, attrs["clamp_min"],
+                                  attrs["clamp_max"]),
+                         attrs["sinkhorn_iters"], attrs["eps"],
+                         row_axis=2, col_axis=1)
+        u = jnp.sum(h_pre[..., None] * xf, axis=1)
+        return {"U": u.astype(x.dtype), "HPost": h_post, "HRes": h_res}
+
+
+@register_op("mhc_post", inputs=("X", "Y", "HPost", "HRes"),
+             outputs=("Out",))
+def mhc_post(ins, attrs):
+    """The write half: Out = H_res X + outer(H_post, Y), X [B, n, T, C],
+    Y [B, T, C] the sublayer's output, HPost [B, n, T], HRes
+    [B, n, n, T]; float32 arithmetic, Out in X's dtype."""
+    x = ins["X"]
+    with jax.named_scope("pt_mhc"):
+        xf = x.astype(_F32)
+        # n^2 multiply-adds a stream element on the VPU, written as one
+        # broadcast product summed over j: no slice of X, whose
+        # transpose would pad a gradient back to X's size n times
+        mixed = jnp.sum(ins["HRes"].astype(_F32)[..., None]
+                        * xf[:, None], axis=2)
+        out = mixed + ins["HPost"].astype(_F32)[..., None] \
+            * ins["Y"].astype(_F32)[:, None]
+        return {"Out": out.astype(x.dtype)}

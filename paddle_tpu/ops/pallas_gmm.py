@@ -1,0 +1,230 @@
+"""Grouped matrix multiplication over the experts one chip holds.
+
+The expert feed-forward of a sparse mixture routes every token to k of E
+experts; a chip that holds G of them computes, for the rows routed to
+its own experts, `rows_g @ W[g]` for each held expert g.  The rows are
+laid out sorted by expert with every group padded to whole tiles of
+`tm` rows (ops/llm_ops.py `_group_layout`), so a row tile belongs to
+exactly ONE expert and the kernels are plain tiled matmuls whose weight
+block is picked per tile from a scalar-prefetched table:
+
+    gmm    out[t]  = lhs[t] @ rhs[tile_group[t]]         pt_gmm_fwd
+    gmm^T  out[t]  = lhs[t] @ rhs[tile_group[t]]^T       pt_gmm_bwd_dx
+    tgmm   out[g]  = sum over tiles t of g: lhs[t]^T @ g[t]
+                                                         pt_gmm_bwd_dw
+
+The number of row tiles is static (worst case: every token-expert pair
+routed here), the number that hold rows is a run-time scalar: a tile
+past it is skipped, its blocks mapped onto the last active tile's so
+that nothing is fetched or written for it.  What such a tile's output
+rows hold is not defined; the caller never reads them.  No capacity,
+no dropped token, no [N, E, C] one-hot.
+
+Technique after the megablox grouped matmul of jax's Pallas TPU
+examples; tile-aligned groups make the row masks and the group-metadata
+pass of that kernel unnecessary.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+def _tile(dim, target):
+    """Largest multiple of 128 that divides `dim` and is <= target, else
+    the whole dimension (a block equal to the array's extent is legal
+    whatever its size)."""
+    best = None
+    for t in range(128, min(dim, target) + 1, 128):
+        if dim % t == 0:
+            best = t
+    return best or dim
+
+
+def _gmm_kernel(tg_ref, na_ref, x_ref, w_ref, o_ref, acc_ref, *,
+                transpose_rhs):
+    i, kk = pl.program_id(1), pl.program_id(2)
+    active = i < na_ref[0]
+
+    @pl.when(active & (kk == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(active)
+    def _accumulate():
+        dims = (((1,), (1,)), ((), ())) if transpose_rhs \
+            else (((1,), (0,)), ((), ()))
+        acc_ref[...] += lax.dot_general(
+            x_ref[...], w_ref[0], dims,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(active & (kk == pl.num_programs(2) - 1))
+    def _store():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _tgmm_kernel(tg_ref, na_ref, x_ref, g_ref, o_ref, acc_ref, *,
+                 n_tiles):
+    i = pl.program_id(2)
+    na = na_ref[0]
+    active = i < na
+    here = tg_ref[i]
+    first = (i == 0) | (tg_ref[jnp.maximum(i - 1, 0)] != here)
+    last = (i == na - 1) | (tg_ref[jnp.minimum(i + 1, n_tiles - 1)]
+                            != here)
+
+    @pl.when(active & first)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(active)
+    def _accumulate():
+        acc_ref[...] += lax.dot_general(
+            x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(active & last)
+    def _store():
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _params(interpret, semantics):
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics)}
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "tm", "transpose_rhs", "interpret"))
+def gmm_pallas(lhs, rhs, tile_group, n_active, tm, transpose_rhs=False,
+               interpret=False):
+    """lhs [M, K] (M a multiple of tm), rhs [G, K, N] (or [G, N, K] with
+    transpose_rhs), tile_group [M/tm] int32, n_active [1] int32 ->
+    [M, N] in lhs's dtype; rows of tiles past n_active undefined."""
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    n_tiles = m // tm
+    tn, tk = _tile(n, 1024), _tile(k, 512)
+
+    def tile(i, na):
+        return jnp.minimum(i, na[0] - 1)
+
+    def depth(i, kk, na):
+        # a skipped tile keeps the last active tile's last block: no fetch
+        return jnp.where(i < na[0], kk, k // tk - 1)
+
+    if transpose_rhs:
+        w_spec = pl.BlockSpec(
+            (1, tn, tk), lambda j, i, kk, tg, na:
+            (tg[tile(i, na)], j, depth(i, kk, na)))
+    else:
+        w_spec = pl.BlockSpec(
+            (1, tk, tn), lambda j, i, kk, tg, na:
+            (tg[tile(i, na)], depth(i, kk, na), j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n // tn, n_tiles, k // tk),
+        in_specs=[
+            pl.BlockSpec((tm, tk), lambda j, i, kk, tg, na:
+                         (tile(i, na), depth(i, kk, na))),
+            w_spec],
+        out_specs=pl.BlockSpec((tm, tn), lambda j, i, kk, tg, na:
+                               (tile(i, na), j)),
+        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        name="pt_gmm_bwd_dx" if transpose_rhs else "pt_gmm_fwd",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        interpret=interpret,
+        **_params(interpret, ("parallel", "arbitrary", "arbitrary")),
+    )(tile_group, n_active, lhs, rhs)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "tm", "n_groups", "interpret"))
+def tgmm_pallas(lhs, grad, tile_group, n_active, tm, n_groups,
+                interpret=False):
+    """lhs [M, K], grad [M, N] -> [G, K, N] in lhs's dtype: for each
+    group the sum over its tiles of lhs[t]^T @ grad[t].  Every group
+    has at least one tile (the layout's guarantee), so every output
+    block is written."""
+    m, k = lhs.shape
+    n = grad.shape[1]
+    n_tiles = m // tm
+    tk, tn = _tile(k, 512), _tile(n, 1024)
+
+    def tile(i, na):
+        return jnp.minimum(i, na[0] - 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(k // tk, n // tn, n_tiles),
+        in_specs=[
+            pl.BlockSpec((tm, tk), lambda kk, j, i, tg, na:
+                         (tile(i, na), kk)),
+            pl.BlockSpec((tm, tn), lambda kk, j, i, tg, na:
+                         (tile(i, na), j))],
+        out_specs=pl.BlockSpec((1, tk, tn), lambda kk, j, i, tg, na:
+                               (tg[tile(i, na)], kk, j)),
+        scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, n_tiles=n_tiles),
+        name="pt_gmm_bwd_dw",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_groups, k, n), lhs.dtype),
+        interpret=interpret,
+        **_params(interpret, ("parallel", "parallel", "arbitrary")),
+    )(tile_group, n_active, lhs, grad)
+
+
+# ---------------------------------------------------------------------------
+# the same three products in plain XLA on the same layout: what the
+# kernels are tested against, and the implementation off the chip
+# ---------------------------------------------------------------------------
+
+def gmm_xla(lhs, rhs, tile_group, n_active, tm, transpose_rhs=False):
+    m, k = lhs.shape
+    t = m // tm
+    w = rhs[tile_group]                       # [T, K, N] or [T, N, K]
+    spec = "tmk,tnk->tmn" if transpose_rhs else "tmk,tkn->tmn"
+    out = jnp.einsum(spec, lhs.reshape(t, tm, k), w,
+                     preferred_element_type=jnp.float32)
+    live = (jnp.arange(t) < n_active[0])[:, None, None]
+    return jnp.where(live, out, 0.0).astype(lhs.dtype).reshape(m, -1)
+
+
+def tgmm_xla(lhs, grad, tile_group, n_active, tm, n_groups):
+    m, k = lhs.shape
+    t = m // tm
+    per_tile = jnp.einsum("tmk,tmn->tkn", lhs.reshape(t, tm, k),
+                          grad.reshape(t, tm, -1),
+                          preferred_element_type=jnp.float32)
+    live = jnp.arange(t) < n_active[0]
+    own = (tile_group[:, None] == jnp.arange(n_groups)[None]) \
+        & live[:, None]
+    return jnp.einsum("tg,tkn->gkn", own.astype(jnp.float32),
+                      jnp.where(live[:, None, None], per_tile, 0.0)
+                      ).astype(lhs.dtype)
+
+
+def gmm(lhs, rhs, tile_group, n_active, tm, impl, transpose_rhs=False):
+    if impl == "xla":
+        return gmm_xla(lhs, rhs, tile_group, n_active, tm, transpose_rhs)
+    return gmm_pallas(lhs, rhs, tile_group, n_active, tm,
+                      transpose_rhs=transpose_rhs,
+                      interpret=impl == "interpret")
+
+
+def tgmm(lhs, grad, tile_group, n_active, tm, n_groups, impl):
+    if impl == "xla":
+        return tgmm_xla(lhs, grad, tile_group, n_active, tm, n_groups)
+    return tgmm_pallas(lhs, grad, tile_group, n_active, tm, n_groups,
+                       interpret=impl == "interpret")

@@ -11,7 +11,9 @@ accumulation in VMEM scratch — the [Tq, Tk] matrix never leaves VMEM
 (FlashAttention pattern).
 
 Layout: q/k/v are [B, H, T, D] (the transformer model's post-split-heads
-layout).  Grid is (B*H/hpb, Tq/block_q, Tk/block_k) with the KV
+layout); v, the output and its gradient may have a head size Dv of their
+own (latent attention: q.k 192, v 128).  Grid is (B*H/hpb, Tq/block_q,
+Tk/block_k) with the KV
 dimension innermost so the (acc, m, l) scratch carries across KV steps;
 hpb is the heads-per-block packing factor (1, or 2 under the
 `flash_head_pack` flag — see below).
@@ -344,14 +346,16 @@ def _pad_axis(x, axis, mult):
 def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                       interpret=False, packed_stats=False,
                       head_pack=False):
-    """q/k/v: [B, H, T, D] -> ([B, H, Tq, D], lse [B*H, Tq_padded])."""
+    """q/k: [B, H, T, D], v: [B, H, Tk, Dv] (Dv = D everywhere but in
+    latent attention, whose q.k size is 192 and v size 128) ->
+    ([B, H, Tq, Dv], lse [B*H, Tq_padded])."""
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     bq = min(block_q, max(tq, 8))
     bk = min(block_k, max(tk, 8))
     qp = _pad_axis(q.reshape(b * h, tq, d), 1, bq)
     kp = _pad_axis(k.reshape(b * h, tk, d), 1, bk)
-    vp = _pad_axis(v.reshape(b * h, tk, d), 1, bk)
+    vp = _pad_axis(v.reshape(b * h, tk, dv), 1, bk)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
     packed = packed_stats and _packed_geom_ok(bq)
     hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)) else 1
@@ -378,18 +382,18 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((hpb, bq, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((hpb, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((hpb, bk, d), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((hpb, bk, dv), lambda bh, i, j: (bh, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((hpb, bq, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((hpb, bq, dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec(lse_block, lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, tq_p, dv), q.dtype),
             jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((hpb, bq, d), jnp.float32),
+            pltpu.VMEM((hpb, bq, dv), jnp.float32),
             pltpu.VMEM((hpb, bq, _MIN_LANES), jnp.float32),
             pltpu.VMEM((hpb, bq, _MIN_LANES), jnp.float32),
         ],
@@ -400,7 +404,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     # packed unpacks with a free row-major reshape at the XLA boundary,
     # replicated strips the lanes
     lse2 = lse.reshape(b * h, tq_p) if packed else lse[:, :, 0]
-    return (out[:, :tq, :].reshape(b, h, tq, d), lse2)
+    return (out[:, :tq, :].reshape(b, h, tq, dv), lse2)
 
 
 # ---------------------------------------------------------------------------
@@ -563,8 +567,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
                       block_k, interpret=False, dlse=None,
                       packed_stats=False, head_pack=False):
-    """q/k/v: [B, H, T, D]; lse: [B*H, Tq] or q-block padded, as the
-    forward kernel returns it; g = dO.
+    """q/k: [B, H, T, D], v, o and g = dO: [.., Dv]; lse: [B*H, Tq] or
+    q-block padded, as the forward kernel returns it.
 
     dlse ([B*H, Tq] or None): cotangent of the lse output when the
     caller consumes it (ring attention's cross-chunk merge).  Since
@@ -578,13 +582,13 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     the third, the seq-1M OOM).
     """
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     bq = min(block_q, max(tq, 8))
     bk = min(block_k, max(tk, 8))
     qp = _pad_axis(q.reshape(b * h, tq, d), 1, bq)
     kp = _pad_axis(k.reshape(b * h, tk, d), 1, bk)
-    vp = _pad_axis(v.reshape(b * h, tk, d), 1, bk)
-    gp = _pad_axis(g.reshape(b * h, tq, d), 1, bq)
+    vp = _pad_axis(v.reshape(b * h, tk, dv), 1, bk)
+    gp = _pad_axis(g.reshape(b * h, tq, dv), 1, bq)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
     # rows past tq are masked in the kernels: what they hold is not read
     lse = _pad_axis(lse, 1, bq)
@@ -627,13 +631,15 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     qspec = pl.BlockSpec((hpb, bq, d), lambda bh, i, j: (bh, i, 0))
+    gspec = pl.BlockSpec((hpb, bq, dv), lambda bh, i, j: (bh, i, 0))
     lspec = pl.BlockSpec(lblk, lambda bh, i, j: (bh, i, 0))
     kspec = pl.BlockSpec((hpb, bk, d), lambda bh, i, j: (bh, j, 0))
+    vspec = pl.BlockSpec((hpb, bk, dv), lambda bh, i, j: (bh, j, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         name="pt_flash_bwd_dq",
         grid=(b * h // hpb, tq_p // bq, tk_p // bk),
-        in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
+        in_specs=[qspec, kspec, vspec, gspec, lspec, lspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((hpb, bq, d), jnp.float32)],
@@ -644,26 +650,28 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     # dkv grid: kv blocks outer, q blocks inner (accumulator carries
     # across the q sweep); block index maps swap i<->j roles
     qspec2 = pl.BlockSpec((hpb, bq, d), lambda bh, j, i: (bh, i, 0))
+    gspec2 = pl.BlockSpec((hpb, bq, dv), lambda bh, j, i: (bh, i, 0))
     lspec2 = pl.BlockSpec(lblk, lambda bh, j, i: (bh, i, 0))
     kspec2 = pl.BlockSpec((hpb, bk, d), lambda bh, j, i: (bh, j, 0))
-    dk, dv = pl.pallas_call(
+    vspec2 = pl.BlockSpec((hpb, bk, dv), lambda bh, j, i: (bh, j, 0))
+    dk, dv_ = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
         name="pt_flash_bwd_dkv",
         grid=(b * h // hpb, tk_p // bk, tq_p // bq),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, lspec2, lspec2],
-        out_specs=[kspec2, kspec2],
+        in_specs=[qspec2, kspec2, vspec2, gspec2, lspec2, lspec2],
+        out_specs=[kspec2, vspec2],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk_p, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, tk_p, dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((hpb, bk, d), jnp.float32),
-                        pltpu.VMEM((hpb, bk, d), jnp.float32)],
+                        pltpu.VMEM((hpb, bk, dv), jnp.float32)],
         interpret=interpret,
         **params,
     )(qp, kp, vp, gp, lse3, delta3)
     return (dq[:, :tq, :].reshape(b, h, tq, d),
             dk[:, :tk, :].reshape(b, h, tk, d),
-            dv[:, :tk, :].reshape(b, h, tk, d))
+            dv_[:, :tk, :].reshape(b, h, tk, dv))
 
 
 # ---------------------------------------------------------------------------
@@ -746,7 +754,9 @@ def _default_block(t):
 def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
                     block_k=None, impl=None, packed_stats=None,
                     head_pack=None):
-    """Fused attention. q/k/v: [B, H, T, D]; returns [B, H, Tq, D].
+    """Fused attention. q/k: [B, H, T, D], v: [B, H, Tk, Dv]; returns
+    [B, H, Tq, Dv].  Dv = D everywhere but in latent attention (q.k 192
+    = 128 + 64 rotary, v 128): the three kernels take the two sizes.
 
     impl: None (auto: pallas on TPU, XLA elsewhere), "pallas",
     "interpret" (pallas interpret mode, for CPU tests), or "xla".

@@ -35,6 +35,41 @@ def _flash(shape, grad):
     return (bwd if grad else fwd), (_sds(shape),) * 3, 3 if grad else 1
 
 
+def _flash_mla(grad, shape=(1, 32, 4096)):
+    """Latent attention's two head sizes: q.k 192 (128 + 64 rotary),
+    v 128; the forward and the saved-residual backward kernels."""
+    from paddle_tpu.ops.pallas_kernels import (_flash_attention_bwd,
+                                               _flash_attention_fwd)
+
+    b, h, t = shape
+    q, v = _sds((b, h, t, 192)), _sds((b, h, t, 128))
+    call = dict(causal=True, scale=0.1, impl="pallas")
+    if not grad:
+        return (lambda q, k, v: _flash_attention_fwd(q, k, v, **call)), \
+            (q, q, v), 1
+    return (lambda q, k, v, o, lse, g: _flash_attention_bwd(
+        q, k, v, o, lse, g, **call)), \
+        (q, q, v, v, _sds((b, h, t), jnp.float32), v), 2
+
+
+def _gmm(which, rows=16384, held=8, hidden=3584, width=1024, tm=256):
+    """The grouped matmuls of 8 held experts at the worst-case number
+    of rows (4096 tokens x 4 experts each)."""
+    from paddle_tpu.ops.pallas_gmm import gmm_pallas, tgmm_pallas
+
+    m = (rows // tm + held) * tm
+    maps = (_sds((m // tm,), jnp.int32), _sds((1,), jnp.int32))
+    if which == "fwd":
+        return (lambda x, w, tg, na: gmm_pallas(x, w, tg, na, tm)), \
+            (_sds((m, hidden)), _sds((held, hidden, width))) + maps, 1
+    if which == "dx":
+        return (lambda g, w, tg, na: gmm_pallas(
+            g, w, tg, na, tm, transpose_rhs=True)), \
+            (_sds((m, width)), _sds((held, hidden, width))) + maps, 1
+    return (lambda x, g, tg, na: tgmm_pallas(x, g, tg, na, tm, held)), \
+        (_sds((m, hidden)), _sds((m, width))) + maps, 1
+
+
 def _decode(head_dim, head_pack, batch=64, heads=8, page_size=128,
             max_pages=4):
     from paddle_tpu.ops.pallas_kernels import flash_decode
@@ -76,6 +111,11 @@ CASES = {
     "flash_bwd_32x8x512x64": lambda: _flash((32, 8, 512, 64), True),
     "flash_fwd_1x8x32768x128": lambda: _flash((1, 8, 32768, 128), False),
     "flash_bwd_1x8x32768x128": lambda: _flash((1, 8, 32768, 128), True),
+    "flash_fwd_1x32x4096_qk192_v128": lambda: _flash_mla(False),
+    "flash_bwd_saved_1x32x4096_qk192_v128": lambda: _flash_mla(True),
+    "gmm_fwd_8x3584x1024_rows16384": lambda: _gmm("fwd"),
+    "gmm_bwd_dx_8x3584x1024_rows16384": lambda: _gmm("dx"),
+    "gmm_bwd_dw_8x3584x1024_rows16384": lambda: _gmm("dw"),
     "flash_decode_d128_b64": lambda: _decode(128, False),
     "flash_decode_d64_headpacked_b64": lambda: _decode(64, True),
     "conv2d_epilogue_3x3_56x56x64_mb128": lambda: _conv(False),

@@ -1,0 +1,531 @@
+"""The ops of the 2024-26 decoder block (ops/llm_ops.py, ops/pallas_gmm.py,
+the flash kernels at two head sizes), one at a time, against plain
+numpy / jax.numpy written here.
+
+Tolerances, and why.  Everything the ops state as float32 (norm
+statistics, router scores and gates, the mixing coefficients and the
+Sinkhorn) is held to 1e-5 or tighter: bfloat16 there would be off by
+2^-8 = 3.9e-3, so a rounding to bf16 anywhere in those paths fails the
+case.  Kernels in interpret mode run the same float32 arithmetic as
+the XLA form in another order: 1e-5.  bf16 operands round once on the
+way out: one bf16 ulp, 2^-7 relative at worst.
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.core.registry import get_op_def
+
+F32_TOL = 1e-5
+BF16_ULP = 2.0 ** -7
+
+
+def _op(name, ins, **attrs):
+    od = get_op_def(name)
+    return od.compute(ins, od.canonical_attrs(attrs))
+
+
+# -- RMSNorm, SwiGLU ---------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_rms_norm_statistic_is_float32(dtype):
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(0, 3, (4, 7, 96)), dtype)
+    scale = jnp.asarray(rng.uniform(0.5, 1.5, 96), jnp.float32)
+    y = _op("rms_norm", {"X": x, "Scale": scale}, epsilon=1e-6)["Y"]
+    assert y.dtype == x.dtype
+    xf = np.asarray(x, np.float64)
+    want = xf / np.sqrt((xf ** 2).mean(-1, keepdims=True) + 1e-6) \
+        * np.asarray(scale, np.float64)
+    tol = F32_TOL if dtype == "float32" else BF16_ULP
+    np.testing.assert_allclose(np.asarray(y, np.float64), want, rtol=tol,
+                               atol=tol * 1e-2)
+
+
+def test_swiglu():
+    rng = np.random.default_rng(1)
+    g, u = (jnp.asarray(rng.normal(0, 2, (5, 64)), jnp.float32)
+            for _ in range(2))
+    out = _op("swiglu", {"Gate": g, "Up": u})["Out"]
+    gf = np.asarray(g, np.float64)
+    np.testing.assert_allclose(
+        out, gf / (1 + np.exp(-gf)) * np.asarray(u, np.float64),
+        rtol=F32_TOL, atol=1e-6)
+
+
+# -- rotary embedding with YaRN frequencies ----------------------------------
+
+def _yarn_by_hand(dim, theta, factor, orig, beta_fast, beta_slow):
+    """Peng et al. 2023 as deepseek_v3 computes it, dimension by
+    dimension."""
+    def correction(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
+    out = []
+    for i in range(dim // 2):
+        plain = theta ** (-2.0 * i / dim)
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        out.append(plain * (1 - ramp) + plain / factor * ramp)
+    return np.array(out), low, high
+
+
+def test_yarn_frequencies():
+    from paddle_tpu.ops.llm_ops import yarn_inv_freq
+
+    got = yarn_inv_freq(64, 10000.0, 64.0, 4096, 32.0, 1.0)
+    want, low, high = _yarn_by_hand(64, 10000.0, 64.0, 4096, 32.0, 1.0)
+    # the published numbers: the ramp runs from dimension 10 to 23
+    assert (low, high) == (10, 23)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    # fast dimensions are untouched, slow ones divided by the factor
+    assert got[0] == pytest.approx(1.0)
+    assert got[31] == pytest.approx(10000.0 ** (-62 / 64) / 64, rel=1e-6)
+    # factor 1: the plain embedding
+    np.testing.assert_allclose(
+        yarn_inv_freq(64, 10000.0, 1.0, 4096, 32.0, 1.0),
+        10000.0 ** (-np.arange(0, 64, 2) / 64), rtol=1e-6)
+
+
+@pytest.mark.parametrize("rotary_dim", [0, 8])
+def test_rotary_rotates_interleaved_pairs(rotary_dim):
+    from paddle_tpu.ops.llm_ops import yarn_inv_freq
+
+    rng = np.random.default_rng(2)
+    b, t, h, d = 2, 12, 3, 24
+    x = jnp.asarray(rng.normal(0, 1, (b, t, h, d)), jnp.float32)
+    kw = dict(theta=10000.0, factor=64.0, original_max_position=16,
+              beta_fast=32.0, beta_slow=1.0)
+    out = np.asarray(_op("rotary_embedding", {"X": x},
+                         rotary_dim=rotary_dim, **kw)["Out"])
+    rd = rotary_dim or d
+    inv = yarn_inv_freq(rd, 10000.0, 64.0, 16, 32.0, 1.0)
+    xn = np.asarray(x)
+    want = xn.copy()
+    for pos in range(t):
+        for i in range(rd // 2):
+            a, c = xn[:, pos, :, d - rd + 2 * i], \
+                xn[:, pos, :, d - rd + 2 * i + 1]
+            ang = pos * inv[i]
+            want[:, pos, :, d - rd + 2 * i] = \
+                a * np.cos(ang) - c * np.sin(ang)
+            want[:, pos, :, d - rd + 2 * i + 1] = \
+                a * np.sin(ang) + c * np.cos(ang)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    # position 0 is the identity, the leading entries pass through
+    np.testing.assert_array_equal(out[:, 0], xn[:, 0])
+    np.testing.assert_array_equal(out[..., :d - rd], xn[..., :d - rd])
+    # a rotation keeps the norm of every pair, and q.k depends on the
+    # distance only
+    np.testing.assert_allclose((out ** 2).sum(-1), (xn ** 2).sum(-1),
+                               rtol=1e-5)
+
+
+# -- router ------------------------------------------------------------------
+
+def _route(x, w, bias, **kw):
+    out = _op("moe_route", {"X": x, "W": w, "Bias": bias}, **kw)
+    return np.asarray(out["TopkIdx"]), np.asarray(out["TopkWeight"])
+
+
+def test_router_bias_selects_and_does_not_weigh():
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(0, 1, (64, 32)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 0.3, (32, 16)), jnp.float32)
+    zero = jnp.zeros(16, jnp.float32)
+    kw = dict(k=4, routed_scaling_factor=2.0, norm_topk_prob=True)
+    idx0, wt0 = _route(x, w, zero, **kw)
+    s = 1 / (1 + np.exp(-np.asarray(x, np.float64) @ np.asarray(w)))
+    # selection: the 4 largest scores; gates: the scores, normalised
+    want_idx = np.argsort(-s, axis=-1, kind="stable")[:, :4]
+    np.testing.assert_array_equal(np.sort(idx0, -1), np.sort(want_idx, -1))
+    picked = np.take_along_axis(s, idx0, -1)
+    np.testing.assert_allclose(wt0, 2.0 * picked / picked.sum(-1,
+                                                              keepdims=True),
+                               rtol=F32_TOL)
+    # the gates of a token sum to routed_scaling_factor, in float32
+    np.testing.assert_allclose(wt0.sum(-1), 2.0, rtol=1e-6)
+    assert wt0.dtype == np.float32 and idx0.dtype == np.int32
+    # a bias that lifts expert 5 above everything selects it everywhere
+    bias = zero.at[5].set(10.0)
+    idx1, wt1 = _route(x, w, bias, **kw)
+    assert (idx1 == 5).any(-1).all()
+    # and weighs nothing: the gates are still the raw scores normalised
+    picked1 = np.take_along_axis(s, idx1, -1)
+    np.testing.assert_allclose(
+        wt1, 2.0 * picked1 / picked1.sum(-1, keepdims=True), rtol=F32_TOL)
+    # without normalisation the gate is scaling x score
+    _, wt2 = _route(x, w, zero, k=4, routed_scaling_factor=2.0,
+                    norm_topk_prob=False)
+    np.testing.assert_allclose(wt2, 2.0 * picked, rtol=F32_TOL)
+
+
+def test_router_scores_ignore_the_activation_dtype():
+    """Under AMP the router's input arrives rounded to bf16; the scores
+    of that input are still float32: the gates sum to the scaling
+    factor to 1e-6, which bf16 scores (2^-8) would not."""
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(0, 1, (32, 64)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(0, 0.1, (64, 8)), jnp.float32)
+    _, wt = _route(x, w, jnp.zeros(8), k=2, routed_scaling_factor=2.0,
+                   norm_topk_prob=True)
+    np.testing.assert_allclose(wt.sum(-1), 2.0, rtol=1e-6)
+
+
+# -- the held experts ----------------------------------------------------------
+
+def _experts_case(seed, n=96, c=128, w=256, e=8, k=2, held=(2, 5, 7),
+                  empty=None, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    idx = np.stack([rng.permutation(e)[:k] for _ in range(n)]) \
+        .astype(np.int32)
+    if empty is not None:          # an expert nobody is routed to
+        idx = np.where(idx == empty, (empty + 1) % e, idx)
+    g = len(held)
+    return {
+        "X": jnp.asarray(rng.normal(0, 1, (n, c)), dtype),
+        "TopkIdx": jnp.asarray(idx),
+        "TopkWeight": jnp.asarray(rng.uniform(0.2, 1, (n, k)), jnp.float32),
+        "WGate": jnp.asarray(rng.normal(0, 0.1, (g, c, w)), dtype),
+        "WUp": jnp.asarray(rng.normal(0, 0.1, (g, c, w)), dtype),
+        "WDown": jnp.asarray(rng.normal(0, 0.1, (g, w, c)), dtype)}
+
+
+def _dense_experts(ins, held):
+    """A loop over the held experts with a mask over all tokens."""
+    x = ins["X"].astype(jnp.float32)
+    y = jnp.zeros_like(x)
+    for slot, e in enumerate(held):
+        gate = jnp.where(ins["TopkIdx"] == e, ins["TopkWeight"], 0).sum(-1)
+        h = jax.nn.silu(x @ ins["WGate"][slot].astype(jnp.float32)) \
+            * (x @ ins["WUp"][slot].astype(jnp.float32))
+        y = y + gate[:, None] * (h @ ins["WDown"][slot].astype(jnp.float32))
+    return y
+
+
+DIFF = ("X", "TopkWeight", "WGate", "WUp", "WDown")
+
+
+@pytest.mark.parametrize("impl,block_m,empty", [
+    ("xla", 32, None), ("interpret", 32, None), ("interpret", 128, None),
+    ("interpret", 32, 5), ("xla", 64, 5)])
+def test_moe_experts_forward_and_gradients(impl, block_m, empty):
+    held = (2, 5, 7)
+    ins = _experts_case(1, held=held, empty=empty)
+
+    def run(diff):
+        out = _op("moe_experts", {**ins, **diff}, held=list(held),
+                  block_m=block_m, impl=impl)["Out"]
+        return (out * jnp.sin(out)).sum(), out
+
+    def ref(diff):
+        out = _dense_experts({**ins, **diff}, held)
+        return (out * jnp.sin(out)).sum(), out
+
+    diff = {k: ins[k] for k in DIFF}
+    (_, out), grads = jax.value_and_grad(run, has_aux=True)(diff)
+    (_, want), want_grads = jax.value_and_grad(ref, has_aux=True)(diff)
+    np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
+    for k in DIFF:
+        scale = float(jnp.abs(want_grads[k]).max())
+        np.testing.assert_allclose(grads[k], want_grads[k], rtol=1e-4,
+                                   atol=1e-5 * scale, err_msg=k)
+    # a token none of whose experts is held gets exactly nothing
+    mine = np.isin(np.asarray(ins["TopkIdx"]), held).any(-1)
+    assert (~mine).any()
+    assert not np.asarray(out)[~mine].any()
+
+
+def test_moe_experts_drops_no_token_when_all_go_to_one_expert():
+    """The worst case the static row count covers: every pair routed
+    to ONE held expert."""
+    ins = _experts_case(2, n=64, k=2, e=4, held=(0, 1))
+    ins["TopkIdx"] = jnp.zeros((64, 2), jnp.int32).at[:, 1].set(3)
+    out = _op("moe_experts", ins, held=[0, 1], block_m=32,
+              impl="interpret")["Out"]
+    np.testing.assert_allclose(out, _dense_experts(ins, (0, 1)),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_moe_experts_bf16_operands():
+    ins = _experts_case(3, dtype=jnp.bfloat16)
+    out = _op("moe_experts", ins, held=[2, 5, 7], block_m=32,
+              impl="interpret")["Out"]
+    assert out.dtype == jnp.bfloat16
+    want = _dense_experts(ins, (2, 5, 7))
+    # three bf16 roundings between the products (h, act, y)
+    np.testing.assert_allclose(np.asarray(out, np.float32), want,
+                               rtol=4 * BF16_ULP,
+                               atol=BF16_ULP * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("transpose_rhs", [False, True])
+def test_gmm_kernel_skips_inactive_tiles(transpose_rhs):
+    from paddle_tpu.ops import pallas_gmm as pg
+
+    rng = np.random.default_rng(5)
+    tm, tiles, g, k, n = 16, 6, 3, 256, 128
+    lhs = jnp.asarray(rng.normal(0, 1, (tiles * tm, k)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(0, 1, (g, n, k) if transpose_rhs
+                                 else (g, k, n)), jnp.float32)
+    tile_group = jnp.asarray([0, 0, 1, 2, 2, 2], jnp.int32)
+    n_active = jnp.asarray([4], jnp.int32)
+    got = pg.gmm_pallas(lhs, rhs, tile_group, n_active, tm,
+                        transpose_rhs=transpose_rhs, interpret=True)
+    want = pg.gmm_xla(lhs, rhs, tile_group, n_active, tm, transpose_rhs)
+    live = 4 * tm
+    np.testing.assert_allclose(got[:live], want[:live], rtol=1e-5,
+                               atol=1e-4)
+    by_hand = np.asarray(lhs[2 * tm:3 * tm]) @ (
+        np.asarray(rhs[1]).T if transpose_rhs else np.asarray(rhs[1]))
+    np.testing.assert_allclose(got[2 * tm:3 * tm], by_hand, rtol=1e-4,
+                               atol=1e-4)
+
+
+def test_tgmm_kernel_sums_a_groups_tiles():
+    from paddle_tpu.ops import pallas_gmm as pg
+
+    rng = np.random.default_rng(6)
+    tm, tiles, g, k, n = 16, 6, 3, 128, 256
+    lhs = jnp.asarray(rng.normal(0, 1, (tiles * tm, k)), jnp.float32)
+    grad = jnp.asarray(rng.normal(0, 1, (tiles * tm, n)), jnp.float32)
+    tile_group = jnp.asarray([0, 0, 1, 2, 2, 2], jnp.int32)
+    n_active = jnp.asarray([5], jnp.int32)      # the last tile is skipped
+    got = pg.tgmm_pallas(lhs, grad, tile_group, n_active, tm, g,
+                         interpret=True)
+    want = pg.tgmm_xla(lhs, grad, tile_group, n_active, tm, g)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    rows = slice(3 * tm, 5 * tm)                # group 2's active tiles
+    np.testing.assert_allclose(
+        got[2], np.asarray(lhs[rows]).T @ np.asarray(grad[rows]),
+        rtol=1e-4, atol=1e-4)
+
+
+# -- hyper-connections ---------------------------------------------------------
+
+@pytest.mark.parametrize("iters", [5, 20])
+def test_sinkhorn_is_doubly_stochastic(iters):
+    from paddle_tpu.ops.llm_ops import sinkhorn
+
+    rng = np.random.default_rng(7)
+    a = jnp.asarray(rng.normal(0, 1, (50, 4, 4)), jnp.float32)
+    m = np.asarray(sinkhorn(a, iters, 1e-6))
+    # columns are normalised last: exact in float32 (bf16 would be off
+    # by 4e-3); rows converge geometrically: 20 rounds reach float32's
+    # resolution on a generic matrix, 5 do not
+    np.testing.assert_allclose(m.sum(-2), 1.0, atol=1e-5)
+    np.testing.assert_allclose(m.sum(-1), 1.0,
+                               atol=1e-5 if iters == 20 else 5e-2)
+    # as a layer starts (8 I plus a small perturbation) the matrix is
+    # the identity to 1e-3 and doubly stochastic to 1e-4: the diagonal
+    # dominance that keeps it there also slows the iteration down
+    near = np.asarray(sinkhorn(
+        jnp.asarray(rng.normal(0, 0.01, (50, 4, 4)), jnp.float32)
+        + 8 * jnp.eye(4), iters, 1e-6))
+    np.testing.assert_allclose(near.sum(-1), 1.0, atol=1e-4)
+    np.testing.assert_allclose(near, np.broadcast_to(np.eye(4), near.shape),
+                               atol=2e-3)
+    assert (m > 0).all()
+
+
+def _mhc_by_hand(x, norm, phi, alpha, bias, iters, eps, lo, hi):
+    """x [B, T, n, C] token-major, as the equations are written;
+    returns u [B, T, C], h_post [B, T, n], h_res [B, T, n, n]."""
+    x = np.asarray(x, np.float64)
+    b, t, n, c = x.shape
+    flat = x.reshape(b, t, n * c)
+    flat = flat / np.sqrt((flat ** 2).mean(-1, keepdims=True) + eps) * norm
+    p = flat @ phi
+    sig = lambda z: 1 / (1 + np.exp(-z))                      # noqa: E731
+    h_pre = sig(alpha[0] * p[..., :n] + bias[:n])
+    h_post = 2 * sig(alpha[1] * p[..., n:2 * n] + bias[n:2 * n])
+    m = np.exp(np.clip(alpha[2] * p[..., 2 * n:].reshape(b, t, n, n)
+                       + bias[2 * n:].reshape(n, n), lo, hi))
+    for _ in range(iters):
+        m = m / (m.sum(-1, keepdims=True) + eps)
+        m = m / (m.sum(-2, keepdims=True) + eps)
+    return np.einsum("btn,btnc->btc", h_pre, x), h_post, m
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_mhc_pre_and_post(dtype):
+    rng = np.random.default_rng(8)
+    b, t, n, c = 2, 6, 4, 32
+    width = 2 * n + n * n
+    # the ops take the streams stream-major, [B, n, T, C]
+    x = jnp.asarray(rng.normal(0, 1, (b, n, t, c)), dtype)
+    token_major = jnp.transpose(x.astype(jnp.float32), (0, 2, 1, 3))
+    norm = rng.uniform(0.5, 1.5, n * c).astype(np.float32)
+    phi = rng.normal(0, 0.5, (n * c, width)).astype(np.float32)
+    alpha = np.array([0.3, 0.2, 0.5], np.float32)
+    bias = np.concatenate([rng.normal(0, 1, 2 * n),
+                           3 * np.eye(n).reshape(-1)]).astype(np.float32)
+    out = _op("mhc_pre", {"X": x, "NormScale": jnp.asarray(norm),
+                          "Phi": jnp.asarray(phi),
+                          "Alpha": jnp.asarray(alpha),
+                          "Bias": jnp.asarray(bias)},
+              sinkhorn_iters=20, eps=1e-6, clamp_min=-30.0,
+              clamp_max=30.0)
+    u, h_post, h_res = _mhc_by_hand(token_major, norm, phi, alpha, bias,
+                                    20, 1e-6, -30, 30)
+    # the coefficients are float32 whatever the streams' dtype, tokens
+    # last: HPost [B, n, T], HRes [B, i, j, T]
+    assert out["HPost"].dtype == out["HRes"].dtype == jnp.float32
+    assert out["HPost"].shape == (b, n, t)
+    assert out["HRes"].shape == (b, n, n, t) and out["U"].dtype == x.dtype
+    got_post = np.transpose(np.asarray(out["HPost"]), (0, 2, 1))
+    got_res = np.transpose(np.asarray(out["HRes"]), (0, 3, 1, 2))
+    np.testing.assert_allclose(got_post, h_post, rtol=F32_TOL)
+    np.testing.assert_allclose(got_res, h_res, rtol=1e-4, atol=1e-7)
+    # columns sum to 1 in float32; bf16 coefficients would miss by 4e-3
+    np.testing.assert_allclose(got_res.sum(-2), 1.0, atol=1e-5)
+    tol = F32_TOL if dtype == "float32" else BF16_ULP
+    np.testing.assert_allclose(np.asarray(out["U"], np.float64), u,
+                               rtol=tol, atol=tol)
+    y = jnp.asarray(rng.normal(0, 1, (b, t, c)), dtype)
+    mixed = _op("mhc_post", {"X": x, "Y": y, "HPost": out["HPost"],
+                             "HRes": out["HRes"]})["Out"]
+    xf, yf = np.asarray(token_major, np.float64), np.asarray(y, np.float64)
+    want = np.einsum("btij,btjc->btic", h_res, xf) \
+        + h_post[..., None] * yf[:, :, None, :]
+    assert mixed.dtype == x.dtype and mixed.shape == x.shape
+    np.testing.assert_allclose(
+        np.transpose(np.asarray(mixed, np.float64), (0, 2, 1, 3)), want,
+        rtol=tol, atol=tol)
+
+
+def test_mhc_clamp_acts_before_exp():
+    """Pre-activations of +-1000 would overflow exp; clamped to +-30
+    they give a finite doubly stochastic matrix."""
+    n, c = 4, 8
+    x = jnp.ones((1, n, 1, c), jnp.float32)
+    bias = np.zeros(2 * n + n * n, np.float32)
+    bias[2 * n:] = (2000 * np.eye(n) - 1000).reshape(-1)
+    out = _op("mhc_pre", {"X": x, "NormScale": jnp.ones(n * c),
+                          "Phi": jnp.zeros((n * c, 2 * n + n * n)),
+                          "Alpha": jnp.ones(3), "Bias": jnp.asarray(bias)},
+              sinkhorn_iters=20, eps=1e-6, clamp_min=-30.0,
+              clamp_max=30.0)
+    h = np.asarray(out["HRes"])
+    assert np.isfinite(h).all()
+    np.testing.assert_allclose(h[0, :, :, 0], np.eye(n), atol=1e-6)
+
+
+# -- flash attention at latent attention's head sizes --------------------------
+
+def _mla_qkv(seed, b=1, h=2, t=256, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (b, h, t, 192), dtype)
+    k = jax.random.normal(ks[1], (b, h, t, 192), dtype)
+    v = jax.random.normal(ks[2], (b, h, t, 128), dtype)
+    g = jax.random.normal(ks[3], (b, h, t, 128), dtype)
+    return q, k, v, g
+
+
+SCALE = 192 ** -0.5 * 1.4159 ** 2
+
+
+def test_flash_192_128_forward_interpret():
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    q, k, v, _ = _mla_qkv(0)
+    out, lse = pk._flash_attention_fwd(q, k, v, causal=True, scale=SCALE,
+                                       impl="interpret", block_q=128,
+                                       block_k=128)
+    want, want_lse = pk._plain_attention(q, k, v, True, SCALE,
+                                         with_lse=True)
+    assert out.shape == (1, 2, 256, 128) and lse.shape == (1, 2, 256)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (64, 256)])
+def test_flash_192_128_saved_residual_backward(blocks):
+    """The grad op's saved path: the two backward kernels on the
+    forward's Out and LSE, against jax's gradient of plain attention."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    q, k, v, g = _mla_qkv(1)
+    call = dict(causal=True, scale=SCALE, impl="interpret",
+                block_q=blocks[0], block_k=blocks[1])
+    out, lse = pk._flash_attention_fwd(q, k, v, **call)
+    dq, dk, dv = pk._flash_attention_bwd(q, k, v, out, lse, g, **call)
+    _, vjp = jax.vjp(lambda q, k, v: pk._plain_attention(
+        q, k, v, True, SCALE), q, k, v)
+    assert dq.shape == q.shape and dk.shape == k.shape \
+        and dv.shape == v.shape
+    for got, want in zip((dq, dk, dv), vjp(g)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=2e-5)
+
+
+def test_flash_op_and_grad_op_at_two_head_sizes():
+    """Through the registered ops, as a program runs them: the grad op
+    with Out and LSE bound equals the grad op without (jax.vjp over the
+    forward), off the chip both in XLA."""
+    q, k, v, g = _mla_qkv(2, t=64)
+    attrs = dict(causal=True, scale=SCALE)
+    fwd = _op("flash_attention", {"Q": q, "K": k, "V": v}, **attrs)
+    assert fwd["Out"].shape == (1, 2, 64, 128)
+    ins = {"Q": q, "K": k, "V": v, "Out@GRAD": g}
+    saved = _op("flash_attention_grad",
+                {**ins, "Out": fwd["Out"], "LSE": fwd["LSE"]}, **attrs)
+    again = _op("flash_attention_grad", ins, **attrs)
+    for slot in ("Q@GRAD", "K@GRAD", "V@GRAD"):
+        np.testing.assert_allclose(saved[slot], again[slot], rtol=1e-5,
+                                   atol=1e-6)
+
+
+# -- every new op through the IR: shape rules and the verifier ----------------
+
+def test_new_ops_pass_shape_check_and_verifier():
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    from paddle_tpu.analysis import verifier
+    from paddle_tpu.analysis.shape_check import infer_program_shapes
+
+    x = layers.data("x", shape=[4, 16, 32], dtype="float32")     # streams
+    u, h_post, h_res = layers.mhc_pre(x, sinkhorn_iters=5, name="hc")
+    u = layers.rms_norm(u, name="norm")
+    q = layers.rotary_embedding(layers.reshape(u, [-1, 16, 4, 8]),
+                                rotary_dim=4, factor=64.0,
+                                original_max_position=8)
+    idx, gate = layers.moe_route(u, 8, 2, routed_scaling_factor=2.0,
+                                 name="router")
+    y = layers.moe_experts(u, idx, gate, held=[1, 3], width=16,
+                           name="experts")
+    y = layers.elementwise_add(y, layers.swiglu(u, u))
+    out = layers.mhc_post(x, y, h_post, h_res)
+    block = fluid.default_main_program().global_block()
+    assert out.shape == (-1, 4, 16, 32) and u.shape == (-1, 16, 32)
+    assert tuple(q.shape) == (-1, 16, 4, 8)
+    assert tuple(idx.shape) == (-1, 16, 2) and idx.dtype == "int32"
+    assert tuple(h_res.shape) == (-1, 4, 4, 16)
+    types = [op.type for op in block.ops]
+    for name in ("mhc_pre", "rms_norm", "rotary_embedding", "moe_route",
+                 "moe_experts", "swiglu", "mhc_post"):
+        assert name in types
+    _, diags = infer_program_shapes(fluid.default_main_program())
+    assert not diags, [str(d) for d in diags]
+    verifier.verify(fluid.default_main_program())
+    # the selection bias gets no gradient, the router weight does
+    assert block.var("router_bias.w").stop_gradient
+    assert not block.var("router.w").stop_gradient
+
+
+def test_amp_lists_keep_the_float32_parts():
+    from paddle_tpu.contrib.mixed_precision import fp16_lists, fp16_utils
+
+    assert "moe_route" in fp16_lists.black_list
+    assert "rms_norm" in fp16_lists.follow_x_list
+    assert {"moe_experts", "mhc_pre", "mhc_post"} <= fp16_lists.white_list
+    keep = fp16_utils._WHITE_KEEP_FP32
+    assert keep["moe_experts"] == {"TopkWeight"}
+    assert keep["mhc_pre"] == {"NormScale", "Phi", "Alpha", "Bias"}
+    assert keep["mhc_post"] == {"HPost", "HRes"}
+    assert fp16_utils._WHITE_LOWP_OUT["mhc_pre"] == {"U"}
