@@ -55,11 +55,12 @@ SEED = 0
 # its own tiny sizes; nothing else chooses a size.
 REAL = {
     "transformer": dict(batch=32, seq=512, steps=5, n_layer=6,
-                        # 6 layers x (1 forward + 2 backward flash
-                        # kernels): what the v5e compile of this step
+                        # 6 layers x (1 forward + 1 backward flash
+                        # kernel): what the v5e compile of this step
                         # shows (the grad op reads the forward's Out
-                        # and LSE; it ran the forward again until PR 25)
-                        custom_calls=18),
+                        # and LSE, it ran the forward again until
+                        # PR 25; one backward sweep since PR 29)
+                        custom_calls=12),
     "resnet": dict(batch=128, image=224, steps=3, custom_calls=0),
     "serve": dict(vocab=32000, d_model=1024, num_heads=8, head_dim=128,
                   page_size=128, n_requests=8, prompt_min=32,
@@ -71,7 +72,7 @@ REAL = {
                     conv1x1=(128, 56, 56, 64, 256),
                     fc=(16384, 512, 2048)),
     "gspmd": dict(batch=32, seq=512, steps=3, n_layer=6, dp=2, tp=2,
-                  custom_calls=18),
+                  custom_calls=12),
 }
 
 # stated tolerances of the on-chip comparisons (max abs difference,

@@ -21,10 +21,12 @@ hpb is the heads-per-block packing factor (1, or 2 under the
 The public `flash_attention` is differentiable via ONE custom_vjp
 (`_flash_lse`, shared with `flash_attention_lse` and the IR op): forward
 runs the Pallas kernel on TPU (plain XLA path elsewhere) and saves
-(q, k, v, o, lse); backward runs dedicated Pallas kernels (two-pass
-FlashAttention bwd: a dq sweep and a dk/dv sweep that recompute P
-blockwise from lse) — the [Tq, Tk] matrices stay in VMEM in both
-directions.  The XLA impl is plain attention, differentiated by jax.
+(q, k, v, o, lse); backward runs ONE Pallas kernel that recomputes P
+blockwise from lse and writes dq, dk and dv from it (kv blocks outer,
+the head's dq resident in VMEM; past 74k rows, where that dq does not
+fit, a dq sweep and a dk/dv sweep) — the [Tq, Tk] matrices stay in VMEM
+in both directions.  The XLA impl is plain attention, differentiated by
+jax.
 
 Memory-layout variants (docs/FLASH_ATTENTION.md; both default OFF until
 the chip chaser validates them — zero behavior change under the
@@ -334,6 +336,18 @@ def _pad_axis(x, axis, mult):
     return jnp.pad(x, widths)
 
 
+def _block_geometry(q, k, block_q, block_k, packed_stats, head_pack):
+    """(bq, bk, packed, hpb) of the kernels' grid over these operands:
+    blocks clamped to the lengths, and the layout variants where their
+    geometry holds."""
+    b, h, tq, d = q.shape
+    bq = min(block_q, max(tq, 8))
+    bk = min(block_k, max(k.shape[2], 8))
+    packed = packed_stats and _packed_geom_ok(bq)
+    hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)) else 1
+    return bq, bk, packed, hpb
+
+
 # jitted so that a step traces each kernel once per signature, not once
 # per layer: jax keeps no cache of kernel-body traces (the partial it is
 # handed is new every call), and of a six-layer Transformer step's first
@@ -351,14 +365,12 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     ([B, H, Tq, Dv], lse [B*H, Tq_padded])."""
     b, h, tq, d = q.shape
     tk, dv = k.shape[2], v.shape[3]
-    bq = min(block_q, max(tq, 8))
-    bk = min(block_k, max(tk, 8))
+    bq, bk, packed, hpb = _block_geometry(q, k, block_q, block_k,
+                                          packed_stats, head_pack)
     qp = _pad_axis(q.reshape(b * h, tq, d), 1, bq)
     kp = _pad_axis(k.reshape(b * h, tk, d), 1, bk)
     vp = _pad_axis(v.reshape(b * h, tk, dv), 1, bk)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
-    packed = packed_stats and _packed_geom_ok(bq)
-    hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)) else 1
     grid = (b * h // hpb, tq_p // bq, tk_p // bk)
 
     kernel = functools.partial(
@@ -408,14 +420,16 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
-# pallas backward kernels (standard two-pass FlashAttention bwd)
+# pallas backward kernels
 # ---------------------------------------------------------------------------
 # Recompute P blockwise from (q, k, lse); with delta = rowsum(dO * O):
 #   dV = P^T dO
 #   dS = P * (dO V^T - delta) * scale
 #   dQ = dS K ;  dK = dS^T Q
-# The [Tq, Tk] matrices never leave VMEM — the previous bwd replayed
-# plain attention in XLA, materializing P in HBM.
+# The [Tq, Tk] matrices never leave VMEM.  One sweep (_bwd_dkv_kernel
+# with_dq) forms P and dS once a block pair for all three; the dq sweep
+# beside a dk/dv sweep without dq, which form them once each, stay for
+# the lengths whose dq does not fit VMEM (_flash_bwd).
 
 def _bwd_interior(*, causal, block_q, block_k, kv_len, q_len, q_off,
                   qi, ki):
@@ -506,17 +520,38 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                    block_q, block_k, kv_len, q_len, q_off, packed,
-                    hpb):
+                    *refs, scale, causal, block_q, block_k, kv_len,
+                    q_len, q_off, packed, hpb, with_dq):
+    """The dk/dv sweep: kv blocks outer, q blocks inner, dk_acc/dv_acc
+    carried across the q sweep.
+
+    with_dq, it is the whole backward: P and dS, formed once a block
+    pair, feed all three products.  dq_acc holds the head's whole dq
+    [hpb, Tq_p, d] in float32 and carries across the OUTER kv axis, so
+    each q block's rows add their kv blocks in ascending order (the sum
+    `_bwd_dq_kernel` forms, bit for bit) and dq reaches HBM once a
+    head."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+        nk = pl.num_programs(1)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    if with_dq:
+        @pl.when(ki == 0)
+        def _init_dq():
+            for h in range(hpb):
+                dq_acc[h, rows, :] = jnp.zeros(
+                    (block_q, dq_acc.shape[2]), dq_acc.dtype)
 
     if causal:
         # q blocks entirely above the diagonal contribute nothing
@@ -545,6 +580,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dk_acc[h] += lax.dot_general(
                 ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            if with_dq:
+                dq_acc[h, rows, :] += lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
     @pl.when(run & interior)
     def _compute_fast():
@@ -560,15 +599,73 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dk_ref[h, ...] = dk_acc[h].astype(dk_ref.dtype)
             dv_ref[h, ...] = dv_acc[h].astype(dv_ref.dtype)
 
+    if with_dq:
+        @pl.when(ki == nk - 1)
+        def _finalize_dq():
+            for h in range(hpb):
+                dq_ref[h, rows, :] = dq_acc[h, rows, :].astype(
+                    dq_ref.dtype)
+
+
+# What Mosaic scopes a kernel to on a v5e unless it asks for more, and
+# the most the one-sweep backward asks for of the core's 128 MiB.
+_MOSAIC_SCOPED_VMEM = 16 << 20
+_BWD_FUSED_VMEM_MAX = 96 << 20
+
+
+def _bwd_fused_vmem_bytes(hpb, tq_p, bq, bk, d, dv, itemsize, packed):
+    """VMEM the one-sweep backward needs, bytes, from above: what the
+    chip's compiler asked for, compiled for a described v5e over the
+    cells' shapes, head sizes 64 to 192, both dtypes and hpb 2, was
+    0.35 to 0.9 of this (PERF.md, PR 29).  A minor dim occupies whole
+    128-lane tiles."""
+    def lanes(n):
+        return -(-n // _MIN_LANES) * _MIN_LANES
+
+    d, dv = lanes(d), lanes(dv)
+    # q, k, v, dO and the two row statistics, double-buffered
+    blocks = 2 * itemsize * (bq + bk) * (d + dv) \
+        + 2 * 2 * 4 * (bq if packed else bq * _MIN_LANES)
+    # dk/dv: the double-buffered output blocks and their accumulators
+    dkv = (2 * itemsize + 4) * bk * (d + dv)
+    # dq: the head's float32 accumulator and the output block
+    dq = (2 * itemsize + 4) * tq_p * d
+    # S/P, dP, dS and their casts, as far as Mosaic keeps them whole
+    temps = 4 * 4 * bq * bk
+    return hpb * (blocks + dkv + dq + temps)
+
+
+def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
+    """(dq, dk, dv) by `_flash_bwd_pallas`.  The shape alone picks the
+    sweep: one kernel that writes all three where a head's dq fits the
+    VMEM a kernel may ask for; past that two, dq streamed by q block.
+    Counted here, outside the jit, so that a step of six layers reads
+    six.  **call: the static arguments `_call_args` resolved."""
+    bq, bk, packed, hpb = _block_geometry(
+        q, k, call["block_q"], call["block_k"], call["packed_stats"],
+        call["head_pack"])
+    vmem = _bwd_fused_vmem_bytes(
+        hpb, -(-q.shape[2] // bq) * bq, bq, bk, q.shape[3], v.shape[3],
+        q.dtype.itemsize, packed)
+    fused = vmem <= _BWD_FUSED_VMEM_MAX
+    _count_impl("flash_attention_bwd", "fused" if fused else "two_sweep")
+    return _flash_bwd_pallas(
+        q, k, v, o, lse, g, dlse=dlse, **call,
+        one_sweep_vmem=max(vmem, _MOSAIC_SCOPED_VMEM) if fused else None)
+
 
 @functools.partial(jax.jit, static_argnames=(    # see _flash_fwd_pallas
     "causal", "scale", "block_q", "block_k", "interpret", "packed_stats",
-    "head_pack"))
+    "head_pack", "one_sweep_vmem"))
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
                       block_k, interpret=False, dlse=None,
-                      packed_stats=False, head_pack=False):
+                      packed_stats=False, head_pack=False, *,
+                      one_sweep_vmem):
     """q/k: [B, H, T, D], v, o and g = dO: [.., Dv]; lse: [B*H, Tq] or
-    q-block padded, as the forward kernel returns it.
+    q-block padded, as the forward kernel returns it.  one_sweep_vmem:
+    the VMEM to ask for, bytes, for the one sweep (`_bwd_dkv_kernel`
+    with_dq), or None for the dq and the dk/dv sweep; `_flash_bwd`
+    picks.
 
     dlse ([B*H, Tq] or None): cotangent of the lse output when the
     caller consumes it (ring attention's cross-chunk merge).  Since
@@ -583,8 +680,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     """
     b, h, tq, d = q.shape
     tk, dv = k.shape[2], v.shape[3]
-    bq = min(block_q, max(tq, 8))
-    bk = min(block_k, max(tk, 8))
+    bq, bk, packed, hpb = _block_geometry(q, k, block_q, block_k,
+                                          packed_stats, head_pack)
     qp = _pad_axis(q.reshape(b * h, tq, d), 1, bq)
     kp = _pad_axis(k.reshape(b * h, tk, d), 1, bk)
     vp = _pad_axis(v.reshape(b * h, tk, dv), 1, bk)
@@ -592,8 +689,6 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     tq_p, tk_p = qp.shape[1], kp.shape[1]
     # rows past tq are masked in the kernels: what they hold is not read
     lse = _pad_axis(lse, 1, bq)
-    packed = packed_stats and _packed_geom_ok(bq)
-    hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)) else 1
     # delta = rowsum(dO * O): cheap elementwise+reduce, done in XLA;
     # an lse cotangent subtracts from it (see docstring)
     delta_full = jnp.sum(
@@ -625,50 +720,76 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
                   kv_len=tk, q_len=tq, q_off=q_off, packed=packed,
                   hpb=hpb)
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    operands = (qp, kp, vp, gp, lse3, delta3)
+    out_shape = [
+        jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype),
+        jax.ShapeDtypeStruct((b * h, tk_p, d), k.dtype),
+        jax.ShapeDtypeStruct((b * h, tk_p, dv), v.dtype),
+    ]
 
-    qspec = pl.BlockSpec((hpb, bq, d), lambda bh, i, j: (bh, i, 0))
-    gspec = pl.BlockSpec((hpb, bq, dv), lambda bh, i, j: (bh, i, 0))
-    lspec = pl.BlockSpec(lblk, lambda bh, i, j: (bh, i, 0))
-    kspec = pl.BlockSpec((hpb, bk, d), lambda bh, i, j: (bh, j, 0))
-    vspec = pl.BlockSpec((hpb, bk, dv), lambda bh, i, j: (bh, j, 0))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
-        name="pt_flash_bwd_dq",
-        grid=(b * h // hpb, tq_p // bq, tk_p // bk),
-        in_specs=[qspec, kspec, vspec, gspec, lspec, lspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((hpb, bq, d), jnp.float32)],
-        interpret=interpret,
-        **params,
-    )(qp, kp, vp, gp, lse3, delta3)
+    def specs(qmap, kmap):
+        """in_specs of (q, k, v, dO, lse, delta) for a grid order."""
+        return [pl.BlockSpec((hpb, bq, d), qmap),
+                pl.BlockSpec((hpb, bk, d), kmap),
+                pl.BlockSpec((hpb, bk, dv), kmap),
+                pl.BlockSpec((hpb, bq, dv), qmap),
+                pl.BlockSpec(lblk, qmap), pl.BlockSpec(lblk, qmap)]
 
-    # dkv grid: kv blocks outer, q blocks inner (accumulator carries
-    # across the q sweep); block index maps swap i<->j roles
-    qspec2 = pl.BlockSpec((hpb, bq, d), lambda bh, j, i: (bh, i, 0))
-    gspec2 = pl.BlockSpec((hpb, bq, dv), lambda bh, j, i: (bh, i, 0))
-    lspec2 = pl.BlockSpec(lblk, lambda bh, j, i: (bh, i, 0))
-    kspec2 = pl.BlockSpec((hpb, bk, d), lambda bh, j, i: (bh, j, 0))
-    vspec2 = pl.BlockSpec((hpb, bk, dv), lambda bh, j, i: (bh, j, 0))
-    dk, dv_ = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **common),
-        name="pt_flash_bwd_dkv",
-        grid=(b * h // hpb, tk_p // bk, tq_p // bq),
-        in_specs=[qspec2, kspec2, vspec2, gspec2, lspec2, lspec2],
-        out_specs=[kspec2, vspec2],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk_p, dv), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((hpb, bk, d), jnp.float32),
-                        pltpu.VMEM((hpb, bk, dv), jnp.float32)],
-        interpret=interpret,
-        **params,
-    )(qp, kp, vp, gp, lse3, delta3)
+    def params(outer="parallel", vmem_limit_bytes=None):
+        if interpret:
+            return {}
+        return {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", outer, "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes)}
+
+    # kv blocks outer, q blocks inner: the dk/dv accumulators carry
+    # across the q sweep
+    kv_specs = specs(lambda bh, j, i: (bh, i, 0),
+                     lambda bh, j, i: (bh, j, 0))
+    kv_grid = (b * h // hpb, tk_p // bk, tq_p // bq)
+    kv_scratch = [pltpu.VMEM((hpb, bk, d), jnp.float32),
+                  pltpu.VMEM((hpb, bk, dv), jnp.float32)]
+    if one_sweep_vmem is not None:
+        dq, dk, dv_ = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, with_dq=True, **common),
+            name="pt_flash_bwd_dkv",
+            grid=kv_grid,
+            in_specs=kv_specs,
+            # dq: the head's whole [Tq_p, d], resident over both sweeps
+            out_specs=[pl.BlockSpec((hpb, tq_p, d),
+                                    lambda bh, j, i: (bh, 0, 0)),
+                       kv_specs[1], kv_specs[2]],
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((hpb, tq_p, d), jnp.float32)]
+            + kv_scratch,
+            interpret=interpret,
+            **params("arbitrary", one_sweep_vmem),
+        )(*operands)
+    else:
+        q_specs = specs(lambda bh, i, j: (bh, i, 0),
+                        lambda bh, i, j: (bh, j, 0))
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, **common),
+            name="pt_flash_bwd_dq",
+            grid=(b * h // hpb, tq_p // bq, tk_p // bk),
+            in_specs=q_specs,
+            out_specs=q_specs[0],
+            out_shape=out_shape[0],
+            scratch_shapes=[pltpu.VMEM((hpb, bq, d), jnp.float32)],
+            interpret=interpret,
+            **params(),
+        )(*operands)
+        dk, dv_ = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, with_dq=False, **common),
+            name="pt_flash_bwd_dkv",
+            grid=kv_grid,
+            in_specs=kv_specs,
+            out_specs=kv_specs[1:3],
+            out_shape=out_shape[1:],
+            scratch_shapes=kv_scratch,
+            interpret=interpret,
+            **params(),
+        )(*operands)
     return (dq[:, :tq, :].reshape(b, h, tq, d),
             dk[:, :tk, :].reshape(b, h, tk, d),
             dv_[:, :tk, :].reshape(b, h, tk, dv))
@@ -702,10 +823,10 @@ def _flash_lse_bwd(causal, scale, block_q, block_k, interpret,
                    packed_stats, head_pack, res, g):
     q, k, v, o, lse = res
     do, dlse = g
-    return _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale,
-                             block_q, block_k, interpret=interpret,
-                             dlse=dlse, packed_stats=packed_stats,
-                             head_pack=head_pack)
+    return _flash_bwd(q, k, v, o, lse, do, dlse=dlse, causal=causal,
+                      scale=scale, block_q=block_q, block_k=block_k,
+                      interpret=interpret, packed_stats=packed_stats,
+                      head_pack=head_pack)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -829,7 +950,7 @@ def _flash_attention_fwd(q, k, v, **call):
 
 def _flash_attention_bwd(q, k, v, out, lse, g, **call):
     """(dq, dk, dv) from what `_flash_attention_fwd` returned and the
-    cotangent g of out: the two backward kernels and nothing else.  The
+    cotangent g of out: the backward kernel and nothing else.  The
     forward kernel does not run again.  Kernel impls only: plain
     attention keeps no residual worth saving, jax differentiates it."""
     _, kw = _call_args(q, k, **call)
@@ -843,8 +964,7 @@ def _flash_attention_bwd(q, k, v, out, lse, g, **call):
     lse, g = lax.optimization_barrier((lse, g))
     # see _flash_attention_fwd: one call line, flag or no flag
     with _obs_device.annotate("flash_attention_grad"), _kernel_scope():
-        return _flash_bwd_pallas(q, k, v, out, lse.reshape(b * h, tq), g,
-                                 **kw)
+        return _flash_bwd(q, k, v, out, lse.reshape(b * h, tq), g, **kw)
 
 
 def _auto_impl():
@@ -1453,7 +1573,7 @@ def _flash_attention_grad_op(ins, attrs):
     PR 24).  The choice follows from what the op can see:
 
       * Out and LSE bound (append_backward binds them) and the impl
-        resolves to a kernel: the two backward kernels on the saved
+        resolves to a kernel: the backward kernel on the saved
         residuals, under the same shard_map gate as the forward;
       * either slot unbound (a program serialized before the slots, a
         hand-built op) or the XLA impl: `jax.vjp` over the forward

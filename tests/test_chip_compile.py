@@ -22,7 +22,9 @@ def _sds(shape, dtype=BF16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _flash(shape, grad):
+def _flash(shape, grad, n_bwd=1):
+    """n_bwd: the backward's kernels, one sweep where a head's dq fits
+    the VMEM it may ask for and two past that."""
     from paddle_tpu.ops.pallas_kernels import flash_attention
 
     def fwd(q, k, v):
@@ -32,7 +34,8 @@ def _flash(shape, grad):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    return (bwd if grad else fwd), (_sds(shape),) * 3, 3 if grad else 1
+    return (bwd if grad else fwd), (_sds(shape),) * 3, \
+        (n_bwd + 1 if grad else 1)
 
 
 def _flash_mla(grad, shape=(1, 32, 4096)):
@@ -49,7 +52,7 @@ def _flash_mla(grad, shape=(1, 32, 4096)):
             (q, q, v), 1
     return (lambda q, k, v, o, lse, g: _flash_attention_bwd(
         q, k, v, o, lse, g, **call)), \
-        (q, q, v, v, _sds((b, h, t), jnp.float32), v), 2
+        (q, q, v, v, _sds((b, h, t), jnp.float32), v), 1
 
 
 def _gmm(which, rows=16384, held=8, hidden=3584, width=1024, tm=256):
@@ -111,6 +114,9 @@ CASES = {
     "flash_bwd_32x8x512x64": lambda: _flash((32, 8, 512, 64), True),
     "flash_fwd_1x8x32768x128": lambda: _flash((1, 8, 32768, 128), False),
     "flash_bwd_1x8x32768x128": lambda: _flash((1, 8, 32768, 128), True),
+    "flash_bwd_4x8x8192x64": lambda: _flash((4, 8, 8192, 64), True),
+    "flash_bwd_two_sweep_1x2x131072x64": lambda: _flash(
+        (1, 2, 131072, 64), True, n_bwd=2),
     "flash_fwd_1x32x4096_qk192_v128": lambda: _flash_mla(False),
     "flash_bwd_saved_1x32x4096_qk192_v128": lambda: _flash_mla(True),
     "gmm_fwd_8x3584x1024_rows16384": lambda: _gmm("fwd"),

@@ -29,7 +29,9 @@ from paddle_tpu.flags import set_flags
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.parallel import env as penv
 
-KERNELS = ("pt_flash_fwd", "pt_flash_bwd_dq", "pt_flash_bwd_dkv")
+# the one backward sweep carries the dk/dv sweep's name (it is that
+# sweep, now writing dq too); pt_flash_bwd_dq is the two-sweep fallback's
+KERNELS = ("pt_flash_fwd", "pt_flash_bwd_dkv")
 GRADS = ["q@GRAD", "k@GRAD", "v@GRAD"]
 
 
@@ -82,14 +84,9 @@ def _run(prog, feed, fetch):
                        fetch_list=list(fetch))
 
 
-def _kernel_calls(prog, feed):
-    """{pallas_call name: count} in the jaxpr of the whole block."""
-    def step(feeds):
-        env = _TraceEnv()
-        env.update(feeds)
-        _run_block_symbolic(prog, 0, env)
-        return [env[g] for g in GRADS]
-
+def _pallas_calls(fn, *args):
+    """{pallas_call name: count} in the jaxpr of fn(*args), nested
+    jaxprs (jit, checkpoint, shard_map, custom_vjp) included."""
     found = collections.Counter()
 
     def walk(jaxpr):
@@ -103,8 +100,19 @@ def _kernel_calls(prog, feed):
                     if hasattr(sub, "eqns"):
                         walk(sub)
 
-    walk(jax.make_jaxpr(step)(feed).jaxpr)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
     return dict(found)
+
+
+def _kernel_calls(prog, feed):
+    """{pallas_call name: count} in the jaxpr of the whole block."""
+    def step(feeds):
+        env = _TraceEnv()
+        env.update(feeds)
+        _run_block_symbolic(prog, 0, env)
+        return [env[g] for g in GRADS]
+
+    return _pallas_calls(step, feed)
 
 
 def _unbind_saved(prog):
@@ -143,9 +151,8 @@ def test_one_forward_kernel_a_layer(interpret, n_layers):
     # and what it was before: the vjp runs the forward kernel again
     assert _unbind_saved(prog) == n_layers
     calls = _kernel_calls(prog, feed)
-    assert calls["pt_flash_fwd"] == 2 * n_layers
-    assert calls["pt_flash_bwd_dq"] == calls["pt_flash_bwd_dkv"] \
-        == n_layers
+    assert calls == {"pt_flash_fwd": 2 * n_layers,
+                     "pt_flash_bwd_dkv": n_layers}
 
 
 # -- (b) same numbers -------------------------------------------------------
@@ -162,12 +169,14 @@ def test_saved_equals_recompute_bit_for_bit(interpret, shape, kw):
     before = _impl_counts()
     saved = _run(prog, feed, GRADS)
     assert _since(before) == {("flash_attention", "interpret"): 1,
-                              ("flash_attention_grad", "saved"): 1}
+                              ("flash_attention_grad", "saved"): 1,
+                              ("flash_attention_bwd", "fused"): 1}
     _unbind_saved(prog)
     before = _impl_counts()
     recomputed = _run(prog, feed, GRADS)
     assert _since(before) == {("flash_attention", "interpret"): 2,
-                              ("flash_attention_grad", "recompute"): 1}
+                              ("flash_attention_grad", "recompute"): 1,
+                              ("flash_attention_bwd", "fused"): 1}
     for name, a, b in zip(GRADS, saved, recomputed):
         assert np.array_equal(a, b), name
         assert np.abs(a).max() > 0, name
@@ -308,6 +317,7 @@ def test_saved_path_under_shard_map_matches_one_device(interpret):
         assert _since(before) == {
             ("flash_attention", "interpret"): 1,
             ("flash_attention_grad", "saved"): 1,
+            ("flash_attention_bwd", "fused"): 1,
             ("flash_attention_gspmd", "shard_map"): 2}
     finally:
         set_flags({"gspmd": False})
@@ -364,4 +374,5 @@ def test_transformer_step_counts_saved_six_times(interpret):
                       fetch_list=[model["loss"]])
     assert np.isfinite(loss).all()
     assert _since(before) == {("flash_attention", "interpret"): 6,
-                              ("flash_attention_grad", "saved"): 6}
+                              ("flash_attention_grad", "saved"): 6,
+                              ("flash_attention_bwd", "fused"): 6}
