@@ -312,8 +312,8 @@ def _train_steps(name, model_loss, feed, steps, watch, platform,
 
 
 def phase_train_transformer(cfg, watch, platform, flash_impl="pallas"):
-    from bench import TRANSFORMER_BASE as c
-    from bench import _fresh_programs
+    from tools.gate_programs import TRANSFORMER_BASE as c
+    from tools.gate_programs import _fresh_programs
     from paddle_tpu import optimizer
     from paddle_tpu.contrib.mixed_precision import decorate
     from paddle_tpu.models.transformer import transformer_encoder_model
@@ -325,7 +325,7 @@ def phase_train_transformer(cfg, watch, platform, flash_impl="pallas"):
         n_head=c["n_head"], d_inner=c["d_inner"],
         n_layer=cfg["n_layer"], dropout_rate=0.0)
     # bf16 has fp32's exponent range: static loss scale 1.0, as in
-    # bench._build_transformer_train
+    # tools/gate_programs._build_transformer_train
     decorate(optimizer.Adam(learning_rate=1e-4), init_loss_scaling=1.0,
              use_dynamic_loss_scaling=False).minimize(model["loss"])
     ids = np.random.RandomState(SEED).randint(
@@ -340,7 +340,7 @@ def phase_train_transformer(cfg, watch, platform, flash_impl="pallas"):
 
 
 def phase_train_resnet(cfg, watch, platform):
-    from bench import _fresh_programs
+    from tools.gate_programs import _fresh_programs
     from paddle_tpu import framework, optimizer
     from paddle_tpu.contrib.mixed_precision import decorate
     from paddle_tpu.models.resnet import resnet50
@@ -627,8 +627,8 @@ def build_gspmd_transformer(cfg, sharded, devices=None):
     program as ONE pjit step over MeshPlan(dp, tp) on `devices`
     (default: all jax sees).  Returns (compiled, model, feed)."""
     import paddle_tpu as fluid
-    from bench import TRANSFORMER_BASE as c
-    from bench import _fresh_programs
+    from tools.gate_programs import TRANSFORMER_BASE as c
+    from tools.gate_programs import _fresh_programs
     from paddle_tpu import optimizer
     from paddle_tpu.contrib.mixed_precision import decorate
     from paddle_tpu.flags import set_flags
@@ -674,7 +674,7 @@ def count_collectives(text):
 def phase_gspmd(cfg, watch, platform, flash_impl="pallas"):
     import jax
 
-    from bench import TRANSFORMER_BASE as c
+    from tools.gate_programs import TRANSFORMER_BASE as c
     from paddle_tpu.core.scope import global_scope
 
     dp, tp = cfg["dp"], cfg["tp"]

@@ -91,7 +91,7 @@ def enable_compile_cache():
     (graph, flags, shapes) and reused across processes and restarts, so
     a second run of a program — or a replica fleet warming its bucket
     set — replays compiles from disk.  Called by the entry points that
-    compile for the chip (chip_smoke.py, bench.py legs,
+    compile for the chip (chip_smoke.py, benchmarks/run.py,
     tools/serving_load.py, the servers' ``start()``), not at import.
     A default directory that cannot be made raises.  Returns the
     directory, or None when the cache is switched off."""

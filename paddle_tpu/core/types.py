@@ -9,7 +9,7 @@ Reference parity:
     machine without a chip and Executor(CPUPlace()) on a machine with
     one both run on JAX's default device.  Code that must run on the
     chip asserts the platform and where its state lives itself
-    (chip_smoke.py, bench.py).  CUDAPlace is accepted as an alias so
+    (chip_smoke.py, benchmarks/run.py).  CUDAPlace is accepted as an alias so
     reference user code ports cleanly.
   - VarType enum: /root/reference/paddle/fluid/framework/framework.proto:105-165
 """

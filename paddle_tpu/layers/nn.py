@@ -929,7 +929,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     with a single kernel that never materializes the [Tq, Tk] score matrix.
     block_q/block_k override the kernel tile sizes (default picked by
     sequence length: 1024 for T >= 1024, else 512 — pinned by the
-    2026-08-01 v5e sweep, tools/flash_block_sweep.py).
+    2026-08-01 v5e sweep; PERF.md section 6, PR 21).
 
     Returns Out.  The op also writes LSE, the per-row log-sum-exp
     (float32 [B, H, Tq], no gradient): the residual flash_attention_grad

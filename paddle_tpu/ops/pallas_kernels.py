@@ -864,7 +864,7 @@ def flash_attention_lse(q, k, v, *, causal=False, scale=None,
 def _default_block(t):
     """Default tile edge for a sequence length of t.
 
-    Pinned by the 2026-08-01 on-chip sweep (tools/flash_block_sweep.py,
+    Pinned by the 2026-08-01 on-chip sweep (PERF.md section 6, PR 21:
     v5e, seq 32k d64): 1024x1024 ran fwd+bwd 1.5x faster than the old
     512x512 default (76.9 ms vs 116.8).  Short sequences keep 512 —
     the kernel clamps to T anyway and seq-512 shapes showed no win

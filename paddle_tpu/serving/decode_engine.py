@@ -214,7 +214,7 @@ class TinyDecodeLM:
         self._qkv_jit = jax.jit(_qkv)
         self._logits_jit = jax.jit(_logits)
         # the closed-over forms stay public for a caller that inlines
-        # them under its own jit (bench.py _build_llm_decode, the
+        # them under its own jit (gate_programs._build_llm_decode, the
         # lowering gate); that jit then carries the weights
         self.qkv_fn = functools.partial(_qkv, self.params)
         self.logits_fn = functools.partial(_logits, self.params)

@@ -126,19 +126,6 @@ def fresh_programs_factory():
     return _ctx
 
 
-@pytest.fixture
-def cpu_bench_peaks(monkeypatch):
-    """bench.py refuses a device kind its spec-sheet tables do not
-    know.  A test that runs a bench leg on the CPU to check its
-    plumbing steers that here, in the test: it gives the tables a
-    "cpu" row, so the leg's utilization fields compute (and mean
-    nothing)."""
-    import bench
-
-    monkeypatch.setitem(bench._PEAK_BY_KIND, "cpu", 1e12)
-    monkeypatch.setitem(bench._PEAK_BW_BY_KIND, "cpu", 1e11)
-
-
 @pytest.fixture(scope="session")
 def chip_gate():
     """tools/tpu_lowering_check.py with its v5e:2x2 topology described

@@ -57,11 +57,8 @@ def test_op_freq_statistic():
 
 def test_model_stat_summary(capsys):
     from paddle_tpu.contrib import summary
-    from paddle_tpu.models.resnet import resnet50
-    import bench
-
-    bench._fresh_programs()
     from paddle_tpu import framework
+    from paddle_tpu.models.resnet import resnet50
 
     resnet50(is_test=True)
     rows = summary(framework.default_main_program())

@@ -474,10 +474,11 @@ def test_transpiler_skips_shared_conv_output():
     assert n == 0
 
 
-def test_grad_flows_through_fused_ir_op_bit_exact():
+def test_grad_flows_through_fused_ir_op():
     """append_backward over the fused program (flag off -> the exact
     unfused composite inside the custom_vjp) reproduces the unfused
-    program's loss AND weight gradient bit-exactly."""
+    program's loss bit-exactly and its gradients to a few units in the
+    last place."""
     import paddle_tpu as fluid
     from paddle_tpu import backward, framework, layers
     from paddle_tpu.core.scope import global_scope
@@ -519,9 +520,23 @@ def test_grad_flows_through_fused_ir_op_bit_exact():
         global_scope().find_var(k).set(jnp.asarray(v))
     got = exe2.run(prog2, feed={"image": x},
                    fetch_list=[loss2.name] + fetches)
-    for name, a, e in zip(["loss"] + fetches, got, ref):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(e),
-                                      err_msg=name)
+    # the forward is the same composite term for term: bit-equal
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref[0]),
+                                  err_msg="loss")
+    # the backward is not the same program: the unfused graph runs the
+    # hand-written batch_norm_grad and a conv2d_grad of its own, the
+    # fused op's grad is jax.vjp over the whole composite, and XLA sums
+    # the 128 terms of each BN moment and of each filter tap in another
+    # order.  Bit-equality cannot be guaranteed across the two; a few
+    # float32 units in the last place of the gradient's largest element
+    # can (seen: 3.3 for the filter, 2.6 for the BN scale, 0 for the
+    # BN bias).
+    eps = np.finfo(np.float32).eps
+    for name, a, e in zip(fetches, got[1:], ref[1:]):
+        e = np.asarray(e)
+        np.testing.assert_allclose(np.asarray(a), e, rtol=0,
+                                   atol=8 * eps * np.abs(e).max(),
+                                   err_msg=name)
 
 
 def test_nhwc_transpile_carries_fused_op():

@@ -562,65 +562,3 @@ def test_chunked_join_32k_slo():
                           threshold_s=0.5, page_size=64)
     assert v["firing"] is False, v
     assert v["attained"] >= 0.99, v
-
-
-# ---------------------------------------------------------------------------
-# bench legs + workload signatures
-# ---------------------------------------------------------------------------
-
-def test_bench_spec_leg_contract_and_self_draft(cpu_bench_peaks):
-    import bench
-
-    res = bench.bench_llm_decode_spec(
-        streams=2, spec_k=2, prefill_len=8, gen_tokens=3, heads=2,
-        head_dim=32, page_size=8, vocab=64, draft_heads=2,
-        draft_head_dim=8, warmup=1)
-    for field in ("tokens_per_sec", "acceptance_rate", "spec_k",
-                  "emitted_per_iter", "streams", "paged",
-                  "draft_heads"):
-        assert field in res, field
-    assert res["spec_k"] == 2
-    # a draft identical to the target must accept EVERYTHING — the
-    # end-to-end proof the bench's verify/rewind loop is lossless
-    res_self = bench.bench_llm_decode_spec(
-        streams=2, spec_k=2, prefill_len=8, gen_tokens=3, heads=2,
-        head_dim=32, page_size=8, vocab=64, draft_heads=2,
-        draft_head_dim=32, warmup=1)
-    assert res_self["acceptance_rate"] == 1.0
-    assert res_self["emitted_per_iter"] == 3.0   # k+1 every iter
-
-
-def test_bench_chunked_join_and_prefix_share_contract(cpu_bench_peaks):
-    import bench
-
-    res = bench.bench_llm_decode_chunked_join(
-        streams=2, join_prompt=64, chunk=16, prefill_len=8,
-        gen_tokens=6, heads=2, head_dim=32, page_size=8, vocab=64,
-        warmup=1)
-    for field in ("tokens_per_sec", "inter_token_p99_during_join_ms",
-                  "inter_token_p99_after_join_ms", "chunked_join",
-                  "join_prompt_len", "chunk"):
-        assert field in res, field
-    assert res["chunked_join"] is True
-    res2 = bench.bench_llm_decode(
-        streams=3, prefill_len=8, gen_tokens=3, heads=2,
-        head_dim=32, page_size=8, vocab=64, warmup=1,
-        prefix_share=16)
-    assert res2["prefix_shared"] == 16
-    assert res2["pool_pages"] < res2["pool_pages_unshared_equiv"]
-
-
-def test_workload_sig_keys_act2_variants_apart():
-    import bench
-
-    base = {"streams": 64, "heads": 8, "head_dim": 128, "paged": True}
-    a = bench._workload_sig("llm_decode_flash_str64", base)
-    b = bench._workload_sig("llm_decode_spec_k4_flash_str64",
-                            dict(base, spec_k=4))
-    c = bench._workload_sig("llm_decode_spec_k8_flash_str64",
-                            dict(base, spec_k=8))
-    d = bench._workload_sig("llm_decode_flash_str64_prefix_shared",
-                            dict(base, prefix_shared=2048))
-    e = bench._workload_sig("llm_decode_chunked_join_flash",
-                            dict(base, chunked_join=True))
-    assert len({a, b, c, d, e}) == 5

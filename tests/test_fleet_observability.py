@@ -23,9 +23,7 @@ Contracts pinned here:
     and tail_forensics --slowest attributes the aggregate dominantly
     to admission-queue wait with closing segment sums;
   - collector off + sample 0.0 sends zero new wire bytes (the server
-    sees the exact legacy payload; no pusher exists);
-  - the perf sentinel flags direction-aware drift beyond the noise
-    band and passes identical rows.
+    sees the exact legacy payload; no pusher exists).
 """
 
 import importlib.util
@@ -703,60 +701,6 @@ def test_overload_exemplar_resolves_in_collector_and_forensics(
             child.kill()
         tracing.stop_tracing()
         coll.stop()
-
-
-# ---------------------------------------------------------------------------
-# perf sentinel
-# ---------------------------------------------------------------------------
-
-def test_perf_sentinel_direction_aware_bands(tmp_path):
-    ps = _tools_mod("perf_sentinel")
-    base = {"sig": {"p50_ms": 10.0, "tokens_per_sec": 100.0}}
-    same = {"sig": {"p50_ms": 11.0, "tokens_per_sec": 95.0}}
-    checked, flagged, missing = ps.compare(same, base, band=4.0)
-    assert checked == 2 and not flagged and not missing
-    # latency regressed 5x -> flagged; throughput fell 5x -> flagged
-    bad = {"sig": {"p50_ms": 50.0, "tokens_per_sec": 20.0}}
-    _, flagged, _ = ps.compare(bad, base, band=4.0)
-    assert {f["metric"] for f in flagged} == {"p50_ms",
-                                             "tokens_per_sec"}
-    # direction-awareness: a FASTER latency / HIGHER throughput never
-    # flags, however large the move
-    good = {"sig": {"p50_ms": 0.1, "tokens_per_sec": 10000.0}}
-    _, flagged, _ = ps.compare(good, base, band=4.0)
-    assert not flagged
-    # a missing fresh row is informational, not a regression
-    _, flagged, missing = ps.compare({}, base, band=4.0)
-    assert not flagged and missing == ["sig"]
-
-
-def test_perf_sentinel_serving_rows_and_main(tmp_path):
-    ps = _tools_mod("perf_sentinel")
-    rec = {"metric": "serving_goodput", "mode": "fixed",
-           "replicas": 1, "max_batch": 8, "deadline_ms": 250.0,
-           "p50_ms": 3.0, "p99_ms": 8.0, "goodput_qps": 150.0,
-           "time_to_first_batch_cold_s": 0.05,
-           "time_to_first_batch_warm_s": 0.01}
-    rows = ps.serving_rows([rec])
-    (sig, row), = rows.items()
-    assert "fixed" in sig and "mb8" in sig
-    assert row["p50_ms"] == 3.0
-
-    fresh = tmp_path / "fresh.json"
-    fresh.write_text(json.dumps(rec) + "\n")
-    baseline = tmp_path / "base.json"
-    assert ps.main(["--fresh", str(fresh), "--update-baseline",
-                    str(baseline)]) == 0
-    assert ps.main(["--fresh", str(fresh), "--baseline",
-                    str(baseline)]) == 0
-    # regress the cold start 10x: the gated metric flags
-    rec2 = dict(rec, time_to_first_batch_cold_s=0.5)
-    fresh2 = tmp_path / "fresh2.json"
-    fresh2.write_text(json.dumps(rec2) + "\n")
-    assert ps.main(["--fresh", str(fresh2), "--baseline",
-                    str(baseline)]) == 1
-    assert ps.main(["--fresh", str(fresh2), "--baseline",
-                    str(baseline), "--advise"]) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Round-trip property test (ISSUE 15 satellite): programs the bench /
-lowering-gate builders construct verify green, serialize through
+"""Round-trip property test (ISSUE 15 satellite): programs the
+lowering-gate builders (tools/gate_programs.py) construct verify green, serialize through
 to_bytes/parse_from_bytes with an unchanged ``program_fingerprint``,
 and re-verify green after each applicable transpiler pass.
 
@@ -36,21 +36,43 @@ def _roundtrip_stable(program):
 
 
 # tiny shapes: the property under test is IR structure, not perf —
-# same builders as bench/tpu_lowering_check, _TINY-scale arguments
+# the builders tools/tpu_lowering_check.py compiles at real size.
+# Each entry builds and returns the program to check, or None for the
+# default main program.
 _BUILDERS = {
     "transformer_train": lambda b: b._build_transformer_train(2, 64),
     "transformer_train_fusedadam": lambda b:
         b._build_transformer_train(2, 64, fused_adam=True),
+    "transformer_train_fcep": lambda b:
+        b._build_transformer_train(2, 64, fc_epilogue=True),
+    "transformer_train_gspmd": lambda b:
+        b._build_transformer_train(2, 64, gspmd=True, tp=2),
     "deepfm_train": lambda b: b._build_deepfm_train(64),
+    "bert_train": lambda b: b._build_bert_train(1, 128),
+    "longctx_train": lambda b: b._build_longctx_train(1, 2, 512, 64),
+    "serving_tp_sharded": lambda b: b._build_serving_tp_sharded(tp=2),
+    "resnet50_train": lambda b: b._build_resnet50_train(2),
+    "resnet50_train_s2d": lambda b:
+        b._build_resnet50_train(2, s2d=True),
+    "resnet50_train_convep": lambda b:
+        b._build_resnet50_train(2, conv_epilogue=True),
+    "resnet50_train_convbnstats": lambda b:
+        b._build_resnet50_train(2, conv_bn_stats=True),
+    # the int8 programs are transpiled clones, which the builder returns
+    "resnet50_infer_int8": lambda b:
+        b._build_resnet50_infer_int8(2)[-1],
+    "resnet50_infer_int8_interlayer": lambda b:
+        b._build_resnet50_infer_int8(2, int8_activations=True)[-1],
 }
 
 
 @pytest.mark.parametrize("name", sorted(_BUILDERS))
 def test_bench_builder_programs_roundtrip(name):
-    import bench
+    from tools import gate_programs
 
-    _BUILDERS[name](bench)
-    prog = framework.default_main_program()
+    built = _BUILDERS[name](gate_programs)
+    prog = built if isinstance(built, Program) \
+        else framework.default_main_program()
     assert prog.global_block().ops, name
     assert _errors(verify(prog)) == []
     assert _errors(verify(framework.default_startup_program())) == []

@@ -41,10 +41,11 @@ def test_resnet_tiny_cifar_trains():
     assert losses[-1] < losses[0], losses
 
 
-def test_resnet_cifar10_trains_and_benches(cpu_bench_peaks):
+def test_resnet_cifar10_trains_and_benches():
     """resnet_cifar10 (reference tests/book/test_image_classification
     .py:28, the ResNet32 row of float16_benchmark.md:72-74): trains,
-    and the bench leg's bf16+NHWC inference build runs on CPU."""
+    and the two cifar bf16+NHWC inference programs of the lowering
+    gate build and run on CPU."""
     from paddle_tpu.models.resnet import resnet_cifar10
 
     with pytest.raises(ValueError):
@@ -58,12 +59,14 @@ def test_resnet_cifar10_trains_and_benches(cpu_bench_peaks):
                     steps=12, lr=1e-3)
     assert losses[-1] < losses[0], losses
 
-    import bench
+    from tools import gate_programs, tpu_lowering_check
 
-    for leg in ("vgg_cifar", "rn32_cifar"):
-        res = getattr(bench, bench._LEG_FUNCS[leg])(
-            **{**bench._TINY[leg], "chain": 1})
-        assert res["ms_per_batch"] > 0, (leg, res)
+    for which in ("vgg_cifar", "rn32_cifar"):
+        fn, state, feeds = tpu_lowering_check._infer(
+            gate_programs, which, 16)
+        _, (logits,) = fn(state, feeds)
+        assert logits.shape == (16, 10)
+        assert np.isfinite(np.asarray(logits, np.float32)).all()
 
 
 def test_transformer_tiny_trains():

@@ -433,37 +433,28 @@ def test_decode_submit_validation():
 
 
 # ---------------------------------------------------------------------------
-# bench leg + load generator plumbing
+# the lowering gate's decode step, run
 # ---------------------------------------------------------------------------
 
-def test_bench_llm_decode_row_contract(cpu_bench_peaks):
-    import bench
+def test_gate_decode_step_runs_and_ignores_the_page_layout():
+    """tools/gate_programs._build_llm_decode is compiled for the chip
+    by the lowering gate and run nowhere else: at tiny size its step
+    runs, and pages strided through the pool (the layout a
+    disaggregated prefill tier leaves) pick the tokens contiguous
+    pages pick."""
+    from tools import gate_programs
 
-    res = bench.bench_llm_decode(streams=2, prefill_len=8,
-                                 gen_tokens=3, heads=2, head_dim=32,
-                                 page_size=8, vocab=64, warmup=1)
-    for field in ("tokens_per_sec", "inter_token_p50_ms",
-                  "inter_token_p99_ms", "streams", "paged",
-                  "kv_gb_per_step", "kv_bw_pct", "page_size"):
-        assert field in res, field
-    assert res["paged"] is True and res["streams"] == 2
-    res8 = bench.bench_llm_decode(streams=2, prefill_len=8,
-                                  gen_tokens=2, heads=2, head_dim=32,
-                                  page_size=8, vocab=64, warmup=1,
-                                  kv_int8=True)
-    assert res8["kv_int8"] is True
-
-
-def test_workload_sig_keys_decode_variants_apart():
-    import bench
-
-    base = {"streams": 64, "heads": 8, "head_dim": 128, "paged": True}
-    a = bench._workload_sig("llm_decode_flash_str64", base)
-    b = bench._workload_sig("llm_decode_flash_str64_int8kv",
-                            dict(base, kv_int8=True))
-    c = bench._workload_sig("llm_decode_flash_str256",
-                            dict(base, streams=256))
-    assert a != b and a != c and b != c
-    # same workload under a differently-spelled key collapses
-    d = bench._workload_sig("llm_decode_flash", base)
-    assert a == d
+    tiny = dict(streams=2, prefill_len=8, heads=2, head_dim=32,
+                page_size=8)
+    picks = {}
+    for disagg in (False, True):
+        fn, state, feed = gate_programs._build_llm_decode(
+            disagg=disagg, **tiny)
+        new_state, nxt = fn(state, feed)
+        assert nxt.shape == (2,)
+        assert new_state["k_pages"].shape == state["k_pages"].shape
+        picks[disagg] = np.asarray(nxt)
+    assert (picks[True] == picks[False]).all()
+    fn, state, feed = gate_programs._build_llm_decode(spec_k=2, **tiny)
+    _, nxt = fn(state, feed)
+    assert nxt.shape == (2, 3)

@@ -6,8 +6,9 @@ XLA reference vs kernel output under float32 matmul precision, plus
 grad checks through the custom_vjp.  The backward reuses the SAME XLA
 conv vjp the unfused graph runs, so gradients compare bit-exact; the
 forward compares to float tolerance (the kernel's tap-loop reduction
-order differs from XLA's conv reduction — 1x1 convs, a single
-contraction in both, do come out bit-identical and are asserted so).
+order differs from XLA's conv reduction — 1x1 convs in one Cout block,
+a single contraction of one shape in both, do come out bit-identical
+and are asserted so).
 """
 
 import numpy as np
@@ -17,8 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.flags import set_flags
-from paddle_tpu.ops.pallas_conv import (_norm_padding, _reference,
-                                        conv2d_epilogue)
+from paddle_tpu.ops.pallas_conv import (_DEFAULT_BLOCK_CO, _norm_padding,
+                                        _reference, conv2d_epilogue)
 
 
 def _mk(rng, n, h, w, cin, cout, k, oh, ow, has_bias, has_res,
@@ -57,10 +58,21 @@ def test_fused_matches_unfused(case):
         ref = _reference(x, wt, b, r, (s, s), _norm_padding((p, p)),
                          act or "")
     assert fused.shape == (n, oh, ow, cout)
-    if k == 1:
-        # a 1x1 conv is ONE contraction in both paths: bit parity
+    if k == 1 and cout <= _DEFAULT_BLOCK_CO:
+        # a 1x1 conv in one Cout block is ONE contraction of one shape
+        # in both paths: bit parity
         np.testing.assert_array_equal(np.asarray(fused),
                                       np.asarray(ref))
+    elif k == 1:
+        # tiled over Cout the kernel contracts [OH*OW, Cin] x [Cin, 256]
+        # and the reference [.., Cin] x [Cin, 300]: the CPU picks its
+        # vector width and fused multiply-adds by the shape, so the two
+        # round apart by one float32 unit in the last place of an
+        # element (seen: 0.47 of the largest element's)
+        np.testing.assert_allclose(
+            np.asarray(fused), np.asarray(ref), rtol=0,
+            atol=2 * np.finfo(np.float32).eps
+            * np.abs(np.asarray(ref)).max())
     else:
         np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
                                    atol=2e-5)

@@ -179,7 +179,7 @@ def test_sliced_pool_serves_bit_identical(tmp_path, sharded_flag):
 def test_sliced_pool_kill_mid_batch_failover(tmp_path, sharded_flag):
     """Kill-mid-batch failover works PER SLICE: a killed sharded
     replica's batch requeues onto a surviving slice and every request
-    is answered exactly once with the bit-identical output."""
+    is answered exactly once with the unsharded predictor's output."""
     d, probe, _ = _save_model(tmp_path)
     set_flags({"serving_sharded": False})
     base = inference.create_predictor(inference.Config(d))
@@ -197,8 +197,19 @@ def test_sliced_pool_kill_mid_batch_failover(tmp_path, sharded_flag):
             reqs = [srv.submit({"x": probe[i:i + 1]})
                     for i in range(6)]
             outs = [r.result(timeout=60.0)[0] for r in reqs]
+        # the reference ran the 8 probe rows as one batch; here six
+        # one-row requests ride batches of 4 and 2, and the killed
+        # batch is re-run in whatever batch the survivor forms.  The
+        # tp2 program is compiled per batch size, and below 4 rows the
+        # CPU's partitioned matmul rounds another way (the sharded
+        # predictor alone at 1, 2 and 3 rows: 0.3 to 0.8 float32 units
+        # in the last place of the largest output; bit-equal from 4
+        # up, which test_sharded_predictor_tp2_bit_parity holds).  So:
+        # the right answer to 2 such units, whatever batch served it.
+        atol = 2 * np.finfo(np.float32).eps * np.abs(base_out).max()
         for i, o in enumerate(outs):
-            assert np.array_equal(o, base_out[i:i + 1])
+            np.testing.assert_allclose(o, base_out[i:i + 1], rtol=0,
+                                       atol=atol)
         st = srv.stats()
         assert st["accounted"]
         assert sum(1 for r in srv.pool.replicas if r.alive) == 1

@@ -1,11 +1,10 @@
 """Full-model TPU compile gate (tools/tpu_lowering_check.py).
 
 The kernel-level tests (test_pallas_kernels.py, test_chip_compile.py)
-check the kernels in isolation; this checks the COMPLETE bench programs
-(IR build -> transpiles -> autodiff -> optimizer -> jit) compiled by
-the chip's own compiler for a described v5e, i.e. exactly what bench.py
-will ask the chip to run.  A subset runs here; tools/ci.sh runs the
-full sweep.
+check the kernels in isolation; this checks COMPLETE programs
+(tools/gate_programs.py: IR build -> transpiles -> autodiff ->
+optimizer -> jit) compiled by the chip's own compiler for a described
+v5e.  A subset runs here; tools/ci.sh runs the full sweep.
 """
 
 import pytest

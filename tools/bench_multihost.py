@@ -89,8 +89,8 @@ def _parse(argv=None):
 
 # smoke transformer (small on purpose: the leg proves the multi-host
 # gspmd path — mesh spanning hosts, per-host feeds, sharded state
-# commit — not kernel throughput; real MFU rows come from the
-# tf_train_gspmd chaser legs on chip)
+# commit — not kernel throughput; a real MFU comes from the chip,
+# the benchmark's cell tfm_base_train_dp2tp2)
 GSPMD_SMOKE = dict(vocab=512, d_model=64, n_head=4, d_inner=128,
                    n_layer=2)
 
@@ -136,8 +136,8 @@ def _gspmd_build(global_batch, seq, tp):
 
 def _cpu_peak_flops():
     """Nominal per-'chip' peak for MFU on the simulated mesh — an
-    arbitrary 100 GFLOP/s anchor (same spirit as bench.py's unknown-
-    device fallback); real MFU comes from on-chip rows."""
+    arbitrary 100 GFLOP/s anchor; a real MFU comes from the chip
+    (benchmarks/run.py, PERF_LEDGER.jsonl)."""
     import jax
 
     dev = jax.devices()[0]

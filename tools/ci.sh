@@ -80,15 +80,17 @@ print("gspmd smoke OK: dp=%s tp=%s mfu=%s%%"
       % (rec["dp"], rec["tp"], rec["mfu_pct"]))
 PY
 
-echo "== 5/8 no chip, no number (bench.py and chip_smoke.py refuse the CPU) =="
+echo "== 5/8 no chip, no number (benchmarks/run.py and chip_smoke.py refuse the CPU) =="
 # this matrix runs on the CPU.  The benchmark and the chip smoke
 # measure on the chip or not at all: without one each must exit
 # non-zero before it runs anything, print no result and write nothing
 # under docs/.  On the chip: `python chip_smoke.py` (through the chip
 # tool from a sandbox; docs/GETTING_STARTED.md).
 docs_before="$(git status --porcelain docs/)"
-if JAX_PLATFORMS=cpu python bench.py > /tmp/_bench_stdout.json; then
-  echo "bench.py ran without a chip"; exit 1
+if JAX_PLATFORMS=cpu python benchmarks/run.py \
+    --workload tfm_base_train_s512 --seed 1 --seconds 1 --trace 0 \
+    > /tmp/_bench_stdout.json 2> /dev/null; then
+  echo "benchmarks/run.py ran without a chip"; exit 1
 fi
 if JAX_PLATFORMS=cpu python chip_smoke.py > /tmp/_smoke_stdout.json \
     2> /dev/null; then
@@ -99,7 +101,7 @@ fi
 echo "no-chip refusal OK"
 
 echo "== 5b/8 serving load generator (one-JSON-line contract) =="
-# same stdout contract as bench.py: the driver/soak parse this as ONE
+# stdout contract: the driver/soak parse this as ONE
 # JSON line; a short fixed-rate leg proves the generator + server
 # round-trip and the headline fields (docs/SERVING.md)
 JAX_PLATFORMS=cpu python tools/serving_load.py --seconds 1.5 \
@@ -263,32 +265,6 @@ print("forensics gate OK: %s dominates at %.1f%% over %d traces"
       % (rec["dominant"], rec["value"], rec["n_traces"]))
 PY
 
-echo "== 5e/8 perf-regression sentinel (CPU-harness rows vs banked baseline) =="
-# ISSUE 12: the 5b rows (inter-token p50, time_to_first_batch
-# warm/cold, p50/goodput) are diffed against the committed CPU
-# baseline keyed by workload signature — the bench trajectory is
-# machine-gated, not eyeballed.  The 4x band absorbs CI-machine
-# variance and still catches order-of-magnitude breakage.
-JAX_PLATFORMS=cpu python tools/perf_sentinel.py --mode serving \
-  --fresh /tmp/_serving_load.json,/tmp/_serving_load_decode.json \
-  --baseline docs/perf_baseline_cpu.json > /tmp/_sentinel.json
-cat /tmp/_sentinel.json
-python - <<'PY'
-import json
-lines = [ln for ln in open("/tmp/_sentinel.json").read().splitlines()
-         if ln.strip()]
-assert len(lines) == 1, "perf_sentinel stdout must be ONE JSON line"
-rec = json.loads(lines[0])
-assert rec["metric"] == "perf_sentinel"
-assert rec["checked"] >= 6, (
-    "sentinel must actually compare the CPU-harness rows: %r" % rec)
-assert rec["ok"] is True, (
-    "PERF REGRESSION flagged vs docs/perf_baseline_cpu.json: %r"
-    % rec["flagged"])
-print("perf sentinel OK: %d metrics checked, 0 regressions"
-      % rec["checked"])
-PY
-
 echo "== 5f/8 fleet rollout smoke (zero-drop rolling swap + SLO autoscaler) =="
 # ISSUE 13: one seeded rollout iteration — a 3-replica fleet serving
 # live traffic swaps v1 -> v2 replica-by-replica under a chaos plan
@@ -366,22 +342,6 @@ print("disagg serving gate OK: %.1f tok/s, %d/%d handoffs adopted, "
       "0 in transit" % (rec["tokens_per_sec"], h["adopted"],
                         h["offered"]))
 PY
-# the disagg row joins the machine-gated CPU-harness trajectory
-# (baseline re-banked with this PR; disagg_prefill rides the row sig
-# so the tiered run never pairs with the single-tier decode row)
-JAX_PLATFORMS=cpu python tools/perf_sentinel.py --mode serving \
-  --fresh /tmp/_serving_load_disagg.json \
-  --baseline docs/perf_baseline_cpu.json > /tmp/_sentinel_disagg.json
-cat /tmp/_sentinel_disagg.json
-python - <<'PY'
-import json
-rec = json.loads(open("/tmp/_sentinel_disagg.json").read())
-assert rec["metric"] == "perf_sentinel" and rec["ok"] is True, (
-    "PERF REGRESSION flagged on the disagg row: %r"
-    % rec.get("flagged"))
-assert rec["checked"] >= 3, rec
-print("disagg perf sentinel OK: %d metrics checked" % rec["checked"])
-PY
 
 echo "== 6/8 per-op regression gate (hot ops vs committed CPU baseline) =="
 # 3x tolerance absorbs machine load; catches order-of-magnitude
@@ -392,7 +352,7 @@ python tools/op_bench.py --cpu --suite tools/op_bench_suite.json \
 
 echo "== 7/8 TPU compile gate (the chip's compiler, asked without a chip) =="
 # interpret-mode tests never run Mosaic's block-mapping checks; this
-# compiles bench workloads for a described v5e:2x2 on the CPU (Mosaic
+# compiles the gate programs for a described v5e:2x2 on the CPU (Mosaic
 # lowering, VMEM limits, HBM fit of each program).  The suite (step 1)
 # already compiles transformer/deepfm/int8 via
 # tests/test_tpu_lowering_gate.py, so only the rest run here.

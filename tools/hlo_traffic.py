@@ -1,12 +1,10 @@
 """Attribute HLO layout traffic (transpose/copy) to framework ops.
 
-The 2026-08-01 on-chip profile showed the rn50 train step is
-HBM-bound: 50.9 GB accessed vs ~17 GB ideal, with 423 transposes and
-288 copies in the compiled module (tools/profile_resnet.py).  This
-tool names the offenders: it compiles the same step, walks the HLO
-text, sizes every transpose/copy/bitcast-convert by its result shape,
-and aggregates by the op_name metadata JAX attaches — so each GB of
-layout traffic points back at a model layer or an inserted pass.
+Compiles a step, walks the HLO text, sizes every
+transpose/copy/bitcast-convert by its result shape, and aggregates by
+the op_name metadata JAX attaches — so each GB of layout traffic
+points back at a model layer or an inserted pass.  It reads HLO and
+times nothing.
 
 Usage: python tools/hlo_traffic.py [--model resnet50|transformer]
            [--batch N] [--top 25] [--min-mb 1]
@@ -185,15 +183,15 @@ def roofline_rows(hlo_text):
 
 
 def build_resnet(batch, nhwc=True, bf16=True, conv_bn_stats=False):
-    """conv_bn_stats=True builds the EXACT bench graph of the
-    rn_train_convbnstats leg (fuse_conv_bn_train + AMP + NHWC) so the
+    """conv_bn_stats=True builds the lowering gate's
+    resnet50_train_convbnstats graph (fuse_conv_bn_train + AMP + NHWC) so the
     roofline can show the BN-moment re-read of the conv output is gone
     — the ISSUE 4 acceptance check.  The default build stays the plain
     local construction below (kept so historical reports diff)."""
     if conv_bn_stats:
         import jax
 
-        from bench import _build_resnet50_train
+        from tools.gate_programs import _build_resnet50_train
         from paddle_tpu.flags import set_flags
 
         out = _build_resnet50_train(batch, conv_bn_stats=True)[:3]
@@ -220,7 +218,7 @@ def _build_resnet_plain(batch, nhwc=True, bf16=True):
     from paddle_tpu import framework, optimizer
     from paddle_tpu.models.resnet import resnet50
     from paddle_tpu.transpiler import nhwc_transpile
-    from bench import _build_compiled_fn, _fresh_programs
+    from tools.gate_programs import _build_compiled_fn, _fresh_programs
 
     _fresh_programs()
     model = resnet50(is_test=False)
@@ -366,7 +364,7 @@ def op_boundary_rows(program, state, feed):
 
 def int8_interlayer_report(batch, min_reduction_pct):
     """ISSUE-5 acceptance check, three instruments over the EXACT
-    bench recipes (bench._build_resnet50_infer_int8):
+    gate programs (gate_programs._build_resnet50_infer_int8):
 
     1. compiled s8 evidence — the interlayer module must carry at
        least one activation-sized s8 tensor per folded edge (assert);
@@ -382,14 +380,14 @@ def int8_interlayer_report(batch, min_reduction_pct):
        emulation upcasts, which TPU's MXU lowering doesn't have.)
 
     Returns process exit code."""
-    import bench
     from paddle_tpu.core.scope import Scope, scope_guard
+    from tools import gate_programs
 
     rows = {}
     for name, inter in (("calibrated", False), ("interlayer", True)):
         with scope_guard(Scope()):
-            fn, state, feed, _fetch, _nq, calib, prog = \
-                bench._build_resnet50_infer_int8(
+            fn, state, feed, _fetch, calib, prog = \
+                gate_programs._build_resnet50_infer_int8(
                     batch, int8_activations=inter)
             comp = fn.lower(state, feed).compile()
             btotal, brows = op_boundary_rows(prog, state, feed)
@@ -442,13 +440,12 @@ def int8_interlayer_report(batch, min_reduction_pct):
 
 
 def build_deepfm(batch):
-    """The bench DeepFM train step, byte-attributable: the CTR leg is
-    a gather/scatter workload, so its roofline lives in this report
-    (embedding lookups, segment-sum grads, Adam state), not in MFU —
-    VERDICT r5 next-round #7."""
-    import bench
+    """The DeepFM train step, byte-attributable: CTR is a
+    gather/scatter workload, so what bounds it is in this report
+    (embedding lookups, segment-sum grads, Adam state), not in MFU."""
+    from tools import gate_programs
 
-    fn, state, feed, _loss = bench._build_deepfm_train(batch)
+    fn, state, feed, _loss = gate_programs._build_deepfm_train(batch)
     return fn, state, feed
 
 

@@ -4,8 +4,7 @@ bf16 on ResNet32-cifar10, on CPU (emulated int8) / interpret mode.
 The reference publishes accuracy ALONGSIDE throughput for its int8
 pipeline (/root/reference/paddle/fluid/inference/tests/api/
 int8_mkldnn_quantization.md — per-model top-1 deltas); the repo so far
-had bit-exactness unit tests and a banked latency row (9.56 ms rn50
-mb128) but no end-to-end prediction-level bound — "an int8 number
+had bit-exactness unit tests but no end-to-end prediction-level bound — "an int8 number
 without an accuracy bound is half a result" (VERDICT r5 #2 /
 next-round #4, accuracy half).
 
@@ -14,7 +13,7 @@ transpile pipelines — f32 reference, bf16 (the production inference
 path: conv+bn fold is skipped, NHWC + bf16_transpile), and calibrated
 int8 (conv+bn fold + NHWC + per-channel abs-max weights + static
 InScale activation scales from a calibration batch + bf16 inter-layer,
-exactly bench._build_resnet50_infer_int8's recipe) — then compare
+exactly tools/gate_programs._build_resnet50_infer_int8's recipe) — then compare
 top-1 predictions over N held-out inputs.  No trained checkpoint
 exists in this environment, so inputs are synthetic and the metric is
 top-1 AGREEMENT between paths (delta_pp = 100 - agreement%): the same
@@ -43,9 +42,9 @@ sys.path.insert(0, REPO)
 
 
 def _fresh():
-    import bench
+    from tools import gate_programs
 
-    bench._fresh_programs()
+    gate_programs._fresh_programs()
 
 
 def _predict_fn(kind):
@@ -73,8 +72,8 @@ def _predict_fn(kind):
             convert_to_int8_execution, post_training_quantize,
             quantize_weights_abs_max)
 
-        # same recipe as the banked rn50 int8 latency row
-        # (bench._build_resnet50_infer_int8): fold conv+bn, NHWC,
+        # same recipe as the rn50 int8 gate program
+        # (gate_programs._build_resnet50_infer_int8): fold conv+bn, NHWC,
         # per-channel abs-max weights, static InScale from a
         # calibration batch, bf16 inter-layer activations;
         # "int8_interlayer" additionally runs the ISSUE-5 interlayer
@@ -154,7 +153,7 @@ def run(n=256, batch=64, int8_activations=True):
         "bf16_vs_f32_pp": delta_pp("bf16", "f32"),
         "recipe": "calibrated static InScale + per-channel abs-max "
                   "weights + conv-bn fold + bf16 inter-layer "
-                  "(= the banked int8 latency rows)",
+                  "(= the lowering gate's int8 graph)",
         "inputs": "synthetic (no trained checkpoint in this env); "
                   "agreement bound, conservative vs a trained net",
     }

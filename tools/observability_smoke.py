@@ -44,7 +44,7 @@ ISSUE 12 legs:
     store, and /fleetz must parse with both processes present.
 
 stdout contract: EXACTLY ONE JSON line (the same driver/gate shape as
-bench.py / serving_load.py); progress goes to stderr.  Exit 0 iff every
+serving_load.py); progress goes to stderr.  Exit 0 iff every
 assertion held.
 """
 

@@ -2,10 +2,9 @@
 
 Drives an in-process InferenceServer (CPU, tiny fc model) with a
 seeded Poisson arrival stream and reports goodput vs offered load and
-the latency distribution of admitted requests — the
-"millions of users" counterpart of bench.py's throughput rows.
+the latency distribution of admitted requests.
 
-stdout contract (gated in tools/ci.sh like bench stdout): EXACTLY ONE
+stdout contract (gated in tools/ci.sh): EXACTLY ONE
 JSON line; progress goes to stderr.  Headline fields:
 
     {"metric": "serving_goodput", "value": <goodput_qps>, "unit":

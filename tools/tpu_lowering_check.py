@@ -1,17 +1,17 @@
-"""Compile every bench workload for the TPU — on the CPU, without a chip.
+"""Compile every gate program for the TPU — on the CPU, without a chip.
 
 Why this exists: Pallas interpret mode (what CPU tests run) never
 enforces Mosaic's TPU block-mapping rules, so a kernel can pass the
 whole suite and still be rejected on the chip.  That exact failure
 shipped once: a [1, bq] lse block spec crashed the first on-hardware
-transformer bench while 546 CPU tests were green.
+transformer step while 546 CPU tests were green.
 
 The chip's compiler is installed here and compiles for a chip that is
 DESCRIBED and not attached (on-chip-measurement guide, section 2, third
 rehearsal): jax.experimental.topologies describes a `v5e:2x2`, and
 `jit(step).lower(avals placed on a described device).compile()` raises
-what the chip's compiler would raise.  This tool builds the EXACT
-programs bench.py times (same builders, same shapes) and compiles each
+what the chip's compiler would raise.  This tool builds the programs
+of tools/gate_programs.py at real size and compiles each
 that way: one-chip programs for described device 0, the sharded
 programs over a mesh of the four described devices.
 
@@ -109,28 +109,28 @@ ON_MESH = ("transformer_train_gspmd", "serving_tp_sharded")
 
 
 def _workloads():
-    import bench
+    from tools import gate_programs as progs
 
     return {
-        "transformer_train": lambda: bench._build_transformer_train(
+        "transformer_train": lambda: progs._build_transformer_train(
             32, 512)[:3],
-        "resnet50_train": lambda: bench._build_resnet50_train(128)[:3],
-        "resnet50_train_s2d": lambda: bench._build_resnet50_train(
+        "resnet50_train": lambda: progs._build_resnet50_train(128)[:3],
+        "resnet50_train_s2d": lambda: progs._build_resnet50_train(
             128, s2d=True)[:3],
         # fused conv-epilogue Pallas graphs (ops/pallas_conv.py):
         # interpret-mode tests never enforce Mosaic's tiling/lowering
         # rules, so the convep A/B legs must compile here BEFORE a
         # chip call is spent on them (the flash [1,bq] lse lesson)
-        "resnet50_train_convep": lambda: bench._build_resnet50_train(
+        "resnet50_train_convep": lambda: progs._build_resnet50_train(
             128, conv_epilogue=True)[:3],
         "resnet50_infer_convep": lambda: _infer(
-            bench, "resnet", 128, conv_epilogue=True),
+            progs, "resnet", 128, conv_epilogue=True),
         # conv+BN-stats train-chain fusion (ISSUE 4): the stat sibling
         # outputs' (1, bco) blocks and the one-pass normalize kernel's
         # row blocks are exactly the construct class Mosaic may reject
         # while interpret mode stays green
         "resnet50_train_convbnstats": lambda:
-            bench._build_resnet50_train(128, conv_bn_stats=True)[:3],
+            progs._build_resnet50_train(128, conv_bn_stats=True)[:3],
         # flash memory-overhaul variants (ops/pallas_kernels.py): the
         # packed (bq/128, 128) row-stats block and the in-kernel
         # (bq,)<->(bq/128, 128) relayout are EXACTLY the construct
@@ -138,27 +138,27 @@ def _workloads():
         # the ISSUE's stated risk (the strided-slice lesson from the
         # convep round).  seq 4096 keeps the build fast while
         # block_q=1024 makes the packed gate real.
-        "longctx_train_packed": lambda: bench._build_longctx_train(
+        "longctx_train_packed": lambda: progs._build_longctx_train(
             1, 8, 4096, 64, block_q=1024, block_k=1024,
             packed_stats=True)[:3],
-        "longctx_train_hp2": lambda: bench._build_longctx_train(
+        "longctx_train_hp2": lambda: progs._build_longctx_train(
             1, 8, 4096, 64, block_q=1024, block_k=1024,
             head_pack=True)[:3],
-        "longctx_train_packed_hp2": lambda: bench._build_longctx_train(
+        "longctx_train_packed_hp2": lambda: progs._build_longctx_train(
             1, 8, 4096, 64, block_q=1024, block_k=1024,
             packed_stats=True, head_pack=True)[:3],
         # the fused multi-tensor Adam tail (optimizer.py
         # Adam(fuse=True)): concat/split over every param must lower
         # for tpu before the batch-slide A/B leg runs
         "transformer_train_fusedadam": lambda:
-            bench._build_transformer_train(8, 512, fused_adam=True)[:3],
+            progs._build_transformer_train(8, 512, fused_adam=True)[:3],
         # ISSUE 17: the unified-epilogue fc anchor — the fused
         # matmul+bias+residual+act kernel's (bm, bn) output blocks and
         # full-K operand blocks are new Mosaic surface the plain mul
         # lowering never sees (the conv workloads above gate the conv
         # anchors of the same stage grammar)
         "transformer_train_fcep": lambda:
-            bench._build_transformer_train(8, 512,
+            progs._build_transformer_train(8, 512,
                                            fc_epilogue=True)[:3],
         # ISSUE 17: the greedy logits tail (the epilogue grammar's
         # terminal argmax stage, shared by the decode engine's step,
@@ -172,7 +172,7 @@ def _workloads():
         # lowering never sees.  Built over the four DESCRIBED devices:
         # dp2 x tp2, the mesh `chip_smoke.py --chips 4` runs.
         "transformer_train_gspmd": lambda:
-            bench._build_transformer_train(
+            progs._build_transformer_train(
                 8, 512, gspmd=True, tp=2,
                 devices=described_devices())[:3],
         # ISSUE 14: the tp-sharded serving-INFERENCE graph — one jit
@@ -180,25 +180,25 @@ def _workloads():
         # column-parallel fc weights + the inter-layer all-gathers
         # the SPMD partitioner inserts: SPMD surface the unsharded
         # predictor lowering never sees.  Over two described devices.
-        "serving_tp_sharded": lambda: bench._build_serving_tp_sharded(
+        "serving_tp_sharded": lambda: progs._build_serving_tp_sharded(
             tp=2, devices=described_devices())[:3],
         # ISSUE 14: the disagg decode graph — the flash_decode step
         # over handoff-fragmented block tables (pages strided across
         # the pool in prefill-completion order).  The kernel walks
         # the table through scalar prefetch either way.
-        "llm_decode_disagg": lambda: bench._build_llm_decode(
+        "llm_decode_disagg": lambda: progs._build_llm_decode(
             streams=8, prefill_len=64, heads=8, head_dim=128,
-            page_size=128, disagg=True)[:3],
-        "bert_train": lambda: bench._build_bert_train(8, 512)[:3],
-        "deepfm_train": lambda: bench._build_deepfm_train(2048)[:3],
+            page_size=128, disagg=True),
+        "bert_train": lambda: progs._build_bert_train(8, 512)[:3],
+        "deepfm_train": lambda: progs._build_deepfm_train(2048)[:3],
         "resnet50_infer_int8": lambda:
-            bench._build_resnet50_infer_int8(128)[:3],
+            progs._build_resnet50_infer_int8(128)[:3],
         # ISSUE 5: the int8-interlayer graph — s8-in convs, raw-s32
         # accumulator outputs and the fused requantize epilogue are
         # exactly the lowering surface Mosaic/XLA:TPU may reject while
         # the CPU suite stays green
         "resnet50_infer_int8_interlayer": lambda:
-            bench._build_resnet50_infer_int8(
+            progs._build_resnet50_infer_int8(
                 128, int8_activations=True)[:3],
         # ISSUE 7: the paged-KV flash-decode step — scalar-prefetch
         # block-table index maps, the (1, hpb, page_size, d) page
@@ -206,32 +206,32 @@ def _workloads():
         # are exactly the construct class Mosaic may reject while the
         # interpret suite stays green; every variant flag compiles
         # here
-        "llm_decode": lambda: bench._build_llm_decode(
+        "llm_decode": lambda: progs._build_llm_decode(
             streams=8, prefill_len=64, heads=8, head_dim=128,
-            page_size=128)[:3],
-        "llm_decode_d64_hp2": lambda: bench._build_llm_decode(
+            page_size=128),
+        "llm_decode_d64_hp2": lambda: progs._build_llm_decode(
             streams=8, prefill_len=64, heads=8, head_dim=64,
-            page_size=128, head_pack=True)[:3],
-        "llm_decode_int8kv": lambda: bench._build_llm_decode(
+            page_size=128, head_pack=True),
+        "llm_decode_int8kv": lambda: progs._build_llm_decode(
             streams=8, prefill_len=64, heads=8, head_dim=128,
-            page_size=128, kv_int8=True)[:3],
-        "llm_decode_bf16": lambda: _llm_decode_bf16(bench),
+            page_size=128, kv_int8=True),
+        "llm_decode_bf16": lambda: _llm_decode_bf16(progs),
         # ISSUE 11c: the q-len-(k+1) speculative VERIFY step — the
         # per-row causal mask (min(kv_len, kv_len-R+1+row) over a row
         # iota) and the 16-sublane query block at R > 8 are new
         # Mosaic surface the q-len-1 gate never sees
-        "llm_decode_spec_k4": lambda: bench._build_llm_decode(
+        "llm_decode_spec_k4": lambda: progs._build_llm_decode(
             streams=8, prefill_len=64, heads=8, head_dim=128,
-            page_size=128, spec_k=4)[:3],
-        "llm_decode_spec_k8": lambda: bench._build_llm_decode(
+            page_size=128, spec_k=4),
+        "llm_decode_spec_k8": lambda: progs._build_llm_decode(
             streams=8, prefill_len=64, heads=8, head_dim=128,
-            page_size=128, spec_k=8)[:3],
-        "resnet50_infer": lambda: _infer(bench, "resnet", 128),
-        "vgg16_infer": lambda: _infer(bench, "vgg", 64),
-        "vgg16_cifar_infer": lambda: _infer(bench, "vgg_cifar", 512),
-        "resnet32_cifar_infer": lambda: _infer(bench, "rn32_cifar",
+            page_size=128, spec_k=8),
+        "resnet50_infer": lambda: _infer(progs, "resnet", 128),
+        "vgg16_infer": lambda: _infer(progs, "vgg", 64),
+        "vgg16_cifar_infer": lambda: _infer(progs, "vgg_cifar", 512),
+        "resnet32_cifar_infer": lambda: _infer(progs, "rn32_cifar",
                                                512),
-        "longctx_train": lambda: bench._build_longctx_train()[:3],
+        "longctx_train": lambda: progs._build_longctx_train()[:3],
     }
 
 
@@ -247,15 +247,15 @@ def _decode_greedy_tail():
     return fn, {}, feed
 
 
-def _llm_decode_bf16(bench):
+def _llm_decode_bf16(progs):
     import jax.numpy as jnp
 
-    return bench._build_llm_decode(
+    return progs._build_llm_decode(
         streams=8, prefill_len=64, heads=8, head_dim=64,
-        page_size=128, dtype=jnp.bfloat16)[:3]
+        page_size=128, dtype=jnp.bfloat16)
 
 
-def _infer(bench, which, batch, conv_epilogue=False):
+def _infer(progs, which, batch, conv_epilogue=False):
     import jax.numpy as jnp
     import numpy as np
 
@@ -294,7 +294,7 @@ def _infer(bench, which, batch, conv_epilogue=False):
             "image": jnp.asarray(
                 rng.rand(batch, 3, 224, 224).astype(np.float32),
                 jnp.bfloat16)}
-    return bench._build_infer(lambda: build(is_test=True), feed,
+    return progs._build_infer(lambda: build(is_test=True), feed,
                               "logits",
                               conv_epilogue=conv_epilogue)[:3]
 
@@ -303,13 +303,13 @@ FAST_SKIP = ("resnet50_train", "bert_train")
 
 
 def check_workload(name, build):
-    """Build the bench program and compile its jitted step for the
+    """Build the gate program and compile its jitted step for the
     described chip.  Returns (ok, detail, seconds); detail of a compile
     that passed is its memory_analysis() and kernel count."""
     t0 = time.time()
     # Force the Pallas path during tracing: impl auto-detection sees a
     # CPU device in this process, but the program we must validate is
-    # the one the bench traces ON THE CHIP (where _on_tpu() is True).
+    # the one traced ON THE CHIP (where _on_tpu() is True).
     import paddle_tpu.ops.pallas_kernels as pk
 
     orig = pk._on_tpu

@@ -9,7 +9,7 @@ registry key).
 A legal workload must produce ZERO error diagnostics end to end; any
 pass that hands broken IR forward fails the sweep with a typed
 diagnostic naming the pass, the block/op-index, and the var
-(docs/ANALYSIS.md).  Shapes are _TINY-scale: the property under test
+(docs/ANALYSIS.md).  Shapes are tiny: the property under test
 is IR structure, not perf.
 
 Usage: python tools/verifier_sweep.py [--json] [workload ...]
@@ -36,7 +36,7 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 
-def _rn32_infer(bench, conv_epilogue=False):
+def _rn32_infer(progs, conv_epilogue=False):
     import jax.numpy as jnp
     import numpy as np
 
@@ -47,11 +47,11 @@ def _rn32_infer(bench, conv_epilogue=False):
         "image": jnp.asarray(rng.rand(8, 3, 32, 32).astype(np.float32),
                              jnp.bfloat16),
         "label": np.zeros((8, 1), np.int64)}
-    return bench._build_infer(lambda: build(is_test=True), feed,
+    return progs._build_infer(lambda: build(is_test=True), feed,
                               "logits", conv_epilogue=conv_epilogue)
 
 
-def _vgg_cifar_infer(bench):
+def _vgg_cifar_infer(progs):
     import jax.numpy as jnp
     import numpy as np
 
@@ -61,7 +61,7 @@ def _vgg_cifar_infer(bench):
     feed = lambda: {  # noqa: E731
         "image": jnp.asarray(rng.rand(8, 3, 32, 32).astype(np.float32),
                              jnp.bfloat16)}
-    return bench._build_infer(
+    return progs._build_infer(
         lambda: vgg(16, class_dim=10, img_shape=(3, 32, 32),
                     is_test=True),
         feed, "logits")
@@ -76,30 +76,30 @@ def _workloads():
     engine builds no Program IR (its step is a jax function over the
     paged cache), so it has no entry here — its serving contracts are
     gated by ci.sh 5b/5g and the chaos soak."""
-    import bench
+    from tools import gate_programs as progs
 
     return {
         "transformer_train": lambda:
-            bench._build_transformer_train(2, 64),
+            progs._build_transformer_train(2, 64),
         "transformer_train_fusedadam": lambda:
-            bench._build_transformer_train(2, 64, fused_adam=True),
+            progs._build_transformer_train(2, 64, fused_adam=True),
         # ISSUE 17: the unified epilogue pass (fc anchor) under full
         # verification — the fuse rewrite, the stamped epilogue attrs
         # (the epilogue-spec rule re-parses every one) and the derived
         # fc_epilogue_grad ops all sweep
         "transformer_train_fcep": lambda:
-            bench._build_transformer_train(2, 64, fc_epilogue=True),
+            progs._build_transformer_train(2, 64, fc_epilogue=True),
         "transformer_train_gspmd": lambda:
-            bench._build_transformer_train(2, 64, gspmd=True, tp=2),
-        "deepfm_train": lambda: bench._build_deepfm_train(64),
-        "resnet32_cifar_infer": lambda: _rn32_infer(bench),
+            progs._build_transformer_train(2, 64, gspmd=True, tp=2),
+        "deepfm_train": lambda: progs._build_deepfm_train(64),
+        "resnet32_cifar_infer": lambda: _rn32_infer(progs),
         "resnet32_cifar_infer_convep": lambda:
-            _rn32_infer(bench, conv_epilogue=True),
-        "vgg16_cifar_infer": lambda: _vgg_cifar_infer(bench),
+            _rn32_infer(progs, conv_epilogue=True),
+        "vgg16_cifar_infer": lambda: _vgg_cifar_infer(progs),
         "resnet50_infer_int8": lambda:
-            bench._build_resnet50_infer_int8(2),
+            progs._build_resnet50_infer_int8(2),
         "resnet50_infer_int8_interlayer": lambda:
-            bench._build_resnet50_infer_int8(2, int8_activations=True),
+            progs._build_resnet50_infer_int8(2, int8_activations=True),
     }
 
 
@@ -107,13 +107,12 @@ def sweep_workload(name, build):
     from paddle_tpu import framework
     from paddle_tpu.analysis import check_shapes, verify
     from paddle_tpu.flags import set_flags
-
-    import bench
+    from tools import gate_programs as progs
 
     t0 = time.time()
     # a fresh default program per workload: a builder that constructs
     # no IR must read as empty, not as the previous workload's graph
-    bench._fresh_programs()
+    progs._fresh_programs()
     set_flags({"ir_verify": "full"})
     try:
         build()
