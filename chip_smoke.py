@@ -542,6 +542,31 @@ def phase_kernels(cfg, watch, impl=None, expect="pallas"):
         TOL["bf16"], resolved, shape=[b, h, t, d], dtype="bfloat16",
         causal=True)
 
+    # -- the same heads token-major, [B, T, H*d] as a projection leaves
+    # them: the kernels address them in place, and must give what they
+    # give head-major (delta is summed in another order: a few ulps)
+    def token_major_loss(q, k, v):
+        o = pk.flash_attention(q, k, v, causal=True, impl=impl, heads=h)
+        return (o.astype(jnp.float32)
+                * pk._merge_heads(w).astype(jnp.float32)).sum(), o
+
+    layouts = kernel_impls()
+    ((_, o_t), g_t), resolved = run_resolved(
+        "flash_attention", expect, lambda: jax.jit(jax.value_and_grad(
+            token_major_loss, argnums=(0, 1, 2), has_aux=True))(
+                *(pk._merge_heads(x) for x in (q, k, v))))
+    layouts = impls_since(layouts)
+    check(layouts.get(("flash_attention_layout", "token_major"))
+          and not layouts.get(("flash_attention_layout", "head_major")),
+          "flash_attention on [B, T, H*d] did not address the heads in "
+          "place: %s" % fmt_impls(layouts))
+    _compare("flash_attention_token_major", {
+        "out": (o_t, pk._merge_heads(o_k)),
+        **{n: (g, pk._merge_heads(r))
+           for n, g, r in zip(("dq", "dk", "dv"), g_t, g_k)}},
+        TOL["bf16"], resolved, shape=[b, t, h * d], dtype="bfloat16",
+        causal=True)
+
     # -- flash_decode against flash_decode_reference ----------------------
     dc = cfg["decode"]
     nb, nh, hd, ps, mp = (dc["batch"], dc["heads"], dc["head_dim"],

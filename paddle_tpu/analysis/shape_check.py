@@ -271,9 +271,13 @@ def check_sharding(program, plan, raise_=True, label=""):
             continue
         qname = (op.inputs.get("Q") or [None])[0]
         qvar = gb.vars.get(qname) if qname else None
-        if qvar is None or qvar.shape is None or len(qvar.shape) != 4:
+        heads = op.attrs.get("heads") or None
+        if qvar is None or qvar.shape is None \
+                or len(qvar.shape) != (3 if heads else 4):
             continue
-        B, H = qvar.shape[0], qvar.shape[1]
+        # token-major [B, T, H*D] with the head count an attr, or
+        # [B, H, T, D]
+        B, H = qvar.shape[0], heads or qvar.shape[1]
         for axis, extent, what in ((ba, B, "batch"), (ha, H, "head")):
             if axis is None:
                 continue
@@ -292,6 +296,18 @@ def check_sharding(program, plan, raise_=True, label=""):
                     "back to the unsharded kernel SILENTLY at trace "
                     "time",
                     block_idx=0, op_idx=i, op_type=op.type))
+        tp = plan.axis_size(ha) if ha in plan.axes else 1
+        if heads and H % tp == 0 and qvar.shape[2] >= 0 \
+                and qvar.shape[2] // H == 64 and (H // tp) % 2:
+            diags.append(Diagnostic(
+                "attention-head-layout",
+                f"{H // tp} heads of 64 lanes a shard do not fill "
+                "128-lane blocks: every shard transposes q, k, v and "
+                "out to [B, H, T, D] and back (the same numbers at "
+                "the cost of the copies; ops/pallas_kernels.py "
+                "_flash_layout)",
+                severity=_WARNING, block_idx=0, op_idx=i,
+                op_type=op.type))
     if any(t[2] for t in tagged):
         for i, op in enumerate(gb.ops):
             if op.type != "flash_attention_grad":

@@ -921,15 +921,20 @@ def sequence_mask(x, maxlen=None, dtype="int64", name=None):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, name=None):
+                    block_k=None, name=None, n_head=None):
     """Fused blockwise attention (Pallas TPU kernel; ops/pallas_kernels.py).
 
-    q/k/v: [B, H, T, D] post-split-heads.  Replaces the reference's
-    matmul+softmax+matmul composition (nets.py scaled_dot_product_attention)
-    with a single kernel that never materializes the [Tq, Tk] score matrix.
-    block_q/block_k override the kernel tile sizes (default picked by
-    sequence length: 1024 for T >= 1024, else 512 — pinned by the
-    2026-08-01 v5e sweep; PERF.md section 6, PR 21).
+    q/k/v: [B, H, T, D] post-split-heads, or, with n_head, token-major
+    [B, T, H*D] as the q, k and v projections leave them: Out is then
+    [B, Tq, H*D], what the output projection takes, and no head split
+    or merge is made (where the kernels cannot address the heads in
+    place the op transposes inside, to the same answer).  Replaces the
+    reference's matmul+softmax+matmul composition (nets.py
+    scaled_dot_product_attention) with a single kernel that never
+    materializes the [Tq, Tk] score matrix.  block_q/block_k override
+    the kernel tile sizes (default picked by sequence length: 1024 for
+    T >= 1024, else 512 — pinned by the 2026-08-01 v5e sweep; PERF.md
+    section 6, PR 21).
 
     Returns Out.  The op also writes LSE, the per-row log-sum-exp
     (float32 [B, H, Tq], no gradient): the residual flash_attention_grad
@@ -942,7 +947,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         type="flash_attention", inputs={"Q": q, "K": k, "V": v},
         outputs={"Out": out, "LSE": lse},
         attrs={"causal": causal, "scale": float(scale or 0.0),
-               "block_q": int(block_q or 0), "block_k": int(block_k or 0)})
+               "block_q": int(block_q or 0), "block_k": int(block_k or 0),
+               "heads": int(n_head or 0)})
     return out
 
 

@@ -70,13 +70,17 @@ def multi_head_attention(q_in, kv_in, d_model, n_head, dropout_rate=0.0,
     v = layers.fc(kv_in, d_model, num_flatten_dims=2, bias_attr=False,
                   param_attr=_w(pfx, "v"))
 
-    q = _split_heads(q, tq, n_head, head_dim)
-    k = _split_heads(k, tk, n_head, head_dim)
-    v = _split_heads(v, tk, n_head, head_dim)
     weight_dropout = bool(dropout_rate) and not is_test
     if use_flash and not weight_dropout and attn_bias is None:
-        out = layers.flash_attention(q, k, v, causal=causal)
+        # the fc outputs as they are, [B, T, H*hd], and Out as the
+        # output fc takes it: the kernels address the heads in place
+        out = layers.flash_attention(q, k, v, causal=causal,
+                                     n_head=n_head)
     else:
+        # the unfused composition needs [B, H, Tq, Tk]
+        q = _split_heads(q, tq, n_head, head_dim)
+        k = _split_heads(k, tk, n_head, head_dim)
+        v = _split_heads(v, tk, n_head, head_dim)
         attn = layers.matmul(q, k, transpose_y=True,
                              alpha=float(head_dim) ** -0.5)  # [B,H,Tq,Tk]
         if causal:
@@ -94,9 +98,8 @@ def multi_head_attention(q_in, kv_in, d_model, n_head, dropout_rate=0.0,
                 weights, dropout_rate,
                 dropout_implementation="upscale_in_train")
         out = layers.matmul(weights, v)  # [B,H,Tq,hd]
-
-    out = layers.transpose(out, [0, 2, 1, 3])
-    out = layers.reshape(out, [-1, tq, d_model])
+        out = layers.transpose(out, [0, 2, 1, 3])
+        out = layers.reshape(out, [-1, tq, d_model])
     return layers.fc(out, d_model, num_flatten_dims=2, bias_attr=False,
                      param_attr=_w(pfx, "out"))
 

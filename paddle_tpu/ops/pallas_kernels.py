@@ -10,13 +10,26 @@ Pallas kernel: blockwise QK^T on the MXU with online-softmax
 accumulation in VMEM scratch — the [Tq, Tk] matrix never leaves VMEM
 (FlashAttention pattern).
 
-Layout: q/k/v are [B, H, T, D] (the transformer model's post-split-heads
-layout); v, the output and its gradient may have a head size Dv of their
-own (latent attention: q.k 192, v 128).  Grid is (B*H/hpb, Tq/block_q,
+Layout: two, and ONE set of kernel bodies (docs/FLASH_ATTENTION.md).
+Head-major q/k/v are [B, H, T, D] (what a caller that builds its heads
+itself has: latent attention, whose v, output and its gradient have a
+head size Dv of their own, q.k 192, v 128; ring and Ulysses).
+Token-major they are [B, T, H*D], as the q, k and v projections leave
+their matmuls and as the output projection wants the result back: the
+kernels address head h through the BlockSpec index maps (`_Tiles`), so
+no [B, H, T, D] copy of q, k, v, out or a gradient is ever made (96
+copies of 33.5 MB in a six-layer step at 64 x 512; PERF.md, PR 31).  A
+block's last dim must be a multiple of 128 lanes: at D = 64 a block
+holds two heads, at 128 one, and a head's tile is the 128 lanes with
+the other head's zeroed (`_head_tile`), which costs no MXU pass on a
+128 x 128 array.  `_flash_layout` picks from the operands' rank, head
+size and head count; a token-major call the blocks cannot serve is
+transposed inside the entry.  Grid is (B*H/hpb, Tq/block_q,
 Tk/block_k) with the KV
 dimension innermost so the (acc, m, l) scratch carries across KV steps;
-hpb is the heads-per-block packing factor (1, or 2 under the
-`flash_head_pack` flag — see below).
+hpb is the heads a step takes: head-major 1, or 2 under the
+`flash_head_pack` flag (see below); token-major the heads of a lane
+block, 128/D.
 
 The public `flash_attention` is differentiable via ONE custom_vjp
 (`_flash_lse`, shared with `flash_attention_lse` and the IR op): forward
@@ -220,13 +233,37 @@ def _stat_rows(ref, h, block_q, packed):
     return ref[h, :, 0]
 
 
+def _head_tile(ref, h, hpb, token_major):
+    """Head-slot h's [rows, width] tile of a q/k/v/dO block.
+
+    Head-major, the block is [hpb, rows, d] and the tile its h-th
+    entry.  Token-major, the block is [1, rows, 128]: the hpb heads of
+    one lane block side by side, and the tile is ALL 128 lanes with the
+    other head's zeroed.  Nothing is sliced or shuffled across lanes: a
+    contraction over the 128 lanes adds exact zeros to head h's 64
+    products in float32, and a product that is 128 lanes wide holds
+    head h's result in its lanes and exact zeros in the others, so the
+    tiles of a block's heads ADD to the block.  On a 128 x 128 array a
+    64-deep contraction and a 64-wide output cost a full pass already
+    (PERF.md section 5)."""
+    if not token_major:
+        return ref[h]
+    x = ref[0]
+    if hpb == 1:
+        return x
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    d = x.shape[1] // hpb
+    mine = (lane >= h * d) & (lane < (h + 1) * d)
+    return jnp.where(mine, x, jnp.zeros_like(x))
+
+
 # ---------------------------------------------------------------------------
 # pallas forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                 l_ref, *, scale, causal, block_q, block_k, kv_len,
-                q_off, packed, hpb):
+                q_off, packed, hpb, token_major=False):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -267,9 +304,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         # interleaves their MXU and VPU work within the step (the whole
         # point of hpb=2 at d<=64)
         for h in range(hpb):
-            q = q_ref[h]                  # [bq, d]
-            k = k_ref[h]                  # [bk, d]
-            v = v_ref[h]
+            q = _head_tile(q_ref, h, hpb, token_major)    # [bq, d]
+            k = _head_tile(k_ref, h, hpb, token_major)    # [bk, d]
+            v = _head_tile(v_ref, h, hpb, token_major)
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
             if masked:
@@ -305,7 +342,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         for h in range(hpb):
             l = l_ref[h, :, 0]
             l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0 out
-            o_ref[h, ...] = (acc_ref[h] / l[:, None]).astype(o_ref.dtype)
+            out = acc_ref[h] / l[:, None]
+            if not token_major:
+                o_ref[h, ...] = out.astype(o_ref.dtype)
+            elif h == 0:
+                block = out
+            else:
+                # each head's acc is zero off its lanes (_head_tile)
+                block = block + out
             # log-sum-exp per row, consumed by the backward kernels; for
             # a fully-masked row m=-inf and l was clamped to 1 ->
             # lse=-inf, whose exp(s - lse) entries are all masked off in
@@ -324,6 +368,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                 # enforce tiling)
                 lse_ref[h, ...] = jnp.broadcast_to(rows[:, None],
                                                    lse_ref.shape[1:])
+        if token_major:
+            o_ref[0] = block.astype(o_ref.dtype)
 
 
 def _pad_axis(x, axis, mult):
@@ -336,16 +382,133 @@ def _pad_axis(x, axis, mult):
     return jnp.pad(x, widths)
 
 
-def _block_geometry(q, k, block_q, block_k, packed_stats, head_pack):
-    """(bq, bk, packed, hpb) of the kernels' grid over these operands:
-    blocks clamped to the lengths, and the layout variants where their
-    geometry holds."""
-    b, h, tq, d = q.shape
+def _dims(q, k, v, heads):
+    """(b, h, tq, tk, d, dv) of head-major [B, H, T, D] operands, or,
+    with a head count, of token-major [B, T, H*D] ones."""
+    if heads is None:
+        b, h, tq, d = q.shape
+        return b, h, tq, k.shape[2], d, v.shape[3]
+    b, tq, width = q.shape
+    return b, heads, tq, k.shape[1], width // heads, v.shape[2] // heads
+
+
+def _block_geometry(q, k, v, block_q, block_k, packed_stats, head_pack,
+                    heads=None):
+    """(dims, bq, bk, packed, hpb) of the kernels' grid over these
+    operands: their `_dims`, blocks clamped to the lengths, the layout
+    variants where their geometry holds, and the heads a grid step
+    takes: on the token-major layout (`heads` given) the heads of one
+    128-lane block, which is a matter of geometry and not of
+    `flash_head_pack`."""
+    dims = b, h, tq, tk, d, _ = _dims(q, k, v, heads)
     bq = min(block_q, max(tq, 8))
-    bk = min(block_k, max(k.shape[2], 8))
+    bk = min(block_k, max(tk, 8))
     packed = packed_stats and _packed_geom_ok(bq)
-    hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)) else 1
-    return bq, bk, packed, hpb
+    if heads is not None:
+        hpb = _MIN_LANES // d
+    else:
+        hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)) else 1
+    return dims, bq, bk, packed, hpb
+
+
+class _Tiles:
+    """Where the kernels' per-head [rows, width] tiles of q, k, v, out
+    and their gradients lie in HBM: the ONE thing, with `_head_tile`,
+    that differs between the two layouts.
+
+    Head-major [B, H, T, D]: the kernels see [B*H, T, D] (a free
+    reshape) and grid step g takes the block (hpb, rows, D) at g, the
+    tiles of heads g*hpb ... of the flattened B*H axis.  Token-major
+    [B, T, H*D], as a projection leaves it: the kernels see it as it
+    is, and step g takes the block (1, rows, 128) at batch g // n,
+    lane block g % n of the n = H*D/128 a batch has, which holds the
+    same hpb = 128/D heads g*hpb ... of the flattened axis.  The row
+    statistics are [B*H, T, 128] on both."""
+
+    def __init__(self, dims, hpb, token_major):
+        self.b, self.h = dims[:2]
+        self.hpb, self.token_major = hpb, token_major
+
+    def operand(self, x, block_rows):
+        """x as the kernels take it, rows padded to whole blocks."""
+        if not self.token_major:
+            x = x.reshape(self.b * self.h, *x.shape[2:])
+        return _pad_axis(x, 1, block_rows)
+
+    def spec(self, rows, width, row_block):
+        """BlockSpec of the (rows, width) tiles; row_block(i, j) is the
+        row block at the grid's two inner indices."""
+        if not self.token_major:
+            return pl.BlockSpec(
+                (self.hpb, rows, width),
+                lambda g, i, j: (g, row_block(i, j), 0))
+        n = self.h // self.hpb
+        return pl.BlockSpec(
+            (1, rows, self.hpb * width),
+            lambda g, i, j: (g // n, row_block(i, j), g % n))
+
+    def shape(self, rows, width):
+        if not self.token_major:
+            return (self.b * self.h, rows, width)
+        return (self.b, rows, self.h * width)
+
+    def acc(self, rows, width, per_head=False):
+        """float32 VMEM accumulator of a step's (rows, width) tiles: one
+        a head slot, or, token-major, ONE [rows, 128] for the lane
+        block, into which its heads' products add (`_head_tile`);
+        per_head where each head needs its own all the same (the
+        forward's, rescaled by the head's alpha)."""
+        if not self.token_major:
+            return pltpu.VMEM((self.hpb, rows, width), jnp.float32)
+        return pltpu.VMEM((self.hpb if per_head else 1, rows, _MIN_LANES),
+                          jnp.float32)
+
+    def result(self, x, rows):
+        """A kernel output without its row padding, in the layout the
+        operands came in."""
+        x = x[:, :rows]
+        if not self.token_major:
+            x = x.reshape(self.b, self.h, *x.shape[1:])
+        return x
+
+
+def _first(i, j):
+    """Row-block pickers for `_Tiles.spec`: which of a grid's two inner
+    indices walks a tile's rows."""
+    return i
+
+
+def _second(i, j):
+    return j
+
+
+def _lanes(n):
+    """A minor dim occupies whole 128-lane tiles."""
+    return -(-n // _MIN_LANES) * _MIN_LANES
+
+
+# What Mosaic scopes a kernel to on a v5e unless it asks for more, and
+# the most the one-sweep backward asks for of the core's 128 MiB.
+_MOSAIC_SCOPED_VMEM = 16 << 20
+_BWD_FUSED_VMEM_MAX = 96 << 20
+
+
+def _fwd_vmem_bytes(hpb, bq, bk, d, dv, itemsize, packed,
+                    token_major=False):
+    """VMEM the forward needs, bytes, from above, reckoned as
+    `_bwd_fused_vmem_bytes` is.  Two heads a step at 1,024-row blocks
+    pass what Mosaic scopes a kernel to by default (their score tiles
+    alone are 24 MiB), so the forward asks."""
+    d, dv = _lanes(d), _lanes(dv)
+    slots = 1 if token_major else hpb
+    # q, k, v and out blocks, double-buffered
+    tiles = 2 * itemsize * (bq * (d + dv) + bk * (d + dv))
+    # the lse block, double-buffered; m, l and the float32 acc
+    stats = 2 * 4 * (bq if packed else bq * _MIN_LANES) \
+        + 2 * 4 * bq * _MIN_LANES + 4 * bq * dv
+    # S, P and P's cast, as far as Mosaic keeps them whole
+    temps = 3 * 4 * bq * bk
+    return slots * tiles + hpb * (stats + temps)
 
 
 # jitted so that a step traces each kernel once per signature, not once
@@ -356,31 +519,37 @@ def _block_geometry(q, k, block_q, block_k, packed_stats, head_pack):
 # counts and code size, compiled for a described v5e; PERF.md, PR 24).
 @functools.partial(jax.jit, static_argnames=(
     "causal", "scale", "block_q", "block_k", "interpret", "packed_stats",
-    "head_pack"))
+    "head_pack", "heads"))
 def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                       interpret=False, packed_stats=False,
-                      head_pack=False):
+                      head_pack=False, heads=None):
     """q/k: [B, H, T, D], v: [B, H, Tk, Dv] (Dv = D everywhere but in
     latent attention, whose q.k size is 192 and v size 128) ->
-    ([B, H, Tq, Dv], lse [B*H, Tq_padded])."""
-    b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]
-    bq, bk, packed, hpb = _block_geometry(q, k, block_q, block_k,
-                                          packed_stats, head_pack)
-    qp = _pad_axis(q.reshape(b * h, tq, d), 1, bq)
-    kp = _pad_axis(k.reshape(b * h, tk, d), 1, bk)
-    vp = _pad_axis(v.reshape(b * h, tk, dv), 1, bk)
+    ([B, H, Tq, Dv], lse [B*H, Tq_padded]).  With `heads`, the three
+    and the output are token-major [B, T, H*D] (`_Tiles`; the entries
+    send only what `_flash_layout` passed)."""
+    token_major = heads is not None
+    dims, bq, bk, packed, hpb = _block_geometry(
+        q, k, v, block_q, block_k, packed_stats, head_pack, heads)
+    b, h, tq, tk, d, dv = dims
+    tiles = _Tiles(dims, hpb, token_major)
+    qp, kp, vp = tiles.operand(q, bq), tiles.operand(k, bk), \
+        tiles.operand(v, bk)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
     grid = (b * h // hpb, tq_p // bq, tk_p // bk)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         kv_len=tk, q_off=tk - tq if causal else 0, packed=packed,
-        hpb=hpb)
+        hpb=hpb, token_major=token_major)
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(
+                _MOSAIC_SCOPED_VMEM,
+                _fwd_vmem_bytes(hpb, bq, bk, d, dv, q.dtype.itemsize,
+                                packed, token_major)))
     if packed:
         lse_shape = (b * h, tq_p // _MIN_LANES, _MIN_LANES)
         lse_block = (hpb, bq // _MIN_LANES, _MIN_LANES)
@@ -392,20 +561,20 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         name="pt_flash_fwd",
         grid=grid,
         in_specs=[
-            pl.BlockSpec((hpb, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((hpb, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((hpb, bk, dv), lambda bh, i, j: (bh, j, 0)),
+            tiles.spec(bq, d, _first),
+            tiles.spec(bk, d, _second),
+            tiles.spec(bk, dv, _second),
         ],
         out_specs=[
-            pl.BlockSpec((hpb, bq, dv), lambda bh, i, j: (bh, i, 0)),
+            tiles.spec(bq, dv, _first),
             pl.BlockSpec(lse_block, lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq_p, dv), q.dtype),
+            jax.ShapeDtypeStruct(tiles.shape(tq_p, dv), q.dtype),
             jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((hpb, bq, dv), jnp.float32),
+            tiles.acc(bq, dv, per_head=True),
             pltpu.VMEM((hpb, bq, _MIN_LANES), jnp.float32),
             pltpu.VMEM((hpb, bq, _MIN_LANES), jnp.float32),
         ],
@@ -416,7 +585,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     # packed unpacks with a free row-major reshape at the XLA boundary,
     # replicated strips the lanes
     lse2 = lse.reshape(b * h, tq_p) if packed else lse[:, :, 0]
-    return (out[:, :tq, :].reshape(b, h, tq, dv), lse2)
+    return (tiles.result(out, tq), lse2)
 
 
 # ---------------------------------------------------------------------------
@@ -472,7 +641,8 @@ def _bwd_p_ds_block(q, k, v, do, lse, delta, *, scale, causal,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, acc_ref, *, scale, causal, block_q,
-                   block_k, kv_len, q_len, q_off, packed, hpb):
+                   block_k, kv_len, q_len, q_off, packed, hpb,
+                   token_major=False):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -491,8 +661,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def _accumulate(masked):
         for h in range(hpb):
-            q, k, v = q_ref[h], k_ref[h], v_ref[h]
-            do = do_ref[h].astype(jnp.float32)
+            q, k, v, do = (_head_tile(r, h, hpb, token_major)
+                           for r in (q_ref, k_ref, v_ref, do_ref))
+            do = do.astype(jnp.float32)
             _, ds = _bwd_p_ds_block(
                 q, k, v, do,
                 _stat_rows(lse_ref, h, block_q, packed),
@@ -501,7 +672,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 causal=causal, block_q=block_q, block_k=block_k,
                 kv_len=kv_len, q_len=q_len, q_off=q_off, qi=qi, ki=ki,
                 masked=masked)
-            acc_ref[h] += lax.dot_general(
+            acc_ref[0 if token_major else h] += lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
@@ -515,15 +686,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        for h in range(hpb):
-            dq_ref[h, ...] = acc_ref[h].astype(dq_ref.dtype)
+        for a in range(acc_ref.shape[0]):
+            dq_ref[a, ...] = acc_ref[a].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     *refs, scale, causal, block_q, block_k, kv_len,
-                    q_len, q_off, packed, hpb, with_dq):
+                    q_len, q_off, packed, hpb, with_dq,
+                    token_major=False):
     """The dk/dv sweep: kv blocks outer, q blocks inner, dk_acc/dv_acc
-    carried across the q sweep.
+    carried across the q sweep.  Head-major, every head slot has its
+    accumulators; token-major, the heads of a lane block share one
+    (`_head_tile`: their products are zero off their own lanes).
 
     with_dq, it is the whole backward: P and dS, formed once a block
     pair, feed all three products.  dq_acc holds the head's whole dq
@@ -549,8 +723,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if with_dq:
         @pl.when(ki == 0)
         def _init_dq():
-            for h in range(hpb):
-                dq_acc[h, rows, :] = jnp.zeros(
+            for a in range(dq_acc.shape[0]):
+                dq_acc[a, rows, :] = jnp.zeros(
                     (block_q, dq_acc.shape[2]), dq_acc.dtype)
 
     if causal:
@@ -564,8 +738,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def _accumulate(masked):
         for h in range(hpb):
-            q, k, v = q_ref[h], k_ref[h], v_ref[h]
-            do = do_ref[h].astype(jnp.float32)
+            q, k, v, do = (_head_tile(r, h, hpb, token_major)
+                           for r in (q_ref, k_ref, v_ref, do_ref))
+            do = do.astype(jnp.float32)
+            a = 0 if token_major else h
             p, ds = _bwd_p_ds_block(
                 q, k, v, do,
                 _stat_rows(lse_ref, h, block_q, packed),
@@ -574,14 +750,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 causal=causal, block_q=block_q, block_k=block_k,
                 kv_len=kv_len, q_len=q_len, q_off=q_off, qi=qi, ki=ki,
                 masked=masked)
-            dv_acc[h] += lax.dot_general(
+            dv_acc[a] += lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dk_acc[h] += lax.dot_general(
+            dk_acc[a] += lax.dot_general(
                 ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if with_dq:
-                dq_acc[h, rows, :] += lax.dot_general(
+                dq_acc[a, rows, :] += lax.dot_general(
                     ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
 
@@ -595,44 +771,40 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(qi == nq - 1)
     def _finalize():
-        for h in range(hpb):
-            dk_ref[h, ...] = dk_acc[h].astype(dk_ref.dtype)
-            dv_ref[h, ...] = dv_acc[h].astype(dv_ref.dtype)
+        for a in range(dk_acc.shape[0]):
+            dk_ref[a, ...] = dk_acc[a].astype(dk_ref.dtype)
+            dv_ref[a, ...] = dv_acc[a].astype(dv_ref.dtype)
 
     if with_dq:
         @pl.when(ki == nk - 1)
         def _finalize_dq():
-            for h in range(hpb):
-                dq_ref[h, rows, :] = dq_acc[h, rows, :].astype(
+            for a in range(dq_acc.shape[0]):
+                dq_ref[a, rows, :] = dq_acc[a, rows, :].astype(
                     dq_ref.dtype)
 
 
-# What Mosaic scopes a kernel to on a v5e unless it asks for more, and
-# the most the one-sweep backward asks for of the core's 128 MiB.
-_MOSAIC_SCOPED_VMEM = 16 << 20
-_BWD_FUSED_VMEM_MAX = 96 << 20
-
-
-def _bwd_fused_vmem_bytes(hpb, tq_p, bq, bk, d, dv, itemsize, packed):
+def _bwd_fused_vmem_bytes(hpb, tq_p, bq, bk, d, dv, itemsize, packed,
+                          token_major=False):
     """VMEM the one-sweep backward needs, bytes, from above: what the
     chip's compiler asked for, compiled for a described v5e over the
     cells' shapes, head sizes 64 to 192, both dtypes and hpb 2, was
-    0.35 to 0.9 of this (PERF.md, PR 29).  A minor dim occupies whole
-    128-lane tiles."""
-    def lanes(n):
-        return -(-n // _MIN_LANES) * _MIN_LANES
-
-    d, dv = lanes(d), lanes(dv)
-    # q, k, v, dO and the two row statistics, double-buffered
-    blocks = 2 * itemsize * (bq + bk) * (d + dv) \
-        + 2 * 2 * 4 * (bq if packed else bq * _MIN_LANES)
+    0.35 to 0.9 of this (PERF.md, PR 29).  Token-major, the hpb heads
+    of a lane block share its tiles and accumulators (the resident dq
+    is [Tq_p, 128] for the pair: what one head's is, padded to whole
+    lanes); the row statistics and the score tiles stay a head's."""
+    d, dv = _lanes(d), _lanes(dv)
+    slots = 1 if token_major else hpb
+    # q, k, v and dO, double-buffered
+    tiles = 2 * itemsize * (bq + bk) * (d + dv)
+    # the two row statistics, double-buffered
+    stats = 2 * 2 * 4 * (bq if packed else bq * _MIN_LANES)
     # dk/dv: the double-buffered output blocks and their accumulators
     dkv = (2 * itemsize + 4) * bk * (d + dv)
-    # dq: the head's float32 accumulator and the output block
+    # dq: the float32 accumulator and the output block
     dq = (2 * itemsize + 4) * tq_p * d
     # S/P, dP, dS and their casts, as far as Mosaic keeps them whole
     temps = 4 * 4 * bq * bk
-    return hpb * (blocks + dkv + dq + temps)
+    return slots * (tiles + dkv + dq) + hpb * (stats + temps)
 
 
 def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
@@ -641,12 +813,13 @@ def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
     VMEM a kernel may ask for; past that two, dq streamed by q block.
     Counted here, outside the jit, so that a step of six layers reads
     six.  **call: the static arguments `_call_args` resolved."""
-    bq, bk, packed, hpb = _block_geometry(
-        q, k, call["block_q"], call["block_k"], call["packed_stats"],
-        call["head_pack"])
+    heads = call.get("heads")
+    (_, _, tq, _, d, dv), bq, bk, packed, hpb = _block_geometry(
+        q, k, v, call["block_q"], call["block_k"], call["packed_stats"],
+        call["head_pack"], heads)
     vmem = _bwd_fused_vmem_bytes(
-        hpb, -(-q.shape[2] // bq) * bq, bq, bk, q.shape[3], v.shape[3],
-        q.dtype.itemsize, packed)
+        hpb, -(-tq // bq) * bq, bq, bk, d, dv, q.dtype.itemsize, packed,
+        token_major=heads is not None)
     fused = vmem <= _BWD_FUSED_VMEM_MAX
     _count_impl("flash_attention_bwd", "fused" if fused else "two_sweep")
     return _flash_bwd_pallas(
@@ -656,13 +829,15 @@ def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
 
 @functools.partial(jax.jit, static_argnames=(    # see _flash_fwd_pallas
     "causal", "scale", "block_q", "block_k", "interpret", "packed_stats",
-    "head_pack", "one_sweep_vmem"))
+    "head_pack", "heads", "one_sweep_vmem"))
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
                       block_k, interpret=False, dlse=None,
-                      packed_stats=False, head_pack=False, *,
-                      one_sweep_vmem):
-    """q/k: [B, H, T, D], v, o and g = dO: [.., Dv]; lse: [B*H, Tq] or
-    q-block padded, as the forward kernel returns it.  one_sweep_vmem:
+                      packed_stats=False, head_pack=False, heads=None,
+                      *, one_sweep_vmem):
+    """q/k: [B, H, T, D], v, o and g = dO: [.., Dv] (with `heads`, all
+    token-major [B, T, H*D], and so the gradients: `_Tiles`); lse:
+    [B*H, Tq] or q-block padded, as the forward kernel returns it.
+    one_sweep_vmem:
     the VMEM to ask for, bytes, for the one sweep (`_bwd_dkv_kernel`
     with_dq), or None for the dq and the dk/dv sweep; `_flash_bwd`
     picks.
@@ -678,22 +853,41 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     HBM as kernel inputs (~8 GB at seq-1M x 8 heads — with the fwd lse
     the third, the seq-1M OOM).
     """
-    b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]
-    bq, bk, packed, hpb = _block_geometry(q, k, block_q, block_k,
-                                          packed_stats, head_pack)
-    qp = _pad_axis(q.reshape(b * h, tq, d), 1, bq)
-    kp = _pad_axis(k.reshape(b * h, tk, d), 1, bk)
-    vp = _pad_axis(v.reshape(b * h, tk, dv), 1, bk)
-    gp = _pad_axis(g.reshape(b * h, tq, dv), 1, bq)
+    token_major = heads is not None
+    dims, bq, bk, packed, hpb = _block_geometry(
+        q, k, v, block_q, block_k, packed_stats, head_pack, heads)
+    b, h, tq, tk, d, dv = dims
+    tiles = _Tiles(dims, hpb, token_major)
+    qp, kp, vp, gp = tiles.operand(q, bq), tiles.operand(k, bk), \
+        tiles.operand(v, bk), tiles.operand(g, bq)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
     # rows past tq are masked in the kernels: what they hold is not read
     lse = _pad_axis(lse, 1, bq)
     # delta = rowsum(dO * O): cheap elementwise+reduce, done in XLA;
     # an lse cotangent subtracts from it (see docstring)
-    delta_full = jnp.sum(
-        g.astype(jnp.float32) * o.astype(jnp.float32),
-        axis=-1).reshape(b * h, tq)
+    delta_full = g.astype(jnp.float32) * o.astype(jnp.float32)
+    if token_major:
+        # a head's sum is over ITS lanes of a row.  As a reduce XLA
+        # first copies both operands to a rows-minor layout (splitting
+        # the 128-lane tiles is a relayout; six float32 copies of 67 MB
+        # in the `_s512` step, compiled for a described v5e: PERF.md,
+        # PR 31); as a product with the heads' 0/1 lane indicator it
+        # is one fusion that reads dO and O once and writes [B, H, T].
+        # The same float32 sum: a product of two bfloat16 has 16
+        # significant bits, which the three bfloat16 passes of HIGH
+        # hold exactly beside an indicator that is bfloat16 itself;
+        # wider operands take the six of HIGHEST.
+        heads_of = lax.broadcasted_iota(jnp.int32, (h * dv, h), 0) // dv \
+            == lax.broadcasted_iota(jnp.int32, (h * dv, h), 1)
+        delta_full = lax.dot_general(
+            delta_full, heads_of.astype(jnp.float32),
+            (((2,), (0,)), ((), ())),
+            precision=(lax.Precision.HIGH if g.dtype == jnp.bfloat16
+                       else lax.Precision.HIGHEST),
+            preferred_element_type=jnp.float32).transpose(0, 2, 1)
+    else:
+        delta_full = delta_full.sum(-1)
+    delta_full = delta_full.reshape(b * h, tq)
     if dlse is not None:
         # the lse output (and so its cotangent) is q-block padded;
         # only the first tq rows are real
@@ -719,21 +913,22 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     q_off = tk - tq if causal else 0
     common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
                   kv_len=tk, q_len=tq, q_off=q_off, packed=packed,
-                  hpb=hpb)
+                  hpb=hpb, token_major=token_major)
     operands = (qp, kp, vp, gp, lse3, delta3)
     out_shape = [
-        jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype),
-        jax.ShapeDtypeStruct((b * h, tk_p, d), k.dtype),
-        jax.ShapeDtypeStruct((b * h, tk_p, dv), v.dtype),
+        jax.ShapeDtypeStruct(tiles.shape(tq_p, d), q.dtype),
+        jax.ShapeDtypeStruct(tiles.shape(tk_p, d), k.dtype),
+        jax.ShapeDtypeStruct(tiles.shape(tk_p, dv), v.dtype),
     ]
 
-    def specs(qmap, kmap):
-        """in_specs of (q, k, v, dO, lse, delta) for a grid order."""
-        return [pl.BlockSpec((hpb, bq, d), qmap),
-                pl.BlockSpec((hpb, bk, d), kmap),
-                pl.BlockSpec((hpb, bk, dv), kmap),
-                pl.BlockSpec((hpb, bq, dv), qmap),
-                pl.BlockSpec(lblk, qmap), pl.BlockSpec(lblk, qmap)]
+    def specs(q_rows, k_rows):
+        """in_specs of (q, k, v, dO, lse, delta) for a grid order:
+        which of the two inner grid indices is the q block's."""
+        stat = pl.BlockSpec(
+            lblk, lambda bh, i, j: (bh, q_rows(i, j), 0))
+        return [tiles.spec(bq, d, q_rows), tiles.spec(bk, d, k_rows),
+                tiles.spec(bk, dv, k_rows), tiles.spec(bq, dv, q_rows),
+                stat, stat]
 
     def params(outer="parallel", vmem_limit_bytes=None):
         if interpret:
@@ -744,11 +939,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
 
     # kv blocks outer, q blocks inner: the dk/dv accumulators carry
     # across the q sweep
-    kv_specs = specs(lambda bh, j, i: (bh, i, 0),
-                     lambda bh, j, i: (bh, j, 0))
+    kv_specs = specs(q_rows=_second, k_rows=_first)
     kv_grid = (b * h // hpb, tk_p // bk, tq_p // bq)
-    kv_scratch = [pltpu.VMEM((hpb, bk, d), jnp.float32),
-                  pltpu.VMEM((hpb, bk, dv), jnp.float32)]
+    kv_scratch = [tiles.acc(bk, d), tiles.acc(bk, dv)]
     if one_sweep_vmem is not None:
         dq, dk, dv_ = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, with_dq=True, **common),
@@ -756,18 +949,15 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             grid=kv_grid,
             in_specs=kv_specs,
             # dq: the head's whole [Tq_p, d], resident over both sweeps
-            out_specs=[pl.BlockSpec((hpb, tq_p, d),
-                                    lambda bh, j, i: (bh, 0, 0)),
+            out_specs=[tiles.spec(tq_p, d, lambda j, i: 0),
                        kv_specs[1], kv_specs[2]],
             out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((hpb, tq_p, d), jnp.float32)]
-            + kv_scratch,
+            scratch_shapes=[tiles.acc(tq_p, d)] + kv_scratch,
             interpret=interpret,
             **params("arbitrary", one_sweep_vmem),
         )(*operands)
     else:
-        q_specs = specs(lambda bh, i, j: (bh, i, 0),
-                        lambda bh, i, j: (bh, j, 0))
+        q_specs = specs(q_rows=_first, k_rows=_second)
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, **common),
             name="pt_flash_bwd_dq",
@@ -775,7 +965,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             in_specs=q_specs,
             out_specs=q_specs[0],
             out_shape=out_shape[0],
-            scratch_shapes=[pltpu.VMEM((hpb, bq, d), jnp.float32)],
+            scratch_shapes=[tiles.acc(bq, d)],
             interpret=interpret,
             **params(),
         )(*operands)
@@ -790,43 +980,43 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             interpret=interpret,
             **params(),
         )(*operands)
-    return (dq[:, :tq, :].reshape(b, h, tq, d),
-            dk[:, :tk, :].reshape(b, h, tk, d),
-            dv_[:, :tk, :].reshape(b, h, tk, dv))
+    return (tiles.result(dq, tq), tiles.result(dk, tk),
+            tiles.result(dv_, tk))
 
 
 # ---------------------------------------------------------------------------
 # differentiable entries: ONE custom_vjp over the forward/backward kernels
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
-               packed_stats, head_pack):
+               packed_stats, head_pack, heads=None):
     """(out, lse): lse is the mergeable summary ring attention needs and
-    the residual the IR grad op reads."""
+    the residual the IR grad op reads.  heads: `_flash_fwd_pallas`."""
     return _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                              interpret=interpret,
                              packed_stats=packed_stats,
-                             head_pack=head_pack)
+                             head_pack=head_pack, heads=heads)
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k,
-                   interpret, packed_stats, head_pack):
+                   interpret, packed_stats, head_pack, heads):
     out, lse = _flash_fwd_pallas(q, k, v, causal, scale, block_q,
                                  block_k, interpret=interpret,
                                  packed_stats=packed_stats,
-                                 head_pack=head_pack)
+                                 head_pack=head_pack, heads=heads)
     return (out, lse), (q, k, v, out, lse)
 
 
 def _flash_lse_bwd(causal, scale, block_q, block_k, interpret,
-                   packed_stats, head_pack, res, g):
+                   packed_stats, head_pack, heads, res, g):
     q, k, v, o, lse = res
     do, dlse = g
     return _flash_bwd(q, k, v, o, lse, do, dlse=dlse, causal=causal,
                       scale=scale, block_q=block_q, block_k=block_k,
                       interpret=interpret, packed_stats=packed_stats,
-                      head_pack=head_pack)
+                      head_pack=head_pack, heads=heads)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -857,6 +1047,7 @@ def flash_attention_lse(q, k, v, *, causal=False, scale=None,
     impl, kw = _call_args(q, k, causal, scale, block_q, block_k, impl,
                           packed_stats, head_pack)
     _count_impl("flash_attention", impl)
+    _count_impl("flash_attention_layout", "head_major")
     with _kernel_scope():
         return _flash_lse(q, k, v, **kw)
 
@@ -874,10 +1065,16 @@ def _default_block(t):
 
 def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
                     block_k=None, impl=None, packed_stats=None,
-                    head_pack=None):
+                    head_pack=None, heads=None):
     """Fused attention. q/k: [B, H, T, D], v: [B, H, Tk, Dv]; returns
     [B, H, Tq, Dv].  Dv = D everywhere but in latent attention (q.k 192
     = 128 + 64 rotary, v 128): the three kernels take the two sizes.
+
+    heads: the head count of TOKEN-MAJOR operands, q/k [B, T, H*D] and
+    v [B, Tk, H*Dv] as the projections leave them; returns
+    [B, Tq, H*Dv].  No head-major copy is made where the kernels can
+    address the heads in place (`_flash_layout`); elsewhere the
+    operands are transposed here, to the same answer.
 
     impl: None (auto: pallas on TPU, XLA elsewhere), "pallas",
     "interpret" (pallas interpret mode, for CPU tests), or "xla".
@@ -893,26 +1090,73 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     return _flash_attention_fwd(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
         block_k=block_k, impl=impl, packed_stats=packed_stats,
-        head_pack=head_pack)[0]
+        head_pack=head_pack, heads=heads)[0]
 
 
 def _call_args(q, k, causal=False, scale=None, block_q=None, block_k=None,
-               impl=None, packed_stats=None, head_pack=None):
+               impl=None, packed_stats=None, head_pack=None, heads=None):
     """What a flash entry's unset (None, or an op attr's 0) arguments
     mean, resolved in ONE place (the saved-residual backward reads the
     forward's lse and must tile it the same way): scale 1/sqrt(d), impl
     `_auto_impl()`, blocks by sequence length, layout variants from
-    their flags.  Returns (impl, the static arguments `_flash_lse` and
-    `_flash_bwd_pallas` share)."""
+    their flags.  heads: None for [B, H, T, D] operands, the head count
+    of token-major [B, T, H*D] ones (rows are dim -2 of both).  Returns
+    (impl, the static arguments `_flash_lse` and `_flash_bwd_pallas`
+    share)."""
     impl = impl or _auto_impl()
     packed_stats, head_pack = _resolve_variants(packed_stats, head_pack)
     return impl, dict(
         causal=bool(causal),
-        scale=float(scale or 1.0 / math.sqrt(q.shape[-1])),
+        scale=float(scale or 1.0 / math.sqrt(q.shape[-1] // (heads or 1))),
         block_q=block_q or _default_block(q.shape[-2]),
         block_k=block_k or _default_block(k.shape[-2]),
         interpret=impl == "interpret",
-        packed_stats=packed_stats, head_pack=head_pack)
+        packed_stats=packed_stats, head_pack=head_pack,
+        heads=heads or None)
+
+
+def _flash_layout(q, v, heads, impl):
+    """Which way the kernels address the heads of these operands,
+    chosen from what the entry sees and nowhere else: "token_major"
+    where [B, T, H*D] operands can be tiled in place, that is a kernel
+    impl, one head size D = Dv of 64 or 128, and whole 128-lane blocks
+    (an even head count at 64); "head_major" for [B, H, T, D] operands
+    and for every token-major call that fails the rule, which the
+    entry transposes to [B, H, T, D] and back: the same answer at the
+    cost of the copies.  Counted, a call, in
+    paddle_tpu_kernel_impl_total{kernel="flash_attention_layout"}."""
+    if heads:
+        d, dv = q.shape[-1] // heads, v.shape[-1] // heads
+        if impl != "xla" and d == dv and d in (64, 128) \
+                and (heads * d) % _MIN_LANES == 0:
+            return "token_major"
+    return "head_major"
+
+
+def _split_heads(x, heads):
+    """[B, T, H*D] -> [B, H, T, D]."""
+    b, t, width = x.shape
+    return x.reshape(b, t, heads, width // heads).transpose(0, 2, 1, 3)
+
+
+def _merge_heads(x):
+    """[B, H, T, D] -> [B, T, H*D]."""
+    b, h, t, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+
+def _to_kernel_layout(kw, impl, *operands):
+    """What a flash entry does about the layout of its operands (q, k,
+    v first): counts `_flash_layout`'s choice, and where token-major
+    operands cannot be tiled in place returns them [B, H, T, D], with
+    kw["heads"] unset.  Returns (transposed, operands)."""
+    heads = kw["heads"]
+    layout = _flash_layout(operands[0], operands[2], heads, impl)
+    _count_impl("flash_attention_layout", layout)
+    if heads is None or layout == "token_major":
+        return False, operands
+    kw["heads"] = None
+    return True, tuple(_split_heads(x, heads) for x in operands)
 
 
 def _flash_attention_fwd(q, k, v, **call):
@@ -923,9 +1167,11 @@ def _flash_attention_fwd(q, k, v, **call):
     `logsumexp` of the scores plain attention forms on xla.
     Differentiable in q, k, v.  The `flash_attention` IR op is this;
     `_flash_attention_bwd` is its grad op when (out, lse) were kept.
-    **call: `_call_args`' keywords."""
+    **call: `_call_args`' keywords; with `heads`, q, k, v and out are
+    token-major."""
     impl, kw = _call_args(q, k, **call)
     _count_impl("flash_attention", impl)
+    transposed, (q, k, v) = _to_kernel_layout(kw, impl, q, k, v)
     # device-time attribution (ISSUE 10): at runtime with the `tracing`
     # flag on, an annotation carrying the active trace id; otherwise
     # the null context.  ONE call line either way: source locations
@@ -933,10 +1179,15 @@ def _flash_attention_fwd(q, k, v, **call):
     # compiled module (and its cache key) depend on the flag
     with _obs_device.annotate("flash_attention"), _kernel_scope():
         if impl == "xla":
-            return _plain_attention(q, k, v, kw["causal"], kw["scale"],
-                                    with_lse=True)
-        out, lse = _flash_lse(q, k, v, **kw)
-    b, h, tq, _ = q.shape
+            out, lse = _plain_attention(q, k, v, kw["causal"],
+                                        kw["scale"], with_lse=True)
+        else:
+            out, lse = _flash_lse(q, k, v, **kw)
+    if transposed:
+        out = _merge_heads(out)
+    if impl == "xla":
+        return out, lse
+    b, h, tq = _dims(q, k, v, kw["heads"])[:3]
     # the kernel's [B*H, Tq_padded]: a slice (none at tq % block_q == 0)
     # and a free reshape.  The kernel writes the statistic lane-
     # replicated ([B*H, Tq, 128]) and `_flash_fwd_pallas` strips the
@@ -953,8 +1204,10 @@ def _flash_attention_bwd(q, k, v, out, lse, g, **call):
     cotangent g of out: the backward kernel and nothing else.  The
     forward kernel does not run again.  Kernel impls only: plain
     attention keeps no residual worth saving, jax differentiates it."""
-    _, kw = _call_args(q, k, **call)
-    b, h, tq, _ = q.shape
+    impl, kw = _call_args(q, k, **call)
+    transposed, (q, k, v, out, g) = _to_kernel_layout(
+        kw, impl, q, k, v, out, g)
+    b, h, tq = _dims(q, k, v, kw["heads"])[:3]
     # lse has been ready since the forward, and the kernels read it
     # lane-replicated ([B*H, Tq, 128]): left free, XLA's scheduler makes
     # that broadcast right after the forward kernel and keeps 128x the
@@ -964,7 +1217,8 @@ def _flash_attention_bwd(q, k, v, out, lse, g, **call):
     lse, g = lax.optimization_barrier((lse, g))
     # see _flash_attention_fwd: one call line, flag or no flag
     with _obs_device.annotate("flash_attention_grad"), _kernel_scope():
-        return _flash_bwd(q, k, v, out, lse.reshape(b * h, tq), g, **kw)
+        grads = _flash_bwd(q, k, v, out, lse.reshape(b * h, tq), g, **kw)
+    return tuple(map(_merge_heads, grads)) if transposed else grads
 
 
 def _auto_impl():
@@ -1487,57 +1741,69 @@ def _flash_decode_entry(q, k_pages, v_pages, block_tables, seq_lens,
 from paddle_tpu.core.registry import register_op  # noqa: E402
 
 
-def _gspmd_flash_shard_map(attrs, call, operands, out_ranks):
+def _gspmd_flash_shard_map(attrs, call, operands, kinds):
     """GSPMD front-end hook (parallel/gspmd.py tag_attention_ops):
     when the typed `gspmd` flag is on and the op carries
     gspmd_batch_axis / gspmd_head_axis attrs, run the kernel under
     shard_map on the current mesh — Mosaic kernels can't ride XLA's
     automatic partitioner, and attention is independent per
-    (batch, head) row so the dp x tp split is exact.  Every operand and
-    output leads with [B, H]: a rank-r one rides P(batch_axis,
-    head_axis, None, ...) (q/k/v/out/grads rank 4, lse rank 3).  Flag
-    off or an untagged op returns None and the caller runs the plain
+    (batch, head) row so the dp x tp split is exact.
+
+    `call(*operands, heads=...)` computes one shard.  `kinds`: a
+    letter an operand, then "->", then a letter an output: "x" for
+    q/k/v/out and their gradients, "l" for LSE.  Head-major (no
+    `heads` attr) x is [B, H, T, D] and rides P(batch_axis, head_axis,
+    None, None); token-major it is [B, T, H*D] and rides
+    P(batch_axis, None, head_axis), which is the sharding a
+    column-parallel projection leaves its output in, and a shard is
+    called with its LOCAL head count (where that does not fill lane
+    blocks, `_flash_layout` sends the shard head-major).  LSE is
+    [B, H, Tq], P(batch_axis, head_axis, None), on both.  Flag off or
+    an untagged op returns None and the caller runs the plain
     single-program path.  A TAGGED op that fails a gate (no mesh, axis
-    missing or size 1, dim not divisible) also runs plain, and says so
-    in paddle_tpu_kernel_impl_total{kernel="flash_attention_gspmd"}:
+    missing or size 1, batch or head count not divisible) also runs
+    plain, and says so in
+    paddle_tpu_kernel_impl_total{kernel="flash_attention_gspmd"}:
     impl="plain" against impl="shard_map"."""
     from paddle_tpu.flags import get_flag
 
+    heads = attrs.get("heads") or None
+    plain = functools.partial(call, heads=heads)
     if not get_flag("gspmd"):
-        return None
+        return plain(*operands)
     ba = attrs.get("gspmd_batch_axis") or None
     ha = attrs.get("gspmd_head_axis") or None
     if not (ba or ha):
-        return None
+        return plain(*operands)
     from paddle_tpu.parallel import env as penv
 
     mesh = penv.get_mesh()
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape)) \
         if mesh is not None else {}
-    bsz, hsz = operands[0].shape[:2]
+    bsz, hsz = operands[0].shape[0], heads or operands[0].shape[1]
     if ba and (sizes.get(ba, 1) <= 1 or bsz % sizes.get(ba, 1) != 0):
         ba = None
     if ha and (sizes.get(ha, 1) <= 1 or hsz % sizes.get(ha, 1) != 0):
         ha = None
     if not (ba or ha):
         _count_impl("flash_attention_gspmd", "plain")
-        return None
+        return plain(*operands)
     from jax.sharding import PartitionSpec as P
 
     _count_impl("flash_attention_gspmd", "shard_map")
-
-    def spec(rank):
-        return P(ba, ha, *[None] * (rank - 2))
-
-    f = jax.shard_map(call, mesh=mesh,
-                      in_specs=tuple(spec(x.ndim) for x in operands),
-                      out_specs=tuple(spec(r) for r in out_ranks),
-                      check_vma=False)
-    return f(*operands)
+    spec = {"l": P(ba, ha, None),
+            "x": P(ba, None, ha) if heads else P(ba, ha, None, None)}
+    ins, outs = kinds.split("->")
+    return jax.shard_map(
+        functools.partial(
+            call, heads=heads and heads // sizes.get(ha, 1)),
+        mesh=mesh, in_specs=tuple(spec[c] for c in ins),
+        out_specs=tuple(spec[c] for c in outs),
+        check_vma=False)(*operands)
 
 
 _FLASH_OP_ATTRS = {"causal": False, "scale": 0.0, "block_q": 0,
-                   "block_k": 0, "gspmd_batch_axis": "",
+                   "block_k": 0, "heads": 0, "gspmd_batch_axis": "",
                    "gspmd_head_axis": ""}
 
 
@@ -1550,14 +1816,17 @@ def _flash_op_call(attrs):
 @register_op("flash_attention", inputs=("Q", "K", "V"),
              outputs=("Out", "LSE"), attrs=_FLASH_OP_ATTRS)
 def _flash_attention_op(ins, attrs):
-    """Out and the residual the grad op reads instead of running the
-    forward kernel again: LSE, the per-row log-sum-exp, float32
-    [B, H, Tq] on every impl (_flash_attention_fwd).  An op desc that
-    binds no LSE (a program from before the slot) runs the same."""
-    call = functools.partial(_flash_attention_fwd, **_flash_op_call(attrs))
-    operands = (ins["Q"], ins["K"], ins["V"])
-    res = _gspmd_flash_shard_map(attrs, call, operands, (4, 3))
-    out, lse = call(*operands) if res is None else res
+    """Q, K, V and Out are [B, H, T, D], or, where the `heads` attr is
+    set, token-major [B, T, H*D] as the projections leave them
+    (`_flash_layout`).  Out comes with the residual the grad op reads
+    instead of running the forward kernel again: LSE, the per-row
+    log-sum-exp, float32 [B, H, Tq] on every impl and both layouts
+    (_flash_attention_fwd).  An op desc that binds no LSE (a program
+    from before the slot) runs the same."""
+    out, lse = _gspmd_flash_shard_map(
+        attrs,
+        functools.partial(_flash_attention_fwd, **_flash_op_call(attrs)),
+        (ins["Q"], ins["K"], ins["V"]), "xxx->xl")
     return {"Out": out, "LSE": lse}
 
 
@@ -1590,11 +1859,11 @@ def _flash_attention_grad_op(ins, attrs):
         dq, dk, dv = vjp(g)
     else:
         _count_impl("flash_attention_grad", "saved")
-        call = functools.partial(_flash_attention_bwd,
-                                 **_flash_op_call(attrs))
-        operands = (q, k, v, ins["Out"], ins["LSE"], g)
-        res = _gspmd_flash_shard_map(attrs, call, operands, (4, 4, 4))
-        dq, dk, dv = call(*operands) if res is None else res
+        dq, dk, dv = _gspmd_flash_shard_map(
+            attrs,
+            functools.partial(_flash_attention_bwd,
+                              **_flash_op_call(attrs)),
+            (q, k, v, ins["Out"], ins["LSE"], g), "xxxxlx->xxx")
     return {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
 
 

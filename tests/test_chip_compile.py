@@ -38,6 +38,40 @@ def _flash(shape, grad, n_bwd=1):
         (n_bwd + 1 if grad else 1)
 
 
+def _flash_token_major(shape, heads, grad):
+    """The op's entries on token-major [B, T, H*d] operands, as the
+    q, k and v projections leave them: the forward kernel, and the
+    one-sweep backward on the saved residuals."""
+    from paddle_tpu.ops.pallas_kernels import (_flash_attention_bwd,
+                                               _flash_attention_fwd)
+
+    x = _sds(shape)
+    call = dict(causal=True, impl="pallas", heads=heads)
+    if not grad:
+        return (lambda q, k, v: _flash_attention_fwd(q, k, v, **call)), \
+            (x,) * 3, 1
+    lse = _sds((shape[0], heads, shape[1]), jnp.float32)
+    return (lambda q, k, v, o, lse, g: _flash_attention_bwd(
+        q, k, v, o, lse, g, **call)), (x, x, x, x, lse, x), 1
+
+
+def _attention_block(batch=64, seq=512, width=512, heads=8):
+    """Projection -> flash -> projection, with its gradient: what a
+    Transformer layer's attention is once the op takes the
+    projections' output as it is."""
+    from paddle_tpu.ops.pallas_kernels import flash_attention
+
+    def loss(x, wq, wk, wv, wo):
+        q, k, v = (jnp.dot(x, w) for w in (wq, wk, wv))
+        out = flash_attention(q, k, v, causal=True, impl="pallas",
+                              heads=heads)
+        return jnp.dot(out, wo).astype(jnp.float32).sum()
+
+    w = _sds((width, width))
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), \
+        (_sds((batch, seq, width)), w, w, w, w), 2
+
+
 def _flash_mla(grad, shape=(1, 32, 4096)):
     """Latent attention's two head sizes: q.k 192 (128 + 64 rotary),
     v 128; the forward and the saved-residual backward kernels."""
@@ -117,6 +151,20 @@ CASES = {
     "flash_bwd_4x8x8192x64": lambda: _flash((4, 8, 8192, 64), True),
     "flash_bwd_two_sweep_1x2x131072x64": lambda: _flash(
         (1, 2, 131072, 64), True, n_bwd=2),
+    # token-major, at the three tfm_base_* cells' widths (the last is
+    # one chip's shard of _dp2tp2: half the heads of half the batch)
+    **{"flash_%s_token_major_%s_h%d" % (
+        "bwd" if grad else "fwd", "x".join(map(str, shape)), heads):
+       (lambda shape=shape, heads=heads, grad=grad:
+        _flash_token_major(shape, heads, grad))
+       for shape, heads in (((4, 8192, 512), 8), ((64, 512, 512), 8),
+                            ((64, 512, 256), 4))
+       for grad in (False, True)},
+    "flash_fwd_2x16384x256_token_major_d128": lambda:
+        _flash_token_major((2, 16384, 256), 2, False),
+    "flash_bwd_2x16384x256_token_major_d128": lambda:
+        _flash_token_major((2, 16384, 256), 2, True),
+    "attention_block_64x512x512_token_major": _attention_block,
     "flash_fwd_1x32x4096_qk192_v128": lambda: _flash_mla(False),
     "flash_bwd_saved_1x32x4096_qk192_v128": lambda: _flash_mla(True),
     "gmm_fwd_8x3584x1024_rows16384": lambda: _gmm("fwd"),
@@ -139,3 +187,6 @@ def test_kernel_compiles_for_described_v5e(chip_gate, case):
     assert exe.as_text().count(
         'custom_call_target="tpu_custom_call"') == n_kernels
     assert exe.memory_analysis().temp_size_in_bytes >= 0
+    if "token_major" in case:
+        # no head split or merge around the kernels
+        assert chip_gate.head_layout_copies(exe.as_text()) == 0

@@ -170,13 +170,15 @@ def test_saved_equals_recompute_bit_for_bit(interpret, shape, kw):
     saved = _run(prog, feed, GRADS)
     assert _since(before) == {("flash_attention", "interpret"): 1,
                               ("flash_attention_grad", "saved"): 1,
-                              ("flash_attention_bwd", "fused"): 1}
+                              ("flash_attention_bwd", "fused"): 1,
+                              ("flash_attention_layout", "head_major"): 2}
     _unbind_saved(prog)
     before = _impl_counts()
     recomputed = _run(prog, feed, GRADS)
     assert _since(before) == {("flash_attention", "interpret"): 2,
                               ("flash_attention_grad", "recompute"): 1,
-                              ("flash_attention_bwd", "fused"): 1}
+                              ("flash_attention_bwd", "fused"): 1,
+                              ("flash_attention_layout", "head_major"): 2}
     for name, a, b in zip(GRADS, saved, recomputed):
         assert np.array_equal(a, b), name
         assert np.abs(a).max() > 0, name
@@ -318,6 +320,7 @@ def test_saved_path_under_shard_map_matches_one_device(interpret):
             ("flash_attention", "interpret"): 1,
             ("flash_attention_grad", "saved"): 1,
             ("flash_attention_bwd", "fused"): 1,
+            ("flash_attention_layout", "head_major"): 2,
             ("flash_attention_gspmd", "shard_map"): 2}
     finally:
         set_flags({"gspmd": False})
@@ -373,6 +376,85 @@ def test_transformer_step_counts_saved_six_times(interpret):
                       feed={"src_ids": ids, "tgt_label": ids},
                       fetch_list=[model["loss"]])
     assert np.isfinite(loss).all()
+    # heads of 8 lanes: the op transposes inside (`_flash_layout`)
     assert _since(before) == {("flash_attention", "interpret"): 6,
                               ("flash_attention_grad", "saved"): 6,
-                              ("flash_attention_bwd", "fused"): 6}
+                              ("flash_attention_bwd", "fused"): 6,
+                              ("flash_attention_layout", "head_major"): 12}
+
+
+def test_transformer_step_at_the_cells_head_size_is_token_major(interpret):
+    """8 heads of 64 lanes, the `tfm_base_*` cells' attention: every
+    layer's forward and backward address the heads in place."""
+    from paddle_tpu.models.transformer import transformer_encoder_model
+
+    model = transformer_encoder_model(
+        vocab_size=32, max_len=16, d_model=512, n_head=8, d_inner=32,
+        n_layer=2, dropout_rate=0.0, param_prefix="tfm")
+    optimizer.Adam(1e-3).minimize(model["loss"])
+    prog = framework.default_main_program()
+    ids = np.random.RandomState(0).randint(0, 32, (1, 16, 1)) \
+        .astype(np.int64)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(framework.default_startup_program())
+    before = _impl_counts()
+    (loss,) = exe.run(fluid.CompiledProgram(prog),
+                      feed={"src_ids": ids, "tgt_label": ids},
+                      fetch_list=[model["loss"]])
+    assert np.isfinite(loss).all()
+    assert _since(before) == {("flash_attention", "interpret"): 2,
+                              ("flash_attention_grad", "saved"): 2,
+                              ("flash_attention_bwd", "fused"): 2,
+                              ("flash_attention_layout", "token_major"): 4}
+
+
+# -- token-major operands: the op on [B, T, H*d] ------------------------------
+
+def _token_major(feed):
+    """[B, H, T, D] feeds as the projections leave them: [B, T, H*D]."""
+    return {n: np.ascontiguousarray(x.transpose(0, 2, 1, 3)).reshape(
+        x.shape[0], x.shape[2], -1) for n, x in feed.items()}
+
+
+@pytest.mark.parametrize("h,d,layout", [(2, 64, "token_major"),
+                                        (2, 128, "token_major"),
+                                        (3, 64, "head_major"),
+                                        (4, 32, "head_major")])
+def test_rank3_op_binds_its_residuals_and_matches_rank4(interpret, h, d,
+                                                        layout):
+    """`append_backward` on a flash_attention that takes [B, T, H*d]:
+    Out and LSE bound on the grad op, LSE [B, H, Tq], the backward on
+    the saved residuals; the numbers of the rank-4 op on the same
+    heads."""
+    feed4 = _feed(b=2, h=h, tq=32, tk=32, d=d)
+    kw = dict(causal=True, block_q=16, block_k=16)
+    want = _run(_attention_net(feed4, **kw), feed4, GRADS)
+    framework.switch_main_program(Program())
+    feed3 = _token_major(feed4)
+    prog = _attention_net(feed3, n_head=h, **kw)
+    (grad_op,) = [op for op in prog.global_block().ops
+                  if op.type == "flash_attention_grad"]
+    (fwd_op,) = [op for op in prog.global_block().ops
+                 if op.type == "flash_attention"]
+    assert grad_op.inputs["Out"] == fwd_op.outputs["Out"]
+    assert grad_op.inputs["LSE"] == fwd_op.outputs["LSE"]
+    assert fwd_op.attrs["heads"] == grad_op.attrs["heads"] == h
+    block = prog.global_block()
+    assert tuple(block.var(fwd_op.outputs["Out"][0]).shape[1:]) \
+        == (32, h * d)
+    assert tuple(block.var(fwd_op.outputs["LSE"][0]).shape[1:]) == (h, 32)
+    before = _impl_counts()
+    got = _run(prog, feed3, GRADS)
+    assert _since(before) == {("flash_attention", "interpret"): 1,
+                              ("flash_attention_grad", "saved"): 1,
+                              ("flash_attention_bwd", "fused"): 1,
+                              ("flash_attention_layout", layout): 2}
+    assert _kernel_calls(prog, feed3) == dict.fromkeys(KERNELS, 1)
+    for name, a, w in zip(GRADS, got, want):
+        w = _token_major({name: w})[name]
+        assert a.shape == w.shape, name
+        if layout == "head_major":
+            assert np.array_equal(a, w), name
+        else:       # delta's sum in another order: a few ulps
+            np.testing.assert_allclose(a, w, atol=2e-6 * np.abs(w).max(),
+                                       err_msg=name)

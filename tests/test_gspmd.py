@@ -409,3 +409,135 @@ def test_serving_prewarm_buckets(tmp_path):
         srv.stop()
     # default stays off without the compile-cache env
     assert serving.ServingConfig().prewarm in (False,)
+
+
+# ---------------------------------------------------------------------------
+# flash attention on token-major operands (ISSUE 31): [B, T, H*d] rides
+# P(dp, None, tp), the sharding a column-parallel projection leaves
+# ---------------------------------------------------------------------------
+
+def _rank3_attention_net(h, d, b=4, t=32):
+    """One rank-3 flash_attention op with its backward; returns
+    (program, feed)."""
+    from paddle_tpu.backward import append_backward
+
+    _fresh()
+    rng = np.random.RandomState(0)
+    feed = {n: rng.randn(b, t, h * d).astype(np.float32) for n in "qkv"}
+    q, k, v = (layers.data(n, shape=[t, h * d], dtype="float32")
+               for n in "qkv")
+    for var in (q, k, v):
+        var.stop_gradient = False
+    out = layers.flash_attention(q, k, v, causal=True, n_head=h,
+                                 block_q=16, block_k=16)
+    append_backward(layers.mean(layers.square(out)))
+    return framework.default_main_program(), feed
+
+
+def _shard_map_specs(fn, *args):
+    """in_specs / out_specs of every shard_map in the jaxpr of fn."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "shard_map":
+                found.append((eqn.params["in_specs"],
+                              eqn.params["out_specs"]))
+            for val in eqn.params.values():
+                for sub in (val if isinstance(val, (list, tuple))
+                            else [val]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_rank3_flash_runs_under_shard_map_and_matches_one_device(
+        monkeypatch):
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.core.compiler import _TraceEnv, _run_block_symbolic
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_auto_impl", lambda: "interpret")
+    grads = ["q@GRAD", "k@GRAD", "v@GRAD"]
+    prog, feed = _rank3_attention_net(h=4, d=64)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with scope_guard(Scope()):
+        want = exe.run(fluid.CompiledProgram(prog), feed=feed,
+                       fetch_list=grads)
+    set_flags({"gspmd": True})
+    compiled = shard_program(fluid.CompiledProgram(prog),
+                             MeshPlan(dp=2, tp=2),
+                             devices=jax.devices()[:4])
+    ops = [op for op in prog.global_block().ops
+           if op.type.startswith("flash_attention")]
+    assert [(op.attrs.get("gspmd_batch_axis"),
+             op.attrs.get("gspmd_head_axis"), op.attrs["heads"])
+            for op in ops] == [("dp", "tp", 4)] * 2
+
+    def counts():
+        c = pk._M_KERNEL_IMPL
+        return {k: c.value(kernel=k[0], impl=k[1]) for k in (
+            ("flash_attention_gspmd", "shard_map"),
+            ("flash_attention_gspmd", "plain"),
+            ("flash_attention_layout", "token_major"),
+            ("flash_attention_layout", "head_major"))}
+
+    before = counts()
+    with scope_guard(Scope()):
+        got = exe.run(compiled, feed=feed, fetch_list=grads)
+    # both ops under shard_map; a shard holds 2 of the 4 heads, one
+    # 128-lane block: token-major inside it too
+    assert {k: v - before[k] for k, v in counts().items()} == {
+        ("flash_attention_gspmd", "shard_map"): 2,
+        ("flash_attention_gspmd", "plain"): 0,
+        ("flash_attention_layout", "token_major"): 2,
+        ("flash_attention_layout", "head_major"): 0}
+    for name, a, w in zip(grads, got, want):
+        np.testing.assert_allclose(np.asarray(a), w,
+                                   atol=2e-6 * np.abs(w).max(),
+                                   err_msg=name)
+        assert np.abs(w).max() > 0
+
+    def step(feeds):
+        env = _TraceEnv()
+        env.update(feeds)
+        _run_block_symbolic(prog, 0, env)
+        return [env[g] for g in grads]
+
+    x, lse = P("dp", None, "tp"), P("dp", "tp", None)
+    assert _shard_map_specs(step, feed) == [
+        ((x, x, x), (x, lse)), ((x, x, x, x, lse, x), (x, x, x))]
+
+
+def test_shape_check_reads_heads_from_the_rank3_op():
+    from paddle_tpu.analysis import check_sharding
+    from paddle_tpu.analysis.shape_check import ShardingCheckError
+    from paddle_tpu.parallel.gspmd import tag_attention_ops
+
+    def diags(h, d, plan):
+        prog, _ = _rank3_attention_net(h=h, d=d)
+        assert tag_attention_ops(prog, plan) == 2
+        return check_sharding(prog, plan, raise_=False)
+
+    assert diags(4, 64, MeshPlan(dp=2, tp=2)) == []
+    # 6 heads over tp=4: the shard_map gate would fall back silently
+    bad = diags(6, 64, MeshPlan(dp=1, tp=4))
+    assert {(d.rule, d.severity) for d in bad} == {
+        ("sharding-indivisible", "error")}
+    assert all("head extent 6" in d.message for d in bad)
+    prog, _ = _rank3_attention_net(h=6, d=64)
+    tag_attention_ops(prog, MeshPlan(dp=1, tp=4))
+    with pytest.raises(ShardingCheckError):
+        check_sharding(prog, MeshPlan(dp=1, tp=4))
+    # 6 heads of 64 lanes over tp=2: 3 a shard do not fill lane
+    # blocks; right numbers, the copies back (a warning, both ops)
+    odd = diags(6, 64, MeshPlan(dp=2, tp=2))
+    assert [(d.rule, d.severity, d.op_type) for d in odd] == [
+        ("attention-head-layout", "warning", "flash_attention"),
+        ("attention-head-layout", "warning", "flash_attention_grad")]
+    # at 128 lanes a head every count fills its blocks
+    assert diags(6, 128, MeshPlan(dp=2, tp=2)) == []

@@ -357,6 +357,63 @@ def test_transformer_fused_vs_unfused():
     np.testing.assert_allclose(outs[True], outs[False], rtol=2e-3)
 
 
+def test_transformer_flash_branch_has_no_head_split_or_merge(monkeypatch):
+    """The flash branch hands the op the q, k, v projections as they
+    are and the output projection the op's Out: no transpose (and no
+    reshape) op in the program, forward or backward.  Three Adam steps
+    give the losses of the same weights through the rank-4 op between
+    `_split_heads` and the merge, which is what the branch was; both on
+    the XLA impl, where the rank-3 op makes those transposes inside."""
+    import paddle_tpu as fluid
+    import paddle_tpu.models.transformer as tr
+    from paddle_tpu import framework, layers, optimizer, unique_name
+    from paddle_tpu.core.program import Program
+    from paddle_tpu.core.scope import Scope, scope_guard
+
+    def rank4(q, k, v, causal=False, n_head=None):
+        tq, tk, width = q.shape[1], k.shape[1], q.shape[2]
+        q = tr._split_heads(q, tq, n_head, width // n_head)
+        k = tr._split_heads(k, tk, n_head, width // n_head)
+        v = tr._split_heads(v, tk, n_head, width // n_head)
+        out = layers.transpose(
+            flash(q, k, v, causal=causal), [0, 2, 1, 3])
+        return layers.reshape(out, [-1, tq, width])
+
+    flash = layers.flash_attention
+    ids = np.random.RandomState(0).randint(0, 64, (2, 16, 1)) \
+        .astype(np.int64)
+    losses = {}
+    for which in ("rank3", "rank4"):
+        framework.switch_main_program(Program())
+        framework.switch_startup_program(Program())
+        unique_name.switch({})
+        np.random.seed(7)                  # same param init both times
+        if which == "rank4":
+            monkeypatch.setattr(layers, "flash_attention", rank4)
+        model = tr.transformer_encoder_model(
+            vocab_size=64, max_len=16, d_model=128, n_head=2, d_inner=64,
+            n_layer=2, dropout_rate=0.0)
+        optimizer.Adam(1e-2).minimize(model["loss"])
+        prog = framework.default_main_program()
+        types = [op.type for op in prog.global_block().ops]
+        assert types.count("flash_attention") == 2
+        assert types.count("flash_attention_grad") == 2
+        moved = [t for t in types if t.startswith(("transpose",
+                                                   "reshape"))]
+        assert bool(moved) == (which == "rank4"), moved
+        with scope_guard(Scope()):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(framework.default_startup_program())
+            compiled = fluid.CompiledProgram(prog)
+            losses[which] = [
+                float(exe.run(compiled,
+                              feed={"src_ids": ids, "tgt_label": ids},
+                              fetch_list=[model["loss"]])[0])
+                for _ in range(3)]
+    assert losses["rank3"][2] < losses["rank3"][0]
+    assert losses["rank3"] == losses["rank4"]
+
+
 # ---------------------------------------------------------------------------
 # Packed row-stats + head-packing layout variants (flash memory
 # overhaul): outputs must be BIT-parity with the default layouts in

@@ -37,6 +37,43 @@ def test_bench_workload_lowers_for_tpu(chip_gate, workload):
     ok, detail, _ = chip_gate.check_workload(
         workload, chip_gate._workloads()[workload])
     assert ok, detail
+    if workload in chip_gate.NO_HEAD_LAYOUT_COPIES:
+        # flash attention takes the projections' [B, T, H*d] as it is
+        # (ISSUE 31): 12 kernels, and no head split or merge around them
+        assert detail["head_layout_copies"] == 0
+        assert detail["tpu_custom_calls"] == 12
+
+
+@pytest.mark.parametrize("workload", ["longctx_train_hp2"])
+def test_two_heads_a_step_compile_at_1024_row_blocks(chip_gate,
+                                                     workload):
+    """The forward asks for its VMEM (ISSUE 31): refused before, by
+    the parent and every PR since the blocks went to 1,024 rows."""
+    ok, detail, _ = chip_gate.check_workload(
+        workload, chip_gate._workloads()[workload])
+    assert ok, detail
+
+
+def test_head_layout_copies_counts_rank4_float_copies_of_the_entry():
+    """The reader itself, on text: only ENTRY, only `copy`, only rank-4
+    float arrays (a head split is [B, T, H, d] <-> [B, H, T, d])."""
+    from tools.tpu_lowering_check import head_layout_copies
+
+    text = """HloModule m
+%fused (p: bf16[64,8,512,64]) -> bf16[64,8,512,64] {
+  %c = bf16[64,8,512,64]{3,2,1,0} copy(%p)
+}
+
+ENTRY %main (a: bf16[64,512,512]) -> bf16[64,512,512] {
+  %copy.1 = bf16[64,8,512,64]{3,2,1,0:T(8,128)(2,1)} copy(%bitcast.1), metadata={}
+  %copy.2 = bf16[64,512,8,64]{1,0,3,2:T(8,128)(2,1)S(1)} copy(%copy.1)
+  %copy.3 = bf16[512,512]{0,1:T(8,128)(2,1)} copy(%w)
+  %copy.4 = s32[1,4,4,128]{3,1,2,0:T(4,128)} copy(%idx)
+  %copy.5 = f32[64,512,512]{1,2,0:T(8,128)} copy(%x)
+  %t = bf16[64,8,512,64]{3,2,1,0} transpose(%y), dimensions={0,2,1,3}
+}
+"""
+    assert head_layout_copies(text) == 2
 
 
 @pytest.mark.parametrize("which,causal", [

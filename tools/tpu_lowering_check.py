@@ -42,6 +42,7 @@ import contextlib
 import functools
 import json
 import os
+import re
 import sys
 import time
 
@@ -301,11 +302,30 @@ def _infer(progs, which, batch, conv_epilogue=False):
 
 FAST_SKIP = ("resnet50_train", "bert_train")
 
+# the Transformer steps whose attention takes q, k and v token-major,
+# [B, T, H*d] as the projections leave them: their compiled step may
+# hold no head split or merge
+NO_HEAD_LAYOUT_COPIES = ("transformer_train", "transformer_train_gspmd")
+
+
+def head_layout_copies(hlo_text):
+    """`copy` instructions of rank-4 float arrays in the ENTRY computation
+    of a compiled module: the head splits and merges ([B, T, H, d] <->
+    [B, H, T, d], each made in two hops) of a step whose attention is
+    fed head-major.  96 in the 64 x 512 Transformer step before flash
+    attention took token-major operands (PERF.md, PR 31), 10 ms of its
+    99."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    return len(re.findall(
+        r" = (?:bf16|f16|f32)\[\d+(?:,\d+){3}\]\S* copy\(", entry))
+
 
 def check_workload(name, build):
     """Build the gate program and compile its jitted step for the
     described chip.  Returns (ok, detail, seconds); detail of a compile
-    that passed is its memory_analysis() and kernel count."""
+    that passed is its memory_analysis() and kernel count, and for the
+    NO_HEAD_LAYOUT_COPIES steps `head_layout_copies`, which fails the
+    workload unless it is 0."""
     t0 = time.time()
     # Force the Pallas path during tracing: impl auto-detection sees a
     # CPU device in this process, but the program we must validate is
@@ -327,12 +347,17 @@ def check_workload(name, build):
         exe = compile_for_chip(fn, (state, feed),
                                on_mesh=name in ON_MESH)
         mem = exe.memory_analysis()
+        text = exe.as_text()
         detail = {
-            "tpu_custom_calls": exe.as_text().count(
+            "tpu_custom_calls": text.count(
                 'custom_call_target="tpu_custom_call"'),
             **{k: getattr(mem, k + "_size_in_bytes") for k in (
                 "temp", "argument", "output", "alias",
                 "generated_code")}}
+        if name in NO_HEAD_LAYOUT_COPIES:
+            detail["head_layout_copies"] = head_layout_copies(text)
+            if detail["head_layout_copies"]:
+                return False, detail, time.time() - t0
         return True, detail, time.time() - t0
     except Exception as e:  # noqa: BLE001 — report, don't crash the sweep
         msg = "%s: %s" % (type(e).__name__, str(e)[:400])
