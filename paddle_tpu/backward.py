@@ -223,7 +223,8 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
             continue
         gop = OpDesc(op.type + "_grad", grad_inputs, grad_outputs,
                      dict(op.attrs), BACKWARD,
-                     stage=op.stage)  # grad runs on its fwd op's stage
+                     stage=op.stage,  # grad runs on its fwd op's stage
+                     scope=op.scope)
         block.ops.append(gop)
         # error clip (reference clip.py error_clip_callback): a forward
         # var carrying _set_error_clip gets its freshly produced grad
@@ -293,6 +294,37 @@ def gradients(targets, inputs, target_gradients=None, no_grad_set=None):
     return outs
 
 
+def _lift_shared_casts(block, segments):
+    """A cast of a persistable var (the AMP rewrite's one bf16 copy of
+    a float32 master weight) whose output is read from OTHER segments
+    than its own becomes a one-op segment ahead of all of them.  Such a
+    copy is alive from forward to backward anyway, as those segments'
+    boundary input; left in its first reader's segment it would be made
+    a second time in that segment's replay, the weight's bytes read and
+    written again for nothing.  Lifted, it is made once a step, and its
+    segment's backward is the cast of the summed partials.  A cast read
+    from its own segment only stays where it is: it dies with the
+    segment and is replayed with it, as before."""
+    home = {}
+    for si, seg in enumerate(segments):
+        for op in seg:
+            if op.type == "cast" and all(
+                    block.has_var(n) and block.var(n).persistable
+                    for n in op.input_names()):
+                for n in op.output_names():
+                    home[n] = (si, op)
+    lifted = {}         # id(op) -> op, in the order first read
+    for si, seg in enumerate(segments):
+        for op in seg:
+            for n in op.input_names():
+                if n in home and home[n][0] != si:
+                    lifted.setdefault(id(home[n][1]), home[n][1])
+    if not lifted:
+        return segments
+    kept = [[op for op in seg if id(op) not in lifted] for seg in segments]
+    return [[c] for c in lifted.values()] + [seg for seg in kept if seg]
+
+
 def _append_backward_recompute(loss, fwd_ops, parameter_list,
                                no_grad_set, checkpoints):
     """Segment-level backward for RecomputeOptimizer (reference incubate
@@ -328,7 +360,7 @@ def _append_backward_recompute(loss, fwd_ops, parameter_list,
         segments[-1].append(op)
         if any(n in cset for n in op.output_names()):
             segments.append([])
-    segments = [s for s in segments if s]
+    segments = _lift_shared_casts(block, [s for s in segments if s])
     for s in segments:
         for op in s:
             if any(isinstance(v, BlockRef) for v in op.attrs.values()):
@@ -388,8 +420,11 @@ def _append_backward_recompute(loss, fwd_ops, parameter_list,
         block.ops.append(op)
         for n, g in zip(grad_in_names, gnames):
             if n in grad_map:
-                # accumulate with the earlier partial
-                acc = _grad_name(n, "@ACC")
+                # accumulate with the earlier partials: a name of its
+                # own a sum, so that a var read from three and more
+                # segments (a weight shared by several executions of a
+                # layer) never has a sum that reads the name it writes
+                acc = _grad_name(n, f"@ACC{si}")
                 _create_grad_var(block, n, acc)
                 block.append_op(type="sum",
                                 inputs={"X": [grad_map[n], g]},

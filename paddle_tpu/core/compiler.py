@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from paddle_tpu.core.program import BlockRef, Program
+from paddle_tpu.core.program import BlockRef, Program, op_scope
 from paddle_tpu.core.registry import get_op_def, has_op_def
 from paddle_tpu.core.scope import Scope
 from paddle_tpu.observability import collector as _obs_collector
@@ -45,6 +45,31 @@ _M_STEP_SECONDS = _obs_metrics.histogram(
 _M_COMPILES = _obs_metrics.counter(
     "paddle_tpu_executor_compiles_total",
     "CompiledProgram jit-cache misses (trace+compile entries built)")
+
+
+def shared_param_reads(program):
+    """{parameter: (readers, casts)} for every parameter that two or
+    more forward ops of the global block read.  A reader of the AMP
+    rewrite's cast of a parameter reads the parameter; `casts` is how
+    many such casts the step makes of it (one, however many read it;
+    none for what stays float32, a norm's scale).  Ops that a recompute
+    segment replays are not in the block and are not counted."""
+    from paddle_tpu.core.program import FORWARD
+
+    params = {p.name for p in program.all_parameters()}
+    cast_of, readers, casts = {}, {}, {}
+    for op in program.global_block().ops:
+        if op.op_role != FORWARD:
+            continue
+        if op.type == "cast" and op.inputs["X"][0] in params:
+            src = op.inputs["X"][0]
+            cast_of[op.outputs["Out"][0]] = src
+            casts[src] = casts.get(src, 0) + 1
+            continue
+        for p in {cast_of.get(n, n) for n in op.input_names()} & params:
+            readers[p] = readers.get(p, 0) + 1
+    return {p: (n, casts.get(p, 0)) for p, n in readers.items() if n > 1}
+
 
 # host-only op types silently skipped when tracing (IO/readers run outside
 # the compiled step, like the reference's feed/fetch special handling)
@@ -164,7 +189,7 @@ def _program_fingerprint(program):
                 bool(v.persistable)))
         for op in b.ops:
             h = hash((
-                h, op.type, op.stage,
+                h, op.type, op.stage, op.scope,
                 tuple((s, tuple(n)) for s, n in sorted(op.inputs.items())),
                 tuple((s, tuple(n))
                       for s, n in sorted(op.outputs.items())),
@@ -242,7 +267,8 @@ def _run_block_symbolic(program, block_idx, env):
                        if env.get(n) is None]
             raise RuntimeError(
                 f"compile: op {op.type} missing inputs {missing}")
-        outs = op_def.compute(ins, op.attrs) or {}
+        with op_scope(op):
+            outs = op_def.compute(ins, op.attrs) or {}
         for slot, names in op.outputs.items():
             if slot not in outs:
                 continue

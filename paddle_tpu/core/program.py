@@ -16,6 +16,7 @@ reference: an op attribute holding a block index.
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import json
 from typing import Optional
@@ -40,6 +41,13 @@ LOSS = "loss"
 # PipelineOptimizer's program cut)
 _CURRENT_STAGE = [None]
 
+# active name scopes (framework.name_scope; reference: the op_namescope
+# attr): ops appended inside carry scope="a/b", and every place that
+# runs an op's compute (the compiled trace, a recompute segment's
+# replay) does so under jax.named_scope(scope), so the scope is in the
+# compiled step's op_name metadata
+_NAME_SCOPE: list = []
+
 # global IR mutation counter: bumped by every append_op / OpDesc.set_attr
 # so compiled-program fingerprints (compiler._program_fingerprint) can
 # memoize cheaply and revalidate on any structured IR edit
@@ -52,6 +60,16 @@ def ir_mutation_counter() -> int:
 
 def _bump_ir_mutation():
     _IR_MUTATION[0] += 1
+
+
+def op_scope(op):
+    """The context an op's compute runs in: jax.named_scope of the
+    name scope it was appended under, or nothing."""
+    if not op.scope:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.named_scope(op.scope)
 
 
 class pipeline_stage:
@@ -270,7 +288,7 @@ class OpDesc:
     """
 
     def __init__(self, type: str, inputs=None, outputs=None, attrs=None,
-                 op_role: str = FORWARD, stage=None):
+                 op_role: str = FORWARD, stage=None, scope=None):
         self.type = type
         self.inputs = {k: list(v) for k, v in (inputs or {}).items()}
         self.outputs = {k: list(v) for k, v in (outputs or {}).items()}
@@ -280,6 +298,7 @@ class OpDesc:
         # device_guard that PipelineOptimizer cuts the program at).  None
         # = unannotated; PipelineOptimizer infers by dataflow.
         self.stage = stage
+        self.scope = scope      # "a/b" of framework.name_scope, or None
 
     def set_attr(self, name, value):
         """In-place attr edit visible to compiled-program caching (a raw
@@ -329,6 +348,8 @@ class OpDesc:
         }
         if self.stage is not None:
             out["stage"] = self.stage
+        if self.scope:
+            out["scope"] = self.scope
         return out
 
     @staticmethod
@@ -343,7 +364,7 @@ class OpDesc:
                 attrs[k] = v
         return OpDesc(
             d["type"], d["inputs"], d["outputs"], attrs,
-            d.get("op_role", FORWARD), d.get("stage"),
+            d.get("op_role", FORWARD), d.get("stage"), d.get("scope"),
         )
 
 
@@ -374,6 +395,18 @@ class Block:
         return v
 
     def create_parameter(self, name, shape, dtype, **kwargs) -> VarDesc:
+        """A second call under a name that exists is SHARING: it returns
+        the one VarDesc, and raises where shape or dtype differ (a layer
+        built twice over one ParamAttr name reads one parameter)."""
+        have = self.vars.get(name)
+        if have is not None:
+            if tuple(have.shape or ()) != tuple(shape) or \
+                    str(have.dtype) != str(dtype):
+                raise ValueError(
+                    f"parameter '{name}' is shared by name: it exists as "
+                    f"{tuple(have.shape or ())} {have.dtype}, asked for "
+                    f"again as {tuple(shape)} {dtype}")
+            return have
         v = self.create_var(
             name, shape=shape, dtype=dtype, persistable=True, trainable=True,
             **kwargs,
@@ -424,7 +457,8 @@ class Block:
         op_def = get_op_def(type)
         attrs = op_def.canonical_attrs(attrs or {})
         op = OpDesc(type, in_names, out_names, attrs, op_role,
-                    stage=_CURRENT_STAGE[0])
+                    stage=_CURRENT_STAGE[0],
+                    scope="/".join(_NAME_SCOPE) or None)
         self.ops.append(op)
         _bump_ir_mutation()
         if infer_shape and not op_def.host_only:

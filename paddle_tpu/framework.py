@@ -50,12 +50,18 @@ def program_guard(main_program, startup_program=None):
 
 @contextlib.contextmanager
 def name_scope(prefix):
+    """Generated names get `prefix/`, and ops appended inside carry the
+    scope into the compiled step's metadata (core/program.py
+    `op_scope`)."""
     from paddle_tpu import unique_name
+    from paddle_tpu.core.program import _NAME_SCOPE
 
     unique_name._prefix.append(prefix)
+    _NAME_SCOPE.append(prefix)
     try:
         yield
     finally:
+        _NAME_SCOPE.pop()
         unique_name._prefix.pop()
 
 

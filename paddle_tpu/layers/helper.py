@@ -62,18 +62,28 @@ class LayerHelper:
             f"{self.name}.{suffix}"
         )
         shape = [int(s) for s in shape]
-        main_param = self.block.program.global_block().create_parameter(
-            name, shape, dtype
-        )
-        main_param.stop_gradient = not attr.trainable
-        main_param.trainable = attr.trainable
-        main_param.regularizer = attr.regularizer
+        main_block = self.block.program.global_block()
+        startup_block = self.startup_program.global_block()
+        shared = name in main_block.vars
+        # a name that exists is SHARED (a stack of layers run several
+        # times over one set of weights; a test program built beside the
+        # train program): ONE VarDesc, whose shape and dtype the second
+        # asker has to match and whose attributes the first one set
+        main_param = main_block.create_parameter(name, shape, dtype)
+        if not shared:
+            main_param.stop_gradient = not attr.trainable
+            main_param.trainable = attr.trainable
+            main_param.regularizer = attr.regularizer
+        if name in startup_block.vars:
+            # and ONE initializer op: one draw from the seed however
+            # many layers read the parameter
+            startup_block.create_parameter(name, shape, dtype)
+            return main_param
         init = (
             attr.initializer
             or default_initializer
             or (Constant(0.0) if is_bias else Xavier())
         )
-        startup_block = self.startup_program.global_block()
         sv = startup_block.create_parameter(name, shape, dtype)
         sv.trainable = attr.trainable
         init(sv, startup_block)

@@ -45,11 +45,13 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
 
 def rotary_embedding(x, rotary_dim=None, theta=10000.0, factor=1.0,
                      original_max_position=4096, beta_fast=32.0,
-                     beta_slow=1.0, mscale=1.0, name=None):
+                     beta_slow=1.0, mscale=1.0, pairing="interleaved",
+                     name=None):
     """Rotary position embedding of x [B, T, H, D] over positions
-    0..T-1: the last `rotary_dim` entries of D (default all) rotate as
-    interleaved pairs, with YaRN-scaled frequencies when factor != 1
-    (ops/llm_ops.py yarn_inv_freq)."""
+    0..T-1: the last `rotary_dim` entries of D (default all) rotate,
+    as interleaved pairs (x[2i], x[2i+1]) or, with pairing="halves",
+    as split halves (x[i], x[i + rotary_dim/2]); YaRN-scaled
+    frequencies when factor != 1 (ops/llm_ops.py yarn_inv_freq)."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(
@@ -58,7 +60,8 @@ def rotary_embedding(x, rotary_dim=None, theta=10000.0, factor=1.0,
                "factor": float(factor),
                "original_max_position": int(original_max_position),
                "beta_fast": float(beta_fast),
-               "beta_slow": float(beta_slow), "mscale": float(mscale)})
+               "beta_slow": float(beta_slow), "mscale": float(mscale),
+               "pairing": str(pairing)})
     return out
 
 

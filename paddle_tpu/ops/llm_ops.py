@@ -100,20 +100,27 @@ def yarn_inv_freq(dim, theta, factor, original_max, beta_fast, beta_slow):
 @register_op("rotary_embedding", inputs=("X",), outputs=("Out",),
              attrs={"rotary_dim": 0, "theta": 10000.0, "factor": 1.0,
                     "original_max_position": 4096, "beta_fast": 32.0,
-                    "beta_slow": 1.0, "mscale": 1.0})
+                    "beta_slow": 1.0, "mscale": 1.0,
+                    "pairing": "interleaved"})
 def rotary_embedding(ins, attrs):
     """X [B, T, H, D]: rotates the LAST rotary_dim entries of D (0: all
-    of D) as interleaved pairs (x[2i], x[2i+1]) by the angle
-    position * inv_freq[i], positions 0..T-1 along axis 1; the leading
-    D - rotary_dim entries pass through.  The frequencies are a
-    constant built from the attributes (yarn_inv_freq); cos and sin are
-    multiplied by `mscale`."""
+    of D) by the angle position * inv_freq[i], positions 0..T-1 along
+    axis 1; the leading D - rotary_dim entries pass through.  `pairing`
+    says which two entries turn together: "interleaved" (the default,
+    deepseek_v3's) the neighbours (x[2i], x[2i+1]), "halves" (the
+    Llama lineage's rotate_half) the entries (x[i], x[i + rd/2]) of the
+    rotated part.  The frequencies are a constant built from the
+    attributes (yarn_inv_freq); cos and sin are multiplied by
+    `mscale`."""
     x = ins["X"]
     d = x.shape[-1]
     rd = attrs["rotary_dim"] or d
     if rd % 2 or (d - rd) % 2:
         raise ValueError("rotary_embedding: rotary_dim %d of %d must "
                          "leave whole pairs on both sides" % (rd, d))
+    if attrs["pairing"] not in ("interleaved", "halves"):
+        raise ValueError("rotary_embedding: pairing %r is neither "
+                         "'interleaved' nor 'halves'" % attrs["pairing"])
     inv = yarn_inv_freq(rd, float(attrs["theta"]), float(attrs["factor"]),
                         int(attrs["original_max_position"]),
                         float(attrs["beta_fast"]),
@@ -121,6 +128,18 @@ def rotary_embedding(ins, attrs):
     with jax.named_scope("pt_mla"):
         t = x.shape[1]
         ang = jnp.arange(t, dtype=_F32)[:, None] * jnp.asarray(inv)[None]
+        if attrs["pairing"] == "halves":
+            cos = (jnp.cos(ang) * attrs["mscale"])[None, :, None, None, :]
+            sin = (jnp.sin(ang) * attrs["mscale"])[None, :, None, None, :]
+            rot = x[..., d - rd:].astype(_F32).reshape(
+                x.shape[:-1] + (2, rd // 2))
+            # (a, b) -> (a cos - b sin, b cos + a sin), a the first half
+            turned = jnp.flip(rot, -2) * jnp.asarray([[-1.0], [1.0]], _F32)
+            out = (rot * cos + turned * sin).reshape(
+                x.shape[:-1] + (rd,)).astype(x.dtype)
+            if rd < d:
+                out = jnp.concatenate([x[..., :d - rd], out], axis=-1)
+            return {"Out": out}
         # the pairs that pass through turn by the angle 0.  One product
         # over all of D: a slice and a concatenate would each cost a
         # copy of X forward and a padded copy of its gradient backward

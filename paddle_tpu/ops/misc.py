@@ -386,7 +386,7 @@ def recompute_segment_grad(ins, attrs):
     stops XLA from CSE-ing the replay against the forward pass — the
     intra-segment activations are genuinely not kept live between
     forward and backward."""
-    from paddle_tpu.core.program import OpDesc
+    from paddle_tpu.core.program import OpDesc, op_scope
     from paddle_tpu.core.registry import get_op_def
 
     ops = [OpDesc.from_dict(d) for d in attrs["ops"]]
@@ -410,7 +410,8 @@ def recompute_segment_grad(ins, attrs):
                     op_ins[slot] = vals
                 elif vals and vals[0] is not None:
                     op_ins[slot] = vals[0]
-            outs = od.compute(op_ins, op.attrs) or {}
+            with op_scope(op):
+                outs = od.compute(op_ins, op.attrs) or {}
             for slot, names in op.outputs.items():
                 if slot not in outs:
                     continue

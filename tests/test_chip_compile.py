@@ -164,6 +164,11 @@ CASES = {
         _flash_token_major((2, 16384, 256), 2, False),
     "flash_bwd_2x16384x256_token_major_d128": lambda:
         _flash_token_major((2, 16384, 256), 2, True),
+    # the looped decoder's cell: 16 heads of 128, one head a lane block
+    "flash_fwd_1x4096x2048_token_major_d128": lambda:
+        _flash_token_major((1, 4096, 2048), 16, False),
+    "flash_bwd_1x4096x2048_token_major_d128": lambda:
+        _flash_token_major((1, 4096, 2048), 16, True),
     "attention_block_64x512x512_token_major": _attention_block,
     "flash_fwd_1x32x4096_qk192_v128": lambda: _flash_mla(False),
     "flash_bwd_saved_1x32x4096_qk192_v128": lambda: _flash_mla(True),

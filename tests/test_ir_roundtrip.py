@@ -48,6 +48,11 @@ _BUILDERS = {
     "transformer_train_gspmd": lambda b:
         b._build_transformer_train(2, 64, gspmd=True, tp=2),
     "deepfm_train": lambda b: b._build_deepfm_train(64),
+    # ops under framework.name_scope carry their scope through bytes
+    "ouro_train": lambda b: b._build_ouro_train(
+        2, 32, hidden_size=64, num_attention_heads=4, head_dim=16,
+        num_key_value_heads=4, intermediate_size=160, vocab_size=256,
+        num_hidden_layers=2),
     "bert_train": lambda b: b._build_bert_train(1, 128),
     "longctx_train": lambda b: b._build_longctx_train(1, 2, 512, 64),
     "serving_tp_sharded": lambda b: b._build_serving_tp_sharded(tp=2),

@@ -207,6 +207,48 @@ def _build_transformer_train(batch, seq, fused_adam=False, gspmd=False,
     return fn, state, feed, model["loss"].name
 
 
+def _build_ouro_train(batch=1, seq=4096, **sizes):
+    """Build + init the looped decoder's train step as the cell
+    `ouro_2_6b_train_s4k` runs it: the benchmark's own builder
+    (benchmarks/builders/ouro.py `build`) on its configuration
+    (benchmarks/configs/ouro-2.6b.json), so one place says what the
+    cell's step is; `sizes` override the file's keys (the IR tests
+    build it small).  Returns (fn, state, feed, loss_name)."""
+    import importlib.util
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu import framework
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+
+    def load(*path):
+        spec = importlib.util.spec_from_file_location(
+            "_gate_" + path[-1][:-3], os.path.join(bench, *path))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    _fresh_programs()
+    with open(os.path.join(bench, "configs", "ouro-2.6b.json")) as f:
+        config = dict(json.load(f), **sizes)
+    built = load("builders", "ouro.py").build(
+        config, {"batch": batch, "seq_len": seq}, load("flops.py"))
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(framework.default_startup_program())
+    ids, labels = built["make_batch"](np.random.default_rng(0))
+    feed = {"src_ids": jax.device_put(jnp.asarray(ids)),
+            "tgt_label": jax.device_put(jnp.asarray(labels))}
+    loss = built["loss"].name
+    fn, state = _build_compiled_fn(built["compiled"], feed, [loss])
+    return fn, state, feed, loss
+
+
 # Devlin et al. 2018, BERT-base
 BERT_BASE = dict(d_model=768, n_layer=12, d_inner=3072, vocab=30522)
 

@@ -115,6 +115,12 @@ def _workloads():
     return {
         "transformer_train": lambda: progs._build_transformer_train(
             32, 512)[:3],
+        # the looped decoder at the cell's sizes (4,096 tokens, 6
+        # layers x 4 passes over one set of weights, the 49,152-wide
+        # head four times): its memory_analysis() says whether the
+        # step fits before chip time is spent, and token-major flash at
+        # head size 128 has no other program here
+        "ouro_train": lambda: progs._build_ouro_train(1, 4096)[:3],
         "resnet50_train": lambda: progs._build_resnet50_train(128)[:3],
         "resnet50_train_s2d": lambda: progs._build_resnet50_train(
             128, s2d=True)[:3],
@@ -300,12 +306,13 @@ def _infer(progs, which, batch, conv_epilogue=False):
                               conv_epilogue=conv_epilogue)[:3]
 
 
-FAST_SKIP = ("resnet50_train", "bert_train")
+FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train")
 
-# the Transformer steps whose attention takes q, k and v token-major,
-# [B, T, H*d] as the projections leave them: their compiled step may
-# hold no head split or merge
-NO_HEAD_LAYOUT_COPIES = ("transformer_train", "transformer_train_gspmd")
+# the steps whose attention takes q, k and v token-major, [B, T, H*d]
+# as the projections leave them: their compiled step may hold no head
+# split or merge
+NO_HEAD_LAYOUT_COPIES = ("transformer_train", "transformer_train_gspmd",
+                         "ouro_train")
 
 
 def head_layout_copies(hlo_text):
