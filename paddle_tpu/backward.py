@@ -54,6 +54,22 @@ def _create_grad_var(block, fwd_name, grad_name):
     return block.vars[grad_name]
 
 
+def _saved_output_slots(op):
+    """The forward OUTPUT slots of `op` that its grad op declares among
+    its inputs: saved residuals (the reference's
+    DefaultGradOpDescMaker hands the grad op inputs, outputs and output
+    grads alike).  The generic vjp grad declares none, so for every
+    op without a registered grad nothing more is bound and nothing
+    more stays live; today flash_attention_grad alone declares any
+    (Out, LSE)."""
+    op_def = get_op_def(op.type)
+    if not op_def.differentiable or op_def.grad_maker is not None:
+        return []
+    grad_def = get_op_def(op.type + "_grad")
+    return [slot for slot in op.outputs
+            if slot in grad_def.inputs and slot not in op.inputs]
+
+
 def append_backward(loss, parameter_list=None, no_grad_set=None,
                     callbacks=None, checkpoints=None):
     """Appends grad ops for every op contributing to `loss`; returns
@@ -188,15 +204,8 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
         grad_inputs = dict(grad_out_slots)
         for slot, names in op.inputs.items():
             grad_inputs[slot] = list(names)
-        # a forward OUTPUT the grad op declares among its inputs is a
-        # saved residual (the reference's DefaultGradOpDescMaker hands
-        # the grad op inputs, outputs and output grads alike).  The
-        # generic vjp grad declares none, so for every other op nothing
-        # more is bound and nothing more stays live
-        grad_def = get_op_def(op.type + "_grad")
-        for slot, names in op.outputs.items():
-            if slot in grad_def.inputs and slot not in grad_inputs:
-                grad_inputs[slot] = list(names)
+        for slot in _saved_output_slots(op):
+            grad_inputs[slot] = list(op.outputs[slot])
         grad_outputs = {}
         for slot, names in op.inputs.items():
             if not any(_needs_grad(block, n, no_grad_set)
@@ -332,7 +341,14 @@ def _append_backward_recompute(loss, fwd_ops, parameter_list,
     each segment becomes one recompute_segment_grad op whose compute
     replays the segment under jax.checkpoint — the optimization barrier
     stops XLA CSE from deduplicating the replay against the forward
-    pass, which is what makes the memory saving real)."""
+    pass, which is what makes the memory saving real).
+
+    One kind of intra-segment activation does stay live from forward to
+    backward: the outputs an op's registered grad op reads
+    (`_saved_output_slots`: flash_attention's Out and LSE).  The segment
+    op binds them under `Saved`, and its replay takes them for the op's
+    outputs instead of running the op again, as the plain backward's
+    grad op does."""
     from paddle_tpu.core.program import BlockRef
 
     block = loss.block
@@ -407,6 +423,8 @@ def _append_backward_recompute(loss, fwd_ops, parameter_list,
             g = _grad_name(n, f"@SEG{si}" if n in grad_map else "")
             _create_grad_var(block, n, g)
             gnames.append(g)
+        saved = [n for o in seg for slot in _saved_output_slots(o)
+                 for n in o.outputs[slot]]
         op = OpDesc(
             "recompute_segment_grad",
             {"X": list(seg_ins),
@@ -417,6 +435,9 @@ def _append_backward_recompute(loss, fwd_ops, parameter_list,
              "out_names": seg_out_grads,
              "grad_in_names": grad_in_names},
             BACKWARD)
+        if saved:
+            op.inputs["Saved"] = saved
+            op.attrs["saved_names"] = list(saved)
         block.ops.append(op)
         for n, g in zip(grad_in_names, gnames):
             if n in grad_map:

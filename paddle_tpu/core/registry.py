@@ -87,6 +87,14 @@ class OpDef:
     in_place: dict = dataclasses.field(default_factory=dict)
     # host ops run outside jit (readers, prints, saves)
     host_only: bool = False
+    # on a registered '<type>_grad' op that declares forward OUTPUTS
+    # among its inputs: fn(ins, attrs) -> bool, whether its compute
+    # will read them from `ins` (True) or differentiate the forward
+    # compute again (False).  A recompute segment asks before it takes
+    # the forward pass's outputs in place of the op's replay
+    # (ops/misc.py recompute_segment_grad).  None: the segment replays
+    # the op, like every op without a registered grad.
+    reads_saved: Optional[Callable] = None
 
     def canonical_attrs(self, attrs: dict) -> dict:
         out = {}
@@ -126,6 +134,7 @@ def register_op(
     differentiable: bool = True,
     in_place: Optional[dict] = None,
     host_only: bool = False,
+    reads_saved: Optional[Callable] = None,
 ):
     """Decorator registering ``compute`` as op ``type``."""
 
@@ -144,6 +153,7 @@ def register_op(
             differentiable=differentiable,
             in_place=dict(in_place or {}),
             host_only=host_only,
+            reads_saved=reads_saved,
         )
         return compute
 

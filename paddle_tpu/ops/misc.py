@@ -370,11 +370,51 @@ def merge_selected_rows(ins, attrs):
     return {"Out": SelectedRows(jnp.asarray(uniq), vals, x.height)}
 
 
+def _zero_ct(x):
+    # integer/bool values take float0 cotangents (jax's symbolic zero
+    # type): an int zeros_like breaks vjp tree matching
+    if jnp.issubdtype(x.dtype, jnp.inexact):
+        return jnp.zeros_like(x)
+    return np.zeros(x.shape, dtype=jax.dtypes.float0)
+
+
+def _outputs_from_saved(grad_def, attrs, op_ins, saved):
+    """An op's outputs inside a recompute segment, where the forward
+    pass kept them: `saved` ({slot: value}) as they are, no compute;
+    differentiated by the op's registered grad op on the replayed
+    inputs, the saved outputs and the outputs' cotangents.  A cotangent
+    the grad op declares no slot for (flash_attention's LSE) is
+    dropped, as the plain backward drops it."""
+    from paddle_tpu.core.registry import GRAD_SUFFIX
+
+    @jax.custom_vjp
+    def outputs(op_ins, saved):
+        return saved
+
+    def fwd(op_ins, saved):
+        return saved, (op_ins, saved)
+
+    def bwd(res, cts):
+        op_ins, saved = res
+        grad_ins = {**op_ins, **saved}
+        for slot, g in cts.items():
+            if slot + GRAD_SUFFIX in grad_def.inputs:
+                grad_ins[slot + GRAD_SUFFIX] = g
+        grads = grad_def.compute(grad_ins, attrs)
+        return ({slot: grads[slot + GRAD_SUFFIX] for slot in op_ins},
+                jax.tree_util.tree_map(_zero_ct, saved))
+
+    outputs.defvjp(fwd, bwd)
+    return outputs(op_ins, saved)
+
+
 @register_op("recompute_segment_grad",
-             inputs=("X", "OutGrad"), outputs=("XGrad",),
-             duplicable=("X", "OutGrad", "XGrad"),
+             inputs=("X", "OutGrad", "Saved"), outputs=("XGrad",),
+             duplicable=("X", "OutGrad", "XGrad", "Saved"),
+             optional=("Saved",),
              attrs={"ops": REQUIRED, "in_names": REQUIRED,
-                    "out_names": REQUIRED, "grad_in_names": REQUIRED},
+                    "out_names": REQUIRED, "grad_in_names": REQUIRED,
+                    "saved_names": []},
              differentiable=False)
 def recompute_segment_grad(ins, attrs):
     """Backward of one recompute segment (reference incubate
@@ -385,7 +425,19 @@ def recompute_segment_grad(ins, attrs):
     BOUNDARY values only, and the checkpoint's optimization barrier
     stops XLA from CSE-ing the replay against the forward pass — the
     intra-segment activations are genuinely not kept live between
-    forward and backward."""
+    forward and backward.
+
+    But for `Saved` (names in `saved_names`): outputs of segment ops
+    whose registered grad op reads them (flash_attention's Out and
+    LSE), bound from the forward pass.  Such an op is not replayed for
+    its outputs: they are the bound values, arguments of the
+    checkpointed function and so residuals, and its backward is the
+    registered grad op (`_outputs_from_saved`).  XLA does not CSE a
+    Mosaic call, so the replay ran the forward kernel a second time in
+    every segment (PERF.md, PR 33).  The grad op says whether it will
+    read them (`OpDef.reads_saved`: flash says no on the XLA impl);
+    where it will not, and in a desc from before the slot, the op is
+    replayed and differentiated by jax.vjp like every other."""
     from paddle_tpu.core.program import OpDesc, op_scope
     from paddle_tpu.core.registry import get_op_def
 
@@ -397,8 +449,9 @@ def recompute_segment_grad(ins, attrs):
     gs = dict(zip(out_names, ins["OutGrad"]))
     diff = {k: xs[k] for k in grad_in}
     nondiff = {k: v for k, v in xs.items() if k not in diff}
+    bound = dict(zip(attrs.get("saved_names", ()), ins.get("Saved", ())))
 
-    def replay(d):
+    def replay(d, bound):
         env = dict(nondiff)
         env.update(d)
         for op in ops:
@@ -410,8 +463,18 @@ def recompute_segment_grad(ins, attrs):
                     op_ins[slot] = vals
                 elif vals and vals[0] is not None:
                     op_ins[slot] = vals[0]
+            saved = {slot: bound[names[0]]
+                     for slot, names in op.outputs.items()
+                     if names and names[0] in bound}
+            grad_def = get_op_def(op.type + "_grad") if saved else None
             with op_scope(op):
-                outs = od.compute(op_ins, op.attrs) or {}
+                if saved and grad_def.reads_saved and \
+                        grad_def.reads_saved({**op_ins, **saved},
+                                             op.attrs):
+                    outs = _outputs_from_saved(grad_def, op.attrs,
+                                               op_ins, saved)
+                else:
+                    outs = od.compute(op_ins, op.attrs) or {}
             for slot, names in op.outputs.items():
                 if slot not in outs:
                     continue
@@ -423,19 +486,14 @@ def recompute_segment_grad(ins, attrs):
         return {n: env[n] for n in out_names}
 
     replay = jax.checkpoint(replay)
-    primal, vjp = jax.vjp(replay, diff)
-
-    def zero_ct(x):
-        if jnp.issubdtype(x.dtype, jnp.inexact):
-            return jnp.zeros_like(x)
-        return np.zeros(x.shape, dtype=jax.dtypes.float0)
+    primal, vjp = jax.vjp(lambda d: replay(d, bound), diff)
 
     cts = {}
     for n in out_names:
         g = gs.get(n)
         p = primal[n]
         if g is None:
-            cts[n] = zero_ct(p)
+            cts[n] = _zero_ct(p)
         else:
             if g.shape != p.shape and tuple(
                     d for d in g.shape if d != 1) == tuple(

@@ -1830,28 +1830,38 @@ def _flash_attention_op(ins, attrs):
     return {"Out": out, "LSE": lse}
 
 
+def _flash_grad_reads_saved(ins, attrs=None):
+    """Whether flash_attention_grad runs on the forward's Out and LSE:
+    both bound and the impl a kernel (OpDef.reads_saved)."""
+    return "Out" in ins and "LSE" in ins and _auto_impl() != "xla"
+
+
 @register_op("flash_attention_grad",
              inputs=("Q", "K", "V", "Out", "LSE", "Out@GRAD"),
              outputs=("Q@GRAD", "K@GRAD", "V@GRAD"),
              optional=("Out", "LSE"), attrs=_FLASH_OP_ATTRS,
-             differentiable=False)
+             differentiable=False, reads_saved=_flash_grad_reads_saved)
 def _flash_attention_grad_op(ins, attrs):
     """Hand-written: XLA does not CSE a duplicated Mosaic custom call,
     so the generic `jax.vjp` grad op ran the forward kernel a second
     time in every layer (12 pt_flash_fwd in a six-layer step; PERF.md,
-    PR 24).  The choice follows from what the op can see:
+    PR 24).  The choice follows from what the op can see
+    (`_flash_grad_reads_saved`):
 
-      * Out and LSE bound (append_backward binds them) and the impl
-        resolves to a kernel: the backward kernel on the saved
-        residuals, under the same shard_map gate as the forward;
+      * Out and LSE bound (append_backward binds them; a recompute
+        segment's backward binds them on the op it replays,
+        ops/misc.py recompute_segment_grad) and the impl resolves to a
+        kernel: the backward kernel on the saved residuals, under the
+        same shard_map gate as the forward;
       * either slot unbound (a program serialized before the slots, a
         hand-built op) or the XLA impl: `jax.vjp` over the forward
         op's compute, as the generic grad op did.
 
     paddle_tpu_kernel_impl_total{kernel="flash_attention_grad"} says
-    which: impl="saved" | "recompute"."""
+    which: impl="saved" | "recompute".  A segment that keeps its
+    replay of the op never calls this and counts neither."""
     q, k, v, g = (ins[s] for s in ("Q", "K", "V", "Out@GRAD"))
-    if "Out" not in ins or "LSE" not in ins or _auto_impl() == "xla":
+    if not _flash_grad_reads_saved(ins):
         _count_impl("flash_attention_grad", "recompute")
         _, vjp = jax.vjp(
             lambda q, k, v: _flash_attention_op(

@@ -59,9 +59,11 @@ def _feed(b=2, h=2, tq=32, tk=32, d=8, seed=0):
             "v": rng.randn(b, h, tk, d).astype(np.float32)}
 
 
-def _attention_net(feed, n_layers=1, **kw):
+def _attention_net(feed, n_layers=1, segmented=False, **kw):
     """n_layers flash_attention ops chained through Q, a scalar loss,
-    and the backward `append_backward` builds.  Returns the program."""
+    and the backward `append_backward` builds; segmented: a scale after
+    every layer, its output a recompute checkpoint.  Returns the
+    program."""
     x = None
     for name, val in feed.items():
         var = layers.data(name, shape=list(val.shape[1:]),
@@ -70,10 +72,14 @@ def _attention_net(feed, n_layers=1, **kw):
         x = var if name == "q" else x
     k, v = (framework.default_main_program().global_block().var(n)
             for n in ("k", "v"))
+    checkpoints = []
     for _ in range(n_layers):
         x = layers.flash_attention(x, k, v, **kw)
+        if segmented:
+            x = layers.scale(x, scale=1.5)
+            checkpoints.append(x)
     loss = layers.mean(layers.square(x))
-    append_backward(loss)
+    append_backward(loss, checkpoints=checkpoints or None)
     return framework.default_main_program()
 
 
@@ -458,3 +464,191 @@ def test_rank3_op_binds_its_residuals_and_matches_rank4(interpret, h, d,
         else:       # delta's sum in another order: a few ulps
             np.testing.assert_allclose(a, w, atol=2e-6 * np.abs(w).max(),
                                        err_msg=name)
+
+
+# -- inside a recompute segment (ISSUE 33) ------------------------------------
+# The segment's backward binds the forward's Out and LSE (`Saved`), its
+# replay takes them for the op's outputs, and the op's backward is the
+# registered grad op: the forward kernel runs once a layer here too.
+
+def _segment_ops(prog):
+    return [op for op in prog.global_block().ops
+            if op.type == "recompute_segment_grad"]
+
+
+def _unbind_segments(prog):
+    """The segment ops as a program from before the `Saved` slot has
+    them.  Returns how many names were bound."""
+    n = 0
+    for op in _segment_ops(prog):
+        n += len(op.inputs.pop("Saved", ()))
+        op.attrs.pop("saved_names", None)
+    return n
+
+
+@pytest.mark.parametrize("n_layers", [1, 3])
+def test_segment_runs_one_forward_kernel_a_layer(interpret, n_layers):
+    feed = _feed()
+    prog = _attention_net(feed, n_layers, segmented=True, causal=True)
+    flash = [op for op in prog.global_block().ops
+             if op.type == "flash_attention"]
+    bound = [op.inputs["Saved"] for op in _segment_ops(prog)
+             if "Saved" in op.inputs]
+    # the segment that holds layer i binds that layer's Out and LSE
+    assert sorted(bound) == sorted(
+        op.outputs["Out"] + op.outputs["LSE"] for op in flash)
+    before = _impl_counts()
+    assert _kernel_calls(prog, feed) == dict.fromkeys(KERNELS, n_layers)
+    assert _since(before) == {
+        ("flash_attention", "interpret"): n_layers,
+        ("flash_attention_grad", "saved"): n_layers,
+        ("flash_attention_bwd", "fused"): n_layers,
+        ("flash_attention_layout", "head_major"): 2 * n_layers}
+    # and what it was before: the replay runs the forward kernel again.
+    # The jaxpr holds it twice a segment, in the primal of the replay's
+    # vjp, which nothing reads and XLA drops, and in the replay the
+    # backward differentiates: 2 a layer in the compiled step
+    assert _unbind_segments(prog) == 2 * n_layers
+    before = _impl_counts()
+    assert _kernel_calls(prog, feed) == {"pt_flash_fwd": 3 * n_layers,
+                                         "pt_flash_bwd_dkv": n_layers}
+    assert ("flash_attention_grad", "saved") not in _since(before)
+
+
+def _projected(x, width, name):
+    from paddle_tpu.param_attr import ParamAttr
+
+    return layers.fc(x, size=width, num_flatten_dims=2, bias_attr=False,
+                     param_attr=ParamAttr(name=name + ".w"))
+
+
+def _mla_layer(x, i, heads=1, d_qk=192, d_v=128):
+    """Head-major attention at latent attention's two head sizes."""
+    def split(t, d):
+        t = layers.reshape(t, [0, 0, heads, d])
+        return layers.transpose(t, [0, 2, 1, 3])
+
+    q, k = (split(_projected(x, heads * d_qk, "l%d_%s" % (i, n)), d_qk)
+            for n in "qk")
+    v = split(_projected(x, heads * d_v, "l%d_v" % i), d_v)
+    out = layers.flash_attention(q, k, v, causal=True, block_q=16,
+                                 block_k=16)
+    out = layers.reshape(layers.transpose(out, [0, 2, 1, 3]),
+                         [0, 0, heads * d_v])
+    return layers.elementwise_add(x, _projected(out, 32, "l%d_o" % i))
+
+
+def _shared_layer(x, i, heads=2, d=64):
+    """Token-major attention; every execution reads ONE set of
+    weights (names without i)."""
+    q, k, v = (_projected(x, heads * d, "shared_" + n) for n in "qkv")
+    out = layers.flash_attention(q, k, v, causal=True, n_head=heads,
+                                 block_q=16, block_k=16)
+    return layers.elementwise_add(x, _projected(out, 32, "shared_o"))
+
+
+def _train_five(layer, n_layers, unbind):
+    """Five SGD steps under RecomputeOptimizer, one segment a layer
+    execution.  Returns (losses, first-step parameter gradients by
+    name, impl counts, segment ops with names bound)."""
+    x = layers.data("x", shape=[32, 32], dtype="float32")
+    h, checkpoints = x, []
+    for i in range(n_layers):
+        h = layer(h, i)
+        checkpoints.append(h)
+    loss = layers.mean(layers.square(h))
+    opt = optimizer.RecomputeOptimizer(optimizer.SGD(0.5))
+    opt._set_checkpoints(checkpoints)
+    _, params_grads = opt.minimize(loss)
+    prog = framework.default_main_program()
+    bound = sum("Saved" in op.inputs for op in _segment_ops(prog))
+    if unbind:
+        _unbind_segments(prog)
+    grads = sorted(g.name for _, g in params_grads)
+    feed = {"x": np.random.RandomState(1).randn(2, 32, 32)
+            .astype(np.float32)}
+    exe = fluid.Executor(fluid.CPUPlace())
+    np.random.seed(3)           # initializers draw from np.random
+    with scope_guard(Scope()):
+        exe.run(framework.default_startup_program())
+        compiled = fluid.CompiledProgram(prog)
+        before = _impl_counts()
+        first = exe.run(compiled, feed=feed, fetch_list=[loss] + grads)
+        used = _since(before)
+        losses = [first[0]] + [
+            exe.run(compiled, feed=feed, fetch_list=[loss])[0]
+            for _ in range(4)]
+    return losses, dict(zip(grads, first[1:])), used, bound
+
+
+@pytest.mark.parametrize("layer,layout,n_grads", [
+    (_mla_layer, "head_major", 8), (_shared_layer, "token_major", 4),
+], ids=["head_major_192_128", "token_major_shared_weight"])
+def test_segment_saved_equals_replay_bit_for_bit(fresh_programs_factory,
+                                                 interpret, layer, layout,
+                                                 n_grads):
+    runs = []
+    for unbind in (False, True):
+        with fresh_programs_factory():
+            runs.append(_train_five(layer, 2, unbind))
+    (losses, grads, used, bound), (losses_r, grads_r, used_r, _) = runs
+    assert bound == 2
+    assert used[("flash_attention_grad", "saved")] == 2
+    assert used[("flash_attention_layout", layout)] == 4
+    assert ("flash_attention_grad", "saved") not in used_r
+    # the replay traced the forward entry once more a segment
+    assert used_r[("flash_attention", "interpret")] \
+        == used[("flash_attention", "interpret")] + 2
+    assert len(grads) == n_grads and set(grads) == set(grads_r)
+    for name in grads:
+        assert np.array_equal(grads[name], grads_r[name]), name
+        assert np.abs(grads[name]).max() > 0, name
+    assert len(losses) == 5 and losses[4] < losses[0]
+    for a, b in zip(losses, losses_r):
+        assert np.array_equal(a, b)
+
+
+def test_xla_impl_keeps_the_replay_in_a_segment():
+    """No kernel, no residual worth saving: the names are bound (the
+    program does not know its impl) and the replay is what it was,
+    trace for trace; the grad op is not called and nothing is counted
+    in its name."""
+    feed = _feed()
+    prog = _attention_net(feed, 2, segmented=True, causal=True)
+    assert sum("Saved" in op.inputs for op in _segment_ops(prog)) == 2
+
+    def step(feeds):
+        env = _TraceEnv()
+        env.update(feeds)
+        _run_block_symbolic(prog, 0, env)
+        return [env[g] for g in GRADS]
+
+    before = _impl_counts()
+    bound_trace = str(jax.make_jaxpr(step)(feed))
+    bound = _run(prog, feed, GRADS)
+    assert not any(k == "flash_attention_grad" for k, _ in _since(before))
+    _unbind_segments(prog)
+    assert str(jax.make_jaxpr(step)(feed)) == bound_trace
+    for a, b in zip(bound, _run(prog, feed, GRADS)):
+        assert np.array_equal(a, b)
+
+
+def test_segment_desc_serialized_without_saved_still_runs(interpret):
+    feed = _feed()
+    prog = _attention_net(feed, 2, segmented=True, causal=True)
+    want = _run(prog, feed, GRADS)
+    desc = json.loads(prog.to_bytes())
+    stripped = 0
+    for op in desc["blocks"][0]["ops"]:
+        if op["type"] == "recompute_segment_grad":
+            stripped += len(op["inputs"].pop("Saved", ()))
+            op["attrs"].pop("saved_names", None)
+    assert stripped == 4
+    old = Program.parse_from_bytes(json.dumps(desc).encode())
+    from paddle_tpu.analysis.verifier import verify
+
+    assert verify(old, feeds=list(feed)) == []
+    before = _impl_counts()
+    for a, b in zip(want, _run(old, feed, GRADS)):
+        assert np.array_equal(a, b)
+    assert ("flash_attention_grad", "saved") not in _since(before)

@@ -139,3 +139,42 @@ def test_recompute_backward_live_set_shrinks(fresh_programs_factory):
     # (l*.tmp_0/tmp_1 pre-activation values) may appear
     assert not any(".tmp_0" in n or ".tmp_1" in n for n in non_ckpt), \
         sorted(non_ckpt)
+
+
+def test_recompute_backward_live_set_grows_by_the_saved_outputs():
+    """What a registered grad op reads of its forward's outputs stays
+    live from forward to backward inside a segment too (ISSUE 33): with
+    an attention layer a segment, the backward consumes the segments'
+    boundary inputs and, beyond them, exactly the flash ops' Out and
+    LSE, bound under `Saved`: one [B, H, Tq, D] and one float32
+    [B, H, Tq] a layer, the price of not running the forward kernel
+    again."""
+    from paddle_tpu.core.program import BACKWARD
+
+    q, k, v = (layers.data(n, shape=[2, 32, 8], dtype="float32")
+               for n in "qkv")
+    q.stop_gradient = False
+    h, ckpts = q, []
+    for _ in range(3):
+        h = layers.tanh(layers.scale(
+            layers.flash_attention(h, k, v, causal=True), scale=2.0))
+        ckpts.append(h)
+    append_backward(layers.mean(layers.square(h)), checkpoints=ckpts)
+    block = fluid.default_main_program().global_block()
+    fwd = [op for op in block.ops if op.op_role != BACKWARD]
+    bwd = [op for op in block.ops if op.op_role == BACKWARD]
+    fwd_act = {n for op in fwd for n in op.output_names()}
+    segments = [op for op in bwd if op.type == "recompute_segment_grad"]
+    boundary = {n for op in segments for n in op.inputs["X"]}
+    consumed = {n for op in bwd for n in op.input_names()} & fwd_act
+    flash_outs = {n for op in fwd if op.type == "flash_attention"
+                  for n in op.outputs["Out"] + op.outputs["LSE"]}
+    assert len(flash_outs) == 6
+    assert consumed - boundary == flash_outs
+    assert {n for op in segments
+            for n in op.inputs.get("Saved", ())} == flash_outs
+    assert all(op.attrs["saved_names"] == op.inputs["Saved"]
+               for op in segments if "Saved" in op.inputs)
+    # the pre-activation of every tanh is still its segment's own
+    assert not any(n in consumed for op in fwd if op.type == "tanh"
+                   for n in op.input_names())

@@ -44,6 +44,43 @@ def test_bench_workload_lowers_for_tpu(chip_gate, workload):
         assert detail["tpu_custom_calls"] == 12
 
 
+@pytest.mark.parametrize("workload,flash_ops", [
+    ("xing4_train_tiny", 5), ("ouro_train_tiny", 24)])
+def test_recompute_step_holds_one_forward_kernel_a_flash_op(
+        chip_gate, workload, flash_ops):
+    """The two cells that train under RecomputeOptimizer, at their
+    depth and head sizes, narrow and short: a segment's backward takes
+    the forward's Out and LSE (ISSUE 33), so the compiled step holds
+    one `pt_flash_fwd` a flash op and not a second in every segment's
+    replay (10 and 48 before).  The gate fails the workload
+    otherwise."""
+    assert workload in chip_gate.ONE_FLASH_FWD_AN_OP
+    ok, detail, _ = chip_gate.check_workload(
+        workload, chip_gate._workloads()[workload])
+    assert ok, detail
+    assert detail["flash_ops"] == flash_ops
+    assert detail["kernel_calls"]["pt_flash_fwd"] == flash_ops
+    assert detail["kernel_calls"]["pt_flash_bwd_dkv"] == flash_ops
+
+
+def test_kernel_calls_counts_mosaic_calls_by_kernel_name():
+    """The reader itself, on text: only Mosaic calls, the compiler's
+    numbering stripped."""
+    from tools.tpu_lowering_check import kernel_calls
+
+    text = """
+ENTRY %main {
+  %pt_flash_fwd = (bf16[1,8]{1,0}, f32[1,8]{1,0}) custom-call(%a), custom_call_target="tpu_custom_call"
+  %pt_flash_fwd.7 = (bf16[1,8]{1,0}, f32[1,8]{1,0}) custom-call(%a), custom_call_target="tpu_custom_call"
+  %pt_flash_bwd_dkv.12.1 = bf16[1,8]{1,0} custom-call(%a), custom_call_target="tpu_custom_call"
+  %cholesky.3 = f32[8,8]{1,0} custom-call(%b), custom_call_target="Cholesky"
+  ROOT %pt_gmm_fwd.2 = bf16[1,8]{1,0} custom-call(%a), custom_call_target="tpu_custom_call"
+}
+"""
+    assert kernel_calls(text) == {"pt_flash_fwd": 2, "pt_flash_bwd_dkv": 1,
+                                  "pt_gmm_fwd": 1}
+
+
 @pytest.mark.parametrize("workload", ["longctx_train_hp2"])
 def test_two_heads_a_step_compile_at_1024_row_blocks(chip_gate,
                                                      workload):

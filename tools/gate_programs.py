@@ -207,13 +207,12 @@ def _build_transformer_train(batch, seq, fused_adam=False, gspmd=False,
     return fn, state, feed, model["loss"].name
 
 
-def _build_ouro_train(batch=1, seq=4096, **sizes):
-    """Build + init the looped decoder's train step as the cell
-    `ouro_2_6b_train_s4k` runs it: the benchmark's own builder
-    (benchmarks/builders/ouro.py `build`) on its configuration
-    (benchmarks/configs/ouro-2.6b.json), so one place says what the
-    cell's step is; `sizes` override the file's keys (the IR tests
-    build it small).  Returns (fn, state, feed, loss_name)."""
+def _build_cell_train(config_file, builder, batch, seq, sizes):
+    """Build + init a benchmark cell's train step through the
+    benchmark's own builder (benchmarks/builders/<builder> `build`) on
+    its configuration (benchmarks/configs/<config_file>), so one place
+    says what the cell's step is; `sizes` override the file's keys.
+    Returns (fn, state, feed, loss_name)."""
     import importlib.util
     import json
     import os
@@ -235,9 +234,9 @@ def _build_ouro_train(batch=1, seq=4096, **sizes):
         return mod
 
     _fresh_programs()
-    with open(os.path.join(bench, "configs", "ouro-2.6b.json")) as f:
+    with open(os.path.join(bench, "configs", config_file)) as f:
         config = dict(json.load(f), **sizes)
-    built = load("builders", "ouro.py").build(
+    built = load("builders", builder).build(
         config, {"batch": batch, "seq_len": seq}, load("flops.py"))
     exe = fluid.Executor(fluid.TPUPlace())
     exe.run(framework.default_startup_program())
@@ -247,6 +246,20 @@ def _build_ouro_train(batch=1, seq=4096, **sizes):
     loss = built["loss"].name
     fn, state = _build_compiled_fn(built["compiled"], feed, [loss])
     return fn, state, feed, loss
+
+
+def _build_ouro_train(batch=1, seq=4096, **sizes):
+    """The looped decoder's train step as the cell
+    `ouro_2_6b_train_s4k` runs it (the IR tests build it small)."""
+    return _build_cell_train("ouro-2.6b.json", "ouro.py", batch, seq,
+                             sizes)
+
+
+def _build_xing4_train(batch=1, seq=4096, **sizes):
+    """The 2024-26 decoder block's train step as the cell
+    `xing4_29b_train_s4k` runs it."""
+    return _build_cell_train("xing4.0-29b-a4b.json", "xing4.py", batch,
+                             seq, sizes)
 
 
 # Devlin et al. 2018, BERT-base

@@ -38,6 +38,7 @@ chip's own compiler, asked ahead of the chip.
 from __future__ import annotations
 
 import argparse
+import collections
 import contextlib
 import functools
 import json
@@ -121,6 +122,23 @@ def _workloads():
         # step fits before chip time is spent, and token-major flash at
         # head size 128 has no other program here
         "ouro_train": lambda: progs._build_ouro_train(1, 4096)[:3],
+        # the 2024-26 decoder block at the cell's sizes: flash at head
+        # sizes 192 / 128 head-major, the grouped matmuls, a recompute
+        # segment a layer
+        "xing4_train": lambda: progs._build_xing4_train(1, 4096)[:3],
+        # both at the cells' depth and head sizes, narrow and short
+        # (256 tokens; seconds to compile): what is checked is how many
+        # kernels the step holds, not whether it fits
+        # (ONE_FLASH_FWD_AN_OP)
+        "ouro_train_tiny": lambda: progs._build_ouro_train(
+            1, 256, hidden_size=256, num_attention_heads=2,
+            head_dim=128, num_key_value_heads=2, intermediate_size=512,
+            vocab_size=512)[:3],
+        "xing4_train_tiny": lambda: progs._build_xing4_train(
+            1, 256, hidden_size=256, num_attention_heads=2,
+            q_lora_rank=64, kv_lora_rank=64, intermediate_size=512,
+            moe_intermediate_size=128, n_routed_experts=2,
+            vocab_size=512)[:3],
         "resnet50_train": lambda: progs._build_resnet50_train(128)[:3],
         "resnet50_train_s2d": lambda: progs._build_resnet50_train(
             128, s2d=True)[:3],
@@ -306,7 +324,8 @@ def _infer(progs, which, batch, conv_epilogue=False):
                               conv_epilogue=conv_epilogue)[:3]
 
 
-FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train")
+FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train",
+             "xing4_train")
 
 # the steps whose attention takes q, k and v token-major, [B, T, H*d]
 # as the projections leave them: their compiled step may hold no head
@@ -327,12 +346,33 @@ def head_layout_copies(hlo_text):
         r" = (?:bf16|f16|f32)\[\d+(?:,\d+){3}\]\S* copy\(", entry))
 
 
+# the training steps whose every flash_attention op has a grad that
+# reads the forward's Out and LSE, as a grad op of its own or inside a
+# recompute segment: the forward kernel runs once an op
+ONE_FLASH_FWD_AN_OP = ("transformer_train", "transformer_train_gspmd",
+                       "ouro_train", "ouro_train_tiny", "xing4_train",
+                       "xing4_train_tiny")
+
+
+def kernel_calls(hlo_text):
+    """{kernel name: Mosaic calls} of a compiled module.  The TPU
+    compiler names a call after the kernel (`pl.pallas_call(name=)`,
+    PERF.md section 3) and numbers the copies (`pt_flash_fwd.7`)."""
+    return dict(collections.Counter(re.findall(
+        r"^\s*(?:ROOT )?%?([\w-]+?)(?:\.\d+)* = [^\n]*"
+        r'custom_call_target="tpu_custom_call"', hlo_text, re.M)))
+
+
 def check_workload(name, build):
     """Build the gate program and compile its jitted step for the
     described chip.  Returns (ok, detail, seconds); detail of a compile
-    that passed is its memory_analysis() and kernel count, and for the
+    that passed is its memory_analysis() and kernel count, for the
     NO_HEAD_LAYOUT_COPIES steps `head_layout_copies`, which fails the
-    workload unless it is 0."""
+    workload unless it is 0, and for the ONE_FLASH_FWD_AN_OP steps
+    `kernel_calls` and the program's `flash_ops`, which fail it unless
+    `pt_flash_fwd` is called once an op (a recompute segment that
+    replays the op holds a second call: 10 for 5 in `xing4`, 48 for 24
+    in `ouro` before PR 33)."""
     t0 = time.time()
     # Force the Pallas path during tracing: impl auto-detection sees a
     # CPU device in this process, but the program we must validate is
@@ -361,11 +401,20 @@ def check_workload(name, build):
             **{k: getattr(mem, k + "_size_in_bytes") for k in (
                 "temp", "argument", "output", "alias",
                 "generated_code")}}
+        ok = True
         if name in NO_HEAD_LAYOUT_COPIES:
             detail["head_layout_copies"] = head_layout_copies(text)
-            if detail["head_layout_copies"]:
-                return False, detail, time.time() - t0
-        return True, detail, time.time() - t0
+            ok &= not detail["head_layout_copies"]
+        if name in ONE_FLASH_FWD_AN_OP:
+            from paddle_tpu import framework
+
+            detail["flash_ops"] = sum(
+                op.type == "flash_attention" for op in
+                framework.default_main_program().global_block().ops)
+            detail["kernel_calls"] = kernel_calls(text)
+            ok &= detail["kernel_calls"].get("pt_flash_fwd") \
+                == detail["flash_ops"] > 0
+        return ok, detail, time.time() - t0
     except Exception as e:  # noqa: BLE001 — report, don't crash the sweep
         msg = "%s: %s" % (type(e).__name__, str(e)[:400])
         return False, msg, time.time() - t0
