@@ -1,8 +1,9 @@
 """Layer functions of the 2024-26 decoder block (ops/llm_ops.py):
-rms_norm, rotary_embedding, swiglu, moe_route, moe_experts and the two
-halves of a manifold-constrained hyper-connection, mhc_pre / mhc_post.
-docs/XING4_BLOCK.md has the equations; models/xing4.py builds a model
-from them."""
+rms_norm, rotary_embedding, swiglu, moe_route, moe_experts, the two
+halves of a manifold-constrained hyper-connection, mhc_pre / mhc_post,
+and the sequence-wise expert-balance loss, a composition of ops the IR
+has.  docs/XING4_BLOCK.md and docs/DSV2_BLOCK.md have the equations;
+models/xing4.py and models/deepseek_v2.py build models from them."""
 
 from __future__ import annotations
 
@@ -11,7 +12,7 @@ import numpy as np
 from paddle_tpu.layers.helper import LayerHelper
 
 __all__ = ["rms_norm", "rotary_embedding", "swiglu", "moe_route",
-           "moe_experts", "mhc_pre", "mhc_post"]
+           "moe_balance_loss", "moe_experts", "mhc_pre", "mhc_post"]
 
 
 def _named(attr, name, part):
@@ -76,31 +77,77 @@ def swiglu(gate, up, name=None):
 
 def moe_route(input, n_experts, k, routed_scaling_factor=1.0,
               norm_topk_prob=True, param_attr=None, bias_attr=None,
-              name=None):
-    """Sigmoid top-k router over ALL n_experts (DeepSeek-V3 2.1.2,
-    `noaux_tc` without a group limit): returns (topk_idx int32,
-    topk_weight float32), both [.., k].  The selection bias
-    (`<name>_bias.w`, zeros) is persistable and gets no gradient: it
-    selects and does not weigh."""
+              name=None, scoring_func="sigmoid", return_scores=False):
+    """Top-k router over ALL n_experts (n_group = topk_group = 1):
+    returns (topk_idx int32, topk_weight float32), both [.., k], and
+    with return_scores the float32 scores [.., n_experts] as a third,
+    which carry a gradient to every expert (a balance loss reads
+    them).  `scoring_func` chooses the scores and the selection:
+
+    * "sigmoid" (DeepSeek-V3 2.1.2, `noaux_tc`): s = sigmoid(x W); the
+      k largest s + bias are selected.  The selection bias
+      (`<name>_bias.w`, zeros) is persistable and gets no gradient: it
+      selects and does not weigh.
+    * "softmax" (DeepSeek-V2 2.2, `greedy`): s = softmax(x W) over the
+      experts; the k largest s are selected.  No bias parameter is
+      made.
+
+    The gates are routed_scaling_factor * s_e, over the sum of the
+    selected s first with norm_topk_prob."""
     from paddle_tpu.initializer import Constant
 
     helper = LayerHelper("moe_route", name=name)
     c = int(input.shape[-1])
-    w = helper.create_parameter(_named(param_attr, name, ""),
-                                [c, n_experts], "float32")
-    battr = _named(bias_attr, name, "bias")
-    battr.trainable = False
-    bias = helper.create_parameter(battr, [n_experts], "float32",
-                                   default_initializer=Constant(0.0))
+    inputs = {"X": input,
+              "W": helper.create_parameter(_named(param_attr, name, ""),
+                                           [c, n_experts], "float32")}
+    if scoring_func == "sigmoid":
+        battr = _named(bias_attr, name, "bias")
+        battr.trainable = False
+        inputs["Bias"] = helper.create_parameter(
+            battr, [n_experts], "float32",
+            default_initializer=Constant(0.0))
     idx = helper.create_variable_for_type_inference("int32", True)
     weight = helper.create_variable_for_type_inference("float32")
+    scores = helper.create_variable_for_type_inference("float32")
     helper.append_op(
-        type="moe_route", inputs={"X": input, "W": w, "Bias": bias},
-        outputs={"TopkIdx": idx, "TopkWeight": weight},
+        type="moe_route", inputs=inputs,
+        outputs={"TopkIdx": idx, "TopkWeight": weight, "Scores": scores},
         attrs={"k": int(k),
                "routed_scaling_factor": float(routed_scaling_factor),
-               "norm_topk_prob": bool(norm_topk_prob)})
-    return idx, weight
+               "norm_topk_prob": bool(norm_topk_prob),
+               "scoring_func": str(scoring_func)})
+    return (idx, weight, scores) if return_scores else (idx, weight)
+
+
+def moe_balance_loss(topk_idx, scores, alpha, name=None):
+    """Sequence-wise expert-balance loss of a top-k router (DeepSeek-V2,
+    arXiv:2405.04434, 2.2.3 eq. 23-25; `seq_aux`): topk_idx [B, T, k],
+    scores [B, T, E] float32 (`moe_route(..., return_scores=True)`) ->
+    a float32 [1]:
+
+        f[b, i] = E / (k T) * #{(t, j): topk_idx[b, t, j] == i}
+        P[b, i] = mean over t of scores[b, t, i]
+        loss    = alpha * mean over b of sum over i of f[b, i] P[b, i]
+
+    f is a count and gets no gradient; the gradient reaches the router
+    through ALL E scores of every token.  A router that spreads a
+    sequence's tokens evenly gives alpha.  Float32 under AMP (the
+    scores are, one_hot and the reductions stay so), under
+    name_scope("pt_moe_balance")."""
+    from paddle_tpu.framework import name_scope
+    from paddle_tpu.layers import nn
+
+    t, k = int(topk_idx.shape[-2]), int(topk_idx.shape[-1])
+    e = int(scores.shape[-1])
+    with name_scope("pt_moe_balance"):
+        # [B, T k, 1] -> [B, T k, E]: one_hot drops a last axis of 1
+        chosen = nn.one_hot(nn.reshape(topk_idx, [-1, t * k, 1]), e)
+        f = nn.scale(nn.reduce_sum(chosen, dim=1), scale=e / (k * t))
+        f.stop_gradient = True
+        p = nn.reduce_mean(scores, dim=1)
+        per_seq = nn.reduce_sum(nn.elementwise_mul(f, p), dim=1)
+        return nn.scale(nn.mean(per_seq), scale=float(alpha))
 
 
 def moe_experts(input, topk_idx, topk_weight, held, width,

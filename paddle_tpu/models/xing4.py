@@ -25,36 +25,11 @@ As a Fluid trainer uses it:
 
 from __future__ import annotations
 
-import math
-
 from paddle_tpu import layers
 from paddle_tpu.initializer import Normal
+from paddle_tpu.models.latent_attention import (
+    held_experts, latent_attention, router_width)
 from paddle_tpu.param_attr import ParamAttr
-
-
-def attention_scale(config):
-    """Softmax scale of latent attention: (qk_nope + qk_rope)^-1/2 times
-    m^2, m = 0.1 mscale_all_dim ln(factor) + 1 under YaRN."""
-    d = config["qk_nope_head_dim"] + config["qk_rope_head_dim"]
-    rs = config.get("rope_scaling") or {}
-    m = 1.0
-    if rs.get("factor", 1) > 1 and rs.get("mscale_all_dim"):
-        m = 0.1 * rs["mscale_all_dim"] * math.log(rs["factor"]) + 1.0
-    return d ** -0.5 * m * m
-
-
-def held_experts(config):
-    """The expert ids this chip holds: `held_experts` if the config
-    lists them, else the first `n_routed_experts`."""
-    return list(config.get("held_experts")
-                or range(config["n_routed_experts"]))
-
-
-def router_width(config):
-    """Experts the router scores: the published count when this chip
-    holds only its share of them."""
-    return config.get("n_routed_experts_published",
-                      config["n_routed_experts"])
 
 
 def xing4_model(config, seq_len, param_prefix="xing"):
@@ -64,11 +39,7 @@ def xing4_model(config, seq_len, param_prefix="xing"):
     and `checkpoints`: the stream state after each layer, for
     RecomputeOptimizer._set_checkpoints."""
     c, n = config["hidden_size"], config["hc_mult"]
-    heads = config["num_attention_heads"]
-    nope, rope = config["qk_nope_head_dim"], config["qk_rope_head_dim"]
-    vd, kvr = config["v_head_dim"], config["kv_lora_rank"]
     eps = config["rms_norm_eps"]
-    rs = config.get("rope_scaling") or {}
     init = Normal(0.0, config.get("initializer_range", 0.02), fast=True)
     p = param_prefix
 
@@ -79,42 +50,8 @@ def xing4_model(config, seq_len, param_prefix="xing"):
         return layers.fc(x, size, num_flatten_dims=2, bias_attr=False,
                          param_attr=w(name))
 
-    def rotary(x):
-        mscale = 1.0
-        if rs.get("mscale") and rs.get("mscale_all_dim"):
-            mscale = rs["mscale"] / rs["mscale_all_dim"]
-        return layers.rotary_embedding(
-            x, rotary_dim=rope, theta=config["rope_theta"],
-            factor=rs.get("factor", 1.0),
-            original_max_position=rs.get(
-                "original_max_position_embeddings",
-                config.get("max_position_embeddings", seq_len)),
-            beta_fast=rs.get("beta_fast", 32),
-            beta_slow=rs.get("beta_slow", 1), mscale=mscale)
-
     def attention(u, lp):
-        cq = layers.rms_norm(fc(u, config["q_lora_rank"], lp + "_q_a"),
-                             eps, name="%s_%s_q_a_norm" % (p, lp))
-        q = layers.reshape(fc(cq, heads * (nope + rope), lp + "_q_b"),
-                           [-1, seq_len, heads, nope + rope])
-        q = layers.transpose(rotary(q), [0, 2, 1, 3])
-        ckv, k_r = layers.split(fc(u, kvr + rope, lp + "_kv_a"),
-                                [kvr, rope], dim=2)
-        ckv = layers.rms_norm(ckv, eps, name="%s_%s_kv_a_norm" % (p, lp))
-        kv = layers.reshape(fc(ckv, heads * (nope + vd), lp + "_kv_b"),
-                            [-1, seq_len, heads, nope + vd])
-        k_nope, v = layers.split(kv, [nope, vd], dim=3)
-        # one rotary key a token, shared by every head
-        k_r = rotary(layers.reshape(k_r, [-1, seq_len, 1, rope]))
-        k = layers.concat([k_nope, layers.expand(k_r, [1, 1, heads, 1])],
-                          axis=3)
-        out = layers.flash_attention(
-            q, layers.transpose(k, [0, 2, 1, 3]),
-            layers.transpose(v, [0, 2, 1, 3]), causal=True,
-            scale=attention_scale(config))
-        out = layers.reshape(layers.transpose(out, [0, 2, 1, 3]),
-                             [-1, seq_len, heads * vd])
-        return fc(out, c, lp + "_o")
+        return latent_attention(u, config, seq_len, fc, p, lp)
 
     def swiglu_ffn(u, width, lp):
         act = layers.swiglu(fc(u, width, lp + "_gate"),

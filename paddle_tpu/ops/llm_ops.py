@@ -1,13 +1,16 @@
 """Ops of the 2024-26 decoder block: RMSNorm, rotary embedding with YaRN
-frequencies, SwiGLU, the sigmoid top-k router with a selection bias, the
-grouped expert feed-forward over the experts this chip holds, and the
-two halves of a manifold-constrained hyper-connection (n residual
-streams mixed by a Sinkhorn-normalised matrix).
+frequencies, SwiGLU, the top-k router (sigmoid scores with a selection
+bias, or softmax scores), the grouped expert feed-forward over the
+experts this chip holds, and the two halves of a manifold-constrained
+hyper-connection (n residual streams mixed by a Sinkhorn-normalised
+matrix).
 
-Equations: docs/XING4_BLOCK.md.  Attention, routing and experts follow
-the DeepSeek-V3 report (arXiv:2412.19437, 2.1.1-2.1.2), the residual
-path mHC (arXiv:2512.24880) over Hyper-Connections (arXiv:2409.19606).
-models/xing4.py builds the block from these through layers/llm.py.
+Equations: docs/XING4_BLOCK.md and docs/DSV2_BLOCK.md.  Attention,
+routing and experts follow the DeepSeek-V2 and -V3 reports
+(arXiv:2405.04434, 2.1-2.2; arXiv:2412.19437, 2.1.1-2.1.2), the
+residual path mHC (arXiv:2512.24880) over Hyper-Connections
+(arXiv:2409.19606).  models/xing4.py and models/deepseek_v2.py build
+their blocks from these through layers/llm.py.
 
 Precision under AMP (contrib/mixed_precision): statistics, router
 scores, mixing coefficients and the Sinkhorn iterations are computed in
@@ -160,27 +163,45 @@ def rotary_embedding(ins, attrs):
 # ---------------------------------------------------------------------------
 
 @register_op("moe_route", inputs=("X", "W", "Bias"),
-             outputs=("TopkIdx", "TopkWeight"),
+             outputs=("TopkIdx", "TopkWeight", "Scores"),
              attrs={"k": REQUIRED, "routed_scaling_factor": 1.0,
-                    "norm_topk_prob": True})
+                    "norm_topk_prob": True, "scoring_func": "sigmoid"},
+             optional=("Bias",))
 def moe_route(ins, attrs):
-    """Sigmoid scores over ALL experts, float32: s = sigmoid(X W).  The
-    k experts with the largest s + Bias are selected (the bias selects,
-    it does not weigh), their gates are routed_scaling_factor * s_e /
-    sum of the selected s (norm_topk_prob) or routed_scaling_factor *
-    s_e.  X [.., C], W [C, E], Bias [E] -> TopkIdx int32, TopkWeight
-    float32, both [.., k].  No group limit (n_group = topk_group = 1),
-    no capacity: nothing is dropped here."""
+    """Scores over ALL experts, float32, by `scoring_func`: "sigmoid"
+    (DeepSeek-V3 2.1.2) s = sigmoid(X W), "softmax" (DeepSeek-V2 2.2)
+    s = softmax(X W) over the experts.  The k experts with the largest
+    s + Bias are selected (the bias selects, it does not weigh; unbound
+    it is zero, and softmax routing does not read it), their gates are
+    routed_scaling_factor * s_e / sum of the selected s
+    (norm_topk_prob) or routed_scaling_factor * s_e.  X [.., C], W
+    [C, E], Bias [E] -> TopkIdx int32, TopkWeight float32, both
+    [.., k], and Scores = s, float32 [.., E]: what a balance loss
+    reads, with a gradient to every expert's score.  No group limit
+    (n_group = topk_group = 1), no capacity: nothing is dropped here."""
     x, w = ins["X"], ins["W"]
+    scoring = attrs["scoring_func"]
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError("moe_route: scoring_func %r is neither "
+                         "'sigmoid' nor 'softmax'" % (scoring,))
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    pk._count_impl("moe_route_scoring", scoring)
     with jax.named_scope("pt_moe_route"):
-        s = jax.nn.sigmoid(jnp.matmul(x.astype(_F32), w.astype(_F32),
-                                      precision=_HIGHEST))
-        _, idx = lax.top_k(s + ins["Bias"].astype(_F32), attrs["k"])
+        z = jnp.matmul(x.astype(_F32), w.astype(_F32), precision=_HIGHEST)
+        if scoring == "softmax":
+            s = ranked = jax.nn.softmax(z, axis=-1)
+        else:
+            s = ranked = jax.nn.sigmoid(z)
+            if ins.get("Bias") is not None:
+                ranked = s + ins["Bias"].astype(_F32)
+        _, idx = lax.top_k(ranked, attrs["k"])
         sel = jnp.take_along_axis(s, idx, axis=-1)
         if attrs["norm_topk_prob"]:
             sel = sel / jnp.sum(sel, axis=-1, keepdims=True)
         return {"TopkIdx": idx.astype(jnp.int32),
-                "TopkWeight": sel * attrs["routed_scaling_factor"]}
+                "TopkWeight": sel * attrs["routed_scaling_factor"],
+                "Scores": s}
 
 
 # ---------------------------------------------------------------------------

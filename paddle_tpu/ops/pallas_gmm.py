@@ -39,12 +39,21 @@ from jax.experimental.pallas import tpu as pltpu
 def _tile(dim, target):
     """Largest multiple of 128 that divides `dim` and is <= target, else
     the whole dimension (a block equal to the array's extent is legal
-    whatever its size)."""
+    whatever its size).  A width with no larger divisor gets narrow
+    blocks: 1,408 = 11 x 128 with 11 prime yields 128, where 1,024
+    yields 1,024 (target 1024) and 512 (target 512); nothing pads a
+    width to a friendlier one."""
     best = None
     for t in range(128, min(dim, target) + 1, 128):
         if dim % t == 0:
             best = t
     return best or dim
+
+
+def _tiles(k, n):
+    """(tn, tk): the block along the output width n and along the
+    contraction (gmm) or the other output axis (tgmm) k."""
+    return _tile(n, 1024), _tile(k, 512)
 
 
 def _gmm_kernel(tg_ref, na_ref, x_ref, w_ref, o_ref, acc_ref, *,
@@ -111,7 +120,7 @@ def gmm_pallas(lhs, rhs, tile_group, n_active, tm, transpose_rhs=False,
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     n_tiles = m // tm
-    tn, tk = _tile(n, 1024), _tile(k, 512)
+    tn, tk = _tiles(k, n)
 
     def tile(i, na):
         return jnp.minimum(i, na[0] - 1)
@@ -159,7 +168,7 @@ def tgmm_pallas(lhs, grad, tile_group, n_active, tm, n_groups,
     m, k = lhs.shape
     n = grad.shape[1]
     n_tiles = m // tm
-    tk, tn = _tile(k, 512), _tile(n, 1024)
+    tn, tk = _tiles(k, n)
 
     def tile(i, na):
         return jnp.minimum(i, na[0] - 1)
@@ -215,9 +224,19 @@ def tgmm_xla(lhs, grad, tile_group, n_active, tm, n_groups):
                       ).astype(lhs.dtype)
 
 
+def _count_tiles(k, n):
+    """paddle_tpu_kernel_impl_total{kernel="moe_gmm_tile",
+    impl="<tn>x<tk>"}, once a grouped-matmul call that runs a kernel: a
+    step's counters say which block shapes it ran."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    pk._count_impl("moe_gmm_tile", "%dx%d" % _tiles(k, n))
+
+
 def gmm(lhs, rhs, tile_group, n_active, tm, impl, transpose_rhs=False):
     if impl == "xla":
         return gmm_xla(lhs, rhs, tile_group, n_active, tm, transpose_rhs)
+    _count_tiles(lhs.shape[1], rhs.shape[1 if transpose_rhs else 2])
     return gmm_pallas(lhs, rhs, tile_group, n_active, tm,
                       transpose_rhs=transpose_rhs,
                       interpret=impl == "interpret")
@@ -226,5 +245,6 @@ def gmm(lhs, rhs, tile_group, n_active, tm, impl, transpose_rhs=False):
 def tgmm(lhs, grad, tile_group, n_active, tm, n_groups, impl):
     if impl == "xla":
         return tgmm_xla(lhs, grad, tile_group, n_active, tm, n_groups)
+    _count_tiles(lhs.shape[1], grad.shape[1])
     return tgmm_pallas(lhs, grad, tile_group, n_active, tm, n_groups,
                        interpret=impl == "interpret")

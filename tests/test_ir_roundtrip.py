@@ -53,6 +53,14 @@ _BUILDERS = {
         2, 32, hidden_size=64, num_attention_heads=4, head_dim=16,
         num_key_value_heads=4, intermediate_size=160, vocab_size=256,
         num_hidden_layers=2),
+    # a balance loss under name_scope that crosses recompute segments,
+    # moe_route's optional Bias input left unbound
+    "dsv2_train": lambda b: b._build_dsv2_train(
+        2, 32, hidden_size=64, num_attention_heads=4, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        intermediate_size=160, moe_intermediate_size=32,
+        n_routed_experts=4, held_experts=[0, 1, 2, 3], vocab_size=256,
+        num_hidden_layers=3),
     "bert_train": lambda b: b._build_bert_train(1, 128),
     "longctx_train": lambda b: b._build_longctx_train(1, 2, 512, 64),
     "serving_tp_sharded": lambda b: b._build_serving_tp_sharded(tp=2),

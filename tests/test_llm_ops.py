@@ -180,6 +180,94 @@ def test_router_scores_ignore_the_activation_dtype():
 
 # -- the held experts ----------------------------------------------------------
 
+def test_softmax_router_by_hand():
+    """scoring_func "softmax" (DeepSeek-V2): logits ln 1, ln 2, ln 3,
+    ln 4 give scores 0.1, 0.2, 0.3, 0.4.  The two largest are taken as
+    they are: the gates sum to 0.7, not to 1, times the scaling
+    factor; `Scores` holds all four and sums to 1; no bias is read."""
+    x = jnp.asarray([[1.0, 0.0], [0.0, 1.0]], jnp.float32)
+    w = jnp.log(jnp.asarray([[1.0, 2.0, 3.0, 4.0],
+                             [4.0, 1.0, 1.0, 4.0]], jnp.float32))
+    out = _op("moe_route", {"X": x, "W": w}, k=2, norm_topk_prob=False,
+              routed_scaling_factor=1.0, scoring_func="softmax")
+    np.testing.assert_allclose(out["Scores"],
+                               [[0.1, 0.2, 0.3, 0.4], [0.4, 0.1, 0.1, 0.4]],
+                               rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(out["Scores"]).sum(-1), 1.0,
+                               rtol=1e-6)
+    np.testing.assert_array_equal(np.sort(out["TopkIdx"], -1),
+                                  [[2, 3], [0, 3]])
+    np.testing.assert_allclose(np.sort(out["TopkWeight"], -1),
+                               [[0.3, 0.4], [0.4, 0.4]], rtol=1e-6)
+    assert float(np.asarray(out["TopkWeight"]).sum(-1).max()) < 1.0
+    assert out["Scores"].dtype == jnp.float32
+    # scaled, and renormalised only where the config asks for it
+    scaled = _op("moe_route", {"X": x, "W": w}, k=2, norm_topk_prob=False,
+                 routed_scaling_factor=2.5, scoring_func="softmax")
+    np.testing.assert_allclose(np.sort(scaled["TopkWeight"], -1),
+                               [[0.75, 1.0], [1.0, 1.0]], rtol=1e-6)
+    normed = _op("moe_route", {"X": x, "W": w}, k=2, norm_topk_prob=True,
+                 scoring_func="softmax")
+    np.testing.assert_allclose(np.asarray(normed["TopkWeight"]).sum(-1),
+                               1.0, rtol=1e-6)
+    # a bias, if one is bound, selects nothing under softmax
+    biased = _op("moe_route",
+                 {"X": x, "W": w, "Bias": jnp.asarray([9.0, 0, 0, 0])},
+                 k=2, norm_topk_prob=False, scoring_func="softmax")
+    np.testing.assert_array_equal(biased["TopkIdx"], out["TopkIdx"])
+    with pytest.raises(ValueError, match="scoring_func"):
+        _op("moe_route", {"X": x, "W": w}, k=2, scoring_func="tanh")
+
+
+def test_sigmoid_router_scores_are_the_sigmoids():
+    """The default scoring keeps its selection and gates and gains the
+    `Scores` output: the sigmoids of all experts, which do not sum to
+    1."""
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.normal(0, 1, (16, 8)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 0.5, (8, 6)), jnp.float32)
+    out = _op("moe_route", {"X": x, "W": w, "Bias": jnp.zeros(6)}, k=2)
+    s = 1 / (1 + np.exp(-np.asarray(x, np.float64) @ np.asarray(w)))
+    np.testing.assert_allclose(out["Scores"], s, rtol=F32_TOL)
+    idx, wt = _route(x, w, jnp.zeros(6), k=2)
+    np.testing.assert_array_equal(idx, out["TopkIdx"])
+    np.testing.assert_array_equal(wt, out["TopkWeight"])
+
+
+def test_softmax_router_gradient_reaches_unselected_experts_by_scores():
+    """`TopkWeight` carries the scores of the k selected experts and
+    nothing of the others: its gradient is that of the selected scores
+    summed.  The scores of the experts a token did NOT select reach a
+    loss only through `Scores`: a cotangent on those entries alone
+    gives the gradient of the unselected scores summed.  Both against
+    jax.grad of the softmax written out."""
+    rng = np.random.default_rng(9)
+    x = jnp.asarray(rng.normal(0, 1, (12, 8)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 0.5, (8, 6)), jnp.float32)
+
+    def route(w):
+        return _op("moe_route", {"X": x, "W": w}, k=2,
+                   norm_topk_prob=False, scoring_func="softmax")
+
+    chosen = np.zeros((12, 6), bool)
+    np.put_along_axis(chosen, np.asarray(route(w)["TopkIdx"]), True, -1)
+    rest = jnp.asarray(~chosen, jnp.float32)
+
+    def by_hand(w, weights):
+        return jnp.sum(jax.nn.softmax(x @ w, -1) * weights)
+
+    with jax.default_matmul_precision("highest"):
+        g_gates = jax.grad(lambda w: jnp.sum(route(w)["TopkWeight"]))(w)
+        want_gates = jax.grad(by_hand)(w, 1.0 - rest)
+        g_rest = jax.grad(lambda w: jnp.sum(route(w)["Scores"] * rest))(w)
+        want_rest = jax.grad(by_hand)(w, rest)
+    np.testing.assert_allclose(g_gates, want_gates, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(g_rest, want_rest, rtol=1e-4, atol=1e-6)
+    # the scores sum to 1, so the two parts cancel: neither is zero
+    np.testing.assert_allclose(g_gates + g_rest, 0.0, atol=1e-5)
+    assert float(jnp.abs(g_rest).max()) > 1e-2
+
+
 def _experts_case(seed, n=96, c=128, w=256, e=8, k=2, held=(2, 5, 7),
                   empty=None, dtype=jnp.float32):
     rng = np.random.default_rng(seed)

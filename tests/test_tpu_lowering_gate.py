@@ -45,10 +45,11 @@ def test_bench_workload_lowers_for_tpu(chip_gate, workload):
 
 
 @pytest.mark.parametrize("workload,flash_ops", [
-    ("xing4_train_tiny", 5), ("ouro_train_tiny", 24)])
+    ("xing4_train_tiny", 5), ("ouro_train_tiny", 24),
+    ("dsv2_train_tiny", 5)])
 def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         chip_gate, workload, flash_ops):
-    """The two cells that train under RecomputeOptimizer, at their
+    """The three cells that train under RecomputeOptimizer, at their
     depth and head sizes, narrow and short: a segment's backward takes
     the forward's Out and LSE (ISSUE 33), so the compiled step holds
     one `pt_flash_fwd` a flash op and not a second in every segment's
@@ -61,6 +62,12 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
     assert detail["flash_ops"] == flash_ops
     assert detail["kernel_calls"]["pt_flash_fwd"] == flash_ops
     assert detail["kernel_calls"]["pt_flash_bwd_dkv"] == flash_ops
+    if workload == "dsv2_train_tiny":
+        # four expert layers at the published expert width, 1,408 =
+        # 11 x 128: the grouped matmuls compile with 128-wide blocks
+        # (three forward and their replay, three and three backward)
+        assert [detail["kernel_calls"][k] for k in (
+            "pt_gmm_fwd", "pt_gmm_bwd_dx", "pt_gmm_bwd_dw")] == [24, 12, 12]
 
 
 def test_kernel_calls_counts_mosaic_calls_by_kernel_name():

@@ -255,6 +255,13 @@ def _build_ouro_train(batch=1, seq=4096, **sizes):
                              sizes)
 
 
+def _build_dsv2_train(batch=2, seq=4096, **sizes):
+    """The DeepSeek-V2 block's train step as the cell
+    `dsv2_lite_train_s4k` runs it (the IR tests build it small)."""
+    return _build_cell_train("deepseek-v2-lite.json", "deepseek_v2.py",
+                             batch, seq, sizes)
+
+
 def _build_xing4_train(batch=1, seq=4096, **sizes):
     """The 2024-26 decoder block's train step as the cell
     `xing4_29b_train_s4k` runs it."""

@@ -126,6 +126,11 @@ def _workloads():
         # sizes 192 / 128 head-major, the grouped matmuls, a recompute
         # segment a layer
         "xing4_train": lambda: progs._build_xing4_train(1, 4096)[:3],
+        # the DeepSeek-V2 block at the cell's sizes (2 x 4,096 tokens):
+        # the grouped matmuls at an expert width of 1,408 = 11 x 128
+        # (128-wide blocks) and 768 rows an expert, the softmax router
+        # and its balance loss crossing recompute segments
+        "dsv2_train": lambda: progs._build_dsv2_train(2, 4096)[:3],
         # both at the cells' depth and head sizes, narrow and short
         # (256 tokens; seconds to compile): what is checked is how many
         # kernels the step holds, not whether it fits
@@ -139,6 +144,12 @@ def _workloads():
             q_lora_rank=64, kv_lora_rank=64, intermediate_size=512,
             moe_intermediate_size=128, n_routed_experts=2,
             vocab_size=512)[:3],
+        # the published expert width (1,408: 128-wide blocks through
+        # Mosaic) and head sizes, everything else narrow and short
+        "dsv2_train_tiny": lambda: progs._build_dsv2_train(
+            2, 256, hidden_size=256, num_attention_heads=2,
+            kv_lora_rank=64, intermediate_size=512, n_routed_experts=2,
+            held_experts=[0, 1], vocab_size=512)[:3],
         "resnet50_train": lambda: progs._build_resnet50_train(128)[:3],
         "resnet50_train_s2d": lambda: progs._build_resnet50_train(
             128, s2d=True)[:3],
@@ -325,7 +336,7 @@ def _infer(progs, which, batch, conv_epilogue=False):
 
 
 FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train",
-             "xing4_train")
+             "xing4_train", "dsv2_train")
 
 # the steps whose attention takes q, k and v token-major, [B, T, H*d]
 # as the projections leave them: their compiled step may hold no head
@@ -351,7 +362,8 @@ def head_layout_copies(hlo_text):
 # recompute segment: the forward kernel runs once an op
 ONE_FLASH_FWD_AN_OP = ("transformer_train", "transformer_train_gspmd",
                        "ouro_train", "ouro_train_tiny", "xing4_train",
-                       "xing4_train_tiny")
+                       "xing4_train_tiny", "dsv2_train",
+                       "dsv2_train_tiny")
 
 
 def kernel_calls(hlo_text):
