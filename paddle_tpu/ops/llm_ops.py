@@ -343,8 +343,10 @@ def moe_experts(ins, attrs):
     experts.  A selected expert that is not held adds nothing, and no
     token is dropped: the token-expert pairs routed to held experts are
     sorted by expert and go through three grouped matmuls
-    (ops/pallas_gmm.py) with a static worst-case number of rows and
-    run-time group sizes.  impl: "" (pallas on a TPU, xla elsewhere),
+    (ops/pallas_gmm.py) over row arrays of a static worst-case size
+    with run-time group sizes; a kernel's grid ends at the last row
+    tile that holds rows, and the rows past it, which nothing wrote,
+    are not read here.  impl: "" (pallas on a TPU, xla elsewhere),
     "pallas", "interpret", "xla"; block_m: rows a tile (0: 256)."""
     from paddle_tpu.ops import pallas_kernels as pk
 

@@ -13,12 +13,13 @@ block is picked per tile from a scalar-prefetched table:
     tgmm   out[g]  = sum over tiles t of g: lhs[t]^T @ g[t]
                                                          pt_gmm_bwd_dw
 
-The number of row tiles is static (worst case: every token-expert pair
-routed here), the number that hold rows is a run-time scalar: a tile
-past it is skipped, its blocks mapped onto the last active tile's so
-that nothing is fetched or written for it.  What such a tile's output
-rows hold is not defined; the caller never reads them.  No capacity,
-no dropped token, no [N, E, C] one-hot.
+The row arrays are sized for the worst case (every token-expert pair
+routed here); the number of tiles that hold rows, `n_active`, is a
+run-time scalar, and it is the bound of the grid's row-tile axis: a
+call's grid ends at the last tile that holds rows, and no grid step is
+spent on a tile past it.  Such a tile's output rows are never written;
+what they hold is not defined, and the caller never reads them.  No
+capacity, no dropped token, no [N, E, C] one-hot.
 
 Technique after the megablox grouped matmul of jax's Pallas TPU
 examples; tile-aligned groups make the row masks and the group-metadata
@@ -58,22 +59,18 @@ def _tiles(k, n):
 
 def _gmm_kernel(tg_ref, na_ref, x_ref, w_ref, o_ref, acc_ref, *,
                 transpose_rhs):
-    i, kk = pl.program_id(1), pl.program_id(2)
-    active = i < na_ref[0]
+    kk = pl.program_id(2)
 
-    @pl.when(active & (kk == 0))
+    @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(active)
-    def _accumulate():
-        dims = (((1,), (1,)), ((), ())) if transpose_rhs \
-            else (((1,), (0,)), ((), ()))
-        acc_ref[...] += lax.dot_general(
-            x_ref[...], w_ref[0], dims,
-            preferred_element_type=jnp.float32)
+    dims = (((1,), (1,)), ((), ())) if transpose_rhs \
+        else (((1,), (0,)), ((), ()))
+    acc_ref[...] += lax.dot_general(
+        x_ref[...], w_ref[0], dims, preferred_element_type=jnp.float32)
 
-    @pl.when(active & (kk == pl.num_programs(2) - 1))
+    @pl.when(kk == pl.num_programs(2) - 1)
     def _store():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
@@ -81,24 +78,20 @@ def _gmm_kernel(tg_ref, na_ref, x_ref, w_ref, o_ref, acc_ref, *,
 def _tgmm_kernel(tg_ref, na_ref, x_ref, g_ref, o_ref, acc_ref, *,
                  n_tiles):
     i = pl.program_id(2)
-    na = na_ref[0]
-    active = i < na
     here = tg_ref[i]
     first = (i == 0) | (tg_ref[jnp.maximum(i - 1, 0)] != here)
-    last = (i == na - 1) | (tg_ref[jnp.minimum(i + 1, n_tiles - 1)]
-                            != here)
+    last = (i == na_ref[0] - 1) | (tg_ref[jnp.minimum(i + 1, n_tiles - 1)]
+                                   != here)
 
-    @pl.when(active & first)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(active)
-    def _accumulate():
-        acc_ref[...] += lax.dot_general(
-            x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    acc_ref[...] += lax.dot_general(
+        x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(active & last)
+    @pl.when(last)
     def _store():
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
@@ -116,36 +109,24 @@ def gmm_pallas(lhs, rhs, tile_group, n_active, tm, transpose_rhs=False,
                interpret=False):
     """lhs [M, K] (M a multiple of tm), rhs [G, K, N] (or [G, N, K] with
     transpose_rhs), tile_group [M/tm] int32, n_active [1] int32 ->
-    [M, N] in lhs's dtype; rows of tiles past n_active undefined."""
+    [M, N] in lhs's dtype; rows of tiles past n_active undefined.  The
+    grid visits the n_active[0] >= 1 tiles that hold rows and no other."""
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    n_tiles = m // tm
     tn, tk = _tiles(k, n)
-
-    def tile(i, na):
-        return jnp.minimum(i, na[0] - 1)
-
-    def depth(i, kk, na):
-        # a skipped tile keeps the last active tile's last block: no fetch
-        return jnp.where(i < na[0], kk, k // tk - 1)
-
     if transpose_rhs:
         w_spec = pl.BlockSpec(
-            (1, tn, tk), lambda j, i, kk, tg, na:
-            (tg[tile(i, na)], j, depth(i, kk, na)))
+            (1, tn, tk), lambda j, i, kk, tg, na: (tg[i], j, kk))
     else:
         w_spec = pl.BlockSpec(
-            (1, tk, tn), lambda j, i, kk, tg, na:
-            (tg[tile(i, na)], depth(i, kk, na), j))
+            (1, tk, tn), lambda j, i, kk, tg, na: (tg[i], kk, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n // tn, n_tiles, k // tk),
+        grid=(n // tn, n_active[0], k // tk),
         in_specs=[
-            pl.BlockSpec((tm, tk), lambda j, i, kk, tg, na:
-                         (tile(i, na), depth(i, kk, na))),
+            pl.BlockSpec((tm, tk), lambda j, i, kk, tg, na: (i, kk)),
             w_spec],
-        out_specs=pl.BlockSpec((tm, tn), lambda j, i, kk, tg, na:
-                               (tile(i, na), j)),
+        out_specs=pl.BlockSpec((tm, tn), lambda j, i, kk, tg, na: (i, j)),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)])
     return pl.pallas_call(
         functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
@@ -164,28 +145,21 @@ def tgmm_pallas(lhs, grad, tile_group, n_active, tm, n_groups,
     """lhs [M, K], grad [M, N] -> [G, K, N] in lhs's dtype: for each
     group the sum over its tiles of lhs[t]^T @ grad[t].  Every group
     has at least one tile (the layout's guarantee), so every output
-    block is written."""
+    block is written.  The grid's last axis ends at n_active[0]."""
     m, k = lhs.shape
     n = grad.shape[1]
-    n_tiles = m // tm
     tn, tk = _tiles(k, n)
-
-    def tile(i, na):
-        return jnp.minimum(i, na[0] - 1)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(k // tk, n // tn, n_tiles),
+        grid=(k // tk, n // tn, n_active[0]),
         in_specs=[
-            pl.BlockSpec((tm, tk), lambda kk, j, i, tg, na:
-                         (tile(i, na), kk)),
-            pl.BlockSpec((tm, tn), lambda kk, j, i, tg, na:
-                         (tile(i, na), j))],
+            pl.BlockSpec((tm, tk), lambda kk, j, i, tg, na: (i, kk)),
+            pl.BlockSpec((tm, tn), lambda kk, j, i, tg, na: (i, j))],
         out_specs=pl.BlockSpec((1, tk, tn), lambda kk, j, i, tg, na:
-                               (tg[tile(i, na)], kk, j)),
+                               (tg[i], kk, j)),
         scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)])
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, n_tiles=n_tiles),
+        functools.partial(_tgmm_kernel, n_tiles=m // tm),
         name="pt_gmm_bwd_dw",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_groups, k, n), lhs.dtype),
@@ -226,11 +200,14 @@ def tgmm_xla(lhs, grad, tile_group, n_active, tm, n_groups):
 
 def _count_tiles(k, n):
     """paddle_tpu_kernel_impl_total{kernel="moe_gmm_tile",
-    impl="<tn>x<tk>"}, once a grouped-matmul call that runs a kernel: a
-    step's counters say which block shapes it ran."""
+    impl="<tn>x<tk>"} and {kernel="moe_gmm_grid", impl="live_tiles"},
+    once each a grouped-matmul call that runs a kernel: a step's
+    counters say which block shapes it ran, and that every such call's
+    grid ended at the tiles that hold rows."""
     from paddle_tpu.ops import pallas_kernels as pk
 
     pk._count_impl("moe_gmm_tile", "%dx%d" % _tiles(k, n))
+    pk._count_impl("moe_gmm_grid", "live_tiles")
 
 
 def gmm(lhs, rhs, tile_group, n_active, tm, impl, transpose_rhs=False):

@@ -91,7 +91,9 @@ def _flash_mla(grad, shape=(1, 32, 4096)):
 
 def _gmm(which, rows=16384, held=8, hidden=3584, width=1024, tm=256):
     """The grouped matmuls of 8 held experts at the worst-case number
-    of rows (4096 tokens x 4 experts each)."""
+    of rows (xing4: 4096 tokens x 4 experts each; dsv2: 8192 x 6 at an
+    expert width of 11 x 128, so 128-wide blocks).  The row-tile axis
+    of each grid is the run-time n_active."""
     from paddle_tpu.ops.pallas_gmm import gmm_pallas, tgmm_pallas
 
     m = (rows // tm + held) * tm
@@ -105,6 +107,9 @@ def _gmm(which, rows=16384, held=8, hidden=3584, width=1024, tm=256):
             (_sds((m, width)), _sds((held, hidden, width))) + maps, 1
     return (lambda x, g, tg, na: tgmm_pallas(x, g, tg, na, tm, held)), \
         (_sds((m, hidden)), _sds((m, width))) + maps, 1
+
+
+DSV2_GMM = dict(rows=49152, hidden=2048, width=1408)
 
 
 def _decode(head_dim, head_pack, batch=64, heads=8, page_size=128,
@@ -175,6 +180,10 @@ CASES = {
     "gmm_fwd_8x3584x1024_rows16384": lambda: _gmm("fwd"),
     "gmm_bwd_dx_8x3584x1024_rows16384": lambda: _gmm("dx"),
     "gmm_bwd_dw_8x3584x1024_rows16384": lambda: _gmm("dw"),
+    # dsv2: 49,152 pairs are the layout's 51,200 rows, 200 tiles
+    "gmm_fwd_8x2048x1408_rows51200": lambda: _gmm("fwd", **DSV2_GMM),
+    "gmm_bwd_dx_8x2048x1408_rows51200": lambda: _gmm("dx", **DSV2_GMM),
+    "gmm_bwd_dw_8x2048x1408_rows51200": lambda: _gmm("dw", **DSV2_GMM),
     "flash_decode_d128_b64": lambda: _decode(128, False),
     "flash_decode_d64_headpacked_b64": lambda: _decode(64, True),
     "conv2d_epilogue_3x3_56x56x64_mb128": lambda: _conv(False),

@@ -271,8 +271,8 @@ def test_scope_and_counters_after_a_build():
     assert used[("moe_route_scoring", "softmax")] >= 2
     assert ("moe_route_scoring", "sigmoid") not in used
     assert used[("moe_gmm", "xla")] >= 2
-    # off the chip the experts run their XLA form: no block shape
-    assert not [k for k in used if k[0] == "moe_gmm_tile"]
+    # off the chip the experts run their XLA form: no block shape, no grid
+    assert not [k for k in used if k[0] in ("moe_gmm_tile", "moe_gmm_grid")]
     # a kernel call counts the blocks `_tile` gives it: an expert width
     # of 11 x 128 gets 128-wide blocks, 1,024 whole ones
     assert pallas_gmm._tiles(2048, 1408) == (128, 512)
@@ -288,6 +288,9 @@ def test_scope_and_counters_after_a_build():
         atol=1e-4)
     assert counts().get(("moe_gmm_tile", "128x256"), 0) \
         - before.get(("moe_gmm_tile", "128x256"), 0) == 1
+    # and, as often, that its grid ends at the tiles that hold rows
+    assert counts().get(("moe_gmm_grid", "live_tiles"), 0) \
+        - before.get(("moe_gmm_grid", "live_tiles"), 0) == 1
 
 
 # -- the balance loss ---------------------------------------------------------

@@ -353,46 +353,141 @@ def test_moe_experts_bf16_operands():
                                atol=BF16_ULP * float(jnp.abs(want).max()))
 
 
+# (tile_group, n_active): a layout with a dead tail, and a sparse one,
+# 2 of 40 tiles live.  As _group_layout makes them: every group has a
+# live tile, and the tail carries the last group's id.
+GMM_LAYOUTS = {
+    "4_of_6": ([0, 0, 1, 2, 2, 2], 4),
+    "5_of_6": ([0, 0, 1, 2, 2, 2], 5),
+    "2_of_40": ([0, 1] + [1] * 38, 2)}
+
+
+@pytest.mark.parametrize("layout", ["4_of_6", "2_of_40"])
 @pytest.mark.parametrize("transpose_rhs", [False, True])
-def test_gmm_kernel_skips_inactive_tiles(transpose_rhs):
+def test_gmm_kernel_skips_inactive_tiles(transpose_rhs, layout):
     from paddle_tpu.ops import pallas_gmm as pg
 
     rng = np.random.default_rng(5)
-    tm, tiles, g, k, n = 16, 6, 3, 256, 128
+    groups, live_tiles = GMM_LAYOUTS[layout]
+    tm, tiles, g, k, n = 16, len(groups), groups[-1] + 1, 256, 128
     lhs = jnp.asarray(rng.normal(0, 1, (tiles * tm, k)), jnp.float32)
     rhs = jnp.asarray(rng.normal(0, 1, (g, n, k) if transpose_rhs
                                  else (g, k, n)), jnp.float32)
-    tile_group = jnp.asarray([0, 0, 1, 2, 2, 2], jnp.int32)
-    n_active = jnp.asarray([4], jnp.int32)
+    tile_group = jnp.asarray(groups, jnp.int32)
+    n_active = jnp.asarray([live_tiles], jnp.int32)
     got = pg.gmm_pallas(lhs, rhs, tile_group, n_active, tm,
                         transpose_rhs=transpose_rhs, interpret=True)
     want = pg.gmm_xla(lhs, rhs, tile_group, n_active, tm, transpose_rhs)
-    live = 4 * tm
+    live = live_tiles * tm
     np.testing.assert_allclose(got[:live], want[:live], rtol=1e-5,
                                atol=1e-4)
-    by_hand = np.asarray(lhs[2 * tm:3 * tm]) @ (
-        np.asarray(rhs[1]).T if transpose_rhs else np.asarray(rhs[1]))
-    np.testing.assert_allclose(got[2 * tm:3 * tm], by_hand, rtol=1e-4,
+    t = live_tiles - 1                          # the last live tile
+    w = np.asarray(rhs[groups[t]])
+    by_hand = np.asarray(lhs[t * tm:live]) @ (w.T if transpose_rhs else w)
+    np.testing.assert_allclose(got[t * tm:live], by_hand, rtol=1e-4,
                                atol=1e-4)
 
 
-def test_tgmm_kernel_sums_a_groups_tiles():
+@pytest.mark.parametrize("layout", ["5_of_6", "2_of_40"])
+def test_tgmm_kernel_sums_a_groups_tiles(layout):
     from paddle_tpu.ops import pallas_gmm as pg
 
     rng = np.random.default_rng(6)
-    tm, tiles, g, k, n = 16, 6, 3, 128, 256
+    groups, live_tiles = GMM_LAYOUTS[layout]
+    tm, tiles, g, k, n = 16, len(groups), groups[-1] + 1, 128, 256
     lhs = jnp.asarray(rng.normal(0, 1, (tiles * tm, k)), jnp.float32)
     grad = jnp.asarray(rng.normal(0, 1, (tiles * tm, n)), jnp.float32)
-    tile_group = jnp.asarray([0, 0, 1, 2, 2, 2], jnp.int32)
-    n_active = jnp.asarray([5], jnp.int32)      # the last tile is skipped
+    tile_group = jnp.asarray(groups, jnp.int32)
+    n_active = jnp.asarray([live_tiles], jnp.int32)   # the tail is skipped
     got = pg.tgmm_pallas(lhs, grad, tile_group, n_active, tm, g,
                          interpret=True)
     want = pg.tgmm_xla(lhs, grad, tile_group, n_active, tm, g)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
-    rows = slice(3 * tm, 5 * tm)                # group 2's active tiles
+    # the last group's active tiles, and none of the tail it shares a
+    # group id with
+    first = groups.index(groups[live_tiles - 1])
+    rows = slice(first * tm, live_tiles * tm)
     np.testing.assert_allclose(
-        got[2], np.asarray(lhs[rows]).T @ np.asarray(grad[rows]),
+        got[groups[first]],
+        np.asarray(lhs[rows]).T @ np.asarray(grad[rows]),
         rtol=1e-4, atol=1e-4)
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation of a jaxpr, the nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("kernel", ["pt_gmm_fwd", "pt_gmm_bwd_dx",
+                                    "pt_gmm_bwd_dw"])
+def test_gmm_grid_is_bounded_by_n_active(kernel):
+    """The row-tile axis of each grouped-matmul grid is the traced
+    n_active, not the static number of tiles: a grid that went back to
+    the worst case would run a grid step for every tile without rows."""
+    from paddle_tpu.ops import pallas_gmm as pg
+
+    tm, tiles, g, k, n = 16, 40, 3, 256, 128
+    rows, maps = tiles * tm, (jnp.zeros((tiles,), jnp.int32),
+                              jnp.ones((1,), jnp.int32))
+    if kernel == "pt_gmm_bwd_dw":
+        jaxpr = jax.make_jaxpr(lambda x, dy, tg, na: pg.tgmm_pallas(
+            x, dy, tg, na, tm, g, interpret=True))(
+                jnp.zeros((rows, k)), jnp.zeros((rows, n)), *maps)
+    else:
+        t = kernel == "pt_gmm_bwd_dx"
+        jaxpr = jax.make_jaxpr(lambda x, w, tg, na: pg.gmm_pallas(
+            x, w, tg, na, tm, transpose_rhs=t, interpret=True))(
+                jnp.zeros((rows, k)),
+                jnp.zeros((g, n, k) if t else (g, k, n)), *maps)
+    call, = _pallas_calls(jaxpr.jaxpr)
+    assert call.params["name"] == kernel
+    mapping = call.params["grid_mapping"]
+    assert mapping.num_dynamic_grid_bounds == 1
+    # and it is the row tiles' axis: second of gmm's, last of tgmm's
+    axis = 2 if kernel == "pt_gmm_bwd_dw" else 1
+    assert not isinstance(mapping.grid[axis], int)
+
+
+def test_moe_experts_reads_no_row_past_the_live_tiles():
+    """Far more tiles than live ones (34 for about 4): in interpret mode
+    the rows past the grid's bound come back NaN, so finite outputs and
+    gradients that agree with the XLA form say no caller reads them."""
+    held = (2, 5)
+    ins = _experts_case(7, n=256, e=16, k=4, held=held)
+    diff = {k: ins[k] for k in DIFF}
+
+    def run(diff, impl):
+        out = _op("moe_experts", {**ins, **diff}, held=list(held),
+                  block_m=32, impl=impl)["Out"]
+        return (out * jnp.sin(out)).sum(), out
+
+    (_, out), grads = jax.value_and_grad(run, has_aux=True)(
+        diff, "interpret")
+    (_, want), want_grads = jax.value_and_grad(run, has_aux=True)(
+        diff, "xla")
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
+    for k in DIFF:
+        assert np.isfinite(np.asarray(grads[k])).all(), k
+        scale = float(jnp.abs(want_grads[k]).max())
+        np.testing.assert_allclose(grads[k], want_grads[k], rtol=1e-4,
+                                   atol=1e-5 * scale, err_msg=k)
+    # the layout is as sparse as the docstring says, and the kernel did
+    # leave the tail unwritten (else this test would prove nothing)
+    from paddle_tpu.ops import pallas_gmm as pg
+    from paddle_tpu.ops.llm_ops import _group_layout
+
+    lay = _group_layout(ins["TopkIdx"], held, 32)
+    tiles, live = lay["tile_group"].shape[0], int(lay["n_active"][0])
+    assert tiles == 34 and live <= 6
+    xs = jnp.ones((tiles * 32, 128), jnp.float32)
+    tail = pg.gmm_pallas(xs, ins["WGate"], lay["tile_group"],
+                         lay["n_active"], 32, interpret=True)[live * 32:]
+    assert np.isnan(np.asarray(tail)).all()
 
 
 # -- hyper-connections ---------------------------------------------------------

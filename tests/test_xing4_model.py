@@ -226,7 +226,13 @@ def test_the_shares_add_up_to_the_whole_layer(impl):
     reference; and each share of the PROGRAM's ops equals the
     reference's share."""
     from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.ops import pallas_kernels as pk
 
+    def counted(kernel):
+        return sum(v for lbl, v in pk._M_KERNEL_IMPL.items()
+                   if lbl["kernel"] == kernel)
+
+    before = counted("moe_gmm_tile"), counted("moe_gmm_grid")
     rng = np.random.default_rng(11)
     lw = _layer_weights(rng)
     config = {"num_experts_per_tok": 3, "norm_topk_prob": True,
@@ -259,6 +265,12 @@ def test_the_shares_add_up_to_the_whole_layer(impl):
         np.testing.assert_allclose(mine, part, rtol=1e-4, atol=1e-5)
         total = total + part
     np.testing.assert_allclose(total, whole, rtol=1e-5, atol=1e-5)
+    # every grouped-matmul call that ran a kernel (3 a share; none on
+    # the XLA form) counted its block shape and, as often, a grid that
+    # ends at the tiles that hold rows
+    tiles, grids = (counted("moe_gmm_tile") - before[0],
+                    counted("moe_gmm_grid") - before[1])
+    assert tiles == grids == (12 if impl == "interpret" else 0)
     # every token's gates sum to the scaling factor over ALL experts, so
     # the shares of the gates add up too
     np.testing.assert_allclose(np.asarray(r["TopkWeight"]).sum(-1), 2.0,
