@@ -17,7 +17,7 @@ reference.
 
 from __future__ import annotations
 
-from paddle_tpu.core.program import BACKWARD, OpDesc, VarDesc
+from paddle_tpu.core.program import BACKWARD, STAT, OpDesc, VarDesc
 from paddle_tpu.core.registry import GRAD_SUFFIX, get_op_def, has_op_def
 from paddle_tpu import unique_name
 
@@ -368,10 +368,13 @@ def _append_backward_recompute(loss, fwd_ops, parameter_list,
 
     # partition forward ops into segments ending after checkpoint writes
     # (host-only ops are skipped exactly like the compiled trace skips
-    # them — replaying one on jax tracers would crash or re-run IO)
+    # them — replaying one on jax tracers would crash or re-run IO; a
+    # step's stat writes (layers.step_stat) run once, in the forward
+    # pass: a replay would read the ring its own op had written)
     segments = [[]]
     for op in fwd_ops:
-        if not has_op_def(op.type) or get_op_def(op.type).host_only:
+        if not has_op_def(op.type) or get_op_def(op.type).host_only \
+                or op.op_role == STAT:
             continue
         segments[-1].append(op)
         if any(n in cset for n in op.output_names()):

@@ -37,11 +37,13 @@ _WHITE_KEEP_FP32 = {
 # stat outputs MeanOut/VarianceOut/SavedMean/SavedVariance stay fp32,
 # like batch_norm's non-Y outputs under the follow-X rule;
 # flash_attention: Out follows the bf16 q/k/v, the saved row statistic
-# LSE is float32 whatever the inputs are)
+# LSE is float32 whatever the inputs are; moe_experts: so is Load)
 _WHITE_LOWP_OUT = {
     "conv2d_bn_train": frozenset({"Output"}),
     "flash_attention": frozenset({"Out"}),
     "mhc_pre": frozenset({"U"}),
+    # Load is a float32 count, whatever the rows' dtype
+    "moe_experts": frozenset({"Out"}),
 }
 
 

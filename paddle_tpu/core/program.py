@@ -34,6 +34,10 @@ OPTIMIZE = "optimize"
 RPC = "rpc"
 LRSCHED = "lr_sched"
 LOSS = "loss"
+# what a training step says of itself (layers.step_stat: the write into
+# a stat ring and the increment of its step counter): no gradient, not
+# replayed by a recompute segment, dropped by clone(for_test=True)
+STAT = "stat"
 
 
 # active pipeline-stage annotation (reference: fluid.device_guard; ops
@@ -575,6 +579,7 @@ class Program:
 
     def clone(self, for_test: bool = False) -> "Program":
         """Deep structural copy.  for_test=True drops backward/optimize ops
+        and a training step's stat writes (their rings stay, unwritten)
         and switches train-only attrs (reference Program.clone
         framework.py:2950: test mode for dropout/batch_norm)."""
         p = Program()
@@ -590,7 +595,7 @@ class Program:
                 nv.sharding = v.sharding
                 nb.vars[v.name] = nv
             for op in b.ops:
-                if for_test and op.op_role in (BACKWARD, OPTIMIZE):
+                if for_test and op.op_role in (BACKWARD, OPTIMIZE, STAT):
                     continue
                 nop = OpDesc.from_dict(op.to_dict())
                 if for_test and "is_test" in nop.attrs:

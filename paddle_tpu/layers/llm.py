@@ -158,7 +158,15 @@ def moe_experts(input, topk_idx, topk_weight, held, width,
     times the expert's SwiGLU, width `width`.  Weights are stacked
     `[len(held), C, width]` (`<name>_gate.w`, `<name>_up.w`) and
     `[len(held), width, C]` (`<name>_down.w`).  No token is dropped; a
-    selected expert that is not held adds nothing."""
+    selected expert that is not held adds nothing.
+
+    What each step's routing gave this chip is kept a row a step in the
+    stat ring `<name>.load` (layers.step_stat; read it with
+    observability.step_stats.read()): columns `expert_<id>` the pairs
+    routed to each held expert, `routed` their sum, `live_tiles` the
+    row tiles the grouped matmuls' grids ran."""
+    from paddle_tpu.layers.nn import step_stat
+
     helper = LayerHelper("moe_experts", name=name)
     c, g = int(input.shape[-1]), len(held)
 
@@ -169,13 +177,16 @@ def moe_experts(input, topk_idx, topk_weight, held, width,
     wg, wu = stack("gate", [g, c, width]), stack("up", [g, c, width])
     wd = stack("down", [g, width, c])
     out = helper.create_variable_for_type_inference(input.dtype)
+    load = helper.create_variable_for_type_inference("float32", True)
     helper.append_op(
         type="moe_experts",
         inputs={"X": input, "TopkIdx": topk_idx, "TopkWeight": topk_weight,
                 "WGate": wg, "WUp": wu, "WDown": wd},
-        outputs={"Out": out},
+        outputs={"Out": out, "Load": load},
         attrs={"held": [int(e) for e in held], "block_m": int(block_m or 0),
                "impl": impl or ""})
+    step_stat(helper.name + ".load", load,
+              ["expert_%d" % e for e in held] + ["routed", "live_tiles"])
     return out
 
 

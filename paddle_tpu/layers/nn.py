@@ -29,7 +29,7 @@ __all__ = [
     "reduce_any", "pow", "sqrt", "square", "abs", "exp", "log",
     "sequence_mask", "swish", "hard_sigmoid", "elu", "relu6", "softplus",
     "softsign", "prelu", "brelu", "flash_attention", "linear_chain_crf",
-    "crf_decoding", "nce", "hsigmoid", "sample_logits",
+    "crf_decoding", "nce", "hsigmoid", "sample_logits", "step_stat",
 ]
 
 
@@ -306,6 +306,60 @@ def _step_counter(helper, prefix):
             type="increment", inputs={"X": ctr},
             outputs={"Out": ctr}, attrs={"step": 1.0})
     return _dropout_counter_var[key]
+
+
+def step_stat(name, x, columns=None):
+    """Keeps `x`, a float vector of static width computed inside the
+    step, one row a step in the ring `step_stat.<name>` [K, width] of
+    the program's own state (observability/step_stats.py: `read()` is
+    the reader; K = 4096).  The first stat of a program makes its step
+    counter and the one `increment` a step; every stat adds one
+    `step_stat` op.  Both ops have the role `stat`: no gradient, no
+    replay in a recompute segment, dropped by `clone(for_test=True)`.
+    `columns` names x's entries for the reader."""
+    from paddle_tpu import unique_name
+    from paddle_tpu.core.program import STAT
+    from paddle_tpu.layers import tensor
+    from paddle_tpu.observability import step_stats
+
+    helper = LayerHelper("step_stat", name=name)
+    block = helper.main_program.global_block()
+    if helper.block is not block:
+        raise ValueError("step_stat %r: inside a sub-block; a stat is "
+                         "one row a step of the whole program" % name)
+    if x.shape is None or len(x.shape) != 1 or int(x.shape[0]) < 1:
+        raise ValueError("step_stat %r: x must be a vector of static "
+                         "width, got shape %r" % (name, x.shape))
+    width = int(x.shape[0])
+    columns = [str(c) for c in columns] if columns is not None \
+        else ["%d" % i for i in range(width)]
+    if len(columns) != width:
+        raise ValueError("step_stat %r: %d columns for a width of %d"
+                         % (name, len(columns), width))
+    stats = [op for op in block.ops if op.type == "step_stat"]
+    if any(op.attrs["name"] == name for op in stats):
+        raise ValueError("step_stat %r: the program has one already"
+                         % name)
+    if stats:
+        step = block.var(stats[0].inputs["Step"][0])
+    else:
+        step = tensor.create_global_var(
+            [1], 0, "int32", persistable=True,
+            name=unique_name.generate("step_stat_step"))
+        step.stop_gradient = True
+        block.append_op(type="increment", inputs={"X": step},
+                        outputs={"Out": step}, attrs={"step": 1.0},
+                        op_role=STAT)
+    ring = tensor.create_global_var(
+        [step_stats.K, width], 0.0, "float32", persistable=True,
+        name="step_stat." + name)
+    ring.stop_gradient = True
+    block.append_op(type="step_stat",
+                    inputs={"X": x, "Ring": ring, "Step": step},
+                    outputs={"RingOut": ring},
+                    attrs={"name": name, "columns": columns},
+                    op_role=STAT)
+    return ring
 
 
 def dropout(x, dropout_prob, is_test=False, seed=None, name=None,

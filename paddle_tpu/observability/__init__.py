@@ -36,6 +36,10 @@ stack needs all three (docs/OBSERVABILITY.md).
                       per-call stamps from CompiledProgram._run and
                       DeviceFeeder (where exe.run's host time goes),
                       the same phases as profiler annotations
+  step_stats.py       the always-on stat ring: what a compiled step
+                      says of itself (layers.step_stat), one row a
+                      step in the program's own state, read by order
+                      beside the step record
 
 ``paddle_tpu/profiler.py`` (the Fluid-shaped start_profiler/
 stop_profiler/RecordEvent surface) is a thin shim over tracing.py.
@@ -47,6 +51,7 @@ from paddle_tpu.observability import flight_recorder
 from paddle_tpu.observability import metrics
 from paddle_tpu.observability import slo
 from paddle_tpu.observability import step_record
+from paddle_tpu.observability import step_stats
 from paddle_tpu.observability import tracing
 from paddle_tpu.observability.collector import (CollectorPusher,
                                                 CollectorServer)

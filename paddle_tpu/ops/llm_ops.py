@@ -218,7 +218,8 @@ def _group_layout(idx, held, tm):
     where not `mine`), mine [N, k] bool, row_pair [M] the pair (n * k +
     j) that feeds each padded row (undefined where not row_live),
     row_live [M] bool, tile_group [M / tm] int32, n_active [1] int32,
-    with M = (ceil(N k / tm) + G) * tm rows: the worst case, every pair
+    sizes [G] int32 the pairs routed to each held expert, with
+    M = (ceil(N k / tm) + G) * tm rows: the worst case, every pair
     routed here."""
     n, k = idx.shape
     g = len(held)
@@ -252,7 +253,7 @@ def _group_layout(idx, held, tm):
     dest = (pstart[safe] + rank - start[safe]).reshape(n, k)
     return {"dest": dest, "mine": local < g, "row_pair": row_pair,
             "row_live": row_live, "tile_group": tile_group,
-            "n_active": n_active.astype(jnp.int32)}
+            "n_active": n_active.astype(jnp.int32), "sizes": sizes}
 
 
 def _gather_rows(x, index, live):
@@ -331,7 +332,7 @@ _routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 @register_op("moe_experts",
              inputs=("X", "TopkIdx", "TopkWeight", "WGate", "WUp", "WDown"),
-             outputs=("Out",),
+             outputs=("Out", "Load"),
              attrs={"held": REQUIRED, "block_m": 0, "impl": ""})
 def moe_experts(ins, attrs):
     """The routed experts' part of a sparse feed-forward that THIS chip
@@ -347,7 +348,14 @@ def moe_experts(ins, attrs):
     with run-time group sizes; a kernel's grid ends at the last row
     tile that holds rows, and the rows past it, which nothing wrote,
     are not read here.  impl: "" (pallas on a TPU, xla elsewhere),
-    "pallas", "interpret", "xla"; block_m: rows a tile (0: 256)."""
+    "pallas", "interpret", "xla"; block_m: rows a tile (0: 256).
+
+    Load, float32 [G + 2]: what this execution was given, from the
+    arrays that bound the kernels' grids (`_group_layout`'s sizes and
+    n_active), not a recount: the pairs routed to each held expert in
+    stack order, their sum, and the row tiles that hold rows.  No
+    gradient flows to it; layers.moe_experts keeps it a row a step
+    (layers.step_stat)."""
     from paddle_tpu.ops import pallas_kernels as pk
 
     x = ins["X"]
@@ -366,7 +374,9 @@ def moe_experts(ins, attrs):
                 x.reshape(-1, c), gate, ins["WGate"].astype(dt),
                 ins["WUp"].astype(dt), ins["WDown"].astype(dt), lay,
                 idx.shape[-1], tm, impl)
-        return {"Out": out.reshape(x.shape)}
+        load = jnp.concatenate(
+            [lay["sizes"], jnp.sum(lay["sizes"])[None], lay["n_active"]])
+        return {"Out": out.reshape(x.shape), "Load": load.astype(_F32)}
 
 
 # ---------------------------------------------------------------------------

@@ -56,6 +56,24 @@ def auc(ins, attrs):
     return {"AUC": auc_val, "StatPosOut": pos_hist, "StatNegOut": neg_hist}
 
 
+@register_op("step_stat", inputs=("X", "Ring", "Step"),
+             outputs=("RingOut",),
+             attrs={"name": REQUIRED, "columns": REQUIRED},
+             differentiable=False, in_place={"RingOut": "Ring"})
+def step_stat(ins, attrs):
+    """One row a step of what the compiled step says of itself
+    (layers.step_stat, observability/step_stats.py): X, a small vector,
+    is written as float32 into row (Step - 1) mod K of Ring [K, width].
+    Step [1] is the program's count of steps, incremented before any
+    stat is written, so the step with index i (from 0) writes row
+    i mod K.  `name` and `columns` are for the reader: the compute
+    reads neither."""
+    ring = ins["Ring"]
+    row = (ins["Step"].reshape(()) - 1) % ring.shape[0]
+    x = ins["X"].astype(ring.dtype).reshape(1, ring.shape[1])
+    return {"RingOut": jax.lax.dynamic_update_slice(ring, x, (row, 0))}
+
+
 @register_op("precision_recall",
              inputs=("MaxProbs", "Indices", "Labels", "StatesInfo"),
              outputs=("BatchMetrics", "AccumMetrics", "AccumStatesInfo"),

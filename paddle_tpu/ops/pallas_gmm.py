@@ -200,14 +200,12 @@ def tgmm_xla(lhs, grad, tile_group, n_active, tm, n_groups):
 
 def _count_tiles(k, n):
     """paddle_tpu_kernel_impl_total{kernel="moe_gmm_tile",
-    impl="<tn>x<tk>"} and {kernel="moe_gmm_grid", impl="live_tiles"},
-    once each a grouped-matmul call that runs a kernel: a step's
-    counters say which block shapes it ran, and that every such call's
-    grid ended at the tiles that hold rows."""
+    impl="<tn>x<tk>"}, once a grouped-matmul call that runs a kernel:
+    a step's counters say which block shapes it ran.  (How many row
+    tiles its grid ran is a run-time number: moe_experts' Load.)"""
     from paddle_tpu.ops import pallas_kernels as pk
 
     pk._count_impl("moe_gmm_tile", "%dx%d" % _tiles(k, n))
-    pk._count_impl("moe_gmm_grid", "live_tiles")
 
 
 def gmm(lhs, rhs, tile_group, n_active, tm, impl, transpose_rhs=False):

@@ -232,7 +232,7 @@ def test_the_shares_add_up_to_the_whole_layer(impl):
         return sum(v for lbl, v in pk._M_KERNEL_IMPL.items()
                    if lbl["kernel"] == kernel)
 
-    before = counted("moe_gmm_tile"), counted("moe_gmm_grid")
+    before = counted("moe_gmm_tile")
     rng = np.random.default_rng(11)
     lw = _layer_weights(rng)
     config = {"num_experts_per_tok": 3, "norm_topk_prob": True,
@@ -251,26 +251,34 @@ def test_the_shares_add_up_to_the_whole_layer(impl):
                        "Bias": lw["router_bias"]},
                       route.canonical_attrs(
                           {"k": 3, "routed_scaling_factor": 2.0}))
-    total = shared
+    total, loads = shared, []
     for held in shares:
         with jax.default_matmul_precision("highest"):
             part = ref.expert_ffn(u, dict(lw, experts=stack_of(held)),
                                   config, held=held, shared=False)
         st = stack_of(held)
-        mine = experts.compute(
+        outs = experts.compute(
             {"X": u, "TopkIdx": r["TopkIdx"], "TopkWeight": r["TopkWeight"],
              "WGate": st["gate"], "WUp": st["up"], "WDown": st["down"]},
             experts.canonical_attrs({"held": held, "block_m": 16,
-                                     "impl": impl}))["Out"]
-        np.testing.assert_allclose(mine, part, rtol=1e-4, atol=1e-5)
+                                     "impl": impl}))
+        np.testing.assert_allclose(outs["Out"], part, rtol=1e-4, atol=1e-5)
         total = total + part
+        loads.append(np.asarray(outs["Load"]))
     np.testing.assert_allclose(total, whole, rtol=1e-5, atol=1e-5)
     # every grouped-matmul call that ran a kernel (3 a share; none on
-    # the XLA form) counted its block shape and, as often, a grid that
-    # ends at the tiles that hold rows
-    tiles, grids = (counted("moe_gmm_tile") - before[0],
-                    counted("moe_gmm_grid") - before[1])
-    assert tiles == grids == (12 if impl == "interpret" else 0)
+    # the XLA form) counted its block shape
+    assert counted("moe_gmm_tile") - before == \
+        (12 if impl == "interpret" else 0)
+    # and each share's Load says what its grids ran: the pairs routed to
+    # its two experts, their sum, and the row tiles of 16 that hold
+    # them; over the four shares every one of the 40 x 3 pairs once
+    ids = np.asarray(r["TopkIdx"])
+    for held, load in zip(shares, loads):
+        sizes = [int((ids == e).sum()) for e in held]
+        assert load.tolist() == sizes + [sum(sizes), sum(
+            max(-(-s // 16), 1) for s in sizes)]
+    assert sum(load[2] for load in loads) == 40 * 3
     # every token's gates sum to the scaling factor over ALL experts, so
     # the shares of the gates add up too
     np.testing.assert_allclose(np.asarray(r["TopkWeight"]).sum(-1), 2.0,
