@@ -214,13 +214,17 @@ def _group_layout(idx, held, tm):
     tiles of tm rows (at least one tile a group).
 
     idx [N, k] int32 expert ids, held: tuple of the expert ids held.
-    Returns a dict: dest [N, k] the padded row of each pair (undefined
+    Returns a dict: dest [N, k] the padded row of each pair (row 0
     where not `mine`), mine [N, k] bool, row_pair [M] the pair (n * k +
     j) that feeds each padded row (undefined where not row_live),
     row_live [M] bool, tile_group [M / tm] int32, n_active [1] int32,
     sizes [G] int32 the pairs routed to each held expert, with
     M = (ceil(N k / tm) + G) * tm rows: the worst case, every pair
-    routed here."""
+    routed here.  The groups are laid out in stack order from row 0,
+    so the rows that hold anything are a PREFIX of every row array,
+    [0, n_active * tm): the one bound of the kernels' grids and of the
+    op's own row work (_over_live_rows).  Only these index vectors are
+    made for all M rows."""
     n, k = idx.shape
     g = len(held)
     p = n * k
@@ -237,32 +241,129 @@ def _group_layout(idx, held, tm):
     tile_end = jnp.cumsum(tiles)
     pstart = (tile_end - tiles) * tm               # first padded row
     n_active = tile_end[-1:]
+    tile = jnp.arange(n_tiles, dtype=jnp.int32)
     tile_group = jnp.minimum(
-        jnp.searchsorted(tile_end, jnp.arange(n_tiles, dtype=jnp.int32),
-                         side="right"), g - 1).astype(jnp.int32)
-    # rows -> pairs
-    row = jnp.arange(n_tiles * tm, dtype=jnp.int32)
-    row_group = tile_group[row // tm]
-    off = row - pstart[row_group]
-    row_live = (off < sizes[row_group]) & (row // tm < n_active[0])
-    row_pair = order[jnp.clip(start[row_group] + off, 0, p - 1)]
+        jnp.searchsorted(tile_end, tile, side="right"),
+        g - 1).astype(jnp.int32)
+    # rows -> pairs, a tile at a time: a tile's rows share its group,
+    # so what a row needs of its group is looked up once a tile
+    off = (tile * tm - pstart[tile_group])[:, None] \
+        + jnp.arange(tm, dtype=jnp.int32)[None]            # [tiles, tm]
+    row_live = (off < sizes[tile_group][:, None]) \
+        & (tile < n_active[0])[:, None]
+    row_pair = order[jnp.clip(start[tile_group][:, None] + off, 0, p - 1)]
     # pairs -> rows: the rank of a pair inside its group is its sorted
     # position less the group's first
     rank = jnp.argsort(order).astype(jnp.int32)    # inverse permutation
     safe = jnp.minimum(key, g - 1)
-    dest = (pstart[safe] + rank - start[safe]).reshape(n, k)
-    return {"dest": dest, "mine": local < g, "row_pair": row_pair,
-            "row_live": row_live, "tile_group": tile_group,
+    dest = jnp.where(key < g, pstart[safe] + rank - start[safe], 0)
+    return {"dest": dest.reshape(n, k), "mine": local < g,
+            "row_pair": row_pair.reshape(-1),
+            "row_live": row_live.reshape(-1), "tile_group": tile_group,
             "n_active": n_active.astype(jnp.int32), "sizes": sizes}
-
-
-def _gather_rows(x, index, live):
-    return jnp.where(live[:, None], jnp.take(x, index, axis=0), 0)
 
 
 def _silu_and_grad(h):
     sig = jax.nn.sigmoid(h)
     return h * sig, sig * (1 + h * (1 - sig))
+
+
+# row tiles a step of _over_live_rows: 2,048 rows at 256 a tile.  Chosen
+# once on the chip (PERF.md, PR 37: 4, 8 and 16 tiles read within 2% of
+# each other at every live count; 8 and 16 a little ahead of 4)
+_CHUNK_TILES = 8
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _over_live_rows(fn, outs, k, tm, impl, lay, *operands):
+    """The op's own work by padded row, over the rows that came.
+
+    outs: (width or None, dtype) of each row array to make, [M, width]
+    or [M]; fn(rows, k, lay, *operands) -> a chunk of each, where
+    rows(a) is the chunk's slice of a row array `a`.  Runs fn over
+    chunks of _CHUNK_TILES row tiles of the live prefix
+    [0, n_active * tm) and over nothing past it: the trip count is read
+    from n_active at run time, as the kernels' grids are, and each
+    chunk is written into its place in a carried buffer.  The worst
+    case is the same loop, longer.  A last chunk that would pass M
+    starts earlier instead: fn is a map over rows, so a row written
+    twice gets the same value.  The buffers are made unwritten
+    (pallas_gmm.row_buffer) and the rows past the last chunk stay so:
+    not defined, never read, like the rows a kernel's grid did not
+    reach.  A jit with fn static: a step traces and lowers each of the
+    five loops once for its expert layers of one shape, forward and
+    replay alike (without, first_call_s +2.3 s in `dsv2`: PERF.md)."""
+    from paddle_tpu.ops.pallas_gmm import row_buffer
+
+    m = lay["row_pair"].shape[0]
+    size = min(_CHUNK_TILES * tm, m)
+    trips = -(-(lay["n_active"][0] * tm) // size)
+
+    def step(i, bufs):
+        r0 = jnp.minimum(i * size, m - size)
+        chunks = fn(lambda a: lax.dynamic_slice_in_dim(a, r0, size),
+                    k, lay, *operands)
+        return tuple(lax.dynamic_update_slice_in_dim(b, c, r0, 0)
+                     for b, c in zip(bufs, chunks))
+
+    return lax.fori_loop(0, trips, step, tuple(
+        row_buffer((m,) if w is None else (m, w), dt, impl)
+        for w, dt in outs))
+
+
+def _token_rows(rows, k, lay, x):
+    """x [N, ..] by token -> the chunk's rows, each its token's entry;
+    zero where a row holds no pair."""
+    picked = jnp.take(x, rows(lay["row_pair"]) // k, axis=0)
+    return jnp.where(rows(lay["row_live"])[:, None], picked, 0),
+
+
+def _swiglu_rows(rows, k, lay, hg, hu):
+    act = _silu_and_grad(rows(hg).astype(_F32))[0] * rows(hu).astype(_F32)
+    return act.astype(hg.dtype),
+
+
+def _cotangent_rows(rows, k, lay, gate, gf, hg, hu, ys):
+    """d ys, the SwiGLU output again, and each row's d gate: a row's
+    token's cotangent times the row's gate is d ys, and against the
+    row's expert output it is the pair's d gate."""
+    g_tok, = _token_rows(rows, k, lay, gf)
+    row_gate = jnp.where(rows(lay["row_live"]), jnp.take(
+        gate.reshape(-1), rows(lay["row_pair"])), 0.0)
+    return ((g_tok * row_gate[:, None]).astype(hg.dtype),
+            _swiglu_rows(rows, k, lay, hg, hu)[0],
+            jnp.sum(rows(ys).astype(_F32) * g_tok, axis=-1))
+
+
+def _swiglu_grad_rows(rows, k, lay, hg, hu, g_act):
+    silu, dsilu = _silu_and_grad(rows(hg).astype(_F32))
+    g = rows(g_act).astype(_F32)
+    return ((g * silu).astype(hg.dtype),
+            (g * rows(hu).astype(_F32) * dsilu).astype(hg.dtype))
+
+
+def _sum_rows(rows, k, lay, a, b):
+    return rows(a).astype(_F32) + rows(b).astype(_F32),
+
+
+@jax.jit
+def _tokens_of_rows(lay, a, gate=None):
+    """a [M, C] by row -> [N, C] float32: each token's sum over its k
+    pairs, in order, of `gate` [N, k] times the pair's row; a pair
+    whose expert is not held adds zero.  By token, not by row: a token
+    has rows in up to k groups, and the alternative is a scatter-add.
+    One gather of N rows a j: a [N, k, C] array would be laid out
+    again with k padded to a sublane tile."""
+    mine, dest = lay["mine"], lay["dest"]
+    out = None
+    for j in range(mine.shape[1]):
+        picked = jnp.where(
+            mine[:, j, None],
+            jnp.take(a, dest[:, j], axis=0).astype(_F32), 0.0)
+        if gate is not None:
+            picked = picked * gate[:, j, None]
+        out = picked if out is None else out + picked
+    return out
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
@@ -275,20 +376,22 @@ def _routed_fwd(x, gate, wg, wu, wd, lay, k, tm, impl):
     of gate * SwiGLU_e(x), [N, C] in x's dtype.  Gathers only: a row
     gather lays the tokens out by expert, three grouped matmuls run
     over the tiles that hold rows, and each token gathers its k rows
-    back."""
+    back.  Everything indexed by padded row, the kernels and the array
+    work between them (_over_live_rows), ends at the last row tile that
+    holds rows; what a row array holds past it is not defined, and
+    nothing reads it.  Only the combine is by token: k rows each."""
     from paddle_tpu.ops.pallas_gmm import gmm
 
     tg, na = lay["tile_group"], lay["n_active"]
-    xs = _gather_rows(x, lay["row_pair"] // k, lay["row_live"])
+    dt = x.dtype
+    xs, = _over_live_rows(_token_rows, ((x.shape[1], dt),), k, tm, impl,
+                          lay, x)
     hg = gmm(xs, wg, tg, na, tm, impl)
     hu = gmm(xs, wu, tg, na, tm, impl)
-    act = (_silu_and_grad(hg.astype(_F32))[0] * hu.astype(_F32)) \
-        .astype(x.dtype)
+    act, = _over_live_rows(_swiglu_rows, ((hg.shape[1], dt),), k, tm, impl,
+                           lay, hg, hu)
     ys = gmm(act, wd, tg, na, tm, impl)
-    picked = jnp.where(lay["mine"][..., None],
-                       jnp.take(ys, jnp.where(lay["mine"], lay["dest"], 0),
-                                axis=0).astype(_F32), 0.0)   # [N, k, C]
-    out = jnp.sum(picked * gate[..., None], axis=1).astype(x.dtype)
+    out = _tokens_of_rows(lay, ys, gate).astype(dt)
     return out, (x, gate, wg, wu, wd, lay, xs, hg, hu, ys)
 
 
@@ -298,32 +401,25 @@ def _routed_bwd(k, tm, impl, res, g_out):
     x, gate, wg, wu, wd, lay, xs, hg, hu, ys = res
     tg, na = lay["tile_group"], lay["n_active"]
     n_groups = wg.shape[0]
-    mine, dest = lay["mine"], jnp.where(lay["mine"], lay["dest"], 0)
-    gf = g_out.astype(_F32)
-    # d gate: each pair's expert output against the token's cotangent
-    picked = jnp.where(mine[..., None],
-                       jnp.take(ys, dest, axis=0).astype(_F32), 0.0)
-    d_gate = jnp.sum(picked * gf[:, None, :], axis=-1)
-    # d ys, by rows: the row's gate times its token's cotangent
-    row_gate = jnp.where(lay["row_live"],
-                         jnp.take(gate.reshape(-1), lay["row_pair"]), 0.0)
-    g_ys = (_gather_rows(gf, lay["row_pair"] // k, lay["row_live"])
-            * row_gate[:, None]).astype(x.dtype)
-    silu, dsilu = _silu_and_grad(hg.astype(_F32))
-    act = (silu * hu.astype(_F32)).astype(x.dtype)
+    dt = x.dtype
+    rows_c, rows_w = (x.shape[1], dt), (hg.shape[1], dt)
+    g_ys, act, row_dot = _over_live_rows(
+        _cotangent_rows, (rows_c, rows_w, (None, _F32)), k, tm, impl,
+        lay, gate, g_out.astype(_F32), hg, hu, ys)
+    d_gate = jnp.where(lay["mine"], jnp.take(row_dot, lay["dest"]), 0.0)
     d_wd = tgmm(act, g_ys, tg, na, tm, n_groups, impl)
-    g_act = gmm(g_ys, wd, tg, na, tm, impl, transpose_rhs=True) \
-        .astype(_F32)
-    g_hu = (g_act * silu).astype(x.dtype)
-    g_hg = (g_act * hu.astype(_F32) * dsilu).astype(x.dtype)
+    g_act = gmm(g_ys, wd, tg, na, tm, impl, transpose_rhs=True)
+    g_hu, g_hg = _over_live_rows(
+        _swiglu_grad_rows, (rows_w, rows_w), k, tm, impl, lay, hg, hu,
+        g_act)
     d_wg = tgmm(xs, g_hg, tg, na, tm, n_groups, impl)
     d_wu = tgmm(xs, g_hu, tg, na, tm, n_groups, impl)
-    g_xs = gmm(g_hg, wg, tg, na, tm, impl, transpose_rhs=True) \
-        .astype(_F32) + gmm(g_hu, wu, tg, na, tm, impl,
-                            transpose_rhs=True).astype(_F32)
-    d_x = jnp.sum(jnp.where(mine[..., None],
-                            jnp.take(g_xs, dest, axis=0), 0.0),
-                  axis=1).astype(x.dtype)
+    # d x: each token's rows of the float32 sum of the two products
+    g_xs, = _over_live_rows(
+        _sum_rows, ((x.shape[1], _F32),), k, tm, impl, lay,
+        gmm(g_hg, wg, tg, na, tm, impl, transpose_rhs=True),
+        gmm(g_hu, wu, tg, na, tm, impl, transpose_rhs=True))
+    d_x = _tokens_of_rows(lay, g_xs).astype(dt)
     return d_x, d_gate.astype(gate.dtype), d_wg, d_wu, d_wd, None
 
 
@@ -345,10 +441,16 @@ def moe_experts(ins, attrs):
     token is dropped: the token-expert pairs routed to held experts are
     sorted by expert and go through three grouped matmuls
     (ops/pallas_gmm.py) over row arrays of a static worst-case size
-    with run-time group sizes; a kernel's grid ends at the last row
-    tile that holds rows, and the rows past it, which nothing wrote,
-    are not read here.  impl: "" (pallas on a TPU, xla elsewhere),
-    "pallas", "interpret", "xla"; block_m: rows a tile (0: 256).
+    with run-time group sizes.  All work by padded row ends at the last
+    row tile that holds rows, n_active: a kernel's grid, and the
+    gathers, SwiGLU and sums between the kernels, which run in loops
+    over the live rows with a trip count read from n_active
+    (_over_live_rows).  What a row array holds past that is not
+    defined and not read.  There is no capacity: the worst case, every
+    pair routed to one held expert, runs the same code with longer
+    loops.  impl: "" (pallas on a TPU, xla elsewhere), "pallas",
+    "interpret", "xla", the same form for each; block_m: rows a tile
+    (0: 256).
 
     Load, float32 [G + 2]: what this execution was given, from the
     arrays that bound the kernels' grids (`_group_layout`'s sizes and

@@ -18,8 +18,12 @@ routed here); the number of tiles that hold rows, `n_active`, is a
 run-time scalar, and it is the bound of the grid's row-tile axis: a
 call's grid ends at the last tile that holds rows, and no grid step is
 spent on a tile past it.  Such a tile's output rows are never written;
-what they hold is not defined, and the caller never reads them.  No
-capacity, no dropped token, no [N, E, C] one-hot.
+what they hold is not defined, and the caller never reads them.  That
+holds for the caller's own row arrays too: moe_experts makes them with
+`row_buffer`, unwritten, and writes the live prefix [0, n_active * tm)
+in chunks (llm_ops._over_live_rows); nothing by padded row, kernel or
+array code, runs past n_active.  No capacity, no dropped token, no
+[N, E, C] one-hot.
 
 Technique after the megablox grouped matmul of jax's Pallas TPU
 examples; tile-aligned groups make the row masks and the group-metadata
@@ -196,6 +200,21 @@ def tgmm_xla(lhs, grad, tile_group, n_active, tm, n_groups):
     return jnp.einsum("tg,tkn->gkn", own.astype(jnp.float32),
                       jnp.where(live[:, None, None], per_tile, 0.0)
                       ).astype(lhs.dtype)
+
+
+def row_buffer(shape, dtype, impl):
+    """A row array nothing has written yet, for a caller that writes
+    the rows that came itself: what a grouped matmul's output is past
+    n_active, all of it.  A kernel that writes nothing, so no fill is
+    paid for rows nobody reads (in interpret mode they come back NaN,
+    as a grid's unvisited rows do); in plain XLA an empty array."""
+    if impl == "xla":
+        return jnp.empty(shape, dtype)
+    return pl.pallas_call(
+        lambda out_ref: None, name="pt_row_buffer",
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        interpret=impl == "interpret")()
 
 
 def _count_tiles(k, n):

@@ -11,6 +11,7 @@ the XLA form in another order: 1e-5.  bf16 operands round once on the
 way out: one bf16 ulp, 2^-7 relative at worst.
 """
 
+import functools
 import math
 
 import jax
@@ -353,6 +354,279 @@ def test_moe_experts_bf16_operands():
                                atol=BF16_ULP * float(jnp.abs(want).max()))
 
 
+def _idx_with_sizes(sizes, k):
+    """[N, k] expert ids, k distinct a token, that route exactly
+    sizes[e] pairs to expert e (sum(sizes) = N k)."""
+    left = np.asarray(sizes).copy()
+    idx = []
+    while left.sum():
+        picks = np.argsort(-left, kind="stable")[:k]
+        assert (left[picks] > 0).all(), "no such routing"
+        left[picks] -= 1
+        idx.append(picks)
+    return np.asarray(idx, np.int32)
+
+
+def _layout_case(layout, dtype=jnp.float32):
+    """(ins, held) at block_m 32.  one_tile: one held expert and a
+    tile's worth of pairs; sparse: 34 tiles, at most 6 live; full: all
+    experts held and every group one row into a fresh tile, 9 of 10
+    tiles live: with a chunk of 8 tiles the loop runs to M, and its
+    second chunk starts early not to pass it."""
+    if layout == "sparse":
+        held = (2, 5)
+        return _experts_case(7, n=256, e=16, k=4, held=held,
+                             dtype=dtype), held
+    if layout == "one_tile":
+        held = (2,)
+        ins = _experts_case(11, n=96, e=8, k=2, held=held, dtype=dtype)
+        assert 0 < int((np.asarray(ins["TopkIdx"]) == 2).sum()) <= 32
+        return ins, held
+    held = (0, 1, 2, 3)
+    idx = _idx_with_sizes((65, 33, 33, 33), 2)
+    ins = _experts_case(12, n=len(idx), e=4, k=2, held=held, dtype=dtype)
+    ins["TopkIdx"] = jnp.asarray(idx)
+    return ins, held
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("impl", ["interpret", "xla"])
+@pytest.mark.parametrize("layout", ["one_tile", "sparse", "full"])
+def test_moe_experts_at_three_layouts(layout, impl, dtype):
+    """Output and all five gradients against the dense loop, from one
+    live tile to (all but) every tile live: the row work between the
+    kernels runs as many chunks as the layout has live rows."""
+    from paddle_tpu.ops.llm_ops import _CHUNK_TILES, _group_layout
+
+    ins, held = _layout_case(layout, jnp.dtype(dtype))
+    lay = _group_layout(ins["TopkIdx"], held, 32)
+    tiles, live = lay["tile_group"].shape[0], int(lay["n_active"][0])
+    want_tiles = {"one_tile": (7, 1), "sparse": (34, live),
+                  "full": (10, 9)}[layout]
+    assert (tiles, live) == want_tiles and live <= 9
+    if layout == "full":        # the last chunk is the clamped one
+        assert tiles % _CHUNK_TILES and live > _CHUNK_TILES
+
+    def run(diff):
+        out = _op("moe_experts", {**ins, **diff}, held=list(held),
+                  block_m=32, impl=impl)["Out"].astype(jnp.float32)
+        return (out * jnp.sin(out)).sum(), out
+
+    def ref(diff):
+        out = _dense_experts({**ins, **diff}, held)
+        return (out * jnp.sin(out)).sum(), out
+
+    diff = {k: ins[k] for k in DIFF}
+    (_, out), grads = jax.value_and_grad(run, has_aux=True)(diff)
+    (_, want), want_grads = jax.value_and_grad(ref, has_aux=True)(diff)
+    # float32: the products' order; bf16: the roundings between the
+    # products (three forward, five more backward)
+    rtol, atol = (1e-4, 1e-5) if dtype == "float32" \
+        else (4 * BF16_ULP, 2 * BF16_ULP)
+    np.testing.assert_allclose(out, want, rtol=rtol,
+                               atol=atol * float(jnp.abs(want).max()))
+    for k in DIFF:
+        got = np.asarray(grads[k], np.float32)
+        assert np.isfinite(got).all(), k
+        scale = float(jnp.abs(want_grads[k]).max())
+        np.testing.assert_allclose(
+            got, np.asarray(want_grads[k], np.float32), rtol=rtol,
+            atol=atol * scale * (1 if dtype == "float32" else 4),
+            err_msg=k)
+
+
+def _routed_experts_over_all_rows():
+    """The routed experts as they were before their row work followed
+    n_active (PR 36's tree): every gather, SwiGLU and sum over all M
+    rows of the worst-case layout, the pairs gathered as one float32
+    [N, k, C].  What the chunked form is held to, bit for bit."""
+    from paddle_tpu.ops.llm_ops import _F32, _silu_and_grad
+    from paddle_tpu.ops.pallas_gmm import gmm, tgmm
+
+    def gather_rows(x, index, live):
+        return jnp.where(live[:, None], jnp.take(x, index, axis=0), 0)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+    def routed(x, gate, wg, wu, wd, lay, k, tm, impl):
+        return fwd(x, gate, wg, wu, wd, lay, k, tm, impl)[0]
+
+    def fwd(x, gate, wg, wu, wd, lay, k, tm, impl):
+        tg, na = lay["tile_group"], lay["n_active"]
+        xs = gather_rows(x, lay["row_pair"] // k, lay["row_live"])
+        hg = gmm(xs, wg, tg, na, tm, impl)
+        hu = gmm(xs, wu, tg, na, tm, impl)
+        act = (_silu_and_grad(hg.astype(_F32))[0] * hu.astype(_F32)) \
+            .astype(x.dtype)
+        ys = gmm(act, wd, tg, na, tm, impl)
+        picked = jnp.where(
+            lay["mine"][..., None],
+            jnp.take(ys, jnp.where(lay["mine"], lay["dest"], 0),
+                     axis=0).astype(_F32), 0.0)
+        out = jnp.sum(picked * gate[..., None], axis=1).astype(x.dtype)
+        return out, (x, gate, wg, wu, wd, lay, xs, hg, hu, ys)
+
+    def bwd(k, tm, impl, res, g_out):
+        x, gate, wg, wu, wd, lay, xs, hg, hu, ys = res
+        tg, na = lay["tile_group"], lay["n_active"]
+        n_groups = wg.shape[0]
+        mine, dest = lay["mine"], jnp.where(lay["mine"], lay["dest"], 0)
+        gf = g_out.astype(_F32)
+        picked = jnp.where(mine[..., None],
+                           jnp.take(ys, dest, axis=0).astype(_F32), 0.0)
+        d_gate = jnp.sum(picked * gf[:, None, :], axis=-1)
+        row_gate = jnp.where(lay["row_live"], jnp.take(
+            gate.reshape(-1), lay["row_pair"]), 0.0)
+        g_ys = (gather_rows(gf, lay["row_pair"] // k, lay["row_live"])
+                * row_gate[:, None]).astype(x.dtype)
+        silu, dsilu = _silu_and_grad(hg.astype(_F32))
+        act = (silu * hu.astype(_F32)).astype(x.dtype)
+        d_wd = tgmm(act, g_ys, tg, na, tm, n_groups, impl)
+        g_act = gmm(g_ys, wd, tg, na, tm, impl, transpose_rhs=True) \
+            .astype(_F32)
+        g_hu = (g_act * silu).astype(x.dtype)
+        g_hg = (g_act * hu.astype(_F32) * dsilu).astype(x.dtype)
+        d_wg = tgmm(xs, g_hg, tg, na, tm, n_groups, impl)
+        d_wu = tgmm(xs, g_hu, tg, na, tm, n_groups, impl)
+        g_xs = gmm(g_hg, wg, tg, na, tm, impl, transpose_rhs=True) \
+            .astype(_F32) + gmm(g_hu, wu, tg, na, tm, impl,
+                                transpose_rhs=True).astype(_F32)
+        d_x = jnp.sum(jnp.where(mine[..., None],
+                                jnp.take(g_xs, dest, axis=0), 0.0),
+                      axis=1).astype(x.dtype)
+        return d_x, d_gate.astype(gate.dtype), d_wg, d_wu, d_wd, None
+
+    routed.defvjp(fwd, bwd)
+    return routed
+
+
+@pytest.mark.parametrize("layout", ["sparse", "full"])
+def test_moe_experts_row_chunks_keep_the_arithmetic(layout):
+    """Float32 on the CPU: Out, d x and the three weight gradients are
+    the all-rows form's to the last bit (the same rows, products and
+    sums in the same order); d gate, whose sum over C moved from the
+    pairs to the rows, to 1e-6.  One primitive at a time
+    (disable_jit): inside a jit the CPU compiler contracts a product
+    and a sum into one rounding where a fusion lets it, and the two
+    forms fuse differently (d W_gate moved by 7e-8 of its largest
+    entry under jit)."""
+    from paddle_tpu.ops.llm_ops import _group_layout, _routed_experts
+
+    ins, held = _layout_case(layout)
+    k = ins["TopkIdx"].shape[1]
+    lay = _group_layout(ins["TopkIdx"], held, 32)
+    args = (ins["X"], ins["TopkWeight"], ins["WGate"], ins["WUp"],
+            ins["WDown"])
+
+    def run(routed):
+        def loss(*a):
+            out = routed(*a, lay, k, 32, "xla")
+            return (out * jnp.sin(out)).sum(), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+        return (out,) + grads
+
+    with jax.disable_jit():
+        got = run(_routed_experts)
+        want = run(_routed_experts_over_all_rows())
+    for name, a, b in zip(("Out",) + DIFF, got, want):
+        if name == "TopkWeight":
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6 * float(
+                jnp.abs(b).max()), err_msg=name)
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def _eqns_outside_loops(jaxpr, derived):
+    """(equation, derived) for every equation of a jaxpr that is not in
+    a `while` body, looking through call-like equations (pjit,
+    custom_vjp_call, ...); `derived(var)` says whether a variable
+    depends on the marked inputs."""
+    known = set(v for v in jaxpr.invars if derived(v))
+
+    def dep(v):
+        return not isinstance(v, jax.extend.core.Literal) and v in known
+
+    for eqn in jaxpr.eqns:
+        if any(dep(v) for v in eqn.invars):
+            known.update(eqn.outvars)
+        subs = [] if eqn.primitive.name in ("while", "pallas_call") \
+            else list(jax.core.jaxprs_in_params(eqn.params))
+        if not subs:
+            yield eqn, dep
+        for sub in subs:
+            marked = {b for a, b in zip(eqn.invars, sub.invars) if dep(a)} \
+                if len(sub.invars) == len(eqn.invars) else \
+                set(sub.invars if any(dep(v) for v in eqn.invars) else ())
+            yield from _eqns_outside_loops(sub, marked.__contains__)
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+def test_moe_experts_row_work_is_bounded_by_n_active(pass_):
+    """Beside test_gmm_grid_is_bounded_by_n_active: at a sparse layout
+    (34 tiles, at most 6 live) nothing outside a loop yields an array
+    of M rows but a kernel, an unwritten buffer (a kernel too) and the
+    loops themselves, and every loop's condition reads a value derived
+    from n_active.  Code that goes back to walking the worst case over
+    all rows fails here.  And no [N, k, C] array is formed."""
+    from paddle_tpu.ops.llm_ops import _group_layout, _routed_experts
+
+    ins, held = _layout_case("sparse")
+    n, k = ins["TopkIdx"].shape
+    c = ins["X"].shape[1]
+    lay = _group_layout(ins["TopkIdx"], held, 32)
+    m = lay["row_pair"].shape[0]
+    assert m == 34 * 32 and m not in (n, n * k, c, ins["WGate"].shape[2])
+    args = (ins["X"], ins["TopkWeight"], ins["WGate"], ins["WUp"],
+            ins["WDown"])
+
+    def forward(n_active, *a):
+        return _routed_experts(*a, {**lay, "n_active": n_active}, k, 32,
+                               "interpret")
+
+    def backward(n_active, *a):
+        out, vjp = jax.vjp(lambda *a: forward(n_active, *a), *a)
+        return vjp(out)
+
+    jaxpr = jax.make_jaxpr(forward if pass_ == "forward" else backward)(
+        lay["n_active"], *args).jaxpr
+    n_active = jaxpr.invars[0]
+    loops = kernels = 0
+    for eqn, derived in _eqns_outside_loops(jaxpr, lambda v: v is n_active):
+        name = eqn.primitive.name
+        if name == "while":
+            loops += 1
+            # the loop's condition reads the trip count, and that is
+            # computed from n_active
+            n_cond = eqn.params["cond_nconsts"]
+            n_body = eqn.params["body_nconsts"]
+            cond = eqn.params["cond_jaxpr"].jaxpr
+            read = {v for e in cond.eqns for v in e.invars
+                    if not isinstance(v, jax.extend.core.Literal)}
+            operands = eqn.invars[:n_cond] + eqn.invars[n_cond + n_body:]
+            assert any(derived(a) for a, b in zip(operands, cond.invars)
+                       if b in read), eqn
+            continue
+        kernels += name == "pallas_call"
+        for v in eqn.outvars:
+            shape = getattr(v.aval, "shape", ())
+            assert name == "pallas_call" or not shape or shape[0] != m, \
+                "%s yields %s outside a loop" % (name, v.aval)
+    # forward: the gather and SwiGLU loops, their 2 buffers, 3 grouped
+    # matmuls; backward: those again (the vjp runs the forward), then
+    # three loops over 6 buffers and 6 grouped matmuls
+    assert (loops, kernels) == ((2, 5) if pass_ == "forward" else (5, 17))
+    for eqn in _all_eqns(jaxpr):
+        for v in eqn.outvars:
+            assert getattr(v.aval, "shape", ()) != (n, k, c), eqn
+
+
 # (tile_group, n_active): a layout with a dead tail, and a sparse one,
 # 2 of 40 tiles live.  As _group_layout makes them: every group has a
 # live tile, and the tail carries the last group's id.
@@ -452,6 +726,10 @@ def test_gmm_grid_is_bounded_by_n_active(kernel):
     assert not isinstance(mapping.grid[axis], int)
 
 
+def _plus_one_and_row_sums(rows, k, lay, a):
+    return rows(a) + 1, rows(a).sum(-1)
+
+
 def test_moe_experts_reads_no_row_past_the_live_tiles():
     """Far more tiles than live ones (34 for about 4): in interpret mode
     the rows past the grid's bound come back NaN, so finite outputs and
@@ -488,6 +766,21 @@ def test_moe_experts_reads_no_row_past_the_live_tiles():
     tail = pg.gmm_pallas(xs, ins["WGate"], lay["tile_group"],
                          lay["n_active"], 32, interpret=True)[live * 32:]
     assert np.isnan(np.asarray(tail)).all()
+    # and so are the op's own row arrays: made unwritten, written one
+    # chunk of row tiles (all the live rows fit it), the rest left
+    from paddle_tpu.ops.llm_ops import _CHUNK_TILES, _over_live_rows
+
+    assert np.isnan(np.asarray(
+        pg.row_buffer((4, 8), jnp.bfloat16, "interpret"), np.float32)).all()
+    rows, dots = _over_live_rows(
+        _plus_one_and_row_sums, ((128, jnp.float32), (None, jnp.float32)),
+        4, 32, "interpret", lay, xs)
+    written = _CHUNK_TILES * 32
+    assert live * 32 <= written < tiles * 32
+    assert (np.asarray(rows[:written]) == 2).all()
+    assert (np.asarray(dots[:written]) == 128).all()
+    assert np.isnan(np.asarray(rows[written:])).all()
+    assert np.isnan(np.asarray(dots[written:])).all()
 
 
 # -- hyper-connections ---------------------------------------------------------

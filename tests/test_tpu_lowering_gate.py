@@ -68,6 +68,11 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         # (three forward and their replay, three and three backward)
         assert [detail["kernel_calls"][k] for k in (
             "pt_gmm_fwd", "pt_gmm_bwd_dx", "pt_gmm_bwd_dw")] == [24, 12, 12]
+        # and nothing else of the step by padded row stands outside the
+        # loops over the live rows (ISSUE 37): 14 tiles of 256 here
+        assert workload in chip_gate.ROW_WORK_IN_LOOPS
+        assert detail["rows_outside_loops"] == 0
+        assert detail["kernel_calls"]["pt_row_buffer"] == 40
 
 
 def test_kernel_calls_counts_mosaic_calls_by_kernel_name():
@@ -86,6 +91,44 @@ ENTRY %main {
 """
     assert kernel_calls(text) == {"pt_flash_fwd": 2, "pt_flash_bwd_dkv": 1,
                                   "pt_gmm_fwd": 1}
+
+
+def test_rows_outside_loops_counts_float_row_arrays_not_in_a_while():
+    """The reader itself, on text: fusions, gathers and copies of a
+    float [rows, width] array count unless a `while` body, or a
+    computation one calls, holds them; kernels, loops, index vectors
+    and other row counts do not."""
+    from tools.tpu_lowering_check import rows_outside_loops
+
+    text = """HloModule m
+%fused_computation.1 (p: bf16[512,64]) -> bf16[512,64] {
+  ROOT %g = bf16[512,64]{1,0} gather(%p, %i), offset_dims={1}
+}
+
+%helper.2 (p: bf16[512,64]) -> bf16[512,64] {
+  ROOT %fusion.9 = bf16[512,64]{1,0} fusion(%p), kind=kLoop, calls=%fused_computation.1
+}
+
+%body.3 (t: (s32[], bf16[512,64])) -> (s32[], bf16[512,64]) {
+  %fusion.4 = bf16[512,64]{1,0:T(8,128)(2,1)} fusion(%x), kind=kLoop, calls=%fused_computation.1
+  %call.5 = bf16[512,64]{1,0} call(%fusion.4), to_apply=%helper.2
+  ROOT %tuple = (s32[], bf16[512,64]{1,0}) tuple(%i, %call.5)
+}
+
+ENTRY %main (a: bf16[64,64]) -> bf16[512,64] {
+  %pt_gmm_fwd.1 = bf16[512,64]{1,0} custom-call(%a), custom_call_target="tpu_custom_call"
+  %while.6 = (s32[], bf16[512,64]{1,0}) while(%init), condition=%cond.7, body=%body.3
+  %fusion.8 = bf16[512,64]{1,0:T(8,128)(2,1)} fusion(%pt_gmm_fwd.1), kind=kLoop, calls=%fused_computation.1
+  %fusion.10 = (f32[512,64]{1,0}, f32[512]{0}) fusion(%fusion.8), kind=kLoop, calls=%fused_computation.1
+  %copy.11 = f32[512,64]{0,1} copy(%gte)
+  %fusion.12 = s32[512,1]{0,1} fusion(%idx), kind=kLoop, calls=%fused_computation.1
+  %fusion.13 = bf16[256,64]{1,0} fusion(%a), kind=kLoop, calls=%fused_computation.1
+  ROOT %gather.14 = bf16[512,64]{1,0} gather(%copy.11, %idx), offset_dims={1}
+}
+"""
+    assert rows_outside_loops(text, 512) == 4       # 8, 10, 11, 14
+    assert rows_outside_loops(text, 256) == 1
+    assert rows_outside_loops(text, 64) == 0
 
 
 @pytest.mark.parametrize("workload", ["longctx_train_hp2"])

@@ -20,7 +20,11 @@ run.py cannot: the `run` records of the program's step record
 its host phases (`returned - committed` is the wait for the loss).
 Written to --out after every run: side, cell, seed, trace, exit code,
 wall seconds, the result line, the harness's `correctness`, `setup` and
-`trace` lines and per-step times, and the step record's phases, ms.
+`trace` lines and per-step times, the step record's phases, ms, and the
+program's stat rings (observability/step_stats.py: a row a step of what
+the compiled step said of itself; the last rows are the window's, or
+the traced stretch's), so that a step's time can be laid beside the
+live row tiles its expert layers held.
 """
 
 import time
@@ -65,6 +69,15 @@ def _child(checkout, cell, seed, trace, seconds, out):
         runs = [r for r in step_record.records("run") if r.get("fetched")]
     except ImportError:
         runs = []
+    try:        # a parent from before the stat rings has none
+        from paddle_tpu.observability import step_stats
+
+        record["step_stats"] = {
+            name: {"columns": list(s["columns"]),
+                   "steps": s["steps"].tolist(), "rows": s["rows"].tolist()}
+            for name, s in step_stats.read().items()}
+    except ImportError:
+        pass
     record["enter_s"] = [r["enter"] / 1e9 for r in runs if "enter" in r]
     for name, a, b in PHASES:
         record[name + "_ms"] = [(r[b] - r[a]) / 1e6 for r in runs

@@ -375,6 +375,51 @@ def kernel_calls(hlo_text):
         r'custom_call_target="tpu_custom_call"', hlo_text, re.M)))
 
 
+# the steps with expert layers -> the rows M of `_group_layout`'s
+# worst-case layout, (ceil(N k / 256) + held) x 256: of the work by
+# padded row only the kernels may stand outside moe_experts' loops over
+# the live rows (PR 37)
+ROW_WORK_IN_LOOPS = {"dsv2_train": 51200, "dsv2_train_tiny": 3584}
+
+# memory_analysis() bytes (argument + output + temp - alias) a step may
+# take.  dsv2_train: PR 37 reads 9,613,623,296 (PR 36: 9,429,541,888;
+# the live peak fell, the compiler's packing of it rose, and moves by
+# megabytes with the order of the step's ops: PERF.md)
+STEP_BYTES_MAX = {"dsv2_train": 9_650_000_000}
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", re.M)
+_CALLED = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+
+
+def rows_outside_loops(hlo_text, rows):
+    """`fusion`, `gather` and `copy` instructions of a compiled module
+    that yield a float array of `rows` rows ([rows, width]) and are NOT
+    in a `while` body or a computation one calls: array work that walks
+    the worst-case layout of an expert layer whatever is live.  Before
+    PR 37 the `dsv2` step held the gathers, SwiGLU and sums round the
+    grouped matmuls so, 51,200 rows each.  Kernels (custom calls) and
+    the loops themselves do not count; nor do the layout's index
+    vectors (integers)."""
+    heads = list(_COMPUTATION.finditer(hlo_text))
+    bodies = {m.group(1): hlo_text[m.end():(
+        heads[i + 1].start() if i + 1 < len(heads) else len(hlo_text))]
+        for i, m in enumerate(heads)}
+    looped = set(re.findall(r"body=%?([\w.\-]+)", hlo_text))
+    todo = list(looped)
+    while todo:
+        for callee in _CALLED.findall(bodies.get(todo.pop(), "")):
+            if callee not in looped:
+                looped.add(callee)
+                todo.append(callee)
+    row_array = re.compile(
+        r"^\s*(?:ROOT )?%%?[\w.\-]+ = [^=\n]*?(?:bf16|f16|f32)\[%d,\d+"
+        r"[^=\n]*? (?:fusion|gather|copy)\(" % rows, re.M)
+    return sum(len(row_array.findall(body))
+               for name, body in bodies.items()
+               if name not in looped
+               and not name.startswith("fused_computation"))
+
+
 def check_workload(name, build):
     """Build the gate program and compile its jitted step for the
     described chip.  Returns (ok, detail, seconds); detail of a compile
@@ -384,7 +429,10 @@ def check_workload(name, build):
     `kernel_calls` and the program's `flash_ops`, which fail it unless
     `pt_flash_fwd` is called once an op (a recompute segment that
     replays the op holds a second call: 10 for 5 in `xing4`, 48 for 24
-    in `ouro` before PR 33)."""
+    in `ouro` before PR 33); for the ROW_WORK_IN_LOOPS steps
+    `rows_outside_loops`, which fails the workload unless it is 0, and
+    for the STEP_BYTES_MAX steps `step_bytes`, which fails it above
+    the limit."""
     t0 = time.time()
     # Force the Pallas path during tracing: impl auto-detection sees a
     # CPU device in this process, but the program we must validate is
@@ -426,6 +474,14 @@ def check_workload(name, build):
             detail["kernel_calls"] = kernel_calls(text)
             ok &= detail["kernel_calls"].get("pt_flash_fwd") \
                 == detail["flash_ops"] > 0
+        if name in ROW_WORK_IN_LOOPS:
+            detail["rows_outside_loops"] = rows_outside_loops(
+                text, ROW_WORK_IN_LOOPS[name])
+            ok &= not detail["rows_outside_loops"]
+        if name in STEP_BYTES_MAX:
+            detail["step_bytes"] = detail["temp"] + detail["argument"] \
+                + detail["output"] - detail["alias"]
+            ok &= detail["step_bytes"] <= STEP_BYTES_MAX[name]
         return ok, detail, time.time() - t0
     except Exception as e:  # noqa: BLE001 — report, don't crash the sweep
         msg = "%s: %s" % (type(e).__name__, str(e)[:400])
