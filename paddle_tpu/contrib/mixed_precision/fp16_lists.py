@@ -42,6 +42,10 @@ white_list = {
     # in bf16; every coefficient (norm, projections, Sinkhorn) is
     # computed and kept in float32 inside them (fp16_utils)
     "mhc_pre", "mhc_post",
+    # the chunked state-space scan (ops/pallas_ssd.py): bf16 X, B and C
+    # on the MXU; Dt, A, D, the decays, the running state and the saved
+    # chunk states stay float32 (fp16_utils)
+    "ssd_scan",
 }
 
 # numerically sensitive: keep fp32
@@ -75,6 +79,9 @@ gray_list = {
 follow_x_list = {
     "batch_norm", "sync_batch_norm", "layer_norm", "group_norm",
     "instance_norm", "data_norm", "rms_norm",
+    # float32 inside, Y in X's dtype, whatever the filter's, the
+    # gate's or the scale's is
+    "causal_conv1d", "gated_rms_norm",
 }
 
 

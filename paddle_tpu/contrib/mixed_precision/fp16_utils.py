@@ -30,6 +30,10 @@ _WHITE_KEEP_FP32 = {
     # and the coefficients mhc_pre hands mhc_post: float32 end to end
     "mhc_pre": frozenset({"NormScale", "Phi", "Alpha", "Bias"}),
     "mhc_post": frozenset({"HPost", "HRes"}),
+    # the step sizes, the decay rates and the skip weights of a
+    # state-space scan: exp(Dt A) over thousands of tokens in bfloat16
+    # is another recurrence
+    "ssd_scan": frozenset({"Dt", "A", "D"}),
 }
 
 # white-list ops with multiple outputs where only SOME are emitted in
@@ -44,6 +48,8 @@ _WHITE_LOWP_OUT = {
     "mhc_pre": frozenset({"U"}),
     # Load is a float32 count, whatever the rows' dtype
     "moe_experts": frozenset({"Out"}),
+    # States, the running state at each chunk's start, is float32
+    "ssd_scan": frozenset({"Y"}),
 }
 
 

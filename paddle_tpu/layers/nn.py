@@ -975,14 +975,20 @@ def sequence_mask(x, maxlen=None, dtype="int64", name=None):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, name=None, n_head=None):
+                    block_k=None, name=None, n_head=None, n_kv_head=None):
     """Fused blockwise attention (Pallas TPU kernel; ops/pallas_kernels.py).
 
     q/k/v: [B, H, T, D] post-split-heads, or, with n_head, token-major
     [B, T, H*D] as the q, k and v projections leave them: Out is then
     [B, Tq, H*D], what the output projection takes, and no head split
     or merge is made (where the kernels cannot address the heads in
-    place the op transposes inside, to the same answer).  Replaces the
+    place the op transposes inside, to the same answer).  k and v may
+    have fewer heads than q, n_kv_head (grouped-query attention: query
+    head h reads KV head h // (n_head / n_kv_head)): [B, n_kv_head, T,
+    D] or token-major [B, T, n_kv_head*D].  The op reads the count off
+    k's shape and the kernels read K and V in place, never repeated to
+    n_head heads; n_kv_head here only checks k against it.  scale: the
+    factor on q.k before the softmax, default D^-1/2.  Replaces the
     reference's matmul+softmax+matmul composition (nets.py
     scaled_dot_product_attention) with a single kernel that never
     materializes the [Tq, Tk] score matrix.  block_q/block_k override
@@ -994,6 +1000,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     (float32 [B, H, Tq], no gradient): the residual flash_attention_grad
     reads, with Out, instead of running the forward kernel again.
     """
+    if n_kv_head is not None:
+        heads = int(n_head or q.shape[1])
+        have = int(k.shape[1]) if not n_head \
+            else int(k.shape[-1]) // (int(q.shape[-1]) // heads)
+        if have != n_kv_head or heads % n_kv_head:
+            raise ValueError(
+                "flash_attention: k has %d heads of q's size, n_kv_head "
+                "says %d (of %d query heads)" % (have, n_kv_head, heads))
     helper = LayerHelper("flash_attention")
     out = helper.create_variable_for_type_inference(q.dtype)
     lse = helper.create_variable_for_type_inference("float32", True)

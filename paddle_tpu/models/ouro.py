@@ -8,7 +8,8 @@ docs/OURO_BLOCK.md writes the equations out; models/ouro_reference.py
 is the plain float32 reference of the same equations.
 
 A layer: RMSNorm, full causal attention (16 heads of 128 at the
-published size, no bias, split-half rotary), RMSNorm of what attention
+published size, no bias, split-half rotary; k and v at
+`num_key_value_heads` heads where a configuration has fewer), RMSNorm of what attention
 gives, residual; RMSNorm, SwiGLU, RMSNorm, residual: four norms.  After
 the last layer of a pass ONE final norm gives h^r, which the head and
 the gate read and from which pass r + 1 starts.
@@ -51,11 +52,7 @@ def ouro_model(config, seq_len, param_prefix="ouro"):
     pass's logits live only inside its head segment."""
     c, heads, d = (config["hidden_size"], config["num_attention_heads"],
                    config["head_dim"])
-    if config.get("num_key_value_heads", heads) != heads:
-        raise NotImplementedError(
-            "ouro_model: num_key_value_heads %r != num_attention_heads "
-            "%d (grouped KV heads)" % (config["num_key_value_heads"],
-                                       heads))
+    kv_heads = config.get("num_key_value_heads") or heads
     if config.get("rope_scaling"):
         raise NotImplementedError("ouro_model: rope_scaling %r"
                                   % (config["rope_scaling"],))
@@ -77,18 +74,21 @@ def ouro_model(config, seq_len, param_prefix="ouro"):
     def norm(x, name):
         return layers.rms_norm(x, eps, name="%s_%s" % (p, name))
 
-    def rotary(x):
+    def rotary(x, n):
         x = layers.rotary_embedding(
-            layers.reshape(x, [-1, seq_len, heads, d]),
+            layers.reshape(x, [-1, seq_len, n, d]),
             theta=config["rope_theta"], pairing="halves")
-        return layers.reshape(x, [-1, seq_len, heads * d])
+        return layers.reshape(x, [-1, seq_len, n * d])
 
     def layer(x, lp):
         a = norm(x, lp + "_norm1")
+        # k and v at num_key_value_heads heads: the kernels read a
+        # query head's KV head in place (grouped-query attention)
         o = layers.flash_attention(
-            rotary(fc(a, heads * d, lp + "_q")),
-            rotary(fc(a, heads * d, lp + "_k")),
-            fc(a, heads * d, lp + "_v"), causal=True, n_head=heads)
+            rotary(fc(a, heads * d, lp + "_q"), heads),
+            rotary(fc(a, kv_heads * d, lp + "_k"), kv_heads),
+            fc(a, kv_heads * d, lp + "_v"), causal=True, n_head=heads,
+            n_kv_head=kv_heads)
         x = layers.elementwise_add(x, norm(fc(o, c, lp + "_o"),
                                            lp + "_norm2"))
         m = norm(x, lp + "_norm3")

@@ -130,8 +130,13 @@ _F32_SUBLANES = 8  # f32 min sublane tile — gates the packed-stats block
 # ---------------------------------------------------------------------------
 
 def _plain_attention(q, k, v, causal, scale, with_lse=False):
-    """q/k/v: [B, H, T, D].  with_lse: also the log-sum-exp of each
-    row of the scaled, masked scores, float32 [B, H, Tq]."""
+    """q/k/v: [B, H, T, D]; k and v may have H / group heads, and are
+    then repeated to H (what the kernels never do).  with_lse: also
+    the log-sum-exp of each row of the scaled, masked scores, float32
+    [B, H, Tq]."""
+    if k.shape[1] != q.shape[1]:
+        group = q.shape[1] // _kv_heads(q, k, None)
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     p = None
@@ -233,7 +238,7 @@ def _stat_rows(ref, h, block_q, packed):
     return ref[h, :, 0]
 
 
-def _head_tile(ref, h, hpb, token_major):
+def _head_tile(ref, h, hpb, token_major, block=None):
     """Head-slot h's [rows, width] tile of a q/k/v/dO block.
 
     Head-major, the block is [hpb, rows, d] and the tile its h-th
@@ -245,10 +250,13 @@ def _head_tile(ref, h, hpb, token_major):
     head h's result in its lanes and exact zeros in the others, so the
     tiles of a block's heads ADD to the block.  On a 128 x 128 array a
     64-deep contraction and a 64-wide output cost a full pass already
-    (PERF.md section 5)."""
+    (PERF.md section 5).
+
+    block: the lane block to cut the tile from in place of ref's, for
+    the K and V of grouped heads (`_kv_blocks`)."""
     if not token_major:
         return ref[h]
-    x = ref[0]
+    x = ref[0] if block is None else block
     if hpb == 1:
         return x
     lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
@@ -257,16 +265,52 @@ def _head_tile(ref, h, hpb, token_major):
     return jnp.where(mine, x, jnp.zeros_like(x))
 
 
+def _kv_slot(hpb, token_major, group, q_blocks):
+    """Which head slot of ITS lane block holds the KV head that this
+    grid step's query heads read, or None where a head's K and V lie
+    where its Q does: equal head counts, and every layout with one head
+    a step, where the BlockSpec index map alone picks the KV head
+    (`_Tiles.spec`, kv=True).  Token-major at two heads a lane block
+    (D = 64) and an even group, the block's query heads read the SAME
+    KV head, (g % q_blocks) * hpb // group.  Read at the kernel's top:
+    a grid index is not to be had inside a `pl.when` body."""
+    if group == 1 or not token_major or hpb == 1:
+        return None
+    return ((pl.program_id(0) % q_blocks) * hpb // group) % hpb
+
+
+def _kv_blocks(k_ref, v_ref, hpb, slot):
+    """The step's K and V lane blocks with the KV head of `_kv_slot`
+    in every head slot of the lanes: one lane rotation and a select a
+    step, so that `_head_tile` cuts head slot h's K and V out of the
+    lanes its Q lies in.  K and V are read where they are: no [.., H,
+    D] copy of them exists in HBM.  (None, None) without a slot."""
+    if slot is None:
+        return None, None
+    k, v = k_ref[0], v_ref[0]
+    d = k.shape[1] // hpb
+    here = lax.broadcasted_iota(jnp.int32, k.shape, 1) // d == slot
+
+    def both(x):
+        # Mosaic rotates 32-bit lanes only; the round trip is exact
+        turned = pltpu.roll(x.astype(jnp.float32), d, 1).astype(x.dtype)
+        return jnp.where(here, x, turned)
+
+    return both(k), both(v)
+
+
 # ---------------------------------------------------------------------------
 # pallas forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                 l_ref, *, scale, causal, block_q, block_k, kv_len,
-                q_off, packed, hpb, token_major=False):
+                q_off, packed, hpb, token_major=False, group=1,
+                q_blocks=1):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    kv_slot = _kv_slot(hpb, token_major, group, q_blocks)
 
     @pl.when(ki == 0)
     def _init():
@@ -303,10 +347,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         # the heads are independent dependency chains — the scheduler
         # interleaves their MXU and VPU work within the step (the whole
         # point of hpb=2 at d<=64)
+        kb, vb = _kv_blocks(k_ref, v_ref, hpb, kv_slot)
         for h in range(hpb):
             q = _head_tile(q_ref, h, hpb, token_major)    # [bq, d]
-            k = _head_tile(k_ref, h, hpb, token_major)    # [bk, d]
-            v = _head_tile(v_ref, h, hpb, token_major)
+            k = _head_tile(k_ref, h, hpb, token_major, kb)  # [bk, d]
+            v = _head_tile(v_ref, h, hpb, token_major, vb)
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
             if masked:
@@ -384,12 +429,28 @@ def _pad_axis(x, axis, mult):
 
 def _dims(q, k, v, heads):
     """(b, h, tq, tk, d, dv) of head-major [B, H, T, D] operands, or,
-    with a head count, of token-major [B, T, H*D] ones."""
+    with a head count, of token-major [B, T, H*D] ones.  h is the
+    QUERY heads' count; K and V may have fewer (`_kv_heads`)."""
     if heads is None:
         b, h, tq, d = q.shape
         return b, h, tq, k.shape[2], d, v.shape[3]
     b, tq, width = q.shape
-    return b, heads, tq, k.shape[1], width // heads, v.shape[2] // heads
+    d = width // heads
+    return b, heads, tq, k.shape[1], d, v.shape[2] // (k.shape[2] // d)
+
+
+def _kv_heads(q, k, heads):
+    """The KV heads' count, read off K: its head axis, or token-major
+    its width over the head size.  The query heads' count is a whole
+    multiple of it (grouped-query attention: query head h reads KV
+    head h // group)."""
+    h = q.shape[1] if heads is None else heads
+    hkv = k.shape[1] if heads is None else k.shape[2] // (q.shape[2] // h)
+    if hkv < 1 or h % hkv:
+        raise ValueError(
+            "flash_attention: %d query heads are no whole multiple of "
+            "the %d KV heads" % (h, hkv))
+    return hkv
 
 
 def _block_geometry(q, k, v, block_q, block_k, packed_stats, head_pack,
@@ -407,7 +468,10 @@ def _block_geometry(q, k, v, block_q, block_k, packed_stats, head_pack,
     if heads is not None:
         hpb = _MIN_LANES // d
     else:
-        hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)) else 1
+        # grouped KV heads, head-major: one head a step, so that the
+        # index map alone picks the step's KV head
+        hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)
+                    and _kv_heads(q, k, heads) == h) else 1
     return dims, bq, bk, packed, hpb
 
 
@@ -423,29 +487,47 @@ class _Tiles:
     is, and step g takes the block (1, rows, 128) at batch g // n,
     lane block g % n of the n = H*D/128 a batch has, which holds the
     same hpb = 128/D heads g*hpb ... of the flattened axis.  The row
-    statistics are [B*H, T, 128] on both."""
+    statistics are [B*H, T, 128] on both.
 
-    def __init__(self, dims, hpb, token_major):
+    Grouped KV heads (`group` query heads read one KV head: K and V
+    have H / group heads): the grid stays the QUERY heads', and a
+    step's K and V block is its heads' KV head's, found by the index
+    map (`spec(.., kv=True)`): head-major, one head a step, the row
+    b * H/group + h // group of the flattened [B*H/group, T, D];
+    token-major the lane block that holds KV head g*hpb // group.  So
+    K and V are read where they lie and no copy of them at H heads is
+    made.  dk and dv leave the kernels a query head (`shape`, the
+    plain `spec`)."""
+
+    def __init__(self, dims, hpb, token_major, group=1):
         self.b, self.h = dims[:2]
-        self.hpb, self.token_major = hpb, token_major
+        self.hpb, self.token_major, self.group = hpb, token_major, group
 
     def operand(self, x, block_rows):
         """x as the kernels take it, rows padded to whole blocks."""
         if not self.token_major:
-            x = x.reshape(self.b * self.h, *x.shape[2:])
+            x = x.reshape(x.shape[0] * x.shape[1], *x.shape[2:])
         return _pad_axis(x, 1, block_rows)
 
-    def spec(self, rows, width, row_block):
+    def spec(self, rows, width, row_block, kv=False):
         """BlockSpec of the (rows, width) tiles; row_block(i, j) is the
-        row block at the grid's two inner indices."""
+        row block at the grid's two inner indices.  kv: of K or V."""
+        h, hpb = self.h, self.hpb
+        group = self.group if kv else 1
         if not self.token_major:
+            if group == 1:
+                return pl.BlockSpec(
+                    (hpb, rows, width),
+                    lambda g, i, j: (g, row_block(i, j), 0))
             return pl.BlockSpec(
-                (self.hpb, rows, width),
-                lambda g, i, j: (g, row_block(i, j), 0))
-        n = self.h // self.hpb
+                (1, rows, width),
+                lambda g, i, j: (g // h * (h // group) + g % h // group,
+                                 row_block(i, j), 0))
+        n = h // hpb
         return pl.BlockSpec(
-            (1, rows, self.hpb * width),
-            lambda g, i, j: (g // n, row_block(i, j), g % n))
+            (1, rows, hpb * width),
+            lambda g, i, j: (g // n, row_block(i, j),
+                             g % n * hpb // group // hpb))
 
     def shape(self, rows, width):
         if not self.token_major:
@@ -527,12 +609,14 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     latent attention, whose q.k size is 192 and v size 128) ->
     ([B, H, Tq, Dv], lse [B*H, Tq_padded]).  With `heads`, the three
     and the output are token-major [B, T, H*D] (`_Tiles`; the entries
-    send only what `_flash_layout` passed)."""
+    send only what `_flash_layout` passed).  k and v may have fewer
+    heads than q, H / group (`_kv_heads`)."""
     token_major = heads is not None
     dims, bq, bk, packed, hpb = _block_geometry(
         q, k, v, block_q, block_k, packed_stats, head_pack, heads)
     b, h, tq, tk, d, dv = dims
-    tiles = _Tiles(dims, hpb, token_major)
+    group = h // _kv_heads(q, k, heads)
+    tiles = _Tiles(dims, hpb, token_major, group)
     qp, kp, vp = tiles.operand(q, bq), tiles.operand(k, bk), \
         tiles.operand(v, bk)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
@@ -541,7 +625,8 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         kv_len=tk, q_off=tk - tq if causal else 0, packed=packed,
-        hpb=hpb, token_major=token_major)
+        hpb=hpb, token_major=token_major, group=group,
+        q_blocks=h // hpb)
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
@@ -562,8 +647,8 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         grid=grid,
         in_specs=[
             tiles.spec(bq, d, _first),
-            tiles.spec(bk, d, _second),
-            tiles.spec(bk, dv, _second),
+            tiles.spec(bk, d, _second, kv=True),
+            tiles.spec(bk, dv, _second, kv=True),
         ],
         out_specs=[
             tiles.spec(bq, dv, _first),
@@ -642,10 +727,11 @@ def _bwd_p_ds_block(q, k, v, do, lse, delta, *, scale, causal,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, acc_ref, *, scale, causal, block_q,
                    block_k, kv_len, q_len, q_off, packed, hpb,
-                   token_major=False):
+                   token_major=False, group=1, q_blocks=1):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    kv_slot = _kv_slot(hpb, token_major, group, q_blocks)
 
     @pl.when(ki == 0)
     def _init():
@@ -660,9 +746,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                              q_len=q_len, q_off=q_off, qi=qi, ki=ki)
 
     def _accumulate(masked):
+        kb, vb = _kv_blocks(k_ref, v_ref, hpb, kv_slot)
         for h in range(hpb):
-            q, k, v, do = (_head_tile(r, h, hpb, token_major)
-                           for r in (q_ref, k_ref, v_ref, do_ref))
+            q, k, v, do = (_head_tile(r, h, hpb, token_major, blk)
+                           for r, blk in ((q_ref, None), (k_ref, kb),
+                                          (v_ref, vb), (do_ref, None)))
             do = do.astype(jnp.float32)
             _, ds = _bwd_p_ds_block(
                 q, k, v, do,
@@ -693,11 +781,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     *refs, scale, causal, block_q, block_k, kv_len,
                     q_len, q_off, packed, hpb, with_dq,
-                    token_major=False):
+                    token_major=False, group=1, q_blocks=1):
     """The dk/dv sweep: kv blocks outer, q blocks inner, dk_acc/dv_acc
     carried across the q sweep.  Head-major, every head slot has its
     accumulators; token-major, the heads of a lane block share one
     (`_head_tile`: their products are zero off their own lanes).
+
+    Grouped KV heads (group > 1): K and V are read in place by the
+    query head's KV head (`_Tiles.spec`, `_kv_blocks`), and dk and dv
+    are written a QUERY head, each the head's own part; the entry sums
+    a group's parts (`_sum_groups`).
 
     with_dq, it is the whole backward: P and dS, formed once a block
     pair, feed all three products.  dq_acc holds the head's whole dq
@@ -708,6 +801,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+    kv_slot = _kv_slot(hpb, token_major, group, q_blocks)
     if with_dq:
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
         nk = pl.num_programs(1)
@@ -737,9 +831,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                              q_len=q_len, q_off=q_off, qi=qi, ki=ki)
 
     def _accumulate(masked):
+        kb, vb = _kv_blocks(k_ref, v_ref, hpb, kv_slot)
         for h in range(hpb):
-            q, k, v, do = (_head_tile(r, h, hpb, token_major)
-                           for r in (q_ref, k_ref, v_ref, do_ref))
+            q, k, v, do = (_head_tile(r, h, hpb, token_major, blk)
+                           for r, blk in ((q_ref, None), (k_ref, kb),
+                                          (v_ref, vb), (do_ref, None)))
             do = do.astype(jnp.float32)
             a = 0 if token_major else h
             p, ds = _bwd_p_ds_block(
@@ -857,7 +953,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     dims, bq, bk, packed, hpb = _block_geometry(
         q, k, v, block_q, block_k, packed_stats, head_pack, heads)
     b, h, tq, tk, d, dv = dims
-    tiles = _Tiles(dims, hpb, token_major)
+    group = h // _kv_heads(q, k, heads)
+    tiles = _Tiles(dims, hpb, token_major, group)
     qp, kp, vp, gp = tiles.operand(q, bq), tiles.operand(k, bk), \
         tiles.operand(v, bk), tiles.operand(g, bq)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
@@ -913,7 +1010,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     q_off = tk - tq if causal else 0
     common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
                   kv_len=tk, q_len=tq, q_off=q_off, packed=packed,
-                  hpb=hpb, token_major=token_major)
+                  hpb=hpb, token_major=token_major, group=group,
+                  q_blocks=h // hpb)
     operands = (qp, kp, vp, gp, lse3, delta3)
     out_shape = [
         jax.ShapeDtypeStruct(tiles.shape(tq_p, d), q.dtype),
@@ -926,9 +1024,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
         which of the two inner grid indices is the q block's."""
         stat = pl.BlockSpec(
             lblk, lambda bh, i, j: (bh, q_rows(i, j), 0))
-        return [tiles.spec(bq, d, q_rows), tiles.spec(bk, d, k_rows),
-                tiles.spec(bk, dv, k_rows), tiles.spec(bq, dv, q_rows),
-                stat, stat]
+        return [tiles.spec(bq, d, q_rows),
+                tiles.spec(bk, d, k_rows, kv=True),
+                tiles.spec(bk, dv, k_rows, kv=True),
+                tiles.spec(bq, dv, q_rows), stat, stat]
 
     def params(outer="parallel", vmem_limit_bytes=None):
         if interpret:
@@ -940,6 +1039,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     # kv blocks outer, q blocks inner: the dk/dv accumulators carry
     # across the q sweep
     kv_specs = specs(q_rows=_second, k_rows=_first)
+    # dk and dv: a query head's, whatever the KV heads' count
+    dkv_specs = [tiles.spec(bk, d, _first), tiles.spec(bk, dv, _first)]
     kv_grid = (b * h // hpb, tk_p // bk, tq_p // bq)
     kv_scratch = [tiles.acc(bk, d), tiles.acc(bk, dv)]
     if one_sweep_vmem is not None:
@@ -949,8 +1050,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             grid=kv_grid,
             in_specs=kv_specs,
             # dq: the head's whole [Tq_p, d], resident over both sweeps
-            out_specs=[tiles.spec(tq_p, d, lambda j, i: 0),
-                       kv_specs[1], kv_specs[2]],
+            out_specs=[tiles.spec(tq_p, d, lambda j, i: 0)] + dkv_specs,
             out_shape=out_shape,
             scratch_shapes=[tiles.acc(tq_p, d)] + kv_scratch,
             interpret=interpret,
@@ -974,14 +1074,33 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             name="pt_flash_bwd_dkv",
             grid=kv_grid,
             in_specs=kv_specs,
-            out_specs=kv_specs[1:3],
+            out_specs=dkv_specs,
             out_shape=out_shape[1:],
             scratch_shapes=kv_scratch,
             interpret=interpret,
             **params(),
         )(*operands)
-    return (tiles.result(dq, tq), tiles.result(dk, tk),
-            tiles.result(dv_, tk))
+    return (tiles.result(dq, tq),
+            _sum_groups(tiles.result(dk, tk), group,
+                        d if token_major else None),
+            _sum_groups(tiles.result(dv_, tk), group,
+                        dv if token_major else None))
+
+
+def _sum_groups(x, group, width):
+    """dk or dv a QUERY head, [B, H, T, D], or with the head size
+    `width` token-major [B, T, H*D] -> a KV head: the float32 sum over
+    the `group` query heads that read it, in x's dtype."""
+    if group == 1:
+        return x
+    if width is None:
+        b, h, t, d = x.shape
+        parts = x.reshape(b, h // group, group, t, d)
+        return parts.astype(jnp.float32).sum(2).astype(x.dtype)
+    b, t, total = x.shape
+    parts = x.reshape(b, t, total // (group * width), group, width)
+    return parts.astype(jnp.float32).sum(3).astype(x.dtype).reshape(
+        b, t, total // group)
 
 
 # ---------------------------------------------------------------------------
@@ -1115,20 +1234,26 @@ def _call_args(q, k, causal=False, scale=None, block_q=None, block_k=None,
         heads=heads or None)
 
 
-def _flash_layout(q, v, heads, impl):
+def _flash_layout(q, k, v, heads, impl):
     """Which way the kernels address the heads of these operands,
     chosen from what the entry sees and nowhere else: "token_major"
     where [B, T, H*D] operands can be tiled in place, that is a kernel
     impl, one head size D = Dv of 64 or 128, and whole 128-lane blocks
-    (an even head count at 64); "head_major" for [B, H, T, D] operands
+    (an even head count at 64) of Q and, with grouped KV heads, of K
+    and V too, a lane block's query heads reading ONE KV head (an even
+    group at 64: `_kv_blocks`); "head_major" for [B, H, T, D] operands
     and for every token-major call that fails the rule, which the
     entry transposes to [B, H, T, D] and back: the same answer at the
     cost of the copies.  Counted, a call, in
     paddle_tpu_kernel_impl_total{kernel="flash_attention_layout"}."""
     if heads:
-        d, dv = q.shape[-1] // heads, v.shape[-1] // heads
-        if impl != "xla" and d == dv and d in (64, 128) \
-                and (heads * d) % _MIN_LANES == 0:
+        d = q.shape[-1] // heads
+        hkv = _kv_heads(q, k, heads)
+        hpb = _MIN_LANES // d if d in (64, 128) else 0
+        if impl != "xla" and hpb and v.shape[-1] == hkv * d \
+                and (heads * d) % _MIN_LANES == 0 \
+                and (hkv * d) % _MIN_LANES == 0 \
+                and (hkv == heads or (heads // hkv) % hpb == 0):
             return "token_major"
     return "head_major"
 
@@ -1151,12 +1276,16 @@ def _to_kernel_layout(kw, impl, *operands):
     operands cannot be tiled in place returns them [B, H, T, D], with
     kw["heads"] unset.  Returns (transposed, operands)."""
     heads = kw["heads"]
-    layout = _flash_layout(operands[0], operands[2], heads, impl)
+    q, k, v = operands[:3]
+    layout = _flash_layout(q, k, v, heads, impl)
     _count_impl("flash_attention_layout", layout)
     if heads is None or layout == "token_major":
         return False, operands
     kw["heads"] = None
-    return True, tuple(_split_heads(x, heads) for x in operands)
+    hkv = _kv_heads(q, k, heads)
+    # k and v (operands 1 and 2) by their own head count
+    return True, tuple(_split_heads(x, hkv if i in (1, 2) else heads)
+                       for i, x in enumerate(operands))
 
 
 def _flash_attention_fwd(q, k, v, **call):
@@ -1171,6 +1300,13 @@ def _flash_attention_fwd(q, k, v, **call):
     token-major."""
     impl, kw = _call_args(q, k, **call)
     _count_impl("flash_attention", impl)
+    if _kv_heads(q, k, kw["heads"]) != _dims(q, k, v, kw["heads"])[1]:
+        # fewer KV heads than query heads: "grouped", read in place by
+        # q_head // group (the kernels), or "repeated" to the query
+        # heads' count in HBM (plain attention).  A call with equal
+        # counts adds no series: it counts what it counted before
+        _count_impl("flash_attention_kv_heads",
+                    "repeated" if impl == "xla" else "grouped")
     transposed, (q, k, v) = _to_kernel_layout(kw, impl, q, k, v)
     # device-time attribution (ISSUE 10): at runtime with the `tracing`
     # flag on, an annotation carrying the active trace id; otherwise
@@ -1761,7 +1897,8 @@ def _gspmd_flash_shard_map(attrs, call, operands, kinds):
     [B, H, Tq], P(batch_axis, head_axis, None), on both.  Flag off or
     an untagged op returns None and the caller runs the plain
     single-program path.  A TAGGED op that fails a gate (no mesh, axis
-    missing or size 1, batch or head count not divisible) also runs
+    missing or size 1, batch, head or KV head count not divisible) also
+    runs
     plain, and says so in
     paddle_tpu_kernel_impl_total{kernel="flash_attention_gspmd"}:
     impl="plain" against impl="shard_map"."""
@@ -1781,6 +1918,9 @@ def _gspmd_flash_shard_map(attrs, call, operands, kinds):
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape)) \
         if mesh is not None else {}
     bsz, hsz = operands[0].shape[0], heads or operands[0].shape[1]
+    # the head axis splits K and V by KV head: the fewer of the two
+    # counts has to divide
+    hsz = math.gcd(hsz, _kv_heads(operands[0], operands[1], heads))
     if ba and (sizes.get(ba, 1) <= 1 or bsz % sizes.get(ba, 1) != 0):
         ba = None
     if ha and (sizes.get(ha, 1) <= 1 or hsz % sizes.get(ha, 1) != 0):

@@ -1,0 +1,172 @@
+"""Ops of a Mamba-2 state-space mixer: the chunked scan `ssd_scan` with
+its registered grad op, the depthwise causal convolution over time
+`causal_conv1d`, and the gated RMSNorm `gated_rms_norm`.
+
+Equations: docs/GRANITE4_BLOCK.md (arXiv:2405.21060; names after the
+`granitemoehybrid` modelling code).  models/granite_hybrid.py builds
+its mixer from these through layers/ssm.py; the kernels are
+ops/pallas_ssd.py.
+
+Precision under AMP (contrib/mixed_precision): the scan's MXU operands
+X, B and C are bfloat16; Dt, A, D, the cumulative sums, every decay,
+the running state and the saved chunk states are float32
+(fp16_utils._WHITE_KEEP_FP32).  The convolution and the norm compute in
+float32 and write in X's dtype.
+
+Every compute runs under a jax.named_scope (pt_ssd, pt_causal_conv1d,
+pt_gated_rms_norm); the kernels are the Mosaic calls pt_ssd_fwd and
+pt_ssd_bwd.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core.registry import register_op
+from paddle_tpu.ops import pallas_kernels as pk
+from paddle_tpu.ops import pallas_ssd
+
+_F32 = jnp.float32
+
+_SSD_INPUTS = ("X", "Dt", "A", "B", "C", "D")
+_SSD_ATTRS = {"chunk_size": 256, "impl": ""}
+
+
+def _ssd_impl(ins, attrs):
+    """The impl the scan resolves to: the one asked for, else pallas on
+    a TPU and xla elsewhere; xla too where the kernels cannot tile the
+    sizes (pallas_ssd.kernel_geom_ok).  Raises what does not fit."""
+    x, dt = ins["X"], ins["Dt"]
+    if ins["B"].shape[-1] != ins["C"].shape[-1]:
+        raise ValueError("ssd_scan: B and C differ in state size")
+    p = pallas_ssd.check_shapes(x, dt, ins["B"], attrs["chunk_size"])[3]
+    impl = attrs["impl"] or pk._auto_impl()
+    if impl != "xla" and not pallas_ssd.kernel_geom_ok(
+            p, attrs["chunk_size"]):
+        impl = "xla"
+    return impl
+
+
+@register_op("ssd_scan", inputs=_SSD_INPUTS, outputs=("Y", "States"),
+             attrs=_SSD_ATTRS)
+def ssd_scan(ins, attrs):
+    """The Mamba-2 recurrence over time, by chunks of `chunk_size`
+    tokens (ops/pallas_ssd.py has the algorithm).  Per head h, from a
+    zero state:
+
+        S_t = exp(Dt_t A) S_{t-1} + Dt_t x_t B_t^T,  y_t = S_t C_t + D x_t
+
+    X [B, T, H*P] token-major, Dt [B, T, H] (positive: after the
+    softplus), A [H] (negative), B and C [B, T, N] (one group: every
+    head reads the same B and C), D [H] -> Y [B, T, H*P] in X's dtype
+    and States, float32 [B, T/chunk, H*P, N]: the state each chunk
+    starts from, the residual ssd_scan_grad reads.  T % chunk_size != 0
+    raises; nothing is padded.  impl: "" (pallas on a TPU, xla
+    elsewhere), "pallas", "interpret", "xla" (the same chunked
+    algorithm in jax.numpy)."""
+    impl = _ssd_impl(ins, attrs)
+    pk._count_impl("ssd_scan", impl)
+    args = tuple(ins[s] for s in _SSD_INPUTS)
+    with jax.named_scope("pt_ssd"):
+        if impl == "xla":
+            y, states = pallas_ssd.ssd_chunked_xla(
+                *args, attrs["chunk_size"])
+        else:
+            # see pallas_kernels._flash_attention_fwd: one call line
+            with pk._obs_device.annotate("ssd_scan"), pk._kernel_scope():
+                y, states = pallas_ssd.ssd_fwd_pallas(
+                    *args, chunk=attrs["chunk_size"],
+                    interpret=impl == "interpret")
+    return {"Y": y, "States": states}
+
+
+def _ssd_grad_reads_saved(ins, attrs):
+    """Whether ssd_scan_grad runs the backward kernel on the forward's
+    States: bound (with Y, which a recompute segment takes in place of
+    the op's replay) and the impl a kernel (OpDef.reads_saved)."""
+    return "Y" in ins and "States" in ins \
+        and _ssd_impl(ins, attrs) != "xla"
+
+
+@register_op("ssd_scan_grad",
+             inputs=_SSD_INPUTS + ("Y", "States", "Y@GRAD"),
+             outputs=tuple(s + "@GRAD" for s in _SSD_INPUTS),
+             optional=("Y", "States"), attrs=_SSD_ATTRS,
+             differentiable=False, reads_saved=_ssd_grad_reads_saved)
+def ssd_scan_grad(ins, attrs):
+    """Hand-written, as flash_attention_grad is and for its reason: the
+    generic jax.vjp grad op would run pt_ssd_fwd a second time in every
+    layer, and a recompute segment's replay a third.
+
+      * Y and States bound (append_backward binds them; a recompute
+        segment binds them on the op it replays) and the impl a
+        kernel: pt_ssd_bwd on the saved chunk states.  The forward
+        kernel does not run again;
+      * unbound (a hand-built op): the forward kernel again for the
+        states, then pt_ssd_bwd;
+      * the xla impl: jax.vjp over the forward op's compute.
+
+    paddle_tpu_kernel_impl_total{kernel="ssd_scan_grad"} says which:
+    impl="saved" | "recompute"."""
+    args = tuple(ins[s] for s in _SSD_INPUTS)
+    g = ins["Y@GRAD"]
+    impl = _ssd_impl(ins, attrs)
+    saved = impl != "xla" and "Y" in ins and "States" in ins
+    pk._count_impl("ssd_scan_grad", "saved" if saved else "recompute")
+    if impl == "xla":
+        _, vjp = jax.vjp(
+            lambda *a: ssd_scan(dict(zip(_SSD_INPUTS, a)), attrs)["Y"],
+            *args)
+        grads = vjp(g)
+    else:
+        states = ins["States"] if saved else ssd_scan(ins, attrs)["States"]
+        with jax.named_scope("pt_ssd"), \
+                pk._obs_device.annotate("ssd_scan_grad"), \
+                pk._kernel_scope():
+            grads = pallas_ssd.ssd_bwd_pallas(
+                *args, states, g, chunk=attrs["chunk_size"],
+                interpret=impl == "interpret")
+    return {s + "@GRAD": v for s, v in zip(_SSD_INPUTS, grads)}
+
+
+@register_op("causal_conv1d", inputs=("X", "W", "Bias"), outputs=("Y",),
+             attrs={"activation": "silu"}, optional=("Bias",))
+def causal_conv1d(ins, attrs):
+    """Depthwise convolution over time that never reads ahead: X
+    [B, T, C], W [C, K], Bias [C] ->
+
+        Y[b, t, c] = act(Bias[c] + sum_k W[c, k] X[b, t - (K-1) + k, c])
+
+    with X zero before t = 0 (left-padded by K - 1).  activation "silu"
+    or "" (none).  Float32 inside, Y in X's dtype."""
+    x, w = ins["X"], ins["W"]
+    act = attrs["activation"]
+    if act not in ("silu", ""):
+        raise ValueError("causal_conv1d: activation %r is neither 'silu' "
+                         "nor ''" % (act,))
+    k, t = w.shape[-1], x.shape[1]
+    with jax.named_scope("pt_causal_conv1d"):
+        xp = jnp.pad(x.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
+        wf = w.astype(_F32)
+        y = sum(xp[:, i:i + t, :] * wf[:, i] for i in range(k))
+        if ins.get("Bias") is not None:
+            y = y + ins["Bias"].astype(_F32)
+        if act == "silu":
+            y = jax.nn.silu(y)
+        return {"Y": y.astype(x.dtype)}
+
+
+@register_op("gated_rms_norm", inputs=("X", "Gate", "Scale"),
+             outputs=("Y",), attrs={"epsilon": 1e-6})
+def gated_rms_norm(ins, attrs):
+    """Y = RMSNorm(X * silu(Gate)) * Scale over the last axis (the gate
+    BEFORE the norm, one group: the statistic is over all of the last
+    axis), float32 inside, Y in X's dtype."""
+    from paddle_tpu.ops.llm_ops import _rms
+
+    x = ins["X"]
+    with jax.named_scope("pt_gated_rms_norm"):
+        u = x.astype(_F32) * jax.nn.silu(ins["Gate"].astype(_F32))
+        y = _rms(u, attrs["epsilon"]) * ins["Scale"].astype(_F32)
+        return {"Y": y.astype(x.dtype)}

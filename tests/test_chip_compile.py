@@ -38,21 +38,23 @@ def _flash(shape, grad, n_bwd=1):
         (n_bwd + 1 if grad else 1)
 
 
-def _flash_token_major(shape, heads, grad):
+def _flash_token_major(shape, heads, grad, kv_width=None):
     """The op's entries on token-major [B, T, H*d] operands, as the
     q, k and v projections leave them: the forward kernel, and the
-    one-sweep backward on the saved residuals."""
+    one-sweep backward on the saved residuals.  kv_width: K's and V's
+    width where they have fewer heads than Q (grouped KV heads)."""
     from paddle_tpu.ops.pallas_kernels import (_flash_attention_bwd,
                                                _flash_attention_fwd)
 
     x = _sds(shape)
+    kv = _sds(shape[:2] + (kv_width or shape[2],))
     call = dict(causal=True, impl="pallas", heads=heads)
     if not grad:
         return (lambda q, k, v: _flash_attention_fwd(q, k, v, **call)), \
-            (x,) * 3, 1
+            (x, kv, kv), 1
     lse = _sds((shape[0], heads, shape[1]), jnp.float32)
     return (lambda q, k, v, o, lse, g: _flash_attention_bwd(
-        q, k, v, o, lse, g, **call)), (x, x, x, x, lse, x), 1
+        q, k, v, o, lse, g, **call)), (x, kv, kv, x, lse, x), 1
 
 
 def _attention_block(batch=64, seq=512, width=512, heads=8):
@@ -148,7 +150,24 @@ def _fc(m=16384, k=512, n=2048):
         _sds((m, k)), _sds((k, n)), _sds((n,), jnp.float32)), 1
 
 
+def _ssd(grad, b=1, t=8192, h=64, p=64, n=128, chunk=256):
+    """The state-space scan of granite-4.0-h-micro's mixer at the
+    cell's size: 64 heads of 64, state 128, 32 chunks of 256."""
+    from paddle_tpu.ops.pallas_ssd import ssd_bwd_pallas, ssd_fwd_pallas
+
+    f32 = jnp.float32
+    x, bc = _sds((b, t, h * p)), _sds((b, t, n))
+    ins = (x, _sds((b, t, h), f32), _sds((h,), f32), bc, bc,
+           _sds((h,), f32))
+    if not grad:
+        return (lambda *a: ssd_fwd_pallas(*a, chunk=chunk)), ins, 1
+    return (lambda *a: ssd_bwd_pallas(*a, chunk=chunk)), \
+        ins + (_sds((b, t // chunk, h * p, n), f32), x), 1
+
+
 CASES = {
+    "ssd_fwd_1x8192_h64_p64_n128": lambda: _ssd(False),
+    "ssd_bwd_1x8192_h64_p64_n128": lambda: _ssd(True),
     "flash_fwd_32x8x512x64": lambda: _flash((32, 8, 512, 64), False),
     "flash_bwd_32x8x512x64": lambda: _flash((32, 8, 512, 64), True),
     "flash_fwd_1x8x32768x128": lambda: _flash((1, 8, 32768, 128), False),
@@ -174,6 +193,12 @@ CASES = {
         _flash_token_major((1, 4096, 2048), 16, False),
     "flash_bwd_1x4096x2048_token_major_d128": lambda:
         _flash_token_major((1, 4096, 2048), 16, True),
+    # granite-4.0-h-micro's attention layer: 32 query heads of 64 read
+    # 8 KV heads in place (scale 1/64 is a static float like any other)
+    "flash_fwd_1x8192x2048_token_major_kv8": lambda:
+        _flash_token_major((1, 8192, 2048), 32, False, kv_width=512),
+    "flash_bwd_1x8192x2048_token_major_kv8": lambda:
+        _flash_token_major((1, 8192, 2048), 32, True, kv_width=512),
     "attention_block_64x512x512_token_major": _attention_block,
     "flash_fwd_1x32x4096_qk192_v128": lambda: _flash_mla(False),
     "flash_bwd_saved_1x32x4096_qk192_v128": lambda: _flash_mla(True),

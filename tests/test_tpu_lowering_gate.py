@@ -46,10 +46,10 @@ def test_bench_workload_lowers_for_tpu(chip_gate, workload):
 
 @pytest.mark.parametrize("workload,flash_ops", [
     ("xing4_train_tiny", 5), ("ouro_train_tiny", 24),
-    ("dsv2_train_tiny", 5)])
+    ("dsv2_train_tiny", 5), ("granite_train_tiny", 1)])
 def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         chip_gate, workload, flash_ops):
-    """The three cells that train under RecomputeOptimizer, at their
+    """The four cells that train under RecomputeOptimizer, at their
     depth and head sizes, narrow and short: a segment's backward takes
     the forward's Out and LSE (ISSUE 33), so the compiled step holds
     one `pt_flash_fwd` a flash op and not a second in every segment's
@@ -62,6 +62,17 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
     assert detail["flash_ops"] == flash_ops
     assert detail["kernel_calls"]["pt_flash_fwd"] == flash_ops
     assert detail["kernel_calls"]["pt_flash_bwd_dkv"] == flash_ops
+    if workload == "granite_train_tiny":
+        # one period of granite-4.0-h-micro at its head sizes, state
+        # size and chunk (ISSUE 38): nine scans, each forward kernel
+        # ONCE (a segment binds the saved Y and chunk-start states on
+        # the op it replays: not 18, never 27) and each backward once;
+        # the one attention layer reads 2 KV heads from 4 query heads
+        assert workload in chip_gate.ONE_SSD_FWD_AN_OP
+        assert detail["ssd_ops"] == 9
+        assert detail["kernel_calls"]["pt_ssd_fwd"] == 9
+        assert detail["kernel_calls"]["pt_ssd_bwd"] == 9
+        assert detail["tpu_custom_calls"] == 20
     if workload == "dsv2_train_tiny":
         # four expert layers at the published expert width, 1,408 =
         # 11 x 128: the grouped matmuls compile with 128-wide blocks

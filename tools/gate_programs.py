@@ -262,6 +262,13 @@ def _build_dsv2_train(batch=2, seq=4096, **sizes):
                              batch, seq, sizes)
 
 
+def _build_granite_train(batch=1, seq=8192, **sizes):
+    """The hybrid state-space decoder's train step as the cell
+    `granite4_h_micro_train_b1` runs it."""
+    return _build_cell_train("granite-4.0-h-micro.json",
+                             "granite_hybrid.py", batch, seq, sizes)
+
+
 def _build_xing4_train(batch=1, seq=4096, **sizes):
     """The 2024-26 decoder block's train step as the cell
     `xing4_29b_train_s4k` runs it."""

@@ -131,6 +131,18 @@ def _workloads():
         # (128-wide blocks) and 768 rows an expert, the softmax router
         # and its balance loss crossing recompute segments
         "dsv2_train": lambda: progs._build_dsv2_train(2, 4096)[:3],
+        # the hybrid state-space decoder at the cell's sizes (1 x 8,192
+        # tokens, nine Mamba-2 layers and one grouped-KV attention
+        # layer, 772 M parameters): whether 12.35 GB of state and the
+        # step's activations fit, and that each scan's forward kernel
+        # runs once (ONE_SSD_FWD_AN_OP)
+        "granite_train": lambda: progs._build_granite_train(1, 8192)[:3],
+        # the published head sizes, state size and chunk (the kernels'
+        # geometry), one period of layers, everything else narrow
+        "granite_train_tiny": lambda: progs._build_granite_train(
+            1, 512, hidden_size=256, num_attention_heads=4,
+            num_key_value_heads=2, mamba_n_heads=4,
+            shared_intermediate_size=512, vocab_size=512)[:3],
         # both at the cells' depth and head sizes, narrow and short
         # (256 tokens; seconds to compile): what is checked is how many
         # kernels the step holds, not whether it fits
@@ -336,7 +348,7 @@ def _infer(progs, which, batch, conv_epilogue=False):
 
 
 FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train",
-             "xing4_train", "dsv2_train")
+             "xing4_train", "dsv2_train", "granite_train")
 
 # the steps whose attention takes q, k and v token-major, [B, T, H*d]
 # as the projections leave them: their compiled step may hold no head
@@ -363,7 +375,15 @@ def head_layout_copies(hlo_text):
 ONE_FLASH_FWD_AN_OP = ("transformer_train", "transformer_train_gspmd",
                        "ouro_train", "ouro_train_tiny", "xing4_train",
                        "xing4_train_tiny", "dsv2_train",
-                       "dsv2_train_tiny")
+                       "dsv2_train_tiny", "granite_train",
+                       "granite_train_tiny")
+
+# the training steps whose every ssd_scan op has a grad that reads the
+# forward's Y and chunk-start states inside its recompute segment: the
+# forward kernel runs once an op (9 in the cell's step; 18 if the
+# segment replayed it, 27 if the grad op ran it again too), and the
+# backward kernel once
+ONE_SSD_FWD_AN_OP = ("granite_train", "granite_train_tiny")
 
 
 def kernel_calls(hlo_text):
@@ -429,7 +449,9 @@ def check_workload(name, build):
     `kernel_calls` and the program's `flash_ops`, which fail it unless
     `pt_flash_fwd` is called once an op (a recompute segment that
     replays the op holds a second call: 10 for 5 in `xing4`, 48 for 24
-    in `ouro` before PR 33); for the ROW_WORK_IN_LOOPS steps
+    in `ouro` before PR 33), and for the ONE_SSD_FWD_AN_OP steps
+    `ssd_ops`, the same of `pt_ssd_fwd` and `pt_ssd_bwd`; for the
+    ROW_WORK_IN_LOOPS steps
     `rows_outside_loops`, which fails the workload unless it is 0, and
     for the STEP_BYTES_MAX steps `step_bytes`, which fails it above
     the limit."""
@@ -474,6 +496,15 @@ def check_workload(name, build):
             detail["kernel_calls"] = kernel_calls(text)
             ok &= detail["kernel_calls"].get("pt_flash_fwd") \
                 == detail["flash_ops"] > 0
+        if name in ONE_SSD_FWD_AN_OP:
+            from paddle_tpu import framework
+
+            detail["ssd_ops"] = sum(
+                op.type == "ssd_scan" for op in
+                framework.default_main_program().global_block().ops)
+            ok &= detail["kernel_calls"].get("pt_ssd_fwd") \
+                == detail["kernel_calls"].get("pt_ssd_bwd") \
+                == detail["ssd_ops"] > 0
         if name in ROW_WORK_IN_LOOPS:
             detail["rows_outside_loops"] = rows_outside_loops(
                 text, ROW_WORK_IN_LOOPS[name])
