@@ -25,6 +25,19 @@ in chunks (llm_ops._over_live_rows); nothing by padded row, kernel or
 array code, runs past n_active.  No capacity, no dropped token, no
 [N, E, C] one-hot.
 
+The blocks along the two weight axes are chosen by what fits VMEM, not
+by what divides (`_tiles`): an axis admits every multiple of 128 that
+divides it AND its whole extent, and of the shapes whose buffers fit
+the budget a call runs the one with the fewest grid steps a row tile.
+An expert width of 1,408 = 11 x 128 is taken whole, and at both cells'
+shapes so is the other axis: one grid step a row tile a call, 12 a live
+row tile of a layer's twelve calls where 128-wide blocks along 1,408
+made it 418.  With the contraction whole the weight block's index is
+the same for an expert's consecutive row tiles and the pipeline does
+not fetch it again.  The rule reads the call's shapes and dtype and
+nothing else; a call whose blocks need more than Mosaic's default
+scope asks for it (`vmem_limit_bytes`).
+
 Technique after the megablox grouped matmul of jax's Pallas TPU
 examples; tile-aligned groups make the row masks and the group-metadata
 pass of that kernel unnecessary.
@@ -41,24 +54,67 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _tile(dim, target):
-    """Largest multiple of 128 that divides `dim` and is <= target, else
-    the whole dimension (a block equal to the array's extent is legal
-    whatever its size).  A width with no larger divisor gets narrow
-    blocks: 1,408 = 11 x 128 with 11 prime yields 128, where 1,024
-    yields 1,024 (target 1024) and 512 (target 512); nothing pads a
-    width to a friendlier one."""
-    best = None
-    for t in range(128, min(dim, target) + 1, 128):
-        if dim % t == 0:
-            best = t
-    return best or dim
+# The most _tiles plans a call's blocks to take of the core's 128 MiB
+# of VMEM, and what a call asks for beyond its blocks (Mosaic's own
+# scratch).  The budget is the chip's answer (PERF.md, PR 40): at both
+# cells' shapes every step fewer a row tile was faster, the whole
+# matrix a block (16-33 MiB) fastest, in each of the six calls of a
+# layer.
+_VMEM_BUDGET = 40 << 20
+_VMEM_HEADROOM = 4 << 20
 
 
-def _tiles(k, n):
+def _blocks(dim):
+    """The blocks an axis admits: every multiple of 128 that divides it,
+    and its whole extent (a block equal to the array's extent is legal
+    whatever its size).  Nothing pads a width to a friendlier one, and
+    no block hangs over an edge."""
+    return [t for t in range(128, dim, 128) if dim % t == 0] + [dim]
+
+
+def _vmem_bytes(kernel, tm, tk, tn, itemsize):
+    """What a call's blocks take of VMEM: two buffers of each input
+    block and of the output block (the pipeline fetches the next while
+    the kernel works on this one) and the float32 accumulator."""
+    if kernel == "tgmm":
+        ins, out = tm * (tk + tn), tk * tn
+    else:
+        ins, out = tm * tk + tk * tn, tm * tn
+    return 2 * itemsize * (ins + out) + 4 * out
+
+
+def _tiles(kernel, k, n, tm, itemsize):
     """(tn, tk): the block along the output width n and along the
-    contraction (gmm) or the other output axis (tgmm) k."""
-    return _tile(n, 1024), _tile(k, 512)
+    contraction ("gmm", either orientation of rhs) or the other output
+    axis ("tgmm") k.  Of the shapes _blocks admits and _VMEM_BUDGET
+    holds, the one with the fewest grid steps a row tile, (n / tn) x
+    (k / tk).  Among equals gmm takes the deeper tk (a whole
+    contraction leaves the weight block's index alone from one row tile
+    of an expert to the next, and the pipeline does not fetch it
+    again), tgmm the wider tn (the faster of the two on the chip at
+    like steps).  A pure function of the call's shapes and dtype: an
+    expert width of 1,408 = 11 x 128 with 11 prime is taken whole,
+    where "the largest divisor up to 1,024" made every block along it
+    128 wide; [3584, 1024] is one block where it was 7 or 8."""
+    fits = [(tn, tk) for tn in _blocks(n) for tk in _blocks(k)
+            if _vmem_bytes(kernel, tm, tk, tn, itemsize) <= _VMEM_BUDGET]
+    if not fits:        # not even the narrowest fits: take it, and ask
+        return _blocks(n)[0], _blocks(k)[0]
+
+    def cost(block):
+        tn, tk = block
+        steps = (n // tn) * (k // tk)
+        return (steps, -tn, -tk) if kernel == "tgmm" else (steps, -tk, -tn)
+
+    return min(fits, key=cost)
+
+
+def _vmem_limit(vmem_bytes):
+    """What a call whose blocks take vmem_bytes passes as
+    vmem_limit_bytes: Mosaic's default scope unless it needs more."""
+    from paddle_tpu.ops.pallas_kernels import _MOSAIC_SCOPED_VMEM
+
+    return max(_MOSAIC_SCOPED_VMEM, vmem_bytes + _VMEM_HEADROOM)
 
 
 def _gmm_kernel(tg_ref, na_ref, x_ref, w_ref, o_ref, acc_ref, *,
@@ -100,11 +156,12 @@ def _tgmm_kernel(tg_ref, na_ref, x_ref, g_ref, o_ref, acc_ref, *,
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _params(interpret, semantics):
+def _params(interpret, semantics, vmem_bytes):
     if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=semantics)}
+        dimension_semantics=semantics,
+        vmem_limit_bytes=_vmem_limit(vmem_bytes))}
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -117,7 +174,8 @@ def gmm_pallas(lhs, rhs, tile_group, n_active, tm, transpose_rhs=False,
     grid visits the n_active[0] >= 1 tiles that hold rows and no other."""
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tn, tk = _tiles(k, n)
+    itemsize = lhs.dtype.itemsize
+    tn, tk = _tiles("gmm", k, n, tm, itemsize)
     if transpose_rhs:
         w_spec = pl.BlockSpec(
             (1, tn, tk), lambda j, i, kk, tg, na: (tg[i], j, kk))
@@ -138,7 +196,8 @@ def gmm_pallas(lhs, rhs, tile_group, n_active, tm, transpose_rhs=False,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         interpret=interpret,
-        **_params(interpret, ("parallel", "arbitrary", "arbitrary")),
+        **_params(interpret, ("parallel", "arbitrary", "arbitrary"),
+                  _vmem_bytes("gmm", tm, tk, tn, itemsize)),
     )(tile_group, n_active, lhs, rhs)
 
 
@@ -152,7 +211,8 @@ def tgmm_pallas(lhs, grad, tile_group, n_active, tm, n_groups,
     block is written.  The grid's last axis ends at n_active[0]."""
     m, k = lhs.shape
     n = grad.shape[1]
-    tn, tk = _tiles(k, n)
+    itemsize = lhs.dtype.itemsize
+    tn, tk = _tiles("tgmm", k, n, tm, itemsize)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(k // tk, n // tn, n_active[0]),
@@ -168,7 +228,8 @@ def tgmm_pallas(lhs, grad, tile_group, n_active, tm, n_groups,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_groups, k, n), lhs.dtype),
         interpret=interpret,
-        **_params(interpret, ("parallel", "parallel", "arbitrary")),
+        **_params(interpret, ("parallel", "parallel", "arbitrary"),
+                  _vmem_bytes("tgmm", tm, tk, tn, itemsize)),
     )(tile_group, n_active, lhs, grad)
 
 
@@ -217,20 +278,23 @@ def row_buffer(shape, dtype, impl):
         interpret=impl == "interpret")()
 
 
-def _count_tiles(k, n):
+def _count_tiles(kernel, k, n, tm, itemsize):
     """paddle_tpu_kernel_impl_total{kernel="moe_gmm_tile",
     impl="<tn>x<tk>"}, once a grouped-matmul call that runs a kernel:
     a step's counters say which block shapes it ran.  (How many row
     tiles its grid ran is a run-time number: moe_experts' Load.)"""
     from paddle_tpu.ops import pallas_kernels as pk
 
-    pk._count_impl("moe_gmm_tile", "%dx%d" % _tiles(k, n))
+    pk._count_impl("moe_gmm_tile",
+                   "%dx%d" % _tiles(kernel, k, n, tm, itemsize))
 
 
 def gmm(lhs, rhs, tile_group, n_active, tm, impl, transpose_rhs=False):
     if impl == "xla":
         return gmm_xla(lhs, rhs, tile_group, n_active, tm, transpose_rhs)
-    _count_tiles(lhs.shape[1], rhs.shape[1 if transpose_rhs else 2])
+    _count_tiles("gmm", lhs.shape[1],
+                 rhs.shape[1 if transpose_rhs else 2], tm,
+                 lhs.dtype.itemsize)
     return gmm_pallas(lhs, rhs, tile_group, n_active, tm,
                       transpose_rhs=transpose_rhs,
                       interpret=impl == "interpret")
@@ -239,6 +303,7 @@ def gmm(lhs, rhs, tile_group, n_active, tm, impl, transpose_rhs=False):
 def tgmm(lhs, grad, tile_group, n_active, tm, n_groups, impl):
     if impl == "xla":
         return tgmm_xla(lhs, grad, tile_group, n_active, tm, n_groups)
-    _count_tiles(lhs.shape[1], grad.shape[1])
+    _count_tiles("tgmm", lhs.shape[1], grad.shape[1], tm,
+                 lhs.dtype.itemsize)
     return tgmm_pallas(lhs, grad, tile_group, n_active, tm, n_groups,
                        interpret=impl == "interpret")

@@ -229,7 +229,7 @@ def test_program_is_verified_and_shape_checked():
             assert block.var(n).dtype != "bfloat16", (op.type, n)
 
 
-def test_scope_and_counters_after_a_build():
+def test_scope_and_counters_after_a_build(monkeypatch):
     """The scope pt_moe_balance (framework.name_scope around the
     balance loss's ops) and the kernels' scopes reach the compiled
     step's op_name metadata, forward and in the segment's replay; the
@@ -287,11 +287,20 @@ def test_scope_and_counters_after_a_build():
         (row,) = got["rows"]
         assert 0 < row[:4].sum() == row[4] < BATCH * SEQ * 3
         assert 4 <= row[5] <= -(-BATCH * SEQ * 3 // 256) + 4
-    # a kernel call counts the blocks `_tile` gives it: an expert width
-    # of 11 x 128 gets 128-wide blocks, 1,024 whole ones
-    assert pallas_gmm._tiles(2048, 1408) == (128, 512)
-    assert pallas_gmm._tiles(1408, 2048) == (1024, 128)
-    assert pallas_gmm._tiles(3584, 1024) == (1024, 512)
+    # a kernel call counts the blocks `_tiles` gives it: at 256 rows a
+    # tile in bf16 an expert width of 11 x 128 is taken whole, in either
+    # place and by either kernel, and so is the axis beside it; under
+    # Mosaic's default scope alone 1,408 still is, and the other axis
+    # splits
+    for kernel in ("gmm", "tgmm"):
+        assert pallas_gmm._tiles(kernel, 2048, 1408, 256, 2) == (1408, 2048)
+        assert pallas_gmm._tiles(kernel, 1408, 2048, 256, 2) == (2048, 1408)
+        assert pallas_gmm._tiles(kernel, 3584, 1024, 256, 2) == (1024, 3584)
+    with monkeypatch.context() as smaller:
+        smaller.setattr(pallas_gmm, "_VMEM_BUDGET", 12 << 20)
+        assert pallas_gmm._tiles("gmm", 2048, 1408, 256, 2) == (1408, 1024)
+        assert pallas_gmm._tiles("tgmm", 2048, 1408, 256, 2) == (1408, 512)
+        assert pallas_gmm._tiles("gmm", 1408, 2048, 256, 2) == (1024, 1408)
     rng = np.random.default_rng(0)
     lhs = jnp.asarray(rng.normal(0, 1, (32, 256)), jnp.float32)
     rhs = jnp.asarray(rng.normal(0, 1, (2, 256, 1408)), jnp.float32)
@@ -300,8 +309,10 @@ def test_scope_and_counters_after_a_build():
     np.testing.assert_allclose(
         got, pallas_gmm.gmm(lhs, rhs, tg, na, 16, "xla"), rtol=1e-5,
         atol=1e-4)
-    assert counts().get(("moe_gmm_tile", "128x256"), 0) \
-        - before.get(("moe_gmm_tile", "128x256"), 0) == 1
+    assert counts().get(("moe_gmm_tile", "1408x256"), 0) \
+        - before.get(("moe_gmm_tile", "1408x256"), 0) == 1
+    assert not [k for k in counts() if k[0] == "moe_gmm_tile"
+                and k not in before and k[1] != "1408x256"]
 
 
 # -- the balance loss ---------------------------------------------------------

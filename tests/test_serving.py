@@ -273,8 +273,10 @@ def test_failover_exactly_once_accounting_and_drain(tmp_path):
         kinds = {k for _, _, k in inj.log}
         assert "kill" in kinds and "drop" in kinds
         assert st["pool"]["requeues"] >= 2
-        assert srv.pool.live_replicas() == [0]     # survivor serving
-        assert st["pool"]["replicas"][1]["alive"] is False
+        # one survivor serving, the other dead; which of the two took
+        # the second batch, and the kill, is the scheduler's timing
+        (survivor,) = srv.pool.live_replicas()
+        assert st["pool"]["replicas"][1 - survivor]["alive"] is False
     assert faultinject.maybe_injector() is None
 
 

@@ -75,7 +75,7 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert detail["tpu_custom_calls"] == 20
     if workload == "dsv2_train_tiny":
         # four expert layers at the published expert width, 1,408 =
-        # 11 x 128: the grouped matmuls compile with 128-wide blocks
+        # 11 x 128: the grouped matmuls compile with that axis whole
         # (three forward and their replay, three and three backward)
         assert [detail["kernel_calls"][k] for k in (
             "pt_gmm_fwd", "pt_gmm_bwd_dx", "pt_gmm_bwd_dw")] == [24, 12, 12]
@@ -204,3 +204,131 @@ def test_sequence_parallel_flash_lowers_for_tpu(which, causal):
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     export.export(jax.jit(step), platforms=("tpu",))(q, q, q)
+
+
+# -- the grouped matmuls at the two cells' real shapes (ISSUE 40) -------------
+
+# The cells' expert layers: the pairs a step routes at the worst case,
+# hidden width, expert width.  8 experts held, tiles of 256 rows, bf16.
+GMM_CELLS = {"dsv2": (49152, 2048, 1408), "xing4": (16384, 3584, 1024)}
+GMM_HELD, GMM_TM = 8, 256
+# The six grouped-matmul calls of an expert layer's forward and backward
+# (moe_experts' _routed_fwd / _routed_bwd): kernel, rhs transposed,
+# (k, n) of hidden h and width w, and how many run a step (the recompute
+# segment replays the forward; up is gate's shape).
+GMM_CALLS = {
+    "fwd_gate_up": ("gmm", False, lambda h, w: (h, w), 4),
+    "fwd_down": ("gmm", False, lambda h, w: (w, h), 2),
+    "dx_down": ("gmm", True, lambda h, w: (h, w), 1),
+    "dx_gate_up": ("gmm", True, lambda h, w: (w, h), 2),
+    "dw_down": ("tgmm", False, lambda h, w: (w, h), 1),
+    "dw_gate_up": ("tgmm", False, lambda h, w: (h, w), 2)}
+
+
+def _gmm_call(cell, call):
+    """One grouped-matmul call at the cell's real shapes: fn and avals
+    to trace or compile, the kernel's kind, its k and n, and how many
+    such calls a step makes."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_gmm import gmm_pallas, tgmm_pallas
+
+    rows, h, w = GMM_CELLS[cell]
+    kernel, transpose_rhs, kn, times = GMM_CALLS[call]
+    k, n = kn(h, w)
+    m = (rows // GMM_TM + GMM_HELD) * GMM_TM
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    maps = (sds((m // GMM_TM,), jnp.int32), sds((1,), jnp.int32))
+    if kernel == "tgmm":
+        def fn(x, g, tg, na):
+            return tgmm_pallas(x, g, tg, na, GMM_TM, GMM_HELD)
+        operands = (sds((m, k)), sds((m, n)))
+    else:
+        def fn(x, wt, tg, na):
+            return gmm_pallas(x, wt, tg, na, GMM_TM,
+                              transpose_rhs=transpose_rhs)
+        operands = (sds((m, k)), sds((GMM_HELD, n, k) if transpose_rhs
+                                     else (GMM_HELD, k, n)))
+    return types.SimpleNamespace(fn=fn, avals=operands + maps,
+                                 kernel=kernel, k=k, n=n, times=times)
+
+
+def _grid_steps_a_live_tile(call):
+    """From the call's jaxpr: the product of its grid's static axes,
+    what ONE more live row tile adds to the grid (the row-tile axis is
+    the run-time n_active)."""
+    import jax
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from pallas_calls(sub)
+
+    eqn, = pallas_calls(jax.make_jaxpr(call.fn)(*call.avals).jaxpr)
+    mapping = eqn.params["grid_mapping"]
+    assert mapping.num_dynamic_grid_bounds == 1
+    steps = 1
+    for axis in mapping.grid:
+        steps *= axis if isinstance(axis, int) else 1
+    return steps
+
+
+@pytest.mark.parametrize("call", sorted(GMM_CALLS))
+@pytest.mark.parametrize("cell", sorted(GMM_CELLS))
+def test_grouped_matmul_compiles_at_the_cells_shapes(chip_gate, cell, call):
+    """Every call shape of an expert layer at real M, lowered and
+    compiled by the chip's own compiler under the VMEM limit the call
+    passes: blocks the rule reckons to fit and Mosaic refuses fail
+    here, not on the chip."""
+    c = _gmm_call(cell, call)
+    exe = chip_gate.compile_for_chip(c.fn, c.avals)
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("call", sorted(GMM_CALLS))
+@pytest.mark.parametrize("cell", sorted(GMM_CELLS))
+def test_grouped_matmul_blocks_fit_the_limit_the_call_passes(cell, call):
+    """Pure Python: the footprint `_tiles` reckons for the blocks it
+    chose (two buffers a block, the float32 accumulator) is inside the
+    budget and under what the call passes as vmem_limit_bytes, which is
+    Mosaic's default scope or more and well inside the core's 128 MiB;
+    the blocks are ones the axes admit, and none is 128 wide along
+    1,408."""
+    from paddle_tpu.ops import pallas_gmm as pg
+    from paddle_tpu.ops.pallas_kernels import _MOSAIC_SCOPED_VMEM
+
+    c = _gmm_call(cell, call)
+    tn, tk = pg._tiles(c.kernel, c.k, c.n, GMM_TM, 2)
+    assert tn in pg._blocks(c.n) and tk in pg._blocks(c.k)
+    need = pg._vmem_bytes(c.kernel, GMM_TM, tk, tn, 2)
+    assert need <= pg._VMEM_BUDGET
+    assert max(need + 1, _MOSAIC_SCOPED_VMEM) <= pg._vmem_limit(need) \
+        <= 100 << 20
+    for dim, block in ((c.n, tn), (c.k, tk)):
+        assert dim != 1408 or block == 1408
+
+
+@pytest.mark.parametrize("cell,most", [("dsv2", 38), ("xing4", 89)])
+def test_a_live_tile_layer_costs_few_grid_steps(cell, most):
+    """What one more live row tile of one expert layer adds to a
+    step's grids, summed over the layer's twelve calls, from their
+    jaxprs.  The rule gives 12 in both cells today (the whole matrix a
+    block).  The bounds: dsv2 38, what 1,408 whole over blocks of 512
+    and 1,024 costs (4 + 4 + 2 forward, twice; 4 + 2 + 2 for dx; 2 + 4
+    + 4 for dw), where 128-wide blocks along 1,408 made it 418; xing4
+    the 89 of its former 1024x512 / 896x512 blocks (7 a call at [3584,
+    1024], 8 at [1024, 3584]).  A rule that falls back to narrow blocks
+    fails here."""
+    steps = 0
+    for name in GMM_CALLS:
+        call = _gmm_call(cell, name)
+        steps += call.times * _grid_steps_a_live_tile(call)
+    assert steps <= most

@@ -128,7 +128,7 @@ def _workloads():
         "xing4_train": lambda: progs._build_xing4_train(1, 4096)[:3],
         # the DeepSeek-V2 block at the cell's sizes (2 x 4,096 tokens):
         # the grouped matmuls at an expert width of 1,408 = 11 x 128
-        # (128-wide blocks) and 768 rows an expert, the softmax router
+        # (taken whole) and 768 rows an expert, the softmax router
         # and its balance loss crossing recompute segments
         "dsv2_train": lambda: progs._build_dsv2_train(2, 4096)[:3],
         # the hybrid state-space decoder at the cell's sizes (1 x 8,192
@@ -156,7 +156,7 @@ def _workloads():
             q_lora_rank=64, kv_lora_rank=64, intermediate_size=512,
             moe_intermediate_size=128, n_routed_experts=2,
             vocab_size=512)[:3],
-        # the published expert width (1,408: 128-wide blocks through
+        # the published expert width (1,408: one block through
         # Mosaic) and head sizes, everything else narrow and short
         "dsv2_train_tiny": lambda: progs._build_dsv2_train(
             2, 256, hidden_size=256, num_attention_heads=2,
