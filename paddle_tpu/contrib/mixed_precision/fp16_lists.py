@@ -46,6 +46,11 @@ white_list = {
     # on the MXU; Dt, A, D, the decays, the running state and the saved
     # chunk states stay float32 (fp16_utils)
     "ssd_scan",
+    # the chunked delta-rule scan (ops/pallas_kda.py): bf16 Q, K and V
+    # on the MXU; the log-decays G, Beta, the inverse of a chunk's
+    # triangular system, the running state and the saved block states
+    # stay float32 (fp16_utils)
+    "kda_scan",
 }
 
 # numerically sensitive: keep fp32
@@ -81,7 +86,8 @@ follow_x_list = {
     "instance_norm", "data_norm", "rms_norm",
     # float32 inside, Y in X's dtype, whatever the filter's, the
     # gate's or the scale's is
-    "causal_conv1d", "gated_rms_norm",
+    "causal_conv1d", "gated_rms_norm", "head_l2_norm",
+    "head_gated_rms_norm",
 }
 
 

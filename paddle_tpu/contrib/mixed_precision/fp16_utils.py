@@ -34,6 +34,9 @@ _WHITE_KEEP_FP32 = {
     # state-space scan: exp(Dt A) over thousands of tokens in bfloat16
     # is another recurrence
     "ssd_scan": frozenset({"Dt", "A", "D"}),
+    # a delta-rule scan's log-decays and write strengths: e^G over a
+    # chunk in bfloat16 is another recurrence
+    "kda_scan": frozenset({"G", "Beta"}),
 }
 
 # white-list ops with multiple outputs where only SOME are emitted in
@@ -50,6 +53,7 @@ _WHITE_LOWP_OUT = {
     "moe_experts": frozenset({"Out"}),
     # States, the running state at each chunk's start, is float32
     "ssd_scan": frozenset({"Y"}),
+    "kda_scan": frozenset({"O"}),
 }
 
 

@@ -1,0 +1,128 @@
+"""Layer functions of a Kimi Delta Attention mixer (ops/kda_ops.py): the
+chunked delta-rule scan kda_scan, the safe log-decay gate kda_gate, the
+per-head L2 norm head_l2_norm and the head-wise gated RMSNorm
+head_gated_rms_norm.  docs/LING3_BLOCK.md has the equations;
+models/ling3.py builds a hybrid stack from them (the depthwise causal
+convolution is layers.causal_conv1d)."""
+
+from __future__ import annotations
+
+from paddle_tpu.layers.helper import LayerHelper
+from paddle_tpu.layers.llm import _named
+
+__all__ = ["kda_scan", "kda_gate", "head_l2_norm", "head_gated_rms_norm"]
+
+
+def kda_scan(q, k, v, g, beta, chunk_size=64, block_chunks=4, impl=None,
+             name=None):
+    """The delta-rule recurrence with a decay per key channel, by
+    chunks: per head, from a zero state,
+
+        S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1}
+              + beta_t k_t v_t^T,      o_t = S_t^T q_t
+
+    q, k, v [B, T, H*D] token-major, g [B, T, H*D] the log-decay a key
+    channel (float32, in [-5, 0]: `kda_gate`), beta [B, T, H] in (0, 1);
+    returns o [B, T, H*D].  T must be a multiple of block_chunks x
+    chunk_size.  The op also writes States, the transposed state each
+    block of chunks starts from (float32 [B, T/block, H*D, D], no
+    gradient): the residual kda_scan_grad reads, with o, instead of
+    running the forward kernel again.  impl: None (pallas on a TPU, xla
+    elsewhere), "pallas", "interpret", "xla"."""
+    t = q.shape[1] if q.shape is not None else None
+    block = int(chunk_size) * int(block_chunks)
+    if t is not None and t > 0 and t % block:
+        raise ValueError(
+            "kda_scan: %d tokens are no multiple of %d (%d chunks of %d, "
+            "one saved state); nothing is padded"
+            % (t, block, block_chunks, chunk_size))
+    helper = LayerHelper("kda_scan", name=name)
+    o = helper.create_variable_for_type_inference(v.dtype)
+    states = helper.create_variable_for_type_inference("float32", True)
+    helper.append_op(
+        type="kda_scan",
+        inputs={"Q": q, "K": k, "V": v, "G": g, "Beta": beta},
+        outputs={"O": o, "States": states},
+        attrs={"chunk_size": int(chunk_size),
+               "block_chunks": int(block_chunks), "impl": impl or ""})
+    return o
+
+
+# a head's decay rate exp(A_log) is drawn log-uniform in this range,
+# and dt_bias so that g = lower_bound * sigmoid(rate * dt_bias) starts
+# log-uniform in _G0_RANGE a channel: some channels forget in tens of
+# tokens, some carry state over thousands
+_RATE_RANGE = (1.0, 4.0)
+_G0_RANGE = (1e-4, 1e-1)
+
+
+def kda_gate(input, n_head, lower_bound=-5.0, name=None):
+    """The safe log-decay gate of a KDA mixer: input [B, T, H*D] the
+    decay projection ->
+
+        g = lower_bound * sigmoid(exp(A_log_h) * (input + dt_bias))
+
+    float32 in (lower_bound, 0).  Parameters `<name>_A_log.w` [H] and
+    `<name>_dt_bias.w` [H*D], float32.  Initial values, from numpy's
+    global generator when the layer is built: exp(A_log) log-uniform in
+    [1, 4]; dt_bias such that -g starts log-uniform in [1e-4, 1e-1] a
+    channel at input 0."""
+    import numpy as np
+
+    from paddle_tpu.initializer import NumpyArrayInitializer
+
+    helper = LayerHelper("kda_gate", name=name)
+    width = int(input.shape[-1])
+    rate = np.exp(np.random.uniform(*np.log(_RATE_RANGE), n_head))
+    g0 = np.exp(np.random.uniform(*np.log(_G0_RANGE), width))
+    # sigmoid(rate * b) = g0 / -lower_bound
+    share = g0 / -float(lower_bound)
+    bias = np.log(share / (1.0 - share)) / np.repeat(rate, width // n_head)
+    a_log = helper.create_parameter(
+        _named(None, name, "A_log"), [n_head], "float32",
+        default_initializer=NumpyArrayInitializer(
+            np.log(rate).astype(np.float32)))
+    dt_bias = helper.create_parameter(
+        _named(None, name, "dt_bias"), [width], "float32",
+        default_initializer=NumpyArrayInitializer(bias.astype(np.float32)))
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="kda_gate",
+        inputs={"X": input, "ALog": a_log, "DtBias": dt_bias},
+        outputs={"G": out}, attrs={"lower_bound": float(lower_bound)})
+    return out
+
+
+def head_l2_norm(input, n_head, scale=1.0, epsilon=1e-6, name=None):
+    """scale * x_h / sqrt(sum(x_h^2) + epsilon) for each of the n_head
+    slices of the last axis; no parameter."""
+    helper = LayerHelper("head_l2_norm", name=name)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="head_l2_norm", inputs={"X": input}, outputs={"Y": out},
+        attrs={"n_head": int(n_head), "scale": float(scale),
+               "epsilon": float(epsilon)})
+    return out
+
+
+def head_gated_rms_norm(input, gate, epsilon=1e-6, norm=True,
+                        param_attr=None, name=None):
+    """sigmoid(gate_h) * RMSNorm(x_h) * scale for each head's slice x_h
+    of the last axis: input [.., H*D], gate [.., H] one logit a head,
+    the norm a head with ONE learnable scale of D (`<name>.w`,
+    initially 1), the gate after the norm.  norm=False: no norm and no
+    parameter, sigmoid(gate_h) * x_h."""
+    from paddle_tpu.initializer import Constant
+
+    helper = LayerHelper("head_gated_rms_norm", name=name)
+    inputs = {"X": input, "Gate": gate}
+    if norm:
+        inputs["Scale"] = helper.create_parameter(
+            _named(param_attr, name, ""),
+            [int(input.shape[-1]) // int(gate.shape[-1])], "float32",
+            default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="head_gated_rms_norm", inputs=inputs,
+                     outputs={"Y": out},
+                     attrs={"epsilon": float(epsilon)})
+    return out

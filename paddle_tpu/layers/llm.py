@@ -77,9 +77,13 @@ def swiglu(gate, up, name=None):
 
 def moe_route(input, n_experts, k, routed_scaling_factor=1.0,
               norm_topk_prob=True, param_attr=None, bias_attr=None,
-              name=None, scoring_func="sigmoid", return_scores=False):
-    """Top-k router over ALL n_experts (n_group = topk_group = 1):
-    returns (topk_idx int32, topk_weight float32), both [.., k], and
+              name=None, scoring_func="sigmoid", return_scores=False,
+              n_group=1, topk_group=1):
+    """Top-k router over ALL n_experts, group-limited where n_group > 1
+    (the experts are n_group groups of consecutive ids; the topk_group
+    groups with the largest sum of their two best s + bias are kept and
+    the k experts are chosen among theirs; DeepSeek-V3 2.1.2): returns
+    (topk_idx int32, topk_weight float32), both [.., k], and
     with return_scores the float32 scores [.., n_experts] as a third,
     which carry a gradient to every expert (a balance loss reads
     them).  `scoring_func` chooses the scores and the selection:
@@ -116,7 +120,8 @@ def moe_route(input, n_experts, k, routed_scaling_factor=1.0,
         attrs={"k": int(k),
                "routed_scaling_factor": float(routed_scaling_factor),
                "norm_topk_prob": bool(norm_topk_prob),
-               "scoring_func": str(scoring_func)})
+               "scoring_func": str(scoring_func),
+               "n_group": int(n_group), "topk_group": int(topk_group)})
     return (idx, weight, scores) if return_scores else (idx, weight)
 
 

@@ -69,6 +69,9 @@ def latent_attention(u, config, seq_len, fc, prefix, lp):
     come from one rank-`kv_lora_rank` latent, RMS-normed, plus ONE
     rotary key a token that every head shares; q.k size qk_nope +
     qk_rope, v size v_head_dim; causal `flash_attention` head-major.
+    With the config key `attention_output_gate` "head_wise" each head's
+    output is multiplied by sigmoid(u w_gate^h), one logit a head
+    (`<lp>_gate`), before W_o; without the key no gate op is built.
 
     fc(x, size, name) is the model's bias-free projection over its own
     parameter names (`<lp>_q` or `<lp>_q_a`/`<lp>_q_b`, `<lp>_kv_a`,
@@ -104,4 +107,11 @@ def latent_attention(u, config, seq_len, fc, prefix, lp):
         scale=attention_scale(config))
     out = layers.reshape(layers.transpose(out, [0, 2, 1, 3]),
                          [-1, seq_len, heads * vd])
+    gate = config.get("attention_output_gate")
+    if gate == "head_wise":
+        out = layers.head_gated_rms_norm(out, fc(u, heads, lp + "_gate"),
+                                         norm=False)
+    elif gate:
+        raise NotImplementedError(
+            "latent_attention: attention_output_gate %r" % (gate,))
     return fc(out, config["hidden_size"], lp + "_o")

@@ -1,0 +1,218 @@
+"""Ops of a Kimi Delta Attention mixer (KDA; Kimi Linear,
+arXiv:2510.26692): the chunked delta-rule recurrence `kda_scan` with its
+registered grad op, the safe log-decay gate `kda_gate`, the per-head
+L2 norm `head_l2_norm`, and the head-wise gated RMSNorm
+`head_gated_rms_norm`.
+
+Equations: docs/LING3_BLOCK.md.  models/ling3.py builds its mixer from
+these through layers/kda.py; the kernels are ops/pallas_kda.py.
+
+Precision under AMP (contrib/mixed_precision): the scan's MXU operands
+Q, K and V are bfloat16; G, Beta, every decay, the inverse of a chunk's
+triangular system, the running state and the saved block states are
+float32 (fp16_utils._WHITE_KEEP_FP32).  The gate computes and writes
+float32; the norms compute in float32 and write in X's dtype.
+
+Every compute runs under a jax.named_scope (pt_kda, pt_kda_gate,
+pt_head_l2_norm, pt_head_gated_norm); the kernels are the Mosaic calls
+pt_kda_fwd and pt_kda_bwd.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from paddle_tpu.core.registry import REQUIRED, register_op
+from paddle_tpu.ops import pallas_kda
+from paddle_tpu.ops import pallas_kernels as pk
+
+_F32 = jnp.float32
+
+_KDA_INPUTS = ("Q", "K", "V", "G", "Beta")
+_KDA_ATTRS = {"chunk_size": 64, "block_chunks": 4, "impl": ""}
+
+
+def _kda_impl(ins, attrs):
+    """The impl the scan resolves to: the one asked for, else pallas on
+    a TPU and xla elsewhere; xla too where the kernels cannot tile the
+    sizes (pallas_kda.kernel_geom_ok).  Raises what does not fit."""
+    d = pallas_kda.check_shapes(ins["Q"], ins["V"], ins["Beta"],
+                                attrs["chunk_size"],
+                                attrs["block_chunks"])[3]
+    impl = attrs["impl"] or pk._auto_impl()
+    if impl != "xla" and not pallas_kda.kernel_geom_ok(d):
+        impl = "xla"
+    return impl
+
+
+@register_op("kda_scan", inputs=_KDA_INPUTS, outputs=("O", "States"),
+             attrs=_KDA_ATTRS)
+def kda_scan(ins, attrs):
+    """The delta-rule recurrence with a decay per key channel, by
+    chunks of `chunk_size` tokens (ops/pallas_kda.py has the
+    algorithm).  Per head, from a zero state:
+
+        S_t = (I - Beta_t k_t k_t^T) Diag(exp G_t) S_{t-1}
+              + Beta_t k_t v_t^T,      o_t = S_t^T q_t
+
+    Q, K, V [B, T, H*D] token-major, G [B, T, H*D] the log-decay a key
+    channel (float32, in [-5, 0]: a chunk kernel forms e^(-G) over 16
+    tokens), Beta [B, T, H] -> O [B, T, H*D] in V's dtype and States,
+    float32 [B, T / (block_chunks chunk_size), H*D, D]: the TRANSPOSED
+    state each block of chunks starts from, the residual kda_scan_grad
+    reads.  T % (block_chunks chunk_size) != 0 raises; nothing is
+    padded.  chunk_size is a multiple of 16 up to 64.  impl: "" (pallas
+    on a TPU, xla elsewhere), "pallas", "interpret", "xla" (the same
+    chunked algorithm in jax.numpy)."""
+    impl = _kda_impl(ins, attrs)
+    pk._count_impl("kda_scan", impl)
+    args = tuple(ins[s] for s in _KDA_INPUTS)
+    sizes = (attrs["chunk_size"], attrs["block_chunks"])
+    with jax.named_scope("pt_kda"):
+        if impl == "xla":
+            o, states = pallas_kda.kda_chunked_xla(*args, *sizes)
+        else:
+            # see pallas_kernels._flash_attention_fwd: one call line
+            with pk._obs_device.annotate("kda_scan"), pk._kernel_scope():
+                o, states = pallas_kda.kda_fwd_pallas(
+                    *args, *sizes, interpret=impl == "interpret")
+    return {"O": o, "States": states}
+
+
+def _kda_grad_reads_saved(ins, attrs):
+    """Whether kda_scan_grad runs the backward kernel on the forward's
+    States: bound (with O, which a recompute segment takes in place of
+    the op's replay) and the impl a kernel (OpDef.reads_saved)."""
+    return "O" in ins and "States" in ins \
+        and _kda_impl(ins, attrs) != "xla"
+
+
+@register_op("kda_scan_grad",
+             inputs=_KDA_INPUTS + ("O", "States", "O@GRAD"),
+             outputs=tuple(s + "@GRAD" for s in _KDA_INPUTS),
+             optional=("O", "States"), attrs=_KDA_ATTRS,
+             differentiable=False, reads_saved=_kda_grad_reads_saved)
+def kda_scan_grad(ins, attrs):
+    """Hand-written, as ssd_scan_grad is and for its reason: the
+    generic jax.vjp grad op would run pt_kda_fwd a second time in every
+    layer, and a recompute segment's replay a third.
+
+      * O and States bound (append_backward binds them; a recompute
+        segment binds them on the op it replays) and the impl a
+        kernel: pt_kda_bwd on the saved block states.  The forward
+        kernel does not run again;
+      * unbound (a hand-built op): the forward kernel again for the
+        states, then pt_kda_bwd;
+      * the xla impl: jax.vjp over the forward op's compute.
+
+    paddle_tpu_kernel_impl_total{kernel="kda_scan_grad"} says which:
+    impl="saved" | "recompute"."""
+    args = tuple(ins[s] for s in _KDA_INPUTS)
+    g = ins["O@GRAD"]
+    impl = _kda_impl(ins, attrs)
+    saved = impl != "xla" and "O" in ins and "States" in ins
+    pk._count_impl("kda_scan_grad", "saved" if saved else "recompute")
+    if impl == "xla":
+        _, vjp = jax.vjp(
+            lambda *a: kda_scan(dict(zip(_KDA_INPUTS, a)), attrs)["O"],
+            *args)
+        grads = vjp(g)
+    else:
+        states = ins["States"] if saved else kda_scan(ins, attrs)["States"]
+        with jax.named_scope("pt_kda"), \
+                pk._obs_device.annotate("kda_scan_grad"), \
+                pk._kernel_scope():
+            grads = pallas_kda.kda_bwd_pallas(
+                *args, states, g, attrs["chunk_size"],
+                attrs["block_chunks"], interpret=impl == "interpret")
+    return {s + "@GRAD": v for s, v in zip(_KDA_INPUTS, grads)}
+
+
+@register_op("kda_gate", inputs=("X", "ALog", "DtBias"), outputs=("G",),
+             attrs={"lower_bound": -5.0})
+def kda_gate(ins, attrs):
+    """The safe log-decay gate: X [B, T, H*D] the decay projection,
+    ALog [H], DtBias [H*D] ->
+
+        G = lower_bound * sigmoid(exp(ALog_h) * (X + DtBias))
+
+    float32 in (lower_bound, 0), so that exp G lies in (e^lower_bound,
+    1) a channel.  lower_bound is negative."""
+    x = ins["X"]
+    h = ins["ALog"].shape[0]
+    if attrs["lower_bound"] >= 0:
+        raise ValueError("kda_gate: lower_bound %r is not negative"
+                         % (attrs["lower_bound"],))
+    with jax.named_scope("pt_kda_gate"):
+        rate = jnp.repeat(jnp.exp(ins["ALog"].astype(_F32)),
+                          x.shape[-1] // h)
+        return {"G": attrs["lower_bound"] * jax.nn.sigmoid(
+            rate * (x.astype(_F32) + ins["DtBias"].astype(_F32)))}
+
+
+def _head_indicator(width, n_head):
+    """float32 [H*D, H]: 1 where a channel of the last axis belongs to
+    a head.  The norms reduce a head and spread a head's factor back
+    as two thin products against it, so X keeps its [.., H*D] layout:
+    a reshape to [.., H, D] is a relayout of every operand on the chip
+    and its reduce a lane reduce a head (0.70 -> 0.25 ms an L2 norm
+    forward at 4,096 x 4,096 bfloat16; PERF.md section 6, PR 41)."""
+    if width % n_head:
+        raise ValueError("%d channels are no multiple of %d heads"
+                         % (width, n_head))
+    return (jnp.arange(width)[:, None] // (width // n_head)
+            == jnp.arange(n_head)[None]).astype(_F32)
+
+
+def _head_sum(x, indicator):
+    """x float32 [.., H*D] -> its sum over each head's D, [.., H]."""
+    return jnp.matmul(x, indicator, precision=lax.Precision.HIGHEST)
+
+
+def _head_spread(per_head, indicator):
+    """per_head float32 [.., H] -> [.., H*D], a head's value at each of
+    its channels (exact: one term a channel, float32 `highest`)."""
+    return jnp.matmul(per_head, indicator.T,
+                      precision=lax.Precision.HIGHEST)
+
+
+@register_op("head_l2_norm", inputs=("X",), outputs=("Y",),
+             attrs={"n_head": REQUIRED, "scale": 1.0, "epsilon": 1e-6})
+def head_l2_norm(ins, attrs):
+    """Y = scale * X_h / sqrt(sum(X_h^2) + epsilon) for each of the
+    n_head slices X_h of the last axis, float32 inside, Y in X's
+    dtype."""
+    x = ins["X"]
+    with jax.named_scope("pt_head_l2_norm"):
+        ind = _head_indicator(x.shape[-1], attrs["n_head"])
+        xf = x.astype(_F32)
+        inv = attrs["scale"] * lax.rsqrt(
+            _head_sum(jnp.square(xf), ind) + attrs["epsilon"])
+        return {"Y": (xf * _head_spread(inv, ind)).astype(x.dtype)}
+
+
+@register_op("head_gated_rms_norm", inputs=("X", "Gate", "Scale"),
+             outputs=("Y",), attrs={"epsilon": 1e-6}, optional=("Scale",))
+def head_gated_rms_norm(ins, attrs):
+    """X [.., H*D], Gate [.., H] (one logit a head), Scale [D] ->
+
+        Y_h = sigmoid(Gate_h) * RMSNorm(X_h) * Scale
+
+    the norm a head (the statistic over the head's D entries), the gate
+    a head and AFTER the norm.  Scale unbound: no norm, Y_h =
+    sigmoid(Gate_h) * X_h (latent attention's output gate).  Float32
+    inside, Y in X's dtype."""
+    x, gate = ins["X"], ins["Gate"]
+    n_head = gate.shape[-1]
+    with jax.named_scope("pt_head_gated_norm"):
+        ind = _head_indicator(x.shape[-1], n_head)
+        xf = x.astype(_F32)
+        per_head = jax.nn.sigmoid(gate.astype(_F32))
+        if ins.get("Scale") is not None:
+            per_head = per_head * lax.rsqrt(
+                _head_sum(jnp.square(xf), ind) / (x.shape[-1] // n_head)
+                + attrs["epsilon"])
+            xf = xf * jnp.tile(ins["Scale"].astype(_F32), n_head)
+        return {"Y": (xf * _head_spread(per_head, ind)).astype(x.dtype)}

@@ -165,7 +165,8 @@ def rotary_embedding(ins, attrs):
 @register_op("moe_route", inputs=("X", "W", "Bias"),
              outputs=("TopkIdx", "TopkWeight", "Scores"),
              attrs={"k": REQUIRED, "routed_scaling_factor": 1.0,
-                    "norm_topk_prob": True, "scoring_func": "sigmoid"},
+                    "norm_topk_prob": True, "scoring_func": "sigmoid",
+                    "n_group": 1, "topk_group": 1},
              optional=("Bias",))
 def moe_route(ins, attrs):
     """Scores over ALL experts, float32, by `scoring_func`: "sigmoid"
@@ -177,8 +178,16 @@ def moe_route(ins, attrs):
     (norm_topk_prob) or routed_scaling_factor * s_e.  X [.., C], W
     [C, E], Bias [E] -> TopkIdx int32, TopkWeight float32, both
     [.., k], and Scores = s, float32 [.., E]: what a balance loss
-    reads, with a gradient to every expert's score.  No group limit
-    (n_group = topk_group = 1), no capacity: nothing is dropped here."""
+    reads, with a gradient to every expert's score.  No capacity:
+    nothing is dropped here.
+
+    Group-limited selection (DeepSeek-V3 2.1.2, `noaux_tc`) with
+    n_group > 1: the E experts are n_group groups of E / n_group
+    consecutive ids, a group's score is the sum of its two largest
+    s + Bias, the topk_group best groups are kept, and the k experts
+    are the largest s + Bias among the kept groups' (k <= topk_group E
+    / n_group).  n_group 1 is the selection over all experts and the
+    program of before the option."""
     x, w = ins["X"], ins["W"]
     scoring = attrs["scoring_func"]
     if scoring not in ("sigmoid", "softmax"):
@@ -186,7 +195,22 @@ def moe_route(ins, attrs):
                          "'sigmoid' nor 'softmax'" % (scoring,))
     from paddle_tpu.ops import pallas_kernels as pk
 
+    n_group, topk_group = attrs["n_group"], attrs["topk_group"]
+    per_group = w.shape[-1] // max(n_group, 1)
+    # whole groups, of two experts or more where there are several (a
+    # group's score is the sum of its two best), enough kept for k
+    if not (n_group >= 1 and w.shape[-1] % n_group == 0
+            and (n_group == 1 or per_group >= 2)
+            and 1 <= topk_group <= n_group
+            and attrs["k"] <= topk_group * per_group):
+        raise ValueError(
+            "moe_route: %d experts in n_group %d with topk_group %d "
+            "cannot give k = %d" % (w.shape[-1], n_group, topk_group,
+                                    attrs["k"]))
     pk._count_impl("moe_route_scoring", scoring)
+    if n_group > 1:
+        pk._count_impl("moe_route_groups",
+                       "%dof%d" % (topk_group, n_group))
     with jax.named_scope("pt_moe_route"):
         z = jnp.matmul(x.astype(_F32), w.astype(_F32), precision=_HIGHEST)
         if scoring == "softmax":
@@ -195,6 +219,16 @@ def moe_route(ins, attrs):
             s = ranked = jax.nn.sigmoid(z)
             if ins.get("Bias") is not None:
                 ranked = s + ins["Bias"].astype(_F32)
+        if n_group > 1:
+            with jax.named_scope("pt_moe_route_groups"):
+                grouped = ranked.reshape(ranked.shape[:-1]
+                                         + (n_group, per_group))
+                _, best = lax.top_k(
+                    jnp.sum(lax.top_k(grouped, 2)[0], axis=-1), topk_group)
+                kept = jnp.any(best[..., None] == jnp.arange(n_group),
+                               axis=-2)
+                ranked = jnp.where(kept[..., None], grouped,
+                                   -jnp.inf).reshape(ranked.shape)
         _, idx = lax.top_k(ranked, attrs["k"])
         sel = jnp.take_along_axis(s, idx, axis=-1)
         if attrs["norm_topk_prob"]:

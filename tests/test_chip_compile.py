@@ -113,6 +113,8 @@ def _gmm(which, rows=16384, held=8, hidden=3584, width=1024, tm=256):
 
 
 DSV2_GMM = dict(rows=49152, hidden=2048, width=1408)
+# ling3: 32,768 pairs are the layout's 34,816 rows, 136 tiles
+LING3_GMM = dict(rows=32768, hidden=2560, width=768)
 
 
 def _decode(head_dim, head_pack, batch=64, heads=8, page_size=128,
@@ -166,7 +168,24 @@ def _ssd(grad, b=1, t=8192, h=64, p=64, n=128, chunk=256):
         ins + (_sds((b, t // chunk, h * p, n), f32), x), 1
 
 
+def _kda(grad, b=1, t=4096, h=32, d=128, chunk=64, block_chunks=4):
+    """The delta-rule scan of ling-3.0-flash-vl's KDA mixer at the
+    cell's size: 32 heads of 128, 16 blocks of 4 chunks of 64."""
+    from paddle_tpu.ops.pallas_kda import kda_bwd_pallas, kda_fwd_pallas
+
+    f32 = jnp.float32
+    x = _sds((b, t, h * d))
+    ins = (x, x, x, _sds((b, t, h * d), f32), _sds((b, t, h), f32))
+    sizes = dict(chunk=chunk, block_chunks=block_chunks)
+    if not grad:
+        return (lambda *a: kda_fwd_pallas(*a, **sizes)), ins, 1
+    return (lambda *a: kda_bwd_pallas(*a, **sizes)), ins + (
+        _sds((b, t // (chunk * block_chunks), h * d, d), f32), x), 1
+
+
 CASES = {
+    "kda_fwd_1x4096_h32_d128": lambda: _kda(False),
+    "kda_bwd_1x4096_h32_d128": lambda: _kda(True),
     "ssd_fwd_1x8192_h64_p64_n128": lambda: _ssd(False),
     "ssd_bwd_1x8192_h64_p64_n128": lambda: _ssd(True),
     "flash_fwd_32x8x512x64": lambda: _flash((32, 8, 512, 64), False),
@@ -210,6 +229,9 @@ CASES = {
     "gmm_fwd_8x2048x1408_rows51200": lambda: _gmm("fwd", **DSV2_GMM),
     "gmm_bwd_dx_8x2048x1408_rows51200": lambda: _gmm("dx", **DSV2_GMM),
     "gmm_bwd_dw_8x2048x1408_rows51200": lambda: _gmm("dw", **DSV2_GMM),
+    "gmm_fwd_8x2560x768_rows34816": lambda: _gmm("fwd", **LING3_GMM),
+    "gmm_bwd_dx_8x2560x768_rows34816": lambda: _gmm("dx", **LING3_GMM),
+    "gmm_bwd_dw_8x2560x768_rows34816": lambda: _gmm("dw", **LING3_GMM),
     "flash_decode_d128_b64": lambda: _decode(128, False),
     "flash_decode_d64_headpacked_b64": lambda: _decode(64, True),
     "conv2d_epilogue_3x3_56x56x64_mb128": lambda: _conv(False),

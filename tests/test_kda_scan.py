@@ -1,0 +1,453 @@
+"""ops/kda_ops.py: the chunked delta-rule scan (its `xla` and
+`interpret` impls) against the recurrence run TOKEN BY TOKEN, forward
+and all five input gradients; the state crossing chunk, block and
+sub-block edges; a run at the decay's bound; the registered grad op on
+the forward's saved block states, alone and inside a recompute
+segment; the gate, the per-head L2 norm and the head-wise gated norm.
+
+The decay is drawn wide on purpose (-g log-uniform in [1e-3, 2] a
+channel: a channel keeps from 97% down to 1e-14 of itself over a
+16-token sub-block), and beta in (0.1, 0.9), so that a scan that lost
+the state between chunks, or formed a decay across a sub-block edge
+wrongly, would be far outside the tolerance:
+`test_the_state_crosses_chunks` holds the reference with the state
+zeroed at every chunk start to the same bound and requires that it
+FAILS it.
+
+Tolerance: float32 against float32 in another order of summation, with
+a triangular system inverted by products: 5e-5 of each array's largest
+entry (3e-6 seen).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+import paddle_tpu as fluid
+from paddle_tpu import layers, optimizer
+from paddle_tpu.core.registry import get_op_def
+from paddle_tpu.ops import pallas_kernels as pk
+
+SLOTS = ("Q", "K", "V", "G", "Beta")
+TOL = 5e-5
+
+
+def token_by_token(q, k, v, g, beta, reset_every=0, erase=True):
+    """q, k, v, g [B, T, H*D], beta [B, T, H] -> o [B, T, H*D];
+    reset_every zeroes the state every so many tokens, erase False
+    drops the delta rule's erase term (wrong scans on purpose)."""
+    b, t, width = q.shape
+    h = beta.shape[-1]
+
+    def heads(x):
+        return x.reshape(b, t, h, width // h).transpose(1, 0, 2, 3)
+
+    def step(s, inp):
+        i, qt, kt, vt, gt, bt = inp
+        if reset_every:
+            s = jnp.where(i % reset_every == 0, 0.0, s)
+        s = jnp.exp(gt)[..., None] * s
+        seen = jnp.einsum("bhk,bhkv->bhv", kt, s) if erase else 0.0
+        s = s + (bt[..., None] * kt)[..., None] * (vt - seen)[..., None, :]
+        return s, jnp.einsum("bhk,bhkv->bhv", qt, s)
+
+    d = width // h
+    _, o = lax.scan(
+        step, jnp.zeros((b, h, d, d), q.dtype),
+        (jnp.arange(t), heads(q), heads(k), heads(v), heads(g),
+         beta.transpose(1, 0, 2)))
+    return o.transpose(1, 0, 2, 3).reshape(b, t, width)
+
+
+def operands(t, h=2, d=128, b=2, seed=0, g_fixed=None):
+    r = np.random.RandomState(seed)
+
+    def unit(x):
+        x = x.reshape(b, t, h, d)
+        return (x / np.linalg.norm(x, axis=-1, keepdims=True)).reshape(
+            b, t, h * d)
+
+    f = lambda *s: r.randn(*s).astype(np.float32)  # noqa: E731
+    g = -np.exp(r.uniform(np.log(1e-3), np.log(2.0), (b, t, h * d)))
+    if g_fixed is not None:
+        g = np.full_like(g, g_fixed)
+    args = (unit(f(b, t, h * d)) * d ** -0.5, unit(f(b, t, h * d)),
+            f(b, t, h * d), g.astype(np.float32),
+            r.uniform(0.1, 0.9, (b, t, h)).astype(np.float32))
+    return tuple(jnp.asarray(a) for a in args), jnp.asarray(f(b, t, h * d))
+
+
+def rel(got, want):
+    return float(jnp.max(jnp.abs(got - want))
+                 / (jnp.max(jnp.abs(want)) + 1e-30))
+
+
+def reference(args, go, **wrong):
+    with jax.default_matmul_precision("highest"):
+        o, vjp = jax.vjp(lambda *a: token_by_token(*a, **wrong), *args)
+        return o, vjp(go)
+
+
+def scan_and_grads(args, go, attrs):
+    ins = dict(zip(SLOTS, args))
+    outs = get_op_def("kda_scan").compute(ins, attrs)
+    grads = get_op_def("kda_scan_grad").compute(
+        dict(ins, O=outs["O"], States=outs["States"], **{"O@GRAD": go}),
+        attrs)
+    return outs, grads
+
+
+# (tokens, chunk, chunks a block): one block of one chunk; a chunk of
+# two sub-blocks; blocks of several chunks; the cell's 4 x 64
+SHAPES = [(16, 16, 1), (64, 32, 1), (128, 32, 2), (256, 64, 2),
+          (256, 64, 4)]
+
+
+@pytest.mark.parametrize("t,chunk,block_chunks", SHAPES)
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_forward_and_five_gradients_against_the_recurrence(
+        impl, t, chunk, block_chunks):
+    args, go = operands(t, b=1 if t > 128 else 2)
+    want_o, want_g = reference(args, go)
+    attrs = {"chunk_size": chunk, "block_chunks": block_chunks,
+             "impl": impl}
+    outs, grads = scan_and_grads(args, go, attrs)
+    b = args[0].shape[0]
+    assert outs["States"].shape == (b, t // (chunk * block_chunks),
+                                    2 * 128, 128)
+    assert outs["States"].dtype == jnp.float32
+    assert rel(outs["O"], want_o) <= TOL
+    errors = {s: rel(grads[s + "@GRAD"], g) for s, g in zip(SLOTS, want_g)}
+    assert all(e <= TOL for e in errors.values()), errors
+    assert all(grads[s + "@GRAD"].dtype == v.dtype
+               and grads[s + "@GRAD"].shape == v.shape
+               for s, v in zip(SLOTS, args))
+    # every gradient is there to be compared
+    assert all(float(jnp.abs(g).max()) > 0 for g in want_g)
+
+
+def test_the_state_crosses_chunks():
+    """The recurrence with its state zeroed at every chunk start, or
+    without the erase term, is NOT the scan: it misses the tolerance by
+    orders of magnitude, in the output and in every gradient."""
+    args, go = operands(64)
+    want_o, want_g = reference(args, go)
+    for wrong in ({"reset_every": 16}, {"erase": False}):
+        lost_o, lost_g = reference(args, go, **wrong)
+        assert rel(lost_o, want_o) > 1000 * TOL, wrong
+        assert all(rel(lo, w) > 1000 * TOL
+                   for lo, w in zip(lost_g, want_g)), wrong
+    # the first chunk, which starts from zero either way, agrees
+    lost_o, _ = reference(args, go, reset_every=16)
+    assert rel(lost_o[:, :16], want_o[:, :16]) <= TOL
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_a_run_at_the_decays_bound_is_finite_and_right(impl):
+    """128 tokens at g = -5 a channel throughout (two chunks of 64,
+    eight sub-blocks): e^(-G) over a chunk would be e^320.  Nothing
+    overflows, the state a token leaves is not lost to the next (e^-5
+    of it is kept, 0.7% of the output), and a mixed run, which sits at
+    the bound for 70 tokens and then keeps everything, is right too."""
+    for g_of in (lambda g: g, lambda g: g.at[:, 70:].set(-1e-4)):
+        args, go = operands(128, b=1, g_fixed=-5.0)
+        args = args[:3] + (g_of(args[3]),) + args[4:]
+        want_o, want_g = reference(args, go)
+        outs, grads = scan_and_grads(
+            args, go, {"chunk_size": 64, "block_chunks": 2, "impl": impl})
+        assert bool(jnp.isfinite(outs["O"]).all())
+        assert all(bool(jnp.isfinite(g).all()) for g in grads.values())
+        assert rel(outs["O"], want_o) <= TOL
+        errors = {s: rel(grads[s + "@GRAD"], g)
+                  for s, g in zip(SLOTS, want_g)}
+        # at the bound throughout, d G is e^-5-small itself (6e-4 at
+        # its largest) and what is left of the end-of-chunk terms'
+        # cancellation in float32 (4e-6) shows: held to 1e-5 absolute
+        errors["G"] = float(jnp.abs(grads["G@GRAD"] - want_g[3]).max()) \
+            / max(float(jnp.abs(want_g[3]).max()), 0.2)
+        assert all(e <= TOL for e in errors.values()), errors
+    # what the previous token left is in the output: without it the
+    # output is another by far more than the tolerance
+    lost_o, _ = reference(args, go, reset_every=1)
+    assert rel(lost_o, want_o) > 100 * TOL
+
+
+def test_states_are_the_transposed_state_each_block_starts_from():
+    args, _ = operands(64, b=1)
+    outs = get_op_def("kda_scan").compute(
+        dict(zip(SLOTS, args)),
+        {"chunk_size": 16, "block_chunks": 2, "impl": "interpret"})
+    q, k, v, g, beta = (a[0] for a in args)
+    s = jnp.zeros((2, 128, 128), jnp.float32)
+    for t in range(64):
+        if t % 32 == 0:
+            got = outs["States"][0, t // 32].reshape(2, 128, 128)
+            assert rel(got + 1.0, s.transpose(0, 2, 1) + 1.0) <= TOL
+        kt, vt = k[t].reshape(2, 128), v[t].reshape(2, 128)
+        s = jnp.exp(g[t].reshape(2, 128))[..., None] * s
+        s = s + (beta[t][:, None] * kt)[..., None] * (
+            vt - jnp.einsum("hk,hkv->hv", kt, s))[:, None, :]
+
+
+def test_a_length_that_is_no_multiple_of_the_block_raises():
+    args, _ = operands(48)
+    for impl in ("xla", "interpret"):
+        with pytest.raises(ValueError, match="nothing is padded"):
+            get_op_def("kda_scan").compute(
+                dict(zip(SLOTS, args)),
+                {"chunk_size": 16, "block_chunks": 2, "impl": impl})
+    # and at build time, in the layer
+    _fresh()
+    x = layers.data("x", shape=[48, 256], dtype="float32")
+    b = layers.data("b", shape=[48, 2], dtype="float32")
+    with pytest.raises(ValueError, match="nothing is padded"):
+        layers.kda_scan(x, x, x, x, b, chunk_size=16, block_chunks=2)
+    layers.kda_scan(x, x, x, x, b, chunk_size=16, block_chunks=3)
+    # the inverse's series covers four 16-row blocks: no longer chunk
+    long_args, _ = operands(128)
+    with pytest.raises(ValueError, match="up to 64"):
+        get_op_def("kda_scan").compute(
+            dict(zip(SLOTS, long_args)),
+            {"chunk_size": 128, "block_chunks": 1, "impl": "xla"})
+
+
+def test_sizes_the_kernels_cannot_tile_run_the_xla_form():
+    args, go = operands(32, h=4, d=32)
+    want_o, want_g = reference(args, go)
+    before = _counts()
+    outs, grads = scan_and_grads(
+        args, go, {"chunk_size": 16, "block_chunks": 1,
+                   "impl": "interpret"})
+    assert _since(before) == {("kda_scan", "xla"): 2,
+                              ("kda_scan_grad", "recompute"): 1}
+    assert rel(outs["O"], want_o) <= TOL
+    assert all(rel(grads[s + "@GRAD"], g) <= TOL
+               for s, g in zip(SLOTS, want_g))
+
+
+def _counts():
+    return {(lbl["kernel"], lbl["impl"]): v
+            for lbl, v in pk._M_KERNEL_IMPL.items()}
+
+
+def _since(before):
+    return {k: v - before.get(k, 0) for k, v in _counts().items()
+            if v - before.get(k, 0)}
+
+
+def _fresh():
+    from paddle_tpu import framework, unique_name
+    from paddle_tpu.core import scope as scope_mod
+    from paddle_tpu.core.program import Program
+
+    framework.switch_main_program(Program())
+    framework.switch_startup_program(Program())
+    unique_name.switch({})
+    scope_mod._global_scope = scope_mod.Scope()
+
+
+# -- through the IR: the grad op reads the saved states ---------------------
+
+T, H, D, C = 64, 2, 128, 32
+
+
+def _mixer(x, impl=None):
+    """The scan with what a KDA mixer puts round it, every operand a
+    projection of x: (o, the decay projection's gate)."""
+    def fc(width, name):
+        return layers.fc(x, width, num_flatten_dims=2, bias_attr=False,
+                         name=name)
+
+    def branch(name):
+        return layers.causal_conv1d(fc(H * D, name), 4, bias_attr=False,
+                                    name=name + "_conv")
+
+    q = layers.head_l2_norm(branch("q"), H, scale=D ** -0.5)
+    k = layers.head_l2_norm(branch("k"), H)
+    g = layers.kda_gate(fc(H * D, "a"), H, name="decay")
+    beta = layers.sigmoid(layers.cast(fc(H, "beta"), "float32"))
+    o = layers.kda_scan(q, k, branch("v"), g, beta, chunk_size=C,
+                        block_chunks=1, impl=impl, name="kda")
+    return layers.head_gated_rms_norm(o, fc(H, "gate"), name="norm")
+
+
+def _net(recompute, impl):
+    x = layers.data("x", shape=[T, 64], dtype="float32")
+    u = layers.fc(x, 64, num_flatten_dims=2, bias_attr=False)
+    out = layers.fc(_mixer(u, impl), 8, num_flatten_dims=2,
+                    bias_attr=False)
+    loss = layers.mean(layers.square(out))
+    opt = optimizer.SGD(0.0)
+    if recompute:
+        opt = optimizer.RecomputeOptimizer(opt)
+        opt._set_checkpoints([u, out])
+    return loss, opt.backward(loss)
+
+
+def _run_net(recompute, impl):
+    _fresh()
+    np.random.seed(0)
+    loss, pg = _net(recompute, impl)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    before = _counts()
+    feed = {"x": np.random.RandomState(0).randn(2, T, 64).astype(
+        np.float32)}
+    outs = exe.run(fluid.CompiledProgram(fluid.default_main_program()),
+                   feed=feed, fetch_list=[loss] + [g for _, g in pg])
+    return ({p.name: np.asarray(o) for (p, _), o in zip(pg, outs[1:])},
+            float(np.asarray(outs[0]).reshape(-1)[0]), _since(before),
+            fluid.default_main_program())
+
+
+def test_grad_op_in_a_recompute_segment_reads_the_saved_states():
+    want, want_loss, _, _ = _run_net(False, "xla")
+    assert {"decay_A_log.w", "decay_dt_bias.w", "norm.w", "q_conv.w"} \
+        <= set(want)
+    for recompute in (False, True):
+        got, loss, used, prog = _run_net(recompute, "interpret")
+        # the forward kernel once, the backward on the saved states:
+        # never a second forward for the grad op, nor a third for the
+        # segment's replay
+        assert used[("kda_scan", "interpret")] == 1, used
+        assert used[("kda_scan_grad", "saved")] == 1, used
+        assert ("kda_scan_grad", "recompute") not in used
+        assert abs(loss - want_loss) <= 1e-5 * abs(want_loss)
+        errors = {n: float(np.abs(got[n] - w).max() / np.abs(w).max())
+                  for n, w in want.items()}
+        assert all(e <= 1e-4 for e in errors.values()), errors
+        if recompute:
+            seg = [op for op in prog.global_block().ops
+                   if op.type == "recompute_segment_grad"
+                   and op.inputs.get("Saved")]
+            assert len(seg) == 1 and len(seg[0].inputs["Saved"]) == 2
+        else:
+            gop, = [op for op in prog.global_block().ops
+                    if op.type == "kda_scan_grad"]
+            assert gop.inputs.get("O") and gop.inputs.get("States")
+
+
+def test_unbound_states_differentiate_the_forward_again():
+    args, go = operands(32)
+    _, want_g = reference(args, go)
+    before = _counts()
+    grads = get_op_def("kda_scan_grad").compute(
+        dict(zip(SLOTS, args), **{"O@GRAD": go}),
+        {"chunk_size": 16, "block_chunks": 2, "impl": "interpret"})
+    assert _since(before)[("kda_scan_grad", "recompute")] == 1
+    assert all(rel(grads[s + "@GRAD"], g) <= TOL
+               for s, g in zip(SLOTS, want_g))
+
+
+def test_amp_keeps_the_decays_beta_and_the_states_float32():
+    from paddle_tpu.contrib.mixed_precision import decorate
+
+    _fresh()
+    np.random.seed(0)
+    x = layers.data("x", shape=[T, 64], dtype="float32")
+    loss = layers.mean(layers.fc(_mixer(x), 8, num_flatten_dims=2))
+    decorate(optimizer.SGD(0.0), init_loss_scaling=1.0,
+             use_dynamic_loss_scaling=False).minimize(loss)
+    block = fluid.default_main_program().global_block()
+    scan, = [op for op in block.ops if op.type == "kda_scan"]
+    gate, = [op for op in block.ops if op.type == "kda_gate"]
+    # the rates and the bias are read as they are: float32
+    assert gate.inputs["ALog"] == ["decay_A_log.w"]
+    assert gate.inputs["DtBias"] == ["decay_dt_bias.w"]
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    feed = {"x": np.random.RandomState(0).randn(2, T, 64).astype(
+        np.float32)}
+    o, states, g, beta, q, k, v = exe.run(
+        fluid.CompiledProgram(fluid.default_main_program()), feed=feed,
+        fetch_list=[scan.outputs["O"][0], scan.outputs["States"][0]]
+        + [scan.inputs[s][0] for s in ("G", "Beta", "Q", "K", "V")],
+        return_numpy=False)
+    assert o.dtype == q.dtype == k.dtype == v.dtype == jnp.bfloat16
+    assert states.dtype == g.dtype == beta.dtype == jnp.float32
+    assert float(g.min()) > -5.0 and float(g.max()) < 0.0
+
+
+# -- the gate, the L2 norm, the gated norm -----------------------------------
+
+def test_kda_gate_is_bounded_and_is_the_formula():
+    r = np.random.RandomState(1)
+    x = jnp.asarray(30 * r.randn(2, 8, 4 * 16), jnp.float32)
+    a_log = jnp.asarray(r.uniform(0, 1.4, 4), jnp.float32)
+    bias = jnp.asarray(r.randn(64), jnp.float32)
+    op = get_op_def("kda_gate")
+    g = op.compute({"X": x, "ALog": a_log, "DtBias": bias},
+                   {"lower_bound": -5.0})["G"]
+    want = -5.0 / (1 + np.exp(-np.repeat(np.exp(a_log), 16)
+                              * (np.asarray(x, np.float64) + bias)))
+    np.testing.assert_allclose(g, want, rtol=1e-5, atol=1e-6)
+    assert g.dtype == jnp.float32
+    assert float(g.min()) >= -5.0 and float(g.max()) <= 0.0
+    # float32 whatever the projection's dtype
+    assert op.compute({"X": x.astype(jnp.bfloat16), "ALog": a_log,
+                       "DtBias": bias}, {"lower_bound": -5.0}
+                      )["G"].dtype == jnp.float32
+    with pytest.raises(ValueError, match="not negative"):
+        op.compute({"X": x, "ALog": a_log, "DtBias": bias},
+                   {"lower_bound": 5.0})
+
+
+def test_kda_gate_starts_with_channels_that_keep_their_state():
+    """At input 0 the layer's initial gate spreads -g log-uniformly
+    over [1e-4, 1e-1] a channel: a tenth of the channels keep more than
+    a third of their state over 2,000 tokens (PR 38's finding: with a
+    state that forgets in a few tokens no check sees a broken pass
+    between chunks)."""
+    _fresh()
+    np.random.seed(3)
+    x = layers.data("x", shape=[4, 8 * 128], dtype="float32")
+    g = layers.kda_gate(x, 8, name="decay")
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    got, = exe.run(feed={"x": np.zeros((1, 4, 1024), np.float32)},
+                   fetch_list=[g])
+    g0 = -np.asarray(got)[0, 0]
+    assert 0.9e-4 < g0.min() < 2e-4 and 0.5e-1 < g0.max() < 1.1e-1
+    assert np.mean(np.exp(-2000 * g0) > 1 / 3) > 0.1
+
+
+def test_head_l2_norm_is_a_norm_a_head():
+    r = np.random.RandomState(2)
+    x = jnp.asarray(r.randn(2, 5, 3 * 8), jnp.float32)
+    y = get_op_def("head_l2_norm").compute(
+        {"X": x}, {"n_head": 3, "scale": 0.5, "epsilon": 1e-6})["Y"]
+    xh = np.asarray(x).reshape(2, 5, 3, 8)
+    want = 0.5 * xh / np.sqrt((xh ** 2).sum(-1, keepdims=True) + 1e-6)
+    np.testing.assert_allclose(y, want.reshape(2, 5, 24), rtol=1e-5)
+    got = get_op_def("head_l2_norm").compute(
+        {"X": x.astype(jnp.bfloat16)},
+        {"n_head": 3, "scale": 0.5, "epsilon": 1e-6})["Y"]
+    assert got.dtype == jnp.bfloat16
+
+
+def test_head_gated_rms_norm_gates_a_head_after_the_norm():
+    r = np.random.RandomState(3)
+    x = jnp.asarray(r.randn(2, 5, 3 * 8), jnp.float32)
+    gate = jnp.asarray(r.randn(2, 5, 3), jnp.float32)
+    scale = jnp.asarray(r.uniform(0.5, 1.5, 8), jnp.float32)
+    op = get_op_def("head_gated_rms_norm")
+    y = op.compute({"X": x, "Gate": gate, "Scale": scale},
+                   {"epsilon": 1e-6})["Y"]
+    xh = np.asarray(x).reshape(2, 5, 3, 8)
+    normed = xh / np.sqrt((xh ** 2).mean(-1, keepdims=True) + 1e-6) * scale
+    sig = 1 / (1 + np.exp(-np.asarray(gate)))[..., None]
+    np.testing.assert_allclose(y, (sig * normed).reshape(2, 5, 24),
+                               rtol=1e-5, atol=1e-6)
+    # without a scale: the gate alone (latent attention's output gate)
+    y = op.compute({"X": x, "Gate": gate}, {"epsilon": 1e-6})["Y"]
+    np.testing.assert_allclose(y, (sig * xh).reshape(2, 5, 24),
+                               rtol=1e-5, atol=1e-6)
+    # the gate is NOT inside the norm: a head's gate scales its output
+    doubled = op.compute({"X": x, "Gate": gate + 1.0, "Scale": scale},
+                         {"epsilon": 1e-6})["Y"]
+    ratio = (1 / (1 + np.exp(-np.asarray(gate) - 1.0)))[..., None] / sig
+    np.testing.assert_allclose(
+        np.asarray(doubled).reshape(2, 5, 3, 8), sig * normed * ratio,
+        rtol=1e-5, atol=1e-6)

@@ -167,6 +167,108 @@ def test_router_bias_selects_and_does_not_weigh():
     np.testing.assert_allclose(wt2, 2.0 * picked, rtol=F32_TOL)
 
 
+def _group_limited(s, bias, k, n_group, topk_group):
+    """The selection by hand, in numpy float64 over ALL experts: a
+    group's score the sum of its two largest s + bias, the best
+    topk_group groups kept, the k largest s + bias among theirs."""
+    r = s + bias
+    n, e = r.shape
+    per = r.reshape(n, n_group, e // n_group)
+    score = np.sort(per, -1)[..., -2:].sum(-1)
+    alive = np.zeros((n, n_group), bool)
+    np.put_along_axis(alive, np.argsort(-score, -1, kind="stable")
+                      [:, :topk_group], True, -1)
+    r = np.where(np.repeat(alive, e // n_group, -1), r, -np.inf)
+    return np.argsort(-r, -1, kind="stable")[:, :k], alive
+
+
+@pytest.mark.parametrize("n_group,topk_group,k", [(4, 2, 4), (4, 1, 3),
+                                                  (8, 4, 8), (2, 2, 4)])
+def test_group_limited_router_against_the_selection_by_hand(
+        n_group, topk_group, k):
+    rng = np.random.default_rng(7)
+    e = 32
+    x = jnp.asarray(rng.normal(0, 1, (96, 24)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 0.4, (24, e)), jnp.float32)
+    bias = jnp.asarray(rng.normal(0, 0.2, e), jnp.float32)
+    kw = dict(k=k, routed_scaling_factor=2.5, norm_topk_prob=True)
+    before = _impl_counts()
+    idx, wt = _route(x, w, bias, n_group=n_group, topk_group=topk_group,
+                     **kw)
+    assert _impl_since(before) == {
+        ("moe_route_scoring", "sigmoid"): 1,
+        ("moe_route_groups", "%dof%d" % (topk_group, n_group)): 1}
+    s = 1 / (1 + np.exp(-np.asarray(x, np.float64) @ np.asarray(w)))
+    want_idx, alive = _group_limited(s, np.asarray(bias, np.float64), k,
+                                     n_group, topk_group)
+    np.testing.assert_array_equal(np.sort(idx, -1), np.sort(want_idx, -1))
+    # every selected expert lies in a kept group, and with fewer groups
+    # kept than there are the limit binds: the selection over all
+    # experts is another for some token
+    assert np.take_along_axis(alive, idx // (e // n_group), -1).all()
+    free, _ = _route(x, w, bias, **kw)
+    differs = (np.sort(free, -1) != np.sort(idx, -1)).any(-1)
+    assert differs.any() == (topk_group < n_group)
+    # the gates are the raw scores of the selected, normalised: the
+    # bias and the groups select and do not weigh
+    picked = np.take_along_axis(s, idx, -1)
+    np.testing.assert_allclose(
+        wt, 2.5 * picked / picked.sum(-1, keepdims=True), rtol=F32_TOL)
+
+
+def test_router_without_groups_is_the_router_of_before():
+    """n_group 1 (the default, and what `xing4` and `deepseek-v2-lite`
+    pass): the outputs of a call that names no group, bit for bit, and
+    no `moe_route_groups` count."""
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.normal(0, 1, (64, 32)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 0.3, (32, 16)), jnp.float32)
+    bias = jnp.asarray(rng.normal(0, 0.1, 16), jnp.float32)
+    for scoring in ("sigmoid", "softmax"):
+        kw = dict(k=4, routed_scaling_factor=2.0, norm_topk_prob=True,
+                  scoring_func=scoring)
+        plain = _op("moe_route", {"X": x, "W": w, "Bias": bias}, **kw)
+        before = _impl_counts()
+        named = _op("moe_route", {"X": x, "W": w, "Bias": bias},
+                    n_group=1, topk_group=1, **kw)
+        assert _impl_since(before) == {("moe_route_scoring", scoring): 1}
+        for slot in ("TopkIdx", "TopkWeight", "Scores"):
+            assert np.asarray(plain[slot]).tobytes() \
+                == np.asarray(named[slot]).tobytes()
+    # the lowered computation holds no group step
+    from paddle_tpu.core.registry import get_op_def
+
+    op = get_op_def("moe_route")
+    attrs = op.canonical_attrs(dict(k=4))
+    text = jax.jit(lambda x, w, b: op.compute(
+        {"X": x, "W": w, "Bias": b}, attrs)).lower(x, w, bias).as_text(
+            debug_info=True)
+    assert "pt_moe_route" in text and "pt_moe_route_groups" not in text
+
+
+@pytest.mark.parametrize("bad", [dict(n_group=3), dict(n_group=4,
+                                                       topk_group=5),
+                                 dict(n_group=4, topk_group=1, k=5),
+                                 dict(n_group=16, topk_group=4)])
+def test_group_limits_that_cannot_give_k_raise(bad):
+    x, w = jnp.zeros((4, 8)), jnp.zeros((8, 16))
+    kw = dict(dict(k=4, n_group=1, topk_group=1), **bad)
+    with pytest.raises(ValueError, match="cannot give k"):
+        _route(x, w, jnp.zeros(16), **kw)
+
+
+def _impl_counts():
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    return {(lbl["kernel"], lbl["impl"]): v
+            for lbl, v in pk._M_KERNEL_IMPL.items()}
+
+
+def _impl_since(before):
+    return {k: v - before.get(k, 0) for k, v in _impl_counts().items()
+            if v - before.get(k, 0)}
+
+
 def test_router_scores_ignore_the_activation_dtype():
     """Under AMP the router's input arrives rounded to bf16; the scores
     of that input are still float32: the gates sum to the scaling

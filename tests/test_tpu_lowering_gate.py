@@ -46,10 +46,11 @@ def test_bench_workload_lowers_for_tpu(chip_gate, workload):
 
 @pytest.mark.parametrize("workload,flash_ops", [
     ("xing4_train_tiny", 5), ("ouro_train_tiny", 24),
-    ("dsv2_train_tiny", 5), ("granite_train_tiny", 1)])
+    ("dsv2_train_tiny", 5), ("granite_train_tiny", 1),
+    ("ling3_train_tiny", 1)])
 def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         chip_gate, workload, flash_ops):
-    """The four cells that train under RecomputeOptimizer, at their
+    """The five cells that train under RecomputeOptimizer, at their
     depth and head sizes, narrow and short: a segment's backward takes
     the forward's Out and LSE (ISSUE 33), so the compiled step holds
     one `pt_flash_fwd` a flash op and not a second in every segment's
@@ -73,6 +74,19 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert detail["kernel_calls"]["pt_ssd_fwd"] == 9
         assert detail["kernel_calls"]["pt_ssd_bwd"] == 9
         assert detail["tpu_custom_calls"] == 20
+    if workload == "ling3_train_tiny":
+        # the dense layer and one period of ling-3.0-flash-vl at its
+        # head sizes, chunking, router and expert width (ISSUE 41): six
+        # KDA scans, each forward kernel ONCE (a segment binds the
+        # saved O and block-start states on the op it replays: not 12,
+        # never 18) and each backward once; one gated latent-attention
+        # layer; six expert layers' grouped matmuls at width 768
+        assert workload in chip_gate.ONE_KDA_FWD_AN_OP
+        assert detail["kda_ops"] == 6
+        assert detail["kernel_calls"]["pt_kda_fwd"] == 6
+        assert detail["kernel_calls"]["pt_kda_bwd"] == 6
+        assert [detail["kernel_calls"][k] for k in (
+            "pt_gmm_fwd", "pt_gmm_bwd_dx", "pt_gmm_bwd_dw")] == [36, 18, 18]
     if workload == "dsv2_train_tiny":
         # four expert layers at the published expert width, 1,408 =
         # 11 x 128: the grouped matmuls compile with that axis whole
@@ -210,7 +224,10 @@ def test_sequence_parallel_flash_lowers_for_tpu(which, causal):
 
 # The cells' expert layers: the pairs a step routes at the worst case,
 # hidden width, expert width.  8 experts held, tiles of 256 rows, bf16.
-GMM_CELLS = {"dsv2": (49152, 2048, 1408), "xing4": (16384, 3584, 1024)}
+GMM_CELLS = {"dsv2": (49152, 2048, 1408), "xing4": (16384, 3584, 1024),
+             # ISSUE 41: 4,096 tokens x 8 experts a token, K 2,560,
+             # expert width 768 = 6 x 128
+             "ling3": (32768, 2560, 768)}
 GMM_HELD, GMM_TM = 8, 256
 # The six grouped-matmul calls of an expert layer's forward and backward
 # (moe_experts' _routed_fwd / _routed_bwd): kernel, rhs transposed,
@@ -316,7 +333,8 @@ def test_grouped_matmul_blocks_fit_the_limit_the_call_passes(cell, call):
         assert dim != 1408 or block == 1408
 
 
-@pytest.mark.parametrize("cell,most", [("dsv2", 38), ("xing4", 89)])
+@pytest.mark.parametrize("cell,most", [("dsv2", 38), ("xing4", 89),
+                                       ("ling3", 12)])
 def test_a_live_tile_layer_costs_few_grid_steps(cell, most):
     """What one more live row tile of one expert layer adds to a
     step's grids, summed over the layer's twelve calls, from their
@@ -325,8 +343,9 @@ def test_a_live_tile_layer_costs_few_grid_steps(cell, most):
     and 1,024 costs (4 + 4 + 2 forward, twice; 4 + 2 + 2 for dx; 2 + 4
     + 4 for dw), where 128-wide blocks along 1,408 made it 418; xing4
     the 89 of its former 1024x512 / 896x512 blocks (7 a call at [3584,
-    1024], 8 at [1024, 3584]).  A rule that falls back to narrow blocks
-    fails here."""
+    1024], 8 at [1024, 3584]); ling3 the 12 of whole matrices ([2560,
+    768] is 3.9 MB in bf16: a block).  A rule that falls back to narrow
+    blocks fails here."""
     steps = 0
     for name in GMM_CALLS:
         call = _gmm_call(cell, name)

@@ -269,6 +269,13 @@ def _build_granite_train(batch=1, seq=8192, **sizes):
                              "granite_hybrid.py", batch, seq, sizes)
 
 
+def _build_ling3_train(batch=1, seq=4096, **sizes):
+    """The KDA / latent-attention hybrid's train step as the cell
+    `ling3_flash_train_s4k` runs it."""
+    return _build_cell_train("ling-3.0-flash-vl.json", "ling3.py", batch,
+                             seq, sizes)
+
+
 def _build_xing4_train(batch=1, seq=4096, **sizes):
     """The 2024-26 decoder block's train step as the cell
     `xing4_29b_train_s4k` runs it."""

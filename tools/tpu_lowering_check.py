@@ -143,6 +143,19 @@ def _workloads():
             1, 512, hidden_size=256, num_attention_heads=4,
             num_key_value_heads=2, mamba_n_heads=4,
             shared_intermediate_size=512, vocab_size=512)[:3],
+        # the KDA / latent-attention hybrid at the cell's sizes (1 x
+        # 4,096 tokens, six KDA layers and one gated MLA layer over a
+        # 512-wide group-limited router, 822 M parameters): whether
+        # 13.15 GB of state and the step's activations fit
+        # (STEP_BYTES_MAX), and that each scan's forward kernel runs
+        # once (ONE_KDA_FWD_AN_OP)
+        "ling3_train": lambda: progs._build_ling3_train(1, 4096)[:3],
+        # the published head sizes, chunking, router (512 outputs in 8
+        # groups) and expert width, one period of layers, everything
+        # else narrow
+        "ling3_train_tiny": lambda: progs._build_ling3_train(
+            1, 512, hidden_size=256, num_attention_heads=2,
+            kv_lora_rank=64, intermediate_size=512, vocab_size=512)[:3],
         # both at the cells' depth and head sizes, narrow and short
         # (256 tokens; seconds to compile): what is checked is how many
         # kernels the step holds, not whether it fits
@@ -348,7 +361,7 @@ def _infer(progs, which, batch, conv_epilogue=False):
 
 
 FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train",
-             "xing4_train", "dsv2_train", "granite_train")
+             "xing4_train", "dsv2_train", "granite_train", "ling3_train")
 
 # the steps whose attention takes q, k and v token-major, [B, T, H*d]
 # as the projections leave them: their compiled step may hold no head
@@ -376,7 +389,8 @@ ONE_FLASH_FWD_AN_OP = ("transformer_train", "transformer_train_gspmd",
                        "ouro_train", "ouro_train_tiny", "xing4_train",
                        "xing4_train_tiny", "dsv2_train",
                        "dsv2_train_tiny", "granite_train",
-                       "granite_train_tiny")
+                       "granite_train_tiny", "ling3_train",
+                       "ling3_train_tiny")
 
 # the training steps whose every ssd_scan op has a grad that reads the
 # forward's Y and chunk-start states inside its recompute segment: the
@@ -384,6 +398,14 @@ ONE_FLASH_FWD_AN_OP = ("transformer_train", "transformer_train_gspmd",
 # segment replayed it, 27 if the grad op ran it again too), and the
 # backward kernel once
 ONE_SSD_FWD_AN_OP = ("granite_train", "granite_train_tiny")
+
+
+# the training steps whose every kda_scan op has a grad that reads the
+# forward's O and block-start states inside its recompute segment: the
+# forward kernel runs once an op (6 in the cell's step; 12 if the
+# segment replayed it, 18 if the grad op ran it again too), and the
+# backward kernel once
+ONE_KDA_FWD_AN_OP = ("ling3_train", "ling3_train_tiny")
 
 
 def kernel_calls(hlo_text):
@@ -405,7 +427,11 @@ ROW_WORK_IN_LOOPS = {"dsv2_train": 51200, "dsv2_train_tiny": 3584}
 # take.  dsv2_train: PR 37 reads 9,613,623,296 (PR 36: 9,429,541,888;
 # the live peak fell, the compiler's packing of it rose, and moves by
 # megabytes with the order of the step's ops: PERF.md)
-STEP_BYTES_MAX = {"dsv2_train": 9_650_000_000}
+STEP_BYTES_MAX = {"dsv2_train": 9_650_000_000,
+                  # PR 41 reads 13,062,109,696: 9.87 GB of weights and
+                  # float32 Adam moments, 3.20 GB of gradients and a
+                  # segment's activations
+                  "ling3_train": 13_100_000_000}
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", re.M)
 _CALLED = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
@@ -450,8 +476,9 @@ def check_workload(name, build):
     `pt_flash_fwd` is called once an op (a recompute segment that
     replays the op holds a second call: 10 for 5 in `xing4`, 48 for 24
     in `ouro` before PR 33), and for the ONE_SSD_FWD_AN_OP steps
-    `ssd_ops`, the same of `pt_ssd_fwd` and `pt_ssd_bwd`; for the
-    ROW_WORK_IN_LOOPS steps
+    `ssd_ops`, the same of `pt_ssd_fwd` and `pt_ssd_bwd`, and for the
+    ONE_KDA_FWD_AN_OP steps `kda_ops`, the same of `pt_kda_fwd` and
+    `pt_kda_bwd`; for the ROW_WORK_IN_LOOPS steps
     `rows_outside_loops`, which fails the workload unless it is 0, and
     for the STEP_BYTES_MAX steps `step_bytes`, which fails it above
     the limit."""
@@ -505,6 +532,15 @@ def check_workload(name, build):
             ok &= detail["kernel_calls"].get("pt_ssd_fwd") \
                 == detail["kernel_calls"].get("pt_ssd_bwd") \
                 == detail["ssd_ops"] > 0
+        if name in ONE_KDA_FWD_AN_OP:
+            from paddle_tpu import framework
+
+            detail["kda_ops"] = sum(
+                op.type == "kda_scan" for op in
+                framework.default_main_program().global_block().ops)
+            ok &= detail["kernel_calls"].get("pt_kda_fwd") \
+                == detail["kernel_calls"].get("pt_kda_bwd") \
+                == detail["kda_ops"] > 0
         if name in ROW_WORK_IN_LOOPS:
             detail["rows_outside_loops"] = rows_outside_loops(
                 text, ROW_WORK_IN_LOOPS[name])
