@@ -20,10 +20,13 @@ pt_ssd_bwd.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import register_op
+from paddle_tpu.ops import pallas_conv1d
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.ops import pallas_ssd
 
@@ -130,8 +133,73 @@ def ssd_scan_grad(ins, attrs):
     return {s + "@GRAD": v for s, v in zip(_SSD_INPUTS, grads)}
 
 
+_CONV_ATTRS = {"activation": "silu", "impl": ""}
+
+
+def _conv_impl(ins, attrs):
+    """The impl the convolution resolves to: the one asked for, else
+    pallas on a TPU and xla elsewhere; xla too where the kernels cannot
+    tile what the op reads (pallas_conv1d.tiles: C a multiple of 128,
+    T a multiple of a row tile, K <= 8).  Raises an unknown
+    activation."""
+    act = attrs["activation"]
+    if act not in ("silu", ""):
+        raise ValueError("causal_conv1d: activation %r is neither 'silu' "
+                         "nor ''" % (act,))
+    impl = attrs.get("impl") or pk._auto_impl()
+    (_, t, c), k = ins["X"].shape, ins["W"].shape[-1]
+    if impl != "xla" and pallas_conv1d.tiles(t, c, k) is None:
+        impl = "xla"
+    return impl
+
+
+def _conv_xla(x, w, bias, act):
+    k, t = w.shape[-1], x.shape[1]
+    xp = jnp.pad(x.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
+    wf = w.astype(_F32)
+    y = sum(xp[:, i:i + t, :] * wf[:, i] for i in range(k))
+    if bias is not None:
+        y = y + bias.astype(_F32)
+    if act == "silu":
+        y = jax.nn.silu(y)
+    return y.astype(x.dtype)
+
+
+def _conv_grads(x, w, bias, g, act, impl):
+    """(dX, dW, dBias or None) by pt_conv1d_bwd (impl a kernel) or by
+    jax.vjp of the XLA graph."""
+    if impl == "xla":
+        _, vjp = jax.vjp(lambda *a: _conv_xla(*a, act), x, w, bias)
+        return vjp(g)
+    # see pallas_kernels._flash_attention_fwd: one call line
+    with pk._obs_device.annotate("causal_conv1d_grad"), pk._kernel_scope():
+        return pallas_conv1d.conv1d_bwd_pallas(
+            x, w, bias, g, act=act, interpret=impl == "interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_kernel(x, w, bias, act, impl):
+    """pt_conv1d_fwd, differentiated by pt_conv1d_bwd: what jax.vjp of
+    a recompute segment's replay finds where no grad op is bound."""
+    with pk._obs_device.annotate("causal_conv1d"), pk._kernel_scope():
+        return pallas_conv1d.conv1d_fwd_pallas(
+            x, w, bias, act=act, interpret=impl == "interpret")
+
+
+def _conv_kernel_fwd(x, w, bias, act, impl):
+    return _conv_kernel(x, w, bias, act, impl), (x, w, bias)
+
+
+def _conv_kernel_bwd(act, impl, res, g):
+    # traced under the forward's name stack: the op's scope is on it
+    return _conv_grads(*res, g, act, impl)
+
+
+_conv_kernel.defvjp(_conv_kernel_fwd, _conv_kernel_bwd)
+
+
 @register_op("causal_conv1d", inputs=("X", "W", "Bias"), outputs=("Y",),
-             attrs={"activation": "silu"}, optional=("Bias",))
+             attrs=_CONV_ATTRS, optional=("Bias",))
 def causal_conv1d(ins, attrs):
     """Depthwise convolution over time that never reads ahead: X
     [B, T, C], W [C, K], Bias [C] ->
@@ -139,22 +207,37 @@ def causal_conv1d(ins, attrs):
         Y[b, t, c] = act(Bias[c] + sum_k W[c, k] X[b, t - (K-1) + k, c])
 
     with X zero before t = 0 (left-padded by K - 1).  activation "silu"
-    or "" (none).  Float32 inside, Y in X's dtype."""
-    x, w = ins["X"], ins["W"]
-    act = attrs["activation"]
-    if act not in ("silu", ""):
-        raise ValueError("causal_conv1d: activation %r is neither 'silu' "
-                         "nor ''" % (act,))
-    k, t = w.shape[-1], x.shape[1]
+    or "" (none).  Float32 inside, Y in X's dtype.  impl: "" (the
+    kernel pt_conv1d_fwd on a TPU where it can tile X, the XLA graph
+    elsewhere), "pallas", "interpret", "xla"."""
+    impl = _conv_impl(ins, attrs)
+    pk._count_impl("causal_conv1d", impl)
+    x, w, bias = ins["X"], ins["W"], ins.get("Bias")
     with jax.named_scope("pt_causal_conv1d"):
-        xp = jnp.pad(x.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
-        wf = w.astype(_F32)
-        y = sum(xp[:, i:i + t, :] * wf[:, i] for i in range(k))
-        if ins.get("Bias") is not None:
-            y = y + ins["Bias"].astype(_F32)
-        if act == "silu":
-            y = jax.nn.silu(y)
-        return {"Y": y.astype(x.dtype)}
+        if impl == "xla":
+            return {"Y": _conv_xla(x, w, bias, attrs["activation"])}
+        return {"Y": _conv_kernel(x, w, bias, attrs["activation"], impl)}
+
+
+@register_op("causal_conv1d_grad", inputs=("X", "W", "Bias", "Y@GRAD"),
+             outputs=("X@GRAD", "W@GRAD", "Bias@GRAD"),
+             optional=("Bias",), attrs=_CONV_ATTRS, differentiable=False)
+def causal_conv1d_grad(ins, attrs):
+    """Hand-written, as ssd_scan_grad is and for its reason: the
+    generic jax.vjp grad op would run pt_conv1d_fwd again.  Reads X, W,
+    Bias and Y@GRAD only (pt_conv1d_bwd forms z again from X): no
+    forward output is bound.  The xla impl: jax.vjp of the XLA graph.
+    paddle_tpu_kernel_impl_total{kernel="causal_conv1d_grad"} says
+    which."""
+    impl = _conv_impl(ins, attrs)
+    pk._count_impl("causal_conv1d_grad", impl)
+    with jax.named_scope("pt_causal_conv1d"):
+        dx, dw, db = _conv_grads(ins["X"], ins["W"], ins.get("Bias"),
+                                 ins["Y@GRAD"], attrs["activation"], impl)
+    out = {"X@GRAD": dx, "W@GRAD": dw}
+    if db is not None:
+        out["Bias@GRAD"] = db
+    return out
 
 
 @register_op("gated_rms_norm", inputs=("X", "Gate", "Scale"),

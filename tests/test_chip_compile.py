@@ -183,7 +183,27 @@ def _kda(grad, b=1, t=4096, h=32, d=128, chunk=64, block_chunks=4):
         _sds((b, t // (chunk * block_chunks), h * d, d), f32), x), 1
 
 
+def _conv1d(grad, t, c, bias):
+    """The mixers' short convolution at a cell's size (K 4, SiLU):
+    granite-4.0-h-micro 1 x 8,192 x 4,352 with a bias,
+    ling-3.0-flash-vl 1 x 4,096 x 4,096 without."""
+    from paddle_tpu.ops.pallas_conv1d import (conv1d_bwd_pallas,
+                                              conv1d_fwd_pallas)
+
+    f32 = jnp.float32
+    x = _sds((1, t, c))
+    ins = (x, _sds((c, 4), f32), _sds((c,), f32) if bias else None)
+    if not grad:
+        return (lambda *a: conv1d_fwd_pallas(*a, act="silu")), ins, 1
+    return (lambda x, w, b, g: conv1d_bwd_pallas(x, w, b, g, act="silu")), \
+        ins + (x,), 1
+
+
 CASES = {
+    "conv1d_fwd_1x8192x4352_bias": lambda: _conv1d(False, 8192, 4352, True),
+    "conv1d_bwd_1x8192x4352_bias": lambda: _conv1d(True, 8192, 4352, True),
+    "conv1d_fwd_1x4096x4096": lambda: _conv1d(False, 4096, 4096, False),
+    "conv1d_bwd_1x4096x4096": lambda: _conv1d(True, 4096, 4096, False),
     "kda_fwd_1x4096_h32_d128": lambda: _kda(False),
     "kda_bwd_1x4096_h32_d128": lambda: _kda(True),
     "ssd_fwd_1x8192_h64_p64_n128": lambda: _ssd(False),

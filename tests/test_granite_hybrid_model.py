@@ -194,12 +194,18 @@ def test_kernels_in_interpret_mode_against_reference(interpret):
     """The same comparison with pt_ssd_fwd, pt_ssd_bwd and the flash
     kernels (2 KV heads read by 4 query heads: head size 32, so the
     head-major kernels) in the program, inside recompute segments."""
-    got, want = _run(WIDE, False, True)
+    # a state of 64: the convolution's 4 x 64 + 2 x 64 = 384 channels
+    # are whole lane blocks, so pt_conv1d_fwd and pt_conv1d_bwd run too
+    # (at SMALL's 320 the op takes its XLA graph)
+    got, want = _run(dict(WIDE, mamba_d_state=64), False, True)
     _check(got, want, F32)
     used = got["used"]
     assert used[("ssd_scan", "interpret")] == 2
     assert used[("ssd_scan_grad", "saved")] == 2
     assert used[("flash_attention_kv_heads", "grouped")] == 1
+    # each of the two convolutions once in the forward pass and once in
+    # its segment's replay, differentiated there by its custom_vjp
+    assert used[("causal_conv1d", "interpret")] == 4
     assert not [k for k in used if k[1] in ("xla", "recompute", "repeated")]
 
 

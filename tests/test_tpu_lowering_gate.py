@@ -73,7 +73,15 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert detail["ssd_ops"] == 9
         assert detail["kernel_calls"]["pt_ssd_fwd"] == 9
         assert detail["kernel_calls"]["pt_ssd_bwd"] == 9
-        assert detail["tpu_custom_calls"] == 20
+        # and nine short convolutions through their kernels (ISSUE 42):
+        # the forward pass, its segment's replay (the op keeps no
+        # output) and one backward each, no float32 pad of X left
+        assert workload in chip_gate.CONV1D_KERNELS
+        assert detail["conv1d_ops"] == 9
+        assert detail["kernel_calls"]["pt_conv1d_fwd"] == 18
+        assert detail["kernel_calls"]["pt_conv1d_bwd"] == 9
+        assert detail["conv_scope_pads"] == 0
+        assert detail["tpu_custom_calls"] == 47
     if workload == "ling3_train_tiny":
         # the dense layer and one period of ling-3.0-flash-vl at its
         # head sizes, chunking, router and expert width (ISSUE 41): six
@@ -85,6 +93,12 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert detail["kda_ops"] == 6
         assert detail["kernel_calls"]["pt_kda_fwd"] == 6
         assert detail["kernel_calls"]["pt_kda_bwd"] == 6
+        # three short convolutions a KDA layer (ISSUE 42)
+        assert workload in chip_gate.CONV1D_KERNELS
+        assert detail["conv1d_ops"] == 18
+        assert detail["kernel_calls"]["pt_conv1d_fwd"] == 36
+        assert detail["kernel_calls"]["pt_conv1d_bwd"] == 18
+        assert detail["conv_scope_pads"] == 0
         assert [detail["kernel_calls"][k] for k in (
             "pt_gmm_fwd", "pt_gmm_bwd_dx", "pt_gmm_bwd_dw")] == [36, 18, 18]
     if workload == "dsv2_train_tiny":

@@ -408,6 +408,25 @@ ONE_SSD_FWD_AN_OP = ("granite_train", "granite_train_tiny")
 ONE_KDA_FWD_AN_OP = ("ling3_train", "ling3_train_tiny")
 
 
+# the training steps whose every causal_conv1d op runs the kernels of
+# ops/pallas_conv1d.py: pt_conv1d_fwd twice an op (the forward pass and
+# its recompute segment's replay: the op keeps no output, so a segment
+# binds nothing) and pt_conv1d_bwd once (9 ops a granite step: 18 + 9;
+# 18 a ling3 step: 36 + 18), and the XLA graph's float32 pad of X is
+# gone from the op's scope
+CONV1D_KERNELS = ("granite_train", "granite_train_tiny", "ling3_train",
+                  "ling3_train_tiny")
+
+
+def conv_scope_pads(hlo_text):
+    """`pad` instructions of a compiled module under the op_name scope
+    pt_causal_conv1d: the XLA graph's left-padded float32 copy of X
+    (and its gradient's), which the kernels' halo replaces."""
+    return len(re.findall(
+        r'^[^\n]* pad\([^\n]*op_name="[^"]*pt_causal_conv1d[^"]*"',
+        hlo_text, re.M))
+
+
 def kernel_calls(hlo_text):
     """{kernel name: Mosaic calls} of a compiled module.  The TPU
     compiler names a call after the kernel (`pl.pallas_call(name=)`,
@@ -478,7 +497,10 @@ def check_workload(name, build):
     in `ouro` before PR 33), and for the ONE_SSD_FWD_AN_OP steps
     `ssd_ops`, the same of `pt_ssd_fwd` and `pt_ssd_bwd`, and for the
     ONE_KDA_FWD_AN_OP steps `kda_ops`, the same of `pt_kda_fwd` and
-    `pt_kda_bwd`; for the ROW_WORK_IN_LOOPS steps
+    `pt_kda_bwd`; for the CONV1D_KERNELS steps `conv1d_ops` and
+    `conv_scope_pads`, which fail it unless `pt_conv1d_fwd` is called
+    twice and `pt_conv1d_bwd` once a causal_conv1d op and no `pad`
+    stands under the op's scope; for the ROW_WORK_IN_LOOPS steps
     `rows_outside_loops`, which fails the workload unless it is 0, and
     for the STEP_BYTES_MAX steps `step_bytes`, which fails it above
     the limit."""
@@ -541,6 +563,17 @@ def check_workload(name, build):
             ok &= detail["kernel_calls"].get("pt_kda_fwd") \
                 == detail["kernel_calls"].get("pt_kda_bwd") \
                 == detail["kda_ops"] > 0
+        if name in CONV1D_KERNELS:
+            from paddle_tpu import framework
+
+            detail["conv1d_ops"] = sum(
+                op.type == "causal_conv1d" for op in
+                framework.default_main_program().global_block().ops)
+            detail["conv_scope_pads"] = conv_scope_pads(text)
+            ok &= detail["kernel_calls"].get("pt_conv1d_fwd") \
+                == 2 * detail["kernel_calls"].get("pt_conv1d_bwd", 0) \
+                == 2 * detail["conv1d_ops"] > 0
+            ok &= not detail["conv_scope_pads"]
         if name in ROW_WORK_IN_LOOPS:
             detail["rows_outside_loops"] = rows_outside_loops(
                 text, ROW_WORK_IN_LOOPS[name])
