@@ -249,7 +249,8 @@ def _group_layout(idx, held, tm):
 
     idx [N, k] int32 expert ids, held: tuple of the expert ids held.
     Returns a dict: dest [N, k] the padded row of each pair (row 0
-    where not `mine`), mine [N, k] bool, row_pair [M] the pair (n * k +
+    where not `mine`), mine [N, k] bool, slot [N, k] the held expert's
+    place in `held` (G where not `mine`), row_pair [M] the pair (n * k +
     j) that feeds each padded row (undefined where not row_live),
     row_live [M] bool, tile_group [M / tm] int32, n_active [1] int32,
     sizes [G] int32 the pairs routed to each held expert, with
@@ -291,7 +292,7 @@ def _group_layout(idx, held, tm):
     rank = jnp.argsort(order).astype(jnp.int32)    # inverse permutation
     safe = jnp.minimum(key, g - 1)
     dest = jnp.where(key < g, pstart[safe] + rank - start[safe], 0)
-    return {"dest": dest.reshape(n, k), "mine": local < g,
+    return {"dest": dest.reshape(n, k), "mine": local < g, "slot": local,
             "row_pair": row_pair.reshape(-1),
             "row_live": row_live.reshape(-1), "tile_group": tile_group,
             "n_active": n_active.astype(jnp.int32), "sizes": sizes}
@@ -400,6 +401,21 @@ def _tokens_of_rows(lay, a, gate=None):
     return out
 
 
+def _combine(lay, a, gate, dtype, impl):
+    """a [M, C] by row -> [N, C] in `dtype`: each token's sum over its
+    held pairs, in pair order, of `gate` [N, k] times the pair's row.
+    One Pallas kernel that reads the held pairs' rows
+    (ops/pallas_moe_combine.py) where moe_experts made it a plan,
+    _tokens_of_rows' gathers of every pair where not."""
+    plan = lay.get("combine")
+    if plan is None:
+        return _tokens_of_rows(lay, a, gate).astype(dtype)
+    from paddle_tpu.ops.pallas_moe_combine import moe_combine_pallas
+
+    return moe_combine_pallas(a, plan, gate, out_dtype=dtype,
+                              interpret=impl == "interpret")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _routed_experts(x, gate, wg, wu, wd, lay, k, tm, impl):
     return _routed_fwd(x, gate, wg, wu, wd, lay, k, tm, impl)[0]
@@ -413,7 +429,7 @@ def _routed_fwd(x, gate, wg, wu, wd, lay, k, tm, impl):
     back.  Everything indexed by padded row, the kernels and the array
     work between them (_over_live_rows), ends at the last row tile that
     holds rows; what a row array holds past it is not defined, and
-    nothing reads it.  Only the combine is by token: k rows each."""
+    nothing reads it.  Only the combine is by token (_combine)."""
     from paddle_tpu.ops.pallas_gmm import gmm
 
     tg, na = lay["tile_group"], lay["n_active"]
@@ -425,7 +441,7 @@ def _routed_fwd(x, gate, wg, wu, wd, lay, k, tm, impl):
     act, = _over_live_rows(_swiglu_rows, ((hg.shape[1], dt),), k, tm, impl,
                            lay, hg, hu)
     ys = gmm(act, wd, tg, na, tm, impl)
-    out = _tokens_of_rows(lay, ys, gate).astype(dt)
+    out = _combine(lay, ys, gate, dt, impl)
     return out, (x, gate, wg, wu, wd, lay, xs, hg, hu, ys)
 
 
@@ -453,7 +469,7 @@ def _routed_bwd(k, tm, impl, res, g_out):
         _sum_rows, ((x.shape[1], _F32),), k, tm, impl, lay,
         gmm(g_hg, wg, tg, na, tm, impl, transpose_rhs=True),
         gmm(g_hu, wu, tg, na, tm, impl, transpose_rhs=True))
-    d_x = _tokens_of_rows(lay, g_xs).astype(dt)
+    d_x = _combine(lay, g_xs, None, dt, impl)
     return d_x, d_gate.astype(gate.dtype), d_wg, d_wu, d_wd, None
 
 
@@ -482,9 +498,13 @@ def moe_experts(ins, attrs):
     (_over_live_rows).  What a row array holds past that is not
     defined and not read.  There is no capacity: the worst case, every
     pair routed to one held expert, runs the same code with longer
-    loops.  impl: "" (pallas on a TPU, xla elsewhere), "pallas",
-    "interpret", "xla", the same form for each; block_m: rows a tile
-    (0: 256).
+    loops.  The combine alone is by token: with a Pallas impl one
+    kernel that reads the rows of the pairs that are held and sums a
+    token's in pair order (ops/pallas_moe_combine.py; it wants C a
+    multiple of 128 and block_m of 16), else XLA's gathers of every
+    pair's row; the same float32 products added in the same order.
+    impl: "" (pallas on a TPU, xla elsewhere), "pallas", "interpret",
+    "xla", the same form for each; block_m: rows a tile (0: 256).
 
     Load, float32 [G + 2]: what this execution was given, from the
     arrays that bound the kernels' grids (`_group_layout`'s sizes and
@@ -493,15 +513,26 @@ def moe_experts(ins, attrs):
     gradient flows to it; layers.moe_experts keeps it a row a step
     (layers.step_stat)."""
     from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.ops.pallas_moe_combine import combine_plan
 
     x = ins["X"]
     c = x.shape[-1]
     idx = ins["TopkIdx"].reshape(-1, ins["TopkIdx"].shape[-1])
     impl = attrs["impl"] or pk._auto_impl()
     tm = int(attrs["block_m"] or 256)
+    held = tuple(int(e) for e in attrs["held"])
     pk._count_impl("moe_gmm", impl)
     with jax.named_scope("pt_moe_experts"):
-        lay = _group_layout(idx, tuple(int(e) for e in attrs["held"]), tm)
+        lay = _group_layout(idx, held, tm)
+        # the combine's kernel where it can take the call's shapes,
+        # XLA's gathers where not: one plan a layer, read by the
+        # forward's combine, its replay and d x
+        plan = None if impl == "xla" else combine_plan(
+            lay["dest"], lay["slot"], len(held), c,
+            lay["row_pair"].shape[0])
+        pk._count_impl("moe_combine", impl if plan else "xla")
+        if plan:
+            lay["combine"] = plan
         gate = ins["TopkWeight"].reshape(idx.shape).astype(_F32)
         dt = x.dtype
         # see pallas_kernels._flash_attention_fwd: one call line

@@ -199,7 +199,34 @@ def _conv1d(grad, t, c, bias):
         ins + (x,), 1
 
 
+def _moe_combine(n, k, c, rows, f32_rows):
+    """A layer's combine by token at a cell's size, 8 experts held: the
+    forward's (bf16 rows, gated) or d x's (float32 rows), the plan made
+    in the same program."""
+    from paddle_tpu.ops import pallas_moe_combine as pc
+
+    pairs = _sds((n, k), jnp.int32)
+
+    def call(a, dest, slot, gate=None):
+        plan = pc.combine_plan(dest, slot, 8, c, rows)
+        return pc.moe_combine_pallas(a, plan, gate, out_dtype=BF16)
+
+    if f32_rows:
+        return call, (_sds((rows, c), jnp.float32), pairs, pairs), 1
+    return call, (_sds((rows, c)), pairs, pairs,
+                  _sds((n, k), jnp.float32)), 1
+
+
 CASES = {
+    # ling3, xing4, dsv2: tokens, pairs a token, width, the layout's rows
+    **{"moe_combine_%s_%dx%dx%d_rows%d" % (
+        ("dx" if f32 else "fwd",) + shape):
+       (lambda shape=shape, f32=f32: _moe_combine(*shape, f32))
+       # and 32 sequences of ling3's: a grid step reads its own block
+       # of the plan, so the tokens of a call are not bounded by SMEM
+       for shape in ((4096, 8, 2560, 34816), (4096, 4, 3584, 18432),
+                     (8192, 6, 2048, 51200), (131072, 8, 2560, 1050624))
+       for f32 in (False, True)},
     "conv1d_fwd_1x8192x4352_bias": lambda: _conv1d(False, 8192, 4352, True),
     "conv1d_bwd_1x8192x4352_bias": lambda: _conv1d(True, 8192, 4352, True),
     "conv1d_fwd_1x4096x4096": lambda: _conv1d(False, 4096, 4096, False),

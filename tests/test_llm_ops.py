@@ -678,6 +678,7 @@ def test_moe_experts_row_work_is_bounded_by_n_active(pass_):
     from n_active.  Code that goes back to walking the worst case over
     all rows fails here.  And no [N, k, C] array is formed."""
     from paddle_tpu.ops.llm_ops import _group_layout, _routed_experts
+    from paddle_tpu.ops.pallas_moe_combine import combine_plan
 
     ins, held = _layout_case("sparse")
     n, k = ins["TopkIdx"].shape
@@ -685,6 +686,8 @@ def test_moe_experts_row_work_is_bounded_by_n_active(pass_):
     lay = _group_layout(ins["TopkIdx"], held, 32)
     m = lay["row_pair"].shape[0]
     assert m == 34 * 32 and m not in (n, n * k, c, ins["WGate"].shape[2])
+    # as moe_experts hands it on: with the combine kernel's plan
+    lay["combine"] = combine_plan(lay["dest"], lay["slot"], len(held), c, m)
     args = (ins["X"], ins["TopkWeight"], ins["WGate"], ins["WUp"],
             ins["WDown"])
 
@@ -721,9 +724,12 @@ def test_moe_experts_row_work_is_bounded_by_n_active(pass_):
             assert name == "pallas_call" or not shape or shape[0] != m, \
                 "%s yields %s outside a loop" % (name, v.aval)
     # forward: the gather and SwiGLU loops, their 2 buffers, 3 grouped
-    # matmuls; backward: those again (the vjp runs the forward), then
-    # three loops over 6 buffers and 6 grouped matmuls
-    assert (loops, kernels) == ((2, 5) if pass_ == "forward" else (5, 17))
+    # matmuls, the combine; backward: those again (the vjp runs the
+    # forward), then three loops over 6 buffers, 6 grouped matmuls and
+    # d x's combine
+    assert (loops, kernels) == ((2, 6) if pass_ == "forward" else (5, 19))
+    assert [e.params["name"] for e in _pallas_calls(jaxpr)].count(
+        "pt_moe_combine") == (1 if pass_ == "forward" else 2)
     for eqn in _all_eqns(jaxpr):
         for v in eqn.outvars:
             assert getattr(v.aval, "shape", ()) != (n, k, c), eqn
@@ -926,6 +932,253 @@ def test_moe_experts_reads_no_row_past_the_live_tiles():
     assert (np.asarray(dots[:written]) == 128).all()
     assert np.isnan(np.asarray(rows[written:])).all()
     assert np.isnan(np.asarray(dots[written:])).all()
+
+
+# -- the combine by token -----------------------------------------------------
+
+COMBINE_SHARES = ("none", "one_pair", "eighth", "all")
+# _layout_case's three at row tiles of 32, and a few tokens under the
+# default row tile of 256: 32 tokens of 2 pairs, four experts held,
+# every group a tile of its own, so the groups start at rows 0, 256,
+# 512, 768 with 64 pairs in all (REVIEW of PR 43: a first row past
+# twice the pairs)
+COMBINE_LAYOUTS = ("one_tile", "sparse", "full", "few_tokens")
+
+
+def _combine_case(layout, share, c, seed=21):
+    """A layout of one of COMBINE_LAYOUTS' sizes with a chosen share
+    of its pairs held, and a row array that is NaN in every row no held
+    pair points at: (lay, plan, rows, gate, n_groups).  The router has
+    one more expert than the layout's, which nobody holds; at `all`
+    every pair goes to a held expert (twice to one where k > G)."""
+    from paddle_tpu.ops import pallas_moe_combine as pc
+    from paddle_tpu.ops.llm_ops import _group_layout
+
+    if layout == "few_tokens":
+        held, tm = (1, 3, 4, 6), 256
+        ins = _experts_case(14, n=32, e=8, k=2, held=held)
+    else:
+        (ins, held), tm = _layout_case(layout), 32
+    n, k = ins["TopkIdx"].shape
+    nobody = int(np.asarray(ins["TopkIdx"]).max()) + 1
+    rng = np.random.default_rng(seed)
+    to_held = rng.choice(held, (n, k))
+    if share == "none":
+        idx = np.full((n, k), nobody)
+    elif share == "one_pair":
+        idx = np.full((n, k), nobody)
+        idx[n // 2, k - 1] = held[-1]
+    elif share == "eighth":
+        idx = np.where(rng.uniform(size=(n, k)) < 1 / 8, to_held, nobody)
+    else:
+        idx = to_held
+    lay = _group_layout(jnp.asarray(idx, jnp.int32), held, tm)
+    m = lay["row_pair"].shape[0]
+    plan = pc.combine_plan(lay["dest"], lay["slot"], len(held), c, m)
+    assert plan is not None
+    mine = np.asarray(lay["mine"])
+    if share in ("none", "one_pair"):
+        assert mine.sum() == (share == "one_pair")
+    rows = np.full((m, c), np.nan, np.float32)
+    pointed = np.asarray(lay["dest"])[mine]
+    rows[pointed] = rng.normal(0, 1, (len(pointed), c))
+    # gates that are powers of two: a product with one is exact, so a
+    # compiler that fuses the product into the sum (the CPU's does, in
+    # the jitted XLA form and in the interpreted kernel alike, each
+    # where its fusions let it) changes no bit, and the comparison
+    # holds what the kernel must keep: the ORDER of a token's sums
+    gate = jnp.asarray(2.0 ** rng.integers(-2, 2, (n, k)), jnp.float32)
+    return lay, plan, rows, gate, len(held)
+
+
+@pytest.mark.parametrize("c", [128, 384])
+@pytest.mark.parametrize("share", COMBINE_SHARES)
+@pytest.mark.parametrize("layout", COMBINE_LAYOUTS)
+def test_moe_combine_kernel_is_tokens_of_rows_bit_for_bit(layout, share, c):
+    """pt_moe_combine in interpret mode against XLA's by-pair gathers:
+    bf16 rows with a gate (the forward's combine) and float32 rows
+    without (d x), every element equal; the rows no held pair points at
+    (the padding of a group, the tiles past n_active) are NaN and none
+    reaches the output."""
+    from paddle_tpu.ops.llm_ops import _tokens_of_rows
+    from paddle_tpu.ops.pallas_moe_combine import moe_combine_pallas
+
+    lay, plan, rows, gate, _ = _combine_case(layout, share, c)
+    for a, g in ((jnp.asarray(rows, jnp.bfloat16), gate),
+                 (jnp.asarray(rows), None)):
+        got = moe_combine_pallas(a, plan, g, out_dtype=jnp.float32,
+                                 interpret=True)
+        want = _tokens_of_rows(lay, a, g)
+        assert np.isfinite(np.asarray(want)).all()
+        np.testing.assert_array_equal(got, want, err_msg=str(a.dtype))
+    # and any gate, to a rounding of the float32 sum
+    any_gate = jnp.asarray(np.random.default_rng(3).uniform(
+        0.2, 1, gate.shape), jnp.float32)
+    a = jnp.asarray(rows, jnp.bfloat16)
+    got = moe_combine_pallas(a, plan, any_gate, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(_tokens_of_rows(lay, a, any_gate)), rtol=2 * BF16_ULP,
+        atol=1e-6)
+
+
+@pytest.mark.parametrize("rows_dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("layout", COMBINE_LAYOUTS)
+def test_moe_combine_adds_any_gates_products_in_pair_order(layout,
+                                                           rows_dtype):
+    """Gates that are no powers of two, float32 out, every pair held
+    (k terms a token): every element is what a numpy loop gives that
+    adds gate * row to 0 in PAIR ORDER, each product either rounded to
+    float32 before its add (the chip: tools/moe_combine_price.py holds
+    the kernel to XLA's form there, every element) or fused into it
+    (this CPU's compiler, where its fusions let it).  Another order of
+    a token's adds is none of these."""
+    import itertools
+
+    from paddle_tpu.ops.pallas_moe_combine import moe_combine_pallas
+
+    lay, plan, rows, gate, _ = _combine_case(layout, "all", 128)
+    n, k = gate.shape
+    gate = np.random.default_rng(4).uniform(0.2, 1, (n, k)) \
+        .astype(np.float32)
+    a = jnp.asarray(rows, jnp.dtype(rows_dtype))
+    got = np.asarray(moe_combine_pallas(
+        a, plan, jnp.asarray(gate), out_dtype=jnp.float32, interpret=True))
+    a = np.asarray(a.astype(jnp.float32))
+    terms = [(gate[:, j:j + 1], a[np.asarray(lay["dest"])[:, j]])
+             for j in range(k)]
+
+    def total(order, fused):
+        out = np.zeros_like(got)
+        for j, one_rounding in zip(order, fused):
+            g, row = terms[j]
+            # a float32 product is exact in float64
+            out = (g.astype(np.float64) * row + out).astype(np.float32) \
+                if one_rounding else out + g * row
+        return out
+
+    def explained(order):
+        return np.any([got == total(order, (False,) + fused) for fused in
+                       itertools.product((False, True), repeat=k - 1)], 0)
+
+    assert explained(range(k)).all()
+    assert not explained(range(k)[::-1]).all()
+
+
+@pytest.mark.parametrize("share", COMBINE_SHARES)
+@pytest.mark.parametrize("layout", COMBINE_LAYOUTS)
+def test_combine_plan_against_a_compaction_by_hand(layout, share):
+    """cnt, the compacted gates and the place of every held pair's row
+    against loops in numpy: the copies of a block, laid end to end as
+    the kernel lays them, hold the row of a token's s-th held pair at
+    its block's pos[s tn + t] and fit the kernel's buffer; a run is
+    covered by the fewest units of 16 rows."""
+    from paddle_tpu.ops.pallas_moe_combine import (_UNIT, _buffer_rows,
+                                                   _by_block, _compact)
+
+    lay, plan, _, gate, g = _combine_case(layout, share, 128)
+    dest, mine = np.asarray(lay["dest"]), np.asarray(lay["mine"])
+    slot = np.asarray(lay["slot"])
+    n, k = dest.shape
+    blocks, _, tn = plan["cnt"].shape
+    first, units = np.asarray(plan["runs"]).reshape(blocks, 2, g) \
+        .transpose(1, 0, 2)
+
+    def by_token(x):        # [blocks, 1, s tn + t] -> [s, n]
+        return np.asarray(x).reshape(blocks, -1, tn).transpose(1, 0, 2) \
+            .reshape(-1, n)
+
+    pos = by_token(plan["pos"])
+    gate_c = by_token(_by_block(_compact(gate.T, plan["order"]), tn))
+    np.testing.assert_array_equal(by_token(plan["cnt"])[0], mine.sum(1))
+    for b in range(blocks):
+        buffer = np.concatenate([
+            first[b, e] + np.arange(units[b, e] * _UNIT)
+            for e in range(g)] + [np.zeros(0, int)])
+        assert (first[b] % _UNIT == 0).all()
+        assert (buffer < lay["row_pair"].shape[0]).all()
+        assert len(buffer) <= _buffer_rows(tn, k, g)
+        for e in range(g):
+            run = dest[b * tn:(b + 1) * tn][slot[b * tn:(b + 1) * tn] == e]
+            want = 0 if not len(run) else \
+                run.max() // _UNIT - run.min() // _UNIT + 1
+            assert units[b, e] == want, (b, e)
+        for t in range(b * tn, (b + 1) * tn):
+            held = np.nonzero(mine[t])[0]
+            for s, j in enumerate(held):
+                assert buffer[pos[s, t]] == dest[t, j], (t, s)
+                assert gate_c[s, t] == gate[t, j]
+            assert not gate_c[len(held):, t].any()
+
+
+@pytest.mark.parametrize("layout", ["sparse", "full"])
+def test_moe_experts_with_the_combine_kernel_keeps_the_bits(layout):
+    """The routed experts with the combine through pt_moe_combine
+    against the same kernels round XLA's by-pair gathers (a layout
+    without a plan), float32, gates powers of two: Out and all five
+    gradients equal in every element."""
+    from paddle_tpu.ops.llm_ops import _group_layout, _routed_experts
+    from paddle_tpu.ops.pallas_moe_combine import combine_plan
+
+    ins, held = _layout_case(layout)
+    n, k = ins["TopkIdx"].shape
+    lay = _group_layout(ins["TopkIdx"], held, 32)
+    planned = {**lay, "combine": combine_plan(
+        lay["dest"], lay["slot"], len(held), 128, lay["row_pair"].shape[0])}
+    gate = jnp.asarray(2.0 ** np.random.default_rng(5).integers(
+        -2, 2, (n, k)), jnp.float32)
+    args = (ins["X"], gate, ins["WGate"], ins["WUp"], ins["WDown"])
+
+    def run(lay):
+        def loss(*a):
+            out = _routed_experts(*a, lay, k, 32, "interpret")
+            return (out * jnp.sin(out)).sum(), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+        return (out,) + grads
+
+    for name, a, b in zip(("Out",) + DIFF, run(planned), run(lay)):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("c,block_m,impl,counted", [
+    (128, 32, "interpret", "interpret"), (128, 32, "xla", "xla"),
+    # a width that is not whole lanes, row tiles that are not whole
+    # units of the kernel's copies: XLA's gathers under a Pallas impl
+    (192, 32, "interpret", "xla"), (128, 8, "interpret", "xla")])
+def test_moe_combine_impl_is_counted_and_falls_back(c, block_m, impl,
+                                                    counted):
+    held = (2, 5, 7)
+    ins = _experts_case(13, c=c, held=held)
+    before = _impl_counts()
+    out = _op("moe_experts", ins, held=list(held), block_m=block_m,
+              impl=impl)["Out"]
+    since = _impl_since(before)
+    assert since[("moe_combine", counted)] == 1
+    assert [k for k in since if k[0] == "moe_combine"] == [
+        ("moe_combine", counted)]
+    assert since[("moe_gmm", impl)] == 1
+    np.testing.assert_allclose(out, _dense_experts(ins, held), rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_moe_combine_block_is_a_function_of_the_shapes():
+    """The three cells' blocks, the same whatever the tokens (a grid
+    step reads its own block of the plan), and the calls the kernel
+    leaves to XLA: a width that is not whole lanes, rows that are not
+    whole units of its copies."""
+    from paddle_tpu.ops.pallas_moe_combine import _token_block
+
+    assert _token_block(4096, 8, 2560, 8, 34816) == 128       # ling3
+    assert _token_block(4096, 4, 3584, 8, 18432) == 128       # xing4
+    assert _token_block(8192, 6, 2048, 8, 51200) == 256       # dsv2
+    assert _token_block(16384, 8, 2560, 8, 133120) == 128
+    assert _token_block(1 << 20, 6, 2048, 8, 6293504) == 256
+    assert _token_block(4096, 8, 2560 + 64, 8, 34816) is None
+    assert _token_block(4096, 8, 2560, 8, 34816 + 8) is None
+    assert _token_block(82, 2, 128, 4, 320) == 82
 
 
 # -- hyper-connections ---------------------------------------------------------

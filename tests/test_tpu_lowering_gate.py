@@ -101,6 +101,12 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert detail["conv_scope_pads"] == 0
         assert [detail["kernel_calls"][k] for k in (
             "pt_gmm_fwd", "pt_gmm_bwd_dx", "pt_gmm_bwd_dw")] == [36, 18, 18]
+        # and each layer's combine by token through its kernel (ISSUE
+        # 43): the forward's and d x; the replay's is dead code (a
+        # layer's output is the last thing its segment makes)
+        assert workload in chip_gate.MOE_COMBINE_KERNEL
+        assert detail["moe_ops"] == 6
+        assert detail["kernel_calls"]["pt_moe_combine"] == 12
     if workload == "dsv2_train_tiny":
         # four expert layers at the published expert width, 1,408 =
         # 11 x 128: the grouped matmuls compile with that axis whole
@@ -112,6 +118,15 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert workload in chip_gate.ROW_WORK_IN_LOOPS
         assert detail["rows_outside_loops"] == 0
         assert detail["kernel_calls"]["pt_row_buffer"] == 40
+        assert workload in chip_gate.MOE_COMBINE_KERNEL
+        assert detail["moe_ops"] == 4
+        assert detail["kernel_calls"]["pt_moe_combine"] == 8
+    if workload == "xing4_train_tiny":
+        # the replay's combine too: the stream mix after the experts
+        # reads their output in its backward
+        assert workload in chip_gate.MOE_COMBINE_KERNEL
+        assert detail["moe_ops"] == 4
+        assert detail["kernel_calls"]["pt_moe_combine"] == 12
 
 
 def test_kernel_calls_counts_mosaic_calls_by_kernel_name():
