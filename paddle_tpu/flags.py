@@ -117,37 +117,6 @@ define_flag("fc_epilogue", "off",
             "conv_epilogue — covers the transformer train graph's "
             "fc+bias+relu/gelu tails (the Adam-tail diagnosis's "
             "missing A/B leg)")
-define_flag("flash_packed_stats", "off",
-            "flash-attention row-stats layout: 'off' = the validated "
-            "lane-replicated [B*H, T, 128] f32 log-sum-exp (plus two "
-            "more replicated broadcasts materialized as backward "
-            "inputs) — ~12 GB of pure replication at seq-1M x 8 heads, "
-            "the OOM; 'on' = packed [B*H, T/128, 128] (row r -> "
-            "(r//128, r%128)), 128x smaller, and the backward reads "
-            "lse/delta packed instead of broadcast.  Geometric gate: "
-            "packing needs block_q >= 1024 (the f32 (8,128) sublane "
-            "rule on the packed output block); smaller blocks fall "
-            "back to the replicated layout even when 'on'.  Default "
-            "off until the chaser validates on chip "
-            "(docs/FLASH_ATTENTION.md)")
-define_flag("flash_head_pack", "off",
-            "flash-attention d<=64 head packing: 'on' processes TWO "
-            "(batch, head) rows per kernel grid step (block leading "
-            "dim 2) so the Mosaic scheduler can overlap one head's "
-            "VPU softmax with the other's MXU matmuls — at d64 wall "
-            "time is head_dim-independent (half the MXU idle), so the "
-            "second head rides in the bubble.  Requires head_dim <= "
-            "64 and an even B*H; otherwise falls back to one head "
-            "per step.  Default off until the chaser validates "
-            "(docs/FLASH_ATTENTION.md)")
-define_flag("flash_relayout", "reshape",
-            "in-kernel relayout strategy for the packed row-stats "
-            "blocks: 'reshape' = jnp.reshape (bq,)<->(bq//128,128) "
-            "(lowers under Mosaic on jax 0.4.37; cheapest); 'dot' = "
-            "iota/select + one MXU indicator matmul (guaranteed-"
-            "lowerable escape hatch if the chip host's Mosaic rejects "
-            "the reshape — the same class of drift the "
-            "CompilerParams shim covers)")
 define_flag("int8_interlayer", False,
             "int8 end-to-end activation flow (ISSUE 5): "
             "convert_to_int8_execution folds, for every quantized-op -> "

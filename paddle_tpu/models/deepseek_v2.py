@@ -1,7 +1,7 @@
 """A DeepSeek-V2 decoder (model_type `deepseek_v2`, arXiv:2405.04434),
 built from a `config.json`-style dict.  docs/DSV2_BLOCK.md writes the
-equations out; models/deepseek_v2_reference.py is the plain float32
-reference of the same equations.
+equations out; benchmarks/reference/deepseek_v2.py is the plain
+float32 reference of the same equations.
 
 Per layer, on ONE pre-norm residual stream: x + attn(norm(x)), then
 x + ffn(norm(x)).  Attention is latent (models/latent_attention.py):

@@ -6,8 +6,8 @@ ends in one SwiGLU (`shared_intermediate_size`; with `num_local_experts`
 0 there is no expert), and Granite's four multipliers scale the
 embedding, each residual branch, the attention scores and the logits.
 docs/GRANITE4_BLOCK.md writes the equations out;
-models/granite_hybrid_reference.py is the plain float32 reference of
-the same equations.
+benchmarks/reference/granite_hybrid.py is the plain float32 reference
+of the same equations.
 
     h0 = embedding_multiplier E[ids]
     h <- h + residual_multiplier Mixer(RMSNorm(h))

@@ -8,8 +8,8 @@ after `first_k_dense_replace` dense layers the feed-forward is a shared
 expert plus the routed experts in `held_experts`, chosen by a
 group-limited sigmoid router over ALL `num_experts_published` experts
 (DeepSeek-V3's `noaux_tc`).  docs/LING3_BLOCK.md writes the equations
-out; models/ling3_reference.py is the plain float32 reference of the
-same equations.  The vision tower is not built.
+out; benchmarks/reference/ling3.py is the plain float32 reference of
+the same equations.  The vision tower is not built.
 
     h <- h + Mixer_l(RMSNorm(h)),  h <- h + FFN_l(RMSNorm(h))
     logits = RMSNorm(h_L) W_head                         (untied)

@@ -4,8 +4,9 @@ Looped Language Models", arXiv:2510.25741), built from a
 run `total_ut_steps` times over ONE set of weights, with an output head
 and a one-output exit gate read after every pass and a loss that weighs
 the passes' cross-entropies by the exit distribution the gates define.
-docs/OURO_BLOCK.md writes the equations out; models/ouro_reference.py
-is the plain float32 reference of the same equations.
+docs/OURO_BLOCK.md writes the equations out;
+benchmarks/reference/ouro.py is the plain float32 reference of the
+same equations.
 
 A layer: RMSNorm, full causal attention (16 heads of 128 at the
 published size, no bias, split-half rotary; k and v at

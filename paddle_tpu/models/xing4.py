@@ -2,8 +2,8 @@
 connections, built from a `config.json`-style dict (model_type
 `xing4_0`: deepseek_v3's keys plus hc_mult, hc_sinkhorn_iters, hc_eps,
 mhc_h_res_clamp_min/max).  docs/XING4_BLOCK.md writes the equations
-out; models/xing4_reference.py is the plain float32 reference of the
-same equations.
+out; benchmarks/reference/xing4.py is the plain float32 reference of
+the same equations.
 
 Per layer: latent attention (q through a rank-`q_lora_rank` bottleneck,
 k and v from one rank-`kv_lora_rank` latent plus a shared rotary key;

@@ -27,9 +27,8 @@ size and head count; a token-major call the blocks cannot serve is
 transposed inside the entry.  Grid is (B*H/hpb, Tq/block_q,
 Tk/block_k) with the KV
 dimension innermost so the (acc, m, l) scratch carries across KV steps;
-hpb is the heads a step takes: head-major 1, or 2 under the
-`flash_head_pack` flag (see below); token-major the heads of a lane
-block, 128/D.
+hpb is the heads a step takes: head-major 1, token-major the heads
+of a lane block, 128/D.
 
 The public `flash_attention` is differentiable via ONE custom_vjp
 (`_flash_lse`, shared with `flash_attention_lse` and the IR op): forward
@@ -40,32 +39,6 @@ the head's dq resident in VMEM; past 74k rows, where that dq does not
 fit, a dq sweep and a dk/dv sweep) — the [Tq, Tk] matrices stay in VMEM
 in both directions.  The XLA impl is plain attention, differentiated by
 jax.
-
-Memory-layout variants (docs/FLASH_ATTENTION.md; both default OFF until
-the chip chaser validates them — zero behavior change under the
-defaults):
-
-* packed row-stats (`flash_packed_stats`): the per-row log-sum-exp is
-  stored packed as [B*H, T/128, 128] f32 (row r -> (r//128, r%128))
-  instead of 128x lane-replicated [B*H, T, 128], and the backward reads
-  lse/delta through the same packed layout instead of materializing two
-  more replicated broadcasts as kernel inputs.  At seq-1M x 8 heads the
-  replicated layout is ~12 GB of pure replication — the OOM that capped
-  the long-context ladder (ROADMAP 1.2).  Mosaic's f32 (8, 128)
-  sublane rule makes the packed (bq/128, 128) output block legal only
-  for block_q >= 1024; smaller blocks silently keep the replicated
-  layout (the documented fallback).
-
-* head packing (`flash_head_pack`): at head_dim <= 64 the MXU runs
-  half-width (a d-64 contraction pads to the 128-deep systolic array),
-  so d64 wall time equals d128's with half the useful FLOPs banked
-  (16.46% vs 32.99% MFU at seq 32k).  With packing, TWO (batch, head)
-  rows ride in each grid step (block leading dim 2, grid dim 0 halved):
-  the two heads are independent MXU/VPU dependency chains inside one
-  step, so the Mosaic scheduler can overlap head A's VPU softmax with
-  head B's matmuls instead of serializing them across grid steps (the
-  (m, l, acc) carry forces sequential KV steps per head).  Requires an
-  even B*H; odd products fall back to one head per step.
 """
 
 from __future__ import annotations
@@ -122,7 +95,6 @@ def _kernel_scope():
 
 _NEG_INF = -1e30
 _MIN_LANES = 128  # TPU vector lane count; m/l scratch padded to this
-_F32_SUBLANES = 8  # f32 min sublane tile — gates the packed-stats block
 
 
 # ---------------------------------------------------------------------------
@@ -158,85 +130,8 @@ def _plain_attention(q, k, v, causal, scale, with_lse=False):
 
 
 # ---------------------------------------------------------------------------
-# layout-variant gates + in-kernel row-stats relayout
+# a grid step's per-head tiles
 # ---------------------------------------------------------------------------
-
-def _packed_geom_ok(bq):
-    """The packed [T/128, 128] row-stats block is (bq/128, 128): Mosaic
-    requires the last two block dims to be (8k, 128m) for f32, so the
-    packing is legal only when bq/128 >= 8 -> bq >= 1024."""
-    return bq % _MIN_LANES == 0 and bq // _MIN_LANES >= _F32_SUBLANES
-
-
-def _head_pack_geom_ok(bh, d):
-    """Two heads per block: only profitable when the MXU runs
-    half-width (d <= 64) and only legal when B*H pairs up evenly.
-    Pairing is over the flattened B*H axis — any two rows are
-    independent attention problems, so crossing a batch boundary is
-    fine."""
-    return d <= 64 and bh % 2 == 0
-
-
-def _resolve_variants(packed_stats, head_pack):
-    """None -> the typed flags; explicit bools win (tests, ring/Ulysses
-    chunk dispatch)."""
-    from paddle_tpu.flags import get_flag
-
-    if packed_stats is None:
-        packed_stats = get_flag("flash_packed_stats") == "on"
-    if head_pack is None:
-        head_pack = get_flag("flash_head_pack") == "on"
-    return bool(packed_stats), bool(head_pack)
-
-
-def _relayout_how():
-    from paddle_tpu.flags import get_flag
-
-    return get_flag("flash_relayout")
-
-
-def _rows_to_packed(rows, bq):
-    """Per-row vector [bq] -> packed [bq/128, 128] (row r -> (r//128,
-    r%128)).  'reshape' lowers under Mosaic on jax 0.4.37 (verified via
-    the cross-lowering gate); 'dot' is the guaranteed-lowerable escape
-    hatch — iota/compare/select plus one indicator matmul (bq^2 MACs,
-    once per q-block finalize, negligible)."""
-    if _relayout_how() == "dot":
-        rows_repl = jnp.broadcast_to(rows[:, None], (bq, _MIN_LANES))
-        r = lax.broadcasted_iota(jnp.int32, (bq, _MIN_LANES), 0)
-        c = lax.broadcasted_iota(jnp.int32, (bq, _MIN_LANES), 1)
-        sel = jnp.where((r % _MIN_LANES) == c, rows_repl, 0.0)
-        gi = lax.broadcasted_iota(jnp.int32, (bq // _MIN_LANES, bq), 0)
-        gr = lax.broadcasted_iota(jnp.int32, (bq // _MIN_LANES, bq), 1)
-        ind = ((gr // _MIN_LANES) == gi).astype(jnp.float32)
-        return lax.dot_general(ind, sel, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    return rows.reshape(bq // _MIN_LANES, _MIN_LANES)
-
-
-def _packed_to_rows(packed, bq):
-    """Packed [bq/128, 128] -> per-row vector [bq] (inverse of
-    _rows_to_packed; same strategy flag)."""
-    if _relayout_how() == "dot":
-        gr = lax.broadcasted_iota(jnp.int32, (bq, bq // _MIN_LANES), 0)
-        gi = lax.broadcasted_iota(jnp.int32, (bq, bq // _MIN_LANES), 1)
-        ind = ((gr // _MIN_LANES) == gi).astype(jnp.float32)
-        u = lax.dot_general(ind, packed, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        r = lax.broadcasted_iota(jnp.int32, (bq, _MIN_LANES), 0)
-        c = lax.broadcasted_iota(jnp.int32, (bq, _MIN_LANES), 1)
-        return jnp.sum(jnp.where((r % _MIN_LANES) == c, u, 0.0), axis=1)
-    return packed.reshape(bq)
-
-
-def _stat_rows(ref, h, block_q, packed):
-    """Per-row stats vector [bq] for head-slot h from a backward stats
-    input block: [hpb, bq, 128] lane-replicated (read lane 0) or packed
-    [hpb, bq/128, 128]."""
-    if packed:
-        return _packed_to_rows(ref[h], block_q)
-    return ref[h, :, 0]
-
 
 def _head_tile(ref, h, hpb, token_major, block=None):
     """Head-slot h's [rows, width] tile of a q/k/v/dO block.
@@ -305,8 +200,7 @@ def _kv_blocks(k_ref, v_ref, hpb, slot):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                 l_ref, *, scale, causal, block_q, block_k, kv_len,
-                q_off, packed, hpb, token_major=False, group=1,
-                q_blocks=1):
+                q_off, hpb, token_major=False, group=1, q_blocks=1):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -334,7 +228,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 
     def _accumulate(masked):
         # the mask depends only on (qi, ki) geometry — one per step,
-        # shared by every packed head
+        # shared by the step's heads
         mask = None
         if masked:
             kpos = ki * block_k + lax.broadcasted_iota(
@@ -345,8 +239,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                     jnp.int32, (block_q, block_k), 0)
                 mask = mask & (qpos >= kpos)
         # the heads are independent dependency chains — the scheduler
-        # interleaves their MXU and VPU work within the step (the whole
-        # point of hpb=2 at d<=64)
+        # interleaves their MXU and VPU work within the step
         kb, vb = _kv_blocks(k_ref, v_ref, hpb, kv_slot)
         for h in range(hpb):
             q = _head_tile(q_ref, h, hpb, token_major)    # [bq, d]
@@ -400,19 +293,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
             # lse=-inf, whose exp(s - lse) entries are all masked off in
             # backward.
             rows = m_ref[h, :, 0] + jnp.log(l)
-            if packed:
-                # packed [bq/128, 128] block (row r -> (r//128, r%128)):
-                # 128x less HBM than the replicated layout; legal only
-                # for bq >= 1024 (f32 (8,128) sublane rule)
-                lse_ref[h, ...] = _rows_to_packed(rows, block_q)
-            else:
-                # lane-replicated ([bq, 128]): Mosaic requires the last
-                # two block dims to be (8k, 128m) or full — a [1, bq]
-                # block is rejected by the TPU lowering (caught on the
-                # first real-chip bench run; interpret-mode tests never
-                # enforce tiling)
-                lse_ref[h, ...] = jnp.broadcast_to(rows[:, None],
-                                                   lse_ref.shape[1:])
+            # lane-replicated ([bq, 128]): Mosaic requires the last two
+            # block dims to be (8k, 128m) or full — a [1, bq] block is
+            # rejected by the TPU lowering (caught on the first
+            # real-chip bench run; interpret-mode tests never enforce
+            # tiling)
+            lse_ref[h, ...] = jnp.broadcast_to(rows[:, None],
+                                               lse_ref.shape[1:])
         if token_major:
             o_ref[0] = block.astype(o_ref.dtype)
 
@@ -453,26 +340,17 @@ def _kv_heads(q, k, heads):
     return hkv
 
 
-def _block_geometry(q, k, v, block_q, block_k, packed_stats, head_pack,
-                    heads=None):
-    """(dims, bq, bk, packed, hpb) of the kernels' grid over these
-    operands: their `_dims`, blocks clamped to the lengths, the layout
-    variants where their geometry holds, and the heads a grid step
-    takes: on the token-major layout (`heads` given) the heads of one
-    128-lane block, which is a matter of geometry and not of
-    `flash_head_pack`."""
-    dims = b, h, tq, tk, d, _ = _dims(q, k, v, heads)
+def _block_geometry(q, k, v, block_q, block_k, heads=None):
+    """(dims, bq, bk, hpb) of the kernels' grid over these operands:
+    their `_dims`, blocks clamped to the lengths, and the heads a grid
+    step takes: on the token-major layout (`heads` given) the heads of
+    one 128-lane block, head-major one (so that with grouped KV heads
+    the index map alone picks the step's KV head)."""
+    dims = _, _, tq, tk, d, _ = _dims(q, k, v, heads)
     bq = min(block_q, max(tq, 8))
     bk = min(block_k, max(tk, 8))
-    packed = packed_stats and _packed_geom_ok(bq)
-    if heads is not None:
-        hpb = _MIN_LANES // d
-    else:
-        # grouped KV heads, head-major: one head a step, so that the
-        # index map alone picks the step's KV head
-        hpb = 2 if (head_pack and _head_pack_geom_ok(b * h, d)
-                    and _kv_heads(q, k, heads) == h) else 1
-    return dims, bq, bk, packed, hpb
+    hpb = 1 if heads is None else _MIN_LANES // d
+    return dims, bq, bk, hpb
 
 
 class _Tiles:
@@ -575,22 +453,20 @@ _MOSAIC_SCOPED_VMEM = 16 << 20
 _BWD_FUSED_VMEM_MAX = 96 << 20
 
 
-def _fwd_vmem_bytes(hpb, bq, bk, d, dv, itemsize, packed,
-                    token_major=False):
+def _fwd_vmem_bytes(hpb, bq, bk, d, dv, itemsize):
     """VMEM the forward needs, bytes, from above, reckoned as
     `_bwd_fused_vmem_bytes` is.  Two heads a step at 1,024-row blocks
     pass what Mosaic scopes a kernel to by default (their score tiles
     alone are 24 MiB), so the forward asks."""
     d, dv = _lanes(d), _lanes(dv)
-    slots = 1 if token_major else hpb
     # q, k, v and out blocks, double-buffered
     tiles = 2 * itemsize * (bq * (d + dv) + bk * (d + dv))
     # the lse block, double-buffered; m, l and the float32 acc
-    stats = 2 * 4 * (bq if packed else bq * _MIN_LANES) \
+    stats = 2 * 4 * bq * _MIN_LANES \
         + 2 * 4 * bq * _MIN_LANES + 4 * bq * dv
     # S, P and P's cast, as far as Mosaic keeps them whole
     temps = 3 * 4 * bq * bk
-    return slots * tiles + hpb * (stats + temps)
+    return tiles + hpb * (stats + temps)
 
 
 # jitted so that a step traces each kernel once per signature, not once
@@ -600,11 +476,9 @@ def _fwd_vmem_bytes(hpb, bq, bk, d, dv, itemsize, packed,
 # inlines the calls: the compiled step is the same module (same opcode
 # counts and code size, compiled for a described v5e; PERF.md, PR 24).
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "interpret", "packed_stats",
-    "head_pack", "heads"))
+    "causal", "scale", "block_q", "block_k", "interpret", "heads"))
 def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                      interpret=False, packed_stats=False,
-                      head_pack=False, heads=None):
+                      interpret=False, heads=None):
     """q/k: [B, H, T, D], v: [B, H, Tk, Dv] (Dv = D everywhere but in
     latent attention, whose q.k size is 192 and v size 128) ->
     ([B, H, Tq, Dv], lse [B*H, Tq_padded]).  With `heads`, the three
@@ -612,8 +486,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     send only what `_flash_layout` passed).  k and v may have fewer
     heads than q, H / group (`_kv_heads`)."""
     token_major = heads is not None
-    dims, bq, bk, packed, hpb = _block_geometry(
-        q, k, v, block_q, block_k, packed_stats, head_pack, heads)
+    dims, bq, bk, hpb = _block_geometry(q, k, v, block_q, block_k, heads)
     b, h, tq, tk, d, dv = dims
     group = h // _kv_heads(q, k, heads)
     tiles = _Tiles(dims, hpb, token_major, group)
@@ -624,23 +497,15 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        kv_len=tk, q_off=tk - tq if causal else 0, packed=packed,
-        hpb=hpb, token_major=token_major, group=group,
-        q_blocks=h // hpb)
+        kv_len=tk, q_off=tk - tq if causal else 0, hpb=hpb,
+        token_major=token_major, group=group, q_blocks=h // hpb)
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=max(
                 _MOSAIC_SCOPED_VMEM,
-                _fwd_vmem_bytes(hpb, bq, bk, d, dv, q.dtype.itemsize,
-                                packed, token_major)))
-    if packed:
-        lse_shape = (b * h, tq_p // _MIN_LANES, _MIN_LANES)
-        lse_block = (hpb, bq // _MIN_LANES, _MIN_LANES)
-    else:
-        lse_shape = (b * h, tq_p, _MIN_LANES)
-        lse_block = (hpb, bq, _MIN_LANES)
+                _fwd_vmem_bytes(hpb, bq, bk, d, dv, q.dtype.itemsize)))
     out, lse = pl.pallas_call(
         kernel,
         name="pt_flash_fwd",
@@ -652,11 +517,12 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         ],
         out_specs=[
             tiles.spec(bq, dv, _first),
-            pl.BlockSpec(lse_block, lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((hpb, bq, _MIN_LANES),
+                         lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(tiles.shape(tq_p, dv), q.dtype),
-            jax.ShapeDtypeStruct(lse_shape, jnp.float32),
+            jax.ShapeDtypeStruct((b * h, tq_p, _MIN_LANES), jnp.float32),
         ],
         scratch_shapes=[
             tiles.acc(bq, dv, per_head=True),
@@ -666,11 +532,8 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         interpret=interpret,
         **params,
     )(qp, kp, vp)
-    # callers see the documented [B*H, Tq_padded] lse in EVERY layout:
-    # packed unpacks with a free row-major reshape at the XLA boundary,
-    # replicated strips the lanes
-    lse2 = lse.reshape(b * h, tq_p) if packed else lse[:, :, 0]
-    return (tiles.result(out, tq), lse2)
+    # callers see the documented [B*H, Tq_padded] lse: strip the lanes
+    return (tiles.result(out, tq), lse[:, :, 0])
 
 
 # ---------------------------------------------------------------------------
@@ -726,7 +589,7 @@ def _bwd_p_ds_block(q, k, v, do, lse, delta, *, scale, causal,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, acc_ref, *, scale, causal, block_q,
-                   block_k, kv_len, q_len, q_off, packed, hpb,
+                   block_k, kv_len, q_len, q_off, hpb,
                    token_major=False, group=1, q_blocks=1):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -754,8 +617,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do = do.astype(jnp.float32)
             _, ds = _bwd_p_ds_block(
                 q, k, v, do,
-                _stat_rows(lse_ref, h, block_q, packed),
-                _stat_rows(delta_ref, h, block_q, packed),
+                lse_ref[h, :, 0], delta_ref[h, :, 0],
                 scale=scale,
                 causal=causal, block_q=block_q, block_k=block_k,
                 kv_len=kv_len, q_len=q_len, q_off=q_off, qi=qi, ki=ki,
@@ -780,7 +642,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     *refs, scale, causal, block_q, block_k, kv_len,
-                    q_len, q_off, packed, hpb, with_dq,
+                    q_len, q_off, hpb, with_dq,
                     token_major=False, group=1, q_blocks=1):
     """The dk/dv sweep: kv blocks outer, q blocks inner, dk_acc/dv_acc
     carried across the q sweep.  Head-major, every head slot has its
@@ -840,8 +702,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             a = 0 if token_major else h
             p, ds = _bwd_p_ds_block(
                 q, k, v, do,
-                _stat_rows(lse_ref, h, block_q, packed),
-                _stat_rows(delta_ref, h, block_q, packed),
+                lse_ref[h, :, 0], delta_ref[h, :, 0],
                 scale=scale,
                 causal=causal, block_q=block_q, block_k=block_k,
                 kv_len=kv_len, q_len=q_len, q_off=q_off, qi=qi, ki=ki,
@@ -879,28 +740,27 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dq_ref.dtype)
 
 
-def _bwd_fused_vmem_bytes(hpb, tq_p, bq, bk, d, dv, itemsize, packed,
-                          token_major=False):
+def _bwd_fused_vmem_bytes(hpb, tq_p, bq, bk, d, dv, itemsize):
     """VMEM the one-sweep backward needs, bytes, from above: what the
     chip's compiler asked for, compiled for a described v5e over the
-    cells' shapes, head sizes 64 to 192, both dtypes and hpb 2, was
-    0.35 to 0.9 of this (PERF.md, PR 29).  Token-major, the hpb heads
-    of a lane block share its tiles and accumulators (the resident dq
-    is [Tq_p, 128] for the pair: what one head's is, padded to whole
-    lanes); the row statistics and the score tiles stay a head's."""
+    cells' shapes, head sizes 64 to 192 and both dtypes, was 0.35 to
+    0.9 of this (PERF.md, PR 29).  The hpb heads of a lane block
+    (token-major at head size 64) share its tiles and accumulators
+    (the resident dq is [Tq_p, 128] for the pair: what one head's is,
+    padded to whole lanes); the row statistics and the score tiles
+    stay a head's."""
     d, dv = _lanes(d), _lanes(dv)
-    slots = 1 if token_major else hpb
     # q, k, v and dO, double-buffered
     tiles = 2 * itemsize * (bq + bk) * (d + dv)
     # the two row statistics, double-buffered
-    stats = 2 * 2 * 4 * (bq if packed else bq * _MIN_LANES)
+    stats = 2 * 2 * 4 * bq * _MIN_LANES
     # dk/dv: the double-buffered output blocks and their accumulators
     dkv = (2 * itemsize + 4) * bk * (d + dv)
     # dq: the float32 accumulator and the output block
     dq = (2 * itemsize + 4) * tq_p * d
     # S/P, dP, dS and their casts, as far as Mosaic keeps them whole
     temps = 4 * 4 * bq * bk
-    return slots * (tiles + dkv + dq) + hpb * (stats + temps)
+    return tiles + dkv + dq + hpb * (stats + temps)
 
 
 def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
@@ -910,12 +770,10 @@ def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
     Counted here, outside the jit, so that a step of six layers reads
     six.  **call: the static arguments `_call_args` resolved."""
     heads = call.get("heads")
-    (_, _, tq, _, d, dv), bq, bk, packed, hpb = _block_geometry(
-        q, k, v, call["block_q"], call["block_k"], call["packed_stats"],
-        call["head_pack"], heads)
+    (_, _, tq, _, d, dv), bq, bk, hpb = _block_geometry(
+        q, k, v, call["block_q"], call["block_k"], heads)
     vmem = _bwd_fused_vmem_bytes(
-        hpb, -(-tq // bq) * bq, bq, bk, d, dv, q.dtype.itemsize, packed,
-        token_major=heads is not None)
+        hpb, -(-tq // bq) * bq, bq, bk, d, dv, q.dtype.itemsize)
     fused = vmem <= _BWD_FUSED_VMEM_MAX
     _count_impl("flash_attention_bwd", "fused" if fused else "two_sweep")
     return _flash_bwd_pallas(
@@ -924,11 +782,10 @@ def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
 
 
 @functools.partial(jax.jit, static_argnames=(    # see _flash_fwd_pallas
-    "causal", "scale", "block_q", "block_k", "interpret", "packed_stats",
-    "head_pack", "heads", "one_sweep_vmem"))
+    "causal", "scale", "block_q", "block_k", "interpret", "heads",
+    "one_sweep_vmem"))
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
-                      block_k, interpret=False, dlse=None,
-                      packed_stats=False, head_pack=False, heads=None,
+                      block_k, interpret=False, dlse=None, heads=None,
                       *, one_sweep_vmem):
     """q/k: [B, H, T, D], v, o and g = dO: [.., Dv] (with `heads`, all
     token-major [B, T, H*D], and so the gradients: `_Tiles`); lse:
@@ -942,16 +799,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     caller consumes it (ring attention's cross-chunk merge).  Since
     d lse_r / d s_rc = p_rc, it folds into the delta term:
     dS = P*(dO V^T - delta) + P*dlse = P*(dO V^T - (delta - dlse)).
-
-    Under the packed-stats layout, lse and delta ride into the kernels
-    as [B*H, Tq_p/128, 128] free reshapes of the per-row vectors; the
-    replicated layout instead materializes TWO 128x lane-broadcasts in
-    HBM as kernel inputs (~8 GB at seq-1M x 8 heads — with the fwd lse
-    the third, the seq-1M OOM).
     """
     token_major = heads is not None
-    dims, bq, bk, packed, hpb = _block_geometry(
-        q, k, v, block_q, block_k, packed_stats, head_pack, heads)
+    dims, bq, bk, hpb = _block_geometry(q, k, v, block_q, block_k, heads)
     b, h, tq, tk, d, dv = dims
     group = h // _kv_heads(q, k, heads)
     tiles = _Tiles(dims, hpb, token_major, group)
@@ -991,26 +841,17 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
         delta_full = delta_full - dlse.reshape(b * h, -1)[:, :tq] \
             .astype(jnp.float32)
     delta = _pad_axis(delta_full, 1, bq)
-    if packed:
-        # free row-major reshapes of the [B*H, Tq_p] vectors — nothing
-        # is materialized beyond the vectors themselves
-        lse3 = lse.reshape(b * h, tq_p // _MIN_LANES, _MIN_LANES)
-        delta3 = delta.reshape(b * h, tq_p // _MIN_LANES, _MIN_LANES)
-        lblk = (hpb, bq // _MIN_LANES, _MIN_LANES)
-    else:
-        # lane-replicate the per-row vectors: [B*H, Tq_p] ->
-        # [B*H, Tq_p, 128] (2-D [1, bq] blocks violate Mosaic's
-        # last-two-dims tiling rule; same layout the forward kernel
-        # emits for lse)
-        lse3 = jnp.broadcast_to(lse[:, :, None],
-                                (b * h, tq_p, _MIN_LANES))
-        delta3 = jnp.broadcast_to(delta[:, :, None],
-                                  (b * h, tq_p, _MIN_LANES))
-        lblk = (hpb, bq, _MIN_LANES)
+    # lane-replicate the per-row vectors: [B*H, Tq_p] ->
+    # [B*H, Tq_p, 128] (2-D [1, bq] blocks violate Mosaic's
+    # last-two-dims tiling rule; same layout the forward kernel
+    # emits for lse)
+    lse3 = jnp.broadcast_to(lse[:, :, None], (b * h, tq_p, _MIN_LANES))
+    delta3 = jnp.broadcast_to(delta[:, :, None],
+                              (b * h, tq_p, _MIN_LANES))
     q_off = tk - tq if causal else 0
     common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
-                  kv_len=tk, q_len=tq, q_off=q_off, packed=packed,
-                  hpb=hpb, token_major=token_major, group=group,
+                  kv_len=tk, q_len=tq, q_off=q_off, hpb=hpb,
+                  token_major=token_major, group=group,
                   q_blocks=h // hpb)
     operands = (qp, kp, vp, gp, lse3, delta3)
     out_shape = [
@@ -1022,8 +863,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     def specs(q_rows, k_rows):
         """in_specs of (q, k, v, dO, lse, delta) for a grid order:
         which of the two inner grid indices is the q block's."""
-        stat = pl.BlockSpec(
-            lblk, lambda bh, i, j: (bh, q_rows(i, j), 0))
+        stat = pl.BlockSpec((hpb, bq, _MIN_LANES),
+                            lambda bh, i, j: (bh, q_rows(i, j), 0))
         return [tiles.spec(bq, d, q_rows),
                 tiles.spec(bk, d, k_rows, kv=True),
                 tiles.spec(bk, dv, k_rows, kv=True),
@@ -1107,43 +948,37 @@ def _sum_groups(x, group, width):
 # differentiable entries: ONE custom_vjp over the forward/backward kernels
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
-               packed_stats, head_pack, heads=None):
+               heads=None):
     """(out, lse): lse is the mergeable summary ring attention needs and
     the residual the IR grad op reads.  heads: `_flash_fwd_pallas`."""
     return _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                             interpret=interpret,
-                             packed_stats=packed_stats,
-                             head_pack=head_pack, heads=heads)
+                             interpret=interpret, heads=heads)
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k,
-                   interpret, packed_stats, head_pack, heads):
+                   interpret, heads):
     out, lse = _flash_fwd_pallas(q, k, v, causal, scale, block_q,
                                  block_k, interpret=interpret,
-                                 packed_stats=packed_stats,
-                                 head_pack=head_pack, heads=heads)
+                                 heads=heads)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_lse_bwd(causal, scale, block_q, block_k, interpret,
-                   packed_stats, head_pack, heads, res, g):
+def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, heads,
+                   res, g):
     q, k, v, o, lse = res
     do, dlse = g
     return _flash_bwd(q, k, v, o, lse, do, dlse=dlse, causal=causal,
                       scale=scale, block_q=block_q, block_k=block_k,
-                      interpret=interpret, packed_stats=packed_stats,
-                      head_pack=head_pack, heads=heads)
+                      interpret=interpret, heads=heads)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 def flash_attention_lse(q, k, v, *, causal=False, scale=None,
-                        block_q=None, block_k=None, impl="pallas",
-                        packed_stats=None, head_pack=None):
+                        block_q=None, block_k=None, impl="pallas"):
     """Like flash_attention but also returns the per-row log-sum-exp
     ([B*H, Tq_padded_to_block]): (out, lse) is a complete mergeable
     attention summary — two chunks combine as
@@ -1154,17 +989,12 @@ def flash_attention_lse(q, k, v, *, causal=False, scale=None,
 
     impl: "pallas" (the default — this entry has no XLA form) or
     "interpret", which a test asks for by name; nothing here picks
-    interpret mode from the platform.
-
-    packed_stats/head_pack: None -> the `flash_packed_stats` /
-    `flash_head_pack` flags; explicit bools override.  The returned lse
-    is layout-independent ([B*H, Tq_padded]) in every mode."""
+    interpret mode from the platform."""
     if impl not in ("pallas", "interpret"):
         raise ValueError(
             "flash_attention_lse impl must be 'pallas' or 'interpret', "
             "got %r" % (impl,))
-    impl, kw = _call_args(q, k, causal, scale, block_q, block_k, impl,
-                          packed_stats, head_pack)
+    impl, kw = _call_args(q, k, causal, scale, block_q, block_k, impl)
     _count_impl("flash_attention", impl)
     _count_impl("flash_attention_layout", "head_major")
     with _kernel_scope():
@@ -1183,8 +1013,7 @@ def _default_block(t):
 
 
 def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
-                    block_k=None, impl=None, packed_stats=None,
-                    head_pack=None, heads=None):
+                    block_k=None, impl=None, heads=None):
     """Fused attention. q/k: [B, H, T, D], v: [B, H, Tk, Dv]; returns
     [B, H, Tq, Dv].  Dv = D everywhere but in latent attention (q.k 192
     = 128 + 64 rotary, v 128): the three kernels take the two sizes.
@@ -1199,39 +1028,29 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     "interpret" (pallas interpret mode, for CPU tests), or "xla".
     block_q/block_k default to a size picked by sequence length
     (_default_block).
-
-    packed_stats / head_pack: memory-layout variants (module
-    docstring, docs/FLASH_ATTENTION.md).  None defers to the
-    `flash_packed_stats` / `flash_head_pack` flags (both default off);
-    explicit bools override — outputs are identical in every mode, only
-    the kernel's HBM layout and grid packing change.
     """
     return _flash_attention_fwd(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, impl=impl, packed_stats=packed_stats,
-        head_pack=head_pack, heads=heads)[0]
+        block_k=block_k, impl=impl, heads=heads)[0]
 
 
 def _call_args(q, k, causal=False, scale=None, block_q=None, block_k=None,
-               impl=None, packed_stats=None, head_pack=None, heads=None):
+               impl=None, heads=None):
     """What a flash entry's unset (None, or an op attr's 0) arguments
     mean, resolved in ONE place (the saved-residual backward reads the
     forward's lse and must tile it the same way): scale 1/sqrt(d), impl
-    `_auto_impl()`, blocks by sequence length, layout variants from
-    their flags.  heads: None for [B, H, T, D] operands, the head count
-    of token-major [B, T, H*D] ones (rows are dim -2 of both).  Returns
+    `_auto_impl()`, blocks by sequence length.  heads: None for
+    [B, H, T, D] operands, the head count of token-major [B, T, H*D]
+    ones (rows are dim -2 of both).  Returns
     (impl, the static arguments `_flash_lse` and `_flash_bwd_pallas`
     share)."""
     impl = impl or _auto_impl()
-    packed_stats, head_pack = _resolve_variants(packed_stats, head_pack)
     return impl, dict(
         causal=bool(causal),
         scale=float(scale or 1.0 / math.sqrt(q.shape[-1] // (heads or 1))),
         block_q=block_q or _default_block(q.shape[-2]),
         block_k=block_k or _default_block(k.shape[-2]),
-        interpret=impl == "interpret",
-        packed_stats=packed_stats, head_pack=head_pack,
-        heads=heads or None)
+        interpret=impl == "interpret", heads=heads or None)
 
 
 def _flash_layout(q, k, v, heads, impl):
@@ -1400,11 +1219,10 @@ def _on_tpu():
 #     (SMEM) so the K/V BlockSpec index maps can address physical pages
 #     (blk[b, p]) before the body runs — the standard paged-attention
 #     Pallas shape.
-#   * head packing (flag `flash_head_pack`, same gate spirit as the
-#     fwd kernel): at d <= 64 two heads of the SAME sequence ride per
-#     grid step (block (1, 2, ...)), needing H even — the pairing must
-#     not cross a batch boundary because both heads share one block
-#     table entry.
+#   * head packing (the `head_pack` argument): at d <= 64 two heads
+#     of the SAME sequence ride per grid step (block (1, 2, ...)),
+#     needing H even — the pairing must not cross a batch boundary
+#     because both heads share one block table entry.
 #
 # int8 KV (`kv_int8`): pages hold the PR-5 per-channel contract
 # (q = clip(round(x/s*127))); the kernel dequantizes IN VMEM with the
@@ -1820,10 +1638,11 @@ def flash_decode(q, k_pages, v_pages, block_tables, seq_lens, *,
 
     impl: None (auto: pallas on TPU, reference replay elsewhere),
     "pallas", "interpret", or "xla" (the gather+reference path).
-    head_pack: None defers to the `flash_head_pack` flag; needs
-    d <= 64 and an even H.  Every mode is bit-identical (array_equal)
-    to flash_decode_reference — the parity contract tests pin across
-    page boundaries, ragged lengths, d in {64, 128}, f32/bf16/int8-KV,
+    head_pack: two heads a grid step; needs d <= 64 and an even H
+    (`_decode_hpb`; off where they do not hold).  Every mode is
+    bit-identical (array_equal) to flash_decode_reference — the
+    parity contract tests pin across page boundaries, ragged
+    lengths, d in {64, 128}, f32/bf16/int8-KV,
     head-packed and not, q_len 1 and k+1.  Verify row r is ALSO
     bit-identical to a q-len-1 call at seq_len - R + 1 + r (masked
     pages are exact no-ops in the online-softmax merge) — the
@@ -1831,8 +1650,6 @@ def flash_decode(q, k_pages, v_pages, block_tables, seq_lens, *,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     scale = float(scale)
-    if head_pack is None:
-        head_pack = _resolve_variants(None, None)[1]
     if impl is None:
         impl = "pallas" if _on_tpu() else "xla"
     q_len = 1 if q.ndim == 3 else int(q.shape[1])

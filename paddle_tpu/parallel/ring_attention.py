@@ -55,8 +55,7 @@ def _local_causal_bias(q_pos, k_pos):
 
 
 def ring_attention(q, k, v, mesh=None, axis="sp", causal=False,
-                   scale=None, impl=None, block_q=None, block_k=None,
-                   packed_stats=None, head_pack=None):
+                   scale=None, impl=None, block_q=None, block_k=None):
     """Exact attention with sequence sharded over ``axis``.
 
     q/k/v: [B, S, H, D] global arrays (S = full sequence).  Inside jit the
@@ -74,10 +73,7 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False,
     block_q/block_k: kernel tile override for the per-chunk flash
     calls — the chunk length is S/n, not S, so the kernel's
     seq-length-keyed default can land differently than a whole-seq
-    call's; pin them when sweeping.  packed_stats/head_pack: the flash
-    memory-layout variants (ops/pallas_kernels.py; None defers to the
-    flags) — at ring scale the packed row-stats matter most, since
-    every chunk of every rotation materializes its own lse.
+    call's; pin them when sweeping.
     """
     from paddle_tpu.parallel import env as penv
 
@@ -112,9 +108,7 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False,
 
         o, lse = flash_attention_lse(qt, kc, vc, causal=chunk_causal,
                                      scale=scale, impl=flash_impl,
-                                     block_q=block_q, block_k=block_k,
-                                     packed_stats=packed_stats,
-                                     head_pack=head_pack)
+                                     block_q=block_q, block_k=block_k)
         b, h, t, _d = qt.shape
         lse = lse[:, :t].reshape(b, h, t).astype(jnp.float32)
         return o.astype(jnp.float32), lse, jnp.ones_like(lse)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 def ulysses_attention(q, k, v, mesh=None, axis="sp", causal=False,
                       scale=None, impl=None, block_q=None,
-                      block_k=None, packed_stats=None, head_pack=None):
+                      block_k=None):
     """q/k/v: [B, S, H, D] global arrays, S sharded over ``axis``.
 
     impl: None (auto: 'flash' on TPU, 'xla' elsewhere) — after the
@@ -28,11 +28,7 @@ def ulysses_attention(q, k, v, mesh=None, axis="sp", causal=False,
     run through the Pallas flash kernel ('flash'/'flash_interpret') or
     the plain einsum path ('xla').
 
-    block_q/block_k pin the kernel tiles; packed_stats/head_pack are
-    the flash memory-layout variants (None -> flags).  Ulysses is
-    where head_pack composes naturally: each device runs FULL-sequence
-    attention for H/n heads, so at d<=64 an even per-device head count
-    pairs up inside the kernel."""
+    block_q/block_k pin the kernel tiles."""
     from paddle_tpu.parallel import env as penv
     from paddle_tpu.parallel.ring_attention import _plain_attention
 
@@ -65,8 +61,7 @@ def ulysses_attention(q, k, v, mesh=None, axis="sp", causal=False,
                 jnp.swapaxes(qh, 1, 2), jnp.swapaxes(kh, 1, 2),
                 jnp.swapaxes(vh, 1, 2), causal=causal, scale=scale,
                 impl="interpret" if impl == "flash_interpret"
-                else "pallas", block_q=block_q, block_k=block_k,
-                packed_stats=packed_stats, head_pack=head_pack)
+                else "pallas", block_q=block_q, block_k=block_k)
             return jnp.swapaxes(o, 1, 2)
         return _plain_attention(qh, kh, vh, causal, scale)
 

@@ -262,7 +262,7 @@ class DecodeConfig:
             if max_attempts is not None else 2 * self.n_replicas + 1
         self.eos_id = int(eos_id)
         self.kv_int8 = kv_int8      # None -> the typed flag
-        self.head_pack = head_pack  # None -> the typed flag
+        self.head_pack = head_pack  # flash_decode's; None = off
         self.drain_timeout_s = float(drain_timeout_s)
         self.impl = impl            # flash_decode impl (None = auto)
         # observability (ISSUE 9): /metrics + /varz on this server

@@ -2,6 +2,7 @@
 multi-device sharding tests run anywhere (SURVEY.md §4 implication:
 reference subprocess-cluster tests -> virtual device mesh tests)."""
 
+import importlib.util
 import os
 import sys
 
@@ -63,6 +64,25 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if durations.get(item.nodeid, 0.0) > _SLOW_THRESHOLD_S:
             item.add_marker(pytest.mark.slow)
+
+
+def reference_path(name):
+    """benchmarks/reference/<name>.py: the ONE file of a plain
+    reference, which decides a cell's `correct` on the chip."""
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks", "reference", name + ".py")
+
+
+def load_reference(name):
+    """The plain reference `name`, loaded from its path as the
+    benchmark's harness loads it: never entered in sys.modules,
+    `benchmarks/` never on sys.path."""
+    spec = importlib.util.spec_from_file_location(
+        "_reference_" + name, reference_path(name))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _reset_program_state():

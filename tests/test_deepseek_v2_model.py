@@ -1,7 +1,7 @@
 """models/deepseek_v2.py through the normal path (layers -> [recompute]
 -> [AMP] -> backward -> Executor.run(CompiledProgram)) against the plain
-reference models/deepseek_v2_reference.py on seeded weights: the loss,
-its two terms (cross-entropy, expert-balance), the logits and EVERY
+reference benchmarks/reference/deepseek_v2.py on seeded weights: the
+loss, its two terms (cross-entropy, expert-balance), the logits and EVERY
 parameter's gradient; the balance loss by hand; the share test that
 ties the one-chip cut to the whole layer; and `xing4`'s program, which
 now builds its attention through the function the two models share.
@@ -25,6 +25,8 @@ Tolerances, and why.
   float32 scores of a bf16-rounded input: 2e-3 relative.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,8 +35,11 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import layers, optimizer
 from paddle_tpu.core.scope import global_scope
-from paddle_tpu.models import deepseek_v2_reference as ref
 from paddle_tpu.models.deepseek_v2 import deepseek_v2_model
+
+from conftest import load_reference, reference_path
+
+ref = load_reference("deepseek_v2")
 
 SEQ, BATCH = 32, 2
 
@@ -592,14 +597,7 @@ def test_xing4_program_is_the_one_before(amp, monkeypatch):
 
 
 def test_the_benchmarks_reference_is_this_one():
-    """benchmarks/reference/deepseek_v2.py, which decides the cell's
-    `correct` on the chip, is a copy of the reference these tests
-    compare the program with."""
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "reference",
-                           "deepseek_v2.py")) as f, \
-            open(os.path.join(root, "paddle_tpu", "models",
-                              "deepseek_v2_reference.py")) as g:
-        assert f.read() == g.read()
+    """benchmarks/reference/deepseek_v2.py, which decides the cell's `correct`
+    on the chip, is the file these tests compare the program with, and
+    not a copy of it."""
+    assert os.path.samefile(ref.__file__, reference_path("deepseek_v2"))

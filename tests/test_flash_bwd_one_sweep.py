@@ -29,10 +29,9 @@ def _operands(b, h, tq, tk, d, dv, dtype, seed=0):
         (b, h, tq, d), (b, h, tk, d), (b, h, tk, dv), (b, h, tq, dv)))
 
 
-def _static(d, causal, bq, bk, packed_stats=False, head_pack=False):
+def _static(d, causal, bq, bk):
     return dict(causal=causal, scale=d ** -0.5, block_q=bq, block_k=bk,
-                interpret=True, packed_stats=packed_stats,
-                head_pack=head_pack)
+                interpret=True)
 
 
 def _bwd_counts():
@@ -41,38 +40,29 @@ def _bwd_counts():
         if lbl["kernel"] == "flash_attention_bwd"})
 
 
-# b, h, tq, tk, d, dv, causal, block_q, block_k, dtype, variants
+# b, h, tq, tk, d, dv, causal, block_q, block_k, dtype
 CASES = {
-    "full_one_block": (2, 2, 32, 32, 8, 8, False, 32, 32, "float32", {}),
-    "full_blocks": (2, 2, 32, 32, 8, 8, False, 16, 16, "float32", {}),
-    "causal_blocks": (2, 2, 64, 64, 8, 8, True, 16, 16, "float32", {}),
-    "causal_tq_lt_tk": (1, 2, 16, 48, 8, 8, True, 8, 16, "float32", {}),
-    "full_tq_gt_tk": (1, 2, 48, 16, 8, 8, False, 16, 8, "float32", {}),
-    "pad_q": (1, 2, 40, 32, 8, 8, False, 16, 16, "float32", {}),
-    "pad_k": (1, 2, 32, 40, 8, 8, False, 16, 16, "float32", {}),
-    "pad_both_causal": (1, 2, 40, 40, 8, 8, True, 16, 16, "float32", {}),
-    "one_q_block_many_kv": (1, 2, 16, 64, 8, 8, False, 16, 16, "float32",
-                            {}),
-    "many_q_blocks_one_kv": (1, 2, 64, 16, 8, 8, False, 16, 16, "float32",
-                             {}),
-    "d_ne_dv": (1, 2, 32, 32, 24, 16, True, 16, 16, "float32", {}),
-    "latent_192_128": (1, 1, 32, 32, 192, 128, True, 16, 16, "float32",
-                       {}),
-    "head_pack": (1, 4, 32, 32, 64, 64, True, 16, 16, "float32",
-                  {"head_pack": True}),
-    "head_pack_padded": (1, 2, 24, 40, 16, 16, True, 16, 16, "float32",
-                         {"head_pack": True}),
-    "packed_stats": (1, 1, 2048, 2048, 8, 8, True, 1024, 1024, "float32",
-                     {"packed_stats": True}),
-    "bf16": (1, 2, 64, 64, 16, 16, True, 16, 16, "bfloat16", {}),
+    "full_one_block": (2, 2, 32, 32, 8, 8, False, 32, 32, "float32"),
+    "full_blocks": (2, 2, 32, 32, 8, 8, False, 16, 16, "float32"),
+    "causal_blocks": (2, 2, 64, 64, 8, 8, True, 16, 16, "float32"),
+    "causal_tq_lt_tk": (1, 2, 16, 48, 8, 8, True, 8, 16, "float32"),
+    "full_tq_gt_tk": (1, 2, 48, 16, 8, 8, False, 16, 8, "float32"),
+    "pad_q": (1, 2, 40, 32, 8, 8, False, 16, 16, "float32"),
+    "pad_k": (1, 2, 32, 40, 8, 8, False, 16, 16, "float32"),
+    "pad_both_causal": (1, 2, 40, 40, 8, 8, True, 16, 16, "float32"),
+    "one_q_block_many_kv": (1, 2, 16, 64, 8, 8, False, 16, 16, "float32"),
+    "many_q_blocks_one_kv": (1, 2, 64, 16, 8, 8, False, 16, 16, "float32"),
+    "d_ne_dv": (1, 2, 32, 32, 24, 16, True, 16, 16, "float32"),
+    "latent_192_128": (1, 1, 32, 32, 192, 128, True, 16, 16, "float32"),
+    "bf16": (1, 2, 64, 64, 16, 16, True, 16, 16, "bfloat16"),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_one_sweep_equals_two_sweeps_and_the_reference(case):
-    b, h, tq, tk, d, dv, causal, bq, bk, dtype, variants = CASES[case]
+    b, h, tq, tk, d, dv, causal, bq, bk, dtype = CASES[case]
     q, k, v, g = _operands(b, h, tq, tk, d, dv, dtype)
-    kw = _static(d, causal, bq, bk, **variants)
+    kw = _static(d, causal, bq, bk)
     o, lse = pk._flash_fwd_pallas(q, k, v, **kw)
     before = _bwd_counts()
     one = pk._flash_bwd(q, k, v, o, lse, g, **kw)
@@ -252,12 +242,9 @@ def test_token_major_shape_rule_and_vmem():
     on both).  The forward asks for the VMEM two heads' score tiles
     need at 1,024-row blocks (it compiled at neither layout before it
     asked)."""
-    one = pk._bwd_fused_vmem_bytes(1, 8192, 1024, 1024, 64, 64, 2, False)
-    pair = pk._bwd_fused_vmem_bytes(2, 8192, 1024, 1024, 64, 64, 2, False,
-                                    token_major=True)
-    packed = pk._bwd_fused_vmem_bytes(2, 8192, 1024, 1024, 64, 64, 2,
-                                      False)
-    assert one < pair < packed == 2 * one
+    one = pk._bwd_fused_vmem_bytes(1, 8192, 1024, 1024, 64, 64, 2)
+    pair = pk._bwd_fused_vmem_bytes(2, 8192, 1024, 1024, 64, 64, 2)
+    assert one < pair < 2 * one
     # MiB Mosaic took, compiled for a described v5e (PERF.md, PR 31):
     # (hpb, block, d, dv, token_major); 16.2 is what passed the 16 MiB
     # a kernel is scoped to unless it asks
@@ -267,15 +254,14 @@ def test_token_major_shape_rule_and_vmem():
             (1, 1024, 128, 128, False): 12.7,
             (1, 1024, 192, 128, False): 11.4}
     for (hpb, blk, d, dv, tm), mib in took.items():
-        est = pk._fwd_vmem_bytes(hpb, blk, blk, d, dv, 2, False, tm) \
-            / 2 ** 20
+        est = pk._fwd_vmem_bytes(hpb, blk, blk, d, dv, 2) / 2 ** 20
         assert mib < est < 3 * mib, (hpb, blk, d, tm, est)
     # and for the token-major backward: `_s8k`, `_s512`, 16k x 128
     for (hpb, t, blk, d), mib in {(2, 8192, 1024, 64): 27.6,
                                   (2, 512, 512, 64): 6.0,
                                   (1, 16384, 1024, 128): 28.5}.items():
-        est = pk._bwd_fused_vmem_bytes(hpb, t, blk, blk, d, d, 2, False,
-                                       token_major=True) / 2 ** 20
+        est = pk._bwd_fused_vmem_bytes(hpb, t, blk, blk, d, d,
+                                       2) / 2 ** 20
         assert mib < est < 3 * mib, (hpb, t, d, est)
 
     def trace(t, heads=2):
@@ -358,11 +344,10 @@ def test_vmem_asked_for_covers_what_the_chip_compiler_took():
     took = {(1, 8192, 1024, 64, 64, 2): 22.1, (1, 512, 512, 64, 64, 2): 4.5,
             (1, 4096, 1024, 192, 128, 2): 24.4,
             (1, 32768, 1024, 128, 128, 2): 46.2,
-            (2, 8192, 1024, 64, 64, 2): 39.3,
             (1, 8192, 1024, 64, 64, 4): 32.4}
     for (hpb, t, blk, d, dv, itemsize), mib in took.items():
-        est = pk._bwd_fused_vmem_bytes(hpb, t, blk, blk, d, dv, itemsize,
-                                       False) / 2 ** 20
+        est = pk._bwd_fused_vmem_bytes(hpb, t, blk, blk, d, dv,
+                                       itemsize) / 2 ** 20
         assert mib < est < 3 * mib, (hpb, t, d, itemsize, est)
 
 

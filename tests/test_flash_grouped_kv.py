@@ -254,9 +254,11 @@ def test_ouro_model_with_grouped_kv_heads(kv_heads):
     count: the loss and every gradient, a KV projection's as the sum
     over its group."""
     import test_ouro_model as t
+    from conftest import load_reference
     from paddle_tpu.core.scope import global_scope
-    from paddle_tpu.models import ouro_reference as ref
     from paddle_tpu.models.ouro import ouro_model
+
+    ref = load_reference("ouro")
 
     config = dict(t.SMALL, num_key_value_heads=kv_heads,
                   total_ut_steps=2)

@@ -90,15 +90,15 @@ def _run(prog, feed, fetch):
                        fetch_list=list(fetch))
 
 
-def _pallas_calls(fn, *args):
-    """{pallas_call name: count} in the jaxpr of fn(*args), nested
+def _pallas_eqns(fn, *args):
+    """The pallas_call equations in the jaxpr of fn(*args), nested
     jaxprs (jit, checkpoint, shard_map, custom_vjp) included."""
-    found = collections.Counter()
+    found = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                found[eqn.params["name"]] += 1
+                found.append(eqn)
             for val in eqn.params.values():
                 for sub in (val if isinstance(val, (list, tuple))
                             else [val]):
@@ -107,7 +107,19 @@ def _pallas_calls(fn, *args):
                         walk(sub)
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return dict(found)
+    return found
+
+
+def _pallas_calls(fn, *args):
+    """{pallas_call name: count} in the jaxpr of fn(*args)."""
+    return dict(collections.Counter(
+        eqn.params["name"] for eqn in _pallas_eqns(fn, *args)))
+
+
+def _pallas_grids(fn, *args):
+    """The grid of every pallas_call in the jaxpr of fn(*args)."""
+    return [tuple(eqn.params["grid_mapping"].grid)
+            for eqn in _pallas_eqns(fn, *args)]
 
 
 def _kernel_calls(prog, feed):

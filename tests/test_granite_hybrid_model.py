@@ -1,6 +1,6 @@
 """models/granite_hybrid.py through the normal path (layers ->
 [recompute] -> [AMP] -> backward -> Executor.run(CompiledProgram))
-against the plain reference models/granite_hybrid_reference.py on
+against the plain reference benchmarks/reference/granite_hybrid.py on
 seeded weights: the loss, the logits and EVERY parameter's gradient;
 the tied matrix's gradient as the sum of its two readers'; the state
 that has to cross the chunks; the scopes, the counters and the number
@@ -36,9 +36,12 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import optimizer
 from paddle_tpu.core.scope import global_scope
-from paddle_tpu.models import granite_hybrid_reference as ref
 from paddle_tpu.models.granite_hybrid import granite_hybrid_model
 from paddle_tpu.ops import pallas_kernels as pk
+
+from conftest import load_reference
+
+ref = load_reference("granite_hybrid")
 
 SEQ, BATCH, CHUNK = 64, 2, 16
 

@@ -1,7 +1,7 @@
 """models/ling3.py through the normal path (layers -> [recompute] ->
 [AMP] -> backward -> Executor.run(CompiledProgram)) against the plain
-reference models/ling3_reference.py on seeded weights: the loss, the
-logits and EVERY parameter's gradient; the erase term and the group
+reference benchmarks/reference/ling3.py on seeded weights: the loss,
+the logits and EVERY parameter's gradient; the erase term and the group
 limit, which the comparison has to see; the share test that ties the
 one-chip cut to the whole layer; the scopes, the counters and the
 number of scan kernels a step holds; and `xing4` and `deepseek-v2-lite`,
@@ -42,9 +42,12 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import optimizer
 from paddle_tpu.core.scope import global_scope
-from paddle_tpu.models import ling3_reference as ref
 from paddle_tpu.models.ling3 import ling3_model
 from paddle_tpu.ops import pallas_kernels as pk
+
+from conftest import load_reference
+
+ref = load_reference("ling3")
 
 SEQ, BATCH = 64, 2
 

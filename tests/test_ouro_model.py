@@ -1,7 +1,7 @@
 """models/ouro.py through the normal path (layers -> [recompute] ->
 [AMP] -> backward -> Executor.run(CompiledProgram)) against the plain
-reference models/ouro_reference.py on seeded weights: the loss, the R
-passes' logits, the exit distribution and EVERY parameter's gradient;
+reference benchmarks/reference/ouro.py on seeded weights: the loss, the
+R passes' logits, the exit distribution and EVERY parameter's gradient;
 and what a stack run R times over ONE set of weights forced in shared
 code: one VarDesc and one initializer a shared name, a chain of
 partial gradients from more than two recompute segments, one cast of a
@@ -39,8 +39,11 @@ from paddle_tpu.core import scope as scope_mod
 from paddle_tpu.core.compiler import shared_param_reads
 from paddle_tpu.core.program import Program
 from paddle_tpu.core.scope import global_scope
-from paddle_tpu.models import ouro_reference as ref
 from paddle_tpu.models.ouro import ouro_model
+
+from conftest import load_reference, reference_path
+
+ref = load_reference("ouro")
 
 SEQ, BATCH = 32, 2
 
@@ -485,11 +488,6 @@ def test_program_is_verified_and_shape_checked():
 
 def test_the_benchmarks_reference_is_this_one():
     """benchmarks/reference/ouro.py, which decides the cell's `correct`
-    on the chip, is a copy of the reference these tests compare the
-    program with."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "reference",
-                           "ouro.py")) as f, \
-            open(os.path.join(root, "paddle_tpu", "models",
-                              "ouro_reference.py")) as g:
-        assert f.read() == g.read()
+    on the chip, is the file these tests compare the program with, and
+    not a copy of it."""
+    assert os.path.samefile(ref.__file__, reference_path("ouro"))

@@ -415,227 +415,6 @@ def test_transformer_flash_branch_has_no_head_split_or_merge(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Packed row-stats + head-packing layout variants (flash memory
-# overhaul): outputs must be BIT-parity with the default layouts in
-# interpret mode — the variants change only HBM layout and grid
-# packing, never a single arithmetic op per head.
-# ---------------------------------------------------------------------------
-
-def _exact(a, b, msg):
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                  err_msg=msg)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_packed_stats_bit_parity_fwd_bwd(causal, dtype):
-    """Packed [T/128, 128] row-stats vs the replicated layout: forward
-    AND the dedicated Pallas backward are bit-identical (the packing is
-    a pure relayout of the same per-row values).  bq=1024 activates the
-    geometric gate; T=2048 exercises two q-blocks."""
-    rng = np.random.RandomState(11)
-    q, k, v = _rand_qkv(rng, 1, 2, 2048, 2048, 16)
-    q, k, v = (x.astype(dtype) for x in (q, k, v))
-    w = jnp.asarray(rng.randn(1, 2, 2048, 16).astype(np.float32))
-
-    def loss(packed):
-        def f(a, b, c):
-            o = flash_attention(a, b, c, causal=causal,
-                                impl="interpret", block_q=1024,
-                                block_k=256, packed_stats=packed)
-            return (o.astype(jnp.float32) * w).sum()
-        return f
-
-    with jax.default_matmul_precision("float32"):
-        o_base = flash_attention(q, k, v, causal=causal,
-                                 impl="interpret", block_q=1024,
-                                 block_k=256)
-        o_pack = flash_attention(q, k, v, causal=causal,
-                                 impl="interpret", block_q=1024,
-                                 block_k=256, packed_stats=True)
-        _exact(o_base, o_pack, "fwd")
-        g_base = jax.grad(loss(False), argnums=(0, 1, 2))(q, k, v)
-        g_pack = jax.grad(loss(True), argnums=(0, 1, 2))(q, k, v)
-    for name, a, b in zip("q k v".split(), g_base, g_pack):
-        _exact(a, b, f"d{name}")
-
-
-def test_packed_stats_bq_fallback():
-    """bq < 1024 fails the (8, 128) sublane gate: packed_stats=True
-    must silently keep the replicated layout (and stay correct) — the
-    documented fallback path."""
-    from paddle_tpu.ops.pallas_kernels import _packed_geom_ok
-
-    assert _packed_geom_ok(1024) and _packed_geom_ok(2048)
-    assert not _packed_geom_ok(512)    # 4 sublanes < 8
-    assert not _packed_geom_ok(96)     # not lane-aligned
-    rng = np.random.RandomState(12)
-    q, k, v = _rand_qkv(rng, 1, 2, 100, 100, 16)
-    with jax.default_matmul_precision("float32"):
-        base = flash_attention(q, k, v, causal=True, impl="interpret",
-                               block_q=32, block_k=32)
-        pk = flash_attention(q, k, v, causal=True, impl="interpret",
-                             block_q=32, block_k=32, packed_stats=True)
-        _exact(base, pk, "bq<1024 fallback fwd")
-        g1 = jax.grad(lambda a: flash_attention(
-            a, k, v, causal=True, impl="interpret", block_q=32,
-            block_k=32, packed_stats=True).sum())(q)
-        g2 = jax.grad(lambda a: _plain_attention(
-            a, k, v, True, 0.25).sum())(q)
-        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
-                                   atol=2e-5)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_head_pack_bit_parity_fwd_bwd(causal, dtype):
-    """Two heads per grid block vs one: per-head math is identical op
-    for op, so outputs and grads are bit-identical.  4 heads -> 2
-    packed pairs; small blocks (head packing has no bq gate)."""
-    rng = np.random.RandomState(13)
-    q, k, v = _rand_qkv(rng, 1, 4, 96, 96, 32)  # non-multiple of block
-    q, k, v = (x.astype(dtype) for x in (q, k, v))
-    w = jnp.asarray(rng.randn(1, 4, 96, 32).astype(np.float32))
-
-    def loss(hp):
-        def f(a, b, c):
-            o = flash_attention(a, b, c, causal=causal,
-                                impl="interpret", block_q=32,
-                                block_k=32, head_pack=hp)
-            return (o.astype(jnp.float32) * w).sum()
-        return f
-
-    with jax.default_matmul_precision("float32"):
-        o_base = flash_attention(q, k, v, causal=causal,
-                                 impl="interpret", block_q=32,
-                                 block_k=32)
-        o_hp = flash_attention(q, k, v, causal=causal,
-                               impl="interpret", block_q=32,
-                               block_k=32, head_pack=True)
-        _exact(o_base, o_hp, "fwd")
-        g_base = jax.grad(loss(False), argnums=(0, 1, 2))(q, k, v)
-        g_hp = jax.grad(loss(True), argnums=(0, 1, 2))(q, k, v)
-    for name, a, b in zip("q k v".split(), g_base, g_hp):
-        _exact(a, b, f"d{name}")
-
-
-def test_head_pack_gate_and_fallback():
-    """The pairing gate: d <= 64 and even B*H.  Odd B*H (1x3 heads)
-    must fall back to one head per block and stay correct; d=128 never
-    packs (nothing to gain — the MXU is already full-width)."""
-    from paddle_tpu.ops.pallas_kernels import _head_pack_geom_ok
-
-    assert _head_pack_geom_ok(8, 64) and _head_pack_geom_ok(2, 32)
-    assert not _head_pack_geom_ok(3, 64)    # odd B*H
-    assert not _head_pack_geom_ok(8, 128)   # full-width head
-    rng = np.random.RandomState(14)
-    q, k, v = _rand_qkv(rng, 1, 3, 64, 64, 16)
-    with jax.default_matmul_precision("float32"):
-        base = flash_attention(q, k, v, causal=True, impl="interpret",
-                               block_q=32, block_k=32)
-        hp = flash_attention(q, k, v, causal=True, impl="interpret",
-                             block_q=32, block_k=32, head_pack=True)
-        _exact(base, hp, "odd-B*H fallback")
-
-
-def test_packed_hp_compose_lse_and_flags():
-    """packed_stats and head_pack compose in one kernel; the lse
-    output stays the layout-independent [B*H, Tq_padded] contract; and
-    the typed flags drive the dispatch when no kwarg is given."""
-    from paddle_tpu.flags import set_flags
-    from paddle_tpu.ops.pallas_kernels import flash_attention_lse
-
-    rng = np.random.RandomState(15)
-    q, k, v = _rand_qkv(rng, 1, 2, 1024, 1024, 16)
-    with jax.default_matmul_precision("float32"):
-        o1, l1 = flash_attention_lse(q, k, v, causal=True,
-                                     impl="interpret", block_q=1024,
-                                     block_k=256)
-        o2, l2 = flash_attention_lse(q, k, v, causal=True,
-                                     impl="interpret", block_q=1024,
-                                     block_k=256, packed_stats=True,
-                                     head_pack=True)
-        assert l1.shape == l2.shape == (2, 1024)
-        _exact(o1, o2, "compose fwd")
-        _exact(l1, l2, "compose lse")
-        # flag-driven dispatch (the bench/IR path sets flags, not
-        # kwargs) — parity again, then restore defaults
-        set_flags({"flash_packed_stats": "on", "flash_head_pack": "on"})
-        try:
-            o3, l3 = flash_attention_lse(q, k, v, causal=True,
-                                         impl="interpret",
-                                         block_q=1024, block_k=256)
-        finally:
-            set_flags({"flash_packed_stats": "off",
-                       "flash_head_pack": "off"})
-        _exact(o1, o3, "flag-driven fwd")
-        _exact(l1, l3, "flag-driven lse")
-
-
-def test_packed_stats_dot_relayout_strategy():
-    """The 'dot' in-kernel relayout (the Mosaic escape hatch for the
-    reshape) is value-identical to the reshape strategy, forward and
-    backward."""
-    from paddle_tpu.flags import set_flags
-
-    rng = np.random.RandomState(16)
-    q, k, v = _rand_qkv(rng, 1, 2, 1024, 1024, 16)
-    with jax.default_matmul_precision("float32"):
-        base = flash_attention(q, k, v, causal=True, impl="interpret",
-                               block_q=1024, block_k=256,
-                               packed_stats=True)
-        gb = jax.grad(lambda a: flash_attention(
-            a, k, v, causal=True, impl="interpret", block_q=1024,
-            block_k=256, packed_stats=True).sum())(q)
-        set_flags({"flash_relayout": "dot"})
-        try:
-            dot = flash_attention(q, k, v, causal=True,
-                                  impl="interpret", block_q=1024,
-                                  block_k=256, packed_stats=True)
-            gd = jax.grad(lambda a: flash_attention(
-                a, k, v, causal=True, impl="interpret", block_q=1024,
-                block_k=256, packed_stats=True).sum())(q)
-        finally:
-            set_flags({"flash_relayout": "reshape"})
-        _exact(base, dot, "dot relayout fwd")
-        np.testing.assert_allclose(np.asarray(gb), np.asarray(gd),
-                                   atol=1e-5)
-
-
-def test_packed_stats_lse_split_merge():
-    """Ring attention's contract under the packed layout: (out, lse)
-    from packed-stats kernels still merges across a KV split exactly
-    like the replicated layout (lse values are identical; only the
-    kernel-internal storage changed)."""
-    from paddle_tpu.ops.pallas_kernels import flash_attention_lse
-
-    b, h, t, d = 1, 2, 2048, 16
-    rng = np.random.RandomState(17)
-    q, k, v = _rand_qkv(rng, b, h, t, t, d)
-    sc = 1.0 / np.sqrt(d)
-
-    def halves(packed):
-        o1, l1 = flash_attention_lse(q, k[:, :, :t // 2],
-                                     v[:, :, :t // 2],
-                                     impl="interpret", block_q=1024,
-                                     block_k=256, scale=sc,
-                                     packed_stats=packed)
-        o2, l2 = flash_attention_lse(q, k[:, :, t // 2:],
-                                     v[:, :, t // 2:],
-                                     impl="interpret", block_q=1024,
-                                     block_k=256, scale=sc,
-                                     packed_stats=packed)
-        return _merge_lse(o1.astype(jnp.float32), l1.reshape(b, h, t),
-                          o2.astype(jnp.float32), l2.reshape(b, h, t))
-
-    with jax.default_matmul_precision("float32"):
-        o_r, l_r = halves(False)
-        o_p, l_p = halves(True)
-    _exact(o_r, o_p, "merged out")
-    _exact(l_r, l_p, "merged lse")
-
-
-# ---------------------------------------------------------------------------
 # Mosaic TPU lowering legality — interpret mode never enforces the
 # (8, 128) last-two-dims block tiling rule, so a kernel can pass every
 # CPU test and still be rejected by the real-chip lowering (this
@@ -644,67 +423,48 @@ def test_packed_stats_lse_split_merge():
 # the tpu platform on CPU, running the Mosaic block-mapping checks.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape,causal", [
-    ((32, 8, 512, 512, 64), True),    # transformer-base bench shape
-    ((8, 16, 512, 512, 64), False),   # bert-base bench shape
-    ((1, 2, 100, 100, 64), True),     # padding path
-    ((1, 1, 8, 136, 64), False),      # cross attention, tiny q
-])
-def test_flash_tpu_lowering_is_legal(shape, causal):
+# q, k and v shapes; the head count of token-major operands; causal
+LOWERING = {
+    "tfm_base": (((32, 8, 512, 64),) * 3, None, True),
+    "bert_base": (((8, 16, 512, 64),) * 3, None, False),
+    "padding": (((1, 2, 100, 64),) * 3, None, True),
+    "cross_tiny_q": (((1, 1, 8, 64),) + ((1, 1, 136, 64),) * 2, None,
+                     False),
+    # token-major, as the cells' projections leave q, k and v: two
+    # heads a lane block (`_s8k`), one (`ouro`), grouped KV heads read
+    # in place (`granite4`)
+    "token_major_d64": (((4, 8192, 512),) * 3, 8, True),
+    "token_major_d128": (((1, 4096, 2048),) * 3, 16, True),
+    "token_major_32q_8kv": (((1, 8192, 2048),) + ((1, 8192, 512),) * 2,
+                            32, True),
+    # latent attention, head-major as `xing4` and `dsv2` feed it: q.k
+    # 192, v 128
+    "latent_192_128": (((1, 16, 4096, 192),) * 2 + ((1, 16, 4096, 128),),
+                       None, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOWERING))
+def test_flash_tpu_lowering_is_legal(case):
     from jax import export
 
     from paddle_tpu.ops.pallas_kernels import flash_attention_lse
 
-    b, h, tq, tk, d = shape
-    q = jnp.zeros((b, h, tq, d), jnp.bfloat16)
-    k = jnp.zeros((b, h, tk, d), jnp.bfloat16)
-    v = jnp.zeros((b, h, tk, d), jnp.bfloat16)
+    shapes, heads, causal = LOWERING[case]
+    q, k, v = (jnp.zeros(shape, jnp.bfloat16) for shape in shapes)
 
     def step(q, k, v):
         return jax.grad(
             lambda q, k, v: flash_attention(
-                q, k, v, causal=causal, impl="pallas")
+                q, k, v, causal=causal, impl="pallas", heads=heads)
             .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
     export.export(jax.jit(step), platforms=("tpu",))(q, k, v)
+    if heads is not None or v.shape[-1] != q.shape[-1]:
+        return    # flash_attention_lse: head-major, one head size
 
     def step_lse(q, k, v):
         return flash_attention_lse(q, k, v, causal=causal,
                                    impl="pallas")
 
     export.export(jax.jit(step_lse), platforms=("tpu",))(q, k, v)
-
-
-@pytest.mark.parametrize("variant", ["packed", "hp2", "packed_hp2",
-                                     "packed_dot"])
-def test_flash_variant_tpu_lowering_is_legal(variant):
-    """The packed-stats / head-packed kernels must ALSO pass the Mosaic
-    cross-lowering gate — the packed (bq/128, 128) output block and the
-    in-kernel (bq,)<->(bq/128, 128) relayout are exactly the class of
-    construct Mosaic may reject while interpret mode stays green (the
-    ISSUE's stated risk; the reshape strategy verified to lower on jax
-    0.4.37, with the 'dot' escape hatch covered here too)."""
-    from jax import export
-
-    from paddle_tpu.flags import set_flags
-
-    kw = {"packed": dict(packed_stats=True),
-          "hp2": dict(head_pack=True),
-          "packed_hp2": dict(packed_stats=True, head_pack=True),
-          "packed_dot": dict(packed_stats=True)}[variant]
-    q = jnp.zeros((1, 8, 2048, 64), jnp.bfloat16)
-
-    def step(q, k, v):
-        return jax.grad(
-            lambda q, k, v: flash_attention(
-                q, k, v, causal=True, impl="pallas", block_q=1024,
-                block_k=1024, **kw)
-            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
-
-    if variant == "packed_dot":
-        set_flags({"flash_relayout": "dot"})
-    try:
-        export.export(jax.jit(step), platforms=("tpu",))(q, q, q)
-    finally:
-        if variant == "packed_dot":
-            set_flags({"flash_relayout": "reshape"})

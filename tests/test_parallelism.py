@@ -131,16 +131,17 @@ def test_ulysses_flash_impl_matches_plain(causal):
 
 
 @pytest.mark.parametrize("which", ["ring", "ulysses"])
-def test_seq_parallel_flash_variant_dispatch(which):
-    """The flash memory-overhaul variants thread through the
-    sequence-parallel dispatch: ring/Ulysses with head_pack=True (two
-    heads per kernel block inside each chunk) and packed_stats=True
-    (falls back to replicated at these chunk sizes — the gate is
-    geometric, not an error) still match plain attention, values and
-    grads."""
+def test_seq_parallel_pinned_blocks_reach_the_chunk_kernels(which):
+    """block_q / block_k pinned on ring / Ulysses attention are the
+    blocks of the per-chunk flash kernels (a chunk is S/n rows on the
+    ring, S on Ulysses: the length-keyed default would clamp to one
+    block of either), and the result still matches plain attention,
+    values and grads."""
+    from test_flash_saved_residuals import _pallas_grids
+
     mesh = _mesh((4,), ("sp",))
     rng = np.random.RandomState(21)
-    b, s, h, d = 1, 32, 4, 16
+    b, s, h, d = 1, 64, 4, 16
     q, k, v = [rng.randn(b, s, h, d).astype(np.float32)
                for _ in range(3)]
     w = jnp.asarray(rng.randn(b, s, h, d).astype(np.float32))
@@ -150,12 +151,14 @@ def test_seq_parallel_flash_variant_dispatch(which):
     def loss_v(q, k, v):
         return jnp.sum(fn(
             q, k, v, mesh=mesh, axis="sp", causal=True,
-            impl="flash_interpret", block_q=8, block_k=8,
-            packed_stats=True, head_pack=True) * w)
+            impl="flash_interpret", block_q=8, block_k=8) * w)
 
     def loss_plain(q, k, v):
         return jnp.sum(_plain_attention(q, k, v, True, scale) * w)
 
+    blocks = (s // 4 if which == "ring" else s) // 8
+    grids = _pallas_grids(loss_v, q, k, v)
+    assert grids and all(g[1:] == (blocks, blocks) for g in grids), grids
     with jax.default_matmul_precision("float32"):
         v1, g1 = jax.value_and_grad(loss_v, argnums=(0, 1, 2))(q, k, v)
         v2, g2 = jax.value_and_grad(loss_plain, argnums=(0, 1, 2))(

@@ -61,6 +61,18 @@ def test_flags_env_and_types():
     assert "benchmark" in flags.all_flags()
 
 
+@pytest.mark.parametrize("name", ["flash_packed_stats", "flash_head_pack",
+                                  "flash_relayout"])
+def test_removed_flash_flags_are_unknown(name):
+    """The flash layout flags went with the paths they chose (PR 44):
+    an operator's stale setting is told, not swallowed."""
+    from paddle_tpu import flags
+
+    assert name not in flags.all_flags()
+    with pytest.raises(KeyError, match=name):
+        fluid.set_flags({name: "on"})
+
+
 def test_draw_program_dot(tmp_path):
     x, out = _small_net()
     path = str(tmp_path / "prog.dot")

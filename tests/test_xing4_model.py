@@ -1,7 +1,7 @@
 """models/xing4.py through the normal path (layers -> [recompute] ->
 [AMP] -> backward -> Executor.run(CompiledProgram)) against the plain
-reference models/xing4_reference.py on seeded weights: logits, loss and
-EVERY parameter's gradient; and the share test that ties the one-chip
+reference benchmarks/reference/xing4.py on seeded weights: logits, loss
+and EVERY parameter's gradient; and the share test that ties the one-chip
 cut (8 of 64 experts held) to the whole layer.
 
 Tolerances, and why.
@@ -27,6 +27,8 @@ Tolerances, and why.
   worst over the cases).
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,8 +37,11 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import optimizer
 from paddle_tpu.core.scope import global_scope
-from paddle_tpu.models import xing4_reference as ref
 from paddle_tpu.models.xing4 import xing4_model
+
+from conftest import load_reference, reference_path
+
+ref = load_reference("xing4")
 
 SEQ, BATCH = 32, 2
 
@@ -336,14 +341,7 @@ def test_amp_gradients_of_a_var_read_by_several_matmuls():
 
 
 def test_the_benchmarks_reference_is_this_one():
-    """benchmarks/reference/xing4.py, which decides the cell's
-    `correct` on the chip, is a copy of the reference these tests
-    compare the program with."""
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "reference",
-                           "xing4.py")) as f, \
-            open(os.path.join(root, "paddle_tpu", "models",
-                              "xing4_reference.py")) as g:
-        assert f.read() == g.read()
+    """benchmarks/reference/xing4.py, which decides the cell's `correct`
+    on the chip, is the file these tests compare the program with, and
+    not a copy of it."""
+    assert os.path.samefile(ref.__file__, reference_path("xing4"))

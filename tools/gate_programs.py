@@ -514,16 +514,12 @@ def _build_resnet50_infer_int8(batch=128, int8_activations=False):
     return fn, state, feed, model["logits"].name, calib, infer_prog
 
 
-def _build_longctx_train(batch=1, heads=8, seq=32768, head_dim=64,
-                         block_q=None, block_k=None,
-                         packed_stats=False, head_pack=False):
+def _build_longctx_train(batch=1, heads=8, seq=32768, head_dim=64):
     """Build the long-context attention step: causal flash fwd+bwd
     over bf16 q, k, v fed as data, gradients fetched.  Unfused
     attention at seq 32k materializes an ~34 GB fp32 score matrix
     (8 heads x 32768^2 x 4 B); the Pallas kernel keeps scores in VMEM.
-    packed_stats / head_pack set the flash layout flags
-    (ops/pallas_kernels.py, docs/FLASH_ATTENTION.md).  Returns
-    (fn, state, feed, fetches)."""
+    Returns (fn, state, feed, fetches)."""
     import jax
     import jax.numpy as jnp
 
@@ -531,20 +527,13 @@ def _build_longctx_train(batch=1, heads=8, seq=32768, head_dim=64,
     from paddle_tpu import backward, framework, layers
 
     _fresh_programs()
-    # always set explicitly, like conv_epilogue: "off" is the default
-    # graph, not "whatever a previous in-process build left behind"
-    from paddle_tpu.flags import set_flags
-
-    set_flags({"flash_packed_stats": "on" if packed_stats else "off",
-               "flash_head_pack": "on" if head_pack else "off"})
     qkv = []
     for n in "qkv":
         x = layers.data(n, shape=[heads, seq, head_dim],
                         dtype="bfloat16")
         x.stop_gradient = False
         qkv.append(x)
-    out = layers.flash_attention(*qkv, causal=True, block_q=block_q,
-                                 block_k=block_k)
+    out = layers.flash_attention(*qkv, causal=True)
     loss = layers.reduce_sum(layers.cast(out, "float32"))
     backward.append_backward(loss)
     exe = fluid.Executor(fluid.TPUPlace())

@@ -192,22 +192,6 @@ def _workloads():
         # while interpret mode stays green
         "resnet50_train_convbnstats": lambda:
             progs._build_resnet50_train(128, conv_bn_stats=True)[:3],
-        # flash memory-overhaul variants (ops/pallas_kernels.py): the
-        # packed (bq/128, 128) row-stats block and the in-kernel
-        # (bq,)<->(bq/128, 128) relayout are EXACTLY the construct
-        # class Mosaic may reject while interpret mode stays green —
-        # the ISSUE's stated risk (the strided-slice lesson from the
-        # convep round).  seq 4096 keeps the build fast while
-        # block_q=1024 makes the packed gate real.
-        "longctx_train_packed": lambda: progs._build_longctx_train(
-            1, 8, 4096, 64, block_q=1024, block_k=1024,
-            packed_stats=True)[:3],
-        "longctx_train_hp2": lambda: progs._build_longctx_train(
-            1, 8, 4096, 64, block_q=1024, block_k=1024,
-            head_pack=True)[:3],
-        "longctx_train_packed_hp2": lambda: progs._build_longctx_train(
-            1, 8, 4096, 64, block_q=1024, block_k=1024,
-            packed_stats=True, head_pack=True)[:3],
         # the fused multi-tensor Adam tail (optimizer.py
         # Adam(fuse=True)): concat/split over every param must lower
         # for tpu before the batch-slide A/B leg runs
@@ -529,13 +513,12 @@ def check_workload(name, build):
 
     orig = pk._on_tpu
     pk._on_tpu = lambda: True
-    # flag hygiene: variant builds (packed/hp2) set process-global
-    # flags; reset to defaults so a variant workload can never leak
-    # its layout into the next build's trace
+    # flag hygiene: variant builds set process-global flags; reset to
+    # defaults so a variant workload can never leak into the next
+    # build's trace
     from paddle_tpu.flags import set_flags
 
-    set_flags({"flash_packed_stats": "off", "flash_head_pack": "off",
-               "fc_epilogue": "off", "gspmd": False,
+    set_flags({"fc_epilogue": "off", "gspmd": False,
                "serving_sharded": False})
     try:
         fn, state, feed = build()
