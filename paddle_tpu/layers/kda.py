@@ -106,23 +106,33 @@ def head_l2_norm(input, n_head, scale=1.0, epsilon=1e-6, name=None):
 
 
 def head_gated_rms_norm(input, gate, epsilon=1e-6, norm=True,
-                        param_attr=None, name=None):
+                        param_attr=None, name=None, n_head=None):
     """sigmoid(gate_h) * RMSNorm(x_h) * scale for each head's slice x_h
     of the last axis: input [.., H*D], gate [.., H] one logit a head,
     the norm a head with ONE learnable scale of D (`<name>.w`,
     initially 1), the gate after the norm.  norm=False: no norm and no
-    parameter, sigmoid(gate_h) * x_h."""
+    parameter, sigmoid(gate_h) * x_h.  gate None with n_head = H: no
+    gate, RMSNorm(x_h) * scale (the norm on q and on k of an attention
+    layer, a head at a time)."""
     from paddle_tpu.initializer import Constant
 
     helper = LayerHelper("head_gated_rms_norm", name=name)
-    inputs = {"X": input, "Gate": gate}
+    if (gate is None) == (n_head is None) or (gate is None and not norm):
+        raise ValueError("head_gated_rms_norm: one of a gate and n_head "
+                         "says how many heads, and without a gate "
+                         "there is the norm")
+    heads = int(n_head or gate.shape[-1])
+    inputs = {"X": input}
+    if gate is not None:
+        inputs["Gate"] = gate
     if norm:
         inputs["Scale"] = helper.create_parameter(
             _named(param_attr, name, ""),
-            [int(input.shape[-1]) // int(gate.shape[-1])], "float32",
+            [int(input.shape[-1]) // heads], "float32",
             default_initializer=Constant(1.0))
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op(type="head_gated_rms_norm", inputs=inputs,
                      outputs={"Y": out},
-                     attrs={"epsilon": float(epsilon)})
+                     attrs={"epsilon": float(epsilon),
+                            "n_head": int(n_head or 0)})
     return out

@@ -78,7 +78,7 @@ def swiglu(gate, up, name=None):
 def moe_route(input, n_experts, k, routed_scaling_factor=1.0,
               norm_topk_prob=True, param_attr=None, bias_attr=None,
               name=None, scoring_func="sigmoid", return_scores=False,
-              n_group=1, topk_group=1):
+              n_group=1, topk_group=1, norm_topk_eps=0.0):
     """Top-k router over ALL n_experts, group-limited where n_group > 1
     (the experts are n_group groups of consecutive ids; the topk_group
     groups with the largest sum of their two best s + bias are kept and
@@ -97,7 +97,7 @@ def moe_route(input, n_experts, k, routed_scaling_factor=1.0,
       made.
 
     The gates are routed_scaling_factor * s_e, over the sum of the
-    selected s first with norm_topk_prob."""
+    selected s (+ norm_topk_eps) first with norm_topk_prob."""
     from paddle_tpu.initializer import Constant
 
     helper = LayerHelper("moe_route", name=name)
@@ -121,7 +121,8 @@ def moe_route(input, n_experts, k, routed_scaling_factor=1.0,
                "routed_scaling_factor": float(routed_scaling_factor),
                "norm_topk_prob": bool(norm_topk_prob),
                "scoring_func": str(scoring_func),
-               "n_group": int(n_group), "topk_group": int(topk_group)})
+               "n_group": int(n_group), "topk_group": int(topk_group),
+               "norm_topk_eps": float(norm_topk_eps)})
     return (idx, weight, scores) if return_scores else (idx, weight)
 
 

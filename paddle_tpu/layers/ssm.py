@@ -2,14 +2,17 @@
 chunked scan ssd_scan, the depthwise causal convolution over time
 causal_conv1d, and the gated RMSNorm gated_rms_norm.
 docs/GRANITE4_BLOCK.md has the equations; models/granite_hybrid.py
-builds a hybrid stack from them."""
+builds a hybrid stack from them.  gated_short_conv is the same
+convolution op with both gates of an LFM2 layer inside
+(docs/LFM2_BLOCK.md; models/lfm2.py)."""
 
 from __future__ import annotations
 
 from paddle_tpu.layers.helper import LayerHelper
 from paddle_tpu.layers.llm import _named
 
-__all__ = ["ssd_scan", "mamba2_scan", "causal_conv1d", "gated_rms_norm"]
+__all__ = ["ssd_scan", "mamba2_scan", "causal_conv1d", "gated_short_conv",
+           "gated_rms_norm"]
 
 
 def ssd_scan(x, dt, a, b, c, d, chunk_size=256, impl=None, name=None):
@@ -85,7 +88,7 @@ def mamba2_scan(x, dt, b, c, chunk_size=256, impl=None, name=None):
 
 
 def causal_conv1d(input, width, activation="silu", param_attr=None,
-                  bias_attr=None, name=None):
+                  bias_attr=None, name=None, gated=False):
     """Depthwise convolution over time that never reads ahead, bias and
     activation fused: input [B, T, C], a filter of `width` taps a
     channel (`<name>.w` [C, width]) and a bias (`<name>_bias.w` [C];
@@ -93,11 +96,12 @@ def causal_conv1d(input, width, activation="silu", param_attr=None,
     uniform in (-width^-1/2, width^-1/2) unless their attr says
     otherwise: what the Mamba-2 reference code's depthwise Conv1d
     starts from (one input channel a filter: a fan-in of `width`).
-    activation "silu" or None."""
+    activation "silu" or None.  gated: input is [B, T, 3 C], see
+    gated_short_conv."""
     from paddle_tpu.initializer import Uniform
 
     helper = LayerHelper("causal_conv1d", name=name)
-    c = int(input.shape[-1])
+    c = int(input.shape[-1]) // (3 if gated else 1)
     bound = float(width) ** -0.5
     inputs = {"X": input,
               "W": helper.create_parameter(
@@ -110,8 +114,29 @@ def causal_conv1d(input, width, activation="silu", param_attr=None,
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op(type="causal_conv1d", inputs=inputs,
                      outputs={"Y": out},
-                     attrs={"activation": activation or ""})
+                     attrs={"activation": activation or "",
+                            "gated": bool(gated)})
     return out
+
+
+def gated_short_conv(input, width, param_attr=None, name=None):
+    """The doubly gated short convolution of an LFM2 layer, between its
+    two projections: input [B, T, 3 C] is ONE projection whose thirds
+    along the last axis are [Gb | Gc | x], and
+
+        y[t] = Gc[t] * sum_k w[:, k] (Gb * x)[t - (width-1) + k]
+
+    [B, T, C], zero before t = 0; no bias, no activation; a filter of
+    `width` taps a channel (`<name>.w` [C, width], uniform in
+    (-width^-1/2, width^-1/2) unless param_attr says otherwise).  One
+    causal_conv1d op with `gated` set: its kernels read the thirds in
+    place and its grad writes the projection's whole gradient once."""
+    if int(input.shape[-1]) % 3:
+        raise ValueError("gated_short_conv: %d channels are not three "
+                         "thirds" % int(input.shape[-1]))
+    return causal_conv1d(input, width, activation=None,
+                         param_attr=param_attr, bias_attr=False, name=name,
+                         gated=True)
 
 
 def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None):

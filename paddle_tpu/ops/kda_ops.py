@@ -194,7 +194,8 @@ def head_l2_norm(ins, attrs):
 
 
 @register_op("head_gated_rms_norm", inputs=("X", "Gate", "Scale"),
-             outputs=("Y",), attrs={"epsilon": 1e-6}, optional=("Scale",))
+             outputs=("Y",), attrs={"epsilon": 1e-6, "n_head": 0},
+             optional=("Gate", "Scale"))
 def head_gated_rms_norm(ins, attrs):
     """X [.., H*D], Gate [.., H] (one logit a head), Scale [D] ->
 
@@ -202,14 +203,24 @@ def head_gated_rms_norm(ins, attrs):
 
     the norm a head (the statistic over the head's D entries), the gate
     a head and AFTER the norm.  Scale unbound: no norm, Y_h =
-    sigmoid(Gate_h) * X_h (latent attention's output gate).  Float32
-    inside, Y in X's dtype."""
-    x, gate = ins["X"], ins["Gate"]
-    n_head = gate.shape[-1]
-    with jax.named_scope("pt_head_gated_norm"):
+    sigmoid(Gate_h) * X_h (latent attention's output gate).  Gate
+    unbound: no gate, Y_h = RMSNorm(X_h) * Scale with H = n_head (the
+    norm on an attention layer's q and k; scope pt_head_rms_norm).
+    Float32 inside, Y in X's dtype."""
+    x, gate = ins["X"], ins.get("Gate")
+    n_head = attrs["n_head"] if gate is None else gate.shape[-1]
+    if n_head < 1 or x.shape[-1] % n_head or (
+            gate is None and ins.get("Scale") is None):
+        raise ValueError(
+            "head_gated_rms_norm: %d channels in %d heads%s"
+            % (x.shape[-1], n_head,
+               "" if gate is not None else ", no gate and no norm"))
+    with jax.named_scope("pt_head_rms_norm" if gate is None
+                         else "pt_head_gated_norm"):
         ind = _head_indicator(x.shape[-1], n_head)
         xf = x.astype(_F32)
-        per_head = jax.nn.sigmoid(gate.astype(_F32))
+        per_head = 1.0 if gate is None \
+            else jax.nn.sigmoid(gate.astype(_F32))
         if ins.get("Scale") is not None:
             per_head = per_head * lax.rsqrt(
                 _head_sum(jnp.square(xf), ind) / (x.shape[-1] // n_head)

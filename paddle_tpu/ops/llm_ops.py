@@ -166,7 +166,7 @@ def rotary_embedding(ins, attrs):
              outputs=("TopkIdx", "TopkWeight", "Scores"),
              attrs={"k": REQUIRED, "routed_scaling_factor": 1.0,
                     "norm_topk_prob": True, "scoring_func": "sigmoid",
-                    "n_group": 1, "topk_group": 1},
+                    "n_group": 1, "topk_group": 1, "norm_topk_eps": 0.0},
              optional=("Bias",))
 def moe_route(ins, attrs):
     """Scores over ALL experts, float32, by `scoring_func`: "sigmoid"
@@ -174,8 +174,10 @@ def moe_route(ins, attrs):
     s = softmax(X W) over the experts.  The k experts with the largest
     s + Bias are selected (the bias selects, it does not weigh; unbound
     it is zero, and softmax routing does not read it), their gates are
-    routed_scaling_factor * s_e / sum of the selected s
-    (norm_topk_prob) or routed_scaling_factor * s_e.  X [.., C], W
+    routed_scaling_factor * s_e / (sum of the selected s +
+    norm_topk_eps) (norm_topk_prob; the epsilon 0 unless the model's
+    code adds one, as lfm2_moe's does) or routed_scaling_factor * s_e.
+    X [.., C], W
     [C, E], Bias [E] -> TopkIdx int32, TopkWeight float32, both
     [.., k], and Scores = s, float32 [.., E]: what a balance loss
     reads, with a gradient to every expert's score.  No capacity:
@@ -232,7 +234,10 @@ def moe_route(ins, attrs):
         _, idx = lax.top_k(ranked, attrs["k"])
         sel = jnp.take_along_axis(s, idx, axis=-1)
         if attrs["norm_topk_prob"]:
-            sel = sel / jnp.sum(sel, axis=-1, keepdims=True)
+            total = jnp.sum(sel, axis=-1, keepdims=True)
+            if attrs["norm_topk_eps"]:
+                total = total + attrs["norm_topk_eps"]
+            sel = sel / total
         return {"TopkIdx": idx.astype(jnp.int32),
                 "TopkWeight": sel * attrs["routed_scaling_factor"],
                 "Scores": s}

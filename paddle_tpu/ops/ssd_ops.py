@@ -13,9 +13,9 @@ the running state and the saved chunk states are float32
 (fp16_utils._WHITE_KEEP_FP32).  The convolution and the norm compute in
 float32 and write in X's dtype.
 
-Every compute runs under a jax.named_scope (pt_ssd, pt_causal_conv1d,
-pt_gated_rms_norm); the kernels are the Mosaic calls pt_ssd_fwd and
-pt_ssd_bwd.
+Every compute runs under a jax.named_scope (pt_ssd, pt_causal_conv1d
+or, a gated convolution, pt_gated_conv, pt_gated_rms_norm); the
+kernels are the Mosaic calls pt_ssd_fwd and pt_ssd_bwd.
 """
 
 from __future__ import annotations
@@ -133,7 +133,7 @@ def ssd_scan_grad(ins, attrs):
     return {s + "@GRAD": v for s, v in zip(_SSD_INPUTS, grads)}
 
 
-_CONV_ATTRS = {"activation": "silu", "impl": ""}
+_CONV_ATTRS = {"activation": "silu", "impl": "", "gated": False}
 
 
 def _conv_impl(ins, attrs):
@@ -141,61 +141,81 @@ def _conv_impl(ins, attrs):
     pallas on a TPU and xla elsewhere; xla too where the kernels cannot
     tile what the op reads (pallas_conv1d.tiles: C a multiple of 128,
     T a multiple of a row tile, K <= 8).  Raises an unknown
-    activation."""
+    activation, and a gated X that is not three times W's channels."""
     act = attrs["activation"]
     if act not in ("silu", ""):
         raise ValueError("causal_conv1d: activation %r is neither 'silu' "
                          "nor ''" % (act,))
     impl = attrs.get("impl") or pk._auto_impl()
-    (_, t, c), k = ins["X"].shape, ins["W"].shape[-1]
+    (_, t, width), (c, k) = ins["X"].shape, ins["W"].shape
+    thirds = 3 if attrs.get("gated") else 1
+    if width != thirds * c:
+        raise ValueError(
+            "causal_conv1d: X has %d channels for a filter of %d; it "
+            "takes %d times the filter's%s"
+            % (width, c, thirds, " ([Gb | Gc | x], gated)"
+               if thirds == 3 else ""))
     if impl != "xla" and pallas_conv1d.tiles(t, c, k) is None:
         impl = "xla"
     return impl
 
 
-def _conv_xla(x, w, bias, act):
+def _conv_xla(x, w, bias, act, gated=False):
     k, t = w.shape[-1], x.shape[1]
-    xp = jnp.pad(x.astype(_F32), ((0, 0), (k - 1, 0), (0, 0)))
+    xf = x.astype(_F32)
+    if gated:
+        gb, gc, xf = jnp.split(xf, 3, axis=-1)
+        xf = gb * xf
+    xp = jnp.pad(xf, ((0, 0), (k - 1, 0), (0, 0)))
     wf = w.astype(_F32)
     y = sum(xp[:, i:i + t, :] * wf[:, i] for i in range(k))
     if bias is not None:
         y = y + bias.astype(_F32)
     if act == "silu":
         y = jax.nn.silu(y)
+    if gated:
+        y = gc * y
     return y.astype(x.dtype)
 
 
-def _conv_grads(x, w, bias, g, act, impl):
+def _conv_grads(x, w, bias, g, act, gated, impl):
     """(dX, dW, dBias or None) by pt_conv1d_bwd (impl a kernel) or by
     jax.vjp of the XLA graph."""
     if impl == "xla":
-        _, vjp = jax.vjp(lambda *a: _conv_xla(*a, act), x, w, bias)
+        _, vjp = jax.vjp(lambda *a: _conv_xla(*a, act, gated), x, w, bias)
         return vjp(g)
     # see pallas_kernels._flash_attention_fwd: one call line
     with pk._obs_device.annotate("causal_conv1d_grad"), pk._kernel_scope():
         return pallas_conv1d.conv1d_bwd_pallas(
-            x, w, bias, g, act=act, interpret=impl == "interpret")
+            x, w, bias, g, act=act, gated=gated,
+            interpret=impl == "interpret")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _conv_kernel(x, w, bias, act, impl):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _conv_kernel(x, w, bias, act, gated, impl):
     """pt_conv1d_fwd, differentiated by pt_conv1d_bwd: what jax.vjp of
     a recompute segment's replay finds where no grad op is bound."""
     with pk._obs_device.annotate("causal_conv1d"), pk._kernel_scope():
         return pallas_conv1d.conv1d_fwd_pallas(
-            x, w, bias, act=act, interpret=impl == "interpret")
+            x, w, bias, act=act, gated=gated,
+            interpret=impl == "interpret")
 
 
-def _conv_kernel_fwd(x, w, bias, act, impl):
-    return _conv_kernel(x, w, bias, act, impl), (x, w, bias)
+def _conv_kernel_fwd(x, w, bias, act, gated, impl):
+    return _conv_kernel(x, w, bias, act, gated, impl), (x, w, bias)
 
 
-def _conv_kernel_bwd(act, impl, res, g):
+def _conv_kernel_bwd(act, gated, impl, res, g):
     # traced under the forward's name stack: the op's scope is on it
-    return _conv_grads(*res, g, act, impl)
+    return _conv_grads(*res, g, act, gated, impl)
 
 
 _conv_kernel.defvjp(_conv_kernel_fwd, _conv_kernel_bwd)
+
+
+def _conv_scope(attrs):
+    return jax.named_scope("pt_gated_conv" if attrs.get("gated")
+                           else "pt_causal_conv1d")
 
 
 @register_op("causal_conv1d", inputs=("X", "W", "Bias"), outputs=("Y",),
@@ -207,16 +227,24 @@ def causal_conv1d(ins, attrs):
         Y[b, t, c] = act(Bias[c] + sum_k W[c, k] X[b, t - (K-1) + k, c])
 
     with X zero before t = 0 (left-padded by K - 1).  activation "silu"
-    or "" (none).  Float32 inside, Y in X's dtype.  impl: "" (the
+    or "" (none).  `gated`: X is one projection [B, T, 3 C] whose
+    thirds are [Gb | Gc | x]; the convolution reads Gb * x and Y is Gc
+    times the above, [B, T, C] (an LFM2 layer's whole mixer between its
+    two projections).  Float32 inside, Y in X's dtype.  impl: "" (the
     kernel pt_conv1d_fwd on a TPU where it can tile X, the XLA graph
     elsewhere), "pallas", "interpret", "xla"."""
     impl = _conv_impl(ins, attrs)
     pk._count_impl("causal_conv1d", impl)
     x, w, bias = ins["X"], ins["W"], ins.get("Bias")
-    with jax.named_scope("pt_causal_conv1d"):
+    act, gated = attrs["activation"], bool(attrs.get("gated"))
+    if gated:
+        # which form applied the gates; an ungated op adds no series
+        pk._count_impl("causal_conv1d_gates",
+                       "xla" if impl == "xla" else "fused")
+    with _conv_scope(attrs):
         if impl == "xla":
-            return {"Y": _conv_xla(x, w, bias, attrs["activation"])}
-        return {"Y": _conv_kernel(x, w, bias, attrs["activation"], impl)}
+            return {"Y": _conv_xla(x, w, bias, act, gated)}
+        return {"Y": _conv_kernel(x, w, bias, act, gated, impl)}
 
 
 @register_op("causal_conv1d_grad", inputs=("X", "W", "Bias", "Y@GRAD"),
@@ -225,15 +253,18 @@ def causal_conv1d(ins, attrs):
 def causal_conv1d_grad(ins, attrs):
     """Hand-written, as ssd_scan_grad is and for its reason: the
     generic jax.vjp grad op would run pt_conv1d_fwd again.  Reads X, W,
-    Bias and Y@GRAD only (pt_conv1d_bwd forms z again from X): no
-    forward output is bound.  The xla impl: jax.vjp of the XLA graph.
+    Bias and Y@GRAD only (pt_conv1d_bwd forms z again from X, and a
+    gated op's Gb * x and act(z) too): no forward output is bound.
+    X@GRAD has X's shape: of a gated op the whole projection's
+    gradient, written once.  The xla impl: jax.vjp of the XLA graph.
     paddle_tpu_kernel_impl_total{kernel="causal_conv1d_grad"} says
     which."""
     impl = _conv_impl(ins, attrs)
     pk._count_impl("causal_conv1d_grad", impl)
-    with jax.named_scope("pt_causal_conv1d"):
-        dx, dw, db = _conv_grads(ins["X"], ins["W"], ins.get("Bias"),
-                                 ins["Y@GRAD"], attrs["activation"], impl)
+    with _conv_scope(attrs):
+        dx, dw, db = _conv_grads(
+            ins["X"], ins["W"], ins.get("Bias"), ins["Y@GRAD"],
+            attrs["activation"], bool(attrs.get("gated")), impl)
     out = {"X@GRAD": dx, "W@GRAD": dw}
     if db is not None:
         out["Bias@GRAD"] = db

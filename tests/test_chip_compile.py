@@ -115,6 +115,9 @@ def _gmm(which, rows=16384, held=8, hidden=3584, width=1024, tm=256):
 DSV2_GMM = dict(rows=49152, hidden=2048, width=1408)
 # ling3: 32,768 pairs are the layout's 34,816 rows, 136 tiles
 LING3_GMM = dict(rows=32768, hidden=2560, width=768)
+# lfm2: 8,192 tokens x 4 experts a token over 16 held experts of width
+# 1,536 = 12 x 128: the layout's 36,864 rows, 144 tiles
+LFM2_GMM = dict(rows=32768, held=16, hidden=2048, width=1536)
 
 
 def _decode(head_dim, head_pack, batch=64, heads=8, page_size=128,
@@ -199,6 +202,22 @@ def _conv1d(grad, t, c, bias):
         ins + (x,), 1
 
 
+def _gated_conv(grad, t=8192, c=2048, taps=3):
+    """lfm2-24b-a2b's whole conv mixer between its projections: the
+    kernels read the thirds of the 1 x 8,192 x 6,144 projection in
+    place (three index maps forward; whole-width row tiles of 512
+    backward, which write the projection's gradient as one array)."""
+    from paddle_tpu.ops.pallas_conv1d import (conv1d_bwd_pallas,
+                                              conv1d_fwd_pallas)
+
+    p, w = _sds((1, t, 3 * c)), _sds((c, taps), jnp.float32)
+    if not grad:
+        return (lambda p, w: conv1d_fwd_pallas(
+            p, w, None, act="", gated=True)), (p, w), 1
+    return (lambda p, w, g: conv1d_bwd_pallas(
+        p, w, None, g, act="", gated=True)), (p, w, _sds((1, t, c))), 1
+
+
 def _moe_combine(n, k, c, rows, f32_rows):
     """A layer's combine by token at a cell's size, 8 experts held: the
     forward's (bf16 rows, gated) or d x's (float32 rows), the plan made
@@ -231,6 +250,8 @@ CASES = {
     "conv1d_bwd_1x8192x4352_bias": lambda: _conv1d(True, 8192, 4352, True),
     "conv1d_fwd_1x4096x4096": lambda: _conv1d(False, 4096, 4096, False),
     "conv1d_bwd_1x4096x4096": lambda: _conv1d(True, 4096, 4096, False),
+    "conv1d_fwd_gated_1x8192x6144_k3": lambda: _gated_conv(False),
+    "conv1d_bwd_gated_1x8192x6144_k3": lambda: _gated_conv(True),
     "kda_fwd_1x4096_h32_d128": lambda: _kda(False),
     "kda_bwd_1x4096_h32_d128": lambda: _kda(True),
     "ssd_fwd_1x8192_h64_p64_n128": lambda: _ssd(False),
@@ -279,6 +300,9 @@ CASES = {
     "gmm_fwd_8x2560x768_rows34816": lambda: _gmm("fwd", **LING3_GMM),
     "gmm_bwd_dx_8x2560x768_rows34816": lambda: _gmm("dx", **LING3_GMM),
     "gmm_bwd_dw_8x2560x768_rows34816": lambda: _gmm("dw", **LING3_GMM),
+    "gmm_fwd_16x2048x1536_rows36864": lambda: _gmm("fwd", **LFM2_GMM),
+    "gmm_bwd_dx_16x2048x1536_rows36864": lambda: _gmm("dx", **LFM2_GMM),
+    "gmm_bwd_dw_16x2048x1536_rows36864": lambda: _gmm("dw", **LFM2_GMM),
     "flash_decode_d128_b64": lambda: _decode(128, False),
     "flash_decode_d64_headpacked_b64": lambda: _decode(64, True),
     "conv2d_epilogue_3x3_56x56x64_mb128": lambda: _conv(False),

@@ -47,10 +47,10 @@ def test_bench_workload_lowers_for_tpu(chip_gate, workload):
 @pytest.mark.parametrize("workload,flash_ops", [
     ("xing4_train_tiny", 5), ("ouro_train_tiny", 24),
     ("dsv2_train_tiny", 5), ("granite_train_tiny", 1),
-    ("ling3_train_tiny", 1)])
+    ("ling3_train_tiny", 1), ("lfm2_train_tiny", 1)])
 def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         chip_gate, workload, flash_ops):
-    """The five cells that train under RecomputeOptimizer, at their
+    """The six cells that train under RecomputeOptimizer, at their
     depth and head sizes, narrow and short: a segment's backward takes
     the forward's Out and LSE (ISSUE 33), so the compiled step holds
     one `pt_flash_fwd` a flash op and not a second in every segment's
@@ -107,6 +107,29 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert workload in chip_gate.MOE_COMBINE_KERNEL
         assert detail["moe_ops"] == 6
         assert detail["kernel_calls"]["pt_moe_combine"] == 12
+    if workload == "lfm2_train_tiny":
+        # the cell's five layers of lfm2-24b-a2b at its head size, taps,
+        # router and expert width (ISSUE 45): four gated convolutions
+        # through the kernels pt_conv1d_* (the forward pass, its
+        # segment's replay and one backward each) with nothing of the
+        # XLA composition left in their scope: no pad, no concatenate,
+        # no split of the projection or array of the step's tokens
+        assert workload in chip_gate.CONV1D_KERNELS
+        assert workload in chip_gate.GATED_CONV_IN_PLACE
+        assert detail["conv1d_ops"] == 4
+        assert detail["kernel_calls"]["pt_conv1d_fwd"] == 8
+        assert detail["kernel_calls"]["pt_conv1d_bwd"] == 4
+        assert detail["conv_scope_pads"] == 0
+        assert detail["gated_conv_copies"] == 0
+        # the attention layer reads 2 rotated KV heads from 4 query
+        # heads; four expert layers of 16 held experts at width 1,536
+        assert [detail["kernel_calls"][k] for k in (
+            "pt_gmm_fwd", "pt_gmm_bwd_dx", "pt_gmm_bwd_dw")] == [24, 12, 12]
+        assert workload in chip_gate.MOE_COMBINE_KERNEL
+        assert detail["moe_ops"] == 4
+        assert detail["kernel_calls"]["pt_moe_combine"] == 8
+        assert detail["kernel_calls"]["pt_row_buffer"] == 40
+        assert detail["tpu_custom_calls"] == 110
     if workload == "dsv2_train_tiny":
         # four expert layers at the published expert width, 1,408 =
         # 11 x 128: the grouped matmuls compile with that axis whole

@@ -276,6 +276,13 @@ def _build_ling3_train(batch=1, seq=4096, **sizes):
                              seq, sizes)
 
 
+def _build_lfm2_train(batch=1, seq=8192, **sizes):
+    """The gated-convolution / attention hybrid's train step as the
+    cell `lfm2_24b_train_s8k` runs it."""
+    return _build_cell_train("lfm2-24b-a2b.json", "lfm2.py", batch, seq,
+                             sizes)
+
+
 def _build_xing4_train(batch=1, seq=4096, **sizes):
     """The 2024-26 decoder block's train step as the cell
     `xing4_29b_train_s4k` runs it."""

@@ -156,6 +156,20 @@ def _workloads():
         "ling3_train_tiny": lambda: progs._build_ling3_train(
             1, 512, hidden_size=256, num_attention_heads=2,
             kv_lora_rank=64, intermediate_size=512, vocab_size=512)[:3],
+        # the gated-convolution / attention hybrid at the cell's sizes
+        # (1 x 8,192 tokens, four conv layers and one grouped-KV
+        # attention layer with rotary K at 8 heads, 16 of 64 experts
+        # held at width 1,536, 788 M parameters): whether 12.6 GB of
+        # state and the step's activations fit (STEP_BYTES_MAX), and
+        # that the gated convolution reads its projection in place
+        # (GATED_CONV_IN_PLACE)
+        "lfm2_train": lambda: progs._build_lfm2_train(1, 8192)[:3],
+        # the published head size, taps, router (64 outputs) and
+        # expert width, the cell's five layers, everything else narrow
+        "lfm2_train_tiny": lambda: progs._build_lfm2_train(
+            1, 512, hidden_size=256, num_attention_heads=4,
+            num_key_value_heads=2, intermediate_size=512,
+            vocab_size=512)[:3],
         # both at the cells' depth and head sizes, narrow and short
         # (256 tokens; seconds to compile): what is checked is how many
         # kernels the step holds, not whether it fits
@@ -345,7 +359,8 @@ def _infer(progs, which, batch, conv_epilogue=False):
 
 
 FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train",
-             "xing4_train", "dsv2_train", "granite_train", "ling3_train")
+             "xing4_train", "dsv2_train", "granite_train", "ling3_train",
+             "lfm2_train")
 
 # the steps whose attention takes q, k and v token-major, [B, T, H*d]
 # as the projections leave them: their compiled step may hold no head
@@ -374,7 +389,8 @@ ONE_FLASH_FWD_AN_OP = ("transformer_train", "transformer_train_gspmd",
                        "xing4_train_tiny", "dsv2_train",
                        "dsv2_train_tiny", "granite_train",
                        "granite_train_tiny", "ling3_train",
-                       "ling3_train_tiny")
+                       "ling3_train_tiny", "lfm2_train",
+                       "lfm2_train_tiny")
 
 # the training steps whose every ssd_scan op has a grad that reads the
 # forward's Y and chunk-start states inside its recompute segment: the
@@ -396,10 +412,18 @@ ONE_KDA_FWD_AN_OP = ("ling3_train", "ling3_train_tiny")
 # ops/pallas_conv1d.py: pt_conv1d_fwd twice an op (the forward pass and
 # its recompute segment's replay: the op keeps no output, so a segment
 # binds nothing) and pt_conv1d_bwd once (9 ops a granite step: 18 + 9;
-# 18 a ling3 step: 36 + 18), and the XLA graph's float32 pad of X is
-# gone from the op's scope
+# 18 a ling3 step: 36 + 18; 4 an lfm2 step, gated: 8 + 4), and the XLA
+# graph's float32 pad of X is gone from the op's scope
 CONV1D_KERNELS = ("granite_train", "granite_train_tiny", "ling3_train",
-                  "ling3_train_tiny")
+                  "ling3_train_tiny", "lfm2_train", "lfm2_train_tiny")
+
+# the training steps whose causal_conv1d ops are gated -> their tokens
+# T: the kernels read the thirds of the [T, 3 C] projection in place
+# and write its gradient as one array, so under the scope pt_gated_conv
+# stands no pad, no concatenate, and no slice, copy or fusion that
+# yields an array of T rows (the thirds split off, the gates' products
+# or the three gradients joined: the XLA composition's)
+GATED_CONV_IN_PLACE = {"lfm2_train": 8192, "lfm2_train_tiny": 512}
 
 
 # the training steps whose every moe_experts op combines by token
@@ -409,7 +433,8 @@ CONV1D_KERNELS = ("granite_train", "granite_train_tiny", "ling3_train",
 # stream mix after it; in dsv2 and ling3 the replay's combine is dead
 # code and the compiler drops it)
 MOE_COMBINE_KERNEL = ("xing4_train", "xing4_train_tiny", "dsv2_train",
-                      "dsv2_train_tiny", "ling3_train", "ling3_train_tiny")
+                      "dsv2_train_tiny", "ling3_train", "ling3_train_tiny",
+                      "lfm2_train", "lfm2_train_tiny")
 
 
 def conv_scope_pads(hlo_text):
@@ -419,6 +444,20 @@ def conv_scope_pads(hlo_text):
     return len(re.findall(
         r'^[^\n]* pad\([^\n]*op_name="[^"]*pt_causal_conv1d[^"]*"',
         hlo_text, re.M))
+
+
+def gated_conv_copies(hlo_text, rows):
+    """Instructions of a compiled module under the op_name scope
+    pt_gated_conv that move a [., rows, .] array outside the kernels:
+    every `pad` and `concatenate`, and a `slice`, `copy` or fusion that
+    yields an array of `rows` rows.  The filter's transpose and the sum
+    of dW over the batch (a few KB) do not count."""
+    under = r'[^\n]*op_name="[^"]*pt_gated_conv[^"]*"'
+    return len(re.findall(
+        r"^[^\n]* (?:pad|concatenate)\(" + under, hlo_text, re.M)) \
+        + len(re.findall(
+            r"^[^=\n]* = [^=\n]*?\[\d+,%d,\d+\][^=\n]*? "
+            r"(?:slice|copy|fusion)\(" % rows + under, hlo_text, re.M))
 
 
 def kernel_calls(hlo_text):
@@ -446,6 +485,10 @@ ROW_WORK_IN_LOOPS = {"dsv2_train": 51200, "dsv2_train_tiny": 3584}
 # compiler packs the temporaries into grew 3,192,799,744 ->
 # 3,250,685,440 (buffer assignment of both modules, PERF.md PR 43)
 STEP_BYTES_MAX = {"dsv2_train": 9_700_000_000,
+                  # the driver's ceiling for the cell (ISSUE 45):
+                  # step_hbm_gb between 4 and 15.5; the one lever above
+                  # 15.0 is the vocabulary at an eighth
+                  "lfm2_train": 15_000_000_000,
                   # PR 41 reads 13,062,109,696: 9.87 GB of weights and
                   # float32 Adam moments, 3.20 GB of gradients and a
                   # segment's activations
@@ -499,7 +542,10 @@ def check_workload(name, build):
     `pt_kda_bwd`; for the CONV1D_KERNELS steps `conv1d_ops` and
     `conv_scope_pads`, which fail it unless `pt_conv1d_fwd` is called
     twice and `pt_conv1d_bwd` once a causal_conv1d op and no `pad`
-    stands under the op's scope; for the MOE_COMBINE_KERNEL steps
+    stands under the op's scope; for the GATED_CONV_IN_PLACE steps
+    `gated_conv_copies`, which fails it unless no array of the step's
+    tokens is moved under the scope pt_gated_conv outside the kernels;
+    for the MOE_COMBINE_KERNEL steps
     `moe_ops`, which fail it unless `pt_moe_combine` is called two or
     three times a moe_experts op; for the ROW_WORK_IN_LOOPS steps
     `rows_outside_loops`, which fails the workload unless it is 0, and
@@ -574,6 +620,10 @@ def check_workload(name, build):
                 == 2 * detail["kernel_calls"].get("pt_conv1d_bwd", 0) \
                 == 2 * detail["conv1d_ops"] > 0
             ok &= not detail["conv_scope_pads"]
+        if name in GATED_CONV_IN_PLACE:
+            detail["gated_conv_copies"] = gated_conv_copies(
+                text, GATED_CONV_IN_PLACE[name])
+            ok &= not detail["gated_conv_copies"]
         if name in MOE_COMBINE_KERNEL:
             from paddle_tpu import framework
 
