@@ -25,10 +25,13 @@ def kda_scan(q, k, v, g, beta, chunk_size=64, block_chunks=4, impl=None,
     channel (float32, in [-5, 0]: `kda_gate`), beta [B, T, H] in (0, 1);
     returns o [B, T, H*D].  T must be a multiple of block_chunks x
     chunk_size.  The op also writes States, the transposed state each
-    block of chunks starts from (float32 [B, T/block, H*D, D], no
-    gradient): the residual kda_scan_grad reads, with o, instead of
-    running the forward kernel again.  impl: None (pallas on a TPU, xla
-    elsewhere), "pallas", "interpret", "xla"."""
+    block of chunks starts from (float32 [B, T/block, H*D, D]), and
+    Inverse, the inverse of each chunk's triangular system (float32
+    [B, H, T/block, chunk_size, block]); neither has a gradient: the
+    residuals kda_scan_grad reads, with o, instead of running the
+    forward kernel and inverting every chunk's system again.  impl:
+    None (pallas on a TPU, xla elsewhere), "pallas", "interpret",
+    "xla"."""
     t = q.shape[1] if q.shape is not None else None
     block = int(chunk_size) * int(block_chunks)
     if t is not None and t > 0 and t % block:
@@ -39,10 +42,11 @@ def kda_scan(q, k, v, g, beta, chunk_size=64, block_chunks=4, impl=None,
     helper = LayerHelper("kda_scan", name=name)
     o = helper.create_variable_for_type_inference(v.dtype)
     states = helper.create_variable_for_type_inference("float32", True)
+    inverse = helper.create_variable_for_type_inference("float32", True)
     helper.append_op(
         type="kda_scan",
         inputs={"Q": q, "K": k, "V": v, "G": g, "Beta": beta},
-        outputs={"O": o, "States": states},
+        outputs={"O": o, "States": states, "Inverse": inverse},
         attrs={"chunk_size": int(chunk_size),
                "block_chunks": int(block_chunks), "impl": impl or ""})
     return o

@@ -20,6 +20,8 @@ pt_kda_fwd and pt_kda_bwd.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -47,7 +49,10 @@ def _kda_impl(ins, attrs):
     return impl
 
 
-@register_op("kda_scan", inputs=_KDA_INPUTS, outputs=("O", "States"),
+_KDA_SAVED = ("O", "States", "Inverse")
+
+
+@register_op("kda_scan", inputs=_KDA_INPUTS, outputs=_KDA_SAVED,
              attrs=_KDA_ATTRS)
 def kda_scan(ins, attrs):
     """The delta-rule recurrence with a decay per key channel, by
@@ -59,74 +64,107 @@ def kda_scan(ins, attrs):
 
     Q, K, V [B, T, H*D] token-major, G [B, T, H*D] the log-decay a key
     channel (float32, in [-5, 0]: a chunk kernel forms e^(-G) over 16
-    tokens), Beta [B, T, H] -> O [B, T, H*D] in V's dtype and States,
-    float32 [B, T / (block_chunks chunk_size), H*D, D]: the TRANSPOSED
-    state each block of chunks starts from, the residual kda_scan_grad
-    reads.  T % (block_chunks chunk_size) != 0 raises; nothing is
-    padded.  chunk_size is a multiple of 16 up to 64.  impl: "" (pallas
-    on a TPU, xla elsewhere), "pallas", "interpret", "xla" (the same
-    chunked algorithm in jax.numpy)."""
+    tokens), Beta [B, T, H] -> O [B, T, H*D] in V's dtype and the two
+    residuals kda_scan_grad reads, float32 whatever the operands are,
+    with block = block_chunks chunk_size: States [B, T / block, H*D,
+    D], the TRANSPOSED state each block of chunks starts from, and
+    Inverse [B, H, T / block, chunk_size, block], the inverse T =
+    (I + diag(Beta) M)^-1 of each chunk's triangular system, a block's
+    side by side.  T % block != 0 raises; nothing is padded.
+    chunk_size is a multiple of 16 up to 64.  impl: "" (pallas on a
+    TPU, xla elsewhere), "pallas", "interpret", "xla" (the same chunked
+    algorithm in jax.numpy)."""
     impl = _kda_impl(ins, attrs)
     pk._count_impl("kda_scan", impl)
     args = tuple(ins[s] for s in _KDA_INPUTS)
     sizes = (attrs["chunk_size"], attrs["block_chunks"])
     with jax.named_scope("pt_kda"):
         if impl == "xla":
-            o, states = pallas_kda.kda_chunked_xla(*args, *sizes)
+            outs = pallas_kda.kda_chunked_xla(*args, *sizes)
         else:
-            # see pallas_kernels._flash_attention_fwd: one call line
-            with pk._obs_device.annotate("kda_scan"), pk._kernel_scope():
-                o, states = pallas_kda.kda_fwd_pallas(
-                    *args, *sizes, interpret=impl == "interpret")
-    return {"O": o, "States": states}
+            outs = _scan_kernel(*args, sizes, impl)
+    return dict(zip(_KDA_SAVED, outs))
+
+
+def _grads_on_kept(args, states, inverse, g, sizes, impl):
+    # see pallas_kernels._flash_attention_fwd: one call line
+    with pk._obs_device.annotate("kda_scan_grad"), pk._kernel_scope():
+        return pallas_kda.kda_bwd_pallas(
+            *args, states, inverse, g, *sizes,
+            interpret=impl == "interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _scan_kernel(q, k, v, g, beta, sizes, impl):
+    """pt_kda_fwd, differentiated by pt_kda_bwd on the states and the
+    inverses it wrote: what jax.vjp finds where a grad op's residuals
+    are not all bound (a hand-built op, a desc from before Inverse),
+    the op alone or in a recompute segment's replay."""
+    with pk._obs_device.annotate("kda_scan"), pk._kernel_scope():
+        return pallas_kda.kda_fwd_pallas(
+            q, k, v, g, beta, *sizes, interpret=impl == "interpret")
+
+
+def _scan_kernel_fwd(q, k, v, g, beta, sizes, impl):
+    outs = _scan_kernel(q, k, v, g, beta, sizes, impl)
+    return outs, ((q, k, v, g, beta), outs[1], outs[2])
+
+
+def _scan_kernel_bwd(sizes, impl, kept, cts):
+    # traced under the forward's name stack: the op's scope is on it
+    return _grads_on_kept(*kept, cts[0], sizes, impl)
+
+
+_scan_kernel.defvjp(_scan_kernel_fwd, _scan_kernel_bwd)
 
 
 def _kda_grad_reads_saved(ins, attrs):
     """Whether kda_scan_grad runs the backward kernel on the forward's
-    States: bound (with O, which a recompute segment takes in place of
-    the op's replay) and the impl a kernel (OpDef.reads_saved)."""
-    return "O" in ins and "States" in ins \
+    States and Inverse: both bound (with O, which a recompute segment
+    takes in place of the op's replay) and the impl a kernel
+    (OpDef.reads_saved).  A desc from before Inverse binds two of the
+    three: the forward runs again."""
+    return all(s in ins for s in _KDA_SAVED) \
         and _kda_impl(ins, attrs) != "xla"
 
 
 @register_op("kda_scan_grad",
-             inputs=_KDA_INPUTS + ("O", "States", "O@GRAD"),
+             inputs=_KDA_INPUTS + _KDA_SAVED + ("O@GRAD",),
              outputs=tuple(s + "@GRAD" for s in _KDA_INPUTS),
-             optional=("O", "States"), attrs=_KDA_ATTRS,
+             optional=_KDA_SAVED, attrs=_KDA_ATTRS,
              differentiable=False, reads_saved=_kda_grad_reads_saved)
 def kda_scan_grad(ins, attrs):
     """Hand-written, as ssd_scan_grad is and for its reason: the
     generic jax.vjp grad op would run pt_kda_fwd a second time in every
     layer, and a recompute segment's replay a third.
 
-      * O and States bound (append_backward binds them; a recompute
-        segment binds them on the op it replays) and the impl a
-        kernel: pt_kda_bwd on the saved block states.  The forward
-        kernel does not run again;
-      * unbound (a hand-built op): the forward kernel again for the
-        states, then pt_kda_bwd;
-      * the xla impl: jax.vjp over the forward op's compute.
+      * O, States and Inverse bound (append_backward binds them; a
+        recompute segment binds them on the op it replays) and the
+        impl a kernel: pt_kda_bwd on the saved block states and
+        chunk inverses.  The forward kernel does not run again, and
+        no chunk's triangular system is inverted a second time;
+      * anything else (a hand-built op, a desc from before Inverse,
+        the xla impl): jax.vjp over the forward op's compute, which on
+        a kernel impl is the forward kernel again for both residuals
+        and pt_kda_bwd on them (`_scan_kernel`).
 
     paddle_tpu_kernel_impl_total{kernel="kda_scan_grad"} says which:
     impl="saved" | "recompute"."""
     args = tuple(ins[s] for s in _KDA_INPUTS)
     g = ins["O@GRAD"]
-    impl = _kda_impl(ins, attrs)
-    saved = impl != "xla" and "O" in ins and "States" in ins
+    saved = _kda_grad_reads_saved(ins, attrs)
     pk._count_impl("kda_scan_grad", "saved" if saved else "recompute")
-    if impl == "xla":
+    if saved:
+        with jax.named_scope("pt_kda"):
+            grads = _grads_on_kept(
+                args, ins["States"], ins["Inverse"], g,
+                (attrs["chunk_size"], attrs["block_chunks"]),
+                _kda_impl(ins, attrs))
+    else:
         _, vjp = jax.vjp(
             lambda *a: kda_scan(dict(zip(_KDA_INPUTS, a)), attrs)["O"],
             *args)
         grads = vjp(g)
-    else:
-        states = ins["States"] if saved else kda_scan(ins, attrs)["States"]
-        with jax.named_scope("pt_kda"), \
-                pk._obs_device.annotate("kda_scan_grad"), \
-                pk._kernel_scope():
-            grads = pallas_kda.kda_bwd_pallas(
-                *args, states, g, attrs["chunk_size"],
-                attrs["block_chunks"], interpret=impl == "interpret")
     return {s + "@GRAD": v for s, v in zip(_KDA_INPUTS, grads)}
 
 

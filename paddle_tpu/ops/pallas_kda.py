@@ -40,18 +40,33 @@ Operands are token-major: q, k, v, g [B, T, H*D] and beta [B, T, H].
 D is 128: a head is one 128-lane block.  A grid step takes one head
 and one BLOCK of `block_chunks` chunks (256 tokens at 4 x 64) and walks
 its chunks in a loop; grid (B, H, T / block), the last axis sequential:
-it carries the running state in a float32 VMEM scratch [D, D].  The
-forward writes the state each BLOCK starts from (`states`, float32
-[B, T / block, H*D, D]; one state a chunk would be 134 MB a layer at
-4,096 tokens): the residual the backward reads.  The backward walks
-the blocks from the last to the first; inside a block it first runs
-the chunks forward again from the saved state, keeping each chunk's
-start state, T, M, P, W and Ut in VMEM, then walks them in reverse
+it carries the running state in a float32 VMEM scratch [D, D].
+
+The residuals of a forward pass, both float32 whatever the operands
+are, are what the backward cannot make cheaply:
+
+  * `states` [B, T / block, H*D, D]: the state each BLOCK starts from
+    (33.5 MB a layer at 4,096 tokens and 32 heads; one state a chunk
+    would be 134 MB a layer);
+  * `inverse` [B, H, T / block, C, block]: each chunk's T, a block's
+    four side by side so that the 64-wide matrices are not padded to
+    128 lanes in HBM (64 x 256 a grid step; 33.5 MB a layer at the
+    same shape).  T depends on K, G and beta of its own chunk alone,
+    not on the state, and its ten dependent products are the longest
+    chain of either kernel: it is formed once a step, here.
+
+The backward walks the blocks from the last to the first; inside a
+block it first runs the chunks forward again from the saved state with
+the saved inverses (the scores M and P, W, U, Ut and the state chain:
+a few products each, and keeping them would be the 134 MB a layer of a
+state a chunk and as much again for W and Ut), keeping each chunk's
+start state, M, P, W and Ut in VMEM, then walks them in reverse
 carrying dZ.  The loops over a block's chunks are unrolled: a chunk's
-scores and inverse do not wait for the state, so the scheduler runs
-them beside the chunk before's state products (on the chip 4.89 ->
-4.49 ms a forward call and 7.74 -> 6.65 a backward call at 1 x 4,096 x
-32 heads, the same bits; PERF.md section 6, PR 41).
+scores (and in the forward its inverse) do not wait for the state, so
+the scheduler runs them beside the chunk before's state products (on
+the chip 4.89 -> 4.49 ms a forward call and 7.74 -> 6.65 a backward
+call at 1 x 4,096 x 32 heads, the same bits; PERF.md section 6, PR 41;
+the backward without its own inverses: PR 46).
 
 What XLA does round the kernels (`_prep`, `_finish`): the chunk-local
 running sums G (float32, [B, T, H*D]), beta as [B, H, T, 1] columns,
@@ -114,9 +129,10 @@ def kernel_geom_ok(d):
 
 def kda_chunked_xla(q, k, v, g, beta, chunk, block_chunks):
     """(o [B, T, H*D] in v's dtype, states float32 [B, T/block, H*D, D]:
-    the transposed state each block starts from).  Float32 throughout;
-    decays as differences G_r - G_s, the inverse as a triangular
-    solve."""
+    the transposed state each block starts from, inverse float32
+    [B, H, T/block, C, block]: a block's T side by side).  Float32
+    throughout; decays as differences G_r - G_s, the inverse as a
+    triangular solve, against the identity too."""
     from jax.scipy.linalg import solve_triangular
 
     b, t, h, d = check_shapes(q, v, beta, chunk, block_chunks)
@@ -145,11 +161,13 @@ def kda_chunked_xla(q, k, v, g, beta, chunk, block_chunks):
         p = jnp.einsum("bhrc,bhsc,bhrsc->bhrs", qx, kx, decay,
                        precision=_HIGHEST)
         eg = jnp.exp(gx)
-        rhs = bx[..., None] * jnp.concatenate([kx * eg, vx], axis=-1)
-        wu = solve_triangular(
-            jnp.eye(chunk, dtype=_F32) + bx[..., None] * m, rhs,
-            lower=True, unit_diagonal=True)
-        w, u = wu[..., :d], wu[..., d:]
+        eye = jnp.eye(chunk, dtype=_F32)
+        rhs = jnp.concatenate(
+            [bx[..., None] * jnp.concatenate([kx * eg, vx], axis=-1),
+             jnp.broadcast_to(eye, m.shape)], axis=-1)
+        wu = solve_triangular(eye + bx[..., None] * m, rhs,
+                              lower=True, unit_diagonal=True)
+        w, u, t_inv = wu[..., :d], wu[..., d:2 * d], wu[..., 2 * d:]
         ut = u - jnp.einsum("bhrk,bhvk->bhrv", w, z, precision=_HIGHEST)
         o = jnp.einsum("bhrk,bhvk->bhrv", qx * eg, z, precision=_HIGHEST) \
             + jnp.einsum("bhrs,bhsv->bhrv", p, ut, precision=_HIGHEST)
@@ -157,14 +175,18 @@ def kda_chunked_xla(q, k, v, g, beta, chunk, block_chunks):
         z_next = z * jnp.exp(g_last) + jnp.einsum(
             "bhrv,bhrk->bhvk", ut, kx * jnp.exp(g_last - gx),
             precision=_HIGHEST)
-        return z_next, (o, z)
+        return z_next, (o, z, t_inv)
 
-    _, (o, starts) = lax.scan(one_chunk, jnp.zeros((b, h, d, d), _F32),
-                              (qc, kc, vc, gc, bc))
+    _, (o, starts, t_inv) = lax.scan(
+        one_chunk, jnp.zeros((b, h, d, d), _F32), (qc, kc, vc, gc, bc))
     o = o.transpose(1, 0, 3, 2, 4).reshape(b, t, h * d)
+    nb = nc // block_chunks
     states = starts[::block_chunks].transpose(1, 0, 2, 3, 4).reshape(
-        b, nc // block_chunks, h * d, d)
-    return o.astype(v.dtype), states
+        b, nb, h * d, d)
+    # [nb, chunks, B, H, r, s] -> [B, H, nb, r, chunks x s]
+    inverse = t_inv.reshape(nb, block_chunks, b, h, chunk, chunk).transpose(
+        2, 3, 0, 4, 1, 5).reshape(b, h, nb, chunk, block_chunks * chunk)
+    return o.astype(v.dtype), states, inverse
 
 
 # ---------------------------------------------------------------------------
@@ -247,11 +269,13 @@ def _inverse(n, mk):
     return r + mm(mm(y, y), r)
 
 
-def _chunk_forward(q, k, v, gsum, beta, z, mk, dtype):
+def _chunk_forward(q, k, v, gsum, beta, z, mk, dtype, t_inv=None):
     """One chunk from the transposed state z: (o, z_next, and what the
-    backward keeps: t_inv, m, p, w, ut)."""
+    backward keeps: t_inv, m, p, w, ut).  `t_inv`: the chunk's inverse
+    where the forward pass kept it, else it is formed here."""
     m, p = _scores(q, k, gsum, mk, dtype)
-    t_inv = _inverse(beta * m, mk)
+    if t_inv is None:
+        t_inv = _inverse(beta * m, mk)
     eg = jnp.exp(gsum)
     w = _dot(t_inv, beta * (k * eg), _NN, dtype)
     u = _dot(t_inv, beta * v, _NN, dtype)
@@ -267,6 +291,14 @@ def _chunk_forward(q, k, v, gsum, beta, z, mk, dtype):
 # forward
 # ---------------------------------------------------------------------------
 
+def _side_by_side(c, chunk):
+    """The lanes of chunk c's inverse in its block's [C, block] array.
+    Static: Mosaic takes no lane offset it cannot prove a multiple of
+    128, so the inverses pass through a [chunks, C, C] scratch that the
+    chunk loops index by their first axis."""
+    return slice(c * chunk, (c + 1) * chunk)
+
+
 def _load(refs, rows):
     """q, k, v as float32, G, and beta's column of the chunk `rows`."""
     q_ref, k_ref, v_ref, g_ref, b_ref = refs
@@ -275,8 +307,8 @@ def _load(refs, rows):
             b_ref[0, 0, rows, :])
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, z_ref,
-                *, chunk, block_chunks):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, ti_ref,
+                z_ref, t_all, *, chunk, block_chunks):
     dtype = q_ref.dtype
     mk = _masks(chunk)
 
@@ -290,16 +322,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, z_ref,
         rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
         q, k, v, gsum, beta = _load((q_ref, k_ref, v_ref, g_ref, b_ref),
                                     rows)
-        o, z_next, _ = _chunk_forward(q, k, v, gsum, beta, z_ref[...], mk,
-                                      dtype)
+        o, z_next, (t_inv, *_) = _chunk_forward(
+            q, k, v, gsum, beta, z_ref[...], mk, dtype)
         o_ref[0, rows, :] = o.astype(o_ref.dtype)
+        t_all[c] = t_inv
         z_ref[...] = z_next
         return carry
 
     lax.fori_loop(0, block_chunks, one_chunk, 0, unroll=True)
+    for c in range(block_chunks):
+        ti_ref[0, 0, 0, :, _side_by_side(c, chunk)] = t_all[c]
 
 
-def _specs(t, d, block, rev):
+def _specs(t, d, chunk, block, rev):
     """BlockSpecs by operand kind over the grid (B, H, T / block);
     `rev` walks the blocks from the last to the first."""
     nb = t // block
@@ -313,6 +348,8 @@ def _specs(t, d, block, rev):
                              lambda i, h, j: (i, h, blk(j), 0)),
         "state": pl.BlockSpec((1, 1, d, d),
                               lambda i, h, j: (i, blk(j), h, 0)),
+        "inverse": pl.BlockSpec((1, 1, 1, chunk, block),
+                                lambda i, h, j: (i, h, blk(j), 0, 0)),
     }
 
 
@@ -329,21 +366,24 @@ def _params(interpret):
 def kda_fwd_pallas(q, k, v, g, beta, chunk, block_chunks,
                    interpret=False):
     """-> (o [B, T, H*D] in v's dtype, states float32
-    [B, T/block, H*D, D])."""
+    [B, T/block, H*D, D], inverse float32 [B, H, T/block, C, block])."""
     b, t, h, d = check_shapes(q, v, beta, chunk, block_chunks)
     block = chunk * block_chunks
     gsum, bcol = _prep(g, beta, chunk)
-    sp = _specs(t, d, block, rev=False)
+    sp = _specs(t, d, chunk, block, rev=False)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk,
                           block_chunks=block_chunks),
         name="pt_kda_fwd",
         grid=(b, h, t // block),
         in_specs=[sp["x"], sp["x"], sp["x"], sp["x"], sp["beta"]],
-        out_specs=[sp["x"], sp["state"]],
+        out_specs=[sp["x"], sp["state"], sp["inverse"]],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct((b, t // block, h * d, d), _F32)],
-        scratch_shapes=[pltpu.VMEM((d, d), _F32)],
+                   jax.ShapeDtypeStruct((b, t // block, h * d, d), _F32),
+                   jax.ShapeDtypeStruct((b, h, t // block, chunk, block),
+                                        _F32)],
+        scratch_shapes=[pltpu.VMEM((d, d), _F32),
+                        pltpu.VMEM((block_chunks, chunk, chunk), _F32)],
         interpret=interpret,
         **_params(interpret),
     )(q, k.astype(q.dtype), v.astype(q.dtype), gsum, bcol)
@@ -374,7 +414,7 @@ def kda_fwd_pallas(q, k, v, g, beta, chunk, block_chunks,
 #   dG += dQg Qg + dKg Kg - dKend Kend,
 #   dG[last row] += cols(dKend Kend) + cols(dZ' Z) e^(G_C)
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref,
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref, ti_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
                 dz_ref, z_all, t_all, m_all, p_all, w_all, ut_all,
                 *, chunk, block_chunks):
@@ -390,12 +430,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref,
     def rows_of(c):
         return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
 
-    # the block's chunks forward again, from the state the forward kept
+    for c in range(block_chunks):
+        t_all[c] = ti_ref[0, 0, 0, :, _side_by_side(c, chunk)]
+
+    # the block's chunks forward again, from the state and with the
+    # inverses the forward kept
     def again(c, z):
         q, k, v, gsum, beta = _load(ins, rows_of(c))
-        _, z_next, (t_inv, m, p, w, ut) = _chunk_forward(
-            q, k, v, gsum, beta, z, mk, dtype)
-        z_all[c], t_all[c], m_all[c], p_all[c] = z, t_inv, m, p
+        _, z_next, (_, m, p, w, ut) = _chunk_forward(
+            q, k, v, gsum, beta, z, mk, dtype, t_inv=t_all[c])
+        z_all[c], m_all[c], p_all[c] = z, m, p
         w_all[c], ut_all[c] = w, ut
         return z_next
 
@@ -468,14 +512,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "block_chunks", "interpret"))
-def kda_bwd_pallas(q, k, v, g, beta, states, do, chunk, block_chunks,
-                   interpret=False):
+def kda_bwd_pallas(q, k, v, g, beta, states, inverse, do, chunk,
+                   block_chunks, interpret=False):
     """The five input gradients (q, k, v, g, beta), each in its input's
-    dtype, from the block-start states the forward kept."""
+    dtype, from the block-start states and the chunks' inverses the
+    forward kept."""
     b, t, h, d = check_shapes(q, v, beta, chunk, block_chunks)
     block = chunk * block_chunks
     gsum, bcol = _prep(g, beta, chunk)
-    sp = _specs(t, d, block, rev=True)
+    sp = _specs(t, d, chunk, block, rev=True)
 
     def like(x, dtype=_F32):
         return jax.ShapeDtypeStruct(x.shape, dtype)
@@ -487,7 +532,7 @@ def kda_bwd_pallas(q, k, v, g, beta, states, do, chunk, block_chunks,
         name="pt_kda_bwd",
         grid=(b, h, t // block),
         in_specs=[sp["x"], sp["x"], sp["x"], sp["x"], sp["beta"], sp["x"],
-                  sp["state"]],
+                  sp["state"], sp["inverse"]],
         out_specs=[sp["x"], sp["x"], sp["x"], sp["x"], sp["beta"]],
         out_shape=[like(q, q.dtype), like(q, q.dtype), like(q, q.dtype),
                    like(gsum), like(bcol)],
@@ -499,7 +544,7 @@ def kda_bwd_pallas(q, k, v, g, beta, states, do, chunk, block_chunks,
         interpret=interpret,
         **_params(interpret),
     )(q, k.astype(q.dtype), v.astype(q.dtype), gsum, bcol,
-      do.astype(q.dtype), states)
+      do.astype(q.dtype), states, inverse)
     return (dq, dk.astype(k.dtype), dv.astype(v.dtype),
             _finish(dgsum, chunk).astype(g.dtype),
             dbcol[..., 0].transpose(0, 2, 1).astype(beta.dtype))

@@ -182,8 +182,10 @@ def _kda(grad, b=1, t=4096, h=32, d=128, chunk=64, block_chunks=4):
     sizes = dict(chunk=chunk, block_chunks=block_chunks)
     if not grad:
         return (lambda *a: kda_fwd_pallas(*a, **sizes)), ins, 1
+    block = chunk * block_chunks
     return (lambda *a: kda_bwd_pallas(*a, **sizes)), ins + (
-        _sds((b, t // (chunk * block_chunks), h * d, d), f32), x), 1
+        _sds((b, t // block, h * d, d), f32),
+        _sds((b, h, t // block, chunk, block), f32), x), 1
 
 
 def _conv1d(grad, t, c, bias):

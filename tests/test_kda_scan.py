@@ -2,8 +2,10 @@
 `interpret` impls) against the recurrence run TOKEN BY TOKEN, forward
 and all five input gradients; the state crossing chunk, block and
 sub-block edges; a run at the decay's bound; the registered grad op on
-the forward's saved block states, alone and inside a recompute
-segment; the gate, the per-head L2 norm and the head-wise gated norm.
+the forward's saved block states and chunk inverses, alone and inside a
+recompute segment; the saved inverse against a triangular solve, and
+the backward that reads it against the one that formed it again, bit
+for bit; the gate, the per-head L2 norm and the head-wise gated norm.
 
 The decay is drawn wide on purpose (-g log-uniform in [1e-3, 2] a
 channel: a channel keeps from 97% down to 1e-14 of itself over a
@@ -94,8 +96,7 @@ def scan_and_grads(args, go, attrs):
     ins = dict(zip(SLOTS, args))
     outs = get_op_def("kda_scan").compute(ins, attrs)
     grads = get_op_def("kda_scan_grad").compute(
-        dict(ins, O=outs["O"], States=outs["States"], **{"O@GRAD": go}),
-        attrs)
+        dict(ins, **outs, **{"O@GRAD": go}), attrs)
     return outs, grads
 
 
@@ -189,6 +190,121 @@ def test_states_are_the_transposed_state_each_block_starts_from():
         s = jnp.exp(g[t].reshape(2, 128))[..., None] * s
         s = s + (beta[t][:, None] * kt)[..., None] * (
             vt - jnp.einsum("hk,hkv->hv", kt, s))[:, None, :]
+
+
+def chunk_inverses(inverse, chunk):
+    """Inverse [B, H, T/block, C, block], a block's side by side ->
+    [B, H, T/C, C, C], a chunk's alone."""
+    b, h, nb, _, block = inverse.shape
+    return inverse.reshape(b, h, nb, chunk, block // chunk, chunk).transpose(
+        0, 1, 2, 4, 3, 5).reshape(b, h, -1, chunk, chunk)
+
+
+def solved_inverses(k, g, beta, chunk):
+    """(I + diag(beta) M)^-1 of every chunk by a triangular solve
+    against the identity, M[r, s] = sum_c k_r[c] k_s[c] e^(G_r[c] -
+    G_s[c]) for s < r, G the chunk's running sum of g: [B, H, T/C, C,
+    C] float32."""
+    from jax.scipy.linalg import solve_triangular
+
+    b, t, width = k.shape
+    h = beta.shape[-1]
+
+    def chunks(x):                      # -> [B, H, T/C, C, D]
+        return x.reshape(b, t // chunk, chunk, h, -1).transpose(
+            0, 3, 1, 2, 4)
+
+    kc, gc = chunks(k), jnp.cumsum(chunks(g), axis=3)
+    bc = chunks(beta)[..., 0]
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    decay = jnp.exp(jnp.where(
+        strict[..., None], gc[..., :, None, :] - gc[..., None, :, :], 0.0))
+    m = jnp.where(strict, jnp.einsum(
+        "...rc,...sc,...rsc->...rs", kc, kc, decay,
+        precision=lax.Precision.HIGHEST), 0.0)
+    eye = jnp.eye(chunk, dtype=jnp.float32)
+    return solve_triangular(eye + bc[..., None] * m,
+                            jnp.broadcast_to(eye, m.shape), lower=True,
+                            unit_diagonal=True)
+
+
+@pytest.mark.parametrize("t,chunk,block_chunks", SHAPES)
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_inverse_is_each_chunks_triangular_solve(impl, t, chunk,
+                                                 block_chunks):
+    args, _ = operands(t, b=1 if t > 128 else 2)
+    outs = get_op_def("kda_scan").compute(
+        dict(zip(SLOTS, args)),
+        {"chunk_size": chunk, "block_chunks": block_chunks, "impl": impl})
+    inverse = outs["Inverse"]
+    block = chunk * block_chunks
+    assert inverse.shape == (args[0].shape[0], 2, t // block, chunk, block)
+    assert inverse.dtype == jnp.float32
+    got = chunk_inverses(inverse, chunk)
+    want = solved_inverses(args[1], args[3], args[4], chunk)
+    assert rel(got, want) <= TOL
+    # unit lower triangular, and no chunk's is the identity alone
+    upper = jnp.triu(jnp.ones((chunk, chunk), bool), 1)
+    assert float(jnp.abs(jnp.where(upper, got, 0.0)).max()) == 0.0
+    below = jnp.abs(jnp.where(upper.T, got, 0.0)).max(axis=(-1, -2))
+    assert float(below.min()) > 100 * TOL
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_inverse_at_the_decays_bound(impl):
+    args, _ = operands(128, b=1, g_fixed=-5.0)
+    outs = get_op_def("kda_scan").compute(
+        dict(zip(SLOTS, args)),
+        {"chunk_size": 64, "block_chunks": 2, "impl": impl})
+    got = chunk_inverses(outs["Inverse"], 64)
+    assert bool(jnp.isfinite(got).all())
+    assert rel(got, solved_inverses(args[1], args[3], args[4], 64)) <= TOL
+
+
+def _bwd_on(args, outs, go, chunk, block_chunks, jitted=True):
+    from paddle_tpu.ops import pallas_kda
+
+    fn = pallas_kda.kda_bwd_pallas
+    return (fn if jitted else fn.__wrapped__)(
+        *args, outs["States"], outs["Inverse"], go, chunk=chunk,
+        block_chunks=block_chunks, interpret=True)
+
+
+@pytest.mark.parametrize("t,chunk,block_chunks,g_fixed",
+                         [(128, 32, 2, None), (256, 64, 4, None),
+                          (128, 64, 2, -5.0)])
+def test_backward_on_the_saved_inverse_is_the_one_that_formed_it_again(
+        monkeypatch, t, chunk, block_chunks, g_fixed):
+    """pt_kda_bwd reads the forward's inverse; before, its `again` loop
+    called `_inverse` on the block's chunks a second time.  The same
+    ten products of the same operands: the five gradients agree bit
+    for bit (interpret mode), at the decay's bound too."""
+    from paddle_tpu.ops import pallas_kda
+
+    args, go = operands(t, b=1, g_fixed=g_fixed)
+    outs = get_op_def("kda_scan").compute(
+        dict(zip(SLOTS, args)),
+        {"chunk_size": chunk, "block_chunks": block_chunks,
+         "impl": "interpret"})
+    reads = _bwd_on(args, outs, go, chunk, block_chunks)
+
+    real, formed = pallas_kda._chunk_forward, []
+
+    def forms_it_again(*a, t_inv=None):
+        out = real(*a)
+        formed.append(t_inv is not None)
+        return out
+
+    monkeypatch.setattr(pallas_kda, "_chunk_forward", forms_it_again)
+    again = _bwd_on(args, outs, go, chunk, block_chunks, jitted=False)
+    assert formed == [True]             # the backward's loop, traced once
+    for slot, a, b in zip(SLOTS, reads, again):
+        assert a.dtype == b.dtype and bool(jnp.array_equal(a, b)), slot
+    # and a backward that reads ANOTHER inverse is another backward
+    monkeypatch.undo()
+    other = dict(outs, Inverse=outs["Inverse"] * 1.001)
+    moved = _bwd_on(args, other, go, chunk, block_chunks)
+    assert all(rel(m, r) > TOL for m, r in zip(moved, reads))
 
 
 def test_a_length_that_is_no_multiple_of_the_block_raises():
@@ -286,10 +402,12 @@ def _net(recompute, impl):
     return loss, opt.backward(loss)
 
 
-def _run_net(recompute, impl):
+def _run_net(recompute, impl, edit=None):
     _fresh()
     np.random.seed(0)
     loss, pg = _net(recompute, impl)
+    if edit:
+        edit(fluid.default_main_program())
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
     before = _counts()
@@ -302,7 +420,7 @@ def _run_net(recompute, impl):
             fluid.default_main_program())
 
 
-def test_grad_op_in_a_recompute_segment_reads_the_saved_states():
+def test_grad_op_in_a_recompute_segment_reads_the_saved_residuals():
     want, want_loss, _, _ = _run_net(False, "xla")
     assert {"decay_A_log.w", "decay_dt_bias.w", "norm.w", "q_conv.w"} \
         <= set(want)
@@ -322,11 +440,15 @@ def test_grad_op_in_a_recompute_segment_reads_the_saved_states():
             seg = [op for op in prog.global_block().ops
                    if op.type == "recompute_segment_grad"
                    and op.inputs.get("Saved")]
-            assert len(seg) == 1 and len(seg[0].inputs["Saved"]) == 2
+            scan, = [op for op in prog.global_block().ops
+                     if op.type == "kda_scan"]
+            assert len(seg) == 1 and seg[0].inputs["Saved"] == [
+                scan.outputs[s][0] for s in ("O", "States", "Inverse")]
         else:
             gop, = [op for op in prog.global_block().ops
                     if op.type == "kda_scan_grad"]
-            assert gop.inputs.get("O") and gop.inputs.get("States")
+            assert all(gop.inputs.get(s) for s in ("O", "States",
+                                                   "Inverse"))
 
 
 def test_unbound_states_differentiate_the_forward_again():
@@ -341,7 +463,97 @@ def test_unbound_states_differentiate_the_forward_again():
                for s, g in zip(SLOTS, want_g))
 
 
-def test_amp_keeps_the_decays_beta_and_the_states_float32():
+@pytest.mark.parametrize("unbound", [("Inverse",), ("States",),
+                                     ("O",), ("States", "Inverse")])
+def test_any_residual_unbound_runs_the_forward_again_for_both(unbound):
+    """A grad op with O and States but no Inverse is what a desc from
+    before the slot holds: the forward kernel again for the states AND
+    the inverses, counted `recompute`, and the gradients those of the
+    saved path bit for bit."""
+    attrs = {"chunk_size": 32, "block_chunks": 2, "impl": "interpret"}
+    args, go = operands(128, b=1)
+    ins = dict(zip(SLOTS, args))
+    outs = get_op_def("kda_scan").compute(ins, attrs)
+    grad = get_op_def("kda_scan_grad")
+    bound = dict(ins, **outs, **{"O@GRAD": go})
+    assert grad.reads_saved(bound, attrs)
+    before = _counts()
+    want = grad.compute(bound, attrs)
+    assert _since(before) == {("kda_scan_grad", "saved"): 1}
+    for slot in unbound:
+        del bound[slot]
+    assert not grad.reads_saved(bound, attrs)
+    before = _counts()
+    got = grad.compute(bound, attrs)
+    assert _since(before) == {("kda_scan_grad", "recompute"): 1,
+                              ("kda_scan", "interpret"): 1}
+    assert all(bool(jnp.array_equal(got[k], want[k])) for k in want)
+
+
+@pytest.mark.parametrize("recompute", [False, True])
+def test_a_desc_from_before_the_inverse_runs_the_forward_again(recompute):
+    """append_backward binds Inverse on the grad op, or on the segment
+    that holds it; with the slot taken off again (a training program
+    saved before it existed) the step runs the forward kernel a second
+    time, and gives the same gradients.  The grad op counts
+    `recompute`; a segment differentiates its replay, and the grad op
+    never runs."""
+    want, want_loss, used, _ = _run_net(recompute, "interpret")
+    assert used[("kda_scan_grad", "saved")] == 1
+
+    def before_the_slot(prog):
+        ops = prog.global_block().ops
+        if not recompute:
+            gop, = [op for op in ops if op.type == "kda_scan_grad"]
+            assert gop.inputs.pop("Inverse")
+            return
+        seg, = [op for op in ops if op.type == "recompute_segment_grad"
+                and op.inputs.get("Saved")]
+        scan, = [op for op in ops if op.type == "kda_scan"]
+        name, = scan.outputs["Inverse"]
+        seg.inputs["Saved"].remove(name)
+        seg.attrs["saved_names"] = [n for n in seg.attrs["saved_names"]
+                                    if n != name]
+
+    got, loss, used, _ = _run_net(recompute, "interpret", before_the_slot)
+    assert ("kda_scan_grad", "saved") not in used
+    assert used.get(("kda_scan_grad", "recompute"), 0) == (not recompute)
+    assert used[("kda_scan", "interpret")] == 2
+    assert abs(loss - want_loss) <= 1e-6 * abs(want_loss)
+    errors = {n: float(np.abs(got[n] - w).max() / np.abs(w).max())
+              for n, w in want.items()}
+    assert all(e <= 1e-5 for e in errors.values()), errors
+
+
+def test_a_desc_has_the_same_slots_under_every_impl():
+    """The impl is an attr: the scan op, its grad op and a recompute
+    segment's saved list name the same slots whichever computes them,
+    and the vars behind them are declared alike."""
+    def slots(recompute, impl):
+        _fresh()
+        np.random.seed(0)
+        _net(recompute, impl)
+        block = fluid.default_main_program().global_block()
+        return [(op.type,
+                 {s: [(n, block.var(n).dtype) for n in names]
+                  for s, names in op.inputs.items()},
+                 {s: [(n, block.var(n).dtype) for n in names]
+                  for s, names in op.outputs.items()})
+                for op in block.ops
+                if op.type in ("kda_scan", "kda_scan_grad",
+                               "recompute_segment_grad")]
+
+    for recompute in (False, True):
+        want = slots(recompute, "xla")
+        assert slots(recompute, "interpret") == want
+        assert slots(recompute, None) == want
+        scan = want[0]
+        assert scan[0] == "kda_scan" and set(scan[2]) == {
+            "O", "States", "Inverse"}
+        assert scan[2]["Inverse"][0][1] == scan[2]["States"][0][1]
+
+
+def test_amp_keeps_the_decays_beta_the_states_and_the_inverse_float32():
     from paddle_tpu.contrib.mixed_precision import decorate
 
     _fresh()
@@ -360,13 +572,21 @@ def test_amp_keeps_the_decays_beta_and_the_states_float32():
     exe.run(fluid.default_startup_program())
     feed = {"x": np.random.RandomState(0).randn(2, T, 64).astype(
         np.float32)}
-    o, states, g, beta, q, k, v = exe.run(
+    o, states, inverse, g, beta, q, k, v = exe.run(
         fluid.CompiledProgram(fluid.default_main_program()), feed=feed,
-        fetch_list=[scan.outputs["O"][0], scan.outputs["States"][0]]
+        fetch_list=[scan.outputs[s][0] for s in ("O", "States", "Inverse")]
         + [scan.inputs[s][0] for s in ("G", "Beta", "Q", "K", "V")],
         return_numpy=False)
     assert o.dtype == q.dtype == k.dtype == v.dtype == jnp.bfloat16
     assert states.dtype == g.dtype == beta.dtype == jnp.float32
+    # the inverse is float32 where it is kept, and is the solve of the
+    # bfloat16 K the scan read
+    assert inverse.dtype == jnp.float32
+    assert inverse.shape == (2, H, T // C, C, C)
+    want = solved_inverses(k.astype(jnp.float32), g, beta, C)
+    assert rel(chunk_inverses(inverse, C), want) <= TOL
+    gop, = [op for op in block.ops if op.type == "kda_scan_grad"]
+    assert gop.inputs["Inverse"] == scan.outputs["Inverse"]
     assert float(g.min()) > -5.0 and float(g.max()) < 0.0
 
 
@@ -451,3 +671,43 @@ def test_head_gated_rms_norm_gates_a_head_after_the_norm():
     np.testing.assert_allclose(
         np.asarray(doubled).reshape(2, 5, 3, 8), sig * normed * ratio,
         rtol=1e-5, atol=1e-6)
+
+
+# -- tools/kda_price.py, on no chip ------------------------------------------
+
+def test_the_price_tool_rehearses_on_a_cpu(tmp_path):
+    """`--tiny`, this checkout on both sides: a row a side, dtype and
+    variant, no time read off a CPU, and the two sides' outputs and
+    gradients alike in every element (exit 0)."""
+    import importlib.util
+    import json
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "kda_price", os.path.join(repo, "tools", "kda_price.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = str(tmp_path / "price.json")
+    assert tool.main(["--tiny", "--parent", repo, "--out", out]) == 0
+    rows = json.load(open(out))["rows"]
+    priced = [r for r in rows if "side" in r]
+    assert {(r["side"], r["dtype"], r["variant"]) for r in priced} == {
+        (s, d, v) for s in ("parent", "change")
+        for d in ("bfloat16", "float32")
+        for v in ("whole", "inverse_as_I-N")}
+    assert all(r["fwd_ms"] is None and r["bwd_ms"] is None for r in priced)
+    diffs = [r["diff"] for r in rows if "diff" in r]
+    assert len(diffs) == 2 and not any(v for d in diffs for v in d.values())
+
+    # a change whose gradients moved shows in the row main() exits 1 on
+    change = tool.load(repo, "kda_moved")
+    bwd = change.kda_bwd_pallas.__wrapped__
+    change.kda_bwd_pallas.__wrapped__ = lambda *a, **k: tuple(
+        g * 1.5 for g in bwd(*a, **k))
+    rows = tool.price({"parent": tool.load(repo, "kda_parent"),
+                       "change": change}, jnp.float32, 64, 1, 128, (32, 2),
+                      True)
+    diff = rows[-1]["diff"]
+    assert diff["o"] == diff["states"] == 0.0
+    assert all(diff[g] > 0 for g in ("dq", "dk", "dv", "dg", "dbeta"))

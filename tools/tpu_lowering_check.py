@@ -401,10 +401,10 @@ ONE_SSD_FWD_AN_OP = ("granite_train", "granite_train_tiny")
 
 
 # the training steps whose every kda_scan op has a grad that reads the
-# forward's O and block-start states inside its recompute segment: the
-# forward kernel runs once an op (6 in the cell's step; 12 if the
-# segment replayed it, 18 if the grad op ran it again too), and the
-# backward kernel once
+# forward's O, block-start states and chunk inverses inside its
+# recompute segment: the forward kernel runs once an op (6 in the
+# cell's step; 12 if the segment replayed it, 18 if the grad op ran it
+# again too), and the backward kernel once
 ONE_KDA_FWD_AN_OP = ("ling3_train", "ling3_train_tiny")
 
 
