@@ -43,6 +43,7 @@ jax.
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -130,6 +131,47 @@ def _plain_attention(q, k, v, causal, scale, with_lse=False):
 
 
 # ---------------------------------------------------------------------------
+# the causal grid: which block pairs compute, and what a dead step holds
+# ---------------------------------------------------------------------------
+
+class _Diagonal(collections.namedtuple(
+        "_Diagonal", "block_q block_k q_off")):
+    """Where a causal call's diagonal lies over its grid of block pairs:
+    the ONE statement of which pair (qi, ki) computes, read by the
+    three kernel bodies (`run`) and, solved for either index, by the
+    BlockSpec index maps (`_second_held`), so that predicate and maps
+    cannot drift.  q_off = tk - tq: query row r sees keys 0 .. q_off + r.
+    The indices may be Python ints, arrays (a test walks a whole grid
+    at once) or a grid's traced program ids."""
+
+    def run(self, qi, ki):
+        """Block pair (qi, ki) holds a score on or below the diagonal:
+        its first key is no later than its last query row's last key."""
+        return (ki * self.block_k) <= (
+            self.q_off + qi * self.block_q + self.block_q - 1)
+
+    def last_ki(self, qi, nk):
+        """The last kv block of the nk that q block qi runs: `run`
+        solved for ki, held to the grid (0 for a q block that runs
+        none, tq > tk: it computes nothing, whatever it holds)."""
+        last_key = self.q_off + qi * self.block_q + self.block_q - 1
+        return jnp.minimum(jnp.maximum(last_key, 0) // self.block_k,
+                           nk - 1)
+
+    def first_qi(self, ki, nq):
+        """The first q block of the nq that runs kv block ki: `run`
+        solved for qi, held to the grid."""
+        first_row = ki * self.block_k - self.q_off
+        return jnp.minimum(jnp.maximum(first_row, 0) // self.block_q,
+                           nq - 1)
+
+    def dead_steps(self, nk):
+        """Whether a grid of nk kv blocks holds a pair that does not
+        run: the first q block's row of it has the fewest that do."""
+        return not self.run(0, nk - 1)
+
+
+# ---------------------------------------------------------------------------
 # a grid step's per-head tiles
 # ---------------------------------------------------------------------------
 
@@ -212,11 +254,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    if causal:
-        # skip KV blocks strictly above the diagonal of this Q block
-        run = (ki * block_k) <= (q_off + qi * block_q + block_q - 1)
-    else:
-        run = True
+    # KV blocks strictly above the diagonal of this Q block are skipped
+    run = _Diagonal(block_q, block_k, q_off).run(qi, ki) if causal \
+        else True
     # interior blocks (every position valid, fully below the causal
     # diagonal) skip mask construction entirely: the two [bq, bk]
     # iotas + compares + selects are VPU work on par with the exp
@@ -433,13 +473,34 @@ class _Tiles:
 
 
 def _first(i, j):
-    """Row-block pickers for `_Tiles.spec`: which of a grid's two inner
-    indices walks a tile's rows."""
+    """Row-block pickers for `_Tiles.spec` and the row statistics'
+    spec: which of a grid's two inner indices walks a tile's rows."""
     return i
 
 
 def _second(i, j):
     return j
+
+
+def _second_held(diagonal, nq, nk, walks):
+    """`_second` for a causal call: the operand that walks the grid's
+    INNER axis holds the block of the nearest step that runs over the
+    steps that do not, so Pallas's pipeline sees an unchanged block
+    index there and issues no copy: a step that computes nothing
+    fetches nothing.  At a step that runs it is `_second` exactly.
+
+    walks "kv": K and V on a (g, qi, ki) grid (the forward, the dq
+    sweep), whose dead steps END a q block's sweep: the last kv block
+    it runs stays.  walks "q": q, dO and the two row statistics on the
+    (g, ki, qi) grid of the dk/dv sweep, whose dead steps START a kv
+    block's sweep: the first q block it runs is there from step 0
+    (and so fetched early, behind the last step of the sweep before).
+    diagonal None (not causal): `_second` itself."""
+    if diagonal is None:
+        return _second
+    if walks == "kv":
+        return lambda qi, ki: jnp.minimum(ki, diagonal.last_ki(qi, nk))
+    return lambda ki, qi: jnp.maximum(qi, diagonal.first_qi(ki, nq))
 
 
 def _lanes(n):
@@ -494,6 +555,8 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         tiles.operand(v, bk)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
     grid = (b * h // hpb, tq_p // bq, tk_p // bk)
+    diagonal = _Diagonal(bq, bk, tk - tq) if causal else None
+    kv_rows = _second_held(diagonal, *grid[1:], walks="kv")
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
@@ -512,8 +575,8 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
         grid=grid,
         in_specs=[
             tiles.spec(bq, d, _first),
-            tiles.spec(bk, d, _second, kv=True),
-            tiles.spec(bk, dv, _second, kv=True),
+            tiles.spec(bk, d, kv_rows, kv=True),
+            tiles.spec(bk, dv, kv_rows, kv=True),
         ],
         out_specs=[
             tiles.spec(bq, dv, _first),
@@ -600,10 +663,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    if causal:
-        run = (ki * block_k) <= (q_off + qi * block_q + block_q - 1)
-    else:
-        run = True
+    run = _Diagonal(block_q, block_k, q_off).run(qi, ki) if causal \
+        else True
     interior = _bwd_interior(causal=causal, block_q=block_q,
                              block_k=block_k, kv_len=kv_len,
                              q_len=q_len, q_off=q_off, qi=qi, ki=ki)
@@ -683,11 +744,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_acc[a, rows, :] = jnp.zeros(
                     (block_q, dq_acc.shape[2]), dq_acc.dtype)
 
-    if causal:
-        # q blocks entirely above the diagonal contribute nothing
-        run = (ki * block_k) <= (q_off + qi * block_q + block_q - 1)
-    else:
-        run = True
+    # q blocks entirely above the diagonal contribute nothing
+    run = _Diagonal(block_q, block_k, q_off).run(qi, ki) if causal \
+        else True
     interior = _bwd_interior(causal=causal, block_q=block_q,
                              block_k=block_k, kv_len=kv_len,
                              q_len=q_len, q_off=q_off, qi=qi, ki=ki)
@@ -763,6 +822,29 @@ def _bwd_fused_vmem_bytes(hpb, tq_p, bq, bk, d, dv, itemsize):
     return tiles + dkv + dq + hpb * (stats + temps)
 
 
+def _count_causal_fetch(causal, tq, tk, bq, bk):
+    """paddle_tpu_kernel_impl_total{kernel="flash_attention_causal_fetch"}:
+    once a causal entry to the kernels, forward or backward, `held`
+    where its grid has a step above the diagonal (whose blocks the
+    index maps hold, `_second_held`), `all_live` where it has none (one
+    block a sequence).  An entry that is not causal adds no series."""
+    if causal:
+        held = _Diagonal(bq, bk, tk - tq).dead_steps(-(-tk // bk))
+        _count_impl("flash_attention_causal_fetch",
+                    "held" if held else "all_live")
+
+
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+               heads):
+    """(out, lse) by `_flash_fwd_pallas`, counted here, outside the
+    jit, as `_flash_bwd` counts."""
+    (_, _, tq, tk, _, _), bq, bk, _ = _block_geometry(
+        q, k, v, block_q, block_k, heads)
+    _count_causal_fetch(causal, tq, tk, bq, bk)
+    return _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
+                             interpret=interpret, heads=heads)
+
+
 def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
     """(dq, dk, dv) by `_flash_bwd_pallas`.  The shape alone picks the
     sweep: one kernel that writes all three where a head's dq fits the
@@ -770,12 +852,13 @@ def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
     Counted here, outside the jit, so that a step of six layers reads
     six.  **call: the static arguments `_call_args` resolved."""
     heads = call.get("heads")
-    (_, _, tq, _, d, dv), bq, bk, hpb = _block_geometry(
+    (_, _, tq, tk, d, dv), bq, bk, hpb = _block_geometry(
         q, k, v, call["block_q"], call["block_k"], heads)
     vmem = _bwd_fused_vmem_bytes(
         hpb, -(-tq // bq) * bq, bq, bk, d, dv, q.dtype.itemsize)
     fused = vmem <= _BWD_FUSED_VMEM_MAX
     _count_impl("flash_attention_bwd", "fused" if fused else "two_sweep")
+    _count_causal_fetch(call["causal"], tq, tk, bq, bk)
     return _flash_bwd_pallas(
         q, k, v, o, lse, g, dlse=dlse, **call,
         one_sweep_vmem=max(vmem, _MOSAIC_SCOPED_VMEM) if fused else None)
@@ -877,12 +960,15 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             dimension_semantics=("parallel", outer, "arbitrary"),
             vmem_limit_bytes=vmem_limit_bytes)}
 
+    nq, nk = tq_p // bq, tk_p // bk
+    diagonal = _Diagonal(bq, bk, q_off) if causal else None
     # kv blocks outer, q blocks inner: the dk/dv accumulators carry
     # across the q sweep
-    kv_specs = specs(q_rows=_second, k_rows=_first)
+    kv_specs = specs(q_rows=_second_held(diagonal, nq, nk, walks="q"),
+                     k_rows=_first)
     # dk and dv: a query head's, whatever the KV heads' count
     dkv_specs = [tiles.spec(bk, d, _first), tiles.spec(bk, dv, _first)]
-    kv_grid = (b * h // hpb, tk_p // bk, tq_p // bq)
+    kv_grid = (b * h // hpb, nk, nq)
     kv_scratch = [tiles.acc(bk, d), tiles.acc(bk, dv)]
     if one_sweep_vmem is not None:
         dq, dk, dv_ = pl.pallas_call(
@@ -898,11 +984,12 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             **params("arbitrary", one_sweep_vmem),
         )(*operands)
     else:
-        q_specs = specs(q_rows=_first, k_rows=_second)
+        q_specs = specs(q_rows=_first, k_rows=_second_held(
+            diagonal, nq, nk, walks="kv"))
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, **common),
             name="pt_flash_bwd_dq",
-            grid=(b * h // hpb, tq_p // bq, tk_p // bk),
+            grid=(b * h // hpb, nq, nk),
             in_specs=q_specs,
             out_specs=q_specs[0],
             out_shape=out_shape[0],
@@ -953,15 +1040,14 @@ def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
                heads=None):
     """(out, lse): lse is the mergeable summary ring attention needs and
     the residual the IR grad op reads.  heads: `_flash_fwd_pallas`."""
-    return _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                             interpret=interpret, heads=heads)
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k,
+                      interpret, heads)
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k,
                    interpret, heads):
-    out, lse = _flash_fwd_pallas(q, k, v, causal, scale, block_q,
-                                 block_k, interpret=interpret,
-                                 heads=heads)
+    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
+                          interpret, heads)
     return (out, lse), (q, k, v, out, lse)
 
 

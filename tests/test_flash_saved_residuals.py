@@ -186,17 +186,25 @@ def test_saved_equals_recompute_bit_for_bit(interpret, shape, kw):
     prog = _attention_net(feed, **kw)
     before = _impl_counts()
     saved = _run(prog, feed, GRADS)
+    # a causal entry says whether its grid has a step above the
+    # diagonal (ISSUE 48): 16 rows on 48 keys at blocks of 8 / 16 has
+    # none; a call that is not causal says nothing
+    fetch = () if not kw["causal"] else (
+        "flash_attention_causal_fetch",
+        "all_live" if shape["tq"] < shape["tk"] else "held")
     assert _since(before) == {("flash_attention", "interpret"): 1,
                               ("flash_attention_grad", "saved"): 1,
                               ("flash_attention_bwd", "fused"): 1,
-                              ("flash_attention_layout", "head_major"): 2}
+                              ("flash_attention_layout", "head_major"): 2,
+                              **({fetch: 2} if fetch else {})}
     _unbind_saved(prog)
     before = _impl_counts()
     recomputed = _run(prog, feed, GRADS)
     assert _since(before) == {("flash_attention", "interpret"): 2,
                               ("flash_attention_grad", "recompute"): 1,
                               ("flash_attention_bwd", "fused"): 1,
-                              ("flash_attention_layout", "head_major"): 2}
+                              ("flash_attention_layout", "head_major"): 2,
+                              **({fetch: 3} if fetch else {})}
     for name, a, b in zip(GRADS, saved, recomputed):
         assert np.array_equal(a, b), name
         assert np.abs(a).max() > 0, name
@@ -339,6 +347,7 @@ def test_saved_path_under_shard_map_matches_one_device(interpret):
             ("flash_attention_grad", "saved"): 1,
             ("flash_attention_bwd", "fused"): 1,
             ("flash_attention_layout", "head_major"): 2,
+            ("flash_attention_causal_fetch", "all_live"): 2,
             ("flash_attention_gspmd", "shard_map"): 2}
     finally:
         set_flags({"gspmd": False})
@@ -398,7 +407,9 @@ def test_transformer_step_counts_saved_six_times(interpret):
     assert _since(before) == {("flash_attention", "interpret"): 6,
                               ("flash_attention_grad", "saved"): 6,
                               ("flash_attention_bwd", "fused"): 6,
-                              ("flash_attention_layout", "head_major"): 12}
+                              ("flash_attention_layout", "head_major"): 12,
+                              ("flash_attention_causal_fetch",
+                               "all_live"): 12}
 
 
 def test_transformer_step_at_the_cells_head_size_is_token_major(interpret):
@@ -423,7 +434,9 @@ def test_transformer_step_at_the_cells_head_size_is_token_major(interpret):
     assert _since(before) == {("flash_attention", "interpret"): 2,
                               ("flash_attention_grad", "saved"): 2,
                               ("flash_attention_bwd", "fused"): 2,
-                              ("flash_attention_layout", "token_major"): 4}
+                              ("flash_attention_layout", "token_major"): 4,
+                              ("flash_attention_causal_fetch",
+                               "all_live"): 4}
 
 
 # -- token-major operands: the op on [B, T, H*d] ------------------------------
@@ -466,7 +479,8 @@ def test_rank3_op_binds_its_residuals_and_matches_rank4(interpret, h, d,
     assert _since(before) == {("flash_attention", "interpret"): 1,
                               ("flash_attention_grad", "saved"): 1,
                               ("flash_attention_bwd", "fused"): 1,
-                              ("flash_attention_layout", layout): 2}
+                              ("flash_attention_layout", layout): 2,
+                              ("flash_attention_causal_fetch", "held"): 2}
     assert _kernel_calls(prog, feed3) == dict.fromkeys(KERNELS, 1)
     for name, a, w in zip(GRADS, got, want):
         w = _token_major({name: w})[name]
@@ -515,7 +529,8 @@ def test_segment_runs_one_forward_kernel_a_layer(interpret, n_layers):
         ("flash_attention", "interpret"): n_layers,
         ("flash_attention_grad", "saved"): n_layers,
         ("flash_attention_bwd", "fused"): n_layers,
-        ("flash_attention_layout", "head_major"): 2 * n_layers}
+        ("flash_attention_layout", "head_major"): 2 * n_layers,
+        ("flash_attention_causal_fetch", "all_live"): 2 * n_layers}
     # and what it was before: the replay runs the forward kernel again.
     # The jaxpr holds it twice a segment, in the primal of the replay's
     # vjp, which nothing reads and XLA drops, and in the replay the

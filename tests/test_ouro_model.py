@@ -202,7 +202,10 @@ def test_head_size_128_through_the_token_major_kernels(interpret):
     assert used == {("flash_attention", "interpret"): 12,
                     ("flash_attention_layout", "token_major"): 16,
                     ("flash_attention_bwd", "fused"): 4,
-                    ("flash_attention_grad", "saved"): 4}
+                    ("flash_attention_grad", "saved"): 4,
+                    # a forward and a backward entry, one block a
+                    # sequence (ISSUE 48)
+                    ("flash_attention_causal_fetch", "all_live"): 16}
     assert ("flash_attention_layout", "head_major") not in used
 
 
