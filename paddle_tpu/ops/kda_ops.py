@@ -33,7 +33,14 @@ from paddle_tpu.ops import pallas_kernels as pk
 _F32 = jnp.float32
 
 _KDA_INPUTS = ("Q", "K", "V", "G", "Beta")
-_KDA_ATTRS = {"chunk_size": 64, "block_chunks": 4, "impl": ""}
+# decay: what the producer of G promises, which is which path the
+# kernels take for the pairs inside a 16-row sub-block
+# (ops/pallas_kda.py): "bounded", g >= pallas_kda.BOUNDED_G_MIN a token
+# (kda_gate's sigmoid form at -5; the default, what a desc from before
+# the attr came from), or "unbounded", exact for any g <= 0 (its
+# softplus form).  layers.kda_scan sets it from the gate that made G.
+_KDA_ATTRS = {"chunk_size": 64, "block_chunks": 4, "impl": "",
+              "decay": "bounded"}
 
 
 def _kda_impl(ins, attrs):
@@ -52,6 +59,21 @@ def _kda_impl(ins, attrs):
 _KDA_SAVED = ("O", "States", "Inverse")
 
 
+def _decay(attrs):
+    """The attr, or what an attr dict from before it means."""
+    decay = attrs.get("decay", _KDA_ATTRS["decay"])
+    if decay not in ("bounded", "unbounded"):
+        raise ValueError("kda_scan: decay %r is neither bounded nor "
+                         "unbounded" % (decay,))
+    return decay
+
+
+def _sizes(attrs):
+    """(chunk, chunks a block, bounded): the kernels' static choices."""
+    return (attrs["chunk_size"], attrs["block_chunks"],
+            _decay(attrs) == "bounded")
+
+
 @register_op("kda_scan", inputs=_KDA_INPUTS, outputs=_KDA_SAVED,
              attrs=_KDA_ATTRS)
 def kda_scan(ins, attrs):
@@ -63,8 +85,11 @@ def kda_scan(ins, attrs):
               + Beta_t k_t v_t^T,      o_t = S_t^T q_t
 
     Q, K, V [B, T, H*D] token-major, G [B, T, H*D] the log-decay a key
-    channel (float32, in [-5, 0]: a chunk kernel forms e^(-G) over 16
-    tokens), Beta [B, T, H] -> O [B, T, H*D] in V's dtype and the two
+    channel (float32, <= 0; attr `decay` says whether the gate keeps
+    it >= -5.33 a token, "bounded", where a chunk kernel may form
+    e^(-G) over the 16 tokens of a sub-block, or not, "unbounded",
+    where it forms nothing above e^0), Beta [B, T, H] (in (0, 1) or,
+    doubled by the caller, (0, 2)) -> O [B, T, H*D] in V's dtype and the two
     residuals kda_scan_grad reads, float32 whatever the operands are,
     with block = block_chunks chunk_size: States [B, T / block, H*D,
     D], the TRANSPOSED state each block of chunks starts from, and
@@ -76,11 +101,14 @@ def kda_scan(ins, attrs):
     algorithm in jax.numpy)."""
     impl = _kda_impl(ins, attrs)
     pk._count_impl("kda_scan", impl)
+    pk._count_impl("kda_scan_decay", _decay(attrs))
     args = tuple(ins[s] for s in _KDA_INPUTS)
-    sizes = (attrs["chunk_size"], attrs["block_chunks"])
+    sizes = _sizes(attrs)
     with jax.named_scope("pt_kda"):
         if impl == "xla":
-            outs = pallas_kda.kda_chunked_xla(*args, *sizes)
+            # every decay a difference G_r - G_s of its own pair:
+            # exact under either promise
+            outs = pallas_kda.kda_chunked_xla(*args, *sizes[:2])
         else:
             outs = _scan_kernel(*args, sizes, impl)
     return dict(zip(_KDA_SAVED, outs))
@@ -157,8 +185,7 @@ def kda_scan_grad(ins, attrs):
     if saved:
         with jax.named_scope("pt_kda"):
             grads = _grads_on_kept(
-                args, ins["States"], ins["Inverse"], g,
-                (attrs["chunk_size"], attrs["block_chunks"]),
+                args, ins["States"], ins["Inverse"], g, _sizes(attrs),
                 _kda_impl(ins, attrs))
     else:
         _, vjp = jax.vjp(
@@ -169,25 +196,36 @@ def kda_scan_grad(ins, attrs):
 
 
 @register_op("kda_gate", inputs=("X", "ALog", "DtBias"), outputs=("G",),
-             attrs={"lower_bound": -5.0})
+             attrs={"lower_bound": -5.0, "form": "sigmoid_bound"})
 def kda_gate(ins, attrs):
-    """The safe log-decay gate: X [B, T, H*D] the decay projection,
-    ALog [H], DtBias [H*D] ->
+    """The log-decay gate: X [B, T, H*D] the decay projection, ALog
+    [H], DtBias [H*D] -> G float32, by `form`:
 
-        G = lower_bound * sigmoid(exp(ALog_h) * (X + DtBias))
+        "sigmoid_bound"  G = lower_bound * sigmoid(exp(ALog_h) * (X + DtBias))
+        "softplus"       G = -exp(ALog_h) * softplus(X + DtBias)
 
-    float32 in (lower_bound, 0), so that exp G lies in (e^lower_bound,
-    1) a channel.  lower_bound is negative."""
+    The first (the safe gate) lies in (lower_bound, 0), so that exp G
+    lies in (e^lower_bound, 1) a channel; lower_bound is negative.
+    The second (Kimi Linear's) lies in (-inf, 0): no bound, and
+    lower_bound is not read.  Counted in
+    paddle_tpu_kernel_impl_total{kernel="kda_gate_form"}."""
     x = ins["X"]
     h = ins["ALog"].shape[0]
-    if attrs["lower_bound"] >= 0:
+    form = attrs.get("form", "sigmoid_bound")
+    if form not in ("sigmoid_bound", "softplus"):
+        raise ValueError("kda_gate: form %r" % (form,))
+    lower_bound = attrs.get("lower_bound", -5.0)
+    if form == "sigmoid_bound" and lower_bound >= 0:
         raise ValueError("kda_gate: lower_bound %r is not negative"
-                         % (attrs["lower_bound"],))
+                         % (lower_bound,))
+    pk._count_impl("kda_gate_form", form)
     with jax.named_scope("pt_kda_gate"):
         rate = jnp.repeat(jnp.exp(ins["ALog"].astype(_F32)),
                           x.shape[-1] // h)
-        return {"G": attrs["lower_bound"] * jax.nn.sigmoid(
-            rate * (x.astype(_F32) + ins["DtBias"].astype(_F32)))}
+        shifted = x.astype(_F32) + ins["DtBias"].astype(_F32)
+        if form == "softplus":
+            return {"G": -rate * jax.nn.softplus(shifted)}
+        return {"G": lower_bound * jax.nn.sigmoid(rate * shifted)}
 
 
 def _head_indicator(width, n_head):
@@ -240,28 +278,43 @@ def head_gated_rms_norm(ins, attrs):
         Y_h = sigmoid(Gate_h) * RMSNorm(X_h) * Scale
 
     the norm a head (the statistic over the head's D entries), the gate
-    a head and AFTER the norm.  Scale unbound: no norm, Y_h =
-    sigmoid(Gate_h) * X_h (latent attention's output gate).  Gate
-    unbound: no gate, Y_h = RMSNorm(X_h) * Scale with H = n_head (the
-    norm on an attention layer's q and k; scope pt_head_rms_norm).
-    Float32 inside, Y in X's dtype."""
-    x, gate = ins["X"], ins.get("Gate")
-    n_head = attrs["n_head"] if gate is None else gate.shape[-1]
-    if n_head < 1 or x.shape[-1] % n_head or (
-            gate is None and ins.get("Scale") is None):
+    a head and AFTER the norm.  Gate [.., H*D], as wide as X: one
+    logit a CHANNEL, Y = sigmoid(Gate) * RMSNorm_h(X) * Scale with H
+    = n_head.  Scale unbound: no norm, Y = sigmoid(Gate) * X, a head
+    (latent attention's output gate) or a channel (a grouped-KV
+    attention layer's).  Gate unbound: no gate, Y_h = RMSNorm(X_h) *
+    Scale with H = n_head (the norm on an attention layer's q and k;
+    scope pt_head_rms_norm).  Float32 inside, Y in X's dtype."""
+    x, gate, scale = ins["X"], ins.get("Gate"), ins.get("Scale")
+    width = x.shape[-1]
+    # as wide as X, and the heads said beside it or no norm to need
+    # them: a gate a channel
+    said = attrs.get("n_head", 0)
+    channel = gate is not None and gate.shape[-1] == width \
+        and (said > 0 or scale is None)
+    n_head = gate.shape[-1] if gate is not None and not channel else said
+    if (gate is None and scale is None) or (
+            (scale is not None or not channel)
+            and (n_head < 1 or width % n_head)):
         raise ValueError(
             "head_gated_rms_norm: %d channels in %d heads%s"
-            % (x.shape[-1], n_head,
+            % (width, n_head,
                "" if gate is not None else ", no gate and no norm"))
     with jax.named_scope("pt_head_rms_norm" if gate is None
                          else "pt_head_gated_norm"):
-        ind = _head_indicator(x.shape[-1], n_head)
         xf = x.astype(_F32)
-        per_head = 1.0 if gate is None \
+        if channel and scale is None:
+            return {"Y": (xf * jax.nn.sigmoid(gate.astype(_F32))
+                          ).astype(x.dtype)}
+        ind = _head_indicator(width, n_head)
+        per_head = 1.0 if gate is None or channel \
             else jax.nn.sigmoid(gate.astype(_F32))
-        if ins.get("Scale") is not None:
+        if scale is not None:
             per_head = per_head * lax.rsqrt(
-                _head_sum(jnp.square(xf), ind) / (x.shape[-1] // n_head)
+                _head_sum(jnp.square(xf), ind) / (width // n_head)
                 + attrs["epsilon"])
-            xf = xf * jnp.tile(ins["Scale"].astype(_F32), n_head)
-        return {"Y": (xf * _head_spread(per_head, ind)).astype(x.dtype)}
+            xf = xf * jnp.tile(scale.astype(_F32), n_head)
+        y = xf * _head_spread(per_head, ind)
+        if channel:
+            y = y * jax.nn.sigmoid(gate.astype(_F32))
+        return {"Y": y.astype(x.dtype)}

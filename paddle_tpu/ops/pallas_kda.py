@@ -23,13 +23,31 @@ Kg = K * e^G, Qg = Q * e^G, Kend = K * e^(G_C - G):
     O  = Qg Z^T + P Ut
     Z' = Z * e^(G_C) + Ut^T Kend
 
-e^(-G) alone is never formed: at the decay's bound g = -5 a chunk of
-64 tokens would need e^320.  M and P are made a SUB-BLOCK of 16 rows at
-a time against a reference row (the sub-block's first): the rows carry
-e^(G_r - G_ref) <= 1 and the columns e^(min(G_ref - G_s, 80)), which
-is <= 1 for the columns before the sub-block and at most e^75 inside
-it (15 steps of at most 5); columns after it are masked.  So the gate
-has to keep g >= -5 a token (layers.kda_gate does).
+e^(-G) alone is never formed: at g = -5 a token a chunk of 64 tokens
+would need e^320.  M and P are made a SUB-BLOCK of 16 rows at a time
+against a reference row (the sub-block's first): the rows carry
+e^(G_r - G_ref) <= 1 and the columns e^(G_ref - G_s), which is <= 1
+for the columns before the sub-block.  What the columns INSIDE the
+sub-block carry is the static choice `bounded`, which the op takes from
+the gate's form (kda_ops.kda_scan, attr `decay`):
+
+  * bounded (a gate that keeps g >= BOUNDED_G_MIN a token: kda_gate's
+    sigmoid form at -5): the same factors, e^(min(G_ref - G_s, 80)), at
+    most e^75 inside the sub-block (15 steps of at most 5); columns
+    after it are masked.  One product a sub-block.
+  * unbounded (any g <= 0: kda_gate's softplus form): nothing above
+    e^0 is formed and no clamp can change a result.  The sub-block's
+    product is kept for the columns BEFORE it only (the clamp is
+    min(., 0), which those columns never reach); the pairs inside the
+    16 x 16 diagonal blocks are made by the LEVEL at which r and s
+    part: at level b = 8, 4, 2, 1, r lies in the odd block of b rows
+    of a pair of blocks and s in the even one before it, and the
+    reference is the odd block's first row, which lies between s and r
+    in time, so both factors are <= 1 (`_levels`; four more products
+    of the whole chunk, masked to their pairs; P's diagonal, decay
+    e^0, is the rows' q . k).  A decay is still a difference of the
+    chunk's running sums, so its exponent carries the rounding of
+    |G|: one part in 2^24 of the chunk's total log-decay.
 
 The inverse: the 16 x 16 diagonal blocks by doubling, (I + X)(I + X^2)
 (I + X^4)(I + X^8) with X = -N_b, N_b^16 = 0; the blocks below them by
@@ -92,6 +110,9 @@ _LANES = 128
 _SUB = 16                 # rows of a sub-block of M and P
 _MAX_CHUNK = 4 * _SUB     # _inverse's series ends at four diagonal blocks
 _MAX_EXP = 80.0           # e^80 < float32's largest; 15 steps of 5 < 80
+# the least g a token for which the bounded path is exact: a column of
+# a sub-block is at most 15 steps from its reference row
+BOUNDED_G_MIN = -_MAX_EXP / (_SUB - 1)
 _HIGHEST = lax.Precision.HIGHEST
 
 
@@ -216,39 +237,95 @@ def _dot(a, b, dims, dtype):
         precision=_HIGHEST if dtype == _F32 else None)
 
 
-def _masks(chunk):
+_LEVELS = (8, 4, 2, 1)    # the blocks of rows a diagonal block halves into
+
+
+def _masks(chunk, bounded=True):
     r = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     c = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    return {"lower": r >= c, "strict": r > c, "eye": r == c,
-            "diag_block": (r // _SUB) == (c // _SUB)}
+    mk = {"lower": r >= c, "strict": r > c, "eye": r == c,
+          "diag_block": (r // _SUB) == (c // _SUB)}
+    if not bounded:
+        mk["before"] = (r // _SUB) > (c // _SUB)
+        # level b: r in the odd block of b rows, s in the even one
+        # before it
+        mk.update({b: ((r // b) == (c // b) + 1) & ((r // b) % 2 == 1)
+                   for b in _LEVELS})
+    return mk
 
 
-def _sub_blocks(q, k, gsum):
+def _sub_blocks(q, k, gsum, bounded=True):
     """For each sub-block i of 16 rows: (left [32, D]: K_i and Q_i
     times el, right [C, D]: K times er, el [16, D], er [C, D]) with
     el = e^(G_r - G_ref) and er = e^(min(G_ref - G_s, 80)), G_ref the
-    sub-block's first row.  All float32."""
+    sub-block's first row; unbounded, er = e^(min(G_ref - G_s, 0)):
+    the columns before the sub-block never reach the clamp, and the
+    others are `_levels`'.  All float32."""
     out = []
     for i in range(gsum.shape[0] // _SUB):
         rows = slice(i * _SUB, (i + 1) * _SUB)
         gi = gsum[rows]
         ref = gi[0:1]
         el = jnp.exp(gi - ref)
-        er = jnp.exp(jnp.minimum(ref - gsum, _MAX_EXP))
+        er = jnp.exp(jnp.minimum(ref - gsum,
+                                 _MAX_EXP if bounded else 0.0))
         left = jnp.concatenate([k[rows] * el, q[rows] * el], axis=0)
         out.append((left, k * er, el, er))
     return out
 
 
-def _scores(q, k, gsum, mk, dtype):
+def _levels(q, k, gsum):
+    """The pairs (r, s), s < r, INSIDE the 16 x 16 diagonal blocks, by
+    the level b at which they part: (b, left [2C, D]: K and Q times
+    el, right [C, D]: K times er, el, er [C, D]) with el = e^(G_r -
+    G_ref(r)), G_ref(r) the first row of r's block of b rows, and er =
+    e^(min(G_ref'(s) - G_s, 0)), G_ref'(s) the first row of the block
+    AFTER s's.  For a level's pairs (mask b of `_masks`) the two
+    references are one row, between s and r in time: both exponents
+    are <= 0 whatever g <= 0 is.  The clamp touches only a chunk's
+    last block, whose `next block` wraps and whose pairs are masked.
+    All float32."""
+    c = gsum.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, gsum.shape, 0)
+    refs, ref = {1: gsum}, gsum
+    for b in (1, 2, 4):
+        # the first row of a block of 2b rows: of its own block of b
+        # in the even one, of the block before in the odd one (a roll
+        # by b moves the rows down: row r takes row r - b)
+        ref = jnp.where((row // b) % 2 == 1, pltpu.roll(ref, b, 0), ref)
+        refs[2 * b] = ref
+    out = []
+    for b in _LEVELS:
+        el = jnp.exp(gsum - refs[b])
+        er = jnp.exp(jnp.minimum(
+            pltpu.roll(refs[b], c - b, 0) - gsum, 0.0))
+        left = jnp.concatenate([k * el, q * el], axis=0)
+        out.append((b, left, k * er, el, er))
+    return out
+
+
+def _scores(q, k, gsum, mk, dtype, bounded=True):
     """(M strictly lower, P lower) of one chunk, float32 [C, C]."""
     m_rows, p_rows = [], []
-    for left, right, _, _ in _sub_blocks(q, k, gsum):
+    for left, right, _, _ in _sub_blocks(q, k, gsum, bounded):
         mp = _dot(left, right, _NT, dtype)
         m_rows.append(mp[:_SUB])
         p_rows.append(mp[_SUB:])
-    return (jnp.where(mk["strict"], jnp.concatenate(m_rows, axis=0), 0.0),
-            jnp.where(mk["lower"], jnp.concatenate(p_rows, axis=0), 0.0))
+    m = jnp.concatenate(m_rows, axis=0)
+    p = jnp.concatenate(p_rows, axis=0)
+    if bounded:
+        return (jnp.where(mk["strict"], m, 0.0),
+                jnp.where(mk["lower"], p, 0.0))
+    c = gsum.shape[0]
+    m = jnp.where(mk["before"], m, 0.0)
+    # a token's own pair decays by e^0
+    p = jnp.where(mk["before"], p, 0.0) + jnp.where(
+        mk["eye"], jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+    for b, left, right, _, _ in _levels(q, k, gsum):
+        mp = _dot(left, right, _NT, dtype)
+        m = m + jnp.where(mk[b], mp[:c], 0.0)
+        p = p + jnp.where(mk[b], mp[c:], 0.0)
+    return m, p
 
 
 def _inverse(n, mk):
@@ -269,11 +346,12 @@ def _inverse(n, mk):
     return r + mm(mm(y, y), r)
 
 
-def _chunk_forward(q, k, v, gsum, beta, z, mk, dtype, t_inv=None):
+def _chunk_forward(q, k, v, gsum, beta, z, mk, dtype, bounded,
+                   t_inv=None):
     """One chunk from the transposed state z: (o, z_next, and what the
     backward keeps: t_inv, m, p, w, ut).  `t_inv`: the chunk's inverse
     where the forward pass kept it, else it is formed here."""
-    m, p = _scores(q, k, gsum, mk, dtype)
+    m, p = _scores(q, k, gsum, mk, dtype, bounded)
     if t_inv is None:
         t_inv = _inverse(beta * m, mk)
     eg = jnp.exp(gsum)
@@ -308,9 +386,9 @@ def _load(refs, rows):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, ti_ref,
-                z_ref, t_all, *, chunk, block_chunks):
+                z_ref, t_all, *, chunk, block_chunks, bounded):
     dtype = q_ref.dtype
-    mk = _masks(chunk)
+    mk = _masks(chunk, bounded)
 
     @pl.when(pl.program_id(2) == 0)
     def _first_block():
@@ -323,7 +401,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, ti_ref,
         q, k, v, gsum, beta = _load((q_ref, k_ref, v_ref, g_ref, b_ref),
                                     rows)
         o, z_next, (t_inv, *_) = _chunk_forward(
-            q, k, v, gsum, beta, z_ref[...], mk, dtype)
+            q, k, v, gsum, beta, z_ref[...], mk, dtype, bounded)
         o_ref[0, rows, :] = o.astype(o_ref.dtype)
         t_all[c] = t_inv
         z_ref[...] = z_next
@@ -361,19 +439,21 @@ def _params(interpret):
         vmem_limit_bytes=64 << 20)}
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("chunk", "block_chunks", "interpret"))
-def kda_fwd_pallas(q, k, v, g, beta, chunk, block_chunks,
+@functools.partial(jax.jit, static_argnames=(
+    "chunk", "block_chunks", "interpret", "bounded"))
+def kda_fwd_pallas(q, k, v, g, beta, chunk, block_chunks, bounded=True,
                    interpret=False):
     """-> (o [B, T, H*D] in v's dtype, states float32
-    [B, T/block, H*D, D], inverse float32 [B, H, T/block, C, block])."""
+    [B, T/block, H*D, D], inverse float32 [B, H, T/block, C, block]).
+    bounded: g >= BOUNDED_G_MIN a token is the caller's promise; False
+    is exact for any g <= 0."""
     b, t, h, d = check_shapes(q, v, beta, chunk, block_chunks)
     block = chunk * block_chunks
     gsum, bcol = _prep(g, beta, chunk)
     sp = _specs(t, d, chunk, block, rev=False)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk,
-                          block_chunks=block_chunks),
+                          block_chunks=block_chunks, bounded=bounded),
         name="pt_kda_fwd",
         grid=(b, h, t // block),
         in_specs=[sp["x"], sp["x"], sp["x"], sp["x"], sp["beta"]],
@@ -409,6 +489,10 @@ def kda_fwd_pallas(q, k, v, g, beta, chunk, block_chunks,
 #   x = (dleft_k K_i + dleft_q Q_i) el,   y = dright K er
 # (the reference row's own share, cols(y) - cols(x), is zero: both sum
 # the same pairs (r, s), one by its row and one by its column)
+# The unbounded path's levels (`_levels`) are factors of the same form
+# over the whole chunk, dmp masked to a level's pairs, whose two
+# references are one row: the same lines, and the same zero share.
+# P's diagonal q_r . k_r gives dQ_r += dP_rr K_r, dK_r += dP_rr Q_r.
 # and the row-scaled operands give
 #   dQ += dQg e^G, dK += dKg e^G + dKend e^(G_C - G),
 #   dG += dQg Qg + dKg Kg - dKend Kend,
@@ -417,9 +501,9 @@ def kda_fwd_pallas(q, k, v, g, beta, chunk, block_chunks,
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref, ti_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
                 dz_ref, z_all, t_all, m_all, p_all, w_all, ut_all,
-                *, chunk, block_chunks):
+                *, chunk, block_chunks, bounded):
     dtype = q_ref.dtype
-    mk = _masks(chunk)
+    mk = _masks(chunk, bounded)
     ins = (q_ref, k_ref, v_ref, g_ref, b_ref)
     row = lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
 
@@ -438,7 +522,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref, ti_ref,
     def again(c, z):
         q, k, v, gsum, beta = _load(ins, rows_of(c))
         _, z_next, (_, m, p, w, ut) = _chunk_forward(
-            q, k, v, gsum, beta, z, mk, dtype, t_inv=t_all[c])
+            q, k, v, gsum, beta, z, mk, dtype, bounded, t_inv=t_all[c])
         z_all[c], m_all[c], p_all[c] = z, m, p
         w_all[c], ut_all[c] = w, ut
         return z_next
@@ -483,9 +567,27 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref, ti_ref,
         dg = dqg * qg + dkg * kg - z_end + jnp.where(
             row == chunk - 1,
             jnp.sum(z_end, axis=0, keepdims=True) + d_last, 0.0)
+        if not bounded:
+            # the pairs inside the diagonal blocks, level by level
+            dpd = jnp.sum(jnp.where(mk["eye"], dp, 0.0), axis=1,
+                          keepdims=True)
+            dq = dq + dpd * k
+            dk = dk + dpd * q
+            for b, left, right, el, er in _levels(q, k, gsum):
+                dmp = jnp.concatenate([jnp.where(mk[b], dm, 0.0),
+                                       jnp.where(mk[b], dp, 0.0)], axis=0)
+                dleft = _dot(dmp, right, _NN, dtype)
+                dright = _dot(dmp, left, _TN, dtype)
+                dl_k, dl_q = dleft[:chunk], dleft[chunk:]
+                dq = dq + dl_q * el
+                dk = dk + dl_k * el + dright * er
+                dg = dg + (dl_k * k + dl_q * q) * el - dright * k * er
+            # what is left for the sub-blocks: the columns before them
+            dm = jnp.where(mk["before"], dm, 0.0)
+            dp = jnp.where(mk["before"], dp, 0.0)
         dq_rows, dk_rows, dg_rows = [], [], []
         for j, (left, right, el, er) in enumerate(
-                _sub_blocks(q, k, gsum)):
+                _sub_blocks(q, k, gsum, bounded)):
             sub = slice(j * _SUB, (j + 1) * _SUB)
             dmp = jnp.concatenate([dm[sub], dp[sub]], axis=0)
             dleft = _dot(dmp, right, _NN, dtype)
@@ -510,13 +612,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref, ti_ref,
     lax.fori_loop(0, block_chunks, back, 0, unroll=True)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("chunk", "block_chunks", "interpret"))
+@functools.partial(jax.jit, static_argnames=(
+    "chunk", "block_chunks", "interpret", "bounded"))
 def kda_bwd_pallas(q, k, v, g, beta, states, inverse, do, chunk,
-                   block_chunks, interpret=False):
+                   block_chunks, bounded=True, interpret=False):
     """The five input gradients (q, k, v, g, beta), each in its input's
     dtype, from the block-start states and the chunks' inverses the
-    forward kept."""
+    forward kept.  bounded: as the forward's."""
     b, t, h, d = check_shapes(q, v, beta, chunk, block_chunks)
     block = chunk * block_chunks
     gsum, bcol = _prep(g, beta, chunk)
@@ -528,7 +630,7 @@ def kda_bwd_pallas(q, k, v, g, beta, states, inverse, do, chunk,
     cc, cd = (block_chunks, chunk, chunk), (block_chunks, chunk, d)
     dq, dk, dv, dgsum, dbcol = pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk,
-                          block_chunks=block_chunks),
+                          block_chunks=block_chunks, bounded=bounded),
         name="pt_kda_bwd",
         grid=(b, h, t // block),
         in_specs=[sp["x"], sp["x"], sp["x"], sp["x"], sp["beta"], sp["x"],

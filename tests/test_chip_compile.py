@@ -118,6 +118,9 @@ LING3_GMM = dict(rows=32768, hidden=2560, width=768)
 # lfm2: 8,192 tokens x 4 experts a token over 16 held experts of width
 # 1,536 = 12 x 128: the layout's 36,864 rows, 144 tiles
 LFM2_GMM = dict(rows=32768, held=16, hidden=2048, width=1536)
+# solar-open2: 8,192 tokens x 8 experts a token at hidden 4,096 over 8
+# held experts of width 1,280 = 10 x 128: the layout's 67,584 rows
+SOLAR_GMM = dict(rows=65536, hidden=4096, width=1280)
 
 
 def _decode(head_dim, head_pack, batch=64, heads=8, page_size=128,
@@ -171,15 +174,19 @@ def _ssd(grad, b=1, t=8192, h=64, p=64, n=128, chunk=256):
         ins + (_sds((b, t // chunk, h * p, n), f32), x), 1
 
 
-def _kda(grad, b=1, t=4096, h=32, d=128, chunk=64, block_chunks=4):
+def _kda(grad, b=1, t=4096, h=32, d=128, chunk=64, block_chunks=4,
+         bounded=True):
     """The delta-rule scan of ling-3.0-flash-vl's KDA mixer at the
-    cell's size: 32 heads of 128, 16 blocks of 4 chunks of 64."""
+    cell's size: 32 heads of 128, 16 blocks of 4 chunks of 64; and of
+    solar-open2-250b's, 8 held heads over 8,192 tokens on the path
+    that is exact for an unbounded decay (the diagonal blocks' pairs
+    by levels: four more products a chunk, sublane rolls)."""
     from paddle_tpu.ops.pallas_kda import kda_bwd_pallas, kda_fwd_pallas
 
     f32 = jnp.float32
     x = _sds((b, t, h * d))
     ins = (x, x, x, _sds((b, t, h * d), f32), _sds((b, t, h), f32))
-    sizes = dict(chunk=chunk, block_chunks=block_chunks)
+    sizes = dict(chunk=chunk, block_chunks=block_chunks, bounded=bounded)
     if not grad:
         return (lambda *a: kda_fwd_pallas(*a, **sizes)), ins, 1
     block = chunk * block_chunks
@@ -245,17 +252,25 @@ CASES = {
        (lambda shape=shape, f32=f32: _moe_combine(*shape, f32))
        # and 32 sequences of ling3's: a grid step reads its own block
        # of the plan, so the tokens of a call are not bounded by SMEM
+       # solar-open2: 8,192 tokens x 8 pairs at hidden 4,096
        for shape in ((4096, 8, 2560, 34816), (4096, 4, 3584, 18432),
-                     (8192, 6, 2048, 51200), (131072, 8, 2560, 1050624))
+                     (8192, 6, 2048, 51200), (131072, 8, 2560, 1050624),
+                     (8192, 8, 4096, 67584))
        for f32 in (False, True)},
     "conv1d_fwd_1x8192x4352_bias": lambda: _conv1d(False, 8192, 4352, True),
     "conv1d_bwd_1x8192x4352_bias": lambda: _conv1d(True, 8192, 4352, True),
     "conv1d_fwd_1x4096x4096": lambda: _conv1d(False, 4096, 4096, False),
     "conv1d_bwd_1x4096x4096": lambda: _conv1d(True, 4096, 4096, False),
+    "conv1d_fwd_1x8192x1024": lambda: _conv1d(False, 8192, 1024, False),
+    "conv1d_bwd_1x8192x1024": lambda: _conv1d(True, 8192, 1024, False),
     "conv1d_fwd_gated_1x8192x6144_k3": lambda: _gated_conv(False),
     "conv1d_bwd_gated_1x8192x6144_k3": lambda: _gated_conv(True),
     "kda_fwd_1x4096_h32_d128": lambda: _kda(False),
     "kda_bwd_1x4096_h32_d128": lambda: _kda(True),
+    "kda_fwd_unbounded_1x8192_h8_d128": lambda: _kda(
+        False, t=8192, h=8, bounded=False),
+    "kda_bwd_unbounded_1x8192_h8_d128": lambda: _kda(
+        True, t=8192, h=8, bounded=False),
     "ssd_fwd_1x8192_h64_p64_n128": lambda: _ssd(False),
     "ssd_bwd_1x8192_h64_p64_n128": lambda: _ssd(True),
     "flash_fwd_32x8x512x64": lambda: _flash((32, 8, 512, 64), False),
@@ -289,6 +304,12 @@ CASES = {
         _flash_token_major((1, 8192, 2048), 32, False, kv_width=512),
     "flash_bwd_1x8192x2048_token_major_kv8": lambda:
         _flash_token_major((1, 8192, 2048), 32, True, kv_width=512),
+    # solar-open2-250b's attention layer as one tensor-parallel rank
+    # holds it: 8 query heads of 128 read ONE KV head in place
+    "flash_fwd_1x8192x1024_token_major_kv1_d128": lambda:
+        _flash_token_major((1, 8192, 1024), 8, False, kv_width=128),
+    "flash_bwd_1x8192x1024_token_major_kv1_d128": lambda:
+        _flash_token_major((1, 8192, 1024), 8, True, kv_width=128),
     "attention_block_64x512x512_token_major": _attention_block,
     "flash_fwd_1x32x4096_qk192_v128": lambda: _flash_mla(False),
     "flash_bwd_saved_1x32x4096_qk192_v128": lambda: _flash_mla(True),
@@ -305,12 +326,26 @@ CASES = {
     "gmm_fwd_16x2048x1536_rows36864": lambda: _gmm("fwd", **LFM2_GMM),
     "gmm_bwd_dx_16x2048x1536_rows36864": lambda: _gmm("dx", **LFM2_GMM),
     "gmm_bwd_dw_16x2048x1536_rows36864": lambda: _gmm("dw", **LFM2_GMM),
+    "gmm_fwd_8x4096x1280_rows67584": lambda: _gmm("fwd", **SOLAR_GMM),
+    "gmm_bwd_dx_8x4096x1280_rows67584": lambda: _gmm("dx", **SOLAR_GMM),
+    "gmm_bwd_dw_8x4096x1280_rows67584": lambda: _gmm("dw", **SOLAR_GMM),
     "flash_decode_d128_b64": lambda: _decode(128, False),
     "flash_decode_d64_headpacked_b64": lambda: _decode(64, True),
     "conv2d_epilogue_3x3_56x56x64_mb128": lambda: _conv(False),
     "conv2d_bn_act_3x3_56x56x64_mb128": lambda: _conv(True),
     "fc_epilogue_16384x512x2048": lambda: _fc(),
 }
+
+
+# the backward's sum of dk and dv over a KV head's group
+# (pallas_kernels._sum_groups) reshapes [B, T, H d] to [.., group, d]; at
+# ONE KV head of 128 the compiler makes that a rank-4 copy of each of the
+# two float32 arrays (33.5 MB each), which this count sees (at 8 KV heads
+# of 64 the same reshape is rank 5, which it does not).  Left as it is:
+# PR 49's review took a lane-slice form of the sum out again for want of
+# a traced time; the cell's trace with and without it is in PERF.md
+# section 6, PR 49
+GROUP_SUM_COPIES = {"flash_bwd_1x8192x1024_token_major_kv1_d128": 2}
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -324,4 +359,5 @@ def test_kernel_compiles_for_described_v5e(chip_gate, case):
     assert exe.memory_analysis().temp_size_in_bytes >= 0
     if "token_major" in case:
         # no head split or merge around the kernels
-        assert chip_gate.head_layout_copies(exe.as_text()) == 0
+        assert chip_gate.head_layout_copies(exe.as_text()) \
+            == GROUP_SUM_COPIES.get(case, 0)

@@ -175,6 +175,185 @@ def test_a_run_at_the_decays_bound_is_finite_and_right(impl):
     assert rel(lost_o, want_o) > 100 * TOL
 
 
+def _unbounded_case(case):
+    """128 tokens, two chunks of 64: g = -30 a token throughout (a
+    sub-block's reference row is 450 away: e^450 if anything formed
+    e^(G_ref - G_s) there), or each channel of each token drawn from
+    {-1e-4, -40}, so that steps that keep everything and steps that
+    keep nothing lie inside ONE 16-row sub-block; beta at the ends of
+    (0, 2)."""
+    args, go = operands(128, b=1)
+    r = np.random.RandomState(7)
+    q, k, v, g, beta = args
+    if case == "g=-30":
+        g = jnp.full_like(g, -30.0)
+    else:
+        g = jnp.asarray(np.where(r.rand(*g.shape) < 0.5, -1e-4, -40.0),
+                        jnp.float32)
+    beta = jnp.asarray(np.where(r.rand(*beta.shape) < 0.5, 0.01, 1.99),
+                       jnp.float32)
+    return (q, k, v, g, beta), go
+
+
+# a decay is a difference of a chunk's running sums of g: its exponent
+# carries the rounding of |G|, half an ulp of up to 64 x 40 = 2,560
+# (1.2e-4) in the mixed case, where the recurrence multiplies e^g of
+# one token exactly.  3e-5 to 7e-5 seen, the same under every impl
+UNBOUNDED_TOL = {"g=-30": TOL, "mixed": 4 * TOL}
+
+
+@pytest.mark.parametrize("case", ["g=-30", "mixed"])
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_an_unbounded_decay_is_finite_and_the_recurrence(impl, case):
+    """decay "unbounded": forward and the five gradients equal the
+    token-by-token recurrence for g far below the other path's bound
+    and for write strengths near 0 and near 2."""
+    args, go = _unbounded_case(case)
+    want_o, want_g = reference(args, go)
+    outs, grads = scan_and_grads(
+        args, go, {"chunk_size": 64, "block_chunks": 2, "impl": impl,
+                   "decay": "unbounded"})
+    assert bool(jnp.isfinite(outs["O"]).all())
+    assert all(bool(jnp.isfinite(g).all()) for g in grads.values())
+    tol = UNBOUNDED_TOL[case]
+    assert rel(outs["O"], want_o) <= tol
+    errors = {s: rel(grads[s + "@GRAD"], g) for s, g in zip(SLOTS, want_g)}
+    # at -30 a token d G is e^-30-small itself: held absolutely, as at
+    # the other path's bound
+    errors["G"] = float(jnp.abs(grads["G@GRAD"] - want_g[3]).max()) \
+        / max(float(jnp.abs(want_g[3]).max()), 0.2)
+    assert all(e <= tol for e in errors.values()), errors
+    # the state crosses tokens in the mixed run: the steps that keep
+    # everything carry it
+    if case == "mixed":
+        lost_o, _ = reference(args, go, reset_every=1)
+        assert rel(lost_o, want_o) > 100 * tol
+
+
+@pytest.mark.parametrize("case", ["g=-30", "mixed"])
+def test_the_plain_references_recurrence_is_the_same_one(case):
+    """benchmarks/reference/solar_open2.py's token-by-token scan, the
+    third impl a cell's `correct` rests on, on the same operands."""
+    from conftest import load_reference
+
+    args, go = _unbounded_case(case)
+    want_o, _ = reference(args, go)
+    ref = load_reference("solar_open2")
+    q, k, v, g, beta = (a[0] for a in args)
+    with jax.default_matmul_precision("highest"):
+        o = ref.delta_recurrence(*(x.reshape(128, 2, 128)
+                                   for x in (q, k, v, g)), beta)
+    assert bool(jnp.isfinite(o).all())
+    assert rel(o.reshape(1, 128, 256), want_o) <= TOL
+
+
+def test_the_bounded_path_is_wrong_past_its_bound():
+    """What the attr is for: at g = -30 the kernels' bounded path
+    (`ling3`'s, sound for g >= -5.33) clamps a column's factor at e^80
+    while the row's has underflowed: a wrong output, by far more than
+    any tolerance.  The XLA form takes each pair's own difference and
+    is right under either promise."""
+    from paddle_tpu.ops import pallas_kda
+
+    assert pallas_kda.BOUNDED_G_MIN == pytest.approx(-80.0 / 15)
+    args, go = _unbounded_case("g=-30")
+    want_o, _ = reference(args, go)
+    attrs = {"chunk_size": 64, "block_chunks": 2, "decay": "bounded"}
+    wrong, _ = scan_and_grads(args, go, dict(attrs, impl="interpret"))
+    assert rel(wrong["O"], want_o) > 1000 * TOL
+    right, _ = scan_and_grads(args, go, dict(attrs, impl="xla"))
+    assert rel(right["O"], want_o) <= TOL
+    with pytest.raises(ValueError, match="neither bounded"):
+        scan_and_grads(args, go, dict(attrs, impl="xla", decay="some"))
+
+
+@pytest.mark.parametrize("t,chunk,block_chunks", SHAPES)
+def test_the_unbounded_path_on_every_shape(t, chunk, block_chunks):
+    """The levels inside a sub-block at every chunking the kernels
+    take (16, 32 and 64-row chunks: one to four sub-blocks), on the
+    wide draw of g the bounded path is tested on: the two paths agree
+    where both are sound."""
+    args, go = operands(t, b=1)
+    want_o, want_g = reference(args, go)
+    outs, grads = scan_and_grads(
+        args, go, {"chunk_size": chunk, "block_chunks": block_chunks,
+                   "impl": "interpret", "decay": "unbounded"})
+    assert rel(outs["O"], want_o) <= TOL
+    errors = {s: rel(grads[s + "@GRAD"], g) for s, g in zip(SLOTS, want_g)}
+    assert all(e <= TOL for e in errors.values()), errors
+
+
+def _kernel_ops(fn, *avals, **static):
+    """{primitive: count} of a kernel entry's jaxpr, its body's
+    included."""
+    import collections
+    import re
+
+    text = str(jax.make_jaxpr(lambda *a: fn(*a, **static))(*avals))
+    found = collections.Counter()
+    for a, b in re.findall(r"= (\w+)\[|= (\w+) ", text):
+        found[a or b] += 1
+    return found
+
+
+def test_the_bounded_kernels_are_the_ones_from_before_the_attr():
+    """`ling3`'s path: the bounded kernels' bodies hold what they held
+    at the parent commit (PR 48: 20 and 32 products, 11 and 22
+    exponentials a chunk walk, no roll), so that cell's compiled
+    kernels are the parent's; the unbounded bodies add the four levels
+    (4 products forward; 4 in the backward's replay and 2 x 4 in its
+    walk) and the references' rolls."""
+    from paddle_tpu.ops import pallas_kda
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    sds = jax.ShapeDtypeStruct
+    x = sds((1, 512, 256), bf16)
+    ins = (x, x, x, sds((1, 512, 256), f32), sds((1, 512, 2), f32))
+    kept = (sds((1, 2, 256, 128), f32), sds((1, 2, 2, 64, 256), f32), x)
+    sizes = dict(chunk=64, block_chunks=4)
+    fwd = _kernel_ops(pallas_kda.kda_fwd_pallas, *ins, **sizes)
+    bwd = _kernel_ops(pallas_kda.kda_bwd_pallas, *ins, *kept, **sizes)
+    assert (fwd["dot_general"], fwd["exp"], fwd["roll"]) == (20, 11, 0)
+    assert (bwd["dot_general"], bwd["exp"], bwd["roll"]) == (32, 22, 0)
+    # bounded is the default, and asking for it changes nothing
+    assert fwd == _kernel_ops(pallas_kda.kda_fwd_pallas, *ins, **sizes,
+                              bounded=True)
+    fwd_u = _kernel_ops(pallas_kda.kda_fwd_pallas, *ins, **sizes,
+                        bounded=False)
+    bwd_u = _kernel_ops(pallas_kda.kda_bwd_pallas, *ins, *kept, **sizes,
+                        bounded=False)
+    assert fwd_u["dot_general"] == 20 + 4 and fwd_u["roll"] > 0
+    assert bwd_u["dot_general"] == 32 + 4 + 2 * 4 and bwd_u["roll"] > 0
+
+
+def test_the_gate_that_made_g_chooses_the_scans_path():
+    """layers.kda_scan reads what layers.kda_gate promises: the sigmoid
+    form at a bound the kernels' one product a sub-block is sound for
+    -> "bounded"; the softplus form, a lower bound below -5.33, or a g
+    of any other origin -> "unbounded"."""
+    _fresh()
+    x = layers.data("x", shape=[64, 256], dtype="float32")
+    b = layers.data("b", shape=[64, 2], dtype="float32")
+
+    def decay_of(g):
+        layers.kda_scan(x, x, x, g, b, chunk_size=16, block_chunks=2)
+        return fluid.default_main_program().global_block().ops[-1].attrs[
+            "decay"]
+
+    assert decay_of(layers.kda_gate(x, 2, name="g1")) == "bounded"
+    assert decay_of(layers.kda_gate(x, 2, lower_bound=-5.3,
+                                    name="g2")) == "bounded"
+    assert decay_of(layers.kda_gate(x, 2, lower_bound=-8.0,
+                                    name="g3")) == "unbounded"
+    assert decay_of(layers.kda_gate(x, 2, form="softplus",
+                                    name="g4")) == "unbounded"
+    assert decay_of(x) == "unbounded"
+    with pytest.raises(ValueError, match="form"):
+        layers.kda_gate(x, 2, form="relu", name="g5")
+    # a desc from before the attr is the bounded one it was built as
+    assert get_op_def("kda_scan").canonical_attrs({})["decay"] == "bounded"
+
+
 def test_states_are_the_transposed_state_each_block_starts_from():
     args, _ = operands(64, b=1)
     outs = get_op_def("kda_scan").compute(
@@ -337,6 +516,7 @@ def test_sizes_the_kernels_cannot_tile_run_the_xla_form():
         args, go, {"chunk_size": 16, "block_chunks": 1,
                    "impl": "interpret"})
     assert _since(before) == {("kda_scan", "xla"): 2,
+                              ("kda_scan_decay", "bounded"): 2,
                               ("kda_scan_grad", "recompute"): 1}
     assert rel(outs["O"], want_o) <= TOL
     assert all(rel(grads[s + "@GRAD"], g) <= TOL
@@ -486,7 +666,8 @@ def test_any_residual_unbound_runs_the_forward_again_for_both(unbound):
     before = _counts()
     got = grad.compute(bound, attrs)
     assert _since(before) == {("kda_scan_grad", "recompute"): 1,
-                              ("kda_scan", "interpret"): 1}
+                              ("kda_scan", "interpret"): 1,
+                              ("kda_scan_decay", "bounded"): 1}
     assert all(bool(jnp.array_equal(got[k], want[k])) for k in want)
 
 
@@ -614,6 +795,51 @@ def test_kda_gate_is_bounded_and_is_the_formula():
                    {"lower_bound": 5.0})
 
 
+def test_kda_gate_softplus_form_has_no_bound_and_is_the_formula():
+    r = np.random.RandomState(1)
+    x = jnp.asarray(30 * r.randn(2, 8, 4 * 16), jnp.float32)
+    a_log = jnp.asarray(r.uniform(0, 1.4, 4), jnp.float32)
+    bias = jnp.asarray(r.randn(64), jnp.float32)
+    op = get_op_def("kda_gate")
+    before = _counts()
+    g = op.compute({"X": x, "ALog": a_log, "DtBias": bias},
+                   {"form": "softplus"})["G"]
+    assert _since(before) == {("kda_gate_form", "softplus"): 1}
+    z = np.asarray(x, np.float64) + np.asarray(bias, np.float64)
+    want = -np.repeat(np.exp(np.asarray(a_log, np.float64)), 16) \
+        * np.logaddexp(0.0, z)
+    np.testing.assert_allclose(g, want, rtol=1e-5, atol=1e-6)
+    assert g.dtype == jnp.float32 and float(g.max()) <= 0.0
+    # far below the other form's bound
+    assert float(g.min()) < -100.0
+    assert op.compute({"X": x.astype(jnp.bfloat16), "ALog": a_log,
+                       "DtBias": bias}, {"form": "softplus"}
+                      )["G"].dtype == jnp.float32
+    before = _counts()
+    op.compute({"X": x, "ALog": a_log, "DtBias": bias}, {})
+    assert _since(before) == {("kda_gate_form", "sigmoid_bound"): 1}
+    with pytest.raises(ValueError, match="form"):
+        op.compute({"X": x, "ALog": a_log, "DtBias": bias},
+                   {"form": "relu"})
+
+
+def test_kda_gate_softplus_starts_with_channels_that_keep_their_state():
+    """The softplus form starts where the sigmoid form does: -g
+    log-uniform over [1e-4, 1e-1] a channel at input 0."""
+    _fresh()
+    np.random.seed(3)
+    x = layers.data("x", shape=[4, 8 * 128], dtype="float32")
+    g = layers.kda_gate(x, 8, name="decay", form="softplus")
+    assert g.kda_decay_bound is None
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    got, = exe.run(feed={"x": np.zeros((1, 4, 1024), np.float32)},
+                   fetch_list=[g])
+    g0 = -np.asarray(got)[0, 0]
+    assert 0.9e-4 < g0.min() < 2e-4 and 0.5e-1 < g0.max() < 1.1e-1
+    assert np.mean(np.exp(-2000 * g0) > 1 / 3) > 0.1
+
+
 def test_kda_gate_starts_with_channels_that_keep_their_state():
     """At input 0 the layer's initial gate spreads -g log-uniformly
     over [1e-4, 1e-1] a channel: a tenth of the channels keep more than
@@ -673,6 +899,51 @@ def test_head_gated_rms_norm_gates_a_head_after_the_norm():
         rtol=1e-5, atol=1e-6)
 
 
+def test_head_gated_rms_norm_gates_a_channel():
+    """A gate as wide as X: one logit a CHANNEL, after the norm a
+    head; without a scale the gate alone (a grouped-KV attention
+    layer's output gate)."""
+    r = np.random.RandomState(4)
+    x = jnp.asarray(r.randn(2, 5, 3 * 8), jnp.float32)
+    gate = jnp.asarray(r.randn(2, 5, 3 * 8), jnp.float32)
+    scale = jnp.asarray(r.uniform(0.5, 1.5, 8), jnp.float32)
+    op = get_op_def("head_gated_rms_norm")
+    y = op.compute({"X": x, "Gate": gate, "Scale": scale},
+                   {"epsilon": 1e-6, "n_head": 3})["Y"]
+    xh = np.asarray(x).reshape(2, 5, 3, 8)
+    normed = (xh / np.sqrt((xh ** 2).mean(-1, keepdims=True) + 1e-6)
+              * scale).reshape(2, 5, 24)
+    sig = 1 / (1 + np.exp(-np.asarray(gate)))
+    np.testing.assert_allclose(y, sig * normed, rtol=1e-5, atol=1e-6)
+    # a gate a channel is not a gate a head: the head's mean logit
+    # gives another output
+    a_head = op.compute(
+        {"X": x, "Gate": gate.reshape(2, 5, 3, 8).mean(-1),
+         "Scale": scale}, {"epsilon": 1e-6})["Y"]
+    assert float(jnp.abs(a_head - y).max()) > 0.1
+    y = op.compute({"X": x, "Gate": gate}, {"epsilon": 1e-6})["Y"]
+    np.testing.assert_allclose(y, sig * np.asarray(x), rtol=1e-5,
+                               atol=1e-6)
+    got = op.compute({"X": x.astype(jnp.bfloat16), "Gate": gate,
+                      "Scale": scale}, {"epsilon": 1e-6, "n_head": 3})["Y"]
+    assert got.dtype == jnp.bfloat16
+    # the layer: a gate a channel takes the heads beside it
+    _fresh()
+    xv = layers.data("x", shape=[5, 24], dtype="float32")
+    gv = layers.data("g", shape=[5, 24], dtype="float32")
+    hv = layers.data("h", shape=[5, 3], dtype="float32")
+    layers.head_gated_rms_norm(xv, gv, n_head=3, name="n1")
+    block = fluid.default_main_program().global_block()
+    assert block.var("n1.w").shape == (8,)
+    layers.head_gated_rms_norm(xv, gv, norm=False)
+    assert block.ops[-1].attrs["n_head"] == 0 \
+        and "Scale" not in block.ops[-1].inputs
+    with pytest.raises(ValueError, match="one of a gate and n_head"):
+        layers.head_gated_rms_norm(xv, hv, n_head=3)
+    with pytest.raises(ValueError, match="one of a gate and n_head"):
+        layers.head_gated_rms_norm(xv, None, n_head=3, norm=False)
+
+
 # -- tools/kda_price.py, on no chip ------------------------------------------
 
 def test_the_price_tool_rehearses_on_a_cpu(tmp_path):
@@ -711,3 +982,45 @@ def test_the_price_tool_rehearses_on_a_cpu(tmp_path):
     diff = rows[-1]["diff"]
     assert diff["o"] == diff["states"] == 0.0
     assert all(diff[g] > 0 for g in ("dq", "dk", "dv", "dg", "dbeta"))
+
+
+# -- tools/kda_unbounded_chip.py, on no chip ----------------------------------
+
+def test_the_unbounded_chip_tool_rehearses_on_a_cpu(tmp_path):
+    """`--tiny` (interpret mode): the unbounded path equals the tool's
+    own token-by-token recurrence in the three cases and both operand
+    dtypes (exit 0: every float32 case inside this file's tolerances),
+    and the bounded path at g = -30 is shown wrong by the same
+    comparison."""
+    import importlib.util
+    import json
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "kda_unbounded_chip",
+        os.path.join(repo, "tools", "kda_unbounded_chip.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert (tool.TOL["g=-30"], tool.TOL["mixed"]) == (
+        TOL, UNBOUNDED_TOL["mixed"])
+    out = str(tmp_path / "rows.json")
+    assert tool.main(["--tiny", "--out", out]) == 0
+    rows = json.load(open(out))["rows"]
+    held = [r for r in rows if r["decay"] == "unbounded"]
+    assert {(r["case"], r["dtype"]) for r in held} == {
+        (c, d) for c in ("g=-30", "mixed", "cell")
+        for d in ("float32", "bfloat16")}
+    assert all(r["finite"] for r in rows)
+    assert all(r["worst"] <= tool.TOL[r["case"]] for r in held
+               if r["dtype"] == "float32")
+    bounded = {r["case"]: r for r in rows if r["decay"] == "bounded"}
+    assert bounded["g=-30"]["errors"]["O"] > 100 * TOL
+    assert bounded["cell"]["worst"] <= TOL
+    # the tool's recurrence is the tests' own
+    args, _ = _unbounded_case("mixed")
+    with jax.default_matmul_precision("highest"):
+        assert rel(tool.recurrence(*(jnp.tile(a, (1, 2, 1))
+                                     for a in args)),
+                   token_by_token(*(jnp.tile(a, (1, 2, 1))
+                                    for a in args))) <= 1e-6

@@ -47,10 +47,11 @@ def test_bench_workload_lowers_for_tpu(chip_gate, workload):
 @pytest.mark.parametrize("workload,flash_ops", [
     ("xing4_train_tiny", 5), ("ouro_train_tiny", 24),
     ("dsv2_train_tiny", 5), ("granite_train_tiny", 1),
-    ("ling3_train_tiny", 1), ("lfm2_train_tiny", 1)])
+    ("ling3_train_tiny", 1), ("lfm2_train_tiny", 1),
+    ("solar_open2_train_tiny", 1)])
 def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         chip_gate, workload, flash_ops):
-    """The six cells that train under RecomputeOptimizer, at their
+    """The seven cells that train under RecomputeOptimizer, at their
     depth and head sizes, narrow and short: a segment's backward takes
     the forward's Out and LSE (ISSUE 33), so the compiled step holds
     one `pt_flash_fwd` a flash op and not a second in every segment's
@@ -107,6 +108,35 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert workload in chip_gate.MOE_COMBINE_KERNEL
         assert detail["moe_ops"] == 6
         assert detail["kernel_calls"]["pt_moe_combine"] == 12
+    if workload == "solar_open2_train_tiny":
+        # one period of solar-open2-250b as a rank holds it, at its
+        # head sizes, chunking, taps, router (320 outputs) and expert
+        # width (ISSUE 49): one gated attention layer at 2 query heads
+        # on ONE KV head of 128; three KDA scans on the path that is
+        # exact for an unbounded decay (its levels' sublane rolls
+        # through Mosaic), each forward kernel ONCE and each backward
+        # once; nine short convolutions; four expert layers' grouped
+        # matmuls at width 1,280 and their combines
+        assert workload in chip_gate.ONE_KDA_FWD_AN_OP
+        assert detail["kda_ops"] == 3
+        assert detail["kernel_calls"]["pt_kda_fwd"] == 3
+        assert detail["kernel_calls"]["pt_kda_bwd"] == 3
+        assert workload in chip_gate.CONV1D_KERNELS
+        assert detail["conv1d_ops"] == 9
+        assert detail["kernel_calls"]["pt_conv1d_fwd"] == 18
+        assert detail["kernel_calls"]["pt_conv1d_bwd"] == 9
+        assert detail["conv_scope_pads"] == 0
+        assert [detail["kernel_calls"][k] for k in (
+            "pt_gmm_fwd", "pt_gmm_bwd_dx", "pt_gmm_bwd_dw")] == [24, 12, 12]
+        assert workload in chip_gate.MOE_COMBINE_KERNEL
+        assert detail["moe_ops"] == 4
+        assert detail["kernel_calls"]["pt_moe_combine"] == 8
+        assert detail["tpu_custom_calls"] == 131
+        from paddle_tpu import framework
+
+        ops = framework.default_main_program().global_block().ops
+        assert {op.attrs["decay"] for op in ops
+                if op.type == "kda_scan"} == {"unbounded"}
     if workload == "lfm2_train_tiny":
         # the cell's five layers of lfm2-24b-a2b at its head size, taps,
         # router and expert width (ISSUE 45): four gated convolutions

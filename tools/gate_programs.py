@@ -283,6 +283,13 @@ def _build_lfm2_train(batch=1, seq=8192, **sizes):
                              sizes)
 
 
+def _build_solar_open2_train(batch=1, seq=8192, **sizes):
+    """One tensor- and expert-parallel rank's train step of
+    Solar-Open2-250B as the cell `solar_open2_train_s8k` runs it."""
+    return _build_cell_train("solar-open2-250b.json", "solar_open2.py",
+                             batch, seq, sizes)
+
+
 def _build_xing4_train(batch=1, seq=4096, **sizes):
     """The 2024-26 decoder block's train step as the cell
     `xing4_29b_train_s4k` runs it."""

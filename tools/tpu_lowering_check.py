@@ -170,6 +170,21 @@ def _workloads():
             1, 512, hidden_size=256, num_attention_heads=4,
             num_key_value_heads=2, intermediate_size=512,
             vocab_size=512)[:3],
+        # one tensor- and expert-parallel rank of Solar-Open2-250B at
+        # the cell's sizes (1 x 8,192 tokens at hidden 4,096: one gated
+        # attention layer at 8 / 1 heads of 128 and three KDA layers at
+        # 8 heads on the unbounded-decay path, 8 of 320 experts held at
+        # width 1,280, 841 M parameters): whether 13.45 GB of state and
+        # the step's activations fit (STEP_BYTES_MAX), and that each
+        # scan's forward kernel runs once (ONE_KDA_FWD_AN_OP)
+        "solar_open2_train": lambda: progs._build_solar_open2_train(
+            1, 8192)[:3],
+        # the published head sizes, chunking, taps, router (320
+        # outputs) and expert width, one period of layers, everything
+        # else narrow
+        "solar_open2_train_tiny": lambda: progs._build_solar_open2_train(
+            1, 512, hidden_size=256, num_attention_heads=2,
+            kda_heads_held=2, vocab_size=512)[:3],
         # both at the cells' depth and head sizes, narrow and short
         # (256 tokens; seconds to compile): what is checked is how many
         # kernels the step holds, not whether it fits
@@ -360,7 +375,7 @@ def _infer(progs, which, batch, conv_epilogue=False):
 
 FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train",
              "xing4_train", "dsv2_train", "granite_train", "ling3_train",
-             "lfm2_train")
+             "lfm2_train", "solar_open2_train")
 
 # the steps whose attention takes q, k and v token-major, [B, T, H*d]
 # as the projections leave them: their compiled step may hold no head
@@ -390,7 +405,8 @@ ONE_FLASH_FWD_AN_OP = ("transformer_train", "transformer_train_gspmd",
                        "dsv2_train_tiny", "granite_train",
                        "granite_train_tiny", "ling3_train",
                        "ling3_train_tiny", "lfm2_train",
-                       "lfm2_train_tiny")
+                       "lfm2_train_tiny", "solar_open2_train",
+                       "solar_open2_train_tiny")
 
 # the training steps whose every ssd_scan op has a grad that reads the
 # forward's Y and chunk-start states inside its recompute segment: the
@@ -405,7 +421,8 @@ ONE_SSD_FWD_AN_OP = ("granite_train", "granite_train_tiny")
 # recompute segment: the forward kernel runs once an op (6 in the
 # cell's step; 12 if the segment replayed it, 18 if the grad op ran it
 # again too), and the backward kernel once
-ONE_KDA_FWD_AN_OP = ("ling3_train", "ling3_train_tiny")
+ONE_KDA_FWD_AN_OP = ("ling3_train", "ling3_train_tiny",
+                     "solar_open2_train", "solar_open2_train_tiny")
 
 
 # the training steps whose every causal_conv1d op runs the kernels of
@@ -415,7 +432,8 @@ ONE_KDA_FWD_AN_OP = ("ling3_train", "ling3_train_tiny")
 # 18 a ling3 step: 36 + 18; 4 an lfm2 step, gated: 8 + 4), and the XLA
 # graph's float32 pad of X is gone from the op's scope
 CONV1D_KERNELS = ("granite_train", "granite_train_tiny", "ling3_train",
-                  "ling3_train_tiny", "lfm2_train", "lfm2_train_tiny")
+                  "ling3_train_tiny", "lfm2_train", "lfm2_train_tiny",
+                  "solar_open2_train", "solar_open2_train_tiny")
 
 # the training steps whose causal_conv1d ops are gated -> their tokens
 # T: the kernels read the thirds of the [T, 3 C] projection in place
@@ -434,7 +452,8 @@ GATED_CONV_IN_PLACE = {"lfm2_train": 8192, "lfm2_train_tiny": 512}
 # code and the compiler drops it)
 MOE_COMBINE_KERNEL = ("xing4_train", "xing4_train_tiny", "dsv2_train",
                       "dsv2_train_tiny", "ling3_train", "ling3_train_tiny",
-                      "lfm2_train", "lfm2_train_tiny")
+                      "lfm2_train", "lfm2_train_tiny",
+                      "solar_open2_train", "solar_open2_train_tiny")
 
 
 def conv_scope_pads(hlo_text):
@@ -492,7 +511,13 @@ STEP_BYTES_MAX = {"dsv2_train": 9_700_000_000,
                   # PR 41 reads 13,062,109,696: 9.87 GB of weights and
                   # float32 Adam moments, 3.20 GB of gradients and a
                   # segment's activations
-                  "ling3_train": 13_100_000_000}
+                  "ling3_train": 13_100_000_000,
+                  # PR 49 reads 15,325,295,104 (the chip too, to the
+                  # byte): 10.09 GB of weights and float32 Adam
+                  # moments, 5.23 GB of gradients and a segment's
+                  # activations at 8,192 tokens x hidden 4,096; the
+                  # chip holds 15.75 GiB = 16.9e9
+                  "solar_open2_train": 15_400_000_000}
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", re.M)
 _CALLED = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
