@@ -303,11 +303,6 @@ def _group_layout(idx, held, tm):
             "n_active": n_active.astype(jnp.int32), "sizes": sizes}
 
 
-def _silu_and_grad(h):
-    sig = jax.nn.sigmoid(h)
-    return h * sig, sig * (1 + h * (1 - sig))
-
-
 # row tiles a step of _over_live_rows: 2,048 rows at 256 a tile.  Chosen
 # once on the chip (PERF.md, PR 37: 4, 8 and 16 tiles read within 2% of
 # each other at every live count; 8 and 16 a little ahead of 4)
@@ -331,7 +326,7 @@ def _over_live_rows(fn, outs, k, tm, impl, lay, *operands):
     (pallas_gmm.row_buffer) and the rows past the last chunk stay so:
     not defined, never read, like the rows a kernel's grid did not
     reach.  A jit with fn static: a step traces and lowers each of the
-    five loops once for its expert layers of one shape, forward and
+    three loops once for its expert layers of one shape, forward and
     replay alike (without, first_call_s +2.3 s in `dsv2`: PERF.md)."""
     from paddle_tpu.ops.pallas_gmm import row_buffer
 
@@ -358,28 +353,15 @@ def _token_rows(rows, k, lay, x):
     return jnp.where(rows(lay["row_live"])[:, None], picked, 0),
 
 
-def _swiglu_rows(rows, k, lay, hg, hu):
-    act = _silu_and_grad(rows(hg).astype(_F32))[0] * rows(hu).astype(_F32)
-    return act.astype(hg.dtype),
-
-
-def _cotangent_rows(rows, k, lay, gate, gf, hg, hu, ys):
-    """d ys, the SwiGLU output again, and each row's d gate: a row's
-    token's cotangent times the row's gate is d ys, and against the
-    row's expert output it is the pair's d gate."""
+def _cotangent_rows(rows, k, lay, gate, gf, ys):
+    """d ys and each row's d gate: a row's token's cotangent times the
+    row's gate is d ys, and against the row's expert output it is the
+    pair's d gate."""
     g_tok, = _token_rows(rows, k, lay, gf)
     row_gate = jnp.where(rows(lay["row_live"]), jnp.take(
         gate.reshape(-1), rows(lay["row_pair"])), 0.0)
-    return ((g_tok * row_gate[:, None]).astype(hg.dtype),
-            _swiglu_rows(rows, k, lay, hg, hu)[0],
+    return ((g_tok * row_gate[:, None]).astype(ys.dtype),
             jnp.sum(rows(ys).astype(_F32) * g_tok, axis=-1))
-
-
-def _swiglu_grad_rows(rows, k, lay, hg, hu, g_act):
-    silu, dsilu = _silu_and_grad(rows(hg).astype(_F32))
-    g = rows(g_act).astype(_F32)
-    return ((g * silu).astype(hg.dtype),
-            (g * rows(hu).astype(_F32) * dsilu).astype(hg.dtype))
 
 
 def _sum_rows(rows, k, lay, a, b):
@@ -430,9 +412,10 @@ def _routed_fwd(x, gate, wg, wu, wd, lay, k, tm, impl):
     """x [N, C], gate [N, k] float32 -> sum over a token's held experts
     of gate * SwiGLU_e(x), [N, C] in x's dtype.  Gathers only: a row
     gather lays the tokens out by expert, three grouped matmuls run
-    over the tiles that hold rows, and each token gathers its k rows
-    back.  Everything indexed by padded row, the kernels and the array
-    work between them (_over_live_rows), ends at the last row tile that
+    over the tiles that hold rows, the third forming SwiGLU of the
+    first two's outputs on its way in, and each token gathers its k
+    rows back.  Everything indexed by padded row, the kernels and the
+    gathers by row (_over_live_rows), ends at the last row tile that
     holds rows; what a row array holds past it is not defined, and
     nothing reads it.  Only the combine is by token (_combine)."""
     from paddle_tpu.ops.pallas_gmm import gmm
@@ -443,9 +426,7 @@ def _routed_fwd(x, gate, wg, wu, wd, lay, k, tm, impl):
                           lay, x)
     hg = gmm(xs, wg, tg, na, tm, impl)
     hu = gmm(xs, wu, tg, na, tm, impl)
-    act, = _over_live_rows(_swiglu_rows, ((hg.shape[1], dt),), k, tm, impl,
-                           lay, hg, hu)
-    ys = gmm(act, wd, tg, na, tm, impl)
+    ys = gmm((hg, hu), wd, tg, na, tm, impl)
     out = _combine(lay, ys, gate, dt, impl)
     return out, (x, gate, wg, wu, wd, lay, xs, hg, hu, ys)
 
@@ -457,16 +438,15 @@ def _routed_bwd(k, tm, impl, res, g_out):
     tg, na = lay["tile_group"], lay["n_active"]
     n_groups = wg.shape[0]
     dt = x.dtype
-    rows_c, rows_w = (x.shape[1], dt), (hg.shape[1], dt)
-    g_ys, act, row_dot = _over_live_rows(
-        _cotangent_rows, (rows_c, rows_w, (None, _F32)), k, tm, impl,
-        lay, gate, g_out.astype(_F32), hg, hu, ys)
+    g_ys, row_dot = _over_live_rows(
+        _cotangent_rows, ((x.shape[1], dt), (None, _F32)), k, tm, impl,
+        lay, gate, g_out.astype(_F32), ys)
     d_gate = jnp.where(lay["mine"], jnp.take(row_dot, lay["dest"]), 0.0)
-    d_wd = tgmm(act, g_ys, tg, na, tm, n_groups, impl)
-    g_act = gmm(g_ys, wd, tg, na, tm, impl, transpose_rhs=True)
-    g_hu, g_hg = _over_live_rows(
-        _swiglu_grad_rows, (rows_w, rows_w), k, tm, impl, lay, hg, hu,
-        g_act)
+    d_wd = tgmm((hg, hu), g_ys, tg, na, tm, n_groups, impl)
+    # d act stays the kernel's float32 accumulator: SwiGLU's gradient
+    # is what it writes
+    g_hg, g_hu = gmm(g_ys, wd, tg, na, tm, impl, transpose_rhs=True,
+                     gated=(hg, hu))
     d_wg = tgmm(xs, g_hg, tg, na, tm, n_groups, impl)
     d_wu = tgmm(xs, g_hu, tg, na, tm, n_groups, impl)
     # d x: each token's rows of the float32 sum of the two products
@@ -497,9 +477,10 @@ def moe_experts(ins, attrs):
     sorted by expert and go through three grouped matmuls
     (ops/pallas_gmm.py) over row arrays of a static worst-case size
     with run-time group sizes.  All work by padded row ends at the last
-    row tile that holds rows, n_active: a kernel's grid, and the
-    gathers, SwiGLU and sums between the kernels, which run in loops
-    over the live rows with a trip count read from n_active
+    row tile that holds rows, n_active: a kernel's grid (SwiGLU and
+    its gradient are formed inside the kernels, on their blocks), and
+    the two gathers by row and d x's sum of two products, which run in
+    loops over the live rows with a trip count read from n_active
     (_over_live_rows).  What a row array holds past that is not
     defined and not read.  There is no capacity: the worst case, every
     pair routed to one held expert, runs the same code with longer
@@ -527,6 +508,7 @@ def moe_experts(ins, attrs):
     tm = int(attrs["block_m"] or 256)
     held = tuple(int(e) for e in attrs["held"])
     pk._count_impl("moe_gmm", impl)
+    pk._count_impl("moe_swiglu", "xla" if impl == "xla" else "in_kernel")
     with jax.named_scope("pt_moe_experts"):
         lay = _group_layout(idx, held, tm)
         # the combine's kernel where it can take the call's shapes,

@@ -95,8 +95,8 @@ def _gmm(which, rows=16384, held=8, hidden=3584, width=1024, tm=256):
     """The grouped matmuls of 8 held experts at the worst-case number
     of rows (xing4: 4096 tokens x 4 experts each; dsv2: 8192 x 6 at an
     expert width of 11 x 128, taken whole).  The row-tile axis of each
-    grid is the run-time n_active.  All six calls of a layer, at both
-    cells' shapes: test_tpu_lowering_gate.py."""
+    grid is the run-time n_active.  All six call forms of a layer, at
+    the five cells' shapes: test_tpu_lowering_gate.py."""
     from paddle_tpu.ops.pallas_gmm import gmm_pallas, tgmm_pallas
 
     m = (rows // tm + held) * tm

@@ -525,12 +525,19 @@ def _old_xing4_attention(u, config, seq_len, fc, p, lp):
 # under RecomputeOptimizer(Adam) [+ AMP], recorded there before the
 # refactoring: the number of ops, a hash of their types in order, a hash
 # of the sorted parameter names, and the first two losses, which the
-# refactored tree gave to the last bit on the same machine
+# refactored tree gave to the last bit on the same machine.  The AMP
+# run's SECOND loss is PR 50's: moe_experts keeps d act, a grouped
+# matmul's float32 accumulator, where it rounded it to bfloat16 before
+# SwiGLU's gradient, so a bfloat16 step's d W_gate, d W_up and d x
+# moved in their last bits (4.608451843261719 before; the first loss,
+# and both float32 losses, are the record's).  That the new form is
+# the closer one to float32:
+# test_llm_ops.test_swiglu_in_the_kernels_is_no_further_from_float32_in_bf16
 _XING4_BEFORE = {
     False: (239, "574d01011dfe0835", 91, "bf9f7372699d7510",
             (4.847245216369629, 4.608573436737061)),
     True: (286, "23903eb1f615e51b", 91, "bf9f7372699d7510",
-           (4.847033500671387, 4.608451843261719)),
+           (4.847033500671387, 4.608583450317383)),
 }
 
 

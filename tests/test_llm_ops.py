@@ -542,8 +542,8 @@ def _routed_experts_over_all_rows():
     n_active (PR 36's tree): every gather, SwiGLU and sum over all M
     rows of the worst-case layout, the pairs gathered as one float32
     [N, k, C].  What the chunked form is held to, bit for bit."""
-    from paddle_tpu.ops.llm_ops import _F32, _silu_and_grad
-    from paddle_tpu.ops.pallas_gmm import gmm, tgmm
+    from paddle_tpu.ops.llm_ops import _F32
+    from paddle_tpu.ops.pallas_gmm import _silu_and_grad, gmm, tgmm
 
     def gather_rows(x, index, live):
         return jnp.where(live[:, None], jnp.take(x, index, axis=0), 0)
@@ -723,11 +723,12 @@ def test_moe_experts_row_work_is_bounded_by_n_active(pass_):
             shape = getattr(v.aval, "shape", ())
             assert name == "pallas_call" or not shape or shape[0] != m, \
                 "%s yields %s outside a loop" % (name, v.aval)
-    # forward: the gather and SwiGLU loops, their 2 buffers, 3 grouped
-    # matmuls, the combine; backward: those again (the vjp runs the
-    # forward), then three loops over 6 buffers, 6 grouped matmuls and
-    # d x's combine
-    assert (loops, kernels) == ((2, 6) if pass_ == "forward" else (5, 19))
+    # forward: the gather's loop, its buffer, 3 grouped matmuls, the
+    # combine; backward: those again (the vjp runs the forward), then
+    # the cotangent's loop over 2 buffers, d x's sum over 1, 6 grouped
+    # matmuls and d x's combine.  SwiGLU and its gradient are inside
+    # kernels
+    assert (loops, kernels) == ((1, 5) if pass_ == "forward" else (3, 15))
     assert [e.params["name"] for e in _pallas_calls(jaxpr)].count(
         "pt_moe_combine") == (1 if pass_ == "forward" else 2)
     for eqn in _all_eqns(jaxpr):
@@ -836,6 +837,179 @@ def test_tgmm_kernel_sums_a_groups_tiles(layout, widths, budget,
         got[groups[first]],
         np.asarray(lhs[rows]).T @ np.asarray(grad[rows]),
         rtol=1e-4, atol=1e-4)
+
+
+# what the kernels form on their own blocks (ISSUE 50): the form, and
+# which of an expert layer's hidden width C and expert width W is the
+# call's k (a gmm's contraction, a tgmm's lhs axis) and which its n
+GMM_FORMS = {"gmm_swiglu": "wc", "tgmm_swiglu": "wc",
+             "gmm_swiglu_grad": "cw"}
+
+
+def _form_case(form, k, n, groups, seed=8, dtype=jnp.float32):
+    """(operands of pallas_gmm's entry for `form`, as keywords) at tiles
+    of 16 rows: the row arrays hold values everywhere, live or not."""
+    rng = np.random.default_rng(seed)
+    tm, g = 16, groups[-1] + 1
+    m = len(groups) * tm
+
+    def rows(width):
+        return jnp.asarray(rng.normal(0, 1, (m, width)), dtype)
+
+    def weights(*shape):
+        return jnp.asarray(rng.normal(0, 0.1, (g,) + shape), dtype)
+
+    if form == "gmm_swiglu":            # ys = silu(hg) hu @ wd
+        return dict(lhs=(rows(k), rows(k)), rhs=weights(k, n))
+    if form == "tgmm_swiglu":           # d wd = (silu(hg) hu)^T @ d ys
+        return dict(lhs=(rows(k), rows(k)), grad=rows(n))
+    # d act = d ys @ wd^T -> d hg, d hu
+    return dict(lhs=rows(k), rhs=weights(n, k), transpose_rhs=True,
+                gated=(rows(n), rows(n)))
+
+
+def _run_form(pg, case, tile_group, n_active, impl, g):
+    case = dict(case)
+    if "grad" in case:
+        return pg.tgmm(case["lhs"], case["grad"], tile_group, n_active, 16,
+                       g, impl)
+    return pg.gmm(case.pop("lhs"), case.pop("rhs"), tile_group, n_active,
+                  16, impl, **case)
+
+
+@pytest.mark.parametrize("layout,widths", [
+    # W = 1,408 = 11 x 128 whole, beside C = 256
+    ("9_of_12", "1408_whole"),
+    # n_active 1 (one group, one live tile) and every tile live
+    ("1_of_6", "1408_whole"), ("6_of_6", "1408_whole"),
+    # the axis SwiGLU's blocks lie along in two blocks (a gmm's
+    # contraction: the prologue, and the epilogue's accumulator, over
+    # two grid steps; tgmm_swiglu's output rows)
+    ("9_of_12", "split"), ("1_of_6", "split"), ("6_of_6", "split")])
+@pytest.mark.parametrize("form", sorted(GMM_FORMS))
+def test_gmm_kernels_form_swiglu_and_its_gradient(
+        form, layout, widths, monkeypatch):
+    """Interpret mode against the XLA form, over the live rows: the
+    kernels' prologue (SwiGLU of a pair of blocks as the left operand)
+    and epilogue (SwiGLU's gradient from the float32 accumulator, two
+    outputs)."""
+    from paddle_tpu.ops import pallas_gmm as pg
+
+    groups, live_tiles = {**GMM_LAYOUTS, "1_of_6": ([0] * 6, 1),
+                          "6_of_6": ([0, 0, 1, 2, 2, 2], 6)}[layout]
+    if widths == "split":
+        k, n = 512, 128
+        # a budget that holds the call with k in halves and not whole
+        monkeypatch.setattr(pg, "_VMEM_BUDGET",
+                            pg._vmem_bytes(form, 16, 256, 128, 4))
+        assert pg._tiles(form, k, n, 16, 4) == (128, 256)
+    else:
+        c, w = 256, 1408
+        k, n = (w, c) if GMM_FORMS[form] == "wc" else (c, w)
+        assert pg._tiles(form, k, n, 16, 4) == (n, k)
+    g = groups[-1] + 1
+    case = _form_case(form, k, n, groups)
+    tile_group = jnp.asarray(groups, jnp.int32)
+    n_active = jnp.asarray([live_tiles], jnp.int32)
+    with jax.disable_jit():     # the patched budget, not a cached trace
+        got = _run_form(pg, case, tile_group, n_active, "interpret", g)
+    want = _run_form(pg, case, tile_group, n_active, "xla", g)
+    live = slice(None) if form == "tgmm_swiglu" \
+        else slice(0, live_tiles * 16)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a[live], b[live], rtol=1e-5, atol=3e-4)
+
+
+@pytest.mark.parametrize("impl", ["interpret", "xla"])
+@pytest.mark.parametrize("form", sorted(GMM_FORMS))
+def test_swiglu_in_the_kernels_is_no_further_from_float32_in_bf16(
+        form, impl):
+    """From bfloat16 operands, against the same products in float32
+    with nothing rounded on the way: each form is at least as close as
+    the array code between two kernels was (before ISSUE 50: SwiGLU
+    rounded to bfloat16 and then multiplied, which the kernels do too;
+    d act rounded to bfloat16 and then SwiGLU's gradient, where the
+    kernel keeps its float32 accumulator)."""
+    from paddle_tpu.ops import pallas_gmm as pg
+
+    groups, live_tiles = GMM_LAYOUTS["9_of_12"]
+    c, w = 256, 1408
+    k, n = (w, c) if GMM_FORMS[form] == "wc" else (c, w)
+    g = groups[-1] + 1
+    case = _form_case(form, k, n, groups, dtype=jnp.bfloat16)
+    tile_group = jnp.asarray(groups, jnp.int32)
+    n_active = jnp.asarray([live_tiles], jnp.int32)
+    live = slice(None) if form == "tgmm_swiglu" \
+        else slice(0, live_tiles * 16)
+    exact = _run_form(pg, jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32) if hasattr(a, "astype") else a,
+        case), tile_group, n_active, "xla", g)
+
+    def kernel_alone(lhs, rhs_or_grad, **kw):
+        if form == "tgmm_swiglu":
+            return pg.tgmm_pallas(lhs, rhs_or_grad, tile_group, n_active,
+                                  16, g, interpret=True)
+        return pg.gmm_pallas(lhs, rhs_or_grad, tile_group, n_active, 16,
+                             interpret=True, **kw)
+
+    if form == "gmm_swiglu_grad":
+        g_act = kernel_alone(case["lhs"], case["rhs"], transpose_rhs=True)
+        assert g_act.dtype == jnp.bfloat16
+        before = pg._swiglu_grad(*case["gated"], g_act.astype(jnp.float32))
+    else:
+        before = kernel_alone(pg._swiglu(*case["lhs"]),
+                              case.get("rhs", case.get("grad")))
+    got = _run_form(pg, case, tile_group, n_active, impl, g)
+    for a, was, want in zip(*map(jax.tree_util.tree_leaves,
+                                 (got, before, exact))):
+        assert a.dtype == jnp.bfloat16 and want.dtype == jnp.float32
+        err = jnp.abs(a.astype(jnp.float32) - want)[live]
+        err_before = jnp.abs(was.astype(jnp.float32) - want)[live]
+        scale = float(jnp.abs(want[live]).max())
+        assert float(err.max()) <= 2 ** -7 * scale
+        assert float(err.max()) <= float(err_before.max())
+        assert float(err.mean()) <= float(err_before.mean())
+        if form == "gmm_swiglu_grad":   # one rounding where two were
+            assert float(err.mean()) < 0.9 * float(err_before.mean())
+
+
+@pytest.mark.parametrize("impl,counted", [("interpret", "in_kernel"),
+                                          ("xla", "xla")])
+def test_moe_experts_with_swiglu_in_the_kernels_against_the_dense_loop(
+        impl, counted):
+    """At an expert width of 1,408: experts that are not held, a held
+    expert nobody is routed to (its tile is live and all padding), Out
+    and every gradient against the dense loop over the held experts;
+    and the series that says where SwiGLU was formed."""
+    held = (2, 5, 7)
+    ins = _experts_case(13, w=1408, held=held, empty=5)
+    before = _impl_counts()
+
+    def run(diff):
+        out = _op("moe_experts", {**ins, **diff}, held=list(held),
+                  block_m=32, impl=impl)["Out"]
+        return (out * jnp.sin(out)).sum(), out
+
+    def ref(diff):
+        out = _dense_experts({**ins, **diff}, held)
+        return (out * jnp.sin(out)).sum(), out
+
+    diff = {k: ins[k] for k in DIFF}
+    (_, out), grads = jax.value_and_grad(run, has_aux=True)(diff)
+    since = _impl_since(before)
+    assert since[("moe_swiglu", counted)] == 1
+    assert [k for k in since if k[0] == "moe_swiglu"] \
+        == [("moe_swiglu", counted)]
+    (_, want), want_grads = jax.value_and_grad(ref, has_aux=True)(diff)
+    np.testing.assert_allclose(out, want, rtol=1e-4,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+    for k in DIFF:
+        scale = float(jnp.abs(want_grads[k]).max())
+        np.testing.assert_allclose(grads[k], want_grads[k], rtol=1e-4,
+                                   atol=1e-5 * scale, err_msg=k)
+    assert not np.asarray(grads["WGate"])[1].any()      # the empty expert
 
 
 def _pallas_calls(jaxpr):
