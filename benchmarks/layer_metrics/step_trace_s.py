@@ -1,0 +1,23 @@
+"""Layer: build and compile.  `trace_ns` of the step program's first
+call, s: the Python time of running every op's compute
+symbolically under jit (CompiledProgram._build_fn's `step` around
+_run_block_symbolic).
+One of the three parts of first_call_s the program's step record keeps
+(`trace_ns`, `lower_ns`, `compile_ns`; the rest of first_call_s is
+jit's own tracing machinery, the cache key and the first launch).
+Source: the program's step record.  None on a program whose record has
+no such field.
+"""
+
+import os
+import runpy
+
+_sw = runpy.run_path(os.path.join(os.path.dirname(__file__),
+                                  "_step_window.py"))
+
+
+def read(m):
+    r = _sw["first_call"]()
+    if r is None or "trace_ns" not in r:
+        return None
+    return r["trace_ns"] / 1e9

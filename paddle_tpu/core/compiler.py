@@ -717,7 +717,8 @@ class CompiledProgram:
             env = _TraceEnv()
             env.update(state)
             env.update(feeds)
-            _run_block_symbolic(program, 0, env)
+            with _obs_steps.first_call_trace():
+                _run_block_symbolic(program, 0, env)
             new_state = {k: env[k] for k in state_names}
             fetches = [env[f] for f in fetch_names]
             return new_state, fetches
@@ -758,6 +759,47 @@ class CompiledProgram:
                 donate_argnums=donate,
             )
         return jax.jit(step, donate_argnums=donate)
+
+    def step_text(self, feed):
+        """The text of the executable the cached step runs for this
+        feed: the jitted step lowered with the avals of the state the
+        scope holds now and compiled, which finds the executable jit
+        already has, so nothing compiles.  Its instructions' `op_name` metadata is
+        what observability/step_owners.py reads.  Raises unless exactly
+        one cached step matches."""
+        import jax
+
+        from paddle_tpu.core.scope import global_scope
+
+        block = self._program.global_block()
+        feeds = {}
+        for name, val in feed.items():
+            dtype = val.dtype if hasattr(val, "dtype") \
+                else np.asarray(val).dtype
+            if block.has_var(name) and block.var(name).dtype is not None:
+                dtype = np.dtype(block.var(name).dtype)
+            feeds[name] = jax.ShapeDtypeStruct(
+                np.shape(val), jax.dtypes.canonicalize_dtype(dtype))
+        want = tuple(sorted((k, v.shape, str(v.dtype))
+                            for k, v in feeds.items()))
+        steps = [fn for key, fn in self._cache.items()
+                 if callable(fn) and key[0] == want]
+        if len(steps) != 1:
+            raise RuntimeError(
+                "step_text: %d cached steps take this feed (one for each "
+                "fetch list it ran with); run the step once first"
+                % len(steps))
+        state = {}
+        for n in self._persistable_names:
+            v = global_scope().find_var(n).get()
+            # a sharded step: with the sharding the array lives on (an
+            # aval carries its mesh, and without it the step is traced
+            # and compiled again, as another module than the one that
+            # ran); a one-device step: without, for the same reason
+            state[n] = jax.ShapeDtypeStruct(
+                np.shape(v), v.dtype,
+                sharding=v.sharding if self._mesh is not None else None)
+        return steps[0].lower(state, feeds).compile().as_text()
 
     def _globalize(self, feeds, state):
         """Multi-process path (reference: multi-trainer NCCL2 mode):
@@ -904,23 +946,16 @@ class CompiledProgram:
             _obs_flight.record(
                 "executor", "compile",
                 n_feeds=len(feed_specs), n_fetch=len(fetch_names))
-            if _obs_trace._tracer is not None:
-                # the device-trace annotation carries the active trace
-                # id into the jax.profiler timeline (ISSUE 10) — the
-                # span puts the ctx on the thread-local stack first,
-                # so annotate() picks it up
-                with _obs_trace._tracer.span("executor.compile"), \
-                        _obs_device.annotate("executor.compile"):
-                    fn = self._build_fn(
-                        list(feeds), feed_specs, fetch_names,
-                        state_specs, feed_shardings=feed_shardings)
-            else:
-                fn = self._build_fn(list(feeds), feed_specs,
-                                    fetch_names, state_specs,
-                                    feed_shardings=feed_shardings)
+            fn = self._build_fn(list(feeds), feed_specs, fetch_names,
+                                state_specs, feed_shardings=feed_shardings)
             self._cache[key] = fn
+            # the trace, the lowering and the compile (or the load
+            # from the persistent cache) happen inside the first call
+            # of fn, below: the record says what each took
+            call = _obs_steps.first_call(rec, fn)
             rec.stamp("built")
         else:
+            call = fn
             rec.fields["built"] = rec.fields["key"]
         if self._mesh is not None and not multiproc:
             # conform state arrays to the declared in_shardings BEFORE
@@ -954,11 +989,14 @@ class CompiledProgram:
                     state[k] = jax.device_put(v, sh)
         rec.stamp("conformed", phase="executor.dispatch")
         if _obs_trace._tracer is not None:
+            # the span puts its context on the thread-local stack
+            # first, so the device annotation carries the active trace
+            # id into the jax.profiler timeline (ISSUE 10)
             with _obs_trace._tracer.span("executor.step"), \
                     _obs_device.annotate("executor.step"):
-                new_state, fetches = fn(state, feeds)
+                new_state, fetches = call(state, feeds)
         else:
-            new_state, fetches = fn(state, feeds)
+            new_state, fetches = call(state, feeds)
         rec.stamp("dispatched", phase="executor.commit")
         # host enqueue time, not a step time: the device has not
         # finished when fn returns

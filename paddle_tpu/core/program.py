@@ -16,7 +16,6 @@ reference: an op attribute holding a block index.
 
 from __future__ import annotations
 
-import contextlib
 import copy
 import json
 from typing import Optional
@@ -67,13 +66,17 @@ def _bump_ir_mutation():
 
 
 def op_scope(op):
-    """The context an op's compute runs in: jax.named_scope of the
-    name scope it was appended under, or nothing."""
-    if not op.scope:
-        return contextlib.nullcontext()
+    """The context an op's compute runs in, at the two sites that run
+    one (core/compiler.py `_run_block_symbolic`, the replay of a
+    recompute segment in ops/misc.py): jax.named_scope of its owner,
+    `pt_<op_role>.<type>`, then of the name scope it was appended
+    under.  The compiled step's instructions carry the path as
+    `op_name` metadata (observability/step_owners.py reads it); the
+    module itself does not depend on it."""
     import jax
 
-    return jax.named_scope(op.scope)
+    owner = "pt_%s.%s" % (op.op_role, op.type)
+    return jax.named_scope(owner + "/" + op.scope if op.scope else owner)
 
 
 class pipeline_stage:

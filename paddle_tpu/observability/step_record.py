@@ -39,6 +39,20 @@ and ``done`` (when it was closed and appended):
                   and with it the previous step's state arrays (their
                   buffers were donated to the step)
 
+    A record whose ``first_call`` is true also holds what that call's
+    ``dispatched - conformed`` was spent on (``first_call`` below), ns:
+
+      trace_ns    the Python time of running every op's compute
+                  symbolically (``_build_fn``'s ``step`` under jit)
+      lower_ns    jax's ``jaxpr_to_mlir_module_duration`` events
+      compile_ns  jax's ``backend_compile_duration`` events: the XLA
+                  compile, or the load from the persistent cache
+      cache_hit   a ``/jax/compilation_cache/cache_hits`` event fired
+
+    The three are disjoint and sum to less than ``dispatched -
+    conformed``; the rest is jit's own tracing machinery, the cache key
+    and the first launch.  A call that hits the jit cache holds none.
+
 ``put``  one per batch in ``DeviceFeeder``'s transfer thread:
     ``host_wait`` (ns blocked waiting for the producer), ``start`` /
     ``end`` around the ``jax.device_put`` calls (host issue time: the
@@ -59,13 +73,15 @@ session a TraceAnnotation is a flag test.
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import threading
 import time
 
 from paddle_tpu.observability import device_trace as _device
 
-__all__ = ["MAXLEN", "Record", "records", "clear"]
+__all__ = ["MAXLEN", "Record", "records", "clear", "first_call",
+           "first_call_trace"]
 
 MAXLEN = 4096
 _now = time.perf_counter_ns
@@ -110,6 +126,83 @@ class Record:
             self._open = None
         self.fields["done"] = _now()
         _ring.append(self.fields)
+
+
+# -- the first call's parts ---------------------------------------------------
+
+_DURATIONS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_ns",
+    "/jax/core/compile/backend_compile_duration": "compile_ns",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# .fields: the record of the first call that is under way on this
+# thread, outside its trace; jax fires its events on the calling thread
+_first = threading.local()
+_listen_lock = threading.Lock()
+_listening = False
+
+
+def _on_duration(event, secs, **_):
+    fields = getattr(_first, "fields", None)
+    if fields is not None and event in _DURATIONS:
+        fields[_DURATIONS[event]] += int(secs * 1e9)
+
+
+def _on_event(event, **_):
+    fields = getattr(_first, "fields", None)
+    if fields is not None and event == _CACHE_HIT:
+        fields["cache_hit"] = True
+
+
+def _listen():
+    """Registers the two listeners on jax's monitoring events, once a
+    process and not before a step is first called."""
+    global _listening
+    with _listen_lock:
+        if not _listening:
+            import jax.monitoring as mon
+
+            mon.register_event_duration_secs_listener(_on_duration)
+            mon.register_event_listener(_on_event)
+            _listening = True
+
+
+def first_call(rec, fn):
+    """`fn`, a jitted step that has not run yet, wrapped for the ONE
+    call that traces, lowers and compiles it: the call's `run` record
+    gains `trace_ns`, `lower_ns`, `compile_ns` and `cache_hit`.  The
+    caller keeps `fn` itself for every later call."""
+    def call(*args):
+        _listen()
+        rec.fields.update(trace_ns=0, lower_ns=0, compile_ns=0,
+                          cache_hit=False)
+        _first.fields = rec.fields
+        try:
+            return fn(*args)
+        finally:
+            _first.fields = None
+
+    return call
+
+
+@contextlib.contextmanager
+def first_call_trace():
+    """Around the part of a jitted step's Python body that runs the
+    ops' compute: adds its time to `trace_ns` of the first call under
+    way on this thread.  Outside one (a `.lower()` from elsewhere, a
+    retrace) it writes nowhere.  What jax lowers or compiles while
+    the body runs is the trace's time, not counted again."""
+    fields = getattr(_first, "fields", None)
+    if fields is None:
+        yield
+        return
+    _first.fields = None
+    start = _now()
+    try:
+        yield
+    finally:
+        fields["trace_ns"] += _now() - start
+        _first.fields = fields
 
 
 def records(kind=None):

@@ -162,6 +162,64 @@ def device_trace(logdir="/tmp/paddle_tpu_trace"):
         jax.profiler.stop_trace()
 
 
+def device_op_table(logdir, compiled, feed, sorted_key="total"):
+    """The profiler(sorted_key=...) report of a compiled step, in
+    device time: reads the ONE .xplane.pb a `device_trace(logdir)` (or
+    any jax.profiler session) left under `logdir`, takes the first
+    device's `XLA Ops` events that start inside an execution of the
+    step module (the one with the most device time), joins each to the
+    Fluid op that owns its instruction (observability/step_owners.py
+    over `compiled.step_text(feed)`) and
+    prints `Event  Calls  Total(ms)  Ave(ms)  Share`, ms a step: a
+    pass (forward, replay, backward, optimize, other), then `role.type
+    [scope]` within it, what has no owner by the compiler's name.
+    Returns step_owners.device_time's rows (ns over all the steps)
+    and how many steps they cover."""
+    import bisect
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    from paddle_tpu.observability import step_owners
+
+    found = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if len(found) != 1:
+        raise RuntimeError("expected one .xplane.pb under %s, found %s"
+                           % (logdir, found))
+    planes = sorted((p for p in ProfileData.from_file(found[0]).planes
+                     if p.name.startswith("/device:")),
+                    key=lambda p: (len(p.name), p.name))
+    if not planes:
+        raise RuntimeError("%s holds no device plane: the step ran on "
+                           "the host" % found[0])
+    lines = {line.name: [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                         for e in line.events]
+             for line in planes[0].lines
+             if line.name in ("XLA Modules", "XLA Ops")}
+    runs = {}
+    for name, start, end in lines.get("XLA Modules", ()):
+        runs.setdefault(name, []).append((start, end))
+    if not runs:
+        raise RuntimeError("%s: no module ran on %s"
+                           % (found[0], planes[0].name))
+    step = sorted(max(runs.values(),
+                      key=lambda r: sum(e - s for s, e in r)))
+    starts = [s for s, _ in step]
+
+    def in_a_step(event):
+        i = bisect.bisect_right(starts, event[1]) - 1
+        return i >= 0 and event[1] < step[i][1]
+
+    rows = step_owners.device_time(
+        filter(in_a_step, lines.get("XLA Ops", ())),
+        step_owners.owners(compiled.step_text(feed)))
+    print(step_owners.format_table(rows, steps=len(step),
+                                   sorted_key=sorted_key))
+    return rows, len(step)
+
+
 def reset_profiler():
     if _prof_tracer is not None:
         _prof_tracer.clear()

@@ -417,12 +417,7 @@ def test_scopes_are_in_the_compiled_step():
     batch = _batch(SMALL)
     feed = {"src_ids": batch[0], "tgt_label": batch[1]}
     exe.run(compiled, feed=feed, fetch_list=[model["loss"]])
-    step, = [v for v in compiled._cache.values() if callable(v)]
-    state = {n: jax.ShapeDtypeStruct(np.shape(v), v.dtype) for n, v in
-             ((n, global_scope().find_var(n).get())
-              for n in compiled._persistable_names)}
-    text = step.lower(state, {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
-                              for k, v in feed.items()}).compile().as_text()
+    text = compiled.step_text(feed)
     for scope in ("pt_ut_step", "pt_exit_gate", "pt_loop_loss",
                   "pt_rms_norm", "pt_swiglu"):
         assert "/%s/" % scope in text, scope

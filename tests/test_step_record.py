@@ -126,6 +126,101 @@ def test_ring_is_bounded_and_reads_are_copies():
     assert step_record.records() == []
 
 
+# ---------------------------------------------------------------------------
+# the first call's parts (ISSUE 51)
+# ---------------------------------------------------------------------------
+
+PARTS = ("trace_ns", "lower_ns", "compile_ns")
+
+
+def test_first_call_record_holds_its_three_parts():
+    """The call that misses the jit cache says what it spent tracing,
+    lowering and compiling, the three disjoint and inside `dispatched -
+    conformed`; a call that hits the cache holds none; a `.lower()`
+    from outside a run runs `step`'s body again and writes nowhere."""
+    exe, compiled, loss = _tiny_program()
+    exe.run(fluid.default_startup_program())
+    for _ in range(3):
+        exe.run(compiled, feed=_feed(), fetch_list=[loss])
+    first, *later = step_record.records("run")
+    assert first["first_call"] is True
+    assert all(isinstance(first[k], int) and first[k] > 0 for k in PARTS)
+    assert first["cache_hit"] is False     # conftest keeps the cache off
+    assert sum(first[k] for k in PARTS) \
+        <= first["dispatched"] - first["conformed"]
+    for rec in later:
+        assert rec["first_call"] is False
+        assert not {"cache_hit", *PARTS} & set(rec)
+    # a second shape is a second first call, with parts of its own
+    exe.run(compiled, feed={"x": np.ones((3, 4), np.float32)},
+            fetch_list=[loss])
+    again = step_record.records("run")[-1]
+    assert again["first_call"] is True and again["trace_ns"] > 0
+
+    before = step_record.records()
+    traced = []
+    import paddle_tpu.core.compiler as compiler_mod
+    real = compiler_mod._run_block_symbolic
+
+    def spy(*a, **kw):
+        traced.append(1)
+        return real(*a, **kw)
+
+    compiler_mod._run_block_symbolic = spy
+    try:
+        step, = [fn for key, fn in compiled._cache.items()
+                 if callable(fn) and key[0][0][1] == (2, 4)]
+        state = {n: jax.ShapeDtypeStruct(np.shape(v), v.dtype)
+                 for n, v in ((n, fluid.global_scope().find_var(n).get())
+                              for n in compiled._persistable_names)}
+        # other avals than the run's: jit traces the body anew
+        step.lower(state, {"x": jax.ShapeDtypeStruct((5, 4), jnp.float32)})
+    finally:
+        compiler_mod._run_block_symbolic = real
+    assert traced == [1]
+    assert step_record.records() == before
+
+
+def test_first_call_parts_listen_on_their_own_thread_only():
+    """The listeners add what fires on the thread of the first call
+    under way, outside its trace: another thread's compile, and a
+    compile inside the trace (the trace's own time), add nothing."""
+    import threading
+
+    rec = step_record.Record("run", first_call=True)
+    seen = {}
+
+    def fn():
+        step_record._on_duration(
+            "/jax/core/compile/backend_compile_duration", 2.0)
+        step_record._on_duration(
+            "/jax/core/compile/jaxpr_to_mlir_module_duration", 0.5)
+        step_record._on_duration("/jax/core/compile/other", 9.0)
+        step_record._on_event("/jax/compilation_cache/cache_hits")
+        t = threading.Thread(target=step_record._on_duration, args=(
+            "/jax/core/compile/backend_compile_duration", 7.0))
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        with step_record.first_call_trace():
+            step_record._on_duration(
+                "/jax/core/compile/backend_compile_duration", 3.0)
+        seen.update(rec.fields)
+        return "out"
+
+    assert step_record.first_call(rec, fn)() == "out"
+    assert seen["compile_ns"] == 2_000_000_000
+    assert seen["lower_ns"] == 500_000_000
+    assert seen["cache_hit"] is True and seen["trace_ns"] > 0
+    # after the call: nothing listens, nothing is written
+    step_record._on_duration(
+        "/jax/core/compile/backend_compile_duration", 5.0)
+    with step_record.first_call_trace():
+        pass
+    assert rec.fields["compile_ns"] == 2_000_000_000
+    assert rec.fields["trace_ns"] == seen["trace_ns"]
+
+
 def test_run_phases_are_annotated_once_each_under_the_grammar(
         monkeypatch):
     """_run is cut into phases once: the stamps and the profiler

@@ -2,8 +2,10 @@
 
 Compiles a step, walks the HLO text, sizes every
 transpose/copy/bitcast-convert by its result shape, and aggregates by
-the op_name metadata JAX attaches — so each GB of layout traffic
-points back at a model layer or an inserted pass.  It reads HLO and
+the Fluid op that owns it (the op_name metadata JAX attaches, read by
+observability/step_owners.owner_of; the path itself where it names no
+owner) — so each GB of layout traffic points back at a model layer or
+an inserted pass.  It reads HLO and
 times nothing.
 
 Usage: python tools/hlo_traffic.py [--model resnet50|transformer]
@@ -30,6 +32,23 @@ _DTYPE_BYTES = {
 # e.g. "bf16[128,56,56,256]{3,2,1,0}" — capture dtype and dims
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _OPNAME_RE = re.compile(r'op_name="([^"]+)"')
+
+
+def owner_label(op_name):
+    """The group an instruction's traffic is booked under: the Fluid op
+    that owns it, `[<pass>] <role>.<type> [<scope>]`
+    (observability/step_owners.owner_of), so that the calls of one op
+    add up whatever jax primitive each came from; the op_name itself
+    where the path names no owner."""
+    from paddle_tpu.observability.step_owners import owner_of
+
+    who = owner_of(op_name)
+    if who.role is None:
+        return op_name
+    label = "%s.%s" % (who.role, who.type)
+    if who.step_pass != who.role:       # a segment's replay or gradient
+        label = who.step_pass + " " + label
+    return label + " [%s]" % who.scope if who.scope else label
 
 
 def shape_bytes(shape_str):
@@ -506,7 +525,7 @@ def main():
         key = (op, "fused" if fused else "TOP")
         total[key] += nbytes
         if not fused:
-            by_name[(op, name)] += nbytes
+            by_name[(op, owner_label(name))] += nbytes
 
     print("== layout-traffic totals (result bytes; traffic ~2x: r+w) ==")
     for (op, where), b in total.most_common():
@@ -514,12 +533,12 @@ def main():
                 if r[0] == op and (r[3] == (where == "fused")))
         print(f"  {op:16s} [{where:5s}] {n:4d} ops  {b/1e9:7.3f} GB")
 
-    print(f"\n== top {args.top} TOP-LEVEL (op, op_name) by bytes ==")
+    print(f"\n== top {args.top} TOP-LEVEL (op, owner) by bytes ==")
     for (op, name), b in by_name.most_common(args.top):
         if b < args.min_mb * 1e6:
             break
         n = sum(1 for r in rows
-                if r[0] == op and r[2] == name and not r[3])
+                if r[0] == op and owner_label(r[2]) == name and not r[3])
         print(f"  {b/1e9:7.3f} GB  {n:3d}x {op:10s} {name}")
 
     # full roofline attribution: every top-level op, result+operand
@@ -538,7 +557,7 @@ def main():
               f"{b/1e9:7.3f} GB")
     by_op = collections.Counter()
     for opcode, b, name in rr:
-        by_op[(opcode, name)] += b
+        by_op[(opcode, owner_label(name))] += b
     print(f"\n== top {args.top} top-level ops by bytes ==")
     for (opcode, name), b in by_op.most_common(args.top):
         print(f"  {b/1e9:7.3f} GB  {opcode:12s} {name[:90]}")
