@@ -24,6 +24,7 @@ compiled step's HLO metadata can tell the XLA fusions apart.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 
@@ -34,6 +35,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core.registry import REQUIRED, register_op
+from paddle_tpu.ops import pallas_mhc
+from paddle_tpu.ops.pallas_mhc import sinkhorn  # noqa: F401 (its users' name)
 
 _F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
@@ -537,14 +540,98 @@ def moe_experts(ins, attrs):
 # manifold-constrained hyper-connections: n residual streams
 # ---------------------------------------------------------------------------
 
-def sinkhorn(a, iters, eps, row_axis=-1, col_axis=-2):
-    """exp(a) with rows then columns divided by their sums (+ eps),
-    `iters` times; a row's entries lie along row_axis."""
-    m = jnp.exp(a)
-    for _ in range(iters):
-        m = m / (jnp.sum(m, axis=row_axis, keepdims=True) + eps)
-        m = m / (jnp.sum(m, axis=col_axis, keepdims=True) + eps)
-    return m
+def _mhc_impl(x, kernels, impl=None):
+    """The impl a hyper-connection half resolves to, counted as
+    paddle_tpu_kernel_impl_total{kernel="mhc"}: `impl`, else pallas on
+    a TPU and xla elsewhere; xla too where pallas_mhc.token_block finds
+    no block for one of the op's `kernels` at X's shape and dtype."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    impl = impl or pk._auto_impl()
+    (_, n, t, c), size = x.shape, x.dtype.itemsize
+    if impl != "xla" and not all(
+            pallas_mhc.token_block(k, n, t, c, size) for k in kernels):
+        impl = "xla"
+    pk._count_impl("mhc", impl)
+    return impl
+
+
+@contextlib.contextmanager
+def _kernel_call(name):
+    """The scopes of a kernel entry; see
+    pallas_kernels._flash_attention_fwd: one call line."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    with pk._obs_device.annotate(name), pk._kernel_scope():
+        yield
+
+
+def _mhc_pre_xla(x, norm_scale, phi, alpha, bias, attrs):
+    """(U, HPost, HRes): the XLA composition."""
+    b, n, t, c = x.shape
+    xf = x.astype(_F32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=(1, 3))
+                    + attrs["eps"])                            # [B, T]
+    # x~ Phi = rsqrt(..) * (X (NormScale * Phi)): X is read once
+    w = (norm_scale.astype(_F32)[:, None]
+         * phi.astype(_F32)).reshape(n, c, -1)
+    p = jnp.einsum("bntc,ncw->bwt", xf, w, precision=_HIGHEST) \
+        * inv[:, None, :]                                      # [B, W, T]
+    alpha, bias = alpha.astype(_F32), bias.astype(_F32)
+    h_pre = jax.nn.sigmoid(alpha[0] * p[:, :n] + bias[:n, None])
+    h_post = 2 * jax.nn.sigmoid(alpha[1] * p[:, n:2 * n]
+                                + bias[n:2 * n, None])
+    raw = alpha[2] * p[:, 2 * n:].reshape(b, n, n, t) \
+        + bias[2 * n:].reshape(n, n)[..., None]
+    h_res = sinkhorn(jnp.clip(raw, attrs["clamp_min"], attrs["clamp_max"]),
+                     attrs["sinkhorn_iters"], attrs["eps"],
+                     row_axis=2, col_axis=1)
+    u = jnp.sum(h_pre[..., None] * xf, axis=1)
+    return u.astype(x.dtype), h_post, h_res
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _mhc_pre_kernel(x, norm_scale, phi, alpha, bias, attrs, interpret):
+    """pt_mhc_pre_fwd; attrs: the op's, as sorted items (hashable)."""
+    a = dict(attrs)
+    with _kernel_call("mhc_pre"):
+        return pallas_mhc.mhc_pre_fwd_pallas(
+            x, norm_scale, phi, alpha, bias, iters=a["sinkhorn_iters"],
+            eps=a["eps"], clamp=(a["clamp_min"], a["clamp_max"]),
+            interpret=interpret)
+
+
+def _mhc_pre_kernel_fwd(x, norm_scale, phi, alpha, bias, attrs, interpret):
+    return (_mhc_pre_kernel(x, norm_scale, phi, alpha, bias, attrs,
+                            interpret),
+            (x, norm_scale, phi, alpha, bias))
+
+
+def _mhc_pre_kernel_bwd(attrs, interpret, res, cts):
+    x = res[0]
+    a = dict(attrs)
+    if not pallas_mhc.token_block("pre_bwd", *x.shape[1:], x.dtype.itemsize):
+        # its blocks are the largest of the four kernels'
+        _, vjp = jax.vjp(lambda *r: _mhc_pre_xla(*r, a), *res)
+        return vjp(cts)
+    # traced under the forward's name stack: the op's scope is on it
+    with _kernel_call("mhc_pre_grad"):
+        return pallas_mhc.mhc_pre_bwd_pallas(
+            *res, *cts, iters=a["sinkhorn_iters"], eps=a["eps"],
+            clamp=(a["clamp_min"], a["clamp_max"]), interpret=interpret)
+
+
+_mhc_pre_kernel.defvjp(_mhc_pre_kernel_fwd, _mhc_pre_kernel_bwd)
+
+
+def _mhc_pre(x, norm_scale, phi, alpha, bias, attrs, impl=None):
+    impl = _mhc_impl(x, ("pre_fwd",), impl)
+    with jax.named_scope("pt_mhc"):
+        if impl == "xla":
+            return _mhc_pre_xla(x, norm_scale, phi, alpha, bias, attrs)
+        return _mhc_pre_kernel(x, norm_scale, phi, alpha, bias,
+                               tuple(sorted(attrs.items())),
+                               impl == "interpret")
 
 
 @register_op("mhc_pre",
@@ -570,30 +657,65 @@ def mhc_pre(ins, attrs):
     [B, n, n, T] (HRes[b, i, j, t] weighs stream j in new stream i)
     stay float32 for mhc_post, tokens on the lane axis: the 20
     Sinkhorn rounds then work on [n, n, T] slabs instead of T padded
-    [n, n] tiles."""
-    x = ins["X"]
-    b, n, t, c = x.shape
+    [n, n] tiles.
+
+    On a TPU, where ops/pallas_mhc.py can tile X (C whole lane tiles, T
+    a multiple of 16, n <= 4), the forward is ONE kernel,
+    pt_mhc_pre_fwd, that reads X once for the norm, the product, the
+    gates, the rounds and U, and its gradient one, pt_mhc_pre_bwd;
+    elsewhere the XLA composition.
+    paddle_tpu_kernel_impl_total{kernel="mhc"} says which."""
+    u, h_post, h_res = _mhc_pre(
+        ins["X"], ins["NormScale"], ins["Phi"], ins["Alpha"], ins["Bias"],
+        {k: attrs[k] for k in ("sinkhorn_iters", "eps", "clamp_min",
+                               "clamp_max")})
+    return {"U": u, "HPost": h_post, "HRes": h_res}
+
+
+def _mhc_post_xla(x, y, h_post, h_res):
+    xf = x.astype(_F32)
+    # n^2 multiply-adds a stream element on the VPU, written as one
+    # broadcast product summed over j: no slice of X, whose
+    # transpose would pad a gradient back to X's size n times
+    mixed = jnp.sum(h_res.astype(_F32)[..., None] * xf[:, None], axis=2)
+    out = mixed + h_post.astype(_F32)[..., None] * y.astype(_F32)[:, None]
+    return out.astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _mhc_post_kernel(x, y, h_post, h_res, interpret):
+    """pt_mhc_post_fwd, differentiated by pt_mhc_post_bwd."""
+    with _kernel_call("mhc_post"):
+        return pallas_mhc.mhc_post_fwd_pallas(
+            x, y, pallas_mhc.coef_rows(h_res, h_post), interpret=interpret)
+
+
+def _mhc_post_kernel_fwd(x, y, h_post, h_res, interpret):
+    return (_mhc_post_kernel(x, y, h_post, h_res, interpret),
+            (x, y, h_post, h_res))
+
+
+def _mhc_post_kernel_bwd(interpret, res, g):
+    x, y, h_post, h_res = res
+    # traced under the forward's name stack: the op's scope is on it
+    with _kernel_call("mhc_post_grad"):
+        dx, dy, dcoef = pallas_mhc.mhc_post_bwd_pallas(
+            x, y, pallas_mhc.coef_rows(h_res, h_post), g,
+            interpret=interpret)
+    d_res, d_post = pallas_mhc.coef_cols(dcoef, h_res.shape, h_post.shape)
+    return dx, dy, d_post.astype(h_post.dtype), d_res.astype(h_res.dtype)
+
+
+_mhc_post_kernel.defvjp(_mhc_post_kernel_fwd, _mhc_post_kernel_bwd)
+
+
+def _mhc_post(x, y, h_post, h_res, impl=None):
+    impl = _mhc_impl(x, ("post_fwd", "post_bwd"), impl)
     with jax.named_scope("pt_mhc"):
-        xf = x.astype(_F32)
-        inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=(1, 3))
-                        + attrs["eps"])                        # [B, T]
-        # x~ Phi = rsqrt(..) * (X (NormScale * Phi)): X is read once
-        w = (ins["NormScale"].astype(_F32)[:, None]
-             * ins["Phi"].astype(_F32)).reshape(n, c, -1)
-        p = jnp.einsum("bntc,ncw->bwt", xf, w, precision=_HIGHEST) \
-            * inv[:, None, :]                                  # [B, W, T]
-        alpha, bias = ins["Alpha"].astype(_F32), ins["Bias"].astype(_F32)
-        h_pre = jax.nn.sigmoid(alpha[0] * p[:, :n] + bias[:n, None])
-        h_post = 2 * jax.nn.sigmoid(alpha[1] * p[:, n:2 * n]
-                                    + bias[n:2 * n, None])
-        raw = alpha[2] * p[:, 2 * n:].reshape(b, n, n, t) \
-            + bias[2 * n:].reshape(n, n)[..., None]
-        h_res = sinkhorn(jnp.clip(raw, attrs["clamp_min"],
-                                  attrs["clamp_max"]),
-                         attrs["sinkhorn_iters"], attrs["eps"],
-                         row_axis=2, col_axis=1)
-        u = jnp.sum(h_pre[..., None] * xf, axis=1)
-        return {"U": u.astype(x.dtype), "HPost": h_post, "HRes": h_res}
+        if impl == "xla":
+            return _mhc_post_xla(x, y, h_post, h_res)
+        return _mhc_post_kernel(x, y.astype(x.dtype), h_post, h_res,
+                                impl == "interpret")
 
 
 @register_op("mhc_post", inputs=("X", "Y", "HPost", "HRes"),
@@ -601,15 +723,7 @@ def mhc_pre(ins, attrs):
 def mhc_post(ins, attrs):
     """The write half: Out = H_res X + outer(H_post, Y), X [B, n, T, C],
     Y [B, T, C] the sublayer's output, HPost [B, n, T], HRes
-    [B, n, n, T]; float32 arithmetic, Out in X's dtype."""
-    x = ins["X"]
-    with jax.named_scope("pt_mhc"):
-        xf = x.astype(_F32)
-        # n^2 multiply-adds a stream element on the VPU, written as one
-        # broadcast product summed over j: no slice of X, whose
-        # transpose would pad a gradient back to X's size n times
-        mixed = jnp.sum(ins["HRes"].astype(_F32)[..., None]
-                        * xf[:, None], axis=2)
-        out = mixed + ins["HPost"].astype(_F32)[..., None] \
-            * ins["Y"].astype(_F32)[:, None]
-        return {"Out": out.astype(x.dtype)}
+    [B, n, n, T]; float32 arithmetic, Out in X's dtype.  The kernels
+    pt_mhc_post_fwd / pt_mhc_post_bwd where mhc_pre's runs, under the
+    same counter."""
+    return {"Out": _mhc_post(ins["X"], ins["Y"], ins["HPost"], ins["HRes"])}

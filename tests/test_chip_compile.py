@@ -11,6 +11,8 @@ times.  Skipped where the topology cannot be described (no libtpu, or
 another process holds its lock).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -227,6 +229,34 @@ def _gated_conv(grad, t=8192, c=2048, taps=3):
         p, w, None, g, act="", gated=True)), (p, w, _sds((1, t, c))), 1
 
 
+def _mhc(kernel, b, t, dtype, n=4, c=3584):
+    """A kernel of a hyper-connection (ops/pallas_mhc.py) at
+    xing4_29b_train_s4k's streams, 1 x 4 x 4,096 x 3,584, and at twice
+    the batch and the length; bfloat16 (the cell, under AMP: the
+    product's weight in three bfloat16 parts) and float32 (`highest`
+    dots)."""
+    from paddle_tpu.ops import pallas_mhc as pm
+
+    f32 = jnp.float32
+    assert pm.token_block(kernel, n, t, c, jnp.dtype(dtype).itemsize)
+    x, y = _sds((b, n, t, c), dtype), _sds((b, t, c), dtype)
+    if kernel.startswith("post"):
+        coef = _sds((b, t, n * n + n), f32)
+        if kernel == "post_fwd":
+            return pm.mhc_post_fwd_pallas, (x, y, coef), 1
+        return pm.mhc_post_bwd_pallas, (x, y, coef, x), 1
+    width = 2 * n + n * n
+    params = (_sds((n * c,), f32), _sds((n * c, width), f32),
+              _sds((3,), f32), _sds((width,), f32))
+    rounds = dict(iters=20, eps=1e-6, clamp=(-30.0, 30.0))
+    if kernel == "pre_fwd":
+        return functools.partial(pm.mhc_pre_fwd_pallas, **rounds), \
+            (x,) + params, 1
+    return functools.partial(pm.mhc_pre_bwd_pallas, **rounds), \
+        (x,) + params + (y, _sds((b, n, t), f32),
+                         _sds((b, n, n, t), f32)), 1
+
+
 def _moe_combine(n, k, c, rows, f32_rows):
     """A layer's combine by token at a cell's size, 8 experts held: the
     forward's (bf16 rows, gated) or d x's (float32 rows), the plan made
@@ -246,6 +276,15 @@ def _moe_combine(n, k, c, rows, f32_rows):
 
 
 CASES = {
+    # float32 streams leave mhc_pre's backward to XLA at this width (its
+    # blocks pass the 40 MiB a kernel allows itself): no kernel to compile
+    **{"mhc_%s_%dx4x%dx3584_%s" % (kernel, b, t, jnp.dtype(dtype).name):
+       (lambda kernel=kernel, b=b, t=t, dtype=dtype: _mhc(kernel, b, t,
+                                                          dtype))
+       for kernel in ("post_fwd", "post_bwd", "pre_fwd", "pre_bwd")
+       for b, t, dtype in ((1, 4096, BF16), (1, 4096, jnp.float32),
+                           (2, 8192, BF16))
+       if (kernel, dtype) != ("pre_bwd", jnp.float32)},
     # ling3, xing4, dsv2: tokens, pairs a token, width, the layout's rows
     **{"moe_combine_%s_%dx%dx%d_rows%d" % (
         ("dx" if f32 else "fwd",) + shape):

@@ -1465,6 +1465,156 @@ def test_mhc_clamp_acts_before_exp():
     np.testing.assert_allclose(h[0, :, :, 0], np.eye(n), atol=1e-6)
 
 
+# -- the hyper-connection kernels (ops/pallas_mhc.py) in interpret mode ------
+
+MHC_ATTRS = {"eps": 1e-6, "clamp_min": -2.0, "clamp_max": 2.5}
+# B, T, C at n = 4: one and several chunks of tokens, one and several
+# lane tiles, several blocks of a batch, the cell's width (at T 16
+# only: the interpreter walks 28 lane tiles a chunk)
+MHC_SHAPES = [(1, 16, 128), (2, 16, 384), (2, 256, 128), (1, 256, 384),
+              (1, 16, 3584)]
+
+
+def _mhc_operands(b, t, c, dtype, n=4, seed=0):
+    """X, Y, mhc_pre's parameters with pre-activations of the size a
+    trained block has (|Alpha p| about 1; a third of H_res's beyond
+    MHC_ATTRS' clamp) and a cotangent for every output."""
+    rng = np.random.default_rng(seed + 31 * c + t)
+    width = 2 * n + n * n
+
+    def normal(shape, scale=1.0, dtype=jnp.float32):
+        return jnp.asarray(scale * rng.standard_normal(shape), dtype)
+
+    ops = {
+        "x": normal((b, n, t, c), dtype=dtype),
+        "y": normal((b, t, c), dtype=dtype),
+        "norm_scale": 1 + normal((n * c,), 0.2),
+        "phi": normal((n * c, width), (n * c) ** -0.5),
+        "alpha": jnp.array([0.8, 1.2, 2.0], jnp.float32),
+        "bias": jnp.concatenate([normal((2 * n,), 0.5),
+                                 normal((n * n,), 1.5)]),
+        "d_out": normal((b, n, t, c), dtype=dtype),
+        "d_u": normal((b, t, c), dtype=dtype),
+        "d_post": normal((b, n, t)),
+        "d_res": normal((b, n, n, t)),
+    }
+    return ops
+
+
+@functools.partial(jax.jit, static_argnames=("impl", "iters"))
+def _mhc_passes(ops, impl, iters):
+    """Every output and every gradient of the two ops' functions under
+    `impl`, by name; one program."""
+    from paddle_tpu.ops import llm_ops
+
+    attrs = dict(MHC_ATTRS, sinkhorn_iters=iters)
+    pre_in = [ops[k] for k in ("x", "norm_scale", "phi", "alpha", "bias")]
+    (u, h_post, h_res), pre_vjp = jax.vjp(
+        lambda *a: llm_ops._mhc_pre(*a, attrs, impl), *pre_in)
+    # mhc_post on the XLA form's coefficients under both impls
+    coefs = llm_ops._mhc_pre(*pre_in, attrs, "xla")[1:]
+    out, post_vjp = jax.vjp(
+        lambda *a: llm_ops._mhc_post(*a, impl), ops["x"], ops["y"], *coefs)
+    got = {"U": u, "HPost": h_post, "HRes": h_res, "Out": out}
+    got.update(zip(("pre.dX", "dNormScale", "dPhi", "dAlpha", "dBias"),
+                   pre_vjp((ops["d_u"], ops["d_post"], ops["d_res"]))))
+    got.update(zip(("post.dX", "dY", "dHPost", "dHRes"),
+                   post_vjp(ops["d_out"])))
+    return got
+
+
+# gradients that are sums over every token of the batch
+MHC_PARAM_GRADS = ("dNormScale", "dPhi", "dAlpha", "dBias")
+
+
+def _assert_mhc_close(got, want, f32_tol):
+    for name, ref in want.items():
+        a, r = (np.asarray(v, np.float64) for v in (got[name], ref))
+        assert got[name].dtype == ref.dtype, name
+        scale = np.abs(r).max()
+        if ref.dtype == jnp.bfloat16:
+            # the same float32 sum rounded once: one bfloat16 ulp of
+            # the element where the two forms' sums straddle a tie;
+            # where a sum's terms cancel, 2^-16 of the largest entry
+            # (what pt_mhc_pre_bwd's one-pass product leaves out of
+            # dX, 2^-7 of a bfloat16 ulp there)
+            np.testing.assert_allclose(a, r, rtol=BF16_ULP,
+                                       atol=2.0 ** -16 * scale,
+                                       err_msg=name)
+        else:
+            # float32 sums in another order, against the largest entry;
+            # a parameter's gradient sums B T times the terms
+            tol = f32_tol * (4 if name in MHC_PARAM_GRADS else 1)
+            np.testing.assert_allclose(a, r, rtol=0, atol=tol * scale,
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "shape,iters",
+    # 5 rounds where the blocks are one and many: a case compiles the
+    # rounds and their gradient, 3.5 s
+    [(s, 20) for s in MHC_SHAPES] + [(MHC_SHAPES[0], 5), (MHC_SHAPES[3], 5)],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_mhc_kernels_against_the_xla_composition(shape, iters, dtype):
+    """pt_mhc_pre_fwd / pt_mhc_pre_bwd / pt_mhc_post_fwd /
+    pt_mhc_post_bwd in interpret mode: U, HPost, HRes, Out and the
+    gradients of X (either op's), Y, NormScale, Phi, Alpha, Bias, and
+    of HPost and HRes, with the clamp active."""
+    ops = _mhc_operands(*shape, jnp.dtype(dtype))
+    before = _impl_counts()
+    got = _mhc_passes(ops, "interpret", iters)
+    assert _impl_since(before) == {("mhc", "interpret"): 2, ("mhc", "xla"): 1}
+    want = _mhc_passes(ops, "xla", iters)
+    raw = np.asarray(want["HRes"])
+    assert 0 < (raw == raw.max()).mean() < 1    # not one constant matrix
+    # float32 operands: 1e-6 of the largest entry a term, the two
+    # forms' sums in another order 2e-6, and twice that for the sums of
+    # n C = 14,336 terms at the cell's width
+    _assert_mhc_close(got, want, 4e-6 if shape[2] > 384 else 2e-6)
+
+
+@pytest.mark.parametrize("shape", [(1, 16, 96), (1, 24, 128)],
+                         ids=["C96", "T24"])
+def test_mhc_takes_the_xla_form_where_the_kernels_cannot_tile(shape):
+    """C no whole lane tiles, T no whole chunks of 16 tokens: asked for
+    the kernels, the ops run the XLA composition, say so in the
+    counter, and give its results bit for bit."""
+    from paddle_tpu.ops import pallas_mhc
+
+    assert all(pallas_mhc.token_block(k, 4, *shape[1:], 2) is None
+               for k in pallas_mhc._BLOCK_UNITS)
+    ops = _mhc_operands(*shape, jnp.bfloat16)
+    before = _impl_counts()
+    got = _mhc_passes(ops, "interpret", 5)
+    assert _impl_since(before) == {("mhc", "xla"): 3}
+    for name, ref in _mhc_passes(ops, "xla", 5).items():
+        np.testing.assert_array_equal(np.asarray(got[name], np.float32),
+                                      np.asarray(ref, np.float32), name)
+
+
+def test_mhc_token_blocks_at_the_cell():
+    """The blocks the four kernels take of xing4_29b_train_s4k's
+    streams (1 x 4 x 4,096 x 3,584): whole lane tiles of tokens for the
+    kernels that write coefficients tokens-last, all under the 40 MiB
+    a kernel allows itself; float32 streams leave mhc_pre's backward to
+    XLA (its blocks and the weight's gradient pass it)."""
+    from paddle_tpu.ops import pallas_mhc as pm
+
+    def blocks(size):
+        return {k: pm.token_block(k, 4, 4096, 3584, size)
+                for k in pm._BLOCK_UNITS}
+
+    assert blocks(2) == {"post_fwd": 256, "post_bwd": 128, "pre_fwd": 256,
+                         "pre_bwd": 128}
+    assert blocks(4) == {"post_fwd": 128, "post_bwd": 64, "pre_fwd": 128,
+                         "pre_bwd": None}
+    for kernel, tt in blocks(2).items():
+        assert pm._vmem(kernel, 4, tt, 3584, 2) <= 40 << 20
+    # n = 6: 3 x 48 products pass a lane tile
+    assert pm.token_block("post_fwd", 6, 4096, 3584, 2) is None
+
+
 # -- flash attention at latent attention's head sizes --------------------------
 
 def _mla_qkv(seed, b=1, h=2, t=256, dtype=jnp.float32):

@@ -184,6 +184,41 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert workload in chip_gate.MOE_COMBINE_KERNEL
         assert detail["moe_ops"] == 4
         assert detail["kernel_calls"]["pt_moe_combine"] == 12
+        # the ten hyper-connections through the kernels of
+        # ops/pallas_mhc.py (ISSUE 52): mhc_pre's forward in the
+        # forward pass and in each segment's replay, mhc_post's too but
+        # a segment's last, each backward once; and under the scope
+        # pt_mhc XLA makes no stream-sized array (the composition's
+        # casts, broadcast products and copies)
+        assert workload in chip_gate.MHC_STREAMS_IN_KERNELS
+        assert {k: detail["kernel_calls"][k]
+                for k in chip_gate.MHC_KERNEL_CALLS} == {
+            "pt_mhc_pre_fwd": 20, "pt_mhc_post_fwd": 15,
+            "pt_mhc_pre_bwd": 10, "pt_mhc_post_bwd": 10}
+        assert detail["mhc_stream_moves"] == []
+        assert detail["tpu_custom_calls"] == 145
+        assert chip_gate.STEP_BYTES_MAX["xing4_train"] == 12_753_077_248
+
+
+def test_mhc_stream_moves_reads_stream_sized_arrays_under_the_scope():
+    """The reader itself, on text: a fusion, copy, convert or transpose
+    under pt_mhc that yields an array of a stream's elements, alone or
+    in a tuple; not the kernels, not a coefficient-sized array, not
+    another op's scope."""
+    from tools.tpu_lowering_check import mhc_stream_moves
+
+    scope = 'metadata={op_name="jit(step)/pt_forward.mhc_post/pt_mhc/mul"}'
+    text = """
+  %fusion.1 = f32[1,4,256,256]{3,2,1,0} fusion(%a), kind=kLoop, calls=%f, SCOPE
+  %copy.2 = bf16[1,256,256]{2,1,0} copy(%a), SCOPE
+  %fusion.3 = (f32[1,4,256]{2,1,0}, bf16[4,65536]{1,0}) fusion(%a), kind=kLoop, calls=%f, SCOPE
+  %fusion.4 = f32[1,256,20]{2,1,0} fusion(%a), kind=kLoop, calls=%f, SCOPE
+  %transpose.5 = f32[1,20,256]{2,1,0} transpose(%a), dimensions={0,2,1}, SCOPE
+  %pt_mhc_post_fwd.6 = bf16[1,4,256,256]{3,2,1,0} custom-call(%a), custom_call_target="tpu_custom_call", SCOPE
+  %fusion.7 = f32[1,4,256,256]{3,2,1,0} fusion(%a), kind=kLoop, calls=%f, metadata={op_name="jit(step)/pt_forward.sum/add"}
+""".replace("SCOPE", scope)
+    found = mhc_stream_moves(text, (4 * 256 * 256, 256 * 256))
+    assert [f.split()[0] for f in found] == ["fusion", "copy", "fusion"]
 
 
 def test_kernel_calls_counts_mosaic_calls_by_kernel_name():

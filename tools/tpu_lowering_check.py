@@ -42,6 +42,7 @@ import collections
 import contextlib
 import functools
 import json
+import math
 import os
 import re
 import sys
@@ -456,6 +457,41 @@ MOE_COMBINE_KERNEL = ("xing4_train", "xing4_train_tiny", "dsv2_train",
                       "solar_open2_train", "solar_open2_train_tiny")
 
 
+# the training steps whose hyper-connections run the kernels of
+# ops/pallas_mhc.py -> the elements of a stream-sized array there
+# (X [B, n, T, C]; Y, U [B, T C]): under the scope pt_mhc no XLA
+# fusion, copy, convert or transpose yields one (the XLA composition's
+# float32 casts, broadcast products and copies of whole stream arrays),
+# and each kernel is called as often as the segments run it
+MHC_STREAMS_IN_KERNELS = {
+    "xing4_train": (4 * 4096 * 3584, 4096 * 3584),
+    "xing4_train_tiny": (4 * 256 * 256, 256 * 256)}
+# 10 hyper-connections in 5 recompute segments: mhc_pre's forward in
+# the forward pass and in its segment's replay; mhc_post's too, but a
+# segment's last, whose output nothing of the segment reads; each
+# backward once (the first compile's counts, PR 52)
+MHC_KERNEL_CALLS = {"pt_mhc_pre_fwd": 20, "pt_mhc_post_fwd": 15,
+                    "pt_mhc_pre_bwd": 10, "pt_mhc_post_bwd": 10}
+
+
+def mhc_stream_moves(hlo_text, sizes):
+    """Instructions of a compiled module under the op_name scope
+    pt_mhc, outside the kernels, that yield an array of one of `sizes`
+    elements: `fusion`, `copy`, `convert` and `transpose`.  The
+    coefficients' transposes and the weight's parts (a few MB) do not
+    count."""
+    found = []
+    for result, kind in re.findall(
+            r"^\s*(?:ROOT )?%?[\w.\-]+ = ([^\n]*?) "
+            r"(fusion|copy|convert|transpose)\("
+            r'[^\n]*op_name="[^"]*pt_mhc[^"]*"', hlo_text, re.M):
+        for dims in re.findall(r"\w+\[([\d,]+)\]", result):
+            if math.prod(map(int, dims.split(","))) in sizes:
+                found.append("%s %s" % (kind, result))
+                break
+    return found
+
+
 def conv_scope_pads(hlo_text):
     """`pad` instructions of a compiled module under the op_name scope
     pt_causal_conv1d: the XLA graph's left-padded float32 copy of X
@@ -504,6 +540,10 @@ ROW_WORK_IN_LOOPS = {"dsv2_train": 51200, "dsv2_train_tiny": 3584}
 # compiler packs the temporaries into grew 3,192,799,744 ->
 # 3,250,685,440 (buffer assignment of both modules, PERF.md PR 43)
 STEP_BYTES_MAX = {"dsv2_train": 9_700_000_000,
+                  # PR 51 reads 12,713,077,248; 40 MB more (ISSUE 52):
+                  # the stream mixes' kernels leave no stream-sized
+                  # float32 temporary to raise it
+                  "xing4_train": 12_753_077_248,
                   # the driver's ceiling for the cell (ISSUE 45):
                   # step_hbm_gb between 4 and 15.5; the one lever above
                   # 15.0 is the vocabulary at an eighth
@@ -572,7 +612,11 @@ def check_workload(name, build):
     tokens is moved under the scope pt_gated_conv outside the kernels;
     for the MOE_COMBINE_KERNEL steps
     `moe_ops`, which fail it unless `pt_moe_combine` is called two or
-    three times a moe_experts op; for the ROW_WORK_IN_LOOPS steps
+    three times a moe_experts op; for the MHC_STREAMS_IN_KERNELS steps
+    `mhc_stream_moves`, which fails it unless no stream-sized array is
+    made under the scope pt_mhc outside the kernels and the four
+    kernels are called MHC_KERNEL_CALLS times; for the
+    ROW_WORK_IN_LOOPS steps
     `rows_outside_loops`, which fails the workload unless it is 0, and
     for the STEP_BYTES_MAX steps `step_bytes`, which fails it above
     the limit."""
@@ -658,6 +702,12 @@ def check_workload(name, build):
             ok &= 0 < 2 * detail["moe_ops"] \
                 <= detail["kernel_calls"].get("pt_moe_combine", 0) \
                 <= 3 * detail["moe_ops"]
+        if name in MHC_STREAMS_IN_KERNELS:
+            detail["mhc_stream_moves"] = mhc_stream_moves(
+                text, MHC_STREAMS_IN_KERNELS[name])
+            ok &= not detail["mhc_stream_moves"]
+            ok &= all(detail["kernel_calls"].get(k) == v
+                      for k, v in MHC_KERNEL_CALLS.items())
         if name in ROW_WORK_IN_LOOPS:
             detail["rows_outside_loops"] = rows_outside_loops(
                 text, ROW_WORK_IN_LOOPS[name])
