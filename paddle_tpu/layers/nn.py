@@ -975,7 +975,8 @@ def sequence_mask(x, maxlen=None, dtype="int64", name=None):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, name=None, n_head=None, n_kv_head=None):
+                    block_k=None, name=None, n_head=None, n_kv_head=None,
+                    window=None):
     """Fused blockwise attention (Pallas TPU kernel; ops/pallas_kernels.py).
 
     q/k/v: [B, H, T, D] post-split-heads, or, with n_head, token-major
@@ -994,7 +995,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     materializes the [Tq, Tk] score matrix.  block_q/block_k override
     the kernel tile sizes (default picked by sequence length: 1024 for
     T >= 1024, else 512 — pinned by the 2026-08-01 v5e sweep; PERF.md
-    section 6, PR 21).
+    section 6, PR 21).  window (needs causal): a sliding window, query
+    i sees the `window` keys that end at its own, i - window < j <= i;
+    the kernels walk the band only and pick their tiles from the
+    window (docs/FLASH_ATTENTION.md "The band").
 
     Returns Out.  The op also writes LSE, the per-row log-sum-exp
     (float32 [B, H, Tq], no gradient): the residual flash_attention_grad
@@ -1008,6 +1012,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
             raise ValueError(
                 "flash_attention: k has %d heads of q's size, n_kv_head "
                 "says %d (of %d query heads)" % (have, n_kv_head, heads))
+    if window and not causal:
+        raise ValueError("flash_attention: window %r needs causal=True"
+                         % (window,))
     helper = LayerHelper("flash_attention")
     out = helper.create_variable_for_type_inference(q.dtype)
     lse = helper.create_variable_for_type_inference("float32", True)
@@ -1016,7 +1023,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         outputs={"Out": out, "LSE": lse},
         attrs={"causal": causal, "scale": float(scale or 0.0),
                "block_q": int(block_q or 0), "block_k": int(block_k or 0),
-               "heads": int(n_head or 0)})
+               "heads": int(n_head or 0), "window": int(window or 0)})
     return out
 
 

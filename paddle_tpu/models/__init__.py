@@ -18,3 +18,4 @@ from paddle_tpu.models.ssd import ssd_mobilenet
 from paddle_tpu.models.yolov3 import yolov3
 from paddle_tpu.models.vgg import vgg, vgg16
 from paddle_tpu.models.se_resnext import se_resnext
+from paddle_tpu.models.mellum2 import mellum2_model

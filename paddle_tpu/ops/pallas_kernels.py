@@ -30,6 +30,13 @@ dimension innermost so the (acc, m, l) scratch carries across KV steps;
 hpb is the heads a step takes: head-major 1, token-major the heads
 of a lane block, 128/D.
 
+A causal call computes a triangle of its grid and a call with a
+sliding window (`window=`: query i sees keys i - window < j <= i) a
+band of it.  Which block pair runs is ONE predicate (`_Diagonal`); a
+causal call's dead steps hold their blocks (no fetch), and a windowed
+call's grids walk the band's steps alone (no step below the band),
+under kernel names of their own (pt_flash_win_*).
+
 The public `flash_attention` is differentiable via ONE custom_vjp
 (`_flash_lse`, shared with `flash_attention_lse` and the IR op): forward
 runs the Pallas kernel on TPU (plain XLA path elsewhere) and saves
@@ -102,11 +109,13 @@ _MIN_LANES = 128  # TPU vector lane count; m/l scratch padded to this
 # reference (XLA) implementation — also the backward path
 # ---------------------------------------------------------------------------
 
-def _plain_attention(q, k, v, causal, scale, with_lse=False):
+def _plain_attention(q, k, v, causal, scale, with_lse=False, window=0):
     """q/k/v: [B, H, T, D]; k and v may have H / group heads, and are
     then repeated to H (what the kernels never do).  with_lse: also
     the log-sum-exp of each row of the scaled, masked scores, float32
-    [B, H, Tq]."""
+    [B, H, Tq].  window (0: none; causal only): a query sees the
+    `window` keys that end at its own, key j where i - window < j <= i
+    (`_Diagonal`)."""
     if k.shape[1] != q.shape[1]:
         group = q.shape[1] // _kv_heads(q, k, None)
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
@@ -117,7 +126,10 @@ def _plain_attention(q, k, v, causal, scale, with_lse=False):
         tq, tk = s.shape[-2], s.shape[-1]
         qpos = lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
         kpos = lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        mask = (qpos + (tk - tq) >= kpos)[None, None]
+        mask = qpos + (tk - tq) >= kpos
+        if window:
+            mask &= qpos + (tk - tq) - window < kpos
+        mask = mask[None, None]
         s = jnp.where(mask, s, _NEG_INF)
         # fully-masked rows (tq > tk) output 0, matching the kernel
         p = jax.nn.softmax(s, axis=-1) * mask
@@ -134,41 +146,116 @@ def _plain_attention(q, k, v, causal, scale, with_lse=False):
 # the causal grid: which block pairs compute, and what a dead step holds
 # ---------------------------------------------------------------------------
 
+def _block_of(pos, block, n):
+    """The block of `block` rows that holds row `pos`, held to a grid
+    of n blocks (block 0 for a row before the first).  Python ints in,
+    a Python int out (`_Diagonal.band_steps` sizes a grid with it)."""
+    if isinstance(pos, int):
+        return min(max(pos, 0) // block, n - 1)
+    return jnp.minimum(jnp.maximum(pos, 0) // block, n - 1)
+
+
 class _Diagonal(collections.namedtuple(
-        "_Diagonal", "block_q block_k q_off")):
-    """Where a causal call's diagonal lies over its grid of block pairs:
-    the ONE statement of which pair (qi, ki) computes, read by the
-    three kernel bodies (`run`) and, solved for either index, by the
-    BlockSpec index maps (`_second_held`), so that predicate and maps
-    cannot drift.  q_off = tk - tq: query row r sees keys 0 .. q_off + r.
-    The indices may be Python ints, arrays (a test walks a whole grid
-    at once) or a grid's traced program ids."""
+        "_Diagonal", "block_q block_k q_off window", defaults=(0,))):
+    """Where a causal call's diagonal lies over its grid of block pairs,
+    and with a window the band under it: the ONE statement of which
+    pair (qi, ki) computes, read by the three kernel bodies (`run`,
+    `inside`) and, solved for either index, by the BlockSpec index maps
+    (`_second_held`) and the grids' sizes (`band_steps`), so that
+    predicate, maps and grids cannot drift.  q_off = tk - tq: query row
+    r sees keys 0 .. q_off + r, with a window (0: none) the last
+    `window` of them, q_off + r - window + 1 .. q_off + r.  The indices
+    may be Python ints, arrays (a test walks a whole grid at once) or a
+    grid's traced program ids."""
 
     def run(self, qi, ki):
         """Block pair (qi, ki) holds a score on or below the diagonal:
-        its first key is no later than its last query row's last key."""
-        return (ki * self.block_k) <= (
+        its first key is no later than its last query row's last key;
+        and, with a window, one inside the band: its last key is no
+        earlier than its first query row's first key."""
+        runs = (ki * self.block_k) <= (
             self.q_off + qi * self.block_q + self.block_q - 1)
+        if self.window:
+            runs &= (ki * self.block_k + self.block_k - 1) >= (
+                self.q_off + qi * self.block_q - self.window + 1)
+        return runs
+
+    def inside(self, qi, ki):
+        """Every score of block pair (qi, ki) is allowed, so the pair
+        builds no mask: its last key is no later than its first query
+        row's last key and, with a window, its first key no earlier
+        than its last query row's first."""
+        whole = (ki * self.block_k + self.block_k - 1) <= (
+            self.q_off + qi * self.block_q)
+        if self.window:
+            whole &= (ki * self.block_k) >= (
+                self.q_off + qi * self.block_q + self.block_q
+                - self.window)
+        return whole
 
     def last_ki(self, qi, nk):
         """The last kv block of the nk that q block qi runs: `run`
         solved for ki, held to the grid (0 for a q block that runs
         none, tq > tk: it computes nothing, whatever it holds)."""
         last_key = self.q_off + qi * self.block_q + self.block_q - 1
-        return jnp.minimum(jnp.maximum(last_key, 0) // self.block_k,
-                           nk - 1)
+        return _block_of(last_key, self.block_k, nk)
+
+    def first_ki(self, qi, nk):
+        """`last_ki`'s twin at the band's lower edge: the first kv
+        block that q block qi runs (0 without a window)."""
+        if not self.window:
+            return 0
+        first_key = self.q_off + qi * self.block_q - self.window + 1
+        return _block_of(first_key, self.block_k, nk)
 
     def first_qi(self, ki, nq):
         """The first q block of the nq that runs kv block ki: `run`
         solved for qi, held to the grid."""
         first_row = ki * self.block_k - self.q_off
-        return jnp.minimum(jnp.maximum(first_row, 0) // self.block_q,
-                           nq - 1)
+        return _block_of(first_row, self.block_q, nq)
+
+    def last_qi(self, ki, nq):
+        """`first_qi`'s twin at the band's lower edge: the last q
+        block that runs kv block ki (nq - 1 without a window)."""
+        if not self.window:
+            return nq - 1
+        last_row = ki * self.block_k + self.block_k - 1 \
+            + self.window - 1 - self.q_off
+        return _block_of(last_row, self.block_q, nq)
 
     def dead_steps(self, nk):
         """Whether a grid of nk kv blocks holds a pair that does not
         run: the first q block's row of it has the fewest that do."""
         return not self.run(0, nk - 1)
+
+    def band(self, outer, n, walks):
+        """(first, last) of the n inner blocks that outer block `outer`
+        runs: kv blocks of a q block (walks "kv": the forward, the dq
+        sweep) or q blocks of a kv block (walks "q": the dk/dv
+        sweep)."""
+        if walks == "kv":
+            return self.first_ki(outer, n), self.last_ki(outer, n)
+        return self.first_qi(outer, n), self.last_qi(outer, n)
+
+    def band_steps(self, nq, nk, walks):
+        """The inner axis of a windowed call's grid: the most inner
+        blocks an outer block runs, at most (window + block_q + block_k
+        - 2) // block_k + 1 of the nk kv blocks a q block, and not nk:
+        no pair below the band is a grid step.  Step j of outer block o
+        is the pair of inner block first(o) + j (`band_block`), which
+        the body tests like any other."""
+        n_outer, n = (nq, nk) if walks == "kv" else (nk, nq)
+        return max(last - first + 1 for first, last in (
+            self.band(outer, n, walks) for outer in range(n_outer)))
+
+    def band_block(self, outer, j, n, walks):
+        """(inner block, whether the grid has it) of step j of a
+        windowed call's band grid; without a window the grid's inner
+        axis is the blocks themselves."""
+        if not self.window:
+            return j, True
+        first, _ = self.band(outer, n, walks)
+        return first + j, first + j < n
 
 
 # ---------------------------------------------------------------------------
@@ -242,21 +329,29 @@ def _kv_blocks(k_ref, v_ref, hpb, slot):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                 l_ref, *, scale, causal, block_q, block_k, kv_len,
-                q_off, hpb, token_major=False, group=1, q_blocks=1):
+                q_off, hpb, token_major=False, group=1, q_blocks=1,
+                window=0, nk=None):
+    """window, nk: a windowed call's grid walks a q block's BAND of the
+    nk kv blocks and not all of them (`_Diagonal.band_steps`): step j
+    is kv block first_ki(qi) + j."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
     kv_slot = _kv_slot(hpb, token_major, group, q_blocks)
+    diagonal = _Diagonal(block_q, block_k, q_off, window)
+    ki, in_grid = diagonal.band_block(qi, step, nk, "kv")
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # KV blocks strictly above the diagonal of this Q block are skipped
-    run = _Diagonal(block_q, block_k, q_off).run(qi, ki) if causal \
-        else True
+    # KV blocks strictly above the diagonal of this Q block (and, with
+    # a window, strictly below its band) are skipped
+    run = diagonal.run(qi, ki) if causal else True
+    if window:
+        run &= in_grid
     # interior blocks (every position valid, fully below the causal
     # diagonal) skip mask construction entirely: the two [bq, bk]
     # iotas + compares + selects are VPU work on par with the exp
@@ -264,7 +359,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     # on the dominant block population
     interior = (ki + 1) * block_k <= kv_len
     if causal:
-        interior &= (ki * block_k + block_k - 1) <= (q_off + qi * block_q)
+        interior &= diagonal.inside(qi, ki)
 
     def _accumulate(masked):
         # the mask depends only on (qi, ki) geometry — one per step,
@@ -278,6 +373,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                 qpos = q_off + qi * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
                 mask = mask & (qpos >= kpos)
+                if window:
+                    mask = mask & (qpos - window < kpos)
         # the heads are independent dependency chains — the scheduler
         # interleaves their MXU and VPU work within the step
         kb, vb = _kv_blocks(k_ref, v_ref, hpb, kv_slot)
@@ -315,7 +412,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     def _compute_edge():
         _accumulate(masked=True)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         for h in range(hpb):
             l = l_ref[h, :, 0]
@@ -495,9 +592,21 @@ def _second_held(diagonal, nq, nk, walks):
     (g, ki, qi) grid of the dk/dv sweep, whose dead steps START a kv
     block's sweep: the first q block it runs is there from step 0
     (and so fetched early, behind the last step of the sweep before).
-    diagonal None (not causal): `_second` itself."""
+    diagonal None (not causal): `_second` itself.
+
+    With a window the inner axis is a BAND's steps and not the blocks
+    (`_Diagonal.band_steps`): step j is the outer block's first inner
+    block + j, and the steps past its last (a band shorter than the
+    longest: at the sequence's ends, or where the edges fall inside
+    fewer blocks) hold that last one."""
     if diagonal is None:
         return _second
+    if diagonal.window:
+        def held(outer, j):
+            first, last = diagonal.band(
+                outer, nk if walks == "kv" else nq, walks)
+            return jnp.minimum(first + j, last)
+        return held
     if walks == "kv":
         return lambda qi, ki: jnp.minimum(ki, diagonal.last_ki(qi, nk))
     return lambda ki, qi: jnp.maximum(qi, diagonal.first_qi(ki, nq))
@@ -537,15 +646,18 @@ def _fwd_vmem_bytes(hpb, bq, bk, d, dv, itemsize):
 # inlines the calls: the compiled step is the same module (same opcode
 # counts and code size, compiled for a described v5e; PERF.md, PR 24).
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "interpret", "heads"))
+    "causal", "scale", "block_q", "block_k", "interpret", "heads",
+    "window"))
 def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                      interpret=False, heads=None):
+                      interpret=False, heads=None, window=0):
     """q/k: [B, H, T, D], v: [B, H, Tk, Dv] (Dv = D everywhere but in
     latent attention, whose q.k size is 192 and v size 128) ->
     ([B, H, Tq, Dv], lse [B*H, Tq_padded]).  With `heads`, the three
     and the output are token-major [B, T, H*D] (`_Tiles`; the entries
     send only what `_flash_layout` passed).  k and v may have fewer
-    heads than q, H / group (`_kv_heads`)."""
+    heads than q, H / group (`_kv_heads`).  window (causal only; 0:
+    none): the grid's kv axis is the band's steps (`_Diagonal`) and
+    the call is named pt_flash_win_fwd."""
     token_major = heads is not None
     dims, bq, bk, hpb = _block_geometry(q, k, v, block_q, block_k, heads)
     b, h, tq, tk, d, dv = dims
@@ -554,14 +666,17 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     qp, kp, vp = tiles.operand(q, bq), tiles.operand(k, bk), \
         tiles.operand(v, bk)
     tq_p, tk_p = qp.shape[1], kp.shape[1]
-    grid = (b * h // hpb, tq_p // bq, tk_p // bk)
-    diagonal = _Diagonal(bq, bk, tk - tq) if causal else None
-    kv_rows = _second_held(diagonal, *grid[1:], walks="kv")
+    nq, nk = tq_p // bq, tk_p // bk
+    diagonal = _Diagonal(bq, bk, tk - tq, window) if causal else None
+    grid = (b * h // hpb, nq,
+            diagonal.band_steps(nq, nk, "kv") if window else nk)
+    kv_rows = _second_held(diagonal, nq, nk, walks="kv")
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         kv_len=tk, q_off=tk - tq if causal else 0, hpb=hpb,
-        token_major=token_major, group=group, q_blocks=h // hpb)
+        token_major=token_major, group=group, q_blocks=h // hpb,
+        window=window, nk=nk)
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
@@ -571,7 +686,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
                 _fwd_vmem_bytes(hpb, bq, bk, d, dv, q.dtype.itemsize)))
     out, lse = pl.pallas_call(
         kernel,
-        name="pt_flash_fwd",
+        name="pt_flash_win_fwd" if window else "pt_flash_fwd",
         grid=grid,
         in_specs=[
             tiles.spec(bq, d, _first),
@@ -612,19 +727,21 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
 # the lengths whose dq does not fit VMEM (_flash_bwd).
 
 def _bwd_interior(*, causal, block_q, block_k, kv_len, q_len, q_off,
-                  qi, ki):
+                  qi, ki, window=0):
     """Traced predicate: this (qi, ki) block needs no mask — all kv
-    and q positions valid, fully below the causal diagonal."""
+    and q positions valid, fully below the causal diagonal (and, with
+    a window, fully inside the band)."""
     interior = ((ki + 1) * block_k <= kv_len) \
         & ((qi + 1) * block_q <= q_len)
     if causal:
-        interior &= (ki * block_k + block_k - 1) <= (q_off + qi * block_q)
+        interior &= _Diagonal(block_q, block_k, q_off,
+                              window).inside(qi, ki)
     return interior
 
 
 def _bwd_p_ds_block(q, k, v, do, lse, delta, *, scale, causal,
                     block_q, block_k, kv_len, q_len, q_off, qi, ki,
-                    masked=True):
+                    masked=True, window=0):
     """Recompute the probability block P [bq, bk] (forward's mask plus
     a valid-q-row mask — padded q rows must contribute nothing to
     dk/dv) and the score gradient dS = P * (dO V^T - delta) * scale.
@@ -640,6 +757,8 @@ def _bwd_p_ds_block(q, k, v, do, lse, delta, *, scale, causal,
         mask = (kpos < kv_len) & (qrow < q_len)
         if causal:
             mask = mask & ((q_off + qrow) >= kpos)
+            if window:
+                mask = mask & ((q_off + qrow - window) < kpos)
         # masked entries (incl. fully-masked rows where lse=-1e30) -> 0
         p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
     else:
@@ -653,21 +772,26 @@ def _bwd_p_ds_block(q, k, v, do, lse, delta, *, scale, causal,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, acc_ref, *, scale, causal, block_q,
                    block_k, kv_len, q_len, q_off, hpb,
-                   token_major=False, group=1, q_blocks=1):
+                   token_major=False, group=1, q_blocks=1, window=0,
+                   nq=None, nk=None):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
     kv_slot = _kv_slot(hpb, token_major, group, q_blocks)
+    diagonal = _Diagonal(block_q, block_k, q_off, window)
+    ki, in_grid = diagonal.band_block(qi, step, nk, "kv")  # _fwd_kernel
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    run = _Diagonal(block_q, block_k, q_off).run(qi, ki) if causal \
-        else True
-    interior = _bwd_interior(causal=causal, block_q=block_q,
-                             block_k=block_k, kv_len=kv_len,
-                             q_len=q_len, q_off=q_off, qi=qi, ki=ki)
+    run = diagonal.run(qi, ki) if causal else True
+    if window:
+        run &= in_grid
+    geometry = dict(causal=causal, block_q=block_q, block_k=block_k,
+                    kv_len=kv_len, q_len=q_len, q_off=q_off, qi=qi,
+                    ki=ki, window=window)
+    interior = _bwd_interior(**geometry)
 
     def _accumulate(masked):
         kb, vb = _kv_blocks(k_ref, v_ref, hpb, kv_slot)
@@ -679,10 +803,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             _, ds = _bwd_p_ds_block(
                 q, k, v, do,
                 lse_ref[h, :, 0], delta_ref[h, :, 0],
-                scale=scale,
-                causal=causal, block_q=block_q, block_k=block_k,
-                kv_len=kv_len, q_len=q_len, q_off=q_off, qi=qi, ki=ki,
-                masked=masked)
+                scale=scale, masked=masked, **geometry)
             acc_ref[0 if token_major else h] += lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -695,7 +816,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _compute_edge():
         _accumulate(masked=True)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         for a in range(acc_ref.shape[0]):
             dq_ref[a, ...] = acc_ref[a].astype(dq_ref.dtype)
@@ -704,7 +825,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     *refs, scale, causal, block_q, block_k, kv_len,
                     q_len, q_off, hpb, with_dq,
-                    token_major=False, group=1, q_blocks=1):
+                    token_major=False, group=1, q_blocks=1, window=0,
+                    nq=None, nk=None):
     """The dk/dv sweep: kv blocks outer, q blocks inner, dk_acc/dv_acc
     carried across the q sweep.  Head-major, every head slot has its
     accumulators; token-major, the heads of a lane block share one
@@ -720,36 +842,61 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     [hpb, Tq_p, d] in float32 and carries across the OUTER kv axis, so
     each q block's rows add their kv blocks in ascending order (the sum
     `_bwd_dq_kernel` forms, bit for bit) and dq reaches HBM once a
-    head."""
+    head.
+
+    window: the inner axis walks a kv block's BAND of the nq q blocks
+    (`_Diagonal.band_steps`), step j the q block first_qi(ki) + j.  A
+    q block is then no longer met at every kv block, so with_dq the
+    whole dq_acc is zeroed at a head's first step and written at its
+    last, not a q block at the first and last kv block."""
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
     kv_slot = _kv_slot(hpb, token_major, group, q_blocks)
+    diagonal = _Diagonal(block_q, block_k, q_off, window)
+    qi, in_grid = diagonal.band_block(ki, step, nq, "q")
     if with_dq:
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
-        nk = pl.num_programs(1)
-        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        last_ki = pl.num_programs(1) - 1
+
+        def q_rows(i):
+            return pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+        # a band step past the grid runs nothing: any block's rows do
+        rows = q_rows(jnp.minimum(qi, nq - 1) if window else qi)
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = refs
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if with_dq:
+    if with_dq and not window:
         @pl.when(ki == 0)
         def _init_dq():
             for a in range(dq_acc.shape[0]):
                 dq_acc[a, rows, :] = jnp.zeros(
                     (block_q, dq_acc.shape[2]), dq_acc.dtype)
+    elif with_dq:
+        @pl.when((ki == 0) & (step == 0))
+        def _init_dq_whole():
+            def zero(i, carry):
+                for a in range(dq_acc.shape[0]):
+                    dq_acc[a, q_rows(i), :] = jnp.zeros(
+                        (block_q, dq_acc.shape[2]), dq_acc.dtype)
+                return carry
+            lax.fori_loop(0, nq, zero, 0)
 
-    # q blocks entirely above the diagonal contribute nothing
-    run = _Diagonal(block_q, block_k, q_off).run(qi, ki) if causal \
-        else True
-    interior = _bwd_interior(causal=causal, block_q=block_q,
-                             block_k=block_k, kv_len=kv_len,
-                             q_len=q_len, q_off=q_off, qi=qi, ki=ki)
+    # q blocks entirely above the diagonal (and, with a window, below
+    # the band) contribute nothing
+    run = diagonal.run(qi, ki) if causal else True
+    if window:
+        run &= in_grid
+    geometry = dict(causal=causal, block_q=block_q, block_k=block_k,
+                    kv_len=kv_len, q_len=q_len, q_off=q_off, qi=qi,
+                    ki=ki, window=window)
+    interior = _bwd_interior(**geometry)
 
     def _accumulate(masked):
         kb, vb = _kv_blocks(k_ref, v_ref, hpb, kv_slot)
@@ -762,10 +909,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             p, ds = _bwd_p_ds_block(
                 q, k, v, do,
                 lse_ref[h, :, 0], delta_ref[h, :, 0],
-                scale=scale,
-                causal=causal, block_q=block_q, block_k=block_k,
-                kv_len=kv_len, q_len=q_len, q_off=q_off, qi=qi, ki=ki,
-                masked=masked)
+                scale=scale, masked=masked, **geometry)
             dv_acc[a] += lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -785,18 +929,27 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _compute_edge():
         _accumulate(masked=True)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         for a in range(dk_acc.shape[0]):
             dk_ref[a, ...] = dk_acc[a].astype(dk_ref.dtype)
             dv_ref[a, ...] = dv_acc[a].astype(dv_ref.dtype)
 
-    if with_dq:
-        @pl.when(ki == nk - 1)
+    if with_dq and not window:
+        @pl.when(ki == last_ki)
         def _finalize_dq():
             for a in range(dq_acc.shape[0]):
                 dq_ref[a, rows, :] = dq_acc[a, rows, :].astype(
                     dq_ref.dtype)
+    elif with_dq:
+        @pl.when((ki == last_ki) & (step == steps - 1))
+        def _finalize_dq_whole():
+            def write(i, carry):
+                for a in range(dq_acc.shape[0]):
+                    dq_ref[a, q_rows(i), :] = dq_acc[
+                        a, q_rows(i), :].astype(dq_ref.dtype)
+                return carry
+            lax.fori_loop(0, nq, write, 0)
 
 
 def _bwd_fused_vmem_bytes(hpb, tq_p, bq, bk, d, dv, itemsize):
@@ -822,27 +975,38 @@ def _bwd_fused_vmem_bytes(hpb, tq_p, bq, bk, d, dv, itemsize):
     return tiles + dkv + dq + hpb * (stats + temps)
 
 
-def _count_causal_fetch(causal, tq, tk, bq, bk):
+def _count_causal_fetch(causal, tq, tk, bq, bk, window=0):
     """paddle_tpu_kernel_impl_total{kernel="flash_attention_causal_fetch"}:
     once a causal entry to the kernels, forward or backward, `held`
     where its grid has a step above the diagonal (whose blocks the
     index maps hold, `_second_held`), `all_live` where it has none (one
-    block a sequence).  An entry that is not causal adds no series."""
+    block a sequence).  An entry that is not causal adds no series.
+
+    {kernel="flash_attention_window"} beside it, once an entry with a
+    window: which grid the call got, `band` (the inner axis walks a
+    band's steps, `_Diagonal.band_steps`) or `all_live` (one block a
+    sequence: the band is the grid).  An entry without a window adds
+    no series."""
     if causal:
-        held = _Diagonal(bq, bk, tk - tq).dead_steps(-(-tk // bk))
+        held = _Diagonal(bq, bk, tk - tq, window).dead_steps(-(-tk // bk))
         _count_impl("flash_attention_causal_fetch",
                     "held" if held else "all_live")
+    if window:
+        _count_impl("flash_attention_window",
+                    "band" if -(-tq // bq) * -(-tk // bk) > 1
+                    else "all_live")
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               heads):
+               heads, window=0):
     """(out, lse) by `_flash_fwd_pallas`, counted here, outside the
     jit, as `_flash_bwd` counts."""
     (_, _, tq, tk, _, _), bq, bk, _ = _block_geometry(
         q, k, v, block_q, block_k, heads)
-    _count_causal_fetch(causal, tq, tk, bq, bk)
+    _count_causal_fetch(causal, tq, tk, bq, bk, window)
     return _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                             interpret=interpret, heads=heads)
+                             interpret=interpret, heads=heads,
+                             window=window)
 
 
 def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
@@ -858,7 +1022,8 @@ def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
         hpb, -(-tq // bq) * bq, bq, bk, d, dv, q.dtype.itemsize)
     fused = vmem <= _BWD_FUSED_VMEM_MAX
     _count_impl("flash_attention_bwd", "fused" if fused else "two_sweep")
-    _count_causal_fetch(call["causal"], tq, tk, bq, bk)
+    _count_causal_fetch(call["causal"], tq, tk, bq, bk,
+                        call.get("window", 0))
     return _flash_bwd_pallas(
         q, k, v, o, lse, g, dlse=dlse, **call,
         one_sweep_vmem=max(vmem, _MOSAIC_SCOPED_VMEM) if fused else None)
@@ -866,10 +1031,10 @@ def _flash_bwd(q, k, v, o, lse, g, *, dlse=None, **call):
 
 @functools.partial(jax.jit, static_argnames=(    # see _flash_fwd_pallas
     "causal", "scale", "block_q", "block_k", "interpret", "heads",
-    "one_sweep_vmem"))
+    "window", "one_sweep_vmem"))
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
                       block_k, interpret=False, dlse=None, heads=None,
-                      *, one_sweep_vmem):
+                      window=0, *, one_sweep_vmem):
     """q/k: [B, H, T, D], v, o and g = dO: [.., Dv] (with `heads`, all
     token-major [B, T, H*D], and so the gradients: `_Tiles`); lse:
     [B*H, Tq] or q-block padded, as the forward kernel returns it.
@@ -882,6 +1047,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     caller consumes it (ring attention's cross-chunk merge).  Since
     d lse_r / d s_rc = p_rc, it folds into the delta term:
     dS = P*(dO V^T - delta) + P*dlse = P*(dO V^T - (delta - dlse)).
+
+    window: `_flash_fwd_pallas`; the dq grid's kv axis and the dk/dv
+    grid's q axis are the band's steps, and the calls are named
+    pt_flash_win_bwd_dq and pt_flash_win_bwd_dkv.
     """
     token_major = heads is not None
     dims, bq, bk, hpb = _block_geometry(q, k, v, block_q, block_k, heads)
@@ -935,7 +1104,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
     common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
                   kv_len=tk, q_len=tq, q_off=q_off, hpb=hpb,
                   token_major=token_major, group=group,
-                  q_blocks=h // hpb)
+                  q_blocks=h // hpb, window=window,
+                  nq=tq_p // bq, nk=tk_p // bk)
     operands = (qp, kp, vp, gp, lse3, delta3)
     out_shape = [
         jax.ShapeDtypeStruct(tiles.shape(tq_p, d), q.dtype),
@@ -961,19 +1131,22 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             vmem_limit_bytes=vmem_limit_bytes)}
 
     nq, nk = tq_p // bq, tk_p // bk
-    diagonal = _Diagonal(bq, bk, q_off) if causal else None
+    diagonal = _Diagonal(bq, bk, q_off, window) if causal else None
+    names = ("pt_flash_win_bwd_dq", "pt_flash_win_bwd_dkv") if window \
+        else ("pt_flash_bwd_dq", "pt_flash_bwd_dkv")
     # kv blocks outer, q blocks inner: the dk/dv accumulators carry
     # across the q sweep
     kv_specs = specs(q_rows=_second_held(diagonal, nq, nk, walks="q"),
                      k_rows=_first)
     # dk and dv: a query head's, whatever the KV heads' count
     dkv_specs = [tiles.spec(bk, d, _first), tiles.spec(bk, dv, _first)]
-    kv_grid = (b * h // hpb, nk, nq)
+    kv_grid = (b * h // hpb, nk,
+               diagonal.band_steps(nq, nk, "q") if window else nq)
     kv_scratch = [tiles.acc(bk, d), tiles.acc(bk, dv)]
     if one_sweep_vmem is not None:
         dq, dk, dv_ = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, with_dq=True, **common),
-            name="pt_flash_bwd_dkv",
+            name=names[1],
             grid=kv_grid,
             in_specs=kv_specs,
             # dq: the head's whole [Tq_p, d], resident over both sweeps
@@ -988,8 +1161,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
             diagonal, nq, nk, walks="kv"))
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, **common),
-            name="pt_flash_bwd_dq",
-            grid=(b * h // hpb, nq, nk),
+            name=names[0],
+            grid=(b * h // hpb, nq,
+                  diagonal.band_steps(nq, nk, "kv") if window else nk),
             in_specs=q_specs,
             out_specs=q_specs[0],
             out_shape=out_shape[0],
@@ -999,7 +1173,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block_q,
         )(*operands)
         dk, dv_ = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, with_dq=False, **common),
-            name="pt_flash_bwd_dkv",
+            name=names[1],
             grid=kv_grid,
             in_specs=kv_specs,
             out_specs=dkv_specs,
@@ -1035,36 +1209,39 @@ def _sum_groups(x, group, width):
 # differentiable entries: ONE custom_vjp over the forward/backward kernels
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret,
-               heads=None):
+               heads=None, window=0):
     """(out, lse): lse is the mergeable summary ring attention needs and
-    the residual the IR grad op reads.  heads: `_flash_fwd_pallas`."""
+    the residual the IR grad op reads.  heads, window:
+    `_flash_fwd_pallas`."""
     return _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                      interpret, heads)
+                      interpret, heads, window)
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k,
-                   interpret, heads):
+                   interpret, heads, window):
     out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                          interpret, heads)
+                          interpret, heads, window)
     return (out, lse), (q, k, v, out, lse)
 
 
 def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, heads,
-                   res, g):
+                   window, res, g):
     q, k, v, o, lse = res
     do, dlse = g
     return _flash_bwd(q, k, v, o, lse, do, dlse=dlse, causal=causal,
                       scale=scale, block_q=block_q, block_k=block_k,
-                      interpret=interpret, heads=heads)
+                      interpret=interpret, heads=heads, window=window)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 def flash_attention_lse(q, k, v, *, causal=False, scale=None,
-                        block_q=None, block_k=None, impl="pallas"):
+                        block_q=None, block_k=None, impl="pallas",
+                        window=None):
     """Like flash_attention but also returns the per-row log-sum-exp
     ([B*H, Tq_padded_to_block]): (out, lse) is a complete mergeable
     attention summary — two chunks combine as
@@ -1080,26 +1257,51 @@ def flash_attention_lse(q, k, v, *, causal=False, scale=None,
         raise ValueError(
             "flash_attention_lse impl must be 'pallas' or 'interpret', "
             "got %r" % (impl,))
-    impl, kw = _call_args(q, k, causal, scale, block_q, block_k, impl)
+    impl, kw = _call_args(q, k, causal, scale, block_q, block_k, impl,
+                          window=window)
     _count_impl("flash_attention", impl)
     _count_impl("flash_attention_layout", "head_major")
     with _kernel_scope():
         return _flash_lse(q, k, v, **kw)
 
 
-def _default_block(t):
+def _default_block(t, window=0):
     """Default tile edge for a sequence length of t.
 
     Pinned by the 2026-08-01 on-chip sweep (PERF.md section 6, PR 21:
     v5e, seq 32k d64): 1024x1024 ran fwd+bwd 1.5x faster than the old
     512x512 default (76.9 ms vs 116.8).  Short sequences keep 512 —
     the kernel clamps to T anyway and seq-512 shapes showed no win
-    from smaller tiles."""
+    from smaller tiles.
+
+    With a window the band decides where it is shorter than that: the
+    edge is no longer than the window (a power of two, at least 128),
+    since a q block runs about (window + block) / block kv blocks
+    whatever the block and a longer one only adds masked scores.  At a
+    window as long as the length's default the default stays, though no
+    pair then lies inside the band (`_Diagonal.inside`) and twice the
+    allowed pairs are computed: pinned by the 2026-10-04 on-chip sweep
+    (PERF.md section 6, PR 53; tools/flash_window_price.py: v5e, 1 x
+    16,384 tokens, 32 / 4 heads of 128 token-major, a window of 1,024;
+    the Mosaic calls of one layer alone, forward + one-sweep backward,
+    ms): 1024 x 1024 5.15 + 8.70 = 13.85 (2 steps a q block) against
+    512 x 512 7.21 + 7.28 = 14.49 (3 steps, one of them inside the
+    band, 1.5 times the allowed pairs), 512 x 1024 14.51, 1024 x 512
+    17.74, 2048 x 1024 19.98, 2048 x 2048 38.44 and, by the whole
+    programs' time, 256 x 256 27.0 against 17.2 and 16.6 (five steps of
+    a quarter the work: the step's own cost and the statistics' stores
+    decide, not the masked scores).  The forward alone would take 1024
+    and the backward 512 (12.43 together): one rule for both keeps the
+    op's and the custom_vjp's blocks the same.  The full causal layer
+    there: 19.39 + 34.52 = 53.9."""
+    if window:
+        return min(_default_block(t),
+                   max(128, 1 << (window.bit_length() - 1)))
     return 1024 if t >= 1024 else 512
 
 
 def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
-                    block_k=None, impl=None, heads=None):
+                    block_k=None, impl=None, heads=None, window=None):
     """Fused attention. q/k: [B, H, T, D], v: [B, H, Tk, Dv]; returns
     [B, H, Tq, Dv].  Dv = D everywhere but in latent attention (q.k 192
     = 128 + 64 rotary, v 128): the three kernels take the two sizes.
@@ -1110,33 +1312,50 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     address the heads in place (`_flash_layout`); elsewhere the
     operands are transposed here, to the same answer.
 
+    window (None or 0: none; needs causal): a sliding window, query i
+    sees the `window` keys that end at its own, i - window < j <= i.
+    The kernels' grids walk the band and no pair below it
+    (`_Diagonal`), under names of their own (pt_flash_win_fwd,
+    pt_flash_win_bwd_dq, pt_flash_win_bwd_dkv); a window that reaches
+    every key is no window.
+
     impl: None (auto: pallas on TPU, XLA elsewhere), "pallas",
     "interpret" (pallas interpret mode, for CPU tests), or "xla".
-    block_q/block_k default to a size picked by sequence length
-    (_default_block).
+    block_q/block_k default to a size picked by sequence length and
+    window (_default_block).
     """
     return _flash_attention_fwd(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, impl=impl, heads=heads)[0]
+        block_k=block_k, impl=impl, heads=heads, window=window)[0]
 
 
 def _call_args(q, k, causal=False, scale=None, block_q=None, block_k=None,
-               impl=None, heads=None):
+               impl=None, heads=None, window=None):
     """What a flash entry's unset (None, or an op attr's 0) arguments
     mean, resolved in ONE place (the saved-residual backward reads the
     forward's lse and must tile it the same way): scale 1/sqrt(d), impl
-    `_auto_impl()`, blocks by sequence length.  heads: None for
+    `_auto_impl()`, blocks by sequence length and window, a window that
+    reaches every key (window >= Tk) none.  heads: None for
     [B, H, T, D] operands, the head count of token-major [B, T, H*D]
     ones (rows are dim -2 of both).  Returns
     (impl, the static arguments `_flash_lse` and `_flash_bwd_pallas`
     share)."""
     impl = impl or _auto_impl()
+    window = int(window or 0)
+    if window < 0 or (window and not causal):
+        raise ValueError(
+            "flash_attention: window %d needs causal=True and a length "
+            "of at least 1 (a query sees the `window` keys that end at "
+            "its own)" % window)
+    if window >= k.shape[-2]:
+        window = 0
     return impl, dict(
         causal=bool(causal),
         scale=float(scale or 1.0 / math.sqrt(q.shape[-1] // (heads or 1))),
-        block_q=block_q or _default_block(q.shape[-2]),
-        block_k=block_k or _default_block(k.shape[-2]),
-        interpret=impl == "interpret", heads=heads or None)
+        block_q=block_q or _default_block(q.shape[-2], window),
+        block_k=block_k or _default_block(k.shape[-2], window),
+        interpret=impl == "interpret", heads=heads or None,
+        window=window)
 
 
 def _flash_layout(q, k, v, heads, impl):
@@ -1221,7 +1440,8 @@ def _flash_attention_fwd(q, k, v, **call):
     with _obs_device.annotate("flash_attention"), _kernel_scope():
         if impl == "xla":
             out, lse = _plain_attention(q, k, v, kw["causal"],
-                                        kw["scale"], with_lse=True)
+                                        kw["scale"], with_lse=True,
+                                        window=kw["window"])
         else:
             out, lse = _flash_lse(q, k, v, **kw)
     if transposed:
@@ -1710,7 +1930,7 @@ _decode_reference_multi_jit = jax.jit(_decode_reference_multi_impl,
 
 def flash_decode(q, k_pages, v_pages, block_tables, seq_lens, *,
                  scale=None, impl=None, head_pack=None,
-                 kv_scales=None, vmem_budget_bytes=None):
+                 kv_scales=None, vmem_budget_bytes=None, window=None):
     """Paged-KV decode-step attention.  q: [B, H, d] (ONE query token
     per sequence) or [B, R, H, d] (the SPECULATIVE VERIFY step, ISSUE
     11c: the R = k+1 newest tokens of each sequence as distinct query
@@ -1732,7 +1952,16 @@ def flash_decode(q, k_pages, v_pages, block_tables, seq_lens, *,
     head-packed and not, q_len 1 and k+1.  Verify row r is ALSO
     bit-identical to a q-len-1 call at seq_len - R + 1 + r (masked
     pages are exact no-ops in the online-softmax merge) — the
-    numerical half of the lossless-speculation contract."""
+    numerical half of the lossless-speculation contract.
+
+    window: NOT built.  The page sweep reads every cached page of a
+    sequence; a sliding window needs a first-page bound in the sweep
+    and a cache allocator that frees the pages behind it (ROADMAP
+    2.1)."""
+    if window:
+        raise NotImplementedError(
+            "flash_decode: a sliding window (%r) is not built: the page "
+            "sweep reads a sequence's whole cache" % (window,))
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     scale = float(scale)
@@ -1846,14 +2075,14 @@ def _gspmd_flash_shard_map(attrs, call, operands, kinds):
 
 
 _FLASH_OP_ATTRS = {"causal": False, "scale": 0.0, "block_q": 0,
-                   "block_k": 0, "heads": 0, "gspmd_batch_axis": "",
-                   "gspmd_head_axis": ""}
+                   "block_k": 0, "heads": 0, "window": 0,
+                   "gspmd_batch_axis": "", "gspmd_head_axis": ""}
 
 
 def _flash_op_call(attrs):
     # an attr left at its 0 default means unset, as None does (_call_args)
     return {k: attrs.get(k) for k in
-            ("causal", "scale", "block_q", "block_k")}
+            ("causal", "scale", "block_q", "block_k", "window")}
 
 
 @register_op("flash_attention", inputs=("Q", "K", "V"),
@@ -1924,7 +2153,7 @@ def _flash_attention_grad_op(ins, attrs):
              inputs=("Q", "KPages", "VPages", "BlockTables", "SeqLens",
                      "KScale", "VScale"),
              outputs=("Out",), optional=("KScale", "VScale"),
-             attrs={"scale": 0.0})
+             attrs={"scale": 0.0, "window": 0})
 def _flash_decode_op(ins, attrs):
     """IR surface of the paged decode-step attention (module section
     above); KScale/VScale are the int8-KV per-channel dequant scales."""
@@ -1934,4 +2163,5 @@ def _flash_decode_op(ins, attrs):
     return {"Out": flash_decode(ins["Q"], ins["KPages"], ins["VPages"],
                                 ins["BlockTables"], ins["SeqLens"],
                                 scale=attrs.get("scale") or None,
-                                kv_scales=kv_scales)}
+                                kv_scales=kv_scales,
+                                window=attrs.get("window") or None)}

@@ -40,17 +40,18 @@ def _flash(shape, grad, n_bwd=1):
         (n_bwd + 1 if grad else 1)
 
 
-def _flash_token_major(shape, heads, grad, kv_width=None):
+def _flash_token_major(shape, heads, grad, kv_width=None, window=None):
     """The op's entries on token-major [B, T, H*d] operands, as the
     q, k and v projections leave them: the forward kernel, and the
     one-sweep backward on the saved residuals.  kv_width: K's and V's
-    width where they have fewer heads than Q (grouped KV heads)."""
+    width where they have fewer heads than Q (grouped KV heads).
+    window: a sliding window, the kernels on their band grid."""
     from paddle_tpu.ops.pallas_kernels import (_flash_attention_bwd,
                                                _flash_attention_fwd)
 
     x = _sds(shape)
     kv = _sds(shape[:2] + (kv_width or shape[2],))
-    call = dict(causal=True, impl="pallas", heads=heads)
+    call = dict(causal=True, impl="pallas", heads=heads, window=window)
     if not grad:
         return (lambda q, k, v: _flash_attention_fwd(q, k, v, **call)), \
             (x, kv, kv), 1
@@ -123,6 +124,9 @@ LFM2_GMM = dict(rows=32768, held=16, hidden=2048, width=1536)
 # solar-open2: 8,192 tokens x 8 experts a token at hidden 4,096 over 8
 # held experts of width 1,280 = 10 x 128: the layout's 67,584 rows
 SOLAR_GMM = dict(rows=65536, hidden=4096, width=1280)
+# mellum2: 16,384 tokens x 8 experts a token at hidden 2,304 = 18 x 128
+# over 16 held experts of width 896 = 7 x 128: the layout's 135,168 rows
+MELLUM2_GMM = dict(rows=131072, held=16, hidden=2304, width=896)
 
 
 def _decode(head_dim, head_pack, batch=64, heads=8, page_size=128,
@@ -349,6 +353,16 @@ CASES = {
         _flash_token_major((1, 8192, 1024), 8, False, kv_width=128),
     "flash_bwd_1x8192x1024_token_major_kv1_d128": lambda:
         _flash_token_major((1, 8192, 1024), 8, True, kv_width=128),
+    # mellum2-12b-a2.5b's layers at 16,384 tokens: 32 query heads of
+    # 128 read 4 KV heads in place; a window layer's kernels on their
+    # band grid (1,024 keys, the blocks `_default_block` gives them,
+    # the whole dq zeroed and written by loops over q blocks), and the
+    # full layer's at the length no cell had
+    **{"flash_%s_1x16384x4096_token_major_kv4_d128%s" % (
+        "bwd" if grad else "fwd", "_w1024" if window else ""):
+       (lambda grad=grad, window=window: _flash_token_major(
+           (1, 16384, 4096), 32, grad, kv_width=512, window=window))
+       for grad in (False, True) for window in (None, 1024)},
     "attention_block_64x512x512_token_major": _attention_block,
     "flash_fwd_1x32x4096_qk192_v128": lambda: _flash_mla(False),
     "flash_bwd_saved_1x32x4096_qk192_v128": lambda: _flash_mla(True),
@@ -368,6 +382,11 @@ CASES = {
     "gmm_fwd_8x4096x1280_rows67584": lambda: _gmm("fwd", **SOLAR_GMM),
     "gmm_bwd_dx_8x4096x1280_rows67584": lambda: _gmm("dx", **SOLAR_GMM),
     "gmm_bwd_dw_8x4096x1280_rows67584": lambda: _gmm("dw", **SOLAR_GMM),
+    "gmm_fwd_16x2304x896_rows135168": lambda: _gmm("fwd", **MELLUM2_GMM),
+    "gmm_bwd_dx_16x2304x896_rows135168": lambda: _gmm("dx",
+                                                      **MELLUM2_GMM),
+    "gmm_bwd_dw_16x2304x896_rows135168": lambda: _gmm("dw",
+                                                      **MELLUM2_GMM),
     "flash_decode_d128_b64": lambda: _decode(128, False),
     "flash_decode_d64_headpacked_b64": lambda: _decode(64, True),
     "conv2d_epilogue_3x3_56x56x64_mb128": lambda: _conv(False),
@@ -384,7 +403,14 @@ CASES = {
 # PR 49's review took a lane-slice form of the sum out again for want of
 # a traced time; the cell's trace with and without it is in PERF.md
 # section 6, PR 49
-GROUP_SUM_COPIES = {"flash_bwd_1x8192x1024_token_major_kv1_d128": 2}
+GROUP_SUM_COPIES = {"flash_bwd_1x8192x1024_token_major_kv1_d128": 2,
+                    # mellum2's 32 / 4 heads of 128: the same two, of
+                    # [1, 16384, 4, 8, 128] in float32 (268 MB each),
+                    # window or none
+                    "flash_bwd_1x16384x4096_token_major_kv4_d128": 2,
+                    "flash_bwd_1x16384x4096_token_major_kv4_d128_w1024": 2}
+# the names a windowed call's kernels carry in the compiled module
+WINDOW_KERNELS = {False: "pt_flash_win_fwd", True: "pt_flash_win_bwd_dkv"}
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -400,3 +426,7 @@ def test_kernel_compiles_for_described_v5e(chip_gate, case):
         # no head split or merge around the kernels
         assert chip_gate.head_layout_copies(exe.as_text()) \
             == GROUP_SUM_COPIES.get(case, 0)
+    if case.startswith("flash_") and "_token_major_kv4" in case:
+        want = WINDOW_KERNELS["_bwd_" in case] if case.endswith("_w1024") \
+            else WINDOW_KERNELS["_bwd_" in case].replace("_win", "")
+        assert chip_gate.kernel_calls(exe.as_text()) == {want: 1}

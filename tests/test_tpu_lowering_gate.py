@@ -48,10 +48,10 @@ def test_bench_workload_lowers_for_tpu(chip_gate, workload):
     ("xing4_train_tiny", 5), ("ouro_train_tiny", 24),
     ("dsv2_train_tiny", 5), ("granite_train_tiny", 1),
     ("ling3_train_tiny", 1), ("lfm2_train_tiny", 1),
-    ("solar_open2_train_tiny", 1)])
+    ("solar_open2_train_tiny", 1), ("mellum2_train_tiny", 1)])
 def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         chip_gate, workload, flash_ops):
-    """The seven cells that train under RecomputeOptimizer, at their
+    """The eight cells that train under RecomputeOptimizer, at their
     depth and head sizes, narrow and short: a segment's backward takes
     the forward's Out and LSE (ISSUE 33), so the compiled step holds
     one `pt_flash_fwd` a flash op and not a second in every segment's
@@ -108,6 +108,33 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert workload in chip_gate.MOE_COMBINE_KERNEL
         assert detail["moe_ops"] == 6
         assert detail["kernel_calls"]["pt_moe_combine"] == 12
+    if workload == "mellum2_train_tiny":
+        # one period of mellum2-12b-a2.5b as an expert-parallel rank
+        # holds it, at its head size, group of 8, window (1,024 of
+        # 2,048 tokens), YaRN numbers, router (64 outputs) and expert
+        # width (ISSUE 53): three window layers, each pt_flash_win_fwd
+        # ONCE (a segment binds the saved Out and LSE on the op it
+        # replays) and the one-sweep pt_flash_win_bwd_dkv once, through
+        # Mosaic with their band grid (2 steps a q block of 1,024 rows)
+        # and the loops that zero and write the whole dq; one full layer under the names the other
+        # cells' calls carry; four expert layers' grouped matmuls at
+        # width 896 over K 2,304 and their combines
+        assert detail["window_flash_ops"] == 3
+        assert detail["kernel_calls"]["pt_flash_win_fwd"] == 3
+        assert detail["kernel_calls"]["pt_flash_win_bwd_dkv"] == 3
+        assert "pt_flash_win_bwd_dq" not in detail["kernel_calls"]
+        assert [detail["kernel_calls"][k] for k in (
+            "pt_gmm_fwd", "pt_gmm_bwd_dx", "pt_gmm_bwd_dw")] == [24, 12, 12]
+        assert workload in chip_gate.MOE_COMBINE_KERNEL
+        assert detail["moe_ops"] == 4
+        assert detail["kernel_calls"]["pt_moe_combine"] == 8
+        assert detail["kernel_calls"]["pt_row_buffer"] == 20
+        assert detail["tpu_custom_calls"] == 84
+        from paddle_tpu import framework
+
+        ops = framework.default_main_program().global_block().ops
+        assert [op.attrs["window"] for op in ops
+                if op.type == "flash_attention"] == [1024, 1024, 1024, 0]
     if workload == "solar_open2_train_tiny":
         # one period of solar-open2-250b as a rank holds it, at its
         # head sizes, chunking, taps, router (320 outputs) and expert
@@ -342,7 +369,10 @@ GMM_CELLS = {"dsv2": (49152, 2048, 1408, 8), "xing4": (16384, 3584, 1024, 8),
              # ISSUE 45: 8,192 tokens x 4, width 1,536 = 12 x 128
              "lfm2": (32768, 2048, 1536, 16),
              # ISSUE 49: 8,192 tokens x 8 at hidden 4,096, width 1,280
-             "solar_open2": (65536, 4096, 1280, 8)}
+             "solar_open2": (65536, 4096, 1280, 8),
+             # ISSUE 53: 16,384 tokens x 8, K 2,304 = 18 x 128, width
+             # 896 = 7 x 128, 16 held
+             "mellum2": (131072, 2304, 896, 16)}
 GMM_TM = 256
 # The six grouped-matmul call forms of an expert layer's forward and
 # backward (moe_experts' _routed_fwd / _routed_bwd): the kernel
@@ -475,7 +505,10 @@ GMM_TILES = {
              "dw_down": (2048, 1536), "dw_gate_up": (1536, 2048)},
     "solar_open2": {"fwd_gate_up": (1280, 4096), "fwd_down": (4096, 1280),
                     "dx_down": (1280, 4096), "dx_gate_up": (4096, 1280),
-                    "dw_down": (4096, 640), "dw_gate_up": (1280, 2048)}}
+                    "dw_down": (4096, 640), "dw_gate_up": (1280, 2048)},
+    "mellum2": {"fwd_gate_up": (896, 2304), "fwd_down": (2304, 896),
+                "dx_down": (896, 2304), "dx_gate_up": (2304, 896),
+                "dw_down": (2304, 896), "dw_gate_up": (896, 2304)}}
 
 
 @pytest.mark.parametrize("call", sorted(GMM_CALLS))
@@ -497,12 +530,13 @@ def test_tiles_at_the_cells_shapes(cell, call):
 
 @pytest.mark.parametrize("cell,most", [("dsv2", 38), ("xing4", 89),
                                        ("ling3", 12), ("lfm2", 12),
-                                       ("solar_open2", 15)])
+                                       ("solar_open2", 15),
+                                       ("mellum2", 12)])
 def test_a_live_tile_layer_costs_few_grid_steps(cell, most):
     """What one more live row tile of one expert layer adds to a
     step's grids, summed over the layer's twelve calls, from their
     jaxprs.  The rule gives 12 where every matrix is a block (dsv2,
-    xing4, ling3, lfm2) and 15 in solar_open2 (d W_down, d W_gate and
+    xing4, ling3, lfm2, mellum2) and 15 in solar_open2 (d W_down, d W_gate and
     d W_up take halves).  The bounds:
     dsv2 38, what 1,408 whole over blocks of 512 and 1,024 costs (4 +
     4 + 2 forward, twice; 4 + 2 + 2 for dx; 2 + 4 + 4 for dw), where

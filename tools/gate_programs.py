@@ -290,6 +290,13 @@ def _build_solar_open2_train(batch=1, seq=8192, **sizes):
                              batch, seq, sizes)
 
 
+def _build_mellum2_train(batch=1, seq=16384, **sizes):
+    """One expert-parallel rank's train step of Mellum2-12B-A2.5B as
+    the cell `mellum2_12b_train_s16k` runs it."""
+    return _build_cell_train("mellum2-12b-a2.5b.json", "mellum2.py",
+                             batch, seq, sizes)
+
+
 def _build_xing4_train(batch=1, seq=4096, **sizes):
     """The 2024-26 decoder block's train step as the cell
     `xing4_29b_train_s4k` runs it."""

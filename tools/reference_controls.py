@@ -7,7 +7,9 @@ or computed in a lower precision: for each seed the cell's program is
 built and initialised as benchmarks/kinds/train_steps.py does (the
 builder, np.random.seed(seed), the startup program, pool batch 0 from
 default_rng(seed)), and the reference's loss on those weights is
-printed as it is, for each `variant` the reference knows, and wholly in
+printed as it is, for each `variant` asked for (`--variants all`: every
+one the reference lists in `VARIANTS`, as benchmarks/reference/mellum2.py
+does: no_window, no_yarn, gates_not_renormalised), and wholly in
 `dtype`; each beside its distance from the float32 reading, as a share
 of it (the variants for the first K seeds only, where K is given), and
 beside what the loop kind's own comparison says of it at the
@@ -98,6 +100,8 @@ def main(argv=None):
     flops = harness._load_file(os.path.join(bench, "flops.py"))
     ref = load("reference", config["reference"])
     tol = config["reference_rtol"]
+    if args.variants == ["all"]:
+        args.variants = list(ref.VARIANTS)
     rows = []
     for i, seed in enumerate(args.seeds):
         variants = args.variants if args.variant_seeds is None \

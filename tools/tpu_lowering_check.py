@@ -186,6 +186,23 @@ def _workloads():
         "solar_open2_train_tiny": lambda: progs._build_solar_open2_train(
             1, 512, hidden_size=256, num_attention_heads=2,
             kda_heads_held=2, vocab_size=512)[:3],
+        # one expert-parallel rank of Mellum2-12B-A2.5B at the cell's
+        # sizes (1 x 16,384 tokens at hidden 2,304: three window layers
+        # of 1,024 keys on the band grid and one YaRN full-attention
+        # layer at 32 / 4 heads of 128, 16 of 64 experts held at width
+        # 896, 595 M parameters): whether 9.52 GB of state and the
+        # step's activations at 16,384 tokens fit (STEP_BYTES_MAX), and
+        # that each flash op's forward kernel runs once, the window
+        # layers' under their own name (ONE_FLASH_FWD_AN_OP)
+        "mellum2_train": lambda: progs._build_mellum2_train(
+            1, 16384)[:3],
+        # the published head size, group of 8, window (1,024 of 2,048
+        # tokens: a band of the grid), YaRN numbers, router (64
+        # outputs) and expert width, one period of layers, everything
+        # else narrow
+        "mellum2_train_tiny": lambda: progs._build_mellum2_train(
+            1, 2048, hidden_size=256, num_attention_heads=8,
+            num_key_value_heads=1, vocab_size=512)[:3],
         # both at the cells' depth and head sizes, narrow and short
         # (256 tokens; seconds to compile): what is checked is how many
         # kernels the step holds, not whether it fits
@@ -376,7 +393,7 @@ def _infer(progs, which, batch, conv_epilogue=False):
 
 FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train",
              "xing4_train", "dsv2_train", "granite_train", "ling3_train",
-             "lfm2_train", "solar_open2_train")
+             "lfm2_train", "solar_open2_train", "mellum2_train")
 
 # the steps whose attention takes q, k and v token-major, [B, T, H*d]
 # as the projections leave them: their compiled step may hold no head
@@ -399,7 +416,9 @@ def head_layout_copies(hlo_text):
 
 # the training steps whose every flash_attention op has a grad that
 # reads the forward's Out and LSE, as a grad op of its own or inside a
-# recompute segment: the forward kernel runs once an op
+# recompute segment: the forward kernel runs once an op (an op with a
+# window its own, pt_flash_win_fwd, and the one-sweep backward
+# pt_flash_win_bwd_dkv: `window_flash_ops`)
 ONE_FLASH_FWD_AN_OP = ("transformer_train", "transformer_train_gspmd",
                        "ouro_train", "ouro_train_tiny", "xing4_train",
                        "xing4_train_tiny", "dsv2_train",
@@ -407,7 +426,8 @@ ONE_FLASH_FWD_AN_OP = ("transformer_train", "transformer_train_gspmd",
                        "granite_train_tiny", "ling3_train",
                        "ling3_train_tiny", "lfm2_train",
                        "lfm2_train_tiny", "solar_open2_train",
-                       "solar_open2_train_tiny")
+                       "solar_open2_train_tiny", "mellum2_train",
+                       "mellum2_train_tiny")
 
 # the training steps whose every ssd_scan op has a grad that reads the
 # forward's Y and chunk-start states inside its recompute segment: the
@@ -454,7 +474,8 @@ GATED_CONV_IN_PLACE = {"lfm2_train": 8192, "lfm2_train_tiny": 512}
 MOE_COMBINE_KERNEL = ("xing4_train", "xing4_train_tiny", "dsv2_train",
                       "dsv2_train_tiny", "ling3_train", "ling3_train_tiny",
                       "lfm2_train", "lfm2_train_tiny",
-                      "solar_open2_train", "solar_open2_train_tiny")
+                      "solar_open2_train", "solar_open2_train_tiny",
+                      "mellum2_train", "mellum2_train_tiny")
 
 
 # the training steps whose hyper-connections run the kernels of
@@ -557,7 +578,12 @@ STEP_BYTES_MAX = {"dsv2_train": 9_700_000_000,
                   # moments, 5.23 GB of gradients and a segment's
                   # activations at 8,192 tokens x hidden 4,096; the
                   # chip holds 15.75 GiB = 16.9e9
-                  "solar_open2_train": 15_400_000_000}
+                  "solar_open2_train": 15_400_000_000,
+                  # PR 53 reads 13,356,171,776: 7.14 GB of weights and
+                  # float32 Adam moments, 6.21 GB of gradients, the
+                  # [16384, 24576] float32 logits with their gradient
+                  # and a segment's replay at 16,384 tokens
+                  "mellum2_train": 13_600_000_000}
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", re.M)
 _CALLED = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
@@ -654,12 +680,19 @@ def check_workload(name, build):
         if name in ONE_FLASH_FWD_AN_OP:
             from paddle_tpu import framework
 
-            detail["flash_ops"] = sum(
-                op.type == "flash_attention" for op in
-                framework.default_main_program().global_block().ops)
+            flash = [op for op in
+                     framework.default_main_program().global_block().ops
+                     if op.type == "flash_attention"]
+            windowed = sum(bool(op.attrs.get("window")) for op in flash)
+            detail["flash_ops"] = len(flash) - windowed
             detail["kernel_calls"] = kernel_calls(text)
-            ok &= detail["kernel_calls"].get("pt_flash_fwd") \
-                == detail["flash_ops"] > 0
+            ok &= len(flash) > 0 and detail["kernel_calls"].get(
+                "pt_flash_fwd", 0) == detail["flash_ops"]
+            if windowed:
+                detail["window_flash_ops"] = windowed
+                ok &= detail["kernel_calls"].get("pt_flash_win_fwd") \
+                    == detail["kernel_calls"].get("pt_flash_win_bwd_dkv") \
+                    == windowed
         if name in ONE_SSD_FWD_AN_OP:
             from paddle_tpu import framework
 
