@@ -47,12 +47,15 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
 def rotary_embedding(x, rotary_dim=None, theta=10000.0, factor=1.0,
                      original_max_position=4096, beta_fast=32.0,
                      beta_slow=1.0, mscale=1.0, pairing="interleaved",
-                     name=None):
+                     n_head=0, name=None):
     """Rotary position embedding of x [B, T, H, D] over positions
     0..T-1: the last `rotary_dim` entries of D (default all) rotate,
     as interleaved pairs (x[2i], x[2i+1]) or, with pairing="halves",
     as split halves (x[i], x[i + rotary_dim/2]); YaRN-scaled
-    frequencies when factor != 1 (ops/llm_ops.py yarn_inv_freq)."""
+    frequencies when factor != 1 (ops/llm_ops.py yarn_inv_freq).
+    With `n_head` = H, x is a projection as it comes, [B, T, H D], and
+    so is the result: no reshape to heads around the op, which on a TPU
+    turns x where it lies (ops/pallas_rotary.py)."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(
@@ -62,7 +65,7 @@ def rotary_embedding(x, rotary_dim=None, theta=10000.0, factor=1.0,
                "original_max_position": int(original_max_position),
                "beta_fast": float(beta_fast),
                "beta_slow": float(beta_slow), "mscale": float(mscale),
-               "pairing": str(pairing)})
+               "pairing": str(pairing), "n_head": int(n_head)})
     return out
 
 

@@ -116,10 +116,8 @@ def lfm2_model(config, seq_len, param_prefix="lfm2"):
     def head_norm_rotary(x, n, name):
         x = layers.head_gated_rms_norm(
             x, None, eps, n_head=n, name="%s_%s" % (p, name))
-        x = layers.rotary_embedding(
-            layers.reshape(x, [-1, seq_len, n, d]),
-            theta=rope["rope_theta"], pairing="halves")
-        return layers.reshape(x, [-1, seq_len, n * d])
+        return layers.rotary_embedding(x, theta=rope["rope_theta"],
+                                       pairing="halves", n_head=n)
 
     def attention(u, lp):
         # k and v at num_key_value_heads heads: the kernels read a

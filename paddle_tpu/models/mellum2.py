@@ -143,10 +143,9 @@ def mellum2_model(config, seq_len, param_prefix="mellum2"):
         return layers.rms_norm(x, eps, name="%s_%s" % (p, name))
 
     def turned(x, n, kind):
-        x = layers.rotary_embedding(
-            layers.reshape(x, [-1, seq_len, n, d]), pairing="halves",
-            **rotary[kind])
-        return layers.reshape(x, [-1, seq_len, n * d])
+        # the projection as it comes: n heads side by side
+        return layers.rotary_embedding(x, pairing="halves", n_head=n,
+                                       **rotary[kind])
 
     def attention(u, lp, kind):
         # k and v at num_key_value_heads heads: the kernels read a

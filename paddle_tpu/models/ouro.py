@@ -20,8 +20,9 @@ do not carry r: the global block holds each parameter once
 (LayerHelper.create_parameter shares by name), so the persistables and
 the optimizer's state do not grow with R, and R = 1 is a plain
 four-norm decoder.  Attention is token-major end to end: [B, T, H*d]
-projections, a free reshape to [B, T, H, d] for the rotary embedding
-and back, `flash_attention(..., n_head=H)`.
+projections, turned where they lie (`rotary_embedding(..., n_head=H)`:
+no reshape to [B, T, H, d], which is a relayout of every tile, PERF.md,
+PR 54), `flash_attention(..., n_head=H)`.
 
 As a Fluid trainer uses it:
 
@@ -76,10 +77,9 @@ def ouro_model(config, seq_len, param_prefix="ouro"):
         return layers.rms_norm(x, eps, name="%s_%s" % (p, name))
 
     def rotary(x, n):
-        x = layers.rotary_embedding(
-            layers.reshape(x, [-1, seq_len, n, d]),
-            theta=config["rope_theta"], pairing="halves")
-        return layers.reshape(x, [-1, seq_len, n * d])
+        # the projection as it comes: n heads side by side
+        return layers.rotary_embedding(x, theta=config["rope_theta"],
+                                       pairing="halves", n_head=n)
 
     def layer(x, lp):
         a = norm(x, lp + "_norm1")

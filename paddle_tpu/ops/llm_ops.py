@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core.registry import REQUIRED, register_op
-from paddle_tpu.ops import pallas_mhc
+from paddle_tpu.ops import pallas_mhc, pallas_rotary
 from paddle_tpu.ops.pallas_mhc import sinkhorn  # noqa: F401 (its users' name)
 
 _F32 = jnp.float32
@@ -103,62 +103,128 @@ def yarn_inv_freq(dim, theta, factor, original_max, beta_fast, beta_slow):
     return inv.astype(np.float32)
 
 
+def _rotary_xla(x, rd, pairing, inv, mscale):
+    """The op as an XLA graph, X [B, T, H, D]: the partner found by a
+    reshape of the last axis and a flip."""
+    t, d = x.shape[1], x.shape[-1]
+    ang = jnp.arange(t, dtype=_F32)[:, None] * jnp.asarray(inv)[None]
+    if pairing == "halves":
+        cos = (jnp.cos(ang) * mscale)[None, :, None, None, :]
+        sin = (jnp.sin(ang) * mscale)[None, :, None, None, :]
+        rot = x[..., d - rd:].astype(_F32).reshape(
+            x.shape[:-1] + (2, rd // 2))
+        # (a, b) -> (a cos - b sin, b cos + a sin), a the first half
+        turned = jnp.flip(rot, -2) * jnp.asarray([[-1.0], [1.0]], _F32)
+        out = (rot * cos + turned * sin).reshape(
+            x.shape[:-1] + (rd,)).astype(x.dtype)
+        if rd < d:
+            out = jnp.concatenate([x[..., :d - rd], out], axis=-1)
+        return out
+    # the pairs that pass through turn by the angle 0.  One product
+    # over all of D: a slice and a concatenate would each cost a
+    # copy of X forward and a padded copy of its gradient backward
+    keep = (d - rd) // 2
+    cos = jnp.pad(jnp.cos(ang) * mscale, ((0, 0), (keep, 0)),
+                  constant_values=1.0)[None, :, None, :, None]
+    sin = jnp.pad(jnp.sin(ang) * mscale, ((0, 0), (keep, 0))
+                  )[None, :, None, :, None]
+    pairs = x.astype(_F32).reshape(x.shape[:-1] + (d // 2, 2))
+    # (a, b) -> (a cos - b sin, a sin + b cos)
+    turned = jnp.flip(pairs, -1) * jnp.asarray([-1.0, 1.0], _F32)
+    out = pairs * cos + turned * sin
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _rotary_kernel(x, geom, interpret, back=False):
+    """pt_rotary over X [B, T, H D]; geom = (D, pairing, mscale,
+    yarn_inv_freq's arguments).  `back`: by the negative angle, which
+    is the op's transpose, so the kernel differentiates itself."""
+    d, pairing, mscale, freq = geom
+    rd = freq[0]
+    cos, sin = pallas_rotary.tables(x.shape[1], d, rd, pairing,
+                                    yarn_inv_freq(*freq), mscale, back=back)
+    with _kernel_call("rotary_embedding_grad" if back
+                      else "rotary_embedding"):
+        return pallas_rotary.rotary_pallas(
+            x, cos, sin, d=d,
+            rule=pallas_rotary.partner_rule(d, rd, pairing),
+            interpret=interpret)
+
+
+def _rotary_kernel_fwd(x, geom, interpret, back):
+    return _rotary_kernel(x, geom, interpret, back), None
+
+
+def _rotary_kernel_bwd(geom, interpret, back, _, g):
+    # traced under the forward's name stack: the op's scope is on it
+    return (_rotary_kernel(g, geom, interpret, not back),)
+
+
+_rotary_kernel.defvjp(_rotary_kernel_fwd, _rotary_kernel_bwd)
+
+
 @register_op("rotary_embedding", inputs=("X",), outputs=("Out",),
              attrs={"rotary_dim": 0, "theta": 10000.0, "factor": 1.0,
                     "original_max_position": 4096, "beta_fast": 32.0,
                     "beta_slow": 1.0, "mscale": 1.0,
-                    "pairing": "interleaved"})
+                    "pairing": "interleaved", "n_head": 0, "impl": ""})
 def rotary_embedding(ins, attrs):
-    """X [B, T, H, D]: rotates the LAST rotary_dim entries of D (0: all
-    of D) by the angle position * inv_freq[i], positions 0..T-1 along
-    axis 1; the leading D - rotary_dim entries pass through.  `pairing`
-    says which two entries turn together: "interleaved" (the default,
-    deepseek_v3's) the neighbours (x[2i], x[2i+1]), "halves" (the
-    Llama lineage's rotate_half) the entries (x[i], x[i + rd/2]) of the
-    rotated part.  The frequencies are a constant built from the
-    attributes (yarn_inv_freq); cos and sin are multiplied by
-    `mscale`."""
+    """X [B, T, H, D], or with `n_head` = H the projection as it comes,
+    [B, T, H D]: rotates the LAST rotary_dim entries of every head's D
+    (0: all of D) by the angle position * inv_freq[i], positions 0..T-1
+    along axis 1; the leading D - rotary_dim entries pass through.
+    `pairing` says which two entries turn together: "interleaved" (the
+    default, deepseek_v3's) the neighbours (x[2i], x[2i+1]), "halves"
+    (the Llama lineage's rotate_half) the entries (x[i], x[i + rd/2])
+    of the rotated part.  The frequencies are a constant built from the
+    attributes (yarn_inv_freq); cos and sin are multiplied by `mscale`.
+    Float32 inside, Out in X's shape and dtype.
+
+    impl: "" (the kernel pt_rotary on a TPU where it can tile X, H D
+    whole lane tiles and T a multiple of 32: one pass over X where it
+    lies, its gradient the same kernel at the negative angle,
+    ops/pallas_rotary.py; the XLA graph elsewhere), "pallas",
+    "interpret", "xla"; paddle_tpu_kernel_impl_total{kernel="rotary"}
+    says which ran."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
     x = ins["X"]
-    d = x.shape[-1]
+    heads = attrs.get("n_head") or 0
+    if heads and (x.ndim != 3 or x.shape[-1] % heads):
+        raise ValueError("rotary_embedding: n_head %d takes X [B, T, H D], "
+                         "not %s" % (heads, x.shape,))
+    if not heads and x.ndim != 4:
+        raise ValueError("rotary_embedding: X %s is not [B, T, H, D] (a "
+                         "flat [B, T, H D] names its n_head)" % (x.shape,))
+    d = x.shape[-1] // heads if heads else x.shape[-1]
     rd = attrs["rotary_dim"] or d
     if rd % 2 or (d - rd) % 2:
         raise ValueError("rotary_embedding: rotary_dim %d of %d must "
                          "leave whole pairs on both sides" % (rd, d))
-    if attrs["pairing"] not in ("interleaved", "halves"):
+    pairing = attrs["pairing"]
+    if pairing not in ("interleaved", "halves"):
         raise ValueError("rotary_embedding: pairing %r is neither "
-                         "'interleaved' nor 'halves'" % attrs["pairing"])
-    inv = yarn_inv_freq(rd, float(attrs["theta"]), float(attrs["factor"]),
-                        int(attrs["original_max_position"]),
-                        float(attrs["beta_fast"]),
-                        float(attrs["beta_slow"]))
+                         "'interleaved' nor 'halves'" % pairing)
+    freq = (rd, float(attrs["theta"]), float(attrs["factor"]),
+            int(attrs["original_max_position"]), float(attrs["beta_fast"]),
+            float(attrs["beta_slow"]))
+    b, t = x.shape[:2]
+    width = math.prod(x.shape[2:])
+    impl = attrs.get("impl") or pk._auto_impl()
+    if impl != "xla" and pallas_rotary.blocks(t, width, d) is None:
+        impl = "xla"
+    pk._count_impl("rotary", impl)
     with jax.named_scope("pt_mla"):
-        t = x.shape[1]
-        ang = jnp.arange(t, dtype=_F32)[:, None] * jnp.asarray(inv)[None]
-        if attrs["pairing"] == "halves":
-            cos = (jnp.cos(ang) * attrs["mscale"])[None, :, None, None, :]
-            sin = (jnp.sin(ang) * attrs["mscale"])[None, :, None, None, :]
-            rot = x[..., d - rd:].astype(_F32).reshape(
-                x.shape[:-1] + (2, rd // 2))
-            # (a, b) -> (a cos - b sin, b cos + a sin), a the first half
-            turned = jnp.flip(rot, -2) * jnp.asarray([[-1.0], [1.0]], _F32)
-            out = (rot * cos + turned * sin).reshape(
-                x.shape[:-1] + (rd,)).astype(x.dtype)
-            if rd < d:
-                out = jnp.concatenate([x[..., :d - rd], out], axis=-1)
-            return {"Out": out}
-        # the pairs that pass through turn by the angle 0.  One product
-        # over all of D: a slice and a concatenate would each cost a
-        # copy of X forward and a padded copy of its gradient backward
-        keep = (d - rd) // 2
-        cos = jnp.pad(jnp.cos(ang) * attrs["mscale"], ((0, 0), (keep, 0)),
-                      constant_values=1.0)[None, :, None, :, None]
-        sin = jnp.pad(jnp.sin(ang) * attrs["mscale"], ((0, 0), (keep, 0))
-                      )[None, :, None, :, None]
-        pairs = x.astype(_F32).reshape(x.shape[:-1] + (d // 2, 2))
-        # (a, b) -> (a cos - b sin, a sin + b cos)
-        turned = jnp.flip(pairs, -1) * jnp.asarray([-1.0, 1.0], _F32)
-        out = pairs * cos + turned * sin
-        return {"Out": out.reshape(x.shape).astype(x.dtype)}
+        if impl == "xla":
+            out = _rotary_xla(x.reshape(b, t, -1, d), rd, pairing,
+                              yarn_inv_freq(*freq), attrs["mscale"])
+        else:
+            out = _rotary_kernel(
+                x.reshape(b, t, width),
+                (d, pairing, float(attrs["mscale"]), freq),
+                impl == "interpret")
+        return {"Out": out.reshape(x.shape)}
 
 
 # ---------------------------------------------------------------------------

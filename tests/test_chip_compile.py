@@ -261,6 +261,56 @@ def _mhc(kernel, b, t, dtype, n=4, c=3584):
                          _sds((b, n, n, t), f32)), 1
 
 
+def _rotary(shape, heads, pairing, rotary_dim, grad, dtype=BF16):
+    """pt_rotary (ops/pallas_rotary.py) through the op's compute, its
+    tables with it, on a projection [B, T, H d] as it comes; `grad`:
+    jax.vjp of the op, the same kernel at the negative angle."""
+    from paddle_tpu.core.registry import get_op_def
+
+    op = get_op_def("rotary_embedding")
+    attrs = dict(op.attrs, pairing=pairing, rotary_dim=rotary_dim,
+                 n_head=heads, impl="pallas", factor=4.0, mscale=1.2)
+
+    def fwd(x):
+        return op.compute({"X": x}, attrs)["Out"]
+
+    if not grad:
+        return fwd, (_sds(shape, dtype),), 1
+    return (lambda x, g: jax.vjp(fwd, x)[1](g)[0]), \
+        (_sds(shape, dtype),) * 2, 1
+
+
+def _mellum2_attention_inputs(kernel):
+    """A window layer of mellum2_12b_train_s16k from the normed stream
+    to the flash call: the three projections, q and k turned, the
+    windowed forward kernel.  kernel: the rotary op on the projections
+    as they come (pt_rotary); not: the parent's form, a reshape to
+    [B, T, H, 128] round the XLA graph."""
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.ops.pallas_kernels import _flash_attention_fwd
+
+    t, c, heads, kv_heads, d = 16384, 2304, 32, 4, 128
+    op = get_op_def("rotary_embedding")
+
+    def turned(x, n):
+        attrs = op.canonical_attrs(dict(
+            pairing="halves", n_head=n if kernel else 0,
+            impl="pallas" if kernel else "xla"))
+        if not kernel:
+            x = x.reshape(1, t, n, d)
+        return op.compute({"X": x}, attrs)["Out"].reshape(1, t, n * d)
+
+    def layer(u, wq, wk, wv):
+        return _flash_attention_fwd(
+            turned(jnp.dot(u, wq), heads), turned(jnp.dot(u, wk), kv_heads),
+            jnp.dot(u, wv), causal=True, impl="pallas", heads=heads,
+            window=1024)
+
+    return layer, (_sds((1, t, c)), _sds((c, heads * d)),
+                   _sds((c, kv_heads * d)), _sds((c, kv_heads * d))), \
+        3 if kernel else 1
+
+
 def _moe_combine(n, k, c, rows, f32_rows):
     """A layer's combine by token at a cell's size, 8 experts held: the
     forward's (bf16 rows, gated) or d x's (float32 rows), the plan made
@@ -279,7 +329,31 @@ def _moe_combine(n, k, c, rows, f32_rows):
                   _sds((n, k), jnp.float32)), 1
 
 
+# shape, heads, pairing, rotary_dim: q and k of mellum2 (16,384 tokens,
+# 32 / 4 heads of 128), ouro (16 of 128), lfm2 (32 of 64: two heads a
+# lane tile), xing4 and dsv2 (32 and 2 x 16 heads of 192, the last 64
+# interleaved); then what no cell runs: the other pairing at each head
+# size, a part of a 128-lane head in halves, float32
+ROTARY = [((1, 16384, 4096), 32, "halves", 0),
+          ((1, 16384, 512), 4, "halves", 0),
+          ((1, 4096, 2048), 16, "halves", 0),
+          ((1, 8192, 2048), 32, "halves", 0),
+          ((1, 4096, 6144), 32, "interleaved", 64),
+          ((2, 4096, 3072), 16, "interleaved", 64),
+          ((1, 4096, 6144), 32, "halves", 64),
+          ((1, 4096, 2048), 16, "halves", 64),
+          ((1, 4096, 2048), 16, "interleaved", 0),
+          ((1, 8192, 2048), 32, "interleaved", 0)]
+
 CASES = {
+    **{"rotary_%s_%s_%s_h%d_rd%d%s" % (
+        ("bwd" if grad else "fwd", "x".join(map(str, shape)), pairing,
+         heads, rd, "" if dtype is BF16 else "_float32")):
+       (lambda shape=shape, heads=heads, pairing=pairing, rd=rd, grad=grad,
+        dtype=dtype: _rotary(shape, heads, pairing, rd, grad, dtype))
+       for shape, heads, pairing, rd in ROTARY for grad in (False, True)
+       for dtype in ((BF16, jnp.float32) if shape[1:] == (4096, 2048)
+                     else (BF16,))},
     # float32 streams leave mhc_pre's backward to XLA at this width (its
     # blocks pass the 40 MiB a kernel allows itself): no kernel to compile
     **{"mhc_%s_%dx4x%dx3584_%s" % (kernel, b, t, jnp.dtype(dtype).name):
@@ -387,6 +461,10 @@ CASES = {
                                                       **MELLUM2_GMM),
     "gmm_bwd_dw_16x2304x896_rows135168": lambda: _gmm("dw",
                                                       **MELLUM2_GMM),
+    "mellum2_attention_inputs_rotary_kernel":
+    lambda: _mellum2_attention_inputs(True),
+    "mellum2_attention_inputs_rotary_xla":
+    lambda: _mellum2_attention_inputs(False),
     "flash_decode_d128_b64": lambda: _decode(128, False),
     "flash_decode_d64_headpacked_b64": lambda: _decode(64, True),
     "conv2d_epilogue_3x3_56x56x64_mb128": lambda: _conv(False),
@@ -409,6 +487,13 @@ GROUP_SUM_COPIES = {"flash_bwd_1x8192x1024_token_major_kv1_d128": 2,
                     # window or none
                     "flash_bwd_1x16384x4096_token_major_kv4_d128": 2,
                     "flash_bwd_1x16384x4096_token_major_kv4_d128_w1024": 2}
+# relayouts of a [1, 16384, H 128] array between the projection and the
+# flash call (gate.token_relayouts): the XLA rotary form's reshape of
+# the lanes costs q and k a copy each (and float32 fusions in a layout
+# of their own), which the count sees; pt_rotary turns them where they
+# lie
+ROTARY_RELAYOUTS = {"mellum2_attention_inputs_rotary_kernel": 0,
+                    "mellum2_attention_inputs_rotary_xla": 2}
 # the names a windowed call's kernels carry in the compiled module
 WINDOW_KERNELS = {False: "pt_flash_win_fwd", True: "pt_flash_win_bwd_dkv"}
 
@@ -426,6 +511,9 @@ def test_kernel_compiles_for_described_v5e(chip_gate, case):
         # no head split or merge around the kernels
         assert chip_gate.head_layout_copies(exe.as_text()) \
             == GROUP_SUM_COPIES.get(case, 0)
+    if case in ROTARY_RELAYOUTS:
+        assert chip_gate.token_relayouts(exe.as_text(), 16384) \
+            == ROTARY_RELAYOUTS[case]
     if case.startswith("flash_") and "_token_major_kv4" in case:
         want = WINDOW_KERNELS["_bwd_" in case] if case.endswith("_w1024") \
             else WINDOW_KERNELS["_bwd_" in case].replace("_win", "")

@@ -258,8 +258,12 @@ def test_kernels_in_interpret_mode_against_reference(interpret):
     assert used[("flash_attention", "interpret")] == 1
     assert used[("flash_attention_kv_heads", "grouped")] > 0
     assert used[("moe_gmm", "interpret")] > 0
-    assert not [k for k in used if k[1] in ("xla", "recompute",
-                                            "repeated")]
+    # q's two heads of 64 fill a lane tile and pt_rotary turns them;
+    # the ONE key head of 64 fills none, so k's op is the XLA form
+    # (ISSUE 54; the cell's 8 key heads are four lane tiles)
+    assert used[("rotary", "interpret")] > 0
+    assert [k for k in used if k[1] in ("xla", "recompute", "repeated")] \
+        == [("rotary", "xla")]
 
 
 def test_float32_tolerance_excludes_bf16():

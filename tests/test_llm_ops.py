@@ -128,6 +128,128 @@ def test_rotary_rotates_interleaved_pairs(rotary_dim):
                                rtol=1e-5)
 
 
+# pt_rotary (ops/pallas_rotary.py) in interpret mode against the XLA
+# form.  (D, rotary_dim, H): 12 heads of 128 are two lane blocks of 768,
+# 64 two heads a lane tile, 192 tables of 384 lanes; T 96 is three row
+# tiles of 32, so a tile's positions are its own and not the first's
+ROTARY_HEADS = {(128, 0): 12, (64, 0): 6, (192, 64): 4, (128, 64): 3}
+
+
+def _rotary_case(pairing, d, rd, flat, dtype, seed=5):
+    """(x, g, attrs of the op, the [B, T, H, D] shape)."""
+    rng = np.random.default_rng(seed)
+    shape = (2, 96, ROTARY_HEADS[d, rd], d)
+    laid = shape[:2] + (shape[2] * d,) if flat else shape
+    x, g = (jnp.asarray(rng.normal(0, 1, laid), dtype) for _ in range(2))
+    attrs = dict(pairing=pairing, rotary_dim=rd, theta=10000.0,
+                 n_head=shape[2] if flat else 0)
+    if (d, rd) == (128, 64):        # YaRN's ramp and its factor on cos, sin
+        attrs.update(factor=40.0, original_max_position=16, mscale=1.3)
+    return x, g, attrs, shape
+
+
+def _rotary_roundings(x, attrs, shape, back=False):
+    """The three float32 values x cos + partner(x) sin may round to, by
+    entry, over the kernel's own tables: both products rounded before
+    the add (the chip), or either fused into it (this CPU's compiler,
+    where its fusions let it; a float32 product is exact in float64)."""
+    from paddle_tpu.ops import pallas_rotary
+    from paddle_tpu.ops.llm_ops import yarn_inv_freq
+
+    b, t, h, d = shape
+    rd = attrs["rotary_dim"] or d
+    inv = yarn_inv_freq(rd, attrs["theta"], attrs.get("factor", 1.0),
+                        attrs.get("original_max_position", 4096), 32.0, 1.0)
+    cos, sin = (np.tile(np.asarray(tab)[:, :d], (1, h))[None]
+                for tab in pallas_rotary.tables(
+                    t, d, rd, attrs["pairing"], inv,
+                    attrs.get("mscale", 1.0), back=back))
+    x = np.asarray(x, np.float32).reshape(b, t, h * d)
+    period, cut, shift, _ = pallas_rotary.partner_rule(
+        d, rd, attrs["pairing"])
+    lane = np.arange(h * d)
+    partner = x[..., np.where(lane % period < cut, lane + shift,
+                              lane - shift) % (h * d)]
+    x64, p64 = x.astype(np.float64), partner.astype(np.float64)
+    return [x * cos + partner * sin,
+            (x64 * cos + partner * sin).astype(np.float32),
+            (x * cos + p64 * sin).astype(np.float32)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("flat", [False, True], ids=["4d", "flat"])
+@pytest.mark.parametrize("d,rd", sorted(ROTARY_HEADS))
+@pytest.mark.parametrize("pairing", ["halves", "interleaved"])
+def test_rotary_kernel_is_the_xla_form(pairing, d, rd, flat, dtype):
+    """Out and jax.vjp of the op, the kernel against the XLA graph: in
+    float32 every entry of either is one of the roundings of the SAME
+    two products (so on the chip, which fuses neither, the two are
+    equal to the last bit: tools/rotary_price.py holds them to that),
+    and the two are within a float32 ulp of each other; in bfloat16
+    within one bf16 ulp.  The backward is the forward at the negative
+    angle: nothing but Out@GRAD is read."""
+    x, g, attrs, shape = _rotary_case(pairing, d, rd, flat, dtype)
+
+    def run(impl):
+        out, vjp = jax.vjp(lambda v: _op("rotary_embedding", {"X": v},
+                                         impl=impl, **attrs)["Out"], x)
+        assert out.shape == x.shape and out.dtype == x.dtype
+        return [np.asarray(a.astype(jnp.float32)).reshape(
+            shape[:2] + (-1,)) for a in (out, vjp(g)[0])]
+
+    got, want = run("interpret"), run("xla")
+    for which, (a, b, of) in enumerate(zip(got, want, (x, g))):
+        if dtype == "float32":
+            fits = _rotary_roundings(of, attrs, shape, back=bool(which))
+            for form in (a, b):
+                assert np.any([form == f for f in fits], axis=0).all()
+            np.testing.assert_allclose(a, b, rtol=2.0 ** -23, atol=1e-7)
+        else:
+            np.testing.assert_allclose(a, b, rtol=BF16_ULP, atol=1e-6)
+    # the leading entries pass through, position 0 is the identity
+    out = got[0].reshape(shape)
+    xn = np.asarray(x.astype(jnp.float32)).reshape(shape)
+    keep = d - (rd or d)
+    np.testing.assert_array_equal(out[..., :keep], xn[..., :keep])
+    scale = attrs.get("mscale", 1.0)
+    np.testing.assert_allclose(out[:, 0, :, keep:], scale * xn[:, 0, :, keep:],
+                               rtol=BF16_ULP if dtype == "bfloat16"
+                               else 1e-6)
+
+
+def test_rotary_falls_back_where_heads_fill_no_lane_tile():
+    """The latent attention's one shared key [B, T, 1, 64]: H D is no
+    multiple of 128, no chunk of whole lane tiles holds whole heads,
+    and the XLA form runs whatever impl was asked for, counted so."""
+    from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.ops import pallas_rotary
+
+    def counts():
+        return {lbl["impl"]: int(n) for lbl, n in pk._M_KERNEL_IMPL.items()
+                if lbl["kernel"] == "rotary"}
+
+    assert pallas_rotary.blocks(64, 64, 64) is None
+    assert pallas_rotary.blocks(64, 128, 64) == (64, 128)
+    assert pallas_rotary.blocks(40, 128, 64) is None       # no row tile
+    x = jnp.asarray(np.random.default_rng(6).normal(0, 1, (2, 64, 1, 64)),
+                    jnp.float32)
+    before = counts()
+    got = _op("rotary_embedding", {"X": x}, impl="pallas")["Out"]
+    after = counts()
+    assert after.get("xla", 0) == before.get("xla", 0) + 1
+    assert after.get("pallas", 0) == before.get("pallas", 0)
+    np.testing.assert_array_equal(
+        got, _op("rotary_embedding", {"X": x}, impl="xla")["Out"])
+    # two heads of 64 fill a lane tile: the kernel
+    _op("rotary_embedding", {"X": jnp.tile(x, (1, 1, 2, 1))},
+        impl="interpret")
+    assert counts().get("interpret", 0) == after.get("interpret", 0) + 1
+    with pytest.raises(ValueError, match="n_head"):
+        _op("rotary_embedding", {"X": x.reshape(2, 64, 64)})
+    with pytest.raises(ValueError, match="n_head"):
+        _op("rotary_embedding", {"X": x}, n_head=1)
+
+
 # -- router ------------------------------------------------------------------
 
 def _route(x, w, bias, **kw):

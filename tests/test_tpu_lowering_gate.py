@@ -64,6 +64,20 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
     assert detail["flash_ops"] == flash_ops
     assert detail["kernel_calls"]["pt_flash_fwd"] == flash_ops
     assert detail["kernel_calls"]["pt_flash_bwd_dkv"] == flash_ops
+    # the rotary ops whose X is whole lane tiles of heads (every q and
+    # k; not the latent attention's one shared key a token) turn it
+    # where it lies (ISSUE 54): pt_rotary in the forward pass, in the
+    # segment's replay, and once more as the op's own backward
+    rotary_ops = {"xing4_train_tiny": 5, "ouro_train_tiny": 48,
+                  "dsv2_train_tiny": 5, "ling3_train_tiny": 1,
+                  "lfm2_train_tiny": 2, "mellum2_train_tiny": 8}
+    assert (workload in chip_gate.ROTARY_KERNEL) == (workload in rotary_ops)
+    if workload in rotary_ops:
+        assert detail["rotary_kernel_ops"] == rotary_ops[workload]
+        assert detail["kernel_calls"]["pt_rotary"] \
+            == 3 * rotary_ops[workload]
+    else:
+        assert "pt_rotary" not in detail["kernel_calls"]
     if workload == "granite_train_tiny":
         # one period of granite-4.0-h-micro at its head sizes, state
         # size and chunk (ISSUE 38): nine scans, each forward kernel
@@ -129,7 +143,8 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert detail["moe_ops"] == 4
         assert detail["kernel_calls"]["pt_moe_combine"] == 8
         assert detail["kernel_calls"]["pt_row_buffer"] == 20
-        assert detail["tpu_custom_calls"] == 84
+        # 24 pt_rotary among them since ISSUE 54
+        assert detail["tpu_custom_calls"] == 108
         from paddle_tpu import framework
 
         ops = framework.default_main_program().global_block().ops
@@ -186,7 +201,8 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
         assert detail["moe_ops"] == 4
         assert detail["kernel_calls"]["pt_moe_combine"] == 8
         assert detail["kernel_calls"]["pt_row_buffer"] == 20
-        assert detail["tpu_custom_calls"] == 90
+        # 6 pt_rotary among them since ISSUE 54
+        assert detail["tpu_custom_calls"] == 96
     if workload == "dsv2_train_tiny":
         # four expert layers at the published expert width, 1,408 =
         # 11 x 128: the grouped matmuls compile with that axis whole
@@ -223,7 +239,8 @@ def test_recompute_step_holds_one_forward_kernel_a_flash_op(
             "pt_mhc_pre_fwd": 20, "pt_mhc_post_fwd": 15,
             "pt_mhc_pre_bwd": 10, "pt_mhc_post_bwd": 10}
         assert detail["mhc_stream_moves"] == []
-        assert detail["tpu_custom_calls"] == 145
+        # 15 pt_rotary among them since ISSUE 54
+        assert detail["tpu_custom_calls"] == 160
         assert chip_gate.STEP_BYTES_MAX["xing4_train"] == 12_753_077_248
 
 

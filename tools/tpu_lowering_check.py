@@ -414,6 +414,20 @@ def head_layout_copies(hlo_text):
         r" = (?:bf16|f16|f32)\[\d+(?:,\d+){3}\]\S* copy\(", entry))
 
 
+def token_relayouts(hlo_text, tokens):
+    """`copy`, `transpose` and `reshape` instructions (not the
+    asynchronous copies between memory spaces) of float arrays with an
+    axis of `tokens` in the ENTRY computation of a compiled module:
+    the relayouts of a projection-sized array.  A rotary op that
+    reshapes the lanes to find an entry's partner costs one each for q
+    and k between the projection and the flash call (PERF.md, PR 54);
+    ops/pallas_rotary.py costs none."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    return len(re.findall(
+        r" = (?:bf16|f16|f32)\[(?:\d+,)*%d(?:,\d+)*\]\S* "
+        r"(?:copy|transpose|reshape)\(" % tokens, entry))
+
+
 # the training steps whose every flash_attention op has a grad that
 # reads the forward's Out and LSE, as a grad op of its own or inside a
 # recompute segment: the forward kernel runs once an op (an op with a
@@ -463,6 +477,35 @@ CONV1D_KERNELS = ("granite_train", "granite_train_tiny", "ling3_train",
 # yields an array of T rows (the thirds split off, the gates' products
 # or the three gradients joined: the XLA composition's)
 GATED_CONV_IN_PLACE = {"lfm2_train": 8192, "lfm2_train_tiny": 512}
+
+
+# the training steps whose rotary_embedding ops run the kernel of
+# ops/pallas_rotary.py wherever it can tile X (H D whole lane tiles:
+# every q and k; not the latent attention's one shared key a token):
+# pt_rotary three times an op, the forward pass, its recompute
+# segment's replay, and the backward the same kernel at the negative
+# angle (the op keeps nothing, so a segment binds nothing)
+ROTARY_KERNEL = ("xing4_train", "xing4_train_tiny", "ouro_train",
+                 "ouro_train_tiny", "dsv2_train", "dsv2_train_tiny",
+                 "ling3_train", "ling3_train_tiny", "lfm2_train",
+                 "lfm2_train_tiny", "mellum2_train", "mellum2_train_tiny")
+
+
+def rotary_kernel_ops(program):
+    """The rotary_embedding ops of a program that pt_rotary can tile."""
+    from paddle_tpu.ops import pallas_rotary
+
+    block = program.global_block()
+    n = 0
+    for op in block.ops:
+        if op.type != "rotary_embedding":
+            continue
+        shape = block.var(op.inputs["X"][0]).shape
+        heads = op.attrs.get("n_head")
+        width, d = (shape[2], shape[2] // heads) if heads \
+            else (shape[2] * shape[3], shape[3])
+        n += pallas_rotary.blocks(shape[1], width, d) is not None
+    return n
 
 
 # the training steps whose every moe_experts op combines by token
@@ -641,7 +684,9 @@ def check_workload(name, build):
     three times a moe_experts op; for the MHC_STREAMS_IN_KERNELS steps
     `mhc_stream_moves`, which fails it unless no stream-sized array is
     made under the scope pt_mhc outside the kernels and the four
-    kernels are called MHC_KERNEL_CALLS times; for the
+    kernels are called MHC_KERNEL_CALLS times; for the ROTARY_KERNEL
+    steps `rotary_kernel_ops`, which fails it unless pt_rotary is
+    called three times an op it can tile; for the
     ROW_WORK_IN_LOOPS steps
     `rows_outside_loops`, which fails the workload unless it is 0, and
     for the STEP_BYTES_MAX steps `step_bytes`, which fails it above
@@ -735,6 +780,13 @@ def check_workload(name, build):
             ok &= 0 < 2 * detail["moe_ops"] \
                 <= detail["kernel_calls"].get("pt_moe_combine", 0) \
                 <= 3 * detail["moe_ops"]
+        if name in ROTARY_KERNEL:
+            from paddle_tpu import framework
+
+            detail["rotary_kernel_ops"] = rotary_kernel_ops(
+                framework.default_main_program())
+            ok &= 0 < 3 * detail["rotary_kernel_ops"] \
+                == detail["kernel_calls"].get("pt_rotary")
         if name in MHC_STREAMS_IN_KERNELS:
             detail["mhc_stream_moves"] = mhc_stream_moves(
                 text, MHC_STREAMS_IN_KERNELS[name])
