@@ -51,6 +51,11 @@ white_list = {
     # triangular system, the running state and the saved block states
     # stay float32 (fp16_utils)
     "kda_scan",
+    # EVA attention (ops/pallas_eva.py): bf16 Q, K, V and chunk
+    # summaries on the MXU; the pooling vectors Mu and Phi, the pooling
+    # softmaxes, the running softmax statistics and LSE stay float32
+    # (fp16_utils)
+    "eva_pool", "eva_attention",
 }
 
 # numerically sensitive: keep fp32

@@ -37,6 +37,8 @@ _WHITE_KEEP_FP32 = {
     # a delta-rule scan's log-decays and write strengths: e^G over a
     # chunk in bfloat16 is another recurrence
     "kda_scan": frozenset({"G", "Beta"}),
+    # the learned pooling vectors of a chunk's two softmaxes
+    "eva_pool": frozenset({"Mu", "Phi"}),
 }
 
 # white-list ops with multiple outputs where only SOME are emitted in
@@ -54,6 +56,7 @@ _WHITE_LOWP_OUT = {
     # States, the running state at each chunk's start, is float32
     "ssd_scan": frozenset({"Y"}),
     "kda_scan": frozenset({"O"}),
+    "eva_attention": frozenset({"Out"}),
 }
 
 

@@ -10,6 +10,7 @@ from paddle_tpu.layers import distributions  # noqa: F401
 from paddle_tpu.layers.llm import *  # noqa: F401,F403
 from paddle_tpu.layers.ssm import *  # noqa: F401,F403
 from paddle_tpu.layers.kda import *  # noqa: F401,F403
+from paddle_tpu.layers.eva import *  # noqa: F401,F403
 from paddle_tpu.layers import detection  # noqa: F401
 from paddle_tpu.layers.detection import *  # noqa: F401,F403
 from paddle_tpu.layers.extras import (  # noqa: F401

@@ -29,18 +29,24 @@ def _named(attr, name, part):
     return attr
 
 
-def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None,
+             unit_offset=False):
     """RMSNorm over the last axis with a learnable scale (initially 1);
-    the statistic is float32, the output has the input's dtype."""
+    the statistic is float32, the output has the input's dtype.
+    unit_offset: y = x / rms(x) * (1 + w), the parameter the scale's
+    distance from one (initially 0): what weight decay and a small
+    initialisation then pull towards is the identity."""
     from paddle_tpu.initializer import Constant
 
     helper = LayerHelper("rms_norm", name=name)
     scale = helper.create_parameter(
         _named(param_attr, name, ""), [int(input.shape[-1])], "float32",
-        default_initializer=Constant(1.0))
+        default_initializer=Constant(0.0 if unit_offset else 1.0))
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op(type="rms_norm", inputs={"X": input, "Scale": scale},
-                     outputs={"Y": out}, attrs={"epsilon": float(epsilon)})
+                     outputs={"Y": out},
+                     attrs={"epsilon": float(epsilon),
+                            "unit_offset": bool(unit_offset)})
     return out
 
 

@@ -19,3 +19,4 @@ from paddle_tpu.models.yolov3 import yolov3
 from paddle_tpu.models.vgg import vgg, vgg16
 from paddle_tpu.models.se_resnext import se_resnext
 from paddle_tpu.models.mellum2 import mellum2_model
+from paddle_tpu.models.evabyte import evabyte_model

@@ -30,3 +30,4 @@ from paddle_tpu.ops import fused_ops  # noqa: F401
 from paddle_tpu.ops import llm_ops  # noqa: F401
 from paddle_tpu.ops import ssd_ops  # noqa: F401
 from paddle_tpu.ops import kda_ops  # noqa: F401
+from paddle_tpu.ops import eva_ops  # noqa: F401

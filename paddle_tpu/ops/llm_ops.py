@@ -52,15 +52,18 @@ def _rms(xf, eps):
 
 
 @register_op("rms_norm", inputs=("X", "Scale"), outputs=("Y",),
-             attrs={"epsilon": 1e-6})
+             attrs={"epsilon": 1e-6, "unit_offset": False})
 def rms_norm(ins, attrs):
     """Y = X / sqrt(mean(X^2, last axis) + epsilon) * Scale, the
-    statistic in float32, Y in X's dtype."""
+    statistic in float32, Y in X's dtype.  unit_offset: times
+    (1 + Scale), the scale held as its distance from one."""
     x = ins["X"]
     with jax.named_scope("pt_rms_norm"):
-        y = _rms(x.astype(_F32), attrs["epsilon"]) \
-            * ins["Scale"].astype(_F32)
-        return {"Y": y.astype(x.dtype)}
+        y = _rms(x.astype(_F32), attrs["epsilon"])
+        scale = ins["Scale"].astype(_F32)
+        if attrs.get("unit_offset"):
+            scale = 1.0 + scale
+        return {"Y": (y * scale).astype(x.dtype)}
 
 
 @register_op("swiglu", inputs=("Gate", "Up"), outputs=("Out",))

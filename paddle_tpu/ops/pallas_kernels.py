@@ -72,7 +72,11 @@ from paddle_tpu.observability import metrics as _obs_metrics
 _M_KERNEL_IMPL = _obs_metrics.counter(
     "paddle_tpu_kernel_impl_total",
     "Pallas-backed kernel entries by the implementation they resolved "
-    "to (pallas | interpret | xla), after every reroute")
+    "to (pallas | interpret | xla), after every reroute",
+    # about 35 kernel names by two to four impls each: a process that
+    # runs them all (a test worker) passed the default 64, and the
+    # overflow series has no `kernel` label for a reader to key on
+    max_series=256)
 
 
 def _count_impl(kernel, impl):

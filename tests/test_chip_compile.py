@@ -60,6 +60,34 @@ def _flash_token_major(shape, heads, grad, kv_width=None, window=None):
         q, k, v, o, lse, g, **call)), (x, kv, kv, x, lse, x), 1
 
 
+def _eva(part, shape=(1, 8192, 4096), heads=32, window=2048, chunk=16):
+    """EvaByte's cell: 32 heads of 128 over 8,192 bytes, windows of
+    2,048 and chunks of 16.  "pool": the summariser, forward or with
+    its backward; "attention": the aggregation's forward (four causal
+    flash windows, the staircase) or its backward on the saved Out and
+    LSE."""
+    from paddle_tpu.ops import pallas_eva
+
+    x = _sds(shape)
+    pooled = _sds((shape[0], shape[1] // chunk, shape[2]))
+    vec = _sds((heads, shape[2] // heads), jnp.float32)
+    geometry = (heads, window, chunk, 128 ** -0.5, False)
+    if part == "pool_fwd":
+        return (lambda k, v, mu, phi: pallas_eva.eva_pool_fwd_pallas(
+            k, v, mu, phi, heads=heads, chunk=chunk)), (x, x, vec, vec), 1
+    if part == "pool_bwd":
+        return (lambda k, v, mu, phi, dks, dvs:
+                pallas_eva.eva_pool_bwd_pallas(
+                    k, v, mu, phi, dks, dvs, heads=heads, chunk=chunk)), \
+            (x, x, vec, vec, pooled, pooled), 1
+    if part == "attention_fwd":
+        return (lambda *a: pallas_eva._aggregate_fwd(*a, *geometry)), \
+            (x, x, x, pooled, pooled), 2
+    lse = _sds((shape[0] * shape[1] // window, heads, window), jnp.float32)
+    return (lambda *a: pallas_eva._aggregate_bwd(*a, *geometry)), \
+        (x, x, x, pooled, pooled, x, lse, x), 2
+
+
 def _attention_block(batch=64, seq=512, width=512, heads=8):
     """Projection -> flash -> projection, with its gradient: what a
     Transformer layer's attention is once the op takes the
@@ -438,6 +466,10 @@ CASES = {
            (1, 16384, 4096), 32, grad, kv_width=512, window=window))
        for grad in (False, True) for window in (None, 1024)},
     "attention_block_64x512x512_token_major": _attention_block,
+    **{"eva_%s_1x8192x4096_w2048_c16" % part:
+       (lambda part=part: _eva(part))
+       for part in ("pool_fwd", "pool_bwd", "attention_fwd",
+                    "attention_bwd")},
     "flash_fwd_1x32x4096_qk192_v128": lambda: _flash_mla(False),
     "flash_bwd_saved_1x32x4096_qk192_v128": lambda: _flash_mla(True),
     "gmm_fwd_8x3584x1024_rows16384": lambda: _gmm("fwd"),
@@ -498,6 +530,16 @@ ROTARY_RELAYOUTS = {"mellum2_attention_inputs_rotary_kernel": 0,
 WINDOW_KERNELS = {False: "pt_flash_win_fwd", True: "pt_flash_win_bwd_dkv"}
 
 
+# the Mosaic calls of the EVA entries, by name
+EVA_KERNELS = {
+    "eva_pool_fwd_1x8192x4096_w2048_c16": {"pt_eva_pool_fwd": 1},
+    "eva_pool_bwd_1x8192x4096_w2048_c16": {"pt_eva_pool_bwd": 1},
+    "eva_attention_fwd_1x8192x4096_w2048_c16":
+    {"pt_flash_fwd": 1, "pt_eva_chunk_fwd": 1},
+    "eva_attention_bwd_1x8192x4096_w2048_c16":
+    {"pt_flash_bwd_dkv": 1, "pt_eva_chunk_bwd": 1}}
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_described_v5e(chip_gate, case):
     fn, avals, n_kernels = CASES[case]()
@@ -511,6 +553,11 @@ def test_kernel_compiles_for_described_v5e(chip_gate, case):
         # no head split or merge around the kernels
         assert chip_gate.head_layout_copies(exe.as_text()) \
             == GROUP_SUM_COPIES.get(case, 0)
+    if case.startswith("eva_"):
+        assert chip_gate.kernel_calls(exe.as_text()) == EVA_KERNELS[case]
+        # no [T, W] or [T, T/c] score array a head, in either direction
+        assert not chip_gate.arrays_of(exe.as_text(), (8192, 2048),
+                                       (8192, 512), (2048, 2048))
     if case in ROTARY_RELAYOUTS:
         assert chip_gate.token_relayouts(exe.as_text(), 16384) \
             == ROTARY_RELAYOUTS[case]

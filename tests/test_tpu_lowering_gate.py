@@ -44,6 +44,30 @@ def test_bench_workload_lowers_for_tpu(chip_gate, workload):
         assert detail["tpu_custom_calls"] == 12
 
 
+def test_evabyte_step_holds_each_eva_kernel_once_an_op(chip_gate):
+    """EvaByte's four layers at the published head size, window and
+    chunk, two windows, narrow (ISSUE 55): under RecomputeOptimizer a
+    segment's backward takes the saved summaries, Out and LSE, so the
+    compiled step holds each of the six Mosaic calls of an EVA mixer
+    (the summariser, a window's causal flash, the staircase; forward
+    and backward) once a layer and not again in the replay; q and k
+    turn where they lie; and no float array of [.., 4096, 2048] or, a
+    window a row, [.., 2048, 2048] (a window's scores) or [.., 4096,
+    256] (the chunk keys') exists."""
+    workload = "evabyte_train_tiny"
+    assert workload in chip_gate.EVA_KERNELS_AN_OP
+    assert workload in chip_gate.ROTARY_KERNEL
+    ok, detail, _ = chip_gate.check_workload(
+        workload, chip_gate._workloads()[workload])
+    assert ok, detail
+    assert detail["eva_ops"] == 4
+    assert detail["eva_score_arrays"] == []
+    assert detail["kernel_calls"] == dict(
+        {k: 4 for k in chip_gate.EVA_KERNELS}, pt_rotary=24)
+    assert detail["rotary_kernel_ops"] == 8
+    assert detail["tpu_custom_calls"] == 48
+
+
 @pytest.mark.parametrize("workload,flash_ops", [
     ("xing4_train_tiny", 5), ("ouro_train_tiny", 24),
     ("dsv2_train_tiny", 5), ("granite_train_tiny", 1),

@@ -297,6 +297,13 @@ def _build_mellum2_train(batch=1, seq=16384, **sizes):
                              batch, seq, sizes)
 
 
+def _build_evabyte_train(batch=1, seq=8192, **sizes):
+    """EvaByte's train step (EVA attention in every layer, eight
+    next-byte heads) as the cell `evabyte_6_5b_train_s8k` runs it."""
+    return _build_cell_train("evabyte-6.5b.json", "evabyte.py", batch,
+                             seq, sizes)
+
+
 def _build_xing4_train(batch=1, seq=4096, **sizes):
     """The 2024-26 decoder block's train step as the cell
     `xing4_29b_train_s4k` runs it."""

@@ -203,6 +203,20 @@ def _workloads():
         "mellum2_train_tiny": lambda: progs._build_mellum2_train(
             1, 2048, hidden_size=256, num_attention_heads=8,
             num_key_value_heads=1, vocab_size=512)[:3],
+        # EvaByte's first four layers at the cell's sizes (1 x 8,192
+        # bytes at hidden 4,096, 32 heads of 128, four windows of 2,048
+        # in chunks of 16, SwiGLU 11,008, eight heads of 320 ids, 821 M
+        # parameters): whether 13.14 GB of state and the step's
+        # activations fit (STEP_BYTES_MAX), that every EVA kernel runs
+        # once an op and that no score array a head exists
+        # (EVA_KERNELS_AN_OP)
+        "evabyte_train": lambda: progs._build_evabyte_train(1, 8192)[:3],
+        # the published head size, window and chunk, two windows,
+        # everything else narrow (three heads: a hidden size that is
+        # neither a window's keys nor the 256 chunk keys)
+        "evabyte_train_tiny": lambda: progs._build_evabyte_train(
+            1, 4096, hidden_size=384, num_attention_heads=3,
+            num_key_value_heads=3, intermediate_size=512)[:3],
         # both at the cells' depth and head sizes, narrow and short
         # (256 tokens; seconds to compile): what is checked is how many
         # kernels the step holds, not whether it fits
@@ -393,7 +407,8 @@ def _infer(progs, which, batch, conv_epilogue=False):
 
 FAST_SKIP = ("resnet50_train", "bert_train", "ouro_train",
              "xing4_train", "dsv2_train", "granite_train", "ling3_train",
-             "lfm2_train", "solar_open2_train", "mellum2_train")
+             "lfm2_train", "solar_open2_train", "mellum2_train",
+             "evabyte_train")
 
 # the steps whose attention takes q, k and v token-major, [B, T, H*d]
 # as the projections leave them: their compiled step may hold no head
@@ -488,7 +503,21 @@ GATED_CONV_IN_PLACE = {"lfm2_train": 8192, "lfm2_train_tiny": 512}
 ROTARY_KERNEL = ("xing4_train", "xing4_train_tiny", "ouro_train",
                  "ouro_train_tiny", "dsv2_train", "dsv2_train_tiny",
                  "ling3_train", "ling3_train_tiny", "lfm2_train",
-                 "lfm2_train_tiny", "mellum2_train", "mellum2_train_tiny")
+                 "lfm2_train_tiny", "mellum2_train", "mellum2_train_tiny",
+                 "evabyte_train", "evabyte_train_tiny")
+
+# the training steps whose every layer mixes by EVA attention, an
+# eva_pool and an eva_attention op each: the six Mosaic calls (the
+# summariser, a window's causal flash, the staircase; forward and
+# backward) ONCE an op, since a recompute segment's replay takes the
+# saved summaries, Out and LSE, and no float array of [.., T, W],
+# [.., T, T/c] or, a window a row, [.., W, W]: a head's scores stay in
+# VMEM in both directions
+EVA_KERNELS_AN_OP = {
+    "evabyte_train": ((8192, 2048), (8192, 512), (2048, 2048)),
+    "evabyte_train_tiny": ((4096, 2048), (4096, 256), (2048, 2048))}
+EVA_KERNELS = ("pt_eva_pool_fwd", "pt_eva_pool_bwd", "pt_eva_chunk_fwd",
+               "pt_eva_chunk_bwd", "pt_flash_fwd", "pt_flash_bwd_dkv")
 
 
 def rotary_kernel_ops(program):
@@ -579,6 +608,18 @@ def gated_conv_copies(hlo_text, rows):
             r"(?:slice|copy|fusion)\(" % rows + under, hlo_text, re.M))
 
 
+def arrays_of(hlo_text, *trailing):
+    """The float arrays of a compiled module whose last two dims are
+    one of `trailing` ((rows, columns) pairs), whatever leads them, as
+    the distinct shapes found: a score array of EVA attention a head
+    would be [.., T, W] (a window's keys; [.., W, W] on the window
+    part's reshape) or [.., T, T/c] (the chunk keys); the kernels keep
+    both in VMEM (PERF.md, PR 55)."""
+    return sorted(set(re.findall(
+        r"(?:bf16|f16|f32)\[(?:\d+,)*(?:%s)\]" % "|".join(
+            "%d,%d" % pair for pair in trailing), hlo_text)))
+
+
 def kernel_calls(hlo_text):
     """{kernel name: Mosaic calls} of a compiled module.  The TPU
     compiler names a call after the kernel (`pl.pallas_call(name=)`,
@@ -626,7 +667,13 @@ STEP_BYTES_MAX = {"dsv2_train": 9_700_000_000,
                   # float32 Adam moments, 6.21 GB of gradients, the
                   # [16384, 24576] float32 logits with their gradient
                   # and a segment's replay at 16,384 tokens
-                  "mellum2_train": 13_600_000_000}
+                  "mellum2_train": 13_600_000_000,
+                  # ISSUE 55's gate: 13.14 GB of weights, gradients and
+                  # float32 Adam moments, the float32 stream at the
+                  # layer boundaries and one segment's replay at 8,192
+                  # bytes x hidden 4,096 (the [8192, 11008] SwiGLU
+                  # arrays); the chip holds 15.75 GiB = 16.9e9
+                  "evabyte_train": 15_900_000_000}
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", re.M)
 _CALLED = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
@@ -780,6 +827,19 @@ def check_workload(name, build):
             ok &= 0 < 2 * detail["moe_ops"] \
                 <= detail["kernel_calls"].get("pt_moe_combine", 0) \
                 <= 3 * detail["moe_ops"]
+        if name in EVA_KERNELS_AN_OP:
+            from paddle_tpu import framework
+
+            detail["kernel_calls"] = kernel_calls(text)
+            detail["eva_ops"] = sum(
+                op.type == "eva_attention" for op in
+                framework.default_main_program().global_block().ops)
+            detail["eva_score_arrays"] = arrays_of(
+                text, *EVA_KERNELS_AN_OP[name])
+            ok &= detail["eva_ops"] > 0 and all(
+                detail["kernel_calls"].get(k) == detail["eva_ops"]
+                for k in EVA_KERNELS)
+            ok &= not detail["eva_score_arrays"]
         if name in ROTARY_KERNEL:
             from paddle_tpu import framework
 
